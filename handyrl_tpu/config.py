@@ -152,8 +152,9 @@ DEFAULT_TRAIN_ARGS: Dict[str, Any] = {
     # + DeviceEpisodeStage — make_batch and the per-update observation H2D
     # re-upload leave the hot loop; single-process, ff mode needs
     # turn_based_training: false, turn mode needs observation: true);
-    # 'thread' keeps the in-process threaded batchers (the portable
-    # fallback, also used automatically when a richer plane cannot start)
+    # 'thread' keeps the in-process threaded batchers.  A configured plane
+    # that cannot be built or started raises; the only hand-over left is
+    # the supervised degrade after batcher deaths (pipe_batcher_fallback)
     "batch_pipeline": "shm",
     # shared-memory ring depth, in slots of one (B, T, P, ...) batch each;
     # clamped up to 2*fused_steps + 2 so the double-buffered device-put can
@@ -741,7 +742,7 @@ def validate_args(args: Dict[str, Any]) -> Dict[str, Any]:
     # can be promised at config time
     floor_slots = effective_shm_slots(dict(train, fused_steps=1))
     if (
-        train["batch_pipeline"] in ("shm", "device")  # device falls back to shm
+        train["batch_pipeline"] == "shm"
         and int(train["num_batchers"]) > floor_slots
     ):
         # a child beyond the ring depth would never be dealt a slot: it

@@ -217,7 +217,19 @@ def _spawned_replica_main(pipe, args: Dict[str, Any]) -> None:
     port.  Binds FIRST and reports the port, THEN publishes/warms — the
     honest cold window warm-then-admit exists for: the router connects
     and probes while the engine compiles, and admits only once
-    ``serve_models`` goes live."""
+    ``serve_models`` goes live.
+
+    One process per chip: a replica claims a device of its own.  Spawned
+    on a host whose chip another process holds, it cannot get one — the
+    backend then raises here, before the bind, and the factory reports
+    the reason at once instead of waiting out its spawn timeout."""
+    try:
+        import jax
+
+        jax.devices()
+    except Exception as exc:
+        pipe.send(f"{type(exc).__name__}: {exc}")
+        raise
     from ..envs import make_env, prepare_env
     from ..models import init_variables
     from ..runtime.checkpoint import latest_verified_epoch, load_verified_params
@@ -237,11 +249,7 @@ def _spawned_replica_main(pipe, args: Dict[str, Any]) -> None:
     router = ModelRouter(module, template_obs, serving_cfg, model_dir=model_dir)
     server = ServingServer(router, serving_cfg).run()
     pipe.send(server.bound_port)
-    newest = 0
-    try:
-        newest = latest_verified_epoch(model_dir)
-    except Exception:
-        pass
+    newest = latest_verified_epoch(model_dir)
     if newest > 0:
         template = init_variables(module, env)["params"]
         params = load_verified_params(model_dir, newest, template,
@@ -278,13 +286,24 @@ class ProcessReplicaFactory:
         )
         proc.start()
         child.close()
-        if not parent.poll(self.spawn_timeout_s):
-            proc.terminate()
-            raise OSError(
-                f"spawned replica reported no port within "
-                f"{self.spawn_timeout_s:.0f}s"
-            )
-        port = int(parent.recv())
+        deadline = time.monotonic() + self.spawn_timeout_s
+        while not parent.poll(0.2):
+            if not proc.is_alive():
+                raise OSError(
+                    f"spawned replica died before reporting a port "
+                    f"(exit code {proc.exitcode})"
+                )
+            if time.monotonic() > deadline:
+                proc.terminate()
+                raise OSError(
+                    f"spawned replica reported no port within "
+                    f"{self.spawn_timeout_s:.0f}s"
+                )
+        reply = parent.recv()
+        if not isinstance(reply, int):
+            # the child could not get a device (one process per chip)
+            raise OSError(f"spawned replica found no device: {reply}")
+        port = reply
         spec = ReplicaSpec("127.0.0.1", port)
         with self._lock:
             self._procs[spec.name] = (proc, parent)

@@ -22,12 +22,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -72,19 +68,10 @@ def _ring_loop(q, k, v, extras, axis_name: str, scores_fn, vary_axes=()):
 
     # accumulators start replicated but become device-varying inside the
     # ring loop; marking them keeps shard_map's VMA typing happy with the
-    # carry.  jax.lax.pvary is deprecated in favor of pcast(..., to=varying).
-    # Compat ladder (newest first): pcast (jax >= 0.8), pvary (0.5-0.7),
-    # identity on older jax (e.g. 0.4.37) — those shard_maps have no
-    # varying-in-manual-axes type system, so there is nothing to mark and
-    # the loop's semantics are unchanged (golden-pinned against the einsum
-    # references across all three branches by tests/test_parallel.py).
+    # carry (golden-pinned against the einsum references by
+    # tests/test_parallel.py)
     vary = (axis_name,) + tuple(a for a in vary_axes if a)
-    if hasattr(jax.lax, "pcast"):
-        _mark = lambda x: jax.lax.pcast(x, vary, to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        _mark = lambda x: jax.lax.pvary(x, vary)
-    else:  # pre-VMA jax: no varying types, marking is a no-op
-        _mark = lambda x: x
+    _mark = lambda x: jax.lax.pcast(x, vary, to="varying")
     o = _mark(jnp.zeros((B, T_loc, H, D), jnp.float32))
     m = _mark(jnp.full((B, H, T_loc), NEG_INF, jnp.float32))
     l = _mark(jnp.zeros((B, H, T_loc), jnp.float32))

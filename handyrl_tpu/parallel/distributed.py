@@ -47,26 +47,6 @@ import jax
 import numpy as np
 
 
-def _enable_cpu_collectives() -> None:
-    """CPU-platform runs need a cross-process collectives backend: without
-    it XLA:CPU rejects every multi-process computation outright
-    ("Multiprocess computations aren't implemented on the CPU backend").
-    Select gloo when the platform is pinned to CPU — it must happen BEFORE
-    the backend initializes, which is why it lives here, at the one
-    chokepoint every multi-process entry path already goes through.  Best
-    effort: jax versions where gloo is absent (or already the default)
-    simply proceed."""
-    platforms = (
-        os.environ.get("JAX_PLATFORMS", "") or getattr(jax.config, "jax_platforms", "") or ""
-    )
-    if "cpu" not in str(platforms).lower().split(","):
-        return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-
-
 def _timeout_error(process_id: int, num_processes: int, address: str,
                    timeout: float, last_exc: Optional[BaseException]) -> RuntimeError:
     return RuntimeError(
@@ -127,13 +107,10 @@ def _reset_half_initialized_state() -> None:
     try:
         jax.distributed.shutdown()
     except Exception:
-        try:
-            from jax._src.distributed import global_state
+        from jax._src.distributed import global_state
 
-            global_state.client = None
-            global_state.service = None
-        except Exception:
-            pass
+        global_state.client = None
+        global_state.service = None
 
 
 def init_distributed(dist_args: Optional[Dict[str, Any]]) -> int:
@@ -153,7 +130,6 @@ def init_distributed(dist_args: Optional[Dict[str, Any]]) -> int:
     """
     if not dist_args or not dist_args.get("coordinator_address"):
         return 0
-    _enable_cpu_collectives()
     address = dist_args["coordinator_address"]
     num_processes = int(dist_args["num_processes"])
     process_id = dist_args.get("process_id")

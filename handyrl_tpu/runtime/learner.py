@@ -682,10 +682,7 @@ class Learner:
         record: Dict[str, Any] = {"epoch": self.model_epoch}
 
         if self._device_eval is not None:
-            try:
-                self._feed_device_eval()
-            except Exception as exc:  # eval must never kill the boundary
-                print(f"device eval failed: {type(exc).__name__}: {exc}")
+            self._feed_device_eval()
 
         if self.model_epoch not in self.results:
             # no eval results this epoch: an explicit null record (tooling
@@ -730,8 +727,8 @@ class Learner:
         if self.trainer.stats:
             record.update(self.trainer.stats)
         if self.trainer.device_replay is None:
-            # read the LIVE mode: an shm pipeline that fell back to
-            # threads at start() must not be recorded as shm
+            # read the LIVE mode: an shm pipeline that degraded to
+            # threads after batcher deaths must not be recorded as shm
             try:
                 record["pipeline"] = self.trainer.batcher.stats()["mode"]
             except Exception:
@@ -1195,6 +1192,10 @@ class Learner:
         self._shutdown_t0 = 0.0
 
         while self._workers_active() or not self.shutdown_flag:
+            if self.trainer.failed:
+                raise RuntimeError(
+                    "the training plane stopped on an error (traceback above)"
+                )
             if self._drain_tick():
                 break
             if self.shutdown_flag and not self._shutdown_t0:

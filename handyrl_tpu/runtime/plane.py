@@ -65,7 +65,7 @@ def _local_view(x):
     Under a multi-process run the learner's params live REPLICATED on the
     global train mesh, which is not fully addressable from any one
     process — and device_put of such an array onto a local mesh has been
-    observed (jax 0.4.37 CPU) to silently rewrap the sharding metadata
+    observed (CPU backend) to silently rewrap the sharding metadata
     WITHOUT moving the buffers, handing the actor plane's Execute()
     learner-device buffers (it kills the rollout thread with placement
     errors); np.asarray on one raises outright.  A replicated array's

@@ -285,10 +285,12 @@ class ShmBatchPipeline:
             maxsize=args.get("prefetch_batches", 2)
         )
         # fork shares the already-warm parent image (children need numpy +
-        # this package, not a fresh interpreter); spawn is the portable
-        # fallback and everything passed to the child is picklable
-        method = "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-        self._mp = mp.get_context(method)
+        # this package, not a fresh interpreter) and the ready pipe rides
+        # fork fd inheritance.  The parent holds the accelerator and runs
+        # its runtime's threads; the children never call into jax
+        # (_spawn_child), so they never touch the device client they
+        # inherited
+        self._mp = mp.get_context("fork")
         self._procs: List[Any] = []
         self._feed_qs: List[Any] = []
         self._slot_views = None
@@ -319,19 +321,11 @@ class ShmBatchPipeline:
         self._started = True
         try:
             self._start_impl()
-        except Exception:
-            traceback.print_exc()
-            print(
-                "[handyrl_tpu] shared-memory batch pipeline failed to start "
-                "(above); falling back to threaded batchers "
-                "(batch_pipeline: thread)",
-                file=sys.stderr,
-            )
+        except BaseException:
+            # no quiet hand-over to the threaded pipeline: a run asked for
+            # the shm plane, so a plane that cannot come up is an error
             self.close()
-            from .trainer import BatchPipeline
-
-            self._fallback = BatchPipeline(self.args, self.store, self.ctx, self.stop_event)
-            self._fallback.start()
+            raise
 
     def _sample_template_windows(self):
         windows = []
@@ -362,13 +356,6 @@ class ShmBatchPipeline:
             create=True, size=self._slot_bytes * self._n_slots
         )
         atexit.register(self._unlink_quiet)
-        if "fork" not in mp.get_all_start_methods():
-            # the ready pipe rides fork fd inheritance; platforms without
-            # fork take the (loud) threaded fallback via start()'s handler
-            raise RuntimeError(
-                "shm batch pipeline requires the fork start method "
-                "(ready-pipe fds are fork-inherited)"
-            )
         self._ready_r, self._ready_w = os.pipe()
         self._ready_buf = b""
         # lock-FREE stop flag, not mp.Event: Event.is_set() takes the

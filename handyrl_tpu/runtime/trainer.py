@@ -82,35 +82,19 @@ def make_pipeline(args: Dict[str, Any], store: EpisodeStore, ctx: TrainContext,
     into device ring buffers and samples/assembles training windows on
     device (runtime/device_batch.py — make_batch and the per-update
     observation H2D re-upload leave the hot loop); ``thread`` — or
-    num_batchers 0, or any platform where the shm plane cannot come up —
-    uses the in-process threaded pipeline.  All three expose
+    num_batchers 0 — uses the in-process threaded pipeline.  A configured
+    pipeline that cannot be built raises: a run never trains through a
+    different pipeline than the one it was asked for.  All three expose
     start()/batch()/stop()/stats()."""
     mode = args.get("batch_pipeline", "shm")
     if mode == "device":
-        try:
-            from .device_batch import DeviceBatchPipeline
+        from .device_batch import DeviceBatchPipeline
 
-            return DeviceBatchPipeline(args, store, ctx, stop_event)
-        except Exception:
-            traceback.print_exc()
-            print(
-                "[handyrl_tpu] device batch pipeline unavailable (above); "
-                "falling back to the shm assembly plane",
-                file=sys.stderr,
-            )
-            mode = "shm"
+        return DeviceBatchPipeline(args, store, ctx, stop_event)
     if mode == "shm" and int(args.get("num_batchers", 0)) > 0:
-        try:
-            from .shm_batch import ShmBatchPipeline
+        from .shm_batch import ShmBatchPipeline
 
-            return ShmBatchPipeline(args, store, ctx, stop_event)
-        except Exception:
-            traceback.print_exc()
-            print(
-                "[handyrl_tpu] shared-memory batch pipeline unavailable "
-                "(above); using threaded batchers",
-                file=sys.stderr,
-            )
+        return ShmBatchPipeline(args, store, ctx, stop_event)
     return BatchPipeline(args, store, ctx, stop_event)
 
 
@@ -270,6 +254,7 @@ class Trainer:
         self.state_host = jax.device_get(self.state)
         self.store = EpisodeStore(args["maximum_episodes"])
         self.stop_event = threading.Event()
+        self._stop_requested = False    # stop() was called by the owner
 
         self.fused = max(1, args.get("fused_steps", 1))
         if self.fused > 1 and jax.default_backend() == "cpu" and mesh.size > 1:
@@ -1088,15 +1073,14 @@ class Trainer:
                      - prev.get("device_queue_depth_sum", 0.0)) / gets, 3
                 )
             self._pipe_stats0 = cur
-        from ..parallel.train_step import peak_flops_per_chip
-
-        peak = peak_flops_per_chip(jax.devices()[0])
-        if peak:  # unknown device kind (e.g. CPU): stat omitted, and the
-            # one-time trace below is skipped — it could never be used.
-            # Resolution happens AFTER `elapsed` is taken: a multi-second
-            # lowering must not deflate the first epoch's rate stats.
+        peak = self._peak_flops()
+        if peak:
+            # resolution happens AFTER `elapsed` is taken: a multi-second
+            # lowering must not deflate the first epoch's rate stats
             if self._flops_per_update is None:
-                self._resolve_flops(replay_train, last_batch)
+                self._flops_per_update = self._resolve_flops(
+                    replay_train, last_batch
+                )
             if self._flops_per_update:
                 self.stats["mfu"] = round(
                     self._flops_per_update * batch_cnt
@@ -1116,51 +1100,57 @@ class Trainer:
         self.state_host = jax.device_get(self.state)
         return self.state_host["params"]
 
-    def _resolve_flops(self, replay_train, batch) -> None:
-        """One-time FLOPs-per-update resolution at the end of the first
-        trained epoch (a lowering / trace, nothing executes).  Failure
-        records 0.0 so it is never retried every epoch."""
-        try:
-            if replay_train is not None:
-                self._flops_per_update = float(
-                    replay_train.flops_per_update(self.state)
-                )
-            elif batch is not None:
-                if self.fused > 1:
-                    # stacked (k, B, ...) tree -> one batch of AVALS: a
-                    # concrete x[0] slice would dispatch multi-device
-                    # gathers outside the per-device dispatch locks (the
-                    # serialized-dispatch invariant, parallel/mesh.py);
-                    # the lowering only needs shapes
-                    batch = jax.tree.map(
-                        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                        batch,
-                    )
-                self._flops_per_update = float(
-                    self.ctx.flops_per_step(self.state, batch) or 0.0
-                )
-            else:
-                self._flops_per_update = 0.0
-        except Exception:
-            # degrade loudly, once: a silent 0.0 would drop the mfu stat
-            # from metrics.jsonl for the whole run with no hint why
-            import sys
+    def _peak_flops(self) -> Optional[float]:
+        """Peak FLOP/s of one of the step's devices.  Utilization is a
+        statement about an accelerator: a CPU run records none (None),
+        and an accelerator whose kind has no peak in the table is an
+        error, not a silently missing stat."""
+        from ..parallel.train_step import peak_flops_per_chip
 
-            traceback.print_exc(limit=2, file=sys.stderr)
-            print(
-                "[handyrl_tpu] FLOPs-per-update resolution failed (above); "
-                "metrics.jsonl will carry no 'mfu' stat this run",
-                file=sys.stderr,
+        device = self.ctx.mesh.devices.flat[0]
+        return None if device.platform == "cpu" else peak_flops_per_chip(device)
+
+    def _resolve_flops(self, replay_train, batch) -> float:
+        """One-time FLOPs-per-update resolution at the end of the first
+        trained epoch (a lowering / trace, nothing executes); 0.0 when
+        the epoch trained nothing to trace."""
+        if replay_train is not None:
+            return float(replay_train.flops_per_update(self.state))
+        if batch is None:
+            return 0.0
+        if self.fused > 1:
+            # stacked (k, B, ...) tree -> one batch of AVALS: a concrete
+            # x[0] slice would dispatch multi-device gathers outside the
+            # per-device dispatch locks (the serialized-dispatch
+            # invariant, parallel/mesh.py); the lowering only needs shapes
+            batch = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), batch
             )
-            self._flops_per_update = 0.0
+        return float(self.ctx.flops_per_step(self.state, batch))
+
+    @property
+    def failed(self) -> bool:
+        """The training plane went down on its own: run() or a pipeline
+        thread raised (traceback on stderr) and set the stop event, which
+        otherwise only stop() sets.  The learner raises on it — a run
+        whose trainer died must not finish its epochs untrained."""
+        return self.stop_event.is_set() and not self._stop_requested
 
     def stop(self):
+        self._stop_requested = True
         self.stop_event.set()
         # process batchers need an explicit join + shm unlink; the
         # threaded pipeline's stop() is just the event set again
         self.batcher.stop()
 
     def run(self):
+        try:
+            self._run()
+        except BaseException:
+            self.stop_event.set()  # -> failed; the learner raises on it
+            raise
+
+    def _run(self):
         print("waiting training")
         while not self._warmed_up():
             if self.stop_event.is_set():

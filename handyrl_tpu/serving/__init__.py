@@ -22,7 +22,7 @@ from .batcher import (
 )
 from .client import ServingClient, ServingError
 from .router import EnsembleRoute, ModelRouter, RouteError
-from .server import ServingServer, serve_main
+from .server import ServingServer, build_serving, serve_main
 
 __all__ = [
     "BadRequest",
@@ -36,5 +36,6 @@ __all__ = [
     "ModelRouter",
     "RouteError",
     "ServingServer",
+    "build_serving",
     "serve_main",
 ]

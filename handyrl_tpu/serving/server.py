@@ -75,7 +75,7 @@ from ..utils.metrics import append_metrics_record
 from ..utils.trace import trace_event
 from .router import ColdRoute, ModelRouter
 
-__all__ = ["ServingServer", "serve_main"]
+__all__ = ["ServingServer", "build_serving", "serve_main"]
 
 
 class ServingServer(QueueCommunicator):
@@ -649,14 +649,16 @@ class ServingServer(QueueCommunicator):
         append_metrics_record(self._metrics_path, record)
 
 
-def serve_main(args: Dict[str, Any]) -> None:
-    """`main.py --serve`: standalone serving plane for the configured env.
+def build_serving(args: Dict[str, Any]) -> ServingServer:
+    """The serving plane `main.py --serve` runs, built and listening:
+    router, newest verified snapshot published, optional flywheel, socket
+    server started.  The router is ``server.router``.
 
-    Publishes the newest manifest-verified snapshot (fresh-init params
-    when the model dir is empty — a cold dev server still answers), then
-    serves until interrupted.  With ``serving.watch_interval`` > 0 the
-    server follows the training run's checkpoints: every new verified
-    snapshot hot-swaps in with zero dropped requests.
+    Publishes the newest manifest-verified snapshot; an EMPTY model dir
+    gets fresh-init params under id 0 (a cold dev server still answers).
+    A model dir that is there but cannot be scanned raises — serving
+    random weights in place of a checkpoint that failed to load is not a
+    degraded mode, it is a wrong answer.
     """
     from ..envs import make_env, prepare_env
     from ..utils import trace
@@ -675,11 +677,7 @@ def serve_main(args: Dict[str, Any]) -> None:
     router = ModelRouter(
         module, template_obs, train.get("serving", {}), model_dir=model_dir
     )
-    newest = 0
-    try:
-        newest = latest_verified_epoch(model_dir)
-    except Exception as exc:
-        print(f"serving: checkpoint scan failed ({exc}); starting fresh")
+    newest = latest_verified_epoch(model_dir)
     if newest > 0:
         template = init_variables(module, env)["params"]
         params = load_verified_params(model_dir, newest, template, pre_verified=True)
@@ -720,6 +718,18 @@ def serve_main(args: Dict[str, Any]) -> None:
     ).run()
     print(f"serving: listening on port {server.bound_port} "
           f"(model {router.latest_id()}, dir {model_dir!r})", flush=True)
+    return server
+
+
+def serve_main(args: Dict[str, Any]) -> None:
+    """`main.py --serve`: standalone serving plane for the configured env
+    (``build_serving``), served until interrupted.  With
+    ``serving.watch_interval`` > 0 the server follows the training run's
+    checkpoints: every new verified snapshot hot-swaps in with zero
+    dropped requests.
+    """
+    server = build_serving(args)
+    train = args["train_args"]
 
     # preemption-aware replica (docs/fault_tolerance.md): SIGTERM — the
     # spot-instance eviction signal — triggers a bounded drain: broadcast

@@ -1,5 +1,5 @@
+from .compile_cache import enable_compile_cache
 from .metrics import METRIC_KEY_PREFIXES, METRIC_KEYS, read_metrics
-from .platform import apply_platform_override
 from .sanitizers import HostSyncSanitizer, RecompileSentinel
 from .tree import (
     tree_map,
@@ -12,7 +12,7 @@ from .tree import (
 )
 
 __all__ = [
-    "apply_platform_override",
+    "enable_compile_cache",
     "read_metrics",
     "METRIC_KEYS",
     "METRIC_KEY_PREFIXES",

@@ -123,17 +123,10 @@ class RecompileSentinel:
         return self
 
     def __exit__(self, *exc: Any) -> None:
-        self._armed = False
-        try:
-            from jax._src import monitoring as _mon
+        import jax.monitoring
 
-            unregister = getattr(
-                _mon, "_unregister_event_duration_listener_by_callback", None
-            )
-            if unregister is not None:
-                unregister(self._on_event)
-        except Exception:
-            pass  # listener stays registered but disarmed
+        self._armed = False
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
 
     @property
     def count(self) -> int:
@@ -262,19 +255,13 @@ class HostSyncSanitizer:
 
         self._patch(jax, "block_until_ready", "block_until_ready")
         self._patch(jax, "device_get", "device_get")
-        try:
-            from jax._src.array import ArrayImpl
+        from jax._src.array import ArrayImpl
 
-            # _value is the cached to-host conversion float()/.item()/
-            # __array__ funnel through on this jax (a property attached to
-            # the extension type — patchable from Python)
-            if isinstance(ArrayImpl.__dict__.get("_value"), property):
-                self._patch(ArrayImpl, "_value", "to_host")
-            arr = ArrayImpl.__dict__.get("__array__")
-            if callable(arr):
-                self._patch(ArrayImpl, "__array__", "to_host")
-        except Exception:
-            pass  # older/newer jax layout: module-level hooks still armed
+        # _value is the cached to-host conversion float()/.item()/
+        # __array__ funnel through (a property attached to the extension
+        # type — patchable from Python)
+        self._patch(ArrayImpl, "_value", "to_host")
+        self._patch(ArrayImpl, "__array__", "to_host")
         return self
 
     def __exit__(self, *exc: Any) -> None:

@@ -20,18 +20,12 @@ Modes:
     --eval-client / -ec      network battle client
 """
 
-import os
 import sys
 
 import yaml
 
-# Platform override BEFORE any backend initializes (shared helper; see
-# handyrl_tpu/utils/platform.py for why JAX_PLATFORMS alone is not enough).
-from handyrl_tpu.utils import apply_platform_override
-
-apply_platform_override()
-
 from handyrl_tpu.config import normalize_args
+from handyrl_tpu.utils import enable_compile_cache
 
 
 def load_args(path: str = "config.yaml"):
@@ -40,6 +34,7 @@ def load_args(path: str = "config.yaml"):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     try:
         args = load_args()
     except FileNotFoundError:

@@ -268,16 +268,17 @@ def test_make_pipeline_selects_device_mode():
     ctx = TrainContext(module, targs, make_mesh({"dp": 1}))
     store = EpisodeStore(10)
     assert isinstance(make_pipeline(targs, store, ctx), DeviceBatchPipeline)
-    # a stage-mode misconfiguration falls back LOUDLY instead of dying:
-    # recurrent net in ff mode -> shm -> (num_batchers > 0) ShmBatchPipeline
+    # a stage-mode misconfiguration raises — a run never trains through
+    # another pipeline than the one it was asked for (recurrent net in
+    # ff mode cannot stage on device)
     bad = _targs("Geister", batch_size=4, forward_steps=8,
                  turn_based_training=False, batch_pipeline="device")
     genv = make_env({"env": "Geister"})
     gctx = TrainContext(genv.net(), dict(bad, turn_based_training=True,
                                          observation=True),
                         make_mesh({"dp": 1}))
-    pipe = make_pipeline(bad, store, gctx)
-    assert not isinstance(pipe, DeviceBatchPipeline)
+    with pytest.raises(ValueError, match="recurrent net"):
+        make_pipeline(bad, store, gctx)
 
 
 def test_config_validates_device_stage_knobs():

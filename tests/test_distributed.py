@@ -447,7 +447,7 @@ def test_worker_chaos_kill_and_rejoin(tmp_path, monkeypatch):
     env = {
         **os.environ,
         "PYTHONPATH": repo,
-        "HANDYRL_PLATFORM": "cpu",  # a killed process must never hold a chip lease
+        "JAX_PLATFORMS": "cpu",  # a child must never claim the parent's chip
     }
 
     def spawn_worker():

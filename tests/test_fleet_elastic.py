@@ -164,6 +164,26 @@ def test_decider_scales_down_only_after_sustained_calm():
 
 
 @pytest.mark.slow  # ~3.5s of socket warm-probe waits; CI fleet step runs it
+def test_spawned_replica_without_device_fails_at_once(monkeypatch):
+    """One process per chip: a replica spawned where it cannot get a
+    device must say so at once, not wait out spawn_timeout_s (the child
+    inherits a platform jax cannot initialize; this process keeps its
+    own, already-initialized CPU backend)."""
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.fleet.autoscale import ProcessReplicaFactory
+
+    factory = ProcessReplicaFactory(
+        normalize_args({"env_args": {"env": "TicTacToe"}, "train_args": {}}),
+        spawn_timeout_s=600.0,
+    )
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")
+    t0 = time.monotonic()
+    with pytest.raises(OSError, match="found no device"):
+        factory.spawn()
+    assert time.monotonic() - t0 < 60.0
+    factory.close()
+
+
 def test_cold_replica_is_warming_not_live_until_published(tmp_path):
     """A connected replica with NO published engine takes zero traffic:
     it shows as warming, every request lands on the warm replica, and

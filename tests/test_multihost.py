@@ -33,8 +33,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -106,8 +104,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from handyrl_tpu.parallel import init_distributed, is_coordinator, make_mesh
@@ -144,8 +140,6 @@ port, pid, nproc, outdir = sys.argv[1], int(sys.argv[2]), int(sys.argv[3]), sys.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -224,7 +218,6 @@ def test_two_process_ring_attention(tmp_path):
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from handyrl_tpu.ops.flash_attention import masked_attention_reference
 
     q, k, v, key_mask, slopes, window = build_ring_inputs()
@@ -400,7 +393,6 @@ def _two_process_train_and_compare(tmp_path, mesh_spec: str, exact_cross: bool):
     # bf16-matmul params against f32 XLA:CPU params and fail spuriously)
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from handyrl_tpu.parallel import make_mesh
 
     batch, module, params, args = build_ttt_batch()
@@ -459,8 +451,6 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % int(extra.get("devices", 2))
 )
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -676,8 +666,6 @@ port, pid, nproc, outdir = sys.argv[1], int(sys.argv[2]), int(sys.argv[3]), sys.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 from handyrl_tpu.parallel import broadcast_resume_epoch, init_distributed, is_coordinator
 from handyrl_tpu.runtime.checkpoint import latest_verified_epoch
@@ -1127,8 +1115,6 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % int(extra.get("devices", 2))
 )
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 from handyrl_tpu.config import normalize_args
 from handyrl_tpu.runtime.actor_host import actor_host_main

@@ -164,6 +164,34 @@ def test_evaluate_mp_random_vs_random(capsys):
     assert "total =" in out
 
 
+def test_learner_raises_when_the_training_plane_dies(tmp_path, monkeypatch):
+    """A configured pipeline that cannot start is an error, not a quiet
+    hand-over to another pipeline, and a dead trainer must end the run
+    with a raise — not finish its epochs untrained with exit code 0."""
+    from handyrl_tpu.runtime.shm_batch import ShmBatchPipeline
+
+    def broken(self):
+        raise OSError("no shared memory today")
+
+    monkeypatch.setattr(ShmBatchPipeline, "_start_impl", broken)
+    monkeypatch.chdir(tmp_path)
+    args = normalize_args({
+        "env_args": {"env": "TicTacToe"},
+        "train_args": {
+            "batch_size": 8, "forward_steps": 4, "minimum_episodes": 5,
+            "update_episodes": 10, "maximum_episodes": 100, "epochs": 2,
+            "num_batchers": 1, "eval_rate": 0.2,
+            "worker": {"num_parallel": 1},
+        },
+    })
+    learner = Learner(args)
+    with pytest.raises(RuntimeError, match="training plane stopped"):
+        learner.run()
+    assert learner.trainer.failed
+    assert learner.trainer.batcher._fallback is None  # no thread hand-over
+    assert not os.path.exists("models/2.ckpt")
+
+
 @pytest.mark.slow
 def test_end_to_end_training(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)

@@ -92,7 +92,7 @@ def test_bench_tpu_transformer_config_traces():
     flash path's kernel shapes are covered by the battery in
     tests/test_flash_attention.py).  The stage never executes in CI, so without
     this trace a shape bug in the big config would first surface
-    mid-capture on a live chip lease.  eval_shape runs the full trace —
+    mid-capture on the chip.  eval_shape runs the full trace —
     forward, attention, losses, grads, Adam — without lowering or
     allocating the big-net state."""
     import sys
@@ -183,7 +183,7 @@ def test_bench_transformer_long_t1024_pin_traces():
     on TPU at this T), bf16 compute.  Same contract as
     test_bench_tpu_transformer_config_traces: the stage's big points are
     chip-gated, so this trace is what keeps a shape bug from first
-    surfacing mid-capture on a live lease."""
+    surfacing mid-capture on the chip."""
     import sys
     from pathlib import Path
 

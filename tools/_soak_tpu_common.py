@@ -4,7 +4,7 @@ Phase ``train`` (real chip, single process, clean exit): Learner.run() with
 a device-replay config, artifacts in ``run_dir`` (metrics.jsonl +
 models/latest.ckpt), then a CPU-pinned ``eval`` subprocess whose verdict —
 not just its survival — becomes the process exit code.
-Phase ``eval`` (CPU-pinned): matched offline evals of the trained net and
+Phase ``eval`` (CPU-pinned by JAX_PLATFORMS in its environment): matched offline evals of the trained net and
 the SAME net untrained, each vs the baseline opponent through the shared
 margin-calibrated aggregation (runtime/evaluation.py:eval_vs_baseline);
 exits non-zero when the outcome margin misses the bar, so a no-learning
@@ -39,9 +39,11 @@ def _train(script_path: str, cfg: dict, run_dir: str) -> None:
     print(f"platform: {d.platform}:{getattr(d, 'device_kind', '?')}", flush=True)
     Learner(normalize_args(cfg)).run()
     print("training done; launching CPU-pinned matched eval", flush=True)
-    # the eval subprocess pins CPU itself; its verdict is the run's whole
-    # point, so its exit code (crash OR missed margin) is ours
+    # this process still holds the chip, so the eval child is pinned to
+    # the CPU by its environment; its verdict is the run's whole point, so
+    # its exit code (crash OR missed margin) is ours
     rc = subprocess.run([sys.executable, script_path, "eval"],
+                        env=dict(os.environ, JAX_PLATFORMS="cpu"),
                         check=False).returncode
     if rc != 0:
         print(f"matched eval FAILED (rc={rc})", flush=True)
@@ -50,8 +52,6 @@ def _train(script_path: str, cfg: dict, run_dir: str) -> None:
 
 def _evaluate(cfg: dict, run_dir: str, opponent: str, margin: float,
               wp_bar: float, num_games: int) -> None:
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     from handyrl_tpu.agents import Agent
     from handyrl_tpu.config import normalize_args
     from handyrl_tpu.envs import make_env

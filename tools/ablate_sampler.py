@@ -39,10 +39,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from handyrl_tpu.utils import apply_platform_override  # noqa: E402
-
-apply_platform_override()
-
 
 def _common(seed: int):
     from handyrl_tpu.config import normalize_args

@@ -83,7 +83,7 @@ def run_one(env_name: str, device_path: bool, epochs: int, run_root: str,
             {"env_args": {"env": env_name}, "train_args": train_args,
              "worker_args": {"server_address": "", "num_parallel": 4}}, f
         )
-    env = dict(os.environ, HANDYRL_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     t0 = time.perf_counter()
     with open(os.path.join(run_dir, "train.log"), "w") as log:
         rc = subprocess.run(

@@ -9,10 +9,9 @@ Dev/judging aid only (needs torch + mounted reference).
 import os
 import sys
 
-# this tool mixes torch and jax in one process: pin jax to CPU BEFORE any
-# backend init (otherwise a site-installed accelerator backend may be dialed
-# and hang) and keep both runtimes to one OpenMP thread each (oversubscribed
-# OpenMP pools from the two runtimes deadlock on this machine)
+# this tool mixes torch and jax in one process: pin jax to CPU before it
+# is imported and keep both runtimes to one OpenMP thread each
+# (oversubscribed OpenMP pools from the two runtimes deadlock)
 os.environ.setdefault("OMP_NUM_THREADS", "1")
 os.environ["JAX_PLATFORMS"] = "cpu"
 
@@ -22,8 +21,6 @@ sys.path.insert(0, "/root/repo")
 sys.path.insert(0, "/root/reference")
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import torch  # noqa: E402
 

@@ -7,9 +7,9 @@ import os
 import sys
 import time
 
-import jax
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-jax.config.update("jax_platforms", "cpu")
+import jax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -41,10 +41,6 @@ import bench  # noqa: E402  (repo-root import)
 def main() -> None:
     import jax
 
-    from handyrl_tpu.utils import apply_platform_override
-
-    apply_platform_override()
-
     split = "--split" in sys.argv[1:]
     argv = [a for a in sys.argv[1:] if a != "--split"]
     duration = float(argv[0]) if argv else 8.0

@@ -4,7 +4,7 @@ Times the REAL TrainContext step (Geister windows, UPGO-capable losses,
 Adam) on the scaled TransformerNet across batch/window/dtype variants,
 reusing one filled episode store, and prints one JSON line per variant:
 updates/s, flops/update, MFU vs the chip's bf16 peak.  Used to pick the
-shape the bench stage pins; run standalone whenever the lease is live:
+shape the bench stage pins; run standalone on the chip:
 
     python tools/tune_transformer.py            # full sweep (~15 min)
     TUNE_T=6 python tools/tune_transformer.py   # shorter timed windows
@@ -83,7 +83,7 @@ def _rebuild_net(reuse, net_args):
 def main() -> None:
     duration = float(os.environ.get("TUNE_T", "8"))
     # validate the variant filter BEFORE any jax/device touch: a typo must
-    # not cost a backend init (which hangs outright on a wedged lease)
+    # not cost a backend init
     raw_only = os.environ.get("TUNE_ONLY", "").strip()
     only = {s.strip() for s in raw_only.split(",") if s.strip()} or None
     if only:
