@@ -1,0 +1,129 @@
+"""Runner ``train_step``: SGD updates through ``TrainContext`` on staged
+device batches, and nothing else of the program.
+
+Set-up: weights from ``--seed`` made on the device in one jitted call;
+``n_batches`` host batches of seeded random-play windows (the program's
+own Generator -> EpisodeStore -> make_batch path), put on the device once;
+two warm-up updates, the first of which compiles or loads the one program.
+Window: updates back to back, rotating the staged batches, at most
+``in_flight`` dispatched ahead of the host; the clock stops after the last
+update's ``block_until_ready``.  A traced run measures ``trace_seconds``
+under the profiler instead of ``--seconds``.
+
+After the window, outside it: every update's loss is fetched and checked,
+and the forward pass the train step runs (``forward_prediction``, in the
+cell's compute dtype) is compared with the configuration's plain float32
+reference on one batch row.
+"""
+
+from __future__ import annotations
+
+import random
+import time
+
+from benchmark import harness, traffic
+
+
+def run(run: harness.Run) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.envs import make_env
+    from handyrl_tpu.parallel import TrainContext, make_mesh
+    from handyrl_tpu.parallel.train_step import forward_prediction
+    from handyrl_tpu.utils import trace as program_trace
+
+    cell, config = run.cell, run.config
+    cfg = normalize_args({
+        "env_args": dict(config["env_args"]),
+        "train_args": dict(config.get("train_args", {}), **cell["train_args"],
+                           seed=run.seed),
+    })
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    random.seed(run.seed)
+    np.random.seed(run.seed)
+    env = make_env(args["env"])
+    module = env.net()
+
+    params = traffic.seeded_params(module, env, run.seed)
+    host_batches = traffic.random_play_batches(
+        env, module, args, int(cell["n_batches"]), int(cell["fill_episodes"]))
+    ctx = TrainContext(module, args, make_mesh(cell["mesh"], devices=run.devices))
+    state = ctx.init_state(params)
+    del params
+    batches = [ctx.put_batch(b) for b in host_batches]
+    lr = float(cell["lr"])
+    for i in range(2):          # the first compiles or loads; the second proves it
+        state, metrics = ctx.train_step(state, batches[i % len(batches)], lr)
+    jax.block_until_ready((state, metrics))
+
+    seconds = min(run.seconds, float(cell["trace_seconds"])) if run.trace else run.seconds
+    if run.trace:
+        program_trace.configure({"enabled": True, "path": "trace.jsonl"})
+        harness.start_profile(run)
+    pending, fetched = [], []
+    in_flight = int(cell["in_flight"])
+    run.open_window()
+    t0 = time.perf_counter()
+    updates = 0
+    while True:
+        with jax.profiler.TraceAnnotation("bench.train_step"):
+            state, metrics = ctx.train_step(state, batches[updates % len(batches)], lr)
+        updates += 1
+        pending.append(metrics)
+        if len(pending) > in_flight:
+            with jax.profiler.TraceAnnotation("bench.block"):
+                jax.block_until_ready(pending[0])
+            fetched.append(pending.pop(0))
+            if time.perf_counter() - t0 >= seconds:
+                break
+    with jax.profiler.TraceAnnotation("bench.block"):
+        jax.block_until_ready((state, pending))
+    window_s = time.perf_counter() - t0
+    if run.trace:
+        harness.stop_profile(run)
+        program_trace.shutdown()
+        run.spans = program_trace.read_trace("trace.jsonl")
+    run.close_window(window_s)
+
+    fetched = jax.device_get(fetched + pending)
+    losses = np.asarray([float(m["total"]) / max(float(m["dcnt"]), 1.0) for m in fetched])
+    skipped = sum(float(m.get("sentinel_bad", 0.0)) for m in fetched)
+    run.attempted = updates
+    run.failed = int((~np.isfinite(losses)).sum() + skipped)
+    steps = updates * int(args["batch_size"]) * int(args["forward_steps"])
+    run.values["trained_steps_per_s"] = steps / window_s
+    run.counters.update(updates=updates, updates_per_s=updates / window_s,
+                        window_s=window_s)
+    run.notes.update(loss_first=float(losses[0]), loss_last=float(losses[-1]),
+                     params=int(sum(x.size for x in jax.tree.leaves(state["params"]))))
+
+    # -- the train step's forward against the plain reference, one row ----
+    row = jax.tree.map(lambda x: np.asarray(x)[:1], host_batches[0])
+    cdt = jnp.bfloat16 if args.get("compute_dtype") == "bfloat16" else None
+
+    def system_forward(p, batch):
+        if cdt is not None:
+            p = jax.tree.map(lambda x: x.astype(cdt), p)
+        return forward_prediction(module, p, batch, ctx.args)
+
+    # on the seeded weights, not the trained ones: lr 1e-5 on random weights
+    # saturates the value head within tens of updates
+    del state
+    params = traffic.seeded_params(module, env, run.seed)
+    system = jax.device_get(jax.jit(system_forward)(params, row))
+    burn_in = int(args["burn_in_steps"])
+    reference = run.reference()
+    with jax.default_matmul_precision("highest"):
+        want = jax.device_get(jax.jit(
+            lambda p, batch: reference.forward_rows(p, batch, config, burn_in)
+        )(params, row))
+    legal = (row["action_mask"][:, burn_in:] == 0) & (row["turn_mask"][:, burn_in:] > 0)
+    observed = row["observation_mask"][:, burn_in:] > 0
+    masks = {k: (legal if k == "policy" else observed) for k in want}
+    verdict = harness.compare_outputs(system, want, float(config["reference_tolerance"]), masks)
+    run.checks["matches_reference"] = verdict.pop("ok")
+    run.checks["losses_finite"] = bool(np.isfinite(losses).all())
+    run.notes["reference_max_abs_diff"] = verdict
