@@ -1,0 +1,23 @@
+"""Not a metric: what the readers of the loop's device programs share.
+
+The program names its jitted functions itself: a module-level constant
+beside each ``jax.jit`` call (``device_rollout.STREAM_PROGRAM``,
+``device_replay.TRAIN_PROGRAM``, ...), which a profile shows as
+``jit_<constant>(<fingerprint>)``.  A reader imports the constant instead of
+repeating the name, so a rename in the program cannot orphan it; a program
+that has no such constant yet (an older commit) answers ``None``."""
+
+
+def find(run, owner, constant):
+    """Device seconds and executions, inside the traced window, of the XLA
+    program that the program's module ``owner`` names in ``constant``; None
+    where the trace, the constant or the program is missing."""
+    name = getattr(owner, constant, None)
+    if run.reduced is None or name is None:
+        return None
+    hits = [v for k, v in run.reduced["programs"].items()
+            if k.split("(")[0] == "jit_" + name]
+    if not hits:
+        return None
+    return {"seconds": sum(h["seconds"] for h in hits),
+            "runs": sum(h["runs"] for h in hits)}

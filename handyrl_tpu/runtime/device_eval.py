@@ -30,6 +30,9 @@ from ..utils import tree_map
 
 ILLEGAL = 1e32
 
+# XLA module name of the eval program (``jit_device_eval`` in a profile)
+EVAL_PROGRAM = "device_eval"
+
 
 def build_eval_stream_fn(venv, module, n_lanes: int, k_steps: int,
                          opponent: str = "rulebase", mesh=None):
@@ -101,6 +104,7 @@ def build_eval_stream_fn(venv, module, n_lanes: int, k_steps: int,
         )
         return state, hidden, records
 
+    fn.__name__ = EVAL_PROGRAM
     if mesh is None:
         return jax.jit(fn, donate_argnums=(1, 2))
     from jax.sharding import NamedSharding, PartitionSpec

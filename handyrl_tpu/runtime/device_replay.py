@@ -82,8 +82,15 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..utils import tree_map
+from ..utils.trace import trace_span
 
 ILLEGAL = 1e32
+
+# XLA module names of the replay's programs (``jit_<name>`` in a device
+# profile; the benchmark's per-layer readers import these)
+INGEST_PROGRAM = "ingest"
+TRAIN_PROGRAM = "replay_train"
+SAMPLE_PROGRAM = "replay_sample"
 
 # record fields consumed positionally by the ring (everything else the
 # streaming fn emits is an env compact-obs field, stored as-is)
@@ -184,7 +191,7 @@ class DeviceReplay:
         self._sample_fns: Dict[int, Any] = {}
         self._sample_debug = None
         self.counters = {
-            "episodes": 0, "game_steps": 0, "player_steps": 0,
+            "ingests": 0, "episodes": 0, "game_steps": 0, "player_steps": 0,
             "outcome_sum": 0.0, "outcome_sq_sum": 0.0,
         }
         # deferred-stats FIFO (ingest_counted(defer=True)): device scalar
@@ -289,6 +296,7 @@ class DeviceReplay:
             "episodes": rep, "game_steps": rep, "player_steps": rep,
             "outcome_sum": rep, "outcome_sq_sum": rep,
         }
+        ingest.__name__ = INGEST_PROGRAM
         return jax.jit(
             ingest,
             donate_argnums=(0,),
@@ -338,8 +346,10 @@ class DeviceReplay:
     def _account(self, dev_stats) -> Dict[str, Any]:
         """Host-fetch one ingest's stats and fold them into the cumulative
         counters (blocks until that ingest has executed)."""
-        # graftlint: allow[HS001] reason=THE deferred-fetch point: callers defer this one dispatch behind the next enqueue (ingest_counted defer=True), so it overlaps execution instead of serializing the rollout thread
-        stats = tree_map(np.asarray, jax.device_get(dev_stats))
+        with trace_span("replay.stats_fetch"):
+            # graftlint: allow[HS001] reason=THE deferred-fetch point: callers defer this one dispatch behind the next enqueue (ingest_counted defer=True), so it overlaps execution instead of serializing the rollout thread
+            stats = tree_map(np.asarray, jax.device_get(dev_stats))
+        self.counters["ingests"] += 1
         self.counters["episodes"] += int(stats["episodes"])
         self.counters["game_steps"] += int(stats["game_steps"])
         self.counters["player_steps"] += int(stats["player_steps"])
@@ -453,6 +463,7 @@ class DeviceReplay:
             def fn(rings, key):
                 return self._sample(rings, key, batch_size)
 
+            fn.__name__ = SAMPLE_PROGRAM
             holder = {}
 
             def bound(key):
@@ -504,6 +515,7 @@ class DeviceReplay:
             )
             return state, jax.tree.map(lambda m: m.sum(axis=0), metrics)
 
+        fn.__name__ = TRAIN_PROGRAM
         # state shardings are bound at first call (shapes unknown here)
         holder = {}
 

@@ -32,6 +32,12 @@ from .replay import compress_block
 
 ILLEGAL = 1e32
 
+# XLA module names of this file's two programs: ``jax.jit`` names a module
+# ``jit_<fn.__name__>``, and that name is what a device profile shows and
+# what the benchmark's per-layer readers look up
+STREAM_PROGRAM = "device_rollout"
+EPISODE_PROGRAM = "device_rollout_episodes"
+
 
 def build_selfplay_fn(venv, module, n_games: int):
     """Compile-once device self-play for a VectorTicTacToe-style env.
@@ -81,6 +87,7 @@ def build_selfplay_fn(venv, module, n_games: int):
         stacked["outcome"] = venv.outcome(state)
         return stacked
 
+    fn.__name__ = EPISODE_PROGRAM
     return jax.jit(fn)
 
 
@@ -282,6 +289,7 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
         )
         return state, hidden, records
 
+    fn.__name__ = STREAM_PROGRAM
     if mesh is None:
         return jax.jit(fn, donate_argnums=(1, 2))
     from jax.sharding import NamedSharding, PartitionSpec

@@ -744,6 +744,11 @@ class Learner:
             record["device_mean_episode_len"] = self._device_epoch_steps / self._device_epoch_eps
             self._device_epoch_eps = 0
             self._device_epoch_steps = 0
+        if self._replay is not None:
+            # cumulative host ints the rollout thread already keeps: game
+            # steps the rings have booked and the ingests that booked them
+            record["device_game_steps"] = self._replay.counters["game_steps"]
+            record["device_rollout_dispatches"] = self._replay.counters["ingests"]
         substituted = getattr(self.model_server, "substituted_snapshots", 0)
         if substituted:
             # cumulative: N old-snapshot requests were served LATEST params
@@ -1633,7 +1638,8 @@ class Learner:
         try:
             while self._rollout_live(gen):
                 if self.num_returned_episodes >= self._next_update_episodes:
-                    time.sleep(0.02)   # epoch episode budget met: yield the chip
+                    with trace_span("rollout.budget_wait"):
+                        time.sleep(0.02)   # epoch episode budget met: yield the chip
                     self._rollout_beat()  # backpressure idle is healthy
                     if split:
                         plane_stats.bump(actor_idle_s=0.02)
@@ -1643,10 +1649,11 @@ class Learner:
                 epoch, params = self._actor_params()
                 t_busy = time.perf_counter()
                 key, sub = jax.random.split(key)
-                vstate, hidden, records = dispatch_serialized(
-                    lambda: self._stream_fn(params, vstate, hidden, sub),
-                    roll_mesh,
-                )
+                with trace_span("rollout.dispatch", epoch=epoch):
+                    vstate, hidden, records = dispatch_serialized(
+                        lambda: self._stream_fn(params, vstate, hidden, sub),
+                        roll_mesh,
+                    )
                 if split:
                     records = record_xfer(records)
                 # deferred stats (the direct-ingest hot path): the records
@@ -1659,7 +1666,8 @@ class Learner:
                 # generation-stats attribution stays exact; the tail is
                 # flushed in the finally below.
                 epoch_fifo.append(epoch)
-                stats = self._replay.ingest_counted(records, defer=True)
+                with trace_span("rollout.ingest", epoch=epoch):
+                    stats = self._replay.ingest_counted(records, defer=True)
                 dispatches += 1
                 self._rollout_dispatched = True  # arms stall detection
                 self._rollout_beat()
@@ -1725,17 +1733,21 @@ class Learner:
         loop as _device_rollout_inner (the server can be busy for minutes
         at an epoch boundary).  False = stop the rollout loop."""
         fut: Future = Future()
-        self._requests.put(("device_counts", counts, fut))
-        while not fut.done():
-            try:
-                fut.result(timeout=5.0)
-                self._rollout_beat()  # served: the wait was the server's
-            except (TimeoutError, FutureTimeoutError):
-                self._rollout_beat()  # waiting on a busy server ≠ a stall
-                if not self._rollout_live(gen):
+        # the server loop serves no request while it runs an epoch boundary
+        # (update(): snapshot wait, checkpoint, eval), so the first submit
+        # after the one that closed the epoch stands here until it is over
+        with trace_span("rollout.submit"):
+            self._requests.put(("device_counts", counts, fut))
+            while not fut.done():
+                try:
+                    fut.result(timeout=5.0)
+                    self._rollout_beat()  # served: the wait was the server's
+                except (TimeoutError, FutureTimeoutError):
+                    self._rollout_beat()  # waiting on a busy server ≠ a stall
+                    if not self._rollout_live(gen):
+                        return False
+                except Exception:
                     return False
-            except Exception:
-                return False
         return True
 
     def _device_rollout_inner(self, roll, key, gen: int) -> None:
@@ -1801,7 +1813,9 @@ class Learner:
                 self._collective_watchdog.start()
             if self._plane_gateway is not None:
                 self._plane_gateway.start()
-            self._trainer_thread = threading.Thread(target=self.trainer.run, daemon=True)
+            self._trainer_thread = threading.Thread(
+                target=self.trainer.run, daemon=True, name="trainer"
+            )
             self._trainer_thread.start()
             self.worker.run()
             self._active_workers = len(getattr(self.worker, "threads", [])) or self.args["worker"]["num_parallel"]

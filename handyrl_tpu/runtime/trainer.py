@@ -848,8 +848,9 @@ class Trainer:
                         self.state, sub, self._step_lr(lr, fused)
                     )
                 if metric_accum:
-                    # graftlint: allow[HS001] reason=deliberate one-deep pipelining: block on update N-1 so the dispatch queue stays shallow and the concurrent rollout thread gets device time
-                    jax.block_until_ready(metric_accum[-1]["total"])
+                    with trace_span("train.pipeline_block", plane="learner"):
+                        # graftlint: allow[HS001] reason=deliberate one-deep pipelining: block on update N-1 so the dispatch queue stays shallow and the concurrent rollout thread gets device time
+                        jax.block_until_ready(metric_accum[-1]["total"])
                 metric_accum.append(metrics)
                 batch_cnt += fused
                 self.steps += fused

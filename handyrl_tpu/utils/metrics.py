@@ -33,6 +33,10 @@ METRIC_KEYS = frozenset({
     # trainer loop
     "loss", "train_steps_per_sec", "input_wait_frac", "input_wait_warmup_s",
     "mfu", "device_mean_episode_len",
+    # device-replay runs, cumulative: game steps the rings have booked and
+    # the rollout dispatches (ingests) that booked them, from
+    # DeviceReplay.counters (host ints, one deferred ingest behind)
+    "device_game_steps", "device_rollout_dispatches",
     # live pipeline / plane topology
     "pipeline", "plane",
     # serving plane (handyrl_tpu/serving): the learner writes only

@@ -156,6 +156,8 @@ class Tracer:
         derives ``trace.jsonl`` -> ``trace.rankN.jsonl``.
         """
         self.shutdown()  # re-configuration replaces the previous plane
+        self.spans = 0
+        self.dropped = 0
         cfg = dict(cfg or {})
         if not cfg.get("enabled"):
             return False
@@ -177,8 +179,6 @@ class Tracer:
         self.rank = rank
         self.ring_size = max(1, int(cfg.get("ring_size", 4096)))
         self.flush_interval = max(0.01, float(cfg.get("flush_interval", 0.5)))
-        self.spans = 0
-        self.dropped = 0
         self._annotation = None
         if cfg.get("annotate_device", True):
             try:

@@ -445,6 +445,9 @@ def test_ingest_counted_deferred_matches_sync(rollout_data):
     assert tail is not None
     returned_eps += int(tail["episodes"])
     assert deferred.counters == sync.counters
+    # one accounted ingest per dispatch under both modes (the count the
+    # epoch record writes out as device_rollout_dispatches)
+    assert sync.counters["ingests"] == deferred.counters["ingests"] == len(chunks)
     # every episode was also RETURNED to the caller exactly once
     assert returned_eps == sync.counters["episodes"]
 
