@@ -38,7 +38,9 @@ Sampling parity with the host path (replay.py:110-140 + batch.py):
 window starts are uniform over the legal ``train_start`` range
 ``[0, max(0, steps - forward_steps)]`` of every finished episode still
 fully resident; one target player uniform per window
-(``turn_based_training: false`` semantics, batch.py:62-67); padding past
+(``turn_based_training: false`` semantics, batch.py:62-67); the starts
+are drawn by inverse CDF over the eligibility mask (``_draw_starts``: one
+pass over the mask per update, not one per sample); padding past
 the episode end reproduces make_batch exactly (prob 1, action-mask all
 illegal, value frozen at the outcome, progress 1, episode_mask 0) —
 pinned key-by-key against make_batch by tests/test_device_replay.py.
@@ -592,6 +594,30 @@ _RECORD_FIELDS = ("active", "observing", "legal", "action", "prob", "value",
                   "outcome", "reward", "ret")
 
 
+def _draw_starts(ok, key, batch_size: int):
+    """``batch_size`` (lane, slot) pairs uniform over the True entries of the
+    (B, S) mask ``ok``, with replacement, by inverse CDF: one integer
+    ``r < ok.sum()`` per draw, the lane found in the per-lane counts'
+    running sum, the slot in the running sum of that lane's mask row.  One
+    pass over the mask and ``batch_size`` rows of prefix sums, whatever the
+    ring holds (lane-sharded rings gather B counts, not a cross-chip scan).
+    An all-False mask gives the in-range (0, 0); callers keep the trainer
+    away until ``eligible_count`` says otherwise."""
+    counts = ok.sum(axis=1, dtype=jnp.int32)               # (B,)
+    ends = jnp.cumsum(counts)                              # (B,) inclusive
+    total = ends[-1]
+    # integers, not floor(uniform * total): float32 is inexact past 2^24
+    r = jax.random.randint(key, (batch_size,), 0, jnp.maximum(total, 1))
+    # "entries of a running sum <= r" is searchsorted(side="right"): it
+    # skips empty lanes, and the first slot whose prefix count exceeds r
+    lane = (ends[None, :] <= r[:, None]).sum(axis=1, dtype=jnp.int32)
+    lane = jnp.where(total > 0, lane, 0)
+    r_in_lane = r - (ends[lane] - counts[lane])
+    prefix = jnp.cumsum(ok[lane], axis=1, dtype=jnp.int32)  # (N, S)
+    slot = (prefix <= r_in_lane[:, None]).sum(axis=1, dtype=jnp.int32)
+    return lane, jnp.where(total > 0, slot, 0)
+
+
 def _draw_windows(rings, key, batch_size: int, forward_steps: int,
                   burn_in: int) -> Dict[str, Any]:
     """Shared window geometry for both sampling modes: draw eligible
@@ -603,10 +629,7 @@ def _draw_windows(rings, key, batch_size: int, forward_steps: int,
     T = burn_in + forward_steps
 
     ok = _eligibility(rings, forward_steps, burn_in)
-    logits = jnp.where(ok.reshape(-1), 0.0, -jnp.inf)
-    flat = jax.random.categorical(key, logits, shape=(batch_size,))
-    lane = (flat // S).astype(jnp.int32)                   # (N,)
-    slot = (flat % S).astype(jnp.int32)                    # train_start slot
+    lane, slot = _draw_starts(ok, key, batch_size)         # (N,) train_start
 
     gs0 = _slot_gsteps(rings["g"], S)[slot]                # (N,) train_start g
     ep_start = rings["ep_start_g"][lane, slot]

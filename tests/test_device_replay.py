@@ -288,6 +288,96 @@ def test_eligibility_and_wrap(rollout_data):
             assert g1 > G - 1 - S
 
 
+def _ring_mask(fixture):
+    def build(request):
+        data = request.getfixturevalue(fixture)
+        from handyrl_tpu.runtime.device_replay import _eligibility
+
+        args = data["args"]
+        return np.asarray(_eligibility(
+            data["replay"].rings, args["forward_steps"], args["burn_in_steps"]
+        ))
+
+    return build
+
+
+def _hand_mask(*eligible):
+    def build(request):
+        ok = np.zeros((5, 7), bool)
+        for lane, slot in eligible:
+            ok[lane, slot] = True
+        return ok
+
+    return build
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_ring_mask("rollout_data"), id="ff_wrapped_ring"),
+    pytest.param(_ring_mask("geister_rollout_data"), id="turn_burn_in"),
+    pytest.param(_hand_mask((0, 0)), id="only_first_slot"),
+    pytest.param(_hand_mask((-1, -1)), id="only_last_slot"),
+    pytest.param(_hand_mask((3, 0)), id="first_slot_after_empty_lanes"),
+    pytest.param(_hand_mask(), id="nothing_eligible"),
+])
+def test_draw_starts_uniform_over_the_mask(build, request):
+    """The inverse-CDF draw: every drawn (lane, slot) is eligible, every
+    eligible slot is drawn, and the hit counts pass a chi-square test
+    against uniform (fixed key, so deterministic: the statistic must stay
+    under its mean + 5 standard deviations, dof + 5 * sqrt(2 * dof)).  A
+    mask with one eligible slot pins the search's side and the empty-lane
+    case (statistic 0 with 0 degrees of freedom); an empty mask gives the
+    in-range (0, 0)."""
+    import jax.numpy as jnp
+
+    from handyrl_tpu.runtime.device_replay import _draw_starts
+
+    ok = build(request)
+    n = 60_000 if ok.sum() > 1 else 256
+    lane, slot = jax.jit(_draw_starts, static_argnums=2)(
+        jnp.asarray(ok), jax.random.PRNGKey(0), n
+    )
+    lane, slot = np.asarray(lane), np.asarray(slot)
+    assert lane.shape == slot.shape == (n,)
+    assert lane.dtype == slot.dtype == np.int32
+    if not ok.any():
+        assert not lane.any() and not slot.any()
+        return
+    assert ((0 <= lane) & (lane < ok.shape[0])).all()
+    assert ((0 <= slot) & (slot < ok.shape[1])).all()
+    assert ok[lane, slot].all(), "drew an ineligible window start"
+    hits = np.zeros(ok.shape, np.int64)
+    np.add.at(hits, (lane, slot), 1)
+    assert (hits[ok] > 0).all(), "an eligible window start was never drawn"
+    dof = int(ok.sum()) - 1
+    expected = n / ok.sum()
+    chi2 = float(((hits[ok] - expected) ** 2 / expected).sum())
+    assert chi2 <= dof + 5 * np.sqrt(2 * dof), (chi2, dof)
+
+
+def test_draw_independent_of_ring_sharding(rollout_data):
+    """Lane-sharded rings (dp=4 on the forced 8-device CPU mesh) draw the
+    same windows as the same rings on one device, and assemble the same
+    batch from them."""
+    from handyrl_tpu.runtime.device_replay import _lane_sharding
+
+    one = rollout_data["replay"]
+    mesh = make_mesh({"dp": 4})
+    sharded = DeviceReplay(VectorHungryGeese, rollout_data["module"],
+                           rollout_data["args"], mesh, N_LANES, slots=SLOTS)
+    sharded.rings = jax.device_put(one.rings, _lane_sharding(mesh, one.rings))
+    assert len(sharded.rings["valid"].sharding.device_set) == 4
+
+    key = jax.random.PRNGKey(9)
+    batch_1, info_1 = one.sample(key, 64, with_info=True)
+    batch_4, info_4 = sharded.sample(key, 64, with_info=True)
+    for k in ("lane", "slot", "player"):
+        np.testing.assert_array_equal(info_4[k], info_1[k], err_msg=k)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6),
+        batch_4, batch_1,
+    )
+
+
 def test_train_fn_runs_and_updates(rollout_data):
     """Fused sample+SGD from the rings: finite loss, params actually move,
     metrics summed over fused steps (dcnt ~ fused * batch turn sum)."""
