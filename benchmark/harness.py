@@ -30,6 +30,14 @@ FALLBACK_COUNTERS = (
     "pipe_batcher_fallback", "plane_watchdog_stalls", "plane_watchdog_degraded",
     "serve_snapshot_substituted",
 )
+# the one limit on a run's life, counted from when its ``Run`` is made:
+# run.py arms faulthandler with it, which ends a hung run inside the driver's
+# 360 s with every thread's stack on stderr.  Every wait of the harness is
+# derived from what is left of it (``Run.seconds_left``).
+RUN_LIMIT_S = 330.0
+# what a run still has to do once its profile is in hand or given up: the
+# reference check, the readers, the two result lines
+PROFILE_RESERVE_S = 20.0
 
 
 def load_json(path: str) -> Dict[str, Any]:
@@ -84,6 +92,7 @@ class Run:
         self.trace = bool(trace)
         self.rehearse = bool(rehearse)
         self.t_process = t_process
+        self.deadline = time.monotonic() + RUN_LIMIT_S
         spec_path = os.path.join(root, "BENCHMARK.json")
         if not os.path.exists(spec_path):
             spec_path = os.path.join(os.path.dirname(root), "BENCHMARK.json")
@@ -150,6 +159,9 @@ class Run:
             if "workloads" not in m or self.workload in m["workloads"]
         ]
 
+    def seconds_left(self) -> float:
+        return self.deadline - time.monotonic()
+
     # -- what runners call -------------------------------------------------
 
     def open_window(self) -> None:
@@ -174,21 +186,29 @@ class Run:
         )
 
     def program(self, role: str) -> Optional[Dict[str, float]]:
-        """Device seconds and executions of the XLA program the cell's file
-        names for ``role``; None where the trace cannot tell it from
-        another role's program (same module name) or holds none."""
-        if self.reduced is None:
-            return None
+        """``program_named`` the XLA module the cell's file names for
+        ``role``; None where the trace cannot tell it from another role's
+        program (same module name)."""
         names = self.cell.get("programs", {})
         module = names.get(role)
         if module is None or sum(1 for v in names.values() if v == module) > 1:
+            return None
+        return self.program_named(module)
+
+    def program_named(self, module: str) -> Optional[Dict[str, float]]:
+        """Device seconds and executions inside the traced window of the
+        XLA program ``module`` (``jit_<fn>``, any fingerprint); None where
+        the trace holds none.  ``seconds`` and ``runs`` hold a run the
+        window's edge cuts by its part inside (for a share of the window),
+        ``whole_seconds`` and ``whole_runs`` only the runs wholly inside
+        (for a time per run)."""
+        if self.reduced is None:
             return None
         hits = [v for k, v in self.reduced["programs"].items()
                 if k.split("(")[0] == module]
         if not hits:
             return None
-        return {"seconds": sum(h["seconds"] for h in hits),
-                "runs": sum(h["runs"] for h in hits)}
+        return {key: sum(h[key] for h in hits) for key in hits[0]}
 
 
 # ---------------------------------------------------------------------------
@@ -234,10 +254,32 @@ def stop_profile(run: Run) -> None:
     run.notes["profile_bytes"] = len(xspace)
 
 
+def join_profiler(run: Run, thread) -> None:
+    """Wait for the thread that closes the traced window (a runner whose
+    program has a loop of its own stops the profiler from a watcher thread)
+    for as long as the run may live: ``ProfilerSession.stop()`` takes half a
+    second a MB of profile, and a faster program writes more MB a second.
+    A thread that still has not returned is said so, by name: ``run.xplane``
+    stays unset, run.py's ``profile_collected`` check fails, and the note
+    says how long the wait was and what had come back."""
+    t0 = time.monotonic()
+    thread.join(timeout=max(0.0, run.seconds_left() - PROFILE_RESERVE_S))
+    if run.trace and thread.is_alive():
+        run.notes["profile_not_collected"] = {
+            "waited_s": time.monotonic() - t0,
+            "seconds_left": run.seconds_left(),
+            "stop_began": "profile_window_s" in run.notes,
+            # stop() hands the planes over in one piece: none so far
+            "profile_bytes": run.notes.get("profile_bytes", 0),
+        }
+
+
 def reduce_profile(run: Run) -> None:
     """The traced window is what lies between the two marker spans."""
     from benchmark import trace_reduce
 
+    with open(run.xplane, "rb") as f:
+        run.notes["profile_planes"] = trace_reduce.plane_sizes(f.read())
     trace = trace_reduce.load_xplane(run.xplane)
     begin = [s for s in trace["host"] if s[0] == WINDOW_BEGIN]
     end = [s for s in trace["host"] if s[0] == WINDOW_END]

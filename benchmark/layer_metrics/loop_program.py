@@ -9,15 +9,9 @@ that has no such constant yet (an older commit) answers ``None``."""
 
 
 def find(run, owner, constant):
-    """Device seconds and executions, inside the traced window, of the XLA
-    program that the program's module ``owner`` names in ``constant``; None
-    where the trace, the constant or the program is missing."""
+    """``run.program_named`` the XLA program that the program's module
+    ``owner`` names in ``constant``: its device seconds and executions
+    inside the traced window, clipped and whole; None where the trace, the
+    constant or the program is missing."""
     name = getattr(owner, constant, None)
-    if run.reduced is None or name is None:
-        return None
-    hits = [v for k, v in run.reduced["programs"].items()
-            if k.split("(")[0] == "jit_" + name]
-    if not hits:
-        return None
-    return {"seconds": sum(h["seconds"] for h in hits),
-            "runs": sum(h["runs"] for h in hits)}
+    return None if name is None else run.program_named("jit_" + name)

@@ -59,6 +59,9 @@ def main(argv=None) -> int:
 
     run = harness.Run(os.path.abspath(opts.root), opts.workload, opts.seed, opts.seconds,
                       bool(opts.trace), opts.rehearse, T_PROCESS)
+    # a hung run must end as a failure inside the driver's limit, with
+    # every thread's stack on stderr
+    faulthandler.dump_traceback_later(run.seconds_left(), exit=True, file=sys.__stderr__)
     devices = jax.devices()
     if not opts.rehearse and (devices[0].platform != "tpu" or len(devices) < run.chips):
         print(f"benchmark: {run.workload} needs {run.chips} TPU chip(s); jax found "
@@ -79,9 +82,6 @@ def main(argv=None) -> int:
           f"{devices[0].device_kind}; compile cache {cache_dir}; out {run.out_dir}",
           file=sys.stderr, flush=True)
 
-    # a hung run must end as a failure inside the driver's limit, with
-    # every thread's stack on stderr
-    faulthandler.dump_traceback_later(330, exit=True, file=sys.__stderr__)
     log_path = os.path.join(run.out_dir, "log.txt")
     stdout, stderr, cwd = sys.stdout, sys.stderr, os.getcwd()
     with open(log_path, "w") as sink:
@@ -97,11 +97,20 @@ def main(argv=None) -> int:
     if markers:
         run.notes["fallback_markers"] = markers
 
-    if run.trace and run.xplane:
+    if run.trace:
+        run.checks["profile_collected"] = run.xplane is not None
+    if run.xplane:
+        t0 = time.monotonic()
         try:
             harness.reduce_profile(run)
         except ValueError as exc:       # a CPU rehearsal has no device plane
             run.notes["trace_not_reduced"] = str(exc)
+        # what sizes the next failure: stop() and the reducer both go with
+        # the bytes, and the bytes with the updates the window held
+        updates = run.counters.get("updates")
+        run.notes.update(
+            reduce_s=time.monotonic() - t0, updates_in_trace=updates,
+            bytes_per_update=run.notes["profile_bytes"] / updates if updates else None)
     faulthandler.cancel_dump_traceback_later()
 
     on_chip = run.devices[0].platform == "tpu" and not opts.rehearse
@@ -132,6 +141,7 @@ def main(argv=None) -> int:
         from benchmark import trace_reduce
 
         result["breakdown"] = trace_reduce.breakdown(run.reduced)
+    run.notes["seconds_left"] = run.seconds_left()
     # an earlier line: what the last line has no key for
     print(json.dumps({
         "workload": run.workload, "seed": run.seed, "window_s": run.window_s,

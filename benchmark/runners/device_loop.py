@@ -16,8 +16,12 @@ chip alone.  Rates are counter differences between those two records over
 the difference of their ``t_mono``: the program's own per-epoch wall rates
 are not used.
 
-A traced run profiles from the opening record to the first record
-``trace_seconds`` later, then stops.
+A traced run profiles from the opening record to the first record that is
+``trace_seconds`` later or, where the cell's file gives ``trace_updates``,
+that many SGD updates later, whichever comes first, and at least
+``MIN_TRACE_EPOCHS`` records later: ``ProfilerSession.stop()`` and the
+reducer cost seconds for every MB, a profile grows with the updates it
+holds, and a faster program must not outgrow the run's limit.  Then it stops.
 
 Outside the window: the net the loop runs (same module, the jitted apply
 the rollout and the engines use) against the configuration's plain
@@ -37,6 +41,9 @@ import time
 from benchmark import harness, traffic
 
 POLL_S = 0.02
+# epoch boundaries the traced window holds at least: epoch_stall_share and
+# the idle gaps are read there
+MIN_TRACE_EPOCHS = 2
 
 
 def _read_new_records(path, offset):
@@ -76,6 +83,7 @@ def run(run: harness.Run) -> None:
 
     seconds = run.seconds
     trace_seconds = float(cell["trace_seconds"])
+    trace_updates = float(cell.get("trace_updates", math.inf))
     warm_records = int(cell["warm_records"])
     train = cfg["train_args"]
     ring_steps = int(train["device_rollout_games"]) * int(train["device_replay_slots"])
@@ -83,7 +91,7 @@ def run(run: harness.Run) -> None:
 
     def watch():
         try:
-            offset, opened, profiling = 0, None, False
+            offset, opened, opened_at, profiling = 0, None, 0, False
             flat = lambda a, b: (  # noqa: E731
                 a["hits"] + a["misses"] == b["hits"] + b["misses"]
                 and b["compile_s"] - a["compile_s"] < 0.05)
@@ -102,12 +110,14 @@ def run(run: harness.Run) -> None:
                     if (opened is None and len(records) >= warm_records
                             and record["_booked"] >= ring_steps
                             and flat(records[-2]["_compile"], record["_compile"])):
-                        opened = record
+                        opened, opened_at = record, len(records)
                         record["_opens_window"] = True
                         if run.trace:
                             harness.start_profile(run)
                             profiling = True
-                    elif (profiling and record["t_mono"] - opened["t_mono"] >= trace_seconds):
+                    elif (profiling and len(records) - opened_at >= MIN_TRACE_EPOCHS
+                          and (record["t_mono"] - opened["t_mono"] >= trace_seconds
+                               or record["steps"] - opened["steps"] >= trace_updates)):
                         record["_closes_trace"] = True
                         learner.shutdown_flag = True    # before the slow part
                         harness.stop_profile(run)
@@ -129,7 +139,7 @@ def run(run: harness.Run) -> None:
     watcher = threading.Thread(target=watch, name="bench-watcher", daemon=True)
     watcher.start()
     code = learner.run()
-    watcher.join(timeout=30.0)
+    harness.join_profiler(run, watcher)
     program_trace.shutdown()
     run.checks["learner_exit_0"] = code == 0 and not watcher_error
     if watcher_error:

@@ -21,32 +21,49 @@ import test_rehearsal as rehearsal  # noqa: E402
 from benchmark import harness  # noqa: E402
 from handyrl_tpu.runtime import device_eval, device_replay, device_rollout  # noqa: E402
 
-# a 2 s window: the fused-4 train program ran 5 times for 1.6 s, the
-# rollout twice for 0.06 s, the ingest twice for 0.04 s
+
+
+def _program(seconds, runs, whole_seconds=None, whole_runs=None):
+    """A program of the reduced trace; no run cut unless said."""
+    return {"seconds": seconds, "runs": runs,
+            "whole_seconds": seconds if whole_seconds is None else whole_seconds,
+            "whole_runs": runs if whole_runs is None else whole_runs}
+
+
+# a 2 s window: the fused-4 train program, 0.33 s a run, ran 5 times for
+# 1.6 s, the window opening 0.05 s into the first; the rollout twice for
+# 0.06 s, the window closing 0.01 s into the second (another fingerprint);
+# the ingest twice for 0.04 s
 REDUCED = {
     "window_s": 2.0,
     "programs": {
-        "jit_%s(111)" % device_replay.TRAIN_PROGRAM: {"seconds": 1.6, "runs": 5.0},
-        "jit_%s(222)" % device_rollout.STREAM_PROGRAM: {"seconds": 0.05, "runs": 1.0},
-        "jit_%s(333)" % device_rollout.STREAM_PROGRAM: {"seconds": 0.01, "runs": 1.0},
-        "jit_%s(444)" % device_replay.INGEST_PROGRAM: {"seconds": 0.04, "runs": 2.0},
-        "jit_%s(555)" % device_eval.EVAL_PROGRAM: {"seconds": 0.02, "runs": 4.0},
+        "jit_%s(111)" % device_replay.TRAIN_PROGRAM: _program(1.6, 5.0, 1.32, 4.0),
+        "jit_%s(222)" % device_rollout.STREAM_PROGRAM: _program(0.05, 1.0),
+        "jit_%s(333)" % device_rollout.STREAM_PROGRAM: _program(0.01, 1.0, 0.0, 0.0),
+        "jit_%s(444)" % device_replay.INGEST_PROGRAM: _program(0.04, 2.0),
+        "jit_%s(555)" % device_eval.EVAL_PROGRAM: _program(0.02, 4.0),
         # a whole-episode rollout is another program, not this one's
-        "jit_%s(666)" % device_rollout.EPISODE_PROGRAM: {"seconds": 0.5, "runs": 1.0},
+        "jit_%s(666)" % device_rollout.EPISODE_PROGRAM: _program(0.5, 1.0),
     },
 }
 BY_HAND = {
-    "rollout_device_share": 3.0,          # 0.06 / 2.0
-    "rollout_ms_per_dispatch": 30.0,      # 0.06 / 2
+    "rollout_device_share": 3.0,          # 0.06 / 2.0: a share keeps the cut run's part
+    "rollout_ms_per_dispatch": 50.0,      # 0.05 / 1: whole runs only
     "train_device_share": 80.0,           # 1.6 / 2.0
-    "replay_train_device_ms": 80.0,       # 1.6 / (5 x 4)
+    "replay_train_device_ms": 82.5,       # 1.32 / (4 x 4): whole runs only
+    "ingest_device_share": 2.0,           # 0.04 / 2.0
 }
 PROGRAM_OF = {
     "rollout_device_share": device_rollout.STREAM_PROGRAM,
     "rollout_ms_per_dispatch": device_rollout.STREAM_PROGRAM,
     "train_device_share": device_replay.TRAIN_PROGRAM,
     "replay_train_device_ms": device_replay.TRAIN_PROGRAM,
+    "ingest_device_share": device_replay.INGEST_PROGRAM,
 }
+PER_RUN = ("rollout_ms_per_dispatch", "replay_train_device_ms")
+READERS = sorted(
+    name[:-3] for name in os.listdir(os.path.join(BENCH, "layer_metrics"))
+    if name.endswith(".py") and name != "loop_program.py")
 
 
 def _span(name, t0, dur, thread="device-rollout-1"):
@@ -87,14 +104,13 @@ def _read(run, name):
     return harness.load_module(run.path("layer_metrics", name + ".py")).read(run)
 
 
-def test_the_cell_lists_the_five_and_each_has_a_reader(run):
+def test_the_cell_lists_the_six_and_each_has_a_reader(run):
     names = run.metric_names("per_layer")
-    for name in list(BY_HAND) + ["rollout_wait_share"]:
+    for name in list(BY_HAND) + ["rollout_wait_share", "epoch_stall_share"]:
         assert name in names
         assert os.path.exists(run.path("layer_metrics", name + ".py"))
-    # those that were there still answer from the cell's own map
-    assert "ingest_device_share" in names and "epoch_stall_share" in names
-    assert _read(run, "ingest_device_share") == pytest.approx(2.0)
+    # the loop's programs are found by the program's constants alone
+    assert "programs" not in run.cell
 
 
 @pytest.mark.parametrize("name", sorted(BY_HAND))
@@ -114,12 +130,38 @@ def test_program_reader_answers_none_without_its_program(run, name):
     assert _read(run, name) is None
 
 
+@pytest.mark.parametrize("name", PER_RUN)
+def test_time_per_run_answers_none_where_no_run_is_whole(run, name):
+    """A window shorter than one run of the program: its share of the
+    window stands, a time per run does not."""
+    for program in run.reduced["programs"].values():
+        program["whole_seconds"] = program["whole_runs"] = 0.0
+    assert _read(run, name) is None
+    assert _read(run, "train_device_share") == pytest.approx(80.0)
+
+
+def _without_constants(monkeypatch):
+    for owner in (device_rollout, device_replay, device_eval):
+        for constant in [c for c in vars(owner) if c.endswith("_PROGRAM")]:
+            monkeypatch.delattr(owner, constant)
+
+
 def test_program_reader_answers_none_for_a_program_without_the_constant(run, monkeypatch):
-    """The parent commit's program has no such constant: no raise."""
-    monkeypatch.delattr(device_rollout, "STREAM_PROGRAM")
-    monkeypatch.delattr(device_replay, "TRAIN_PROGRAM")
+    """An older commit's program (bb0b4ad) has no such constant: no raise."""
+    _without_constants(monkeypatch)
     for name in BY_HAND:
         assert _read(run, name) is None
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_every_reader_answers_none_with_nothing_to_read(run, monkeypatch, name):
+    """Both sides of a check run these files: on a program with no
+    constants, a run with no spans, no counters and no reduced trace, a
+    reader leaves its metric out and raises nothing."""
+    _without_constants(monkeypatch)
+    run.reduced, run.spans, run.counters, run.setup_compile = None, [], {}, None
+    assert _read(run, name) is None
+    assert run.notes == {}
 
 
 def test_rollout_wait_share_by_hand(run):

@@ -41,8 +41,7 @@ CELLS = {
             "device_replay_slots": 128, "fused_steps": 2, "device_eval_games": 8,
             "update_episodes": 40, "minimum_episodes": 450,     # fills the 16 x 128 ring
         },
-        "warm_records": 2, "trace_seconds": 2, "check_samples": 16,
-        "programs": {"rollout": "jit_fn", "train": "jit_fn", "ingest": "jit_ingest"},
+        "warm_records": 2, "trace_seconds": 2, "trace_updates": 8, "check_samples": 16,
         "stall_spans": ["epoch.snapshot_wait"],
     },
     "tiny_train": {

@@ -99,12 +99,74 @@ def test_synthetic_exposed_and_hidden_collective():
     assert reduced["collective_s"] == pytest.approx(2.0 + 10.0)
     # the sync one whole; of the async one what fusion.2 does not cover
     assert reduced["collective_exposed_s"] == pytest.approx(2.0 + (10.0 - 5.5))
-    assert reduced["programs"]["jit_step(1)"] == {"seconds": pytest.approx(19.0), "runs": 2}
+    # no run is cut by this window: the whole-run pair equals the clipped one
+    assert reduced["programs"]["jit_step(1)"] == {
+        "seconds": pytest.approx(19.0), "runs": 2,
+        "whole_seconds": pytest.approx(19.0), "whole_runs": 2}
     gaps = {name: v["seconds"] for name, v in reduced["idle_by_span"].items()}
     # innermost span at the gap's middle: the fetch for 12-20, the epoch
     # span for 26-29
     assert gaps == {"epoch.metrics_fetch": pytest.approx(8.0), "train_epoch": pytest.approx(3.0)}
     assert reduced["longest_gaps"][0][:2] == ("epoch.metrics_fetch", pytest.approx(8.0))
+
+
+def _back_to_back(n, length=10.0, gap=1.0):
+    """``n`` runs of one program, each ``length`` busy, ``gap`` apart."""
+    starts = [i * (length + gap) for i in range(n)]
+    return {"devices": {0: {
+        "modules": [("jit_replay_train(9)", s, s + length) for s in starts],
+        "ops": [("%fusion.1 = f32[8] fusion(f32[8] %p)", s, s + length) for s in starts],
+        "async_ops": [],
+    }}, "host": []}
+
+
+@pytest.mark.parametrize("window, clipped, whole", [
+    # the window opens 4 into the first run and closes 7 into the fifth
+    ((4.0, 51.0), (6.0 + 30.0 + 7.0, 5), (30.0, 3)),
+    # cuts only the last run
+    ((0.0, 36.0), (30.0 + 3.0, 4), (30.0, 3)),
+    # on the runs' own edges, and in the gaps around them: none cut
+    ((0.0, 54.0), (50.0, 5), (50.0, 5)),
+    ((10.5, 43.5), (30.0, 3), (30.0, 3)),
+    # shorter than one run: a share of the window, no time per run
+    ((13.0, 18.0), (5.0, 1), (0.0, 0)),
+])
+def test_runs_the_window_cuts_are_told_from_whole_ones(window, clipped, whole):
+    """A time per run divides whole runs only; a share of the window keeps
+    the clipped seconds (section 7 of PERF.md before PR 28: 20.77 ms read
+    for programs of 22.6 ms an update, a cut run counted as one)."""
+    program = tr.reduce_trace(_back_to_back(5), window)["programs"]["jit_replay_train(9)"]
+    assert (program["seconds"], program["runs"]) == (pytest.approx(clipped[0]), clipped[1])
+    assert (program["whole_seconds"], program["whole_runs"]) == (
+        pytest.approx(whole[0]), whole[1])
+    if whole[1]:
+        assert program["whole_seconds"] / program["whole_runs"] == pytest.approx(10.0)
+
+
+def test_ops_outside_any_program_have_no_whole_run():
+    trace = _back_to_back(2)
+    trace["devices"][0]["ops"].append(("%copy.1 = f32[8] copy(f32[8] %p)", 30.0, 31.0))
+    programs = tr.reduce_trace(trace, (0.0, 40.0))["programs"]
+    assert programs[tr.NO_PROGRAM] == {
+        "seconds": pytest.approx(1.0), "runs": 0, "whole_seconds": 0.0, "whole_runs": 0}
+
+
+def test_plane_sizes_of_a_kept_trace_add_up():
+    """The wire walk against what ProfileData parses from the same file."""
+    found = glob.glob(os.path.join(CAPTURES, "bf16", "plugins", "profile", "*", "*.xplane.pb"))
+    if not found:
+        pytest.skip("the kept bf16 trace is not in this checkout")
+    with open(found[0], "rb") as f:
+        raw = f.read()
+    sizes = tr.plane_sizes(raw)
+    trace = tr.load_xplane(found[0])
+    device = sizes["/device:TPU:0"]
+    assert device["lines"]["XLA Ops"]["events"] == len(trace["devices"][0]["ops"])
+    assert device["lines"]["Async XLA Ops"]["events"] == len(trace["devices"][0]["async_ops"])
+    assert device["lines"]["XLA Modules"]["events"] == len(trace["devices"][0]["modules"])
+    assert sum(v["bytes"] for v in device["lines"].values()) < device["bytes"]
+    # the planes are all of the file but a few bytes of framing a plane
+    assert 0 <= len(raw) - sum(p["bytes"] for p in sizes.values()) < 16 * len(sizes)
 
 
 def test_two_chips_are_averaged():

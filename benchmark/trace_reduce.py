@@ -23,7 +23,10 @@ Definitions (seconds, inside the window):
             where a trace has no op line); idle = window - busy.
 * program   busy time lying inside that program's ``XLA Modules`` events,
             so program times (with ``no_program`` for ops outside any
-            module) sum to busy.
+            module) sum to busy.  ``seconds`` and ``runs`` count a run the
+            window's edge cuts by the part inside, as one run: right for a
+            share of the window.  ``whole_seconds`` and ``whole_runs`` count
+            only runs that lie wholly inside: right for a time per run.
 * collective  union of the collective ops' intervals (sync ops on the op
             line, ``-start``..``-done`` on the async line); exposed = the
             part of it during which no other leaf op runs on that chip.
@@ -37,7 +40,7 @@ the op table come from the first chip.
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -55,6 +58,7 @@ COLLECTIVE = re.compile(
 SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)*$")
 NO_SPAN = "no_span"
 NO_PROGRAM = "no_program"
+PROGRAM_KEYS = ("seconds", "runs", "whole_seconds", "whole_runs")
 # gaps shorter than this are summed under one name instead of being
 # looked up one by one (a launch-bound loop has hundreds of thousands)
 SHORT_GAP_S = 20e-6
@@ -92,6 +96,65 @@ def load_xplane(path: str) -> Dict[str, Any]:
                         host.append((e.name, e.start_ns * 1e-9,
                                      (e.start_ns + e.duration_ns) * 1e-9, line.name))
     return {"devices": devices, "host": host}
+
+
+def _varint(buf: memoryview, pos: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[pos]
+        pos += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return value, pos
+
+
+def _fields(buf: memoryview) -> Iterator[Tuple[int, memoryview]]:
+    """(field number, payload) of each length-delimited field of one
+    protobuf message; scalar fields are stepped over."""
+    pos, end = 0, len(buf)
+    while pos < end:
+        key, pos = _varint(buf, pos)
+        kind = key & 7
+        if kind == 2:
+            size, pos = _varint(buf, pos)
+            yield key >> 3, buf[pos:pos + size]
+            pos += size
+        elif kind == 0:
+            _, pos = _varint(buf, pos)
+        else:
+            pos += 8 if kind == 1 else 4
+
+
+def plane_sizes(raw: bytes, lines_kept: int = 4) -> Dict[str, Dict[str, Any]]:
+    """What a serialized profile's bytes are made of: for each plane its
+    bytes and events, and its ``lines_kept`` largest lines.  Walks the
+    wire format (``XSpace.planes`` = 1; ``XPlane.name`` = 2, ``.lines`` =
+    3; ``XLine.name`` = 2, ``.events`` = 4) and parses no event, so it
+    costs a second where ``ProfileData`` costs ten: the size of a profile
+    is what ``ProfilerSession.stop()`` and the reducer take their time by."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for number, plane in _fields(memoryview(raw)):
+        if number != 1:
+            continue
+        name, lines = "", []
+        for field, payload in _fields(plane):
+            if field == 2:
+                name = bytes(payload).decode(errors="replace")
+            elif field == 3:
+                line_name, events = "", 0
+                for inner, value in _fields(payload):
+                    if inner == 2:
+                        line_name = bytes(value).decode(errors="replace")
+                    elif inner == 4:
+                        events += 1
+                lines.append((len(payload), events, line_name))
+        lines.sort(reverse=True)
+        out[name] = {
+            "bytes": len(plane), "events": sum(n for _, n, _ in lines),
+            "lines": {n: {"bytes": b, "events": e} for b, e, n in lines[:lines_kept]},
+        }
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -222,18 +285,21 @@ def _reduce_device(dev: Dict[str, List[Interval]], lo: float, hi: float) -> Dict
     busy_segments = clip(merge((s, e) for _, s, e in source), lo, hi)
     busy = measure(busy_segments)
 
-    programs: Dict[str, List[float]] = {}
+    programs: Dict[str, List[float]] = {}   # seconds, runs, whole seconds, whole runs
     if modules:
-        windows = clip(_as_array((s, e) for _, s, e in modules), lo, hi)
-        names = [n for n, s, e in modules if min(e, hi) > max(s, lo)]
+        touching = [(n, s, e) for n, s, e in modules if min(e, hi) > max(s, lo)]
+        windows = clip(_as_array((s, e) for _, s, e in touching), lo, hi)
         inside = measure_inside(busy_segments, windows)
-        for name, seconds in zip(names, inside):
-            slot = programs.setdefault(name, [0.0, 0])
+        for (name, s, e), seconds in zip(touching, inside):
+            slot = programs.setdefault(name, [0.0, 0, 0.0, 0])
             slot[0] += float(seconds)
             slot[1] += 1
+            if s >= lo and e <= hi:
+                slot[2] += float(seconds)
+                slot[3] += 1
     outside = busy - sum(v[0] for v in programs.values())
     if outside > 1e-9:
-        programs[NO_PROGRAM] = [outside, 0]
+        programs[NO_PROGRAM] = [outside, 0, 0.0, 0]
 
     op_table: Dict[str, List[float]] = {}
     collective = exposed = 0.0
@@ -298,10 +364,10 @@ def reduce_trace(trace: Dict[str, Any], window: Optional[Tuple[float, float]] = 
 
     programs: Dict[str, Dict[str, float]] = {}
     for dev in per_device.values():
-        for name, (seconds, runs) in dev["programs"].items():
-            slot = programs.setdefault(name, {"seconds": 0.0, "runs": 0.0})
-            slot["seconds"] += seconds / count
-            slot["runs"] += runs / count
+        for name, totals in dev["programs"].items():
+            slot = programs.setdefault(name, dict.fromkeys(PROGRAM_KEYS, 0.0))
+            for key, total in zip(PROGRAM_KEYS, totals):
+                slot[key] += total / count
 
     gaps = complement(first["busy_segments"], lo, hi)
     lengths = gaps[:, 1] - gaps[:, 0] if len(gaps) else np.zeros(0)
