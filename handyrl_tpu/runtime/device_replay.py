@@ -33,6 +33,18 @@ Ring invariants (what makes exact episode bookkeeping cheap):
   masked compare per step; outcome/length/progress all derive from the
   two id rings, no outcome broadcast needed (the final record's
   ``outcome`` field is gathered from the end slot at sample time).
+* Storage: a lane-step's record is ONE row of 32-bit words in ONE ring,
+  ``rings["rec"]`` int32 ``(lanes, slots, W)`` (``RowFormat``, derived
+  from the first record batch's leaves: each leaf flattened, ``bool`` as
+  a byte, narrow elements packed four or two a word, every leaf starting
+  on a word, ``W`` rounded up to a multiple of 128).  Such an array has
+  one natural device layout, and both users take it as it is: an ingest
+  writes its ``(lanes, K, W)`` block in place at ``g % S`` and a sampler
+  gathers ``batch x T`` rows, so a dispatch's work follows what it writes
+  and an update's what it reads, never lanes x slots.  The id rings
+  (``ep_start_g``, ``ep_end_g``, ``valid``) stay ``(lanes, slots)``:
+  they are read by whole-mask passes (eligibility once an update, the
+  finalizing compare once an ingested step), for which slots minor is right.
 
 Sampling parity with the host path (replay.py:110-140 + batch.py):
 window starts are uniform over the legal ``train_start`` range
@@ -75,8 +87,9 @@ Two window modes (checked at construction, dispatched by
 
 from __future__ import annotations
 
+import sys
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +121,128 @@ def _lane_sharding(mesh, tree):
         return NamedSharding(mesh, PartitionSpec())
 
     return tree_map(shard, tree)
+
+
+class RowField(NamedTuple):
+    """One record leaf's place in a row: ``words`` 32-bit words from
+    ``offset``, holding ``shape`` elements of ``dtype`` (a step's leaf,
+    the lane axis dropped)."""
+
+    shape: Tuple[int, ...]
+    dtype: np.dtype
+    offset: int
+    words: int
+
+    @property
+    def size(self) -> int:
+        return int(np.prod(self.shape, dtype=np.int64))
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes the leaf's elements take in the row (``bool``: one each)."""
+        return self.size * self.dtype.itemsize
+
+
+class RowFormat:
+    """The record ring's storage format: which words of a row hold which
+    leaf.  Built from one step's record spec (leaves ``(lanes, ...)``), the
+    control fields left out; fields lie in sorted-name order, each on a
+    word boundary, and ``width`` is their sum rounded up to 128 words."""
+
+    def __init__(self, rec_spec: Dict[str, Any]):
+        self.fields: Dict[str, RowField] = {}
+        offset = 0
+        for name in sorted(k for k in rec_spec if k not in _CONTROL):
+            leaf = rec_spec[name]
+            dtype = np.dtype(jax.dtypes.canonicalize_dtype(leaf.dtype))
+            if dtype.itemsize not in (1, 2, 4):
+                raise TypeError(
+                    f"record field {name!r}: {dtype} does not pack into "
+                    "32-bit words"
+                )
+            shape = tuple(leaf.shape[1:])
+            words = -(-int(np.prod(shape, dtype=np.int64)) * dtype.itemsize // 4)
+            self.fields[name] = RowField(shape, dtype, offset, words)
+            offset += words
+        self.used_bytes = sum(f.nbytes for f in self.fields.values())
+        self.used_words = offset
+        self.width = max(128, -(-offset // 128) * 128)
+
+    def pack(self, rec: Dict[str, Any]):
+        """Leaves ``lead + field.shape`` -> int32 ``lead + (width,)``."""
+        parts = []
+        for name, field in self.fields.items():
+            x = jnp.asarray(rec[name])
+            lead = x.shape[: x.ndim - len(field.shape)]
+            parts.append(_to_words(x.reshape(lead + (-1,))))
+        if self.used_words < self.width:
+            parts.append(
+                jnp.zeros(lead + (self.width - self.used_words,), jnp.int32))
+        return jnp.concatenate(parts, axis=-1)
+
+    def unpack(self, rows, names=None) -> Dict[str, Any]:
+        """int32 ``lead + (width,)`` -> the named leaves (all by default),
+        each ``lead + field.shape`` in its own dtype, bit for bit what
+        ``pack`` was given."""
+        lead = rows.shape[:-1]
+        out = {}
+        for name in self.fields if names is None else names:
+            f = self.fields[name]
+            words = rows[..., f.offset:f.offset + f.words]
+            out[name] = _from_words(words, f.dtype, f.size).reshape(lead + f.shape)
+        return out
+
+
+def _to_words(x):
+    """``(..., n)`` of a 1-, 2- or 4-byte dtype -> int32 ``(..., ceil(n *
+    itemsize / 4))``.  Narrow elements go PLANAR: with ``m`` words, element
+    ``j`` is bits ``[b * (j // m), b * (j // m + 1))`` of word ``j % m`` —
+    shifts of whole ``(..., m)`` slices and one concatenate to undo, no
+    array with a minor dimension of 4 (what a width-changing bitcast makes)."""
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.uint8)
+    bits = 8 * x.dtype.itemsize
+    if bits == 32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    per = 32 // bits
+    u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{bits}")).astype(jnp.uint32)
+    n = u.shape[-1]
+    m = -(-n // per)
+    u = jnp.pad(u, [(0, 0)] * (u.ndim - 1) + [(0, m * per - n)])
+    word = u[..., :m]
+    for c in range(1, per):
+        word = word | (u[..., c * m:(c + 1) * m] << (bits * c))
+    return jax.lax.bitcast_convert_type(word, jnp.int32)
+
+
+def _from_words(words, dtype: np.dtype, n: int):
+    """Inverse of ``_to_words``: the first ``n`` elements of ``dtype``."""
+    stored = np.dtype(np.uint8) if dtype == np.bool_ else dtype
+    bits = 8 * stored.itemsize
+    if bits == 32:
+        return jax.lax.bitcast_convert_type(words, stored)
+    u = jax.lax.bitcast_convert_type(words, jnp.uint32)
+    mask = jnp.uint32((1 << bits) - 1)
+    u = jnp.concatenate(
+        [(u >> (bits * c)) & mask for c in range(32 // bits)], axis=-1
+    )[..., :n].astype(jnp.dtype(f"uint{bits}"))
+    if dtype == np.bool_:
+        return u != 0
+    return jax.lax.bitcast_convert_type(u, stored)
+
+
+def _write_block(ring, rows, pos):
+    """Block ``rows`` (B, K, W) into slots ``(pos + j) % S`` of ``ring``
+    (B, S, W), in place on a donated ring: one ``dynamic_update_slice`` a
+    step, so a block that crosses the ring's end needs no second case and
+    nothing reads the ring.  (On the v5e a scatter over the slot axis, or a
+    read-modify-write of the block's slots, makes the compiler re-lay the
+    whole ring out and back; these writes keep its natural layout.)"""
+    S = ring.shape[1]
+    for j in range(rows.shape[1]):
+        ring = jax.lax.dynamic_update_slice(
+            ring, rows[:, j:j + 1], (0, (pos + j) % S, 0))
+    return ring
 
 
 class DeviceReplay:
@@ -187,6 +322,7 @@ class DeviceReplay:
         self.n_lanes = n_lanes
         self.slots = slots
         self.rings = None        # built lazily from the first record batch
+        self.row_format: Optional[RowFormat] = None   # fixed with them
         self._ingest = None
         self._pending = None     # last dispatched stats (drain target)
         self._train_fns: Dict[int, Any] = {}
@@ -204,79 +340,87 @@ class DeviceReplay:
     # -- ring construction --------------------------------------------------
 
     def _init_rings(self, rec_spec: Dict[str, Any]):
-        """Allocate rings matching one step's record layout (``rec_spec``
-        leaves are per-step (B, ...), the K axis already dropped)."""
+        """Allocate rings for one step's record layout (``rec_spec`` leaves
+        are per-step (B, ...), the K axis already dropped) and fix the
+        record ring's row format from it."""
         B, S = self.n_lanes, self.slots
+        fmt = self.row_format = RowFormat(rec_spec)
+        row_bytes = 4 * fmt.width
+        # an operator with a tiny record sees what the 512 B row floor costs
+        print(
+            f"[handyrl_tpu] device replay ring: {B} lanes x {S} slots x "
+            f"{fmt.width} words, {fmt.used_bytes} B of each {row_bytes} B row "
+            f"used ({100 * (1 - fmt.used_bytes / row_bytes):.1f}% padding), "
+            f"{B * S * row_bytes / 1e6:.1f} MB",
+            file=sys.stderr,
+        )
 
-        def ring(leaf):
-            return jnp.zeros((B, S) + leaf.shape[1:], leaf.dtype)
+        def empty():
+            return {
+                "rec": jnp.zeros((B, S, fmt.width), jnp.int32),
+                "ep_start_g": jnp.full((B, S), -1, jnp.int32),
+                "ep_end_g": jnp.full((B, S), -1, jnp.int32),
+                "valid": jnp.zeros((B, S), bool),
+                "cur_start_g": jnp.zeros((B,), jnp.int32),
+                "g": jnp.zeros((), jnp.int32),
+            }
 
-        rings = {
-            "rec": {
-                k: ring(v) for k, v in rec_spec.items() if k not in _CONTROL
-            },
-            "ep_start_g": jnp.full((B, S), -1, jnp.int32),
-            "ep_end_g": jnp.full((B, S), -1, jnp.int32),
-            "valid": jnp.zeros((B, S), bool),
-            "cur_start_g": jnp.zeros((B,), jnp.int32),
-            "g": jnp.zeros((), jnp.int32),
-        }
-        sharding = _lane_sharding(self.mesh, rings)
+        sharding = _lane_sharding(self.mesh, jax.eval_shape(empty))
         from ..parallel.mesh import dispatch_serialized
 
-        # the first-ingest layout put is a multi-device program dispatched
-        # from the rollout thread — lock it like every other dispatch (the
-        # trainer cannot be stepping yet with an empty ring, but a split
-        # plane's learner mesh may be busy with other programs)
-        put = jax.jit(lambda t: t, out_shardings=sharding)
-        return dispatch_serialized(lambda: put(rings), self.mesh), sharding
+        # the rings are born inside the program, in their layout: built
+        # outside and put, the record ring is on the device twice.  A
+        # multi-device program dispatched from the rollout thread — lock it
+        # like every other dispatch (the trainer cannot be stepping yet with
+        # an empty ring, but a split plane's learner mesh may be busy with
+        # other programs)
+        alloc = jax.jit(empty, out_shardings=sharding)
+        return dispatch_serialized(alloc, self.mesh)
 
     # -- ingest -------------------------------------------------------------
 
     def _build_ingest(self, rec_sharding):
-        B, S = self.n_lanes, self.slots
+        S, fmt = self.slots, self.row_format
 
-        def write_step(rings, rec_t):
-            g = rings["g"]
+        def write_ids(ids, done):
+            g = ids["g"]
             pos = g % S
-            # (1) write the record; invalidating ONLY the overwritten slot
-            # is exact: slots are overwritten oldest-first and windows read
-            # forward (younger slots), so a still-valid start slot can
-            # never reach an overwritten step — an episode losing its
-            # oldest slots just loses those window starts
-            rec = {
-                k: rings["rec"][k].at[:, pos].set(v)
-                for k, v in rec_t.items()
-                if k not in _CONTROL
-            }
-            ep_start_g = rings["ep_start_g"].at[:, pos].set(rings["cur_start_g"])
-            ep_end_g = rings["ep_end_g"].at[:, pos].set(-1)
-            valid = rings["valid"].at[:, pos].set(False)
-            # (2) finished lanes: finalize every slot of the current episode
-            done = rec_t["done"]                                     # (B,)
-            # episode ids (global start steps) are unique per lane forever,
+            # (1) the step's slot takes the lane's current episode id.
+            # Invalidating ONLY the overwritten slot is exact: slots are
+            # overwritten oldest-first and windows read forward (younger
+            # slots), so a still-valid start slot can never reach an
+            # overwritten step — an episode losing its oldest slots just
+            # loses those window starts
+            ep_start_g = ids["ep_start_g"].at[:, pos].set(ids["cur_start_g"])
+            ep_end_g = ids["ep_end_g"].at[:, pos].set(-1)
+            valid = ids["valid"].at[:, pos].set(False)
+            # (2) finished lanes: finalize every slot of the current episode.
+            # Episode ids (global start steps) are unique per lane forever,
             # so this compare can never hit a stale slot of another episode
-            mine = ep_start_g == rings["cur_start_g"][:, None]       # (B, S)
+            mine = ep_start_g == ids["cur_start_g"][:, None]         # (B, S)
             fin = done[:, None] & mine
-            ep_end_g = jnp.where(fin, g, ep_end_g)
-            valid = valid | fin
-            cur_start_g = jnp.where(done, g + 1, rings["cur_start_g"])
             return {
-                "rec": rec,
                 "ep_start_g": ep_start_g,
-                "ep_end_g": ep_end_g,
-                "valid": valid,
-                "cur_start_g": cur_start_g,
+                "ep_end_g": jnp.where(fin, g, ep_end_g),
+                "valid": valid | fin,
+                "cur_start_g": jnp.where(done, g + 1, ids["cur_start_g"]),
                 "g": g + 1,
-            }
+            }, None
 
         def ingest(rings, records):
-            def body(rings, rec_t):
-                return write_step(rings, rec_t), None
-
-            rings, _ = jax.lax.scan(body, rings, records)
-            # counters for host bookkeeping (epoch cadence, gen stats):
             done = records["done"]                                    # (K, B)
+            # the records: one packed row a lane-step, the block in place
+            rows = fmt.pack({
+                k: jnp.swapaxes(v, 0, 1)
+                for k, v in records.items() if k not in _CONTROL
+            })                                                        # (B, K, W)
+            rec = _write_block(rings["rec"], rows, rings["g"] % S)
+            # the books: the id rings and the head, a step at a time (the
+            # record ring is not in this loop's carry)
+            ids, _ = jax.lax.scan(
+                write_ids, {k: v for k, v in rings.items() if k != "rec"}, done)
+            rings = dict(ids, rec=rec)
+            # counters for host bookkeeping (epoch cadence, gen stats):
             active = records["active"]                                # (K, B, P)
             n_done = done.sum(dtype=jnp.int32)
             # mean self-play outcome over finished episodes, per player
@@ -320,7 +464,7 @@ class DeviceReplay:
         never contend with it."""
         if self.rings is None:
             spec = tree_map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), records)
-            self.rings, _ = self._init_rings(spec)
+            self.rings = self._init_rings(spec)
         if self._ingest is None:
             self._rec_sharding = tree_map(
                 lambda x: NamedSharding(self.mesh, PartitionSpec(None, "dp")), records
@@ -429,8 +573,8 @@ class DeviceReplay:
 
     def _sample(self, rings, key, batch_size: int):
         fn = _sample_batch_turn if self.mode == "turn" else _sample_batch
-        return fn(rings, key, batch_size, self.venv, self.args,
-                  self._sample_debug)
+        return fn(rings, self.row_format, key, batch_size, self.venv,
+                  self.args, self._sample_debug)
 
     def sample(self, key, batch_size: int, with_info: bool = False):
         """Eager one-off sampling (tests / inspection).  The production
@@ -540,18 +684,23 @@ class DeviceReplay:
                 self.mesh,
             )
 
-        def flops_per_update(state) -> float:
-            """Analytic FLOPs of ONE SGD update of this program (trace-only,
-            nothing executes): jaxpr_flops over the fused body / fused_steps.
-            Sampling/assembly are gathers, not FLOPs, so this equals the
-            plain train step's count — used for MFU in Trainer.stats."""
-            from ..parallel.train_step import jaxpr_flops
-
-            jaxpr = jax.make_jaxpr(fn)(
+        def jaxpr(state):
+            """This program's jaxpr on the current rings (trace-only,
+            nothing executes)."""
+            return jax.make_jaxpr(fn)(
                 state, self.rings, jax.random.PRNGKey(0), jnp.float32(1e-5)
             )
-            return jaxpr_flops(jaxpr.jaxpr) / fused_steps
 
+        def flops_per_update(state) -> float:
+            """Analytic FLOPs of ONE SGD update of this program: jaxpr_flops
+            over the fused body / fused_steps.  Sampling/assembly are
+            gathers, not FLOPs, so this equals the plain train step's count
+            — used for MFU in Trainer.stats."""
+            from ..parallel.train_step import jaxpr_flops
+
+            return jaxpr_flops(jaxpr(state).jaxpr) / fused_steps
+
+        bound.jaxpr = jaxpr
         bound.flops_per_update = flops_per_update
         self._train_fns[fused_steps] = bound
         return bound
@@ -618,13 +767,14 @@ def _draw_starts(ok, key, batch_size: int):
     return lane, jnp.where(total > 0, slot, 0)
 
 
-def _draw_windows(rings, key, batch_size: int, forward_steps: int,
-                  burn_in: int) -> Dict[str, Any]:
+def _draw_windows(rings, fmt: RowFormat, key, batch_size: int,
+                  forward_steps: int, burn_in: int) -> Dict[str, Any]:
     """Shared window geometry for both sampling modes: draw eligible
     train_starts uniformly, derive per-row in-episode indices / liveness
-    over the (burn_in + forward) window, and gather the per-step record
-    arrays.  Rows with ``i_t < 0`` are burn-in underflow (before the
-    episode start); rows with ``post`` are past the episode end."""
+    over the (burn_in + forward) window, and gather the windows' record
+    rows — ONE gather of N x T rows, unpacked to the per-step arrays.  Rows
+    with ``i_t < 0`` are burn-in underflow (before the episode start); rows
+    with ``post`` are past the episode end."""
     S = rings["valid"].shape[1]
     T = burn_in + forward_steps
 
@@ -642,35 +792,33 @@ def _draw_windows(rings, key, batch_size: int, forward_steps: int,
     live_b = (i_t >= 0) & (gstep <= ep_end[:, None])       # (N, T)
     wslots = (slot[:, None] - burn_in + j[None, :]) % S    # (N, T)
 
-    def gather(x):                                         # (B, S, ...) -> (N, T, ...)
-        return x[lane[:, None], wslots]
-
-    rec = rings["rec"]
+    rec = fmt.unpack(rings["rec"][lane[:, None], wslots])  # leaves (N, T, ...)
     # final outcome lives in the episode's END slot record (younger than
     # train_start, so resident whenever train_start's valid flag survives)
     end_slot = (slot + (ep_end - gs0)) % S
+    outcome = fmt.unpack(rings["rec"][lane, end_slot], ("outcome",))["outcome"]
     out = {
         "lane": lane, "slot": slot, "i_t": i_t, "gstep": gstep,
         "ep_end": ep_end,
         "ep_len": (ep_end - ep_start + 1).astype(jnp.float32),
         "live_b": live_b, "live": live_b.astype(jnp.float32),
         "post": gstep > ep_end[:, None],
-        "active": gather(rec["active"]).astype(jnp.float32),
-        "observing": gather(rec["observing"]).astype(jnp.float32),
-        "prob": gather(rec["prob"]),
-        "value": gather(rec["value"]),
-        "action": gather(rec["action"]),
-        "legal": gather(rec["legal"]),
-        "outcome": rec["outcome"][lane, end_slot],         # (N, P)
+        "active": rec["active"].astype(jnp.float32),
+        "observing": rec["observing"].astype(jnp.float32),
+        "prob": rec["prob"],
+        "value": rec["value"],
+        "action": rec["action"],
+        "legal": rec["legal"],
+        "outcome": outcome,                                # (N, P)
         "compact": {
-            k: gather(v) for k, v in rec.items() if k not in _RECORD_FIELDS
+            k: v for k, v in rec.items() if k not in _RECORD_FIELDS
         },
     }
     # explicit per-step reward/return columns (host-born episodes); the
     # streaming path derives them in closed form instead (_step_returns)
     for k in ("reward", "ret"):
         if k in rec:
-            out[k] = gather(rec[k])
+            out[k] = rec[k]
     return out
 
 
@@ -689,14 +837,15 @@ def _step_returns(venv, gamma: float, w: Dict[str, Any]):
     return w["live"] * step_reward, w["live"] * ret
 
 
-def _sample_batch(rings, key, batch_size: int, venv, args: Dict[str, Any],
+def _sample_batch(rings, fmt: RowFormat, key, batch_size: int, venv,
+                  args: Dict[str, Any],
                   debug: Optional[list] = None) -> Dict[str, Any]:
     """Assemble a (batch_size, T, 1, ...) training batch from the rings —
     the device twin of replay.sample_window + batch.make_batch for the
     simultaneous / feed-forward / single-target-player configuration."""
     P = venv.num_players
     k_start, k_player = jax.random.split(key)
-    w = _draw_windows(rings, k_start, batch_size, args["forward_steps"], 0)
+    w = _draw_windows(rings, fmt, k_start, batch_size, args["forward_steps"], 0)
     player = jax.random.randint(k_player, (batch_size,), 0, P)
     if debug is not None:
         debug.append({"lane": w["lane"], "slot": w["slot"], "player": player})
@@ -760,7 +909,8 @@ def _sample_batch(rings, key, batch_size: int, venv, args: Dict[str, Any],
     }
 
 
-def _sample_batch_turn(rings, key, batch_size: int, venv, args: Dict[str, Any],
+def _sample_batch_turn(rings, fmt: RowFormat, key, batch_size: int, venv,
+                       args: Dict[str, Any],
                        debug: Optional[list] = None) -> Dict[str, Any]:
     """All-player window assembly — the device twin of sample_window +
     make_batch for ``turn_based_training: true`` with ``observation: true``
@@ -776,7 +926,7 @@ def _sample_batch_turn(rings, key, batch_size: int, venv, args: Dict[str, Any],
     T = burn_in + args["forward_steps"]
     P = venv.num_players
 
-    w = _draw_windows(rings, key, batch_size, args["forward_steps"], burn_in)
+    w = _draw_windows(rings, fmt, key, batch_size, args["forward_steps"], burn_in)
     if debug is not None:
         debug.append({"lane": w["lane"], "slot": w["slot"],
                       "player": jnp.full((batch_size,), -1, jnp.int32)})

@@ -365,6 +365,7 @@ def test_draw_independent_of_ring_sharding(rollout_data):
     sharded = DeviceReplay(VectorHungryGeese, rollout_data["module"],
                            rollout_data["args"], mesh, N_LANES, slots=SLOTS)
     sharded.rings = jax.device_put(one.rings, _lane_sharding(mesh, one.rings))
+    sharded.row_format = one.row_format
     assert len(sharded.rings["valid"].sharding.device_set) == 4
 
     key = jax.random.PRNGKey(9)
@@ -568,3 +569,250 @@ def test_ingest_stats_match_records(rollout_data):
             tot[k] += int(stats[k])
     assert tot["episodes"] > 0 and tot["game_steps"] >= tot["episodes"]
     assert replay.eligible_count() > 0
+
+
+# -- the record ring's storage format ----------------------------------------
+
+
+def _stream_record_spec(env_name, venv, observation):
+    """One step's record spec (leaves (lanes, ...)) as the streaming fn
+    emits it for ``venv`` — traced, nothing runs."""
+    env = make_env({"env": env_name})
+    module = env.net()
+    lanes = 2
+    fn = build_streaming_fn(venv, module, lanes, 4, mesh=None,
+                            use_observe_mask=observation)
+    _, _, records = jax.eval_shape(
+        fn, init_variables(module, env)["params"],
+        venv.init(lanes, jax.random.PRNGKey(0)),
+        module.initial_state((lanes, venv.num_players)), jax.random.PRNGKey(1),
+    )
+    return {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype) for k, v in records.items()}
+
+
+def _geese_spec():
+    return _stream_record_spec("HungryGeese", VectorHungryGeese, False)
+
+
+def _geister_spec():
+    from handyrl_tpu.envs.vector_geister import VectorGeister
+
+    return _stream_record_spec("Geister", VectorGeister, True)
+
+
+def _parallel_tictactoe_spec():
+    from handyrl_tpu.envs.vector_parallel_tictactoe import VectorParallelTicTacToe
+
+    return _stream_record_spec("ParallelTicTacToe", VectorParallelTicTacToe, False)
+
+
+def _staged_spec():
+    """A host-born record as ``DeviceEpisodeStage`` queues it: explicit
+    ``reward``/``ret`` columns and whole observation planes, int8 under
+    ``obs_int8``."""
+    import random
+
+    from handyrl_tpu.models import InferenceModel
+    from handyrl_tpu.runtime.device_replay import DeviceEpisodeStage
+    from handyrl_tpu.runtime.generation import Generator
+
+    args = _args(obs_int8=True)
+    random.seed(5)
+    env = make_env({"env": "HungryGeese"})
+    module = env.net()
+    model = InferenceModel(module, init_variables(module, env, seed=5))
+    episode = None
+    while episode is None:
+        episode = Generator(env, args).generate(
+            {p: model for p in env.players()},
+            {"player": env.players(), "model_id": {p: 1 for p in env.players()}},
+        )
+    stage = DeviceEpisodeStage(module, args, make_mesh({"dp": 1}), n_lanes=1)
+    stage.add_episode(episode)
+    rec = stage._queues[0][0][0]                  # leaves (T, ...)
+    assert rec["obs0"].dtype == np.int8 and {"reward", "ret"} <= set(rec)
+    return {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in rec.items()}
+
+
+def _random_leaf(rng, shape, dtype):
+    """Every bit pattern the dtype stores (NaNs and all): the ring must
+    hand back bits, not values."""
+    dtype = np.dtype(dtype)
+    if dtype == np.bool_:
+        return rng.integers(0, 2, shape).astype(bool)
+    raw = rng.integers(0, 256, tuple(shape) + (dtype.itemsize,), dtype=np.uint8)
+    return raw.view(dtype).reshape(shape)
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_geese_spec, id="hungry_geese"),
+    pytest.param(_geister_spec, id="geister"),
+    pytest.param(_parallel_tictactoe_spec, id="parallel_tictactoe"),
+    pytest.param(_staged_spec, id="staged_int8_obs_reward_ret"),
+])
+def test_row_format_pack_unpack_is_identity(build):
+    """pack -> unpack hands every leaf back bit for bit, whole rows and a
+    single named field alike; a row holds every leaf on its own words and
+    is a multiple of 128 words wide."""
+    from handyrl_tpu.runtime.device_replay import RowFormat
+
+    spec = build()
+    fmt = RowFormat(spec)
+    assert set(fmt.fields) == set(spec) - {"done"}
+    assert fmt.width % 128 == 0
+    spans = sorted((f.offset, f.offset + f.words) for f in fmt.fields.values())
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:])), "fields overlap"
+    assert spans[-1][1] <= fmt.width
+    assert fmt.used_bytes == sum(
+        int(np.prod(v.shape[1:])) * np.dtype(v.dtype).itemsize
+        for k, v in spec.items() if k != "done"
+    )
+
+    rng = np.random.default_rng(0)
+    lead = (3, 5)
+    rec = {k: _random_leaf(rng, lead + tuple(spec[k].shape[1:]), spec[k].dtype)
+           for k in fmt.fields}
+    rows = jax.jit(fmt.pack)(rec)
+    assert rows.shape == lead + (fmt.width,) and rows.dtype == np.int32
+    back = jax.device_get(jax.jit(fmt.unpack)(rows))
+    assert set(back) == set(rec)
+    for k, x in rec.items():
+        assert back[k].dtype == x.dtype and back[k].shape == x.shape, k
+        np.testing.assert_array_equal(
+            back[k].view(np.uint8), x.view(np.uint8), err_msg=k)
+    one = jax.device_get(fmt.unpack(rows[0, 0], ("outcome",)))
+    assert set(one) == {"outcome"}
+    np.testing.assert_array_equal(
+        one["outcome"].view(np.uint8), rec["outcome"][0, 0].view(np.uint8))
+
+
+def _stepwise_writer(rings, fields, done, slots):
+    """The plain writer the ingest must equal: one record per lane per
+    step at ``g % S``, only the overwritten slot invalidated, a finished
+    lane finalizing every slot of its current episode."""
+    for t in range(done.shape[0]):
+        g = rings["g"]
+        pos = g % slots
+        for k, v in fields.items():
+            rings["rec"][k][:, pos] = v[t]
+        rings["ep_start_g"][:, pos] = rings["cur_start_g"]
+        rings["ep_end_g"][:, pos] = -1
+        rings["valid"][:, pos] = False
+        fin = done[t][:, None] & (rings["ep_start_g"] == rings["cur_start_g"][:, None])
+        rings["ep_end_g"][fin] = g
+        rings["valid"] |= fin
+        rings["cur_start_g"] = np.where(done[t], g + 1, rings["cur_start_g"])
+        rings["g"] = g + 1
+
+
+@pytest.mark.parametrize("slots,k_steps,n_calls", [
+    pytest.param(48, 32, 4, id="block_crosses_ring_end"),
+    pytest.param(50, 16, 9, id="slots_not_a_multiple_of_k"),
+    pytest.param(64, 16, 6, id="aligned_blocks"),
+    pytest.param(12, 12, 3, id="block_as_long_as_the_ring"),
+    pytest.param(10, 12, 3, id="block_longer_than_the_ring"),
+])
+def test_ingest_equals_stepwise_writer(slots, k_steps, n_calls):
+    """Block ingests (the records written in place as packed rows, the id
+    rings stepped beside them) leave the ring contents, ``ep_start_g``,
+    ``ep_end_g``, ``valid``, ``cur_start_g`` and ``g`` a step-by-step numpy
+    writer leaves: episodes that end inside a block, span blocks, outlive
+    the ring, and lanes that finish nothing."""
+    spec = _geese_spec()
+    lanes = 5
+    env = make_env({"env": "HungryGeese"})
+    replay = DeviceReplay(VectorHungryGeese, env.net(), _args(),
+                          make_mesh({"dp": 1}), lanes, slots=slots)
+    want = {
+        "rec": {k: np.zeros((lanes, slots) + tuple(v.shape[1:]), v.dtype)
+                for k, v in spec.items() if k != "done"},
+        "ep_start_g": np.full((lanes, slots), -1, np.int32),
+        "ep_end_g": np.full((lanes, slots), -1, np.int32),
+        "valid": np.zeros((lanes, slots), bool),
+        "cur_start_g": np.zeros((lanes,), np.int32),
+        "g": 0,
+    }
+    rng = np.random.default_rng(slots)
+    # per-lane finish rates: none at all, rare (episodes outlive the ring),
+    # every few steps, every step
+    rate = np.array([0.0, 0.02, 0.1, 0.3, 1.0])
+    for _ in range(n_calls):
+        records = {
+            k: _random_leaf(rng, (k_steps, lanes) + tuple(v.shape[1:]), v.dtype)
+            for k, v in spec.items() if k != "done"
+        }
+        # the stats read these two as numbers: keep them finite
+        records["outcome"] = rng.standard_normal((k_steps, lanes, 4)).astype(np.float32)
+        records["done"] = rng.random((k_steps, lanes)) < rate
+        replay.ingest(records)
+        _stepwise_writer(want, {k: v for k, v in records.items() if k != "done"},
+                         records["done"], slots)
+    got = jax.device_get(replay.rings)
+    assert int(got["g"]) == want["g"] == n_calls * k_steps
+    for k in ("ep_start_g", "ep_end_g", "valid", "cur_start_g"):
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    assert want["valid"].any() and not want["valid"][0].any()
+    rec = jax.device_get(replay.row_format.unpack(replay.rings["rec"]))
+    for k, x in want["rec"].items():
+        np.testing.assert_array_equal(
+            rec[k].view(np.uint8), x.view(np.uint8), err_msg=k)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold
+    (pjit, scan, while, cond, custom calls), outermost first."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def _ring_sized(eqn, n):
+    return [v for v in eqn.outvars if getattr(v.aval, "size", 0) >= n]
+
+
+def test_no_work_of_the_ring_s_size_outside_the_in_place_update(rollout_data):
+    """What keeps O(ring) work from coming back unseen (the TPU's layout
+    assignment is out of a CPU test's sight): in ``jit_ingest`` the only
+    equations with a result as large as the record ring are the in-place
+    ``dynamic_update_slice``s (and the calls that wrap them), never a loop
+    that carries it; in ``jit_replay_train`` no equation's result is as
+    large.  The ring here is larger than any activation of the update."""
+    slots = 4096
+    replay = DeviceReplay(VectorHungryGeese, rollout_data["module"],
+                          rollout_data["args"], rollout_data["mesh"],
+                          N_LANES, slots=slots)
+    records = {
+        k: np.zeros((K_STEPS, N_LANES) + f.shape, f.dtype)
+        for k, f in rollout_data["replay"].row_format.fields.items()
+    }
+    records["done"] = np.zeros((K_STEPS, N_LANES), bool)
+    replay.ingest(records)
+    n = replay.rings["rec"].size
+    assert n == N_LANES * slots * replay.row_format.width
+
+    ingest = jax.make_jaxpr(replay._ingest)(replay.rings, records)
+    updates = 0
+    for eqn in _walk_eqns(ingest.jaxpr):
+        if not _ring_sized(eqn, n):
+            continue
+        name = eqn.primitive.name
+        assert name not in ("scan", "while"), f"{name} carries the record ring"
+        if name == "dynamic_update_slice":
+            updates += 1
+        else:   # a call around the updates; its body was walked too
+            assert any(hasattr(getattr(v, "jaxpr", v), "eqns")
+                       for v in eqn.params.values()), (
+                f"{name} makes a result as large as the record ring")
+    assert updates == K_STEPS
+
+    ctx = TrainContext(rollout_data["module"], rollout_data["args"],
+                       rollout_data["mesh"])
+    train = replay.train_fn(ctx, fused_steps=2)
+    state = ctx.init_state(rollout_data["params"])
+    big = [eqn.primitive.name for eqn in _walk_eqns(train.jaxpr(state).jaxpr)
+           if _ring_sized(eqn, n)]
+    assert not big, f"as large as the record ring: {big}"

@@ -358,11 +358,14 @@ def test_int8_obs_ring_parity_vs_make_batch(monkeypatch):
     stage.drain()
 
     replay = stage.replay
-    # int8 residency: the staged ring record slots hold int8 obs planes
-    rec = replay.rings["rec"]
-    obs_dtypes = {k: np.dtype(rec[k].dtype) for k in rec
+    # int8 residency: the ring's rows hold the obs planes as int8, a byte
+    # an element (the row format says what each leaf is stored as)
+    obs_fields = {k: f for k, f in replay.row_format.fields.items()
                   if k.startswith("obs") and k[3:].isdigit()}
-    assert obs_dtypes and all(dt == np.int8 for dt in obs_dtypes.values()), obs_dtypes
+    assert obs_fields and all(
+        f.dtype == np.int8 and f.nbytes == np.prod(f.shape)
+        for f in obs_fields.values()), obs_fields
+    assert replay.rings["rec"].dtype == np.int32
 
     S = stage.slots
     G = int(jax.device_get(replay.rings["g"]))
