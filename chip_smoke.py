@@ -36,6 +36,30 @@ import traceback
 ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(ROOT, "chip_smoke_out")
 
+# The transformer phase's model and step: the benchmark's ``xfmr_d1536``
+# configuration under its ``xfmr_train_t64`` workload
+# (tests/test_chip_smoke.py holds the two equal), and the long-window rows
+# the Pallas kernels are compiled at (tests/test_chip_compile.py).
+TRANSFORMER_TPU_NET_ARGS = {"d_model": 1536, "n_heads": 16, "n_layers": 8,
+                            "memory_len": 32}
+TRANSFORMER_TPU_OVERRIDES = {"batch_size": 64, "burn_in_steps": 2,
+                             "forward_steps": 62, "observation": True,
+                             "compute_dtype": "bfloat16",
+                             # 'auto' would pick einsum at T64 too; pinned
+                             # so the phase measures one known program
+                             "seq_attention": "einsum"}
+# batch shrinks with T so that remat, not a vanishing batch, is what fits
+# T1024 in HBM
+TRANSFORMER_LONG_TPU = {
+    "net_args": TRANSFORMER_TPU_NET_ARGS,
+    "sweep_t": (64, 512, 1024),
+    "batch_by_t": {64: 64, 512: 16, 1024: 8},
+    "flash_min_t": 128,
+    "compute_dtype": "bfloat16",
+    "sp_t": 512,
+    "sp_batch": 16,
+}
+
 # real where the model is (widths, batch, window), short where only
 # length is at stake (epochs, episodes, requests)
 SIZES = {
@@ -44,7 +68,7 @@ SIZES = {
         "epochs": 3, "update_episodes": 100, "minimum_episodes": 200,
     },
     "train_device": {
-        # the README's north-star loop at bench.py's northstar2 geometry
+        # the README's north-star loop (HungryGeese, rollout -> rings -> train)
         "batch_size": 128, "forward_steps": 16,
         "device_rollout_games": 128, "device_replay_k_steps": 32,
         "device_replay_slots": 512, "fused_steps": 8,
@@ -53,15 +77,20 @@ SIZES = {
         # finishes ~800 episodes, so an epoch is two dispatches
         "epochs": 3, "update_episodes": 1600, "minimum_episodes": 1600,
     },
-    # transformer: bench.TRANSFORMER_TPU_NET_ARGS / _OVERRIDES, unchanged
-    "transformer": {"net_args": None, "overrides": None, "steps": 3},
+    "transformer": {"net_args": TRANSFORMER_TPU_NET_ARGS,
+                    "overrides": TRANSFORMER_TPU_OVERRIDES, "steps": 3},
     "serve": {"games": 3, "max_steps": 12},
     # --multichip
     "dp": {"batch_size": 128, "device_rollout_games": 128,
            "device_replay_k_steps": 32, "device_replay_slots": 512,
            "fused_steps": 8, "dispatches": 3},
-    # the long-context bench row's per-head shape (TRANSFORMER_LONG_TPU)
-    "ring": {"shape": (2, 1024, 16, 96), "window": 32},
+    # the longest row's per-head shape: (2, 1024, 16, 96), window 32
+    "ring": {
+        "shape": (2, TRANSFORMER_LONG_TPU["sweep_t"][-1],
+                  TRANSFORMER_TPU_NET_ARGS["n_heads"],
+                  TRANSFORMER_TPU_NET_ARGS["d_model"] // TRANSFORMER_TPU_NET_ARGS["n_heads"]),
+        "window": TRANSFORMER_TPU_NET_ARGS["memory_len"],
+    },
 }
 
 # the bound tests/test_flash_attention.py holds the bf16 kernel to against
@@ -232,15 +261,13 @@ def phase_transformer(sizes, seed):
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     from __graft_entry__ import _random_play_batch
     from handyrl_tpu.config import normalize_args
     from handyrl_tpu.envs import make_env
     from handyrl_tpu.models import RandomModel, init_variables
     from handyrl_tpu.parallel import TrainContext, make_mesh
 
-    net_args = sizes["net_args"] or bench.TRANSFORMER_TPU_NET_ARGS
-    overrides = dict(sizes["overrides"] or bench.TRANSFORMER_TPU_OVERRIDES)
+    net_args, overrides = sizes["net_args"], sizes["overrides"]
     random.seed(seed)
     np.random.seed(seed)
     info = {}

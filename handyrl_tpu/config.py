@@ -258,7 +258,7 @@ DEFAULT_TRAIN_ARGS: Dict[str, Any] = {
         # fused into the compiled apply — models/quantize.py).  Applied
         # at engine build, so ModelRouter engines, fleet replicas, and
         # frozen league opponents all inherit it; win-rate parity is
-        # MEASURED by the lowprec bench stage, never assumed
+        # MEASURED (tests/test_lowprec.py's slow pit), never assumed
         "weight_dtype": "float32",
         # replay-episode calibration batches sampled at publish when
         # weight_dtype is int8: the router replays stored observations

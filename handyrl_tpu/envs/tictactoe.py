@@ -140,10 +140,9 @@ class TicTacToeRules:
     hand-written ``VectorTicTacToe`` twin.
 
     This namespace exists as the apples-to-apples yardstick for the
-    twin-less path: the ``league`` bench stage lifts it with
-    ``autovectorize`` and measures per-chip self-play throughput against
-    the hand-written ``vector_tictactoe.VectorTicTacToe`` — same game,
-    same net, so the frac isolates the cost of the lift itself.
+    twin-less path: lifted with ``autovectorize`` it runs beside the
+    hand-written ``vector_tictactoe.VectorTicTacToe`` — same game, same
+    net, so a comparison isolates the cost of the lift itself (ROADMAP D9).
     Bit-parity of every observable against the hand twin is pinned by
     tests/test_autovec.py.
 

@@ -26,7 +26,7 @@ loses zero sessions.
 — anything with ``spawn() -> ReplicaSpec`` / ``stop(spec)`` / ``close()``
 serves.  ``ProcessReplicaFactory`` is the built-in: local serving-plane
 processes (spawn context — a JAX parent must never fork), the shape
-``main.py --fleet`` and the bench use; a cloud deployment would back the
+``main.py --fleet`` uses; a cloud deployment would back the
 same protocol with its instance API.
 """
 

@@ -151,7 +151,7 @@ class PayoffMatrix:
         listed members: r = 400·log10(p/(1-p)) with p clipped away from
         {0, 1} (a member yet to lose is 'at least +478', not infinity).
         Coarse by design — a population spread/ordering signal for the
-        bench and metrics, not a ladder rating; ``anchor`` (when listed)
+        metrics, not a ladder rating; ``anchor`` (when listed)
         is shifted to exactly 0 so ratings are comparable across epochs."""
         ratings: Dict[str, float] = {}
         for m in members:

@@ -94,8 +94,7 @@ class InferenceModel(SingleInferenceMixin):
 def build_inference_model(module, params, weight_dtype: str = "float32"):
     """THE engine-build seam for ``serving.weight_dtype``: every place
     that wraps a published/loaded param tree into an engine model
-    (ModelRouter.publish, its cold-resolve path, the bench's serving
-    stages) goes through here, so the int8 rung reaches the serving
+    (ModelRouter.publish, its cold-resolve path) goes through here, so the int8 rung reaches the serving
     plane, the fleet replicas, and the frozen league opponents from one
     switch.  Lazy import keeps the fp32 path free of the quantize
     module."""

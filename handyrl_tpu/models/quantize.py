@@ -14,9 +14,10 @@ bytes*, not fewer flops.  Two byte streams get an int8 rung here:
   dequantizes INSIDE the compiled program — XLA fuses the
   convert-and-scale into the consuming matmul/conv (dequantize-in-
   matmul), so HBM traffic for weights drops ~4x while the MXU still
-  computes in fp32.  Win-rate parity is MEASURED, never assumed: the
-  ``lowprec`` bench stage pits quantized vs fp32 through the league's
-  ``PayoffMatrix`` ledger (bar |dwp| <= 0.03 over >= 400 games).
+  computes in fp32.  Win-rate parity is MEASURED, never assumed:
+  tests/test_lowprec.py pits quantized vs fp32 through the league's
+  ``PayoffMatrix`` ledger (a chip run's bar: |dwp| <= 0.03 over >= 400
+  games).
 
 * **Observations (wire / shm slots / device rings)** — static per-plane
   scale/zero-point from env metadata (``env.obs_int8_spec()``, default
@@ -130,7 +131,7 @@ def has_quantized_leaves(params: Any) -> bool:
 
 def param_bytes(params: Any) -> int:
     """Resident bytes of a param tree, honoring int8 wrappers — the
-    numerator of the bench's weight-bytes-shrink report."""
+    numerator of the weight-bytes-shrink report."""
     total = [0]
 
     def _arr(leaf):
@@ -203,10 +204,9 @@ def calibration_report(module, params, obs_batches: Sequence[Any],
     """MEASURED fp32-vs-int8 output deviation over replay observations.
 
     ``obs_batches``: batched obs pytrees drawn from stored episodes (the
-    serving router samples them at publish time; the bench feeds its
-    replay store).  Returns max/mean absolute deviation per output head
+    serving router samples them at publish time).  Returns max/mean absolute deviation per output head
     family collapsed to scalars — the honest calibration record the
-    router logs and the ``lowprec`` bench stage reports, instead of a
+    router logs, instead of a
     weight-space error bound that says nothing about the policy."""
     from .inference import InferenceModel
 

@@ -77,15 +77,15 @@ def _cast_floats(tree, dtype):
 def resolve_seq_attention(args: Dict[str, Any], T: int) -> str:
     """THE seq-mode attention auto-pick policy, as one shared resolver
     ('einsum' | 'flash' | 'ring' for a window of length ``T``) used by the
-    compiled forward, the bench's transformer stages, and the CI smoke —
+    compiled forward, chip_smoke.py's transformer phase, and the CI smoke —
     so "which path did the program take" is decided (and reportable) in
     exactly one place.
 
     ``auto`` picks the Pallas masked flash kernel for windows >=
     ``flash_min_t`` and the exact einsum below it.  The crossover is a
     property of the PROGRAM (the O(T^2) score tensor vs the kernel's fixed
-    launch/block overhead, measured on-chip: einsum wins at T64, flash
-    1.54x at T1024 — BENCH_r05 flash_attention.speedup).  The policy is
+    launch/block overhead; no cell holds the kernel yet, so where the
+    crossover sits on today's code is not measured: ROADMAP S5).  The policy is
     shared by TPU (compiled kernel) and CPU (exact interpret-mode kernel —
     CPU long-T runs are tests/smokes on this TPU framework, and sharing
     the pick is what lets CI exercise the very program the chip compiles);
@@ -106,8 +106,8 @@ def resolve_seq_remat(args: Dict[str, Any], T: int) -> str:
 
     Explicit ladder values pass through; booleans collapse to the nearest
     rung (True -> 'block', False -> 'none'); ``auto`` turns 'block' on for
-    long windows (T >= 512) on TPU — the d2048 width sweep died to HBM
-    pressure with remat named as the missing lever (bench.py) — and stays
+    long windows (T >= 512) on TPU, where the activations of a whole
+    window would not fit beside a d1536 model's state, and stays
     'none' elsewhere (short windows fit, and the CPU path prefers speed).
 
     Ring attention is always 'none': each device already holds only its
@@ -248,7 +248,7 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
         #   backward pass instead of storing T steps of DRC gate tensors —
         #   ~T x less live HBM at ~1.3x forward recompute (config: remat).
         # * unroll (default on single-device CPU, i.e. the CPU-fallback
-        #   bench/train case): XLA:CPU executes ops inside while-loop
+        #   train case): XLA:CPU executes ops inside while-loop
         #   bodies without its fast kernel runtime — measured 17-40x slower
         #   than the identical ops unrolled (DRC step: 9.3s looped vs 0.56s
         #   unrolled at batch 16).  Full unroll restores the fast kernels;
@@ -682,8 +682,7 @@ class TrainContext:
 
 
 # peak dense bf16 FLOP/s per chip (public figures) — the denominator for
-# MFU accounting everywhere (bench.py headline stages, Trainer per-epoch
-# stats -> metrics.jsonl)
+# MFU accounting (Trainer per-epoch stats -> metrics.jsonl)
 PEAK_FLOPS_BY_KIND = [
     ("v6", 918e12),
     ("v5p", 459e12),

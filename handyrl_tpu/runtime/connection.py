@@ -91,7 +91,7 @@ class FramedConnection:
         self._send_lock = threading.Lock()
         self._recv_lock = threading.Lock()
         # frame-payload byte tallies (headers included), updated under the
-        # respective direction's lock: the fleet bench reads these to
+        # respective direction's lock: tests/test_fleet.py reads these to
         # measure wire bytes/request — session routing's whole claim
         self.bytes_sent = 0
         self.bytes_received = 0

@@ -1,10 +1,10 @@
 """Host-bypass batch assembly: ``batch_pipeline: device``.
 
-BENCH_r05 on a real TPU v5 lite: the chip consumes 376 updates/s on the
-direct path while the host-fed pipeline delivers 3.0 — batch assembly
-(make_batch + the ~43 MB/update observation H2D re-upload) feeds the
-device at under 1% of what it can eat, and no batcher count fixes a
-per-update host round-trip.  The Sebulba/Podracer lesson the repo already
+Host batch assembly (make_batch + the ~43 MB/update observation H2D
+re-upload on HungryGeese) feeds the device a small part of what a staged
+step consumes, and no batcher count fixes a per-update host round-trip
+(how small is not measured on today's code: PERF.md section 7,
+`geese_hostfed`).  The Sebulba/Podracer lesson the repo already
 builds on (PR 3) applies to the DATA plane too: when the host loses, take
 the host out of the data path.
 
@@ -174,7 +174,7 @@ class DeviceBatchPipeline:
                 with self._lock:
                     # assemble = host decode/staging, put = ring ingest
                     # (the once-per-chunk H2D) — same stat vocabulary as
-                    # the host pipelines so trainer/bench diffs apply
+                    # the host pipelines so the trainer's diffs apply
                     self._stats["assemble_s"] += t1 - t0
                     self._stats["put_s"] += t2 - t1
         except Exception:

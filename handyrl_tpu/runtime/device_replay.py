@@ -1043,8 +1043,7 @@ class DeviceEpisodeStage:
     """Host-born episodes uploaded ONCE into DeviceReplay ring buffers.
 
     The host-fed pipeline re-uploads every sampled observation window per
-    update (~43 MB/update on HungryGeese — BENCH_r05's 3 vs 376 updates/s
-    gap); this stage removes the host from the per-update path for
+    update (~43 MB/update on HungryGeese); this stage removes the host from the per-update path for
     episodes that are BORN on the host (worker actors, remote workers):
 
         episode (decoded dict, or the wire-codec bytes EpisodeStore

@@ -3,7 +3,7 @@
 The fused north-star loop is production-bound by construction: one
 self-play env-step costs ~100x one trained env-step in device time, so a
 single program queue spends >90% of its time in rollout however the duty
-cycle is tuned (round-4 sweep, bench.py northstar2).  The Podracer/
+cycle is tuned.  The Podracer/
 Sebulba answer (Hessel et al. 2021; IMPALA, Espeholt et al. 2018) is to
 stop time-slicing: pin self-play to an **actor mesh** and training to a
 disjoint **learner mesh** (parallel/mesh.py:split_mesh) so both planes

@@ -2,8 +2,8 @@
 
 The threaded BatchPipeline (runtime/trainer.py) keeps every make_batch on
 the learner process's GIL, where it contends with the inference engine,
-the worker threads and jax dispatch — measured at 3 updates/s against 376
-for the direct path on HungryGeese (BENCH_r05.json).  This module moves
+the worker threads and jax dispatch (no cell measures it yet: PERF.md
+section 7, `geese_hostfed`).  This module moves
 assembly off the GIL entirely, the IMPALA/HandyRL decoupled-batcher
 design point (reference train.py:271-401 forks num_batchers processes):
 
@@ -46,7 +46,7 @@ matching the trust model of runtime/codec.py), and each child maintains
 its own recency-biased replica store — per-batch sampling then costs the
 parent nothing.  Every stage is timed (sample / assemble / free-slot wait
 / ready wait / device put / device-queue depth) and surfaced through
-``stats()`` into metrics.jsonl and bench.py.
+``stats()`` into metrics.jsonl.
 
 Supervision (docs/fault_tolerance.md): the parent watches its children.
 An OOM-killed / SIGKILL'd batcher process no longer starves the trainer
@@ -849,7 +849,7 @@ class ShmBatchPipeline:
             self._unlink_quiet()
         # the atexit safety net is only for pipelines that never reached
         # close(); keeping it would pin this instance (ctx/store/spec) for
-        # process lifetime — bench runs build several pipelines per process
+        # process lifetime — a process may build several pipelines
         try:
             atexit.unregister(self._unlink_quiet)
         except Exception:

@@ -48,7 +48,7 @@ from .replay import EpisodeStore
 
 
 # the one canonical stage-key list: every consumer (both pipeline
-# classes, the per-epoch metrics diff below, bench.py's stage report)
+# classes, the per-epoch metrics diff below)
 # imports THIS tuple, so adding a stage cannot silently miss a site
 PIPE_STAT_KEYS = ("sample_s", "assemble_s", "free_wait_s", "ready_wait_s", "put_s")
 

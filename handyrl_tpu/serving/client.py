@@ -2,7 +2,7 @@
 
 One connection, many outstanding requests: every frame carries a ``rid``
 and a single receiver thread resolves the matching future, so a caller
-can keep a submit window open (the load generator the bench uses) or use
+can keep a submit window open (what a load generator does) or use
 the blocking ``infer`` facade.  Server-side sheds and deadline misses
 surface as ``ServingError`` with the wire ``kind`` — fast-fail reaches
 the caller as an exception, never as a hang.

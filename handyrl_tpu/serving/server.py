@@ -159,7 +159,7 @@ class ServingServer(QueueCommunicator):
     # -- lifecycle ----------------------------------------------------------
 
     def run(self) -> "ServingServer":
-        # bind AND listen synchronously: port 0 (tests/bench) resolves
+        # bind AND listen synchronously: port 0 (tests) resolves
         # before return, and a client connecting the instant run() returns
         # must never see a refused connect because the accept thread
         # hasn't reached its own listen() yet

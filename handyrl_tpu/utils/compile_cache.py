@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed from outside.
 
-Every entry point (main.py, chip_smoke.py, bench.py) calls
+Every entry point (main.py, chip_smoke.py, benchmark/run.py) calls
 ``enable_compile_cache()`` before its first compile.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and no path is
 set in code; otherwise the cache lives at one fixed path inside the

@@ -11,7 +11,7 @@ Three parity layers pin the lift end to end:
    ``autovec_verify_games`` startup self-check);
 3. lift == hand twin: the autovectorized TicTacToe is bit-identical to
    the hand-written ``VectorTicTacToe`` on identical action streams —
-   the apples-to-apples pair the ``league`` bench stage measures.
+   the apples-to-apples pair a lift is judged on (ROADMAP D9).
 
 Plus the loud-diagnostic contract: every liftability break (in-place
 mutation, value-dependent branching, missing jnp API, shape-unstable

@@ -1,5 +1,5 @@
 """Both Pallas attention kernels, compiled for a described (not attached)
-TPU v5e by the chip's own compiler, at the shapes bench.py pins.
+TPU v5e by the chip's own compiler, at the shapes chip_smoke.py pins.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (a slice
 not aligned to the tiling, too much VMEM); these compiles can, at about
@@ -17,14 +17,22 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import chip_smoke
 from handyrl_tpu.ops.flash_attention import flash_attention, masked_flash_attention
 
-WINDOW = 32  # memory_len of every pinned transformer shape
+_NET = chip_smoke.TRANSFORMER_TPU_NET_ARGS
+_STEP = chip_smoke.TRANSFORMER_TPU_OVERRIDES
+_LONG = chip_smoke.TRANSFORMER_LONG_TPU
+_HD = (_NET["n_heads"], _NET["d_model"] // _NET["n_heads"])
 
-# (B, T, H, D): TRANSFORMER_TPU (B64 x 2 players, T64) and the two long
-# rows of TRANSFORMER_LONG_TPU (bench.py), all d1536 / 16 heads -> D96,
-# which pads to the 128-lane tile inside the kernel
-PINNED = [(128, 64, 16, 96), (16, 512, 16, 96), (8, 1024, 16, 96)]
+WINDOW = _NET["memory_len"]  # of every pinned transformer shape
+
+# (B, T, H, D): the smoke's and the xfmr_train_t64 cell's step (B64 x 2
+# players, T64) and the two long rows of TRANSFORMER_LONG_TPU, all d1536 /
+# 16 heads -> D96, which pads to the 128-lane tile inside the kernel
+PINNED = [(2 * _STEP["batch_size"], _STEP["burn_in_steps"] + _STEP["forward_steps"]) + _HD] + [
+    (_LONG["batch_by_t"][t], t) + _HD for t in _LONG["sweep_t"][1:]
+]
 # T not a multiple of the 128 tile: only the masked kernel pads T
 UNALIGNED = (8, 200, 16, 96)
 

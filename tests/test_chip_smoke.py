@@ -16,7 +16,6 @@ from types import SimpleNamespace
 import pytest
 
 REPO = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(REPO))
 
 import chip_smoke
 from handyrl_tpu.utils import compile_cache
@@ -197,6 +196,24 @@ def test_training_checks_catch_what_a_fallback_would_hide(monkeypatch, tmp_path)
         )
     with pytest.raises(AssertionError, match="no SGD update"):
         chip_smoke._check_training([good[0], dict(good[1], steps=0)])
+
+
+# -- the transformer phase is the benchmark's cell ------------------------------
+
+
+def test_transformer_sizes_equal_the_benchmarks_cell():
+    """The smoke's transformer phase and the ``xfmr_train_t64`` cell are one
+    model under one step: a change to either side alone fails here."""
+    config = json.loads((REPO / "benchmark/configs/xfmr_d1536.json").read_text())
+    cell = json.loads((REPO / "benchmark/workloads/xfmr_train_t64.json").read_text())
+    assert cell["config"] == config["name"]
+    assert chip_smoke.TRANSFORMER_TPU_NET_ARGS == config["env_args"]["net_args"]
+    assert chip_smoke.TRANSFORMER_TPU_OVERRIDES == {**config["train_args"], **cell["train_args"]}
+    long = chip_smoke.TRANSFORMER_LONG_TPU
+    assert long["net_args"] == config["env_args"]["net_args"]
+    assert long["compute_dtype"] == config["train_args"]["compute_dtype"]
+    assert long["batch_by_t"][long["sweep_t"][0]] == cell["train_args"]["batch_size"]
+    assert chip_smoke.SIZES["ring"] == {"shape": (2, 1024, 16, 96), "window": 32}
 
 
 # -- rehearsal 1: every phase end to end on the CPU at a tiny size -------------

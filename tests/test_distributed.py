@@ -7,12 +7,12 @@ real sockets, a full --train-server/--worker run on localhost, and the
 network battle mode.
 """
 
-import socket
 import sys
 import threading
 
 import numpy as np
 import pytest
+from conftest import free_port
 
 from handyrl_tpu.config import normalize_args
 from handyrl_tpu.runtime import codec
@@ -23,14 +23,6 @@ from handyrl_tpu.runtime.connection import (
     connect_socket_connection,
     send_recv,
 )
-
-
-def free_port() -> int:
-    s = socket.socket()
-    s.bind(("", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
 
 
 def connect_retry(host: str, port: int) -> FramedConnection:

@@ -19,8 +19,8 @@ ENV_NAMES = [
     "ParallelTicTacToe",
     "Geister",
     "HungryGeese",
-    # first-class zoo entry for the worked example (league/autovec bench
-    # legs run against it as a registered scenario)
+    # first-class zoo entry for the worked example (the league and
+    # autovec tests run against it as a registered scenario)
     "ConnectFour",
     # ...and the same module by dotted path, exercising the registry
     # fallback the way a user would (docs/custom_environment.md)

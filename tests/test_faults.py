@@ -33,6 +33,7 @@ from multiprocessing import shared_memory
 
 import numpy as np
 import pytest
+from conftest import free_port
 
 import handyrl_tpu.runtime.checkpoint as cp
 from handyrl_tpu.config import normalize_args
@@ -45,14 +46,6 @@ from handyrl_tpu.runtime.connection import (
 )
 
 pytestmark = pytest.mark.faults
-
-
-def free_port() -> int:
-    s = socket.socket()
-    s.bind(("", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
 
 
 def _tiny_args(extra=None, worker_extra=None):
@@ -588,7 +581,17 @@ def test_stalled_entry_handshake_does_not_block_joins():
     server = WorkerServer(args, lambda req, data, timeout=None: None, None)
     server.run()
     try:
-        trickler = socket.create_connection(("localhost", entry_port), timeout=5)
+        # run() starts the entry thread, which binds and listens when it is
+        # scheduled: a bare connect before that is refused (the failure
+        # under six workers), so connect until the listener is up
+        deadline = time.monotonic() + 10.0
+        while True:
+            try:
+                trickler = socket.create_connection(("localhost", entry_port), timeout=5)
+                break
+            except ConnectionRefusedError:
+                assert time.monotonic() < deadline, "the entry server never listened"
+                time.sleep(0.02)
         stop_trickle = threading.Event()
 
         def trickle():
@@ -603,7 +606,8 @@ def test_stalled_entry_handshake_does_not_block_joins():
                 pass  # server dropped us: the desired outcome
 
         threading.Thread(target=trickle, daemon=True).start()
-        time.sleep(0.2)  # ensure the trickler is accepted first
+        # the trickler's connection is established, so it is ahead of this
+        # one in the listener's accept queue: accepted first
         conn = connect_socket_connection("localhost", entry_port, retry_seconds=5.0)
         t0 = time.monotonic()
         reply = send_recv(conn, {"num_parallel": 2}, timeout=10.0)

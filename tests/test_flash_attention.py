@@ -124,7 +124,7 @@ def test_masked_flash_long_window_golden(T, window):
     ragged observation masks, ALiBi slopes, a non-default eviction window
     — forward AND custom-VJP gradients vs the exact einsum reference
     (interpret-mode kernel on CPU).  This is the shape regime the
-    transformer_long bench drives on-chip; the golden pin here keeps the
+    long rows of chip_smoke.TRANSFORMER_LONG_TPU have on the chip; the golden pin here keeps the
     kernel exact where it is about to be trusted for training."""
     q, k, v, key_mask, slopes = _masked_case(13 + T % 7, 1, T, 2, 16, 0.7)
 

@@ -306,16 +306,36 @@ def test_client_idle_connection_survives_stall_deadline():
         daemon=True,
     )
     t.start()
-    client = ServingClient("127.0.0.1", port, stall_timeout=0.2)
+    client = ServingClient("127.0.0.1", port, stall_timeout=0.5)
+    # count the stall windows the receiver sits out idle (its loop looks
+    # ``conn.recv`` up on every pass)
+    idle_windows = threading.Semaphore(0)
+    framed_recv = client.conn.recv
+
+    def counting_recv(timeout=None):
+        try:
+            return framed_recv(timeout=timeout)
+        except socket.timeout:
+            idle_windows.release()
+            raise
+
+    client.conn.recv = counting_recv
     try:
-        time.sleep(0.8)  # several idle stall windows pass
         t.join(timeout=5)
         assert conns, "server never saw the connection"
-        # the connection still works: a reply sent now resolves a request
         server_conn = conns[0]
-        server_conn.send(("result", {"rid": 1, "model": 0, "out": {"x": 1}}))
-        fut = client.submit(np.zeros(3, np.float32))  # becomes rid 1
+        for _ in range(2):  # several idle stall windows pass
+            assert idle_windows.acquire(timeout=10), "the receiver never timed out idle"
+        # the connection still works.  Request first, then the reply: a
+        # reply sent ahead of its request is an orphan if the receiver
+        # reads it before submit() has registered the rid (the race that
+        # failed this test under load); and a window has just begun, so
+        # the request is not pending across a deadline
+        fut = client.submit(np.zeros(3, np.float32))
+        _, request = server_conn.recv(timeout=5.0)
+        server_conn.send(("result", {"rid": request["rid"], "model": 0, "out": {"x": 1}}))
         assert fut.result(timeout=10)["out"] == {"x": 1}
+        assert client.replies_orphaned == 0
     finally:
         client.close()
         sock.close()

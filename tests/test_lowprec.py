@@ -20,7 +20,7 @@ Two int8 rungs (handyrl_tpu/models/quantize.py, docs/performance.md
 Win-rate parity is MEASURED, never assumed: the slow leg pits the int8
 engine against the fp32 engine holding identical params through the
 league's ``PayoffMatrix`` ledger (the full |dwp| <= 0.03 / >= 400 games
-bar banks in the ``lowprec`` bench stage; the test leg plays fewer games
+bar needs a chip run and has no cell yet; the test leg plays fewer games
 against a looser bound to keep CI honest without making it flaky).
 """
 
@@ -498,7 +498,7 @@ def test_wp_parity_int8_vs_fp32_pit():
     holding IDENTICAL params, seat-balanced through the PayoffMatrix
     ledger.  The test leg plays 64 games against a generous bound (the
     binomial noise floor at 64 games is ~0.13 at 2 sigma); the full
-    >= 400-game |dwp| <= 0.03 bar banks in the lowprec bench stage."""
+    >= 400-game |dwp| <= 0.03 bar needs a chip run and has no cell yet."""
     from handyrl_tpu.agents import Agent
     from handyrl_tpu.league.matchmaker import PayoffMatrix
     from handyrl_tpu.runtime.evaluation import evaluate_mp

@@ -14,11 +14,11 @@ TWO real OS processes, each with 2 virtual CPU devices, connected through
 
 import json
 import os
-import socket
 import subprocess
 import sys
 
 import pytest
+from conftest import free_port
 
 # the whole multi-process surface runs in its own 2-process CI steps
 # (fast leg: epoch loop + resume broadcast + init timeout; slow leg: the
@@ -197,7 +197,7 @@ def test_two_process_ring_attention(tmp_path):
     plane's cross-host claim (its ppermute ring hops process boundaries)."""
     import numpy as np
 
-    port = _free_port()
+    port = free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
@@ -300,15 +300,9 @@ def run_one_train_step(module, args, mesh, params, local_batch):
     return host, float(jax.device_get(metrics["total"]))
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 @pytest.mark.slow
 def test_two_process_cpu_distributed(tmp_path):
-    port = _free_port()
+    port = free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
@@ -349,7 +343,7 @@ def _two_process_train_and_compare(tmp_path, mesh_spec: str, exact_cross: bool):
 
     import numpy as np
 
-    port = _free_port()
+    port = free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
@@ -512,12 +506,26 @@ with open(os.path.join(outdir, f"done_{pid}{extra.get('tag', '')}.json"), "w") a
 from handyrl_tpu.parallel.distributed import shutdown_distributed
 
 shutdown_distributed()
+# Learner.run() sets its planes' stop events and joins none of their
+# threads (the batch pipeline's _device_put_loop, a follower's engine
+# _serve_loop); each ends within a second, but an interpreter torn down
+# while one is still inside a jax call aborts ("FATAL: exception not
+# rethrown", rc -6: 2 of 6 two-process runs on a quiet box).  Wait for
+# them; train_main does not (ROADMAP D14).
+import threading, time
+
+deadline = time.monotonic() + 30.0
+others = [t for t in threading.enumerate() if t is not threading.current_thread()]
+for t in others:
+    t.join(max(0.0, deadline - time.monotonic()))
+left = [t.name for t in others if t.is_alive()]
+assert not left, f"threads still alive 30 s after Learner.run(): {left}"
 sys.exit(code)
 """
 
 
 def _spawn_learners(tmp_path, extra=None, env_extra=None, nproc=2, log_files=False):
-    port, hport = _free_port(), _free_port()
+    port, hport = free_port(), free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
@@ -551,7 +559,7 @@ def _spawn_learners(tmp_path, extra=None, env_extra=None, nproc=2, log_files=Fal
 
 def test_two_process_learner_epoch_loop(tmp_path):
     """Acceptance pin (non-slow, multihost CI step): a REAL 2-process
-    Learner run completes 2 epochs under jax.distributed with params
+    Learner run completes 4 epochs under jax.distributed with params
     bit-identical on both processes, checkpoints/metrics written only by
     the coordinator, and a clean exit-0 shutdown on every rank.
 
@@ -565,9 +573,16 @@ def test_two_process_learner_epoch_loop(tmp_path):
     # generous heartbeat bound: this test pins the lockstep loop, not
     # detection latency, and a CI box under full-suite load can starve a
     # health thread for several seconds at a stretch
+    # The follower's snapshot of an epoch rides its NEXT beat, so a record
+    # folds both ranks only if a beat fell between two boundaries.  With the
+    # children's 1 s interval and two epochs 0.4 s apart (a quiet box) none
+    # did and the last record counted one rank; a loaded box stretched the
+    # epochs and passed.  Beat every 0.1 s and run four epochs: three
+    # boundaries follow the follower's first snapshot.
     procs = _spawn_learners(tmp_path, extra={
-        "epochs": 2,
+        "epochs": 4,
         "heartbeat_timeout": 45.0,
+        "dist": {"heartbeat_interval": 0.1},
         "train": {"trace": {
             "enabled": True,
             "path": str(tmp_path / "trace.jsonl"),
@@ -697,7 +712,7 @@ def test_resume_epoch_broadcast_two_process(tmp_path):
         save_epoch_snapshot(str(coord_dir), epoch, params, dict(params), epoch * 10)
     (tmp_path / "models_1").mkdir()
 
-    port = _free_port()
+    port = free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
@@ -1211,7 +1226,7 @@ def test_actor_host_loss_is_degradable(tmp_path):
     connected actor host must NOT gate the learner — the gateway logs the
     disconnect, bumps dist_actor_host_losses, and the learner's own
     rollout absorbs the game quota to a clean exit-0 finish."""
-    plane_port = _free_port()
+    plane_port = free_port()
     learners = _spawn_learners(
         tmp_path,
         nproc=1,
@@ -1259,7 +1274,7 @@ def test_learner_loss_actor_exits_75(tmp_path):
     tier dies, a dedicated actor host must NOT spin generating against
     unowned params — its next gateway call raises, it announces the fault
     and exits 75 (EX_TEMPFAIL) for the supervisor to relaunch."""
-    plane_port = _free_port()
+    plane_port = free_port()
     learners = _spawn_learners(
         tmp_path,
         nproc=1,

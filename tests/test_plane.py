@@ -21,6 +21,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from conftest import free_port
 
 from handyrl_tpu.config import normalize_args
 from handyrl_tpu.parallel import make_mesh, split_mesh
@@ -426,16 +427,6 @@ def test_learner_split_plane_end_to_end(tmp_path, monkeypatch):
 # ------------------------------------------------- rung 2: cross-host wire
 
 
-def _free_port():
-    import socket
-
-    s = socket.socket()
-    s.bind(("", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 def _gw_dist(port):
     # explicit plane_port: the tests must not depend on health-port
     # derivation (and must not collide with anything else on the host)
@@ -465,7 +456,7 @@ def test_plane_gateway_round_trip():
     and the clean-stop protocol — one gateway, one client, real sockets."""
     from handyrl_tpu.runtime.plane import PlaneClient, PlaneGateway
 
-    dist = _gw_dist(_free_port())
+    dist = _gw_dist(free_port())
     received = []
     gw = PlaneGateway(dist, on_records=received.append)
     gw.start()
@@ -517,7 +508,7 @@ def test_plane_gateway_counts_actor_host_loss():
     must show (dist_actor_host_losses); the gateway keeps serving."""
     from handyrl_tpu.runtime.plane import PlaneClient, PlaneGateway
 
-    dist = _gw_dist(_free_port())
+    dist = _gw_dist(free_port())
     gw = PlaneGateway(dist, on_records=lambda r: None)
     gw.start()
     try:

@@ -155,6 +155,18 @@ def _device_pipeline(dp=2):
     pipe = DeviceBatchPipeline(targs, store, ctx, stop)
     store.extend(eps)
     pipe.start()
+    # The feeder folds the store's snapshot into the rings on its own
+    # thread, and from the second chunk on every ingest fetches the stats
+    # of the one before (DeviceReplay._account, the deferred fetch: a
+    # device_get by design, once a chunk, never on the consumer's thread).
+    # batch() can return after the first chunk, so a window armed then
+    # caught the feeder's later fetches whenever the feeder was slow (six
+    # busy workers).  No episode arrives after the snapshot here: wait for
+    # the feeder to book its one pass (put_s is written after its flush).
+    deadline = time.monotonic() + 120.0
+    while not (pipe.stats()["episodes_staged"] == len(eps) and pipe.stats()["put_s"] > 0):
+        assert time.monotonic() < deadline and not stop.is_set(), pipe.stats()
+        time.sleep(0.02)
     state = ctx.init_state(init_variables(module, env, seed=11)["params"])
     return pipe, ctx, state, stop
 

@@ -6,6 +6,7 @@ win_rate/loss/stats plotters) per SURVEY.md §2.3.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -233,3 +234,72 @@ def test_onnx_roundtrip(env_name, tmp_path):
             jax.tree.leaves(o1["hidden"]), jax.tree.leaves(o2["hidden"])
         ):
             np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+# -- the docs name only what exists --------------------------------------------
+
+DOC_FILES = ["README.md", ".claude/skills/verify/SKILL.md"] + sorted(
+    "docs/" + name for name in os.listdir(os.path.join(REPO, "docs")) if name.endswith(".md")
+)
+_TREE_PATH = re.compile(r"^(?:handyrl_tpu|tools|scripts|tests|benchmark|docs)/[\w./-]*$")
+_BARE_FILE = re.compile(r"^[\w.-]+\.(?:py|json)$")
+_COMMAND = re.compile(r"\bpython3?\s+(-m\s+)?([\w./-]+)")
+# written by a run, never committed
+RUN_TIME_FILES = {"MANIFEST.json"}
+
+
+@pytest.fixture(scope="module")
+def repo_files():
+    """What git would commit; where the checkout is no repository (the
+    chip machine's copy), what is on disk."""
+    listed = subprocess.run(["git", "ls-files"], cwd=REPO, capture_output=True, text=True)
+    if listed.returncode == 0 and listed.stdout:
+        return set(listed.stdout.split("\n")) - {""}
+    return {
+        os.path.relpath(os.path.join(folder, name), REPO)
+        for folder, _, names in os.walk(REPO) for name in names
+    }
+
+
+def _missing(text, files):
+    """Commands and backticked paths in ``text`` that name nothing in
+    ``files``: ``python <file>.py``, ``python -m <module of this repo>``,
+    a path under one of the tree's directories, and a bare ``*.py`` or
+    ``*.json`` name, which may be a file anywhere in the tree.  Globs,
+    ``<placeholders>`` and ``{a,b}`` sets are skipped."""
+    folders = {path[:end] for path in files for end in range(len(path)) if path[end] == "/"}
+    names = {os.path.basename(path) for path in files} | RUN_TIME_FILES
+
+    def exists(path):
+        return path in files or path.rstrip("/") in folders
+
+    missing = []
+    for dash_m, target in _COMMAND.findall(text):
+        if target.endswith((".", "/")):      # a placeholder follows
+            continue
+        if dash_m:
+            path = target.replace(".", "/")
+            if path.split("/")[0] in folders and not any(
+                exists(path + tail) for tail in (".py", "/__main__.py", "/__init__.py")
+            ):
+                missing.append("python -m " + target)
+        elif target.endswith(".py") and not exists(os.path.normpath(target)):
+            missing.append("python " + target)
+    for quoted in re.findall(r"`([^`\n]+)`", text):
+        for token in quoted.split():
+            if any(c in token for c in "*?[<{"):
+                continue
+            path = re.split(r"::|:\d|#", token.strip(".,;()"))[0]
+            if _TREE_PATH.match(path) and not exists(path):
+                missing.append(path)
+            elif _BARE_FILE.match(path) and path not in names:
+                missing.append(path)
+    return sorted(set(missing))
+
+
+@pytest.mark.parametrize("doc", DOC_FILES)
+def test_docs_name_only_files_and_commands_that_exist(doc, repo_files):
+    """A doc that sends a reader to a deleted script fails here (the
+    benchmark the README described for twenty-nine PRs was one)."""
+    with open(os.path.join(REPO, doc)) as f:
+        assert _missing(f.read(), repo_files) == []

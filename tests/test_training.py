@@ -340,7 +340,7 @@ def test_peak_flops_lookup():
 def test_trainer_reports_mfu_with_known_peak(monkeypatch):
     """End of the first trained epoch resolves FLOPs/update once and, when
     the chip's peak rate is known, emits an 'mfu' stat that rides into
-    metrics.jsonl (round-4: MFU is a product stat, not just a bench
+    metrics.jsonl (MFU is a product stat, not a benchmark
     extra).  A CPU run records no utilization, so the lookup is patched."""
     from handyrl_tpu.runtime.trainer import Trainer
 

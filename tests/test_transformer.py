@@ -47,7 +47,7 @@ def test_transformer_step_and_memory():
 
 def test_transformer_net_args_override():
     """env_args['net_args'] scales the family without a new env subclass
-    (the bench's MXU-saturation stage and scale configs rely on this)."""
+    (the benchmark's xfmr_d1536 configuration and the scale configs rely on this)."""
     env, module, model = _model({
         "env": "Geister", "net": "transformer",
         "net_args": {"d_model": 32, "n_heads": 2, "n_layers": 3,
@@ -84,22 +84,17 @@ def test_stateful_model_without_observation_fails_fast():
         TrainContext(env.net(), args, make_mesh(args["mesh"]))
 
 
-def test_bench_tpu_transformer_config_traces():
-    """Abstractly evaluate the EXACT train program the bench's TPU-gated
-    transformer stage compiles on-chip (whatever shape
-    bench.TRANSFORMER_TPU_NET_ARGS currently pins — d1536/L8/H16, B64,
-    T64, bf16, einsum attention as of the 2026-08-02 width sweep; the
-    flash path's kernel shapes are covered by the battery in
-    tests/test_flash_attention.py).  The stage never executes in CI, so without
-    this trace a shape bug in the big config would first surface
-    mid-capture on the chip.  eval_shape runs the full trace —
-    forward, attention, losses, grads, Adam — without lowering or
+def test_smoke_transformer_config_traces():
+    """Abstractly evaluate the train program chip_smoke.py's transformer
+    phase and the benchmark's ``xfmr_train_t64`` cell compile on the chip
+    (whatever chip_smoke.TRANSFORMER_TPU_NET_ARGS pins: d1536/L8/H16, B64,
+    T64, bf16, einsum attention; the flash path's kernel shapes are covered
+    in tests/test_flash_attention.py and tests/test_chip_compile.py).
+    Neither executes in CI, so without this trace a shape bug in the big
+    config would first surface on the chip.  eval_shape runs the full
+    trace — forward, attention, losses, grads, Adam — without lowering or
     allocating the big-net state."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    import bench
+    import chip_smoke
     from handyrl_tpu.parallel import TrainContext, make_mesh
     from handyrl_tpu.runtime import EpisodeStore, Generator, make_batch
     from handyrl_tpu.models import RandomModel
@@ -108,20 +103,19 @@ def test_bench_tpu_transformer_config_traces():
     cfg = normalize_args(
         {
             "env_args": {"env": "Geister", "net": "transformer",
-                         "net_args": bench.TRANSFORMER_TPU_NET_ARGS},
-            "train_args": dict(bench.TRANSFORMER_TPU_OVERRIDES),
+                         "net_args": chip_smoke.TRANSFORMER_TPU_NET_ARGS},
+            "train_args": dict(chip_smoke.TRANSFORMER_TPU_OVERRIDES),
         }
     )
     args = dict(cfg["train_args"])
     args["env"] = cfg["env_args"]
     env = make_env(args["env"])
     module = env.net()
-    # derived from the bench pin, not hard-coded: the whole point of this
-    # guard is to trace whatever the chip-gated stage will actually
-    # compile, so a re-pinned width must never desynchronize it again
+    # derived from the pin, not hard-coded: the guard traces whatever the
+    # chip-gated phase will compile
     assert (module.d_model, module.n_layers) == (
-        bench.TRANSFORMER_TPU_NET_ARGS["d_model"],
-        bench.TRANSFORMER_TPU_NET_ARGS["n_layers"],
+        chip_smoke.TRANSFORMER_TPU_NET_ARGS["d_model"],
+        chip_smoke.TRANSFORMER_TPU_NET_ARGS["n_layers"],
     )
 
     # abstract params/opt state: no 134M-param allocation
@@ -176,25 +170,21 @@ def test_bench_tpu_transformer_config_traces():
     assert set(metrics) >= {"p", "v", "ent", "total", "dcnt"}
 
 
-def test_bench_transformer_long_t1024_pin_traces():
-    """Abstractly evaluate the LONGEST-T program the transformer_long
-    bench stage will compile on-chip: T1024 x d1536 x L8, flash kernel
+def test_transformer_long_t1024_pin_traces():
+    """Abstractly evaluate the longest-T program the pins describe
+    (chip_smoke.TRANSFORMER_LONG_TPU): T1024 x d1536 x L8, flash kernel
     auto-picked (T >= flash_min_t), remat 'block' (what 'auto' resolves to
     on TPU at this T), bf16 compute.  Same contract as
-    test_bench_tpu_transformer_config_traces: the stage's big points are
-    chip-gated, so this trace is what keeps a shape bug from first
-    surfacing mid-capture on the chip."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    import bench
+    test_smoke_transformer_config_traces: nothing runs this shape in CI,
+    so this trace is what keeps a shape bug from first surfacing on the
+    chip."""
+    import chip_smoke
     from handyrl_tpu.models import RandomModel
     from handyrl_tpu.parallel import TrainContext, make_mesh, resolve_seq_attention
     from handyrl_tpu.runtime import EpisodeStore, Generator, make_batch
     from handyrl_tpu.utils import tree_map
 
-    pins = bench.TRANSFORMER_LONG_TPU
+    pins = chip_smoke.TRANSFORMER_LONG_TPU
     T = pins["sweep_t"][-1]
     B = pins["batch_by_t"][T]
     cfg = normalize_args(

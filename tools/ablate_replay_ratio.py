@@ -1,13 +1,13 @@
 """Replay-ratio / staleness ablation for the north-star loop (VERDICT r4 #4).
 
-The tuned northstar2 geometry re-samples each ring window ~60x
-(produce/consume 0.016 at trains_per_rollout=16 on the v5e).  The soaks
-passed in that regime, but nothing showed WHERE learning degrades as the
-ratio grows — the most load-bearing untested assumption in the perf
-story.  This tool measures it: same loop shape as the bench's northstar2
-stage (streaming on-device HungryGeese self-play -> device rings ->
-fused sample+train, self-play always under the latest params,
-bench.py:_device_replay_northstar_bench), but run for LEARNING — a fixed
+A loop that trains many times a rollout re-samples each ring window many
+times (about 60x at trains_per_rollout=16).  The soaks passed in that
+regime, but nothing showed WHERE learning degrades as the ratio grows —
+the most load-bearing untested assumption in the perf story.  This tool
+measures it: the north-star loop's shape (streaming on-device HungryGeese
+self-play -> device rings -> fused sample+train, self-play always under
+the latest params; the benchmark's `geese_loop` cell runs it through
+`Learner`), but run for LEARNING — a fixed
 budget of UPDATES per configuration, win rate vs random evaluated every
 ``eval_every`` updates through DeviceEvaluator, so the curves are
 win-rate-vs-updates at trains_per_rollout in {1, 4, 16, 64}.
@@ -15,7 +15,7 @@ win-rate-vs-updates at trains_per_rollout in {1, 4, 16, 64}.
 Higher trains_per_rollout = less fresh data per update = higher
 effective replay ratio/staleness.  If the 64 curve tracks the 1 curve,
 the V-Trace/UPGO off-policy corrections are carrying the regime; where
-it sags is the measured staleness limit, and the bench default must sit
+it sags is the measured staleness limit, and a shipped default must sit
 below it.  Off-policy corrections anchor: reference train.py:230-239.
 
 CPU mesh is fine (the ratio is a data-freshness property, not a device

@@ -1,10 +1,9 @@
 """Measure the REFERENCE's generation strategy on HungryGeese, on this host.
 
 BASELINE.md's 1,557 env-steps/s generation row is TicTacToe (tiny net,
-9-step episodes); bench.py's geese_gen stage was being divided by it,
-which made the host actor plane look 5x slower than the reference when it
-is actually ~3.6x faster like-for-like.  This tool produces the missing
-like-for-like number: the reference's generation loop shape — ONE
+9-step episodes); a HungryGeese rate divided by it makes the host actor
+plane look several times slower than the reference than it is.  This
+tool produces the like-for-like number: the reference's generation loop shape — ONE
 batch-1 torch inference per ACTIVE player per step, single process
 (reference generation.py:20-93 driving ModelWrapper model.py:50-60) —
 using the reference's OWN torch GeeseNet (imported from
@@ -12,8 +11,7 @@ using the reference's OWN torch GeeseNet (imported from
 kaggle_environments dependency stubbed; the net class itself has no
 kaggle dependency), stepping the same 7x11 torus rules.
 
-Recorded in BASELINE.md and used as bench.py's
-REFERENCE_GEESE_GEN_STEPS_PER_SEC denominator.
+Recorded in BASELINE.md.
 
 Usage: python tools/reference_geese_gen.py [seconds]
 """
