@@ -449,6 +449,25 @@ def _geese_setup(batch_size):
     return args, module, batch, params
 
 
+def _sections_setup(batch_size):
+    """A transformer small enough to compile in seconds whose mlp kernels
+    (2^18 elements at d256) ride the gradient ring: on a dp mesh its
+    sections sum their own gradient (mesh.sum_section_grads)."""
+    from __graft_entry__ import _tiny_batch
+    from handyrl_tpu.config import normalize_args
+
+    cfg = normalize_args({
+        "env_args": {"env": "TicTacToe", "net": "transformer",
+                     "net_args": {"d_model": 256, "n_heads": 4, "n_layers": 2}},
+        "train_args": {"observation": True, "burn_in_steps": 2, "forward_steps": 4,
+                       "compress_steps": 4, "batch_size": batch_size,
+                       "compute_dtype": "bfloat16"},
+    })
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    module, batch, params = _tiny_batch(args, batch_size)
+    return args, module, batch, params
+
+
 def _assert_spans(tree, n, what):
     import jax
 
@@ -456,15 +475,26 @@ def _assert_spans(tree, n, what):
     assert sizes == {n}, f"{what}: arrays span {sizes} devices, not {n}"
 
 
-def phase_dp_train_step(sizes):
-    """One GeeseNet train step on a dp=4 mesh against the same batch and
-    params on a one-device mesh."""
+def _assert_same_on_every_chip(tree, what):
+    """Replicated arrays hold the same bits on every chip."""
+    import jax
+    import numpy as np
+
+    for x in jax.tree.leaves(tree):
+        first, *rest = (np.asarray(s.data) for s in x.addressable_shards)
+        assert all((r == first).all() for r in rest), f"{what}: replicas differ"
+
+
+def phase_dp_train_step(sizes, setup=_geese_setup):
+    """One train step on a dp=4 mesh against the same batch and params on
+    a one-device mesh: GeeseNet (GSPMD sums its gradient), or with
+    ``_sections_setup`` a transformer whose sections ring their own."""
     import jax
     import numpy as np
 
     from handyrl_tpu.parallel import TrainContext, make_mesh
 
-    args, module, batch, params = _geese_setup(sizes["batch_size"])
+    args, module, batch, params = setup(sizes["batch_size"])
     lr, out = 1e-4, {}
     for dp in (4, 1):
         ctx = TrainContext(module, args, make_mesh({"dp": dp}))
@@ -478,6 +508,8 @@ def phase_dp_train_step(sizes):
         state, metrics = ctx.train_step(state, device_batch, lr)
         if dp > 1:
             _assert_spans(state, dp, "dp train step outputs")
+            _assert_same_on_every_chip(state["params"], "dp train step params")
+            grad_sync = ctx.grad_sync
         m = jax.device_get(metrics)
         out[dp] = (float(m["total"]) / float(m["dcnt"]),
                    jax.device_get(state["params"]))
@@ -493,7 +525,8 @@ def phase_dp_train_step(sizes):
     flipped = float((diff > 0.1 * lr).mean())
     assert flipped < 1e-2, f"{flipped:.2%} of the params disagree past 0.1 lr"
     return {"loss_dp4": round(loss4, 6), "loss_dp1": round(loss1, 6),
-            "params_max_diff": float(diff.max()), "params_flipped_frac": flipped}
+            "params_max_diff": float(diff.max()), "params_flipped_frac": flipped,
+            "grad_sync": grad_sync}
 
 
 def phase_dp_rollout_replay(sizes):
@@ -537,6 +570,8 @@ def phase_dp_rollout_replay(sizes):
         state, metrics = train(ctx.init_state(params), jax.random.PRNGKey(5), 1e-4)
         m = jax.device_get(metrics)
         assert np.isfinite(m["total"]) and m["dcnt"] > 0, m
+        if dp > 1:
+            _assert_same_on_every_chip(state["params"], "dp replay train params")
         out[dp] = {"episodes": int(replay.counters["episodes"]),
                    "eligible": int(eligible),
                    "ent": float(m["ent"]) / float(m["dcnt"]),
@@ -653,6 +688,7 @@ def main(argv=None) -> int:
     if opts.multichip:
         phases = {
             "dp-train-step": lambda r: phase_dp_train_step(SIZES["dp"]),
+            "dp-section-ring": lambda r: phase_dp_train_step(SIZES["dp"], _sections_setup),
             "dp-rollout-replay": lambda r: phase_dp_rollout_replay(SIZES["dp"]),
             "ring-attention": lambda r: phase_ring_attention(SIZES["ring"], opts.seed),
         }

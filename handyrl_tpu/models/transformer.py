@@ -145,6 +145,26 @@ class CachedSelfAttention(nn.Module):
         return nn.Dense(self.d_model, name="o")(out.reshape(B, T, H * Dh)), None
 
 
+def _section(fn, names, sum_grads):
+    """``fn(mdl, x, token) -> (y, token)`` as a lifted custom-VJP section
+    that sums its own parameter gradient: the backward rule runs ``fn``'s
+    backward pass and hands the cotangents of the submodules ``names``,
+    ``x``'s cotangent and the token's to ``sum_grads`` (parallel/mesh.py
+    ``sum_section_grads``: a sum over the data-parallel chips, ordered
+    against the backward pass by the token), which returns all three."""
+
+    def forward(mdl, x, token):
+        return nn.vjp(fn, mdl, x, token)
+
+    def backward(vjp_fn, cts):
+        grads, x_t, _ = vjp_fn(cts)
+        own = grads["params"]
+        summed, x_t, token_t = sum_grads({k: own[k] for k in names if k in own}, x_t, cts[1])
+        return {"params": {**own, **summed}}, x_t, token_t
+
+    return nn.custom_vjp(fn, forward_fn=forward, backward_fn=backward)
+
+
 class TransformerNet(nn.Module):
     """Generic memory-transformer policy/value net.
 
@@ -166,9 +186,26 @@ class TransformerNet(nn.Module):
     def __call__(self, obs, hidden=None, train: bool = False, *,
                  seq: bool = False, key_mask=None, burn_in: int = 0,
                  use_flash: bool = False, ring_mesh=None,
-                 remat: str = "none", blk_q: int = 128, blk_k: int = 128):
+                 remat: str = "none", blk_q: int = 128, blk_k: int = 128,
+                 sum_grads=None):
+        # sections of the seq forward: plain calls, or (sum_grads given, a
+        # data-parallel train step's) each one summing its own gradient,
+        # with a token threaded through them for sum_grads to order by
+        token = None if sum_grads is None else jnp.zeros((), jnp.float32)
+
+        def section(fn, names, x):
+            nonlocal token
+            if sum_grads is None:
+                return fn(self, x, None)[0]
+            x, token = _section(fn, names, sum_grads)(self, x, token)
+            return x
+
+        def encode(mdl, flat, tok):
+            x = nn.relu(nn.Dense(self.d_model, name="enc1")(flat))
+            return nn.Dense(self.d_model, name="enc2")(x), tok
+
         if seq:
-            x = nn.relu(nn.Dense(self.d_model, name="enc1")(_flatten_obs(obs, 2)))
+            x = section(encode, ("enc1", "enc2"), _flatten_obs(obs, 2))
             slot = count = None
         else:
             if hidden is None:
@@ -178,7 +215,7 @@ class TransformerNet(nn.Module):
             pos = hidden["pos"]                 # float32 (B,): scan-carry safe
             count = jnp.minimum(pos + 1, self.memory_len).astype(jnp.int32)
             slot = jnp.mod(pos, float(self.memory_len)).astype(jnp.int32)
-        x = nn.Dense(self.d_model, name="enc2")(x)
+            x = nn.Dense(self.d_model, name="enc2")(x)
 
         # selective-remat ladder (seq mode only; config: train_args.remat):
         #   none  — store every activation (fastest backward, most HBM);
@@ -240,26 +277,35 @@ class TransformerNet(nn.Module):
                 )
                 x = mlp_half(self, x + a)
                 new_layers.append(new_cache)
-            elif remat == "block":
-                x = nn.remat(block_fn, policy=pol)(self, x, key_mask)
-                new_layers.append(None)
-            elif remat == "attn":
-                h = nn.LayerNorm(name=f"ln_a{i}")(x)
-                x = mlp_half(self, x + nn.remat(attn_sub, policy=pol)(self, h, key_mask))
-                new_layers.append(None)
             else:
-                x = block_fn(self, x, key_mask)
+                def block(mdl, x, tok, i=i):
+                    if remat == "block":
+                        x = nn.remat(block_fn, policy=pol)(mdl, x, key_mask)
+                    elif remat == "attn":
+                        h = nn.LayerNorm(name=f"ln_a{i}")(x)
+                        x = mlp_half(mdl, x + nn.remat(attn_sub, policy=pol)(mdl, h, key_mask))
+                    else:
+                        x = block_fn(mdl, x, key_mask)
+                    return x, tok
+
+                own = tuple(f"{part}{i}" for part in ("ln_a", "attn", "ln_m", "mlp_up", "mlp_dn"))
+                x = section(block, own, x)
                 new_layers.append(None)
 
-        h = nn.LayerNorm(name="ln_f")(x)
-        out: Dict[str, Any] = {
-            "policy": nn.Dense(self.num_actions, name="policy")(h),
-            "value": jnp.tanh(nn.Dense(1, name="value")(h)),
-        }
-        if not seq:
-            out["hidden"] = {"layers": tuple(new_layers), "pos": hidden["pos"] + 1.0}
-        if self.with_return:
-            out["return"] = nn.Dense(1, name="return_head")(h)
+        def heads(mdl, x, tok):
+            h = nn.LayerNorm(name="ln_f")(x)
+            out: Dict[str, Any] = {
+                "policy": nn.Dense(self.num_actions, name="policy")(h),
+                "value": jnp.tanh(nn.Dense(1, name="value")(h)),
+            }
+            if self.with_return:
+                out["return"] = nn.Dense(1, name="return_head")(h)
+            return out, tok
+
+        if seq:
+            return section(heads, ("ln_f", "policy", "value", "return_head"), x)
+        out = heads(self, x, None)[0]
+        out["hidden"] = {"layers": tuple(new_layers), "pos": hidden["pos"] + 1.0}
         return out
 
     @nn.nowrap

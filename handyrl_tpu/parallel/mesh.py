@@ -2,13 +2,21 @@
 
 The gradient/parameter plane of the framework: where the reference used
 ``nn.DataParallel`` over local GPUs (train.py:340-341), we lay devices out
-in a named ``jax.sharding.Mesh`` and let XLA insert the collectives (psum
-over ICI for gradients).  Axes:
+in a named ``jax.sharding.Mesh``.  Axes:
 
 * ``dp`` — data parallel: batches shard along axis 0, params replicated.
+  XLA/GSPMD infers the gradient's sum over ``dp`` from that layout, with
+  one exception: a net that runs its backward pass in sections
+  (``TransformerNet``'s whole-window path), on a mesh whose only axis
+  larger than 1 is ``dp``.  There the train step differentiates under
+  ``shard_map`` and each section hands its gradient to
+  ``sum_section_grads``: the large leaves go round a ring of ``ppermute``s
+  that runs under the next section's backward compute, the small ones
+  take a ``psum``.
 * further axes (e.g. ``mp``) can be added through the config
   ``train_args.mesh`` dict without touching the train step: params/batch
-  shardings are derived from the mesh axis names.
+  shardings are derived from the mesh axis names, and XLA/GSPMD inserts
+  the collectives the layout implies (gradients included).
 
 Multi-host: under ``jax.distributed`` initialization the same code spans
 hosts — ``jax.devices()`` returns the global device list and XLA routes
@@ -19,9 +27,11 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..utils.trace import trace_span
@@ -128,8 +138,6 @@ def make_mesh(spec: Optional[Dict[str, int]] = None, devices: Optional[Sequence]
         sizes = tuple(spec.values())
     if math.prod(sizes) > n:
         raise ValueError(f"mesh {dict(zip(spec, sizes))} needs more than {n} devices")
-    import numpy as np
-
     return Mesh(np.asarray(devices[: math.prod(sizes)]).reshape(sizes), tuple(spec.keys()))
 
 
@@ -202,6 +210,171 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
+
+
+# A gradient leaf with fewer elements than this is summed by ``psum`` (XLA
+# combines those into a few all-reduces); a larger one rides the ring's
+# buffer (``sum_grads``).  At d1536 this sends every kernel but the scalar
+# heads and the 270-row encoder round the ring (PERF.md, PR 31).
+RING_MIN_ELEMENTS = 1 << 18
+
+
+def grad_sync_axis(mesh: Mesh) -> Optional[str]:
+    """``'dp'`` where a train step may sum its gradient over ``dp`` itself
+    (``sum_section_grads`` under ``shard_map``): a mesh whose only axis
+    larger than 1 is ``dp``.  None elsewhere: one device has nothing to
+    sum, and with an ``mp`` or ``sp`` axis the gradient's collectives are
+    not a plain sum over one axis, so GSPMD keeps inferring them from the
+    layout."""
+    sizes = dict(mesh.shape)
+    if sizes.pop("dp", 1) > 1 and all(s == 1 for s in sizes.values()):
+        return "dp"
+    return None
+
+
+def _rides_ring(x, n: int) -> bool:
+    return x.ndim >= 2 and x.shape[0] % n == 0 and x.size >= RING_MIN_ELEMENTS
+
+
+def ring_order(mesh: Mesh) -> Tuple[int, ...]:
+    """The positions of a dp-only mesh in the order the gradient ring
+    visits them.  Devices that say where they sit (a TPU's ``coords``) are
+    walked boustrophedon, row by row and every other row backwards, so
+    that each hop is one ICI link and the two ways round share none (v5e
+    2x2: 0, 1, 3, 2; in mesh order two hops of four cross the diagonal,
+    over links the other way round needs).  Devices that do not (the
+    CPU's) keep the mesh's order."""
+    devices = list(mesh.devices.flat)
+    if not all(hasattr(d, "coords") for d in devices):
+        return tuple(range(len(devices)))
+
+    def snake(i):
+        x, *rest = devices[i].coords
+        return tuple(reversed(rest)) + (x if sum(rest) % 2 == 0 else -x,)
+
+    return tuple(sorted(range(len(devices)), key=snake))
+
+
+def _after(x, number):
+    """``x``, to be had only once ``number`` is: a select on it.  (Not an
+    ``optimization_barrier``: jax prunes a barrier's unused outputs, and
+    with them the wait.)  ``number`` is a gradient's, finite in any step
+    the sentinel lets through; where it is not, ``x`` is NaN too."""
+    return jnp.where(jnp.isfinite(number), x, jnp.nan)
+
+
+def _ring_sum(x, axis: str, order: Sequence[int]):
+    """Sum ``x`` (one chunk per chip along its leading axis) over mesh axis
+    ``axis`` round the ring ``order`` (mesh positions, see ``ring_order``),
+    inside ``shard_map``: ``n - 1`` hops of ``ppermute`` + add leave the
+    chip at ring position ``i`` holding the whole sum of chunk ``i + 1``,
+    ``n - 1`` more hand the summed chunks round.  The second round ships
+    the summed chunk itself, never a local re-sum, so every chip ends with
+    the same bits.  The two halves of a chunk's rows go round opposite
+    ways in step, over both directions of the links.  Each hop is an async
+    collective-permute, which the TPU runs under compute that does not
+    depend on it; an all-reduce holds the core."""
+    n, rows = len(order), x.shape[1]
+    place = np.empty(n, np.int32)
+    place[np.asarray(order)] = np.arange(n)
+    me = jnp.asarray(place)[jax.lax.axis_index(axis)]
+    ways = ((slice(0, rows // 2), 1), (slice(rows // 2, rows), -1)) if rows > 1 else (
+        (slice(0, rows), 1),)
+    perms = [[(order[i], order[(i + step) % n]) for i in range(n)] for _, step in ways]
+
+    def chunks(k):
+        return [
+            jax.lax.dynamic_index_in_dim(x, (me + step * k) % n, 0, keepdims=False)[part]
+            for part, step in ways
+        ]
+
+    def hop(accs):
+        return [jax.lax.ppermute(a, axis, perm) for a, perm in zip(accs, perms)]
+
+    accs = chunks(0)
+    for k in range(1, n):
+        accs = [a + c for a, c in zip(hop(accs), chunks(-k))]
+    # accs are the sums of chunk me + step; hand them round, each into its place
+    out = jnp.zeros_like(x)
+    for k in range(n):
+        if k:
+            accs = hop(accs)
+        for acc, (part, step) in zip(accs, ways):
+            at = ((me + step * (1 - k)) % n, part.start) + (0,) * (x.ndim - 2)
+            out = jax.lax.dynamic_update_slice(out, acc[None], at)
+    return out
+
+
+def grad_sync_counts(tree, n: int) -> Dict[str, int]:
+    """What crosses an axis of size ``n`` which way when ``sum_grads`` sums
+    ``tree``'s gradient: leaves and bytes (of ``tree``'s dtypes) round the
+    ring and by psum."""
+    counts = {"ring_leaves": 0, "ring_bytes": 0, "psum_leaves": 0, "psum_bytes": 0}
+    for x in jax.tree.leaves(tree):
+        way = "ring" if _rides_ring(x, n) else "psum"
+        counts[way + "_leaves"] += 1
+        counts[way + "_bytes"] += x.size * x.dtype.itemsize
+    return counts
+
+
+def sum_grads(grads, axis: str, order: Sequence[int]):
+    """Sum a tree of gradients over mesh axis ``axis`` (``order``: see
+    ``ring_order``), inside ``shard_map``.  The leaves with at least two
+    dimensions, a leading dimension the axis size divides and
+    ``RING_MIN_ELEMENTS`` elements go round the ring in ONE chain of large
+    hops: leaves of one row shape and dtype share a buffer (chunk ``i`` of
+    it is every such leaf's rows ``i``, put together along the rows, so no
+    leaf changes its tiling), and the next buffer's ring starts when the
+    one before is done.  With one chain, the
+    only work the scheduler finds to run between a hop's start and its
+    done is the backward pass; a chain per leaf gives it the other chains'
+    slices and adds, and it hides nothing under them.  Every other leaf
+    takes one ``lax.psum``, which XLA combines."""
+    n = len(order)
+    leaves, treedef = jax.tree.flatten(grads)
+    buffers: Dict[tuple, list] = {}
+    for i, g in enumerate(leaves):
+        if _rides_ring(g, n):
+            buffers.setdefault((g.shape[1:], g.dtype), []).append(i)
+        else:
+            leaves[i] = jax.lax.psum(g, axis)
+    done = None
+    for members in buffers.values():
+        buffer = jnp.concatenate(
+            [leaves[i].reshape((n, -1) + leaves[i].shape[1:]) for i in members], axis=1
+        )
+        if done is not None:
+            buffer = _after(buffer, done)       # the chain's link
+        buffer = _ring_sum(buffer, axis, order)
+        done = buffer.reshape(-1)[0]
+        at = 0
+        for i in members:
+            rows = leaves[i].shape[0] // n
+            leaves[i] = buffer[:, at:at + rows].reshape(leaves[i].shape)
+            at += rows
+    return jax.tree.unflatten(treedef, leaves)
+
+
+def sum_section_grads(grads, x_ct, token, axis: str, order: Sequence[int]):
+    """The collective part of one section's backward rule, for a net that
+    runs its backward pass in sections (``models/transformer.py``
+    ``_section``), inside ``shard_map``: ``sum_grads`` on the section's
+    parameter cotangents ``grads``, ordered against the backward pass by a
+    token.  ``token`` is the one the section nearer the loss made, and
+    ``x_ct`` (this section's activation cotangent) goes on to the section
+    before only with it in hand; the token returned is a number that
+    exists once every kernel's sum here does.  So each section's sums have
+    exactly the next section's backward pass to run under, and the
+    scheduler cannot push them all behind the last one (which it does
+    when nothing but the optimizer waits for them).
+    Returns ``(summed grads, x_ct, token)``."""
+    x_ct = _after(x_ct, token)
+    summed = sum_grads(grads, axis, order)
+    done = sum(
+        (g.reshape(-1)[0].astype(token.dtype) for g in jax.tree.leaves(summed) if g.ndim >= 2),
+        jnp.zeros_like(token),
+    )
+    return summed, x_ct, done
 
 
 def param_shardings(mesh: Mesh, params):

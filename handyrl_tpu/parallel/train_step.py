@@ -10,7 +10,11 @@ This is the TPU replacement for the reference's host-side training loop
 
 under ``jax.jit`` with NamedShardings: the batch is sharded over the 'dp'
 mesh axis, params/optimizer state replicated; XLA inserts the gradient
-all-reduce over ICI.  The learning rate is a scalar argument (the
+all-reduce over ICI.  One exception: a net that sections its backward pass
+(``TransformerNet``'s whole-window path) on a dp-only mesh.  There the step
+differentiates under ``shard_map`` and each section rings its own gradient
+round 'dp' under the next section's backward compute
+(``mesh.sum_section_grads``).  The learning rate is a scalar argument (the
 reference's data-count-EMA schedule, train.py:328-332/383-385, is computed
 on host per epoch).
 
@@ -28,17 +32,30 @@ Forward-prediction semantics parity (train.py:128-187):
 from __future__ import annotations
 
 
+import functools
+import inspect
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ops import compute_loss_from_outputs
 from ..utils import tree_map
-from .mesh import batch_sharding, dispatch_serialized, param_shardings, replicated_sharding
+from ..utils.trace import trace_event
+from .mesh import (
+    batch_sharding,
+    dispatch_serialized,
+    grad_sync_axis,
+    grad_sync_counts,
+    param_shardings,
+    replicated_sharding,
+    ring_order,
+    sum_section_grads,
+)
 
 
 def _flat_apply(module, params, obs, lead_shape):
@@ -128,9 +145,33 @@ def resolve_seq_remat(args: Dict[str, Any], T: int) -> str:
     return "block" if jax.default_backend() == "tpu" and T >= 512 else "none"
 
 
-def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, Any]) -> Dict[str, Any]:
+def whole_window(module, args: Dict[str, Any]) -> bool:
+    """Whether ``forward_prediction`` runs a recurrent net over the whole
+    window in one call (``seq=True``) and not step by step in a scan."""
+    return bool(
+        module.initial_state((1, 1)) is not None
+        and getattr(module, "supports_seq", False)
+        and args.get("seq_forward", True)
+    )
+
+
+def sums_own_grads(module, args: Dict[str, Any]) -> bool:
+    """Whether ``forward_prediction`` can hand the net a ``sum_grads``:
+    its whole-window call does, to a net whose ``__call__`` takes one."""
+    return whole_window(module, args) and (
+        "sum_grads" in inspect.signature(module.__call__).parameters
+    )
+
+
+def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, Any],
+                       sum_grads=None) -> Dict[str, Any]:
     """Run the net over a (B, T, P, ...) batch; returns post-burn-in outputs
     of length forward_steps, already turn/action/observation masked.
+
+    ``sum_grads`` (only where ``sums_own_grads``; refused elsewhere, where
+    no one would sum the gradient): the sum over the data-parallel chips
+    that the net applies to its own parameter gradient, section by section
+    inside the backward pass (``mesh.sum_section_grads``).
 
     With ``compute_dtype: bfloat16`` the forward runs in bf16 (params are
     cast by the caller; observations/hidden here) — MXU-rate compute with
@@ -155,6 +196,9 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
     B, T, P1 = batch["action"].shape[:3]
     burn_in = args["burn_in_steps"]
     hidden0 = module.initial_state((B, P1))
+    seq = whole_window(module, args)
+    if sum_grads is not None and not seq:
+        raise ValueError("sum_grads given, but only the whole-window path takes it")
 
     if hidden0 is None:
         # Feed-forward compaction: put_batch may have sliced the observation
@@ -173,7 +217,7 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
                 for k, v in outputs.items()
             }
         outputs = {k: v[:, burn_in:] for k, v in outputs.items()}
-    elif getattr(module, "supports_seq", False) and args.get("seq_forward", True):
+    elif seq:
         # whole-window attention path: one batched call instead of a T-step
         # scan — the masks reproduce the KV-cache semantics exactly (see
         # CachedSelfAttention seq mode), so values match the scan path.
@@ -205,6 +249,7 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
             burn_in=burn_in, use_flash=mode == "flash", ring_mesh=ring_mesh,
             remat=resolve_seq_remat(args, T),
             blk_q=int(args.get("blk_q", 128)), blk_k=int(args.get("blk_k", 128)),
+            **({"sum_grads": sum_grads} if sum_grads is not None else {}),
         )
         outputs = {
             k: jnp.moveaxis(v.reshape((B, P1, T) + v.shape[2:]), 1, 2)[:, burn_in:]
@@ -435,12 +480,30 @@ class TrainContext:
                     file=sys.stderr,
                 )
 
+        # where the net sums its own gradient over 'dp', section by section
+        # (a dp-only mesh, see mesh.grad_sync_axis, and a net that takes
+        # sum_grads); elsewhere None, and GSPMD infers the sum.  What went
+        # round the ring and what took a psum is counted when the step is
+        # first traced
+        sync_axis = grad_sync_axis(mesh) if sums_own_grads(module, self.args) else None
+        sync_sum = None
+        if sync_axis is not None:
+            sync_sum = functools.partial(
+                sum_section_grads, axis=sync_axis, order=ring_order(mesh)
+            )
+        self._sync_axis = sync_axis
+        self.grad_sync: Optional[Dict[str, int]] = None
+
         def _loss_fn(params, batch):
             # mixed precision: bf16 copies feed the forward, fp32 master
             # params stay in the optimizer; grads come back fp32 through
             # the cast's vjp
             fwd_params = params if cdt is None else _cast_floats(params, cdt)
-            outputs = forward_prediction(self.module, fwd_params, batch, self.args)
+            if sync_axis is not None and self.grad_sync is None:
+                # of the forward copy: the sum crosses the ICI in its dtype
+                self.grad_sync = grad_sync_counts(fwd_params, mesh.shape[sync_axis])
+                trace_event("train.grad_sync", 0.0, plane="learner", **self.grad_sync)
+            outputs = forward_prediction(self.module, fwd_params, batch, self.args, sync_sum)
             trimmed = trim_burn_in(batch, self.args["burn_in_steps"])
             losses, dcnt = compute_loss_from_outputs(outputs, trimmed, self.args)
             full = {k: losses.get(k, jnp.zeros(())) for k in loss_keys}
@@ -456,10 +519,26 @@ class TrainContext:
         # and escalates a long bad streak to a verified-checkpoint rollback.
         sentinel = bool(args.get("sentinel", True))
 
-        def _step(state, batch, lr):
-            (loss, (losses, dcnt)), grads = jax.value_and_grad(_loss_fn, has_aux=True)(
-                state["params"], batch
+        _grad_fn = jax.value_and_grad(_loss_fn, has_aux=True)
+        if sync_axis is not None:
+            _local_grad = _grad_fn
+
+            def _summed(params, batch):
+                # each chip differentiates its own rows; the gradient
+                # leaves come back already summed (inside the net's
+                # backward pass), the loss sums and the data count here
+                out, grads = _local_grad(params, batch)
+                return jax.lax.psum(out, sync_axis), grads
+
+            # check_vma off: the sums are spelled out above, so nothing is
+            # to be inferred from (or inserted for) the replicated params
+            _grad_fn = shard_map(
+                _summed, mesh=mesh, in_specs=(PartitionSpec(), PartitionSpec(sync_axis)),
+                out_specs=PartitionSpec(), check_vma=False,
             )
+
+        def _step(state, batch, lr):
+            (loss, (losses, dcnt)), grads = _grad_fn(state["params"], batch)
 
             def _apply(_):
                 updates, opt_state = self.tx.update(
@@ -496,8 +575,8 @@ class TrainContext:
         # sharding follows the data: params/opt_state enter laid out by
         # init_state (replicated, or 'mp'-sharded kernels when the mesh has
         # a tensor-parallel axis), the batch enters 'dp'-sharded, and GSPMD
-        # propagates — the gradient all-reduce over ICI falls out of the
-        # layout rather than being spelled as explicit collectives.  The
+        # propagates — outside the dp-only shard_map above, collectives
+        # fall out of the layout rather than being spelled out.  The
         # state shardings are pinned on BOTH sides of the jit (bound lazily
         # on the first state, _bind): without out_shardings the first call
         # compiles against init_state's layout, returns compiler-chosen
@@ -676,7 +755,9 @@ class TrainContext:
         lowered = self._bind(state).lower(state, device_batch, jnp.float32(1e-5))
         flops = float((lowered.cost_analysis() or {}).get("flops", 0.0))
         if flops > 0:
-            return flops
+            # under the shard_map the lowering holds one chip's rows of
+            # the forward and backward pass
+            return flops * (self.mesh.shape[self._sync_axis] if self._sync_axis else 1)
         jaxpr = jax.make_jaxpr(self._step_fn)(state, device_batch, jnp.float32(1e-5))
         return jaxpr_flops(jaxpr.jaxpr)
 
@@ -734,8 +815,9 @@ def hbm_bandwidth_per_chip(device) -> float:
 def jaxpr_flops(jaxpr) -> float:
     """Backend-free analytic flop count of a jaxpr: 2*MACs for every
     ``dot_general`` and ``conv_general_dilated``, recursing through
-    higher-order primitives (scan multiplied by trip count, cond counted
-    at its widest branch, while bodies once).  Elementwise/reduction ops
+    higher-order primitives (scan multiplied by trip count, shard_map by
+    the chips its body runs on, cond counted at its widest branch, while
+    bodies once).  Elementwise/reduction ops
     are ignored — matmul/conv dominate the HLO count this substitutes for
     (flops_per_step's fallback, and the device-replay step's counter).  Tends to overestimate slightly (XLA simplifies some convs
     away): measured 1.15x XLA:CPU's HLO 'flops' on the GeeseNet train
@@ -781,6 +863,11 @@ def jaxpr_flops(jaxpr) -> float:
                 continue
             if p == "scan":
                 mult = float(eqn.params.get("length", 1))
+                total += mult * sum(jaxpr_flops(s) for s in subs)
+            elif p == "shard_map":
+                # the body holds one chip's rows
+                sizes = eqn.params["mesh"].shape
+                mult = float(_np.prod([sizes[a] for a in eqn.params["manual_axes"]]))
                 total += mult * sum(jaxpr_flops(s) for s in subs)
             elif p == "cond":
                 total += max(jaxpr_flops(s) for s in subs)

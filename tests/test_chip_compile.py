@@ -1,14 +1,18 @@
-"""Both Pallas attention kernels, compiled for a described (not attached)
-TPU v5e by the chip's own compiler, at the shapes chip_smoke.py pins.
+"""Both Pallas attention kernels, and the d1536 train step on a {dp: 4}
+mesh, compiled for a described (not attached) TPU v5e by the chip's own
+compiler, at the shapes chip_smoke.py pins.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (a slice
 not aligned to the tiling, too much VMEM); these compiles can, at about
 two seconds each and no chip time.  Nothing executes, so this checks that
 the kernel is IN the program (``tpu_custom_call``) and that its forward
-and its custom-VJP backward compile — not results, not times.
+and its custom-VJP backward compile — not results, not times.  The dp
+step's compile shows the schedule the chip will run: which collectives are in it and what runs between a
+collective-permute's start and its done.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -38,14 +42,18 @@ UNALIGNED = (8, 200, 16, 96)
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_2x2():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # no libtpu in this installation
         pytest.skip(f"cannot describe a v5e topology here: {exc}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -97,3 +105,141 @@ def test_kernel_compiles_for_v5e(v5e, kernel, shape, mode):
     assert "tpu_custom_call" in compiled.as_text(), (
         "the Pallas kernel is not in the compiled program"
     )
+
+
+# -- the d1536 train step on a described {dp: N} mesh ----------------------
+
+def _lowered_step(topo, dp, batch_size, n_layers=2):
+    """The cell's train step (xfmr_train_t64*: d1536, T64, bf16, einsum) at
+    ``n_layers`` blocks, lowered for a {dp: dp} mesh of the described
+    chips from shapes alone.  Returns (context, lowered)."""
+    import random
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from benchmark import traffic
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.envs import make_env
+    from handyrl_tpu.parallel import TrainContext, make_mesh, param_shardings
+
+    cfg = normalize_args({
+        "env_args": {"env": "Geister", "net": "transformer",
+                     "net_args": dict(_NET, n_layers=n_layers)},
+        "train_args": dict(_STEP, batch_size=batch_size, seq_attention="einsum"),
+    })
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    env = make_env(args["env"])
+    module = env.net()
+    mesh = make_mesh({"dp": dp}, devices=topo.devices)
+    ctx = TrainContext(module, args, mesh)
+    rows, rep = NamedSharding(mesh, PartitionSpec("dp")), NamedSharding(mesh, PartitionSpec())
+
+    env.reset()
+    obs = jax.tree.map(lambda x: jnp.asarray(x)[None], env.observation(env.players()[0]))
+    params = jax.eval_shape(
+        lambda key: module.init(key, obs, module.initial_state((1,)))["params"],
+        jax.random.PRNGKey(0),
+    )
+    state = {"params": params, "opt_state": jax.eval_shape(ctx.tx.init, params),
+             "steps": jax.ShapeDtypeStruct((), jnp.int32)}
+    layout = param_shardings(mesh, state)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), state, layout
+    )
+    # a two-window batch of random play gives every leaf's shape and dtype
+    random.seed(0)
+    np.random.seed(0)
+    small = traffic.random_play_batches(env, module, dict(args, batch_size=2), 1, 4)[0]
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (batch_size,) + np.shape(x)[1:], np.asarray(x).dtype, sharding=rows),
+        small,
+    )
+    lowered = jax.jit(
+        ctx._step_fn, donate_argnums=(0,),
+        in_shardings=(layout, rows, rep), out_shardings=(layout, rep),
+    ).lower(state, batch, jax.ShapeDtypeStruct((), jnp.float32, sharding=rep))
+    return ctx, lowered
+
+
+def _entry_ops(hlo_text):
+    """The entry computation's instructions, in schedule order."""
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo_text, re.S | re.M).group(1)
+    return [line.strip() for line in body.splitlines() if " = " in line]
+
+
+def _bytes(shape_text):
+    """Bytes of every array in an HLO result type, tuples included."""
+    widths = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
+    total = 0
+    for dtype, dims in re.findall(r"\b(bf16|f16|f32|s32|u32|pred|s8|u8)\[([\d,]*)\]", shape_text):
+        count = 1
+        for d in filter(None, dims.split(",")):
+            count *= int(d)
+        total += count * widths[dtype]
+    return total
+
+
+def test_dp4_step_rings_its_gradient_under_compute(v5e_2x2):
+    """{dp: 4}: no all-reduce over 2 MB is left in the program; every ring
+    hop's collective-permute has ops scheduled between its start and its
+    done; and the hops sit INSIDE the backward pass, where the chip's trace
+    measured them (PERF.md, PR 31): a section's backward pass starts only
+    once every hop of the sections two or more nearer the loss is done, so
+    each section's ring has the next section's backward pass to run under.
+    (With nothing in the backward pass waiting for a sum, or with the wait
+    folded away, the scheduler runs every hop behind the last backward op,
+    and the chip's trace shows them all exposed there.)"""
+    n_layers = 3
+    ctx, lowered = _lowered_step(
+        v5e_2x2, dp=4, batch_size=4 * _STEP["batch_size"], n_layers=n_layers
+    )
+    assert ctx.grad_sync["ring_leaves"] == n_layers * 6 + 2    # the blocks, enc2, policy
+    ops = _entry_ops(lowered.compile().as_text())
+    started, gaps, done_at, backward_from = {}, [], [], {}
+    for at, op in enumerate(ops):
+        name = re.match(r"(?:ROOT )?%?([\w.\-]+) = ", op).group(1)
+        if " collective-permute-start(" in op:
+            started[name] = at
+        elif " collective-permute-done(" in op:
+            source = re.search(r"collective-permute-done\([^%]*%([\w.\-]+)", op).group(1)
+            gaps.append(at - started[source])
+            done_at.append(at)
+        elif re.search(r" all-reduce(-start)?\(", op):
+            result_type = op.partition(" = ")[2].partition(" all-reduce")[0]
+            assert _bytes(result_type) <= 2 << 20, op[:200]
+        # the first op of each section's backward pass, by the name jax gave it
+        section = re.search(
+            r'op_name="[^"]*transpose\(jvp\(TransformerNet\.(\w+)\)\)/[a-z_]+(\d*)/', op
+        )
+        if section:
+            which = section.group(1)    # heads, encode, or a block and its number
+            backward_from.setdefault(which + section.group(2) * (which == "block"), at)
+    # a section's hops: one buffer a row shape (a block has rows of 1536 and
+    # of 6144), both ways, both rounds
+    hops = {"heads": 1, "encode": 1, **{f"block{i}": 2 for i in range(n_layers)}}
+    hops = {k: v * 2 * 2 * (4 - 1) for k, v in hops.items()}
+    assert len(gaps) == sum(hops.values())
+    assert min(gaps) >= 2, "a collective-permute's done sits right behind its start"
+    backward = ["heads"] + [f"block{i}" for i in reversed(range(n_layers))] + ["encode"]
+    assert sorted(backward_from, key=backward_from.get) == backward
+    for k, section in enumerate(backward[2:]):
+        due = sum(hops[s] for s in backward[:k + 1])
+        done = sum(at < backward_from[section] for at in done_at)
+        assert done >= due, (section, done, due)
+
+
+def test_dp1_step_lowers_without_the_ring(v5e_2x2):
+    """{dp: 1} (the one-chip cells) lowers to the program it always was:
+    nothing of the sections' sums is in its text, all of it is in {dp: 4}'s."""
+    words = ("collective_permute", "all_reduce", "manual_computation")
+    ctx4, lowered4 = _lowered_step(v5e_2x2, dp=4, batch_size=8, n_layers=1)
+    text4 = lowered4.as_text()
+    for word in words:
+        assert word in text4, word
+    ctx1, lowered1 = _lowered_step(v5e_2x2, dp=1, batch_size=8, n_layers=1)
+    text1 = lowered1.as_text()
+    assert ctx1.grad_sync is None
+    for word in words + ("shard_map", "psum"):
+        assert word not in text1, word

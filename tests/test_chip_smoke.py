@@ -257,6 +257,9 @@ def test_every_phase_rehearses_on_the_cpu_at_tiny_size(monkeypatch, tmp_path):
     }
     four_chips = {
         "dp-train-step": lambda r: chip_smoke.phase_dp_train_step(TINY["dp"]),
+        "dp-section-ring": lambda r: chip_smoke.phase_dp_train_step(
+            TINY["dp"], chip_smoke._sections_setup
+        ),
         "dp-rollout-replay": lambda r: chip_smoke.phase_dp_rollout_replay(TINY["dp"]),
         "ring-attention": lambda r: chip_smoke.phase_ring_attention(TINY["ring"], 0),
     }

@@ -446,6 +446,39 @@ def test_seq_remat_bit_parity():
             )
 
 
+@pytest.mark.parametrize("remat", ["none", "attn", "block"])
+def test_sections_sum_their_own_gradient(remat):
+    """``sum_grads`` given, the seq forward runs as custom-VJP sections that
+    hand their own parameter cotangents to it: every parameter exactly
+    once (a function that doubles shows it), the loss untouched, on every
+    rung of the remat ladder."""
+    from handyrl_tpu.parallel import forward_prediction
+
+    env, module, variables, batch, args = _transformer_batch(
+        "TicTacToe", burn_in=2, forward_steps=6,
+        net_args={"d_model": 32, "n_heads": 2, "n_layers": 2, "memory_len": 8},
+    )
+    batch = jax.tree.map(jax.numpy.asarray, batch)
+    seen = []
+
+    def doubled(tree, x_ct, token):
+        seen.extend(jax.tree.leaves(tree))
+        return jax.tree.map(lambda g: 2.0 * g, tree), x_ct, token
+
+    def loss(params, sum_grads):
+        outs = forward_prediction(
+            module, params, batch, {**args, "seq_forward": True, "remat": remat}, sum_grads
+        )
+        return sum((v ** 2).sum() for v in outs.values())
+
+    plain_l, plain_g = jax.jit(jax.value_and_grad(lambda p: loss(p, None)))(variables["params"])
+    own_l, own_g = jax.jit(jax.value_and_grad(lambda p: loss(p, doubled)))(variables["params"])
+    np.testing.assert_allclose(np.asarray(own_l), np.asarray(plain_l), rtol=1e-6)
+    assert len(seen) == len(jax.tree.leaves(variables["params"]))
+    for a, b in zip(jax.tree.leaves(plain_g), jax.tree.leaves(own_g)):
+        np.testing.assert_allclose(np.asarray(b), 2.0 * np.asarray(a), rtol=1e-4, atol=1e-6)
+
+
 @pytest.mark.slow
 def test_seq_remat_reduces_peak_memory():
     """The point of the ladder: at a long window the checkpointed blocks
