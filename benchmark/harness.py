@@ -9,12 +9,13 @@ in files of their own (see README.md) and are found through ``Run``.
 from __future__ import annotations
 
 import importlib.util
+import inspect
 import json
 import math
 import os
 import sys
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -57,6 +58,11 @@ def load_module(path: str):
     sys.modules[name] = module
     spec.loader.exec_module(module)
     return module
+
+
+class NoProgram(Exception):
+    """The program in this checkout cannot run the cell: run.py prints the
+    message and exits 3 with no result line."""
 
 
 class Tee:
@@ -113,6 +119,8 @@ class Run:
         self.failed = 0
         self.checks: Dict[str, bool] = {}      # every one must hold for `correct`
         self.notes: Dict[str, Any] = {}        # printed on an earlier line
+        # each number compared beside its limit: the result line's last key
+        self.compared: Dict[str, List[float]] = {}
         self.setup_compile = None      # compile snapshot at window start
         self.end_compile = None        # compile snapshot at window end
         self.spans: List[Dict[str, Any]] = []  # trace.jsonl records in the window
@@ -139,6 +147,15 @@ class Run:
         module = load_module(self.path("flops", self.config["flops"] + ".py"))
         return module.train_update(self.config, self.cell)
 
+    def scope_work(self) -> Optional[Dict[str, Dict[str, float]]]:
+        """Operations and bytes one update needs inside each named scope
+        (``scope_work(config, cell)`` of the same file: scope -> ``flops``,
+        ``bytes``); None where the file has no such function."""
+        module = load_module(self.path("flops", self.config["flops"] + ".py"))
+        if not hasattr(module, "scope_work"):
+            return None
+        return module.scope_work(self.config, self.cell)
+
     def peaks(self) -> Dict[str, float]:
         table = load_json(os.path.join(HERE, "peaks.json"))
         # a rehearsal stands for the chip the cells are written for; its
@@ -163,6 +180,19 @@ class Run:
         return self.deadline - time.monotonic()
 
     # -- what runners call -------------------------------------------------
+
+    def require_module(self, module) -> None:
+        """The net the program built is the one the configuration names
+        under ``module``.  A program that lacks the configuration's net
+        falls through to the environment's own (``envs/base.py``: any
+        ``net`` it does not know) and would train that under the cell's
+        name: called before a batch is made or a program compiled, this
+        ends the run at once instead."""
+        want, built = self.config["module"], type(module).__name__
+        if built != want:
+            raise NoProgram(
+                f"configuration {self.config['name']} is run with the module {want}; "
+                f"this checkout's program builds {built} for its env_args")
 
     def open_window(self) -> None:
         """Set-up is over: warm-up ran, every shape is compiled."""
@@ -209,6 +239,16 @@ class Run:
         if not hits:
             return None
         return {key: sum(h[key] for h in hits) for key in hits[0]}
+
+    def scope(self, name: str) -> Optional[Dict[str, float]]:
+        """Device ``seconds`` (self time, averaged over the chips) and
+        ``ops`` inside the traced window of the ops whose jax ``op_name``
+        has ``name`` as a path component, forward and backward alike; None
+        where the cell's file lists no ``scopes``, not this one, or no op
+        carries it."""
+        if self.reduced is None:
+            return None
+        return self.reduced.get("scopes", {}).get(name)
 
 
 # ---------------------------------------------------------------------------
@@ -280,12 +320,16 @@ def reduce_profile(run: Run) -> None:
 
     with open(run.xplane, "rb") as f:
         run.notes["profile_planes"] = trace_reduce.plane_sizes(f.read())
-    trace = trace_reduce.load_xplane(run.xplane)
+    # device time by named scope is a second pass over the op metadata,
+    # made only for a cell that lists ``scopes``
+    trace = trace_reduce.load_xplane(run.xplane, scopes=run.cell.get("scopes"))
     begin = [s for s in trace["host"] if s[0] == WINDOW_BEGIN]
     end = [s for s in trace["host"] if s[0] == WINDOW_END]
     window = (begin[0][2], end[-1][1]) if begin and end else None
     trace["host"] = [s for s in trace["host"] if s[0] not in (WINDOW_BEGIN, WINDOW_END)]
     run.reduced = trace_reduce.reduce_trace(trace, window)
+    if "scopes" in run.reduced:
+        run.notes["scopes"] = run.reduced["scopes"]
 
 
 # ---------------------------------------------------------------------------
@@ -319,6 +363,131 @@ def compare_outputs(system: Dict[str, Any], reference: Dict[str, Any],
         ok = ok and out[head] <= tolerance * max(1.0, scale) and scale > 5 * tolerance
     out["ok"] = ok
     return out
+
+
+def limits(verdict: Dict[str, float], tolerance: float, prefix: str = "") -> Dict[str, List[float]]:
+    """``compare_outputs``' verdict as name -> [number compared, its limit]."""
+    return {
+        prefix + head: [verdict[head], tolerance * max(1.0, verdict[head + "_scale"])]
+        for head in verdict if head + "_scale" in verdict
+    }
+
+
+CHOICES = "choices"
+
+
+def choices_agreement(system: Any, reference: Any, mask: Any = None) -> float:
+    """Share of (row, step, layer) choice sets that are the same on both
+    sides.  Each side is a pytree of integer arrays, one per routed layer,
+    whose last axis holds the indices one token's result used; ``mask``
+    (the leaves' shape without that axis, or with it as 1) selects the
+    tokens that count."""
+    import jax
+    import numpy as np
+
+    ours, theirs = jax.tree.leaves(system), jax.tree.leaves(reference)
+    if len(ours) != len(theirs) or not ours:
+        raise ValueError(
+            f"choices: the system returned {len(ours)} routed layer(s), "
+            f"the reference {len(theirs)}")
+    same = counted = 0
+    for a, b in zip(ours, theirs):
+        a, b = np.sort(np.asarray(a), axis=-1), np.sort(np.asarray(b), axis=-1)
+        if a.shape != b.shape or not np.issubdtype(a.dtype, np.integer):
+            raise ValueError(f"choices: {a.dtype}{a.shape} against {b.dtype}{b.shape}")
+        equal = (a == b).all(axis=-1)
+        keep = np.ones(equal.shape, bool) if mask is None else np.broadcast_to(
+            np.asarray(mask, bool).reshape(equal.shape), equal.shape)
+        same += int((equal & keep).sum())
+        counted += int(keep.sum())
+    return same / counted if counted else 0.0
+
+
+def judge_forward(system: Callable, reference_rows: Callable, params: Any, batch: Any,
+                  config: Dict[str, Any], burn_in: int,
+                  mask_of: Callable[[str], Any] = lambda head: None,
+                  system_f32: Optional[Callable] = None):
+    """The comparison that decides ``correct`` for a forward pass over
+    training rows: ``system(params, batch)``, the timed path's forward in
+    its compute dtype, against the configuration's plain reference
+    ``reference_rows(params, batch, config, burn_in)`` in float32 under
+    ``highest``.  ``mask_of(head)`` selects the entries of a head that
+    count.  Returns (checks, notes, compared).
+
+    A routed system returns, beside its heads, ``choices`` (see
+    ``choices_agreement``), and its reference takes ``choices=None`` and
+    returns its own under the same key.  A discrete choice cannot be held
+    to a tolerance: rounding moves a token's k-th and (k+1)-th scores past
+    each other and the two sides then add different experts.  So the
+    reference runs twice.  *Forced*, with the system's choices, held to
+    ``reference_tolerance``: ``matches_reference``, the arithmetic and its
+    precision.  *Free*, with its own: the share of choice sets that agree
+    is held to ``choices_agreement_floor``: ``choices_agree``, the routing.
+    Where the configuration gives ``reference_tolerance_f32``,
+    ``system_f32`` (the same forward, float32 parameters and compute, run
+    under ``highest``) is held to it against the free reference:
+    ``matches_reference_f32``, which one wrong expert fails.  ``choices``
+    on one side only is an error."""
+    import jax
+
+    def reference(**given):
+        with jax.default_matmul_precision("highest"):
+            return dict(jax.device_get(jax.jit(
+                lambda p, b, **kw: reference_rows(p, b, config, burn_in, **kw)
+            )(params, batch, **given)))
+
+    tolerance = float(config["reference_tolerance"])
+    got = dict(jax.device_get(jax.jit(system)(params, batch)))
+    chosen = got.pop(CHOICES, None)
+    takes = CHOICES in inspect.signature(reference_rows).parameters
+    if chosen is None and not takes:
+        want = reference()
+        verdict = compare_outputs(got, want, tolerance, _masks(mask_of, want))
+        compared = limits(verdict, tolerance)
+        return ({"matches_reference": verdict.pop("ok")},
+                {"reference_max_abs_diff": verdict}, compared)
+    if chosen is None:
+        raise ValueError(
+            f"{config['name']}: the reference takes choices, and the system's "
+            "forward returned none")
+    if not takes:
+        raise ValueError(
+            f"{config['name']}: the system's forward returned choices, and the "
+            "reference's forward_rows takes none")
+
+    forced, free = reference(choices=chosen), reference()
+    if choices_agreement(forced.pop(CHOICES), chosen) != 1.0:
+        raise ValueError(f"{config['name']}: the reference did not use the choices it was given")
+    own = free.pop(CHOICES)
+    masks = _masks(mask_of, free)
+    verdict = compare_outputs(got, forced, tolerance, masks)
+    floor = float(config["choices_agreement_floor"])
+    agreement = choices_agreement(chosen, own, mask_of(CHOICES))
+    compared = dict(limits(verdict, tolerance), choices_agreement=[agreement, floor])
+    checks = {"matches_reference": verdict.pop("ok"), "choices_agree": agreement >= floor}
+    notes = {
+        "reference_max_abs_diff": verdict, "choices_agreement": agreement,
+        # no check: what a plain comparison would have read
+        "reference_free_max_abs_diff": compare_outputs(got, free, tolerance, masks),
+    }
+    if "reference_tolerance_f32" in config:
+        if system_f32 is None:
+            raise ValueError(
+                f"{config['name']} gives reference_tolerance_f32, and the runner "
+                "has no float32 forward")
+        tolerance = float(config["reference_tolerance_f32"])
+        with jax.default_matmul_precision("highest"):
+            exact = dict(jax.device_get(jax.jit(system_f32)(params, batch)))
+        exact.pop(CHOICES, None)
+        verdict = compare_outputs(exact, free, tolerance, masks)
+        compared.update(limits(verdict, tolerance, "f32_"))
+        checks["matches_reference_f32"] = verdict.pop("ok")
+        notes["reference_f32_max_abs_diff"] = verdict
+    return checks, notes, compared
+
+
+def _masks(mask_of: Callable[[str], Any], heads) -> Dict[str, Any]:
+    return {head: mask for head in heads if (mask := mask_of(head)) is not None}
 
 
 def log_has_fallback(log_path: str) -> List[str]:

@@ -5,9 +5,11 @@
 Prints progress and the program's own output on stderr, and as the LAST
 line of stdout one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its
-per-layer metrics with ``--trace 1``), ``device`` and, traced,
-``breakdown``.  Without the TPU chips the cell asks for it exits non-zero
-and prints no result.  ``--rehearse`` walks the same control flow on
+per-layer metrics with ``--trace 1``), ``device``, traced ``breakdown``,
+and last ``compared`` (each number the run compared beside its limit,
+printed again as the last lines of stderr).  Without the TPU chips the cell
+asks for it exits 2, and with a program that lacks the configuration's
+``module`` it exits 3, and prints no result.  ``--rehearse`` walks the same control flow on
 whatever device jax has (the CPU, in tests): it prints counts only, says
 ``correct: false`` and exits non-zero, so no CPU number can pass for a
 device metric.  See README.md for the files a cell is made of.
@@ -84,14 +86,19 @@ def main(argv=None) -> int:
 
     log_path = os.path.join(run.out_dir, "log.txt")
     stdout, stderr, cwd = sys.stdout, sys.stderr, os.getcwd()
-    with open(log_path, "w") as sink:
-        sys.stdout = sys.stderr = harness.Tee(sink)
-        os.chdir(run.out_dir)    # the program writes relative paths
-        try:
-            run.runner().run(run)
-        finally:
-            os.chdir(cwd)
-            sys.stdout, sys.stderr = stdout, stderr
+    try:
+        with open(log_path, "w") as sink:
+            sys.stdout = sys.stderr = harness.Tee(sink)
+            os.chdir(run.out_dir)    # the program writes relative paths
+            try:
+                run.runner().run(run)
+            finally:
+                os.chdir(cwd)
+                sys.stdout, sys.stderr = stdout, stderr
+    except harness.NoProgram as exc:
+        faulthandler.cancel_dump_traceback_later()
+        print(f"benchmark: {exc}", file=sys.stderr)
+        return EXIT_NO_PROGRAM
     markers = harness.log_has_fallback(log_path)
     run.checks["no_fallback_marker"] = not markers
     if markers:
@@ -141,6 +148,9 @@ def main(argv=None) -> int:
         from benchmark import trace_reduce
 
         result["breakdown"] = trace_reduce.breakdown(run.reduced)
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of stderr
+    result["compared"] = dict(run.compared, failed=[int(run.failed), 0])
     run.notes["seconds_left"] = run.seconds_left()
     # an earlier line: what the last line has no key for
     print(json.dumps({
@@ -150,6 +160,10 @@ def main(argv=None) -> int:
     }, default=float))
     print(json.dumps(result))
     sys.stdout.flush()
+    for name, (number, limit) in result["compared"].items():
+        print(f"benchmark: compared {name} {number:.6g} limit {limit:.6g}", file=sys.stderr)
+    failing = sorted(name for name, held in run.checks.items() if not held)
+    print(f"benchmark: correct {correct}; checks that fail: {failing}", file=sys.stderr)
     if opts.rehearse:
         return EXIT_REHEARSAL
     return 0 if correct else EXIT_INCORRECT

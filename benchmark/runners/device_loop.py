@@ -77,6 +77,7 @@ def run(run: harness.Run) -> None:
         train_args["trace"] = {"enabled": True, "path": "trace.jsonl",
                                "ring_size": 65536}
     cfg = normalize_args({"env_args": dict(config["env_args"]), "train_args": train_args})
+    run.require_module(make_env(cfg["env_args"]).net())
     random.seed(run.seed)
     np.random.seed(run.seed)
     learner = Learner(cfg)
@@ -215,5 +216,6 @@ def run(run: harness.Run) -> None:
         want = jax.device_get(jax.jit(reference.forward)(params, obs))
     verdict = harness.compare_outputs(
         {k: system[k] for k in want}, want, float(config["reference_tolerance"]))
+    run.compared.update(harness.limits(verdict, float(config["reference_tolerance"])))
     run.checks["matches_reference"] = verdict.pop("ok")
     run.notes["reference_max_abs_diff"] = verdict

@@ -10,10 +10,12 @@ Window: updates back to back, rotating the staged batches, at most
 update's ``block_until_ready``.  A traced run measures ``trace_seconds``
 under the profiler instead of ``--seconds``.
 
-After the window, outside it: every update's loss is fetched and checked,
-and the forward pass the train step runs (``forward_prediction``, in the
-cell's compute dtype) is compared with the configuration's plain float32
-reference on one batch row.
+After the window, outside it: every update's loss is fetched and checked
+(with them the step's ``counter_*`` metrics, whose mean per update goes to
+``run.counters``), and the forward pass the train step runs
+(``forward_prediction``, in the cell's compute dtype) is compared with the
+configuration's plain float32 reference on one batch row
+(``harness.judge_forward``; a routed net hands its choices to the reference).
 """
 
 from __future__ import annotations
@@ -46,6 +48,7 @@ def run(run: harness.Run) -> None:
     np.random.seed(run.seed)
     env = make_env(args["env"])
     module = env.net()
+    run.require_module(module)
 
     params = traffic.seeded_params(module, env, run.seed)
     host_batches = traffic.random_play_batches(
@@ -63,7 +66,7 @@ def run(run: harness.Run) -> None:
     if run.trace:
         program_trace.configure({"enabled": True, "path": "trace.jsonl"})
         harness.start_profile(run)
-    pending, fetched = [], []
+    pending, fetched, stamps = [], [], []
     in_flight = int(cell["in_flight"])
     run.open_window()
     t0 = time.perf_counter()
@@ -77,7 +80,8 @@ def run(run: harness.Run) -> None:
             with jax.profiler.TraceAnnotation("bench.block"):
                 jax.block_until_ready(pending[0])
             fetched.append(pending.pop(0))
-            if time.perf_counter() - t0 >= seconds:
+            stamps.append(time.perf_counter())
+            if stamps[-1] - t0 >= seconds:
                 break
     with jax.profiler.TraceAnnotation("bench.block"):
         jax.block_until_ready((state, pending))
@@ -97,33 +101,44 @@ def run(run: harness.Run) -> None:
     run.values["trained_steps_per_s"] = steps / window_s
     run.counters.update(updates=updates, updates_per_s=updates / window_s,
                         window_s=window_s)
+    # what the step counts on the device (rows routed, a buffer's bound): mean per update
+    for key in fetched[0]:
+        if key.startswith("counter_"):
+            run.counters[key] = float(np.mean([float(m[key]) for m in fetched]))
+    # when each update was seen to end: a run that reads far off says whether
+    # every update was slower or a few stalled (2 of 22 read 4% and 9% low in
+    # PR 33 and left nothing to tell by)
+    gaps = np.diff(stamps) * 1e3
+    if len(gaps):
+        typical = float(np.median(gaps))
+        run.notes["update_interval_ms"] = {
+            "median": typical, "max": float(gaps.max()),
+            "over_1.05_median": int((gaps > 1.05 * typical).sum()),
+            "excess_s": float(np.maximum(gaps - typical, 0.0).sum() / 1e3),
+        }
     run.notes.update(loss_first=float(losses[0]), loss_last=float(losses[-1]),
                      params=int(sum(x.size for x in jax.tree.leaves(state["params"]))))
 
     # -- the train step's forward against the plain reference, one row ----
     row = jax.tree.map(lambda x: np.asarray(x)[:1], host_batches[0])
-    cdt = jnp.bfloat16 if args.get("compute_dtype") == "bfloat16" else None
 
-    def system_forward(p, batch):
-        if cdt is not None:
-            p = jax.tree.map(lambda x: x.astype(cdt), p)
-        return forward_prediction(module, p, batch, ctx.args)
+    def system_forward(p, batch, dtype=args.get("compute_dtype")):
+        if dtype == "bfloat16":
+            p = jax.tree.map(lambda x: x.astype(jnp.bfloat16), p)
+        return forward_prediction(module, p, batch, dict(ctx.args, compute_dtype=dtype))
 
     # on the seeded weights, not the trained ones: lr 1e-5 on random weights
     # saturates the value head within tens of updates
     del state
     params = traffic.seeded_params(module, env, run.seed)
-    system = jax.device_get(jax.jit(system_forward)(params, row))
     burn_in = int(args["burn_in_steps"])
-    reference = run.reference()
-    with jax.default_matmul_precision("highest"):
-        want = jax.device_get(jax.jit(
-            lambda p, batch: reference.forward_rows(p, batch, config, burn_in)
-        )(params, row))
     legal = (row["action_mask"][:, burn_in:] == 0) & (row["turn_mask"][:, burn_in:] > 0)
     observed = row["observation_mask"][:, burn_in:] > 0
-    masks = {k: (legal if k == "policy" else observed) for k in want}
-    verdict = harness.compare_outputs(system, want, float(config["reference_tolerance"]), masks)
-    run.checks["matches_reference"] = verdict.pop("ok")
+    checks, notes, compared = harness.judge_forward(
+        system_forward, run.reference().forward_rows, params, row, config, burn_in,
+        mask_of=lambda head: legal if head == "policy" else observed,
+        system_f32=lambda p, batch: system_forward(p, batch, "float32"))
+    run.checks.update(checks)
     run.checks["losses_finite"] = bool(np.isfinite(losses).all())
-    run.notes["reference_max_abs_diff"] = verdict
+    run.notes.update(notes)
+    run.compared.update(compared)

@@ -164,6 +164,21 @@ def test_every_reader_answers_none_with_nothing_to_read(run, monkeypatch, name):
     assert run.notes == {}
 
 
+def test_every_accessor_answers_none_with_nothing_to_read(run):
+    """What a reader asks a run for, on a run that has nothing of it."""
+    run.reduced = None                          # untraced
+    assert run.program("train") is None and run.program_named("jit__step") is None
+    assert run.scope("attn0") is None
+    run.reduced = json.loads(json.dumps(REDUCED))   # traced; the cell lists no scopes
+    assert "scopes" not in run.cell and run.scope("attn0") is None
+    run.reduced["scopes"] = {"attn0": {"seconds": 0.5, "ops": 12.0}}
+    assert run.scope("attn0") == {"seconds": 0.5, "ops": 12.0}
+    assert run.scope("attn1") is None           # listed, and no op carries it
+    # no flops file has a scope_work yet: a reader of a scope's roofline
+    # share leaves its metric out
+    assert run.scope_work() is None
+
+
 def test_rollout_wait_share_by_hand(run):
     # stats fetches 0.28 + 0.12 + 0.1 (the last is cut at 102.0) and the
     # rollout thread's lock waits 0.04 + 0.06, over 2 s

@@ -167,3 +167,153 @@ def test_transformer_counts_against_a_hand_count():
     assert work["tokens"] == 64 * 2 * 64
     # close to the rule of thumb 6 x parameters x tokens (11.3 TFLOP)
     assert 0.95 < work["flops"] / (6 * n_params * work["tokens"]) < 1.02
+
+
+# -- a routed forward against its reference: harness.judge_forward -------------
+# Four experts, top-2, plain jax.numpy (routed_toy.py): the system mixes the
+# experts through a one-hot mask, the reference gathers the chosen.
+
+sys.path.insert(0, HERE)
+import routed_toy as toy  # noqa: E402
+
+TOY = {"name": "toy", "top_k": 2, "reference_tolerance": 1e-4, "choices_agreement_floor": 0.9}
+TOY_TOKENS = 2 * 5 * 2          # rows x steps past the burn-in x players
+
+
+def _toy(seed=0):
+    """Seeded rows with a near-tie injected at the first compared token: it
+    is the first unit vector, so the router's first row is its logits.
+    Expert 0 leads; 1 and 2 lie 2e-6 apart for second place; 3 is far off."""
+    params, batch = toy.make(seed)
+    x = batch["x"].at[0, 1, 0].set(jnp.eye(8)[0])
+    router = params["router"].at[0].set(jnp.asarray([3.0, 1.0, 1.0 - 2e-6, -3.0]))
+    return dict(params, router=router), {"x": x}
+
+
+def _judge(system, config=TOY, reference=toy.reference_rows, **kwargs):
+    params, batch = _toy()
+    return harness.judge_forward(
+        lambda p, b: system(p, b, config, 1), reference, params, batch, config, 1, **kwargs)
+
+
+def test_routed_system_matches_when_nothing_is_wrong():
+    checks, notes, compared = _judge(toy.system_rows)
+    assert checks == {"matches_reference": True, "choices_agree": True}
+    assert notes["choices_agreement"] == 1.0 and notes["reference_free_max_abs_diff"]["ok"]
+    assert compared["choices_agreement"] == [1.0, 0.9]
+    number, limit = compared["policy"]
+    assert number < 1e-5 and limit == pytest.approx(1e-4 * notes["reference_max_abs_diff"]["policy_scale"])
+
+
+def test_a_near_tie_fails_the_free_comparison_and_passes_the_forced_one():
+    """What rounding does to a top-k: the system's scores differ by 4e-6 in
+    one column, one token takes expert 2 where the reference takes 1, and a
+    whole expert's contribution stands between the two outputs.  Forced to
+    the system's choice the reference agrees to the tolerance."""
+    def rounded(params, batch, config, burn_in):
+        router = params["router"].at[0, 2].add(4e-6)
+        return toy.system_rows(dict(params, router=router), batch, config, burn_in)
+
+    checks, notes, _ = _judge(rounded)
+    assert notes["choices_agreement"] == pytest.approx((TOY_TOKENS - 1) / TOY_TOKENS)
+    assert notes["reference_free_max_abs_diff"]["ok"] is False
+    assert notes["reference_free_max_abs_diff"]["policy"] > 100 * TOY["reference_tolerance"]
+    assert checks == {"matches_reference": True, "choices_agree": True}
+
+
+def test_an_expert_perturbed_by_a_hundredth_fails_the_forced_comparison():
+    def perturbed(params, batch, config, burn_in):
+        experts = params["experts"].at[1].multiply(1.01)
+        return toy.system_rows(dict(params, experts=experts), batch, config, burn_in)
+
+    checks, notes, compared = _judge(perturbed)
+    assert checks == {"matches_reference": False, "choices_agree": True}
+    assert compared["policy"][0] > compared["policy"][1]
+
+
+def test_scores_from_the_wrong_column_pass_the_forced_comparison_and_fail_choices_agree():
+    """The forced comparison hides no routing fault: a top-k taken from the
+    neighbouring column's scores computes every chosen expert rightly, and
+    chooses wrongly."""
+    def misrouted(params, batch, config, burn_in):
+        return toy.system_rows(params, batch, config, burn_in,
+                               select=lambda s: jnp.roll(s, 1, axis=-1))
+
+    checks, notes, compared = _judge(misrouted)
+    assert checks == {"matches_reference": True, "choices_agree": False}
+    assert compared["choices_agreement"][0] == notes["choices_agreement"] < 0.5
+
+
+def test_one_wrong_expert_fails_the_float32_comparison():
+    config = dict(TOY, reference_tolerance_f32=1e-5)
+
+    def one_wrong(params, batch):
+        def select(scores):         # the first compared token takes its worst expert
+            worst = jax.nn.one_hot(jnp.argmin(scores[0, 0, 0]), 4)
+            return scores.at[0, 0, 0].add(10.0 * worst)
+        return toy.system_rows(params, batch, config, 1, select=select)
+
+    sound = lambda p, b: toy.system_rows(p, b, config, 1)  # noqa: E731
+    checks, notes, compared = _judge(toy.system_rows, config, system_f32=sound)
+    assert checks == {"matches_reference": True, "choices_agree": True,
+                      "matches_reference_f32": True}
+    assert compared["f32_policy"][0] <= compared["f32_policy"][1]
+    checks, notes, compared = _judge(toy.system_rows, config, system_f32=one_wrong)
+    assert checks == {"matches_reference": True, "choices_agree": True,
+                      "matches_reference_f32": False}
+    assert "reference_f32_max_abs_diff" in notes
+    with pytest.raises(ValueError, match="reference_tolerance_f32"):
+        _judge(toy.system_rows, config)
+
+
+def _plain_reference(params, batch, config, burn_in):
+    return {"policy": toy.reference_rows(params, batch, config, burn_in)["policy"]}
+
+
+def _plain_system(params, batch, config, burn_in):
+    return {"policy": toy.system_rows(params, batch, config, burn_in)["policy"]}
+
+
+def test_choices_on_one_side_only_is_an_error():
+    with pytest.raises(ValueError, match="returned choices, and the reference"):
+        _judge(toy.system_rows, reference=_plain_reference)
+    with pytest.raises(ValueError, match="the reference takes choices"):
+        _judge(_plain_system)
+
+    def deaf(params, batch, config, burn_in, choices=None):
+        return toy.reference_rows(params, batch, config, burn_in)
+
+    def rounded(params, batch, config, burn_in):
+        router = params["router"].at[0, 2].add(4e-6)
+        return toy.system_rows(dict(params, router=router), batch, config, burn_in)
+
+    with pytest.raises(ValueError, match="did not use the choices"):
+        _judge(rounded, reference=deaf)
+
+
+def test_a_configuration_without_choices_gives_todays_verdict_key_for_key():
+    """What runners/train_step.py did before the comparison moved here."""
+    params, batch = _toy()
+    mask = np.arange(5)[None, None, None, :] > 0        # one logit never counts
+    with jax.default_matmul_precision("highest"):
+        want = jax.device_get(jax.jit(
+            lambda p, b: _plain_reference(p, b, TOY, 1))(params, batch))
+    got = jax.device_get(jax.jit(lambda p, b: _plain_system(p, b, TOY, 1))(params, batch))
+    today = harness.compare_outputs(got, want, TOY["reference_tolerance"], {"policy": mask})
+    checks, notes, compared = _judge(_plain_system, reference=_plain_reference,
+                                     mask_of=lambda head: mask)
+    assert checks == {"matches_reference": today.pop("ok")} == {"matches_reference": True}
+    assert notes == {"reference_max_abs_diff": today}
+    assert list(today) == ["policy", "policy_scale"]
+    assert compared == {"policy": [today["policy"], 1e-4 * max(1.0, today["policy_scale"])]}
+
+
+def test_choices_agreement_counts_sets_over_the_tokens_that_count():
+    ours = {"a": np.asarray([[[0, 1], [2, 3]]]), "b": np.asarray([[[1, 0], [1, 2]]])}
+    theirs = {"a": np.asarray([[[1, 0], [2, 1]]]), "b": np.asarray([[[0, 1], [2, 1]]])}
+    assert harness.choices_agreement(ours, theirs) == 0.75          # order does not count
+    assert harness.choices_agreement(ours, theirs, np.asarray([[[1], [0]]])) == 1.0
+    with pytest.raises(ValueError, match="routed layer"):
+        harness.choices_agreement(ours, {"a": theirs["a"]})
+    with pytest.raises(ValueError, match="float32"):
+        harness.choices_agreement({"a": np.zeros((1, 2, 2), np.float32)}, {"a": theirs["a"]})

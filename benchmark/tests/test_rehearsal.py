@@ -22,79 +22,61 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 REPO = os.path.dirname(BENCH)
 
-TINY_XFMR = {
-    "name": "tiny_xfmr", "source": "test",
-    "env_args": {"env": "Geister", "net": "transformer",
-                 "net_args": {"d_model": 32, "n_heads": 2, "n_layers": 2, "memory_len": 4}},
-    "train_args": {},
-    "shapes": {"observation_width": 270, "players": 2, "actions": 214, "scalar_heads": 2},
-    "flops": "alibi_transformer", "reference_tolerance": 1e-3,
-}
-CELLS = {
-    "tiny_loop": {
-        "config": "geesenet", "runner": "device_loop", "chips": 1,
-        "train_args": {
-            "turn_based_training": False, "observation": False, "device_replay": True,
-            "eval_rate": 0.0, "eval": {"opponent": ["rulebase"]},
-            "worker": {"num_parallel": 1}, "batch_size": 16, "forward_steps": 8,
-            "device_rollout_games": 16, "device_replay_k_steps": 16,
-            "device_replay_slots": 128, "fused_steps": 2, "device_eval_games": 8,
-            "update_episodes": 40, "minimum_episodes": 450,     # fills the 16 x 128 ring
-        },
-        "warm_records": 2, "trace_seconds": 2, "trace_updates": 8, "check_samples": 16,
-        "stall_spans": ["epoch.snapshot_wait"],
-    },
-    "tiny_train": {
-        "config": "tiny_xfmr", "runner": "train_step", "chips": 1,
-        "train_args": {"batch_size": 4, "burn_in_steps": 2, "forward_steps": 6,
-                       "observation": True, "seq_attention": "einsum"},
-        "mesh": {"dp": 1}, "lr": 1e-5, "n_batches": 2, "fill_episodes": 4,
-        "in_flight": 2, "trace_seconds": 1, "programs": {"train": "jit__step"},
-    },
-    "tiny_train_dp4": {
-        "config": "tiny_xfmr", "runner": "train_step", "chips": 4,
-        "train_args": {"batch_size": 8, "burn_in_steps": 2, "forward_steps": 6,
-                       "observation": True, "seq_attention": "einsum"},
-        "mesh": {"dp": 4}, "lr": 1e-5, "n_batches": 2, "fill_episodes": 4,
-        "in_flight": 2, "trace_seconds": 1, "programs": {"train": "jit__step"},
-    },
-}
-SECONDS = {"tiny_loop": 8, "tiny_train": 2, "tiny_train_dp4": 2}
+TINY = os.path.join(HERE, "tiny")
 
 
-def _spec():
-    """The repo's BENCHMARK.json with every metric handed to the tiny cell
-    of its runner, so the rehearsal walks every reader."""
-    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    by_runner = {"device_loop": ["tiny_loop"], "train_step": ["tiny_train", "tiny_train_dp4"]}
-    runner_of = {}
-    for cell in os.listdir(os.path.join(BENCH, "workloads")):
-        data = json.load(open(os.path.join(BENCH, "workloads", cell)))
-        runner_of[data["name"]] = data["runner"]
+def _load(folder):
+    """name -> the JSON object of each file of ``folder``."""
+    found = {}
+    for name in sorted(os.listdir(folder)):
+        if name.endswith(".json"):
+            with open(os.path.join(folder, name)) as f:
+                found[name[:-5]] = json.load(f)
+    return found
+
+
+# The tiny cells and configurations are files (tiny/workloads, tiny/configs):
+# a later PR adds its rehearsal, with a runner of its own if it needs one, as
+# two files.  A tiny configuration names the ``reference`` file it borrows; a
+# tiny cell its ``rehearse_seconds`` and the metrics its rehearsal ``answers``
+# without a device trace.
+CELLS = _load(os.path.join(TINY, "workloads"))
+TINY_CONFIGS = _load(os.path.join(TINY, "configs"))
+
+
+def _spec(workloads=os.path.join(BENCH, "workloads"), tiny=None):
+    """The repo's BENCHMARK.json with every metric handed to the tiny cells
+    of its cells' runners, so the rehearsal walks every reader.  A cell
+    whose runner has no tiny cell hands its metrics to none."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    by_runner = {}
+    for name, cell in (CELLS if tiny is None else tiny).items():
+        by_runner.setdefault(cell["runner"], []).append(name)
+    runner_of = {cell["name"]: cell["runner"] for cell in _load(workloads).values()}
     for group in ("end_to_end", "per_layer"):
         for metric in spec[group]:
             if "workloads" in metric:
                 metric["workloads"] = sorted({
-                    tiny for cell in metric["workloads"] for tiny in by_runner[runner_of[cell]]})
+                    tiny_cell for cell in metric["workloads"]
+                    for tiny_cell in by_runner.get(runner_of[cell], [])})
     return spec
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     """A benchmark root of tiny cells: the real runners, readers,
-    references and flops functions, the test's own cell and config files
-    and a BENCHMARK.json that lists every metric for every cell."""
+    references, flops functions and configurations, the tiny cell and
+    configuration files and a BENCHMARK.json that lists every metric for
+    every tiny cell of its runner."""
     path = tmp_path_factory.mktemp("bench_root")
-    for part in ("runners", "layer_metrics", "reference", "flops"):
+    for part in ("runners", "layer_metrics", "reference", "flops", "configs"):
         shutil.copytree(os.path.join(BENCH, part), path / part)
-    shutil.copy(os.path.join(BENCH, "reference", "xfmr_d1536.py"),
-                path / "reference" / "tiny_xfmr.py")
-    os.makedirs(path / "configs")
-    os.makedirs(path / "workloads")
-    shutil.copy(os.path.join(BENCH, "configs", "geesenet.json"), path / "configs")
-    (path / "configs" / "tiny_xfmr.json").write_text(json.dumps(TINY_XFMR))
-    for name, cell in CELLS.items():
-        (path / "workloads" / (name + ".json")).write_text(json.dumps(dict(cell, name=name)))
+    for name, config in TINY_CONFIGS.items():
+        shutil.copy(os.path.join(TINY, "configs", name + ".json"), path / "configs")
+        shutil.copy(os.path.join(BENCH, "reference", config["reference"] + ".py"),
+                    path / "reference" / (name + ".py"))
+    shutil.copytree(os.path.join(TINY, "workloads"), path / "workloads")
     (path / "BENCHMARK.json").write_text(json.dumps(_spec()))
     return str(path)
 
@@ -104,7 +86,7 @@ def _run(root, workload, trace, rehearse=True, devices=1):
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     cmd = [sys.executable, os.path.join(BENCH, "run.py"), "--root", root,
            "--workload", workload, "--seed", "3",
-           "--seconds", str(SECONDS[workload]), "--trace", str(trace)]
+           "--seconds", str(CELLS[workload]["rehearse_seconds"]), "--trace", str(trace)]
     if rehearse:
         cmd.append("--rehearse")
     return subprocess.run(cmd, env=env, cwd=REPO, capture_output=True, text=True,
@@ -118,7 +100,13 @@ def test_runner_rehearses_on_cpu(root, workload, trace):
     assert proc.returncode == 4, proc.stderr[-4000:]
     lines = proc.stdout.strip().splitlines()
     last, earlier = json.loads(lines[-1]), json.loads(lines[-2])
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    # each number compared beside its limit, and again as stderr's last lines
+    assert last["compared"]["failed"] == [0, 0]
+    assert all(number <= limit for number, limit in last["compared"].values())
+    assert "policy" in last["compared"]
+    for name in last["compared"]:
+        assert f"benchmark: compared {name} " in proc.stderr[-2000:]
     # never a rate, a share or a time under a device metric's name
     assert last["correct"] is False and last["metrics"] == {}
     assert last["device"]["platform"] == "cpu"
@@ -131,21 +119,107 @@ def test_runner_rehearses_on_cpu(root, workload, trace):
     assert earlier["window_s"] > 0
     assert earlier["counters"]["compiles_in_window"] == 0
     answered = set(earlier["notes"]["metrics_answered"])
-    assert answered >= EXPECTED[CELLS[workload]["runner"]][trace], answered
-
-
-# what a rehearsal's readers must answer without a device trace
-EXPECTED = {
-    "device_loop": [{"trained_steps_per_s", "selfplay_steps_per_s", "setup_s"},
-                    {"setup_compile_s", "train_mfu"}],
-    "train_step": [{"trained_steps_per_s", "setup_s"}, {"setup_compile_s", "train_mfu"}],
-}
+    expected = CELLS[workload]["answers"]["traced" if trace else "untraced"]
+    assert answered >= set(expected), answered
 
 
 def test_cpu_run_without_rehearse_prints_no_result(root):
     proc = _run(root, "tiny_train", 0, rehearse=False)
     assert proc.returncode == 2
     assert proc.stdout.strip() == ""
+
+
+def test_a_cell_of_an_unknown_runner_hands_its_metrics_to_no_tiny_cell(tmp_path):
+    """A later PR's cell with a runner of its own, before its tiny cell is
+    written: the metrics it reports go on being handed to the others'."""
+    shutil.copytree(os.path.join(BENCH, "workloads"), tmp_path / "workloads")
+    for name in ("xfmr_train_t64", "xfmr_train_t64_dp4"):
+        cell = dict(CELLS["tiny_train"], name=name, runner="routed_step")
+        (tmp_path / "workloads" / (name + ".json")).write_text(json.dumps(cell))
+    spec = _spec(workloads=str(tmp_path / "workloads"))
+    lists = {m["name"]: m["workloads"] for g in ("end_to_end", "per_layer")
+             for m in spec[g] if "workloads" in m}
+    assert lists["train_step_device_ms"] == []
+    assert lists["trained_steps_per_s"] == lists["epoch_stall_share"] == ["tiny_loop"]
+    # with a tiny cell of that runner, it is handed them
+    tiny = dict(CELLS, tiny_routed=dict(cell, name="tiny_routed"))
+    spec = _spec(workloads=str(tmp_path / "workloads"), tiny=tiny)
+    assert "tiny_routed" in next(
+        m["workloads"] for m in spec["per_layer"] if m["name"] == "train_step_device_ms")
+
+
+def test_every_cell_lists_device_idle_share_and_setup_compile_s():
+    """A new training cell reports the end-to-end metrics these two move;
+    a metric without a list would have to be answered by every such cell."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    cells = sorted(cell["name"] for cell in spec["workloads"])
+    for metric in spec["per_layer"]:
+        assert "workloads" in metric, metric["name"]
+        if metric["name"] in ("device_idle_share", "setup_compile_s"):
+            assert sorted(metric["workloads"]) == cells
+
+
+@pytest.mark.parametrize("name", sorted(_load(os.path.join(BENCH, "configs"))) + sorted(TINY_CONFIGS))
+def test_every_configuration_names_its_module(name):
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.envs import make_env
+
+    config = {**_load(os.path.join(BENCH, "configs")), **TINY_CONFIGS}[name]
+    cfg = normalize_args({"env_args": dict(config["env_args"]), "train_args": {}})
+    assert type(make_env(cfg["env_args"]).net()).__name__ == config["module"]
+
+
+@pytest.mark.parametrize("workload", ["tiny_train", "tiny_loop"])
+def test_a_net_the_program_lacks_is_refused_at_once(root, tmp_path, workload):
+    """A configuration whose ``module`` this program does not build (a later
+    PR's, run by its parent commit): exit 3 within seconds, before a batch
+    or a program is made, and no line on stdout."""
+    import time
+
+    stub = tmp_path / "root"
+    shutil.copytree(root, stub)
+    for path in (stub / "configs").iterdir():
+        config = json.loads(path.read_text())
+        path.write_text(json.dumps(dict(config, module="NoSuchNet")))
+    t0 = time.monotonic()
+    proc = _run(str(stub), workload, 0)
+    assert time.monotonic() - t0 < 20.0
+    assert proc.returncode == 3, proc.stderr[-4000:]
+    assert proc.stdout.strip() == ""
+    assert "is run with the module NoSuchNet" in proc.stderr.strip().splitlines()[-1]
+
+
+def test_counters_of_the_step_reach_the_run(root, monkeypatch, capsys):
+    """``counter_*`` keys of the step's metrics are fetched with the losses
+    and their mean per update goes to ``run.counters``; the tiny train
+    cell, in this process, with a step that counts."""
+    import faulthandler
+
+    sys.path.insert(0, REPO)
+    from benchmark import run as entry
+    from handyrl_tpu.parallel import TrainContext
+
+    real, calls = TrainContext.train_step, []
+
+    def counting(self, state, batch, lr):
+        state, metrics = real(self, state, batch, lr)
+        calls.append(1)
+        return state, dict(metrics, counter_rows_routed=metrics["dcnt"] * 0 + len(calls) % 2,
+                           count_not_a_counter=metrics["dcnt"])
+
+    monkeypatch.setattr(TrainContext, "train_step", counting)
+    try:
+        code = entry.main(["--root", root, "--workload", "tiny_train", "--seed", "5",
+                           "--seconds", "1", "--trace", "0", "--rehearse"])
+    finally:
+        faulthandler.cancel_dump_traceback_later()     # main armed it in this process
+    assert code == entry.EXIT_REHEARSAL
+    earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-2])
+    counters = earlier["counters"]
+    # alternating 1, 0 over the window's updates (the two warm-ups came first)
+    assert counters["counter_rows_routed"] == pytest.approx(0.5, abs=0.5 / counters["updates"])
+    assert "count_not_a_counter" not in counters
 
 
 def test_a_directory_without_the_program_fails(tmp_path):

@@ -210,3 +210,97 @@ def test_interval_arithmetic_against_brute_force():
         both = covered & np.isin(grid % 7, [0, 1, 2])
         assert tr.measure(tr.intersect(merged, other)) == both.sum()
         assert tr.measure(tr.subtract(merged, other)) == (covered & ~both).sum()
+
+
+# -- device time by named scope -------------------------------------------------
+
+STEP = "jit(_step)/jit(main)/"
+
+
+def _scoped():
+    """One chip, one 20 s program: attn1 forward 0-4 (a 1 s child op inside
+    it carries the scope too), attn1 backward 4-7 under jax's transform
+    wrappers, attn10 (no component of which is attn1) 7-12, a fusion with
+    no op_name 12-14, mlp_up0 14-20 of which the window keeps a part."""
+    ops = [
+        ("%fusion.1 = f32[8] fusion(f32[8] %p)", 0.0, 4.0),
+        ("%add.1 = f32[8] add(f32[8] %p)", 1.0, 2.0),
+        ("%fusion.2 = f32[8] fusion(f32[8] %p)", 4.0, 7.0),
+        ("%fusion.3 = f32[8] fusion(f32[8] %p)", 7.0, 12.0),
+        ("%fusion.4 = f32[8] fusion(f32[8] %p)", 12.0, 14.0),
+        ("%fusion.5 = f32[8] fusion(f32[8] %p)", 14.0, 20.0),
+    ]
+    op_names = [
+        STEP + "jvp(TransformerNet)/attn1/q/dot_general:",
+        STEP + "jvp(TransformerNet)/attn1/q/add:",
+        STEP + "transpose(jvp(TransformerNet))/attn1/q/dot_general:",
+        STEP + "jvp(TransformerNet)/attn10/q/dot_general:",
+        "",
+        STEP + "transpose(jvp(mlp_up0))/dot_general:",
+    ]
+    return {"devices": {0: {"modules": [("jit__step(1)", 0.0, 20.0)], "ops": ops,
+                            "async_ops": [], "op_names": op_names}},
+            "host": [], "scopes": ["attn1", "mlp_up0", "TransformerNet", "enc1"]}
+
+
+def test_scopes_count_forward_and_backward_and_whole_components_only():
+    reduced = tr.reduce_trace(_scoped(), (0.0, 16.0))
+    scopes = reduced["scopes"]
+    # self seconds: the forward fusion 4 - 1 of its child, the child 1, the backward 3
+    assert scopes["attn1"] == {"seconds": pytest.approx(7.0), "ops": 3}
+    # a wrapped component counts, and an op the window's edge cuts counts
+    # whole, as in the op table
+    assert scopes["mlp_up0"] == {"seconds": pytest.approx(6.0), "ops": 1}
+    assert scopes["TransformerNet"] == {"seconds": pytest.approx(12.0), "ops": 4}
+    assert "enc1" not in scopes                 # no op carries it
+    assert sum(scopes[name]["seconds"] for name in ("attn1", "mlp_up0")) <= sum(
+        op["seconds"] for op in reduced["ops"].values())
+    # nothing else of the reduction moves
+    plain = _scoped()
+    del plain["scopes"]
+    unscoped = tr.reduce_trace(plain, (0.0, 16.0))
+    assert "scopes" not in unscoped
+    for key in ("busy_s", "idle_s", "programs", "ops", "collective_s"):
+        assert unscoped[key] == reduced[key]
+
+
+def test_scopes_are_averaged_over_chips():
+    trace = _scoped()
+    trace["devices"][1] = {
+        "modules": [], "async_ops": [], "ops": [("%fusion.1 = f32[8] fusion()", 0.0, 3.0)],
+        "op_names": [STEP + "jvp(TransformerNet)/attn1/q/dot_general:"]}
+    scopes = tr.reduce_trace(trace, (0.0, 20.0))["scopes"]
+    assert scopes["attn1"] == {"seconds": pytest.approx((7.0 + 3.0) / 2), "ops": 2.0}
+
+
+@pytest.mark.parametrize("op_name, found", [
+    ("jit(_step)/jit(main)/jvp(TransformerNet)/attn1/q/dot_general:", ["attn1"]),
+    ("jit(_step)/jit(main)/transpose(jvp(attn1))/q/transpose:", ["attn1"]),
+    ("jit(_step)/jit(main)/jvp(TransformerNet)/attn10/attn1_q/dot_general:", []),
+    ("jit(_step)/jit(main)/attn1/enc1/add:add", ["attn1", "enc1"]),
+    ("attn1", ["attn1"]),
+    ("", []),
+])
+def test_a_scope_is_a_whole_path_component(op_name, found):
+    assert tr.scopes_of(op_name, ["attn1", "enc1"]) == found
+
+
+def test_op_names_are_read_off_the_kept_v5e_trace():
+    """The stat is ``tf_op`` on the op's event metadata (looked at by hand
+    in the bf16 capture): GeeseNet's twelfth block, forward and backward."""
+    found = glob.glob(os.path.join(CAPTURES, "bf16", "plugins", "profile", "*", "*.xplane.pb"))
+    if not found:
+        pytest.skip("the kept bf16 trace is not in this checkout")
+    plain = tr.load_xplane(found[0])
+    assert "scopes" not in plain and "op_names" not in plain["devices"][0]
+    trace = tr.load_xplane(found[0], scopes=["ConvBlock_12", "GeeseNet", "NoSuchScope"])
+    device = trace["devices"][0]
+    assert len(device["op_names"]) == len(device["ops"]) == len(plain["devices"][0]["ops"])
+    paths = set(device["op_names"])
+    assert any("/jvp(GeeseNet)/ConvBlock_12/" in p for p in paths)
+    assert any("/transpose(jvp(GeeseNet))/ConvBlock_12/" in p for p in paths)
+    reduced = tr.reduce_trace(trace)
+    scopes = reduced["scopes"]
+    assert set(scopes) == {"ConvBlock_12", "GeeseNet"}
+    assert 0 < scopes["ConvBlock_12"]["seconds"] < scopes["GeeseNet"]["seconds"] < reduced["busy_s"]
+    assert tr.reduce_trace(plain)["busy_s"] == reduced["busy_s"]

@@ -32,6 +32,15 @@ Definitions (seconds, inside the window):
             part of it during which no other leaf op runs on that chip.
 * gap       a maximal idle interval; named by the innermost host span
             open at its middle, or ``no_span``.
+* scope     only where the trace lists ``scopes``: the self seconds and the
+            number of the ops (as the op table counts them: an op the
+            window's edge cuts counts whole) whose jax ``op_name`` has the
+            scope's name as a path component, forward and backward
+            (``transpose(jvp(..))``) alike.  A fusion carries one
+            ``op_name``, its root's: what XLA fused across a scope's edge
+            goes to one side.  Nested scopes each count their ops, so
+            scopes sum to the program's seconds at most where none lies
+            inside another.
 
 Where several chips are traced, seconds are averaged over them; gaps and
 the op table come from the first chip.
@@ -64,13 +73,20 @@ PROGRAM_KEYS = ("seconds", "runs", "whole_seconds", "whole_runs")
 SHORT_GAP_S = 20e-6
 
 
-def load_xplane(path: str) -> Dict[str, Any]:
+def load_xplane(path: str, scopes: Optional[Sequence[str]] = None) -> Dict[str, Any]:
     """Read one ``.xplane.pb`` into plain lists.  Times are seconds on the
     trace's own clock.  Host events are kept where their name is a span's
-    (``SPAN_NAME``)."""
+    (``SPAN_NAME``).  With ``scopes`` each device also gets ``op_names``,
+    the jax ``op_name`` of each event of ``ops`` (``op_names_by_event``),
+    and the trace the list under ``scopes``; without, nothing more is read
+    than before."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
+    named: Dict[str, Dict[str, str]] = {}
+    if scopes:
+        with open(path, "rb") as f:
+            named = op_names_by_event(f.read())
     devices: Dict[int, Dict[str, List[Interval]]] = {}
     host: List[HostSpan] = []
     for plane in data.planes:
@@ -84,18 +100,24 @@ def load_xplane(path: str) -> Dict[str, Any]:
                         for e in line.events
                     ]
             if lines:
-                devices[int(m.group(1))] = {
+                device = devices[int(m.group(1))] = {
                     "modules": lines.get("XLA Modules", []),
                     "ops": lines.get("XLA Ops", []),
                     "async_ops": lines.get("Async XLA Ops", []),
                 }
+                if scopes:
+                    by_event = named.get(plane.name, {})
+                    device["op_names"] = [by_event.get(name, "") for name, _, _ in device["ops"]]
         elif plane.name.startswith("/host:") and plane.name != "/host:metadata":
             for line in plane.lines:
                 for e in line.events:
                     if SPAN_NAME.match(e.name):
                         host.append((e.name, e.start_ns * 1e-9,
                                      (e.start_ns + e.duration_ns) * 1e-9, line.name))
-    return {"devices": devices, "host": host}
+    trace = {"devices": devices, "host": host}
+    if scopes:
+        trace["scopes"] = list(scopes)
+    return trace
 
 
 def _varint(buf: memoryview, pos: int) -> Tuple[int, int]:
@@ -155,6 +177,90 @@ def plane_sizes(raw: bytes, lines_kept: int = 4) -> Dict[str, Dict[str, Any]]:
             "lines": {n: {"bytes": b, "events": e} for b, e, n in lines[:lines_kept]},
         }
     return out
+
+
+# the stat of an op's XEventMetadata that carries jax's ``op_name``
+# (``jit(_step)/transpose(jvp(TransformerNet))/attn0/q/dot_general:``
+# on the v5e, a colon and the op's type behind the path)
+OP_NAME_STAT = "tf_op"
+
+
+def op_names_by_event(raw: bytes) -> Dict[str, Dict[str, str]]:
+    """plane -> event name -> jax ``op_name``, for the device planes of a
+    serialized profile.  ``ProfileData`` gives an event its own stats only;
+    the ``op_name`` is a stat of the event's *metadata*, which every event
+    of one HLO op shares, so it is read off the wire once per op
+    (``XPlane.event_metadata`` = 4 and ``.stat_metadata`` = 5, maps whose
+    entries hold the value under 2; ``XEventMetadata.name`` = 2, ``.stats``
+    = 5; ``XStatMetadata.id`` = 1, ``.name`` = 2; ``XStat.metadata_id`` = 1,
+    ``.str_value`` = 5, ``.ref_value`` = 7, a stat metadata's id whose name
+    is the string)."""
+    out: Dict[str, Dict[str, str]] = {}
+    for number, plane in _fields(memoryview(raw)):
+        if number != 1:
+            continue
+        plane_name, events, stat_names = "", [], {}
+        for field, payload in _fields(plane):
+            if field == 2:
+                plane_name = bytes(payload).decode(errors="replace")
+            elif field in (4, 5):
+                value = next((v for n, v in _fields(payload) if n == 2), None)
+                if value is None:
+                    continue
+                if field == 4:
+                    events.append(value)
+                else:
+                    numbers, strings = _scalars(value)
+                    stat_names[numbers.get(1, 0)] = strings.get(2, "")
+        if not DEVICE_PLANE.match(plane_name):
+            continue
+        wanted = {i for i, n in stat_names.items() if n == OP_NAME_STAT}
+        names: Dict[str, str] = {}
+        for event in events:
+            event_name = op_name = ""
+            for field, payload in _fields(event):
+                if field == 2:
+                    event_name = bytes(payload).decode(errors="replace")
+                elif field == 5:
+                    numbers, strings = _scalars(payload)
+                    if numbers.get(1) in wanted:
+                        op_name = strings.get(5) or stat_names.get(numbers.get(7, -1), "")
+            if op_name:
+                names[event_name] = op_name
+        out[plane_name] = names
+    return out
+
+
+def _scalars(buf: memoryview) -> Tuple[Dict[int, int], Dict[int, str]]:
+    """The varint fields and the string fields of one small message."""
+    numbers: Dict[int, int] = {}
+    strings: Dict[int, str] = {}
+    pos, end = 0, len(buf)
+    while pos < end:
+        key, pos = _varint(buf, pos)
+        kind = key & 7
+        if kind == 0:
+            numbers[key >> 3], pos = _varint(buf, pos)
+        elif kind == 2:
+            size, pos = _varint(buf, pos)
+            strings[key >> 3] = bytes(buf[pos:pos + size]).decode(errors="replace")
+            pos += size
+        else:
+            pos += 8 if kind == 1 else 4
+    return numbers, strings
+
+
+def scopes_of(op_name: str, scopes: Sequence[str]) -> List[str]:
+    """The ``scopes`` that are a path component of ``op_name``.  jax wraps
+    the component a transform entered at (``transpose(jvp(attn0))``), so a
+    component counts with its wrappers peeled; ``attn1`` is no component of
+    ``.../attn10/...``."""
+    parts = set()
+    for part in op_name.rsplit(":", 1)[0].split("/"):
+        while part.endswith(")") and "(" in part:
+            part = part[part.index("(") + 1:-1]
+        parts.add(part)
+    return [name for name in scopes if name in parts]
 
 
 # ---------------------------------------------------------------------------
@@ -279,8 +385,12 @@ def short_op_name(name: str) -> str:
 # ---------------------------------------------------------------------------
 
 
-def _reduce_device(dev: Dict[str, List[Interval]], lo: float, hi: float) -> Dict[str, Any]:
+def _reduce_device(dev: Dict[str, List[Interval]], lo: float, hi: float,
+                   scopes: Sequence[str] = ()) -> Dict[str, Any]:
     ops, modules, async_ops = dev["ops"], dev["modules"], dev.get("async_ops", [])
+    op_names = dev.get("op_names") or [""] * len(ops)
+    scope_table: Dict[str, List[float]] = {name: [0.0, 0] for name in scopes}
+    scopes_cached: Dict[str, List[str]] = {}
     source = ops if ops else modules
     busy_segments = clip(merge((s, e) for _, s, e in source), lo, hi)
     busy = measure(busy_segments)
@@ -319,6 +429,13 @@ def _reduce_device(dev: Dict[str, List[Interval]], lo: float, hi: float) -> Dict
                 slot = op_table.setdefault(short_op_name(name), [0.0, 0])
                 slot[0] += float(self_s[pos])
                 slot[1] += 1
+                if scopes and op_names[idx]:
+                    path = op_names[idx]
+                    if path not in scopes_cached:
+                        scopes_cached[path] = scopes_of(path, scopes)
+                    for scope in scopes_cached[path]:
+                        scope_table[scope][0] += float(self_s[pos])
+                        scope_table[scope][1] += 1
         coll_iv += [(s, e) for n, s, e in async_ops if COLLECTIVE.match(n)]
         coll_segments = clip(merge(coll_iv), lo, hi)
         collective = measure(coll_segments)
@@ -326,7 +443,7 @@ def _reduce_device(dev: Dict[str, List[Interval]], lo: float, hi: float) -> Dict
     return {
         "busy_s": busy, "busy_segments": busy_segments, "programs": programs,
         "ops": op_table, "collective_s": collective,
-        "collective_exposed_s": exposed,
+        "collective_exposed_s": exposed, "scopes": scope_table,
     }
 
 
@@ -358,7 +475,8 @@ def reduce_trace(trace: Dict[str, Any], window: Optional[Tuple[float, float]] = 
             raise ValueError("the trace holds no device event")
         window = (min(s for _, s, _ in every), max(e for _, _, e in every))
     lo, hi = window
-    per_device = {n: _reduce_device(devices[n], lo, hi) for n in sorted(devices)}
+    scopes = trace.get("scopes") or ()
+    per_device = {n: _reduce_device(devices[n], lo, hi, scopes) for n in sorted(devices)}
     count = len(per_device)
     first = per_device[min(per_device)]
 
@@ -388,7 +506,13 @@ def reduce_trace(trace: Dict[str, Any], window: Optional[Tuple[float, float]] = 
     longest = [(names[i], float(lengths[long][i]), float(gaps[long][i, 0] - lo)) for i in top]
 
     mean = lambda key: sum(d[key] for d in per_device.values()) / count  # noqa: E731
-    return {
+    by_scope: Dict[str, Dict[str, float]] = {}
+    for name in scopes:
+        seconds, ops = (sum(d["scopes"][name][i] for d in per_device.values()) / count
+                        for i in (0, 1))
+        if ops:         # a scope no op carries is left out: ``run.scope`` answers None
+            by_scope[name] = {"seconds": seconds, "ops": ops}
+    reduced = {
         "window_s": hi - lo,
         "chips": count,
         "busy_s": mean("busy_s"),
@@ -403,6 +527,9 @@ def reduce_trace(trace: Dict[str, Any], window: Optional[Tuple[float, float]] = 
         "host": [s for s in trace.get("host", []) if s[2] > lo and s[1] < hi],
         "per_device_busy_s": [d["busy_s"] for d in per_device.values()],
     }
+    if scopes:
+        reduced["scopes"] = by_scope
+    return reduced
 
 
 def breakdown(reduced: Dict[str, Any]) -> Dict[str, List[List[Any]]]:
