@@ -111,7 +111,8 @@ class BaseEnvironment:
     def net(self):
         """Return the Flax module for this game (policy/value net).
 
-        Honors ``env_args['net'] == 'transformer'`` for every environment:
+        Honors ``env_args['net'] == 'transformer'`` (and ``'hybrid'``, the
+        layer-pattern family of models/hybrid.py) for every environment:
         the generic KV-cache memory family (models/transformer.py) sized by
         ``transformer_spec()``, with ``env_args['net_args']`` merged over
         the spec — so configs can scale the family (d_model, n_layers,
@@ -125,6 +126,15 @@ class BaseEnvironment:
             spec = dict(self.transformer_spec())
             spec.update(self.args.get("net_args") or {})
             return TransformerNet(**spec)
+        if self.args.get("net") == "hybrid":
+            # the layer-pattern family (models/hybrid.py): the same head
+            # sizes, ``net_args`` carrying the pattern and the widths
+            from ..models import HybridNet
+
+            spec = {k: v for k, v in self.transformer_spec().items()
+                    if k in ("num_actions", "with_return")}
+            spec.update(self.args.get("net_args") or {})
+            return HybridNet(**spec)
         return self.default_net()
 
     def default_net(self):

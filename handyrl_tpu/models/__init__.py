@@ -1,5 +1,6 @@
 from .nets import SimpleConvNet, GeeseNet, GeisterNet
 from .transformer import TransformerNet
+from .hybrid import HybridNet
 from .inference import (
     InferenceModel,
     RandomModel,
@@ -14,6 +15,7 @@ __all__ = [
     "GeeseNet",
     "GeisterNet",
     "TransformerNet",
+    "HybridNet",
     "InferenceModel",
     "RandomModel",
     "build_inference_model",

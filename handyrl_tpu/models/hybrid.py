@@ -1,0 +1,441 @@
+"""A hybrid policy/value trunk built from a layer-pattern string, after the
+``nemotron_h`` family: ``M`` a Mamba-2 mixer, ``E`` a routed expert layer
+with a shared expert, ``*`` grouped-query causal attention.  Each layer is
+``x + mixer(RMSNorm(x))``; a final RMSNorm feeds the heads; no biases but
+the conv's.  One token is one player's observation at one env step: the
+flattened observation through ``enc1``/``enc2`` stands where a language
+model has its embedding, the policy/value/return heads where it has its
+LM head (the same encoder and heads as ``TransformerNet``).
+
+The hidden pytree holds, per layer, what that mixer carries between steps:
+the SSM state (float32) and the conv's last inputs for ``M``, a ring of the
+last ``memory_len`` keys and values for ``*``, nothing for ``E``.  Like
+``TransformerNet`` it has two modes over one parameter set:
+
+* step mode — ``apply(obs, hidden)``: one step of every recurrence (acting,
+  and the train step's scan path, which commits hidden only where observed);
+* whole-window mode — ``seq=True``: a (rows, T) window at once, the scan in
+  its chunked matmul form.  The scan path's rules are kept exactly: an
+  unobserved step (``key_mask`` 0) leaves every state as it was, which the
+  window form gets by moving each row's observed steps to the front
+  (``_compact``), running every mixer on that prefix, and moving the
+  results back; burn-in steps run first, as a window of their own, and what
+  they leave (SSM state, conv tail, keys and values) is handed on under
+  ``stop_gradient``.
+
+``E`` layers are told which experts they hold (``experts_held``,
+``expert_offset``): they score and choose over all ``n_experts`` and add
+their own experts' terms only (``ops/routed_experts.py``).  The window mode
+returns, beside the heads, ``choices`` (per ``E`` layer the experts each
+token chose, (rows, T, top_k)) and ``counters`` (rows the held experts
+computed); ``forward_prediction`` hands both on.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Sequence
+
+import jax
+import jax.numpy as jnp
+import flax.linen as nn
+import numpy as np
+
+from ..ops.routed_experts import choose, held_mix
+from ..ops.ssd import ssd_chunked, ssd_step
+from .transformer import NEG_INF, _flatten_obs
+
+KINDS = "ME*"
+_EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
+
+
+def _dense(features: int, name: str):
+    return nn.Dense(features, use_bias=False, name=name)
+
+
+def _rms(x, scale, eps: float, groups: int = 1):
+    """RMSNorm over the last axis in ``groups`` equal parts, in float32."""
+    shape = x.shape
+    y = x.astype(jnp.float32).reshape(shape[:-1] + (groups, shape[-1] // groups))
+    y = y * jax.lax.rsqrt(jnp.square(y).mean(axis=-1, keepdims=True) + eps)
+    return (y.reshape(shape) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _compact(key_mask):
+    """(place (N, T, T), valid (N, T)): ``place[n, i, t]`` is 1 where step
+    ``t`` is row ``n``'s ``i``-th observed step; ``valid[n, i]`` says there
+    is an ``i``-th."""
+    seen = key_mask > 0
+    order = jnp.argsort(~seen, axis=1, stable=True)                 # observed steps first
+    valid = jnp.arange(seen.shape[1])[None, :] < seen.sum(axis=1, keepdims=True)
+    place = (order[:, :, None] == jnp.arange(seen.shape[1])[None, None, :]) & valid[:, :, None]
+    return place, valid
+
+
+class Mamba2Mixer(nn.Module):
+    d_model: int
+    heads: int
+    head_dim: int
+    groups: int
+    state_size: int
+    conv_kernel: int
+    chunk: int
+    eps: float
+    dt_min: float
+    dt_max: float
+    dt_floor: float
+
+    @nn.compact
+    def __call__(self, u, state, valid=None):
+        """u (N, L, d) with ``valid`` (N, L) a prefix mask, or (N, d) for
+        one step; state {"ssm", "conv"} -> (out, new state)."""
+        H, P, G, S, K = self.heads, self.head_dim, self.groups, self.state_size, self.conv_kernel
+        inner, conv_dim = H * P, H * P + 2 * G * S
+        step = u.ndim == 2
+        if step:
+            u = u[:, None]
+        n, length = u.shape[:2]
+
+        def dt_bias_init(key, shape):
+            # the inverse softplus of a log-uniform draw in [dt_min, dt_max]
+            dt = jnp.exp(jax.random.uniform(key, shape)
+                         * (np.log(self.dt_max) - np.log(self.dt_min)) + np.log(self.dt_min))
+            dt = jnp.maximum(dt, self.dt_floor)
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (K, conv_dim))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+            (H,))
+        skip = self.param("D", nn.initializers.ones, (H,))
+        norm_scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+
+        z, xbc, dt = jnp.split(_dense(inner + conv_dim + H, "in_proj")(u),
+                               [inner, inner + conv_dim], axis=-1)
+        # causal depthwise conv over the last K - 1 inputs and this one
+        tail = state["conv"].astype(xbc.dtype)
+        fed = jnp.concatenate([tail, xbc], axis=1)                   # (N, K - 1 + L, C)
+        conv = sum(fed[:, k:k + length] * conv_w[k].astype(xbc.dtype) for k in range(K))
+        xbc_c = jax.nn.silu(conv + conv_b.astype(xbc.dtype))
+        if valid is None:
+            new_tail = fed[:, length:]
+        else:   # the last K - 1 inputs of the observed prefix
+            last = valid.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]
+            new_tail = jnp.take_along_axis(fed, last[..., None], axis=1)
+        x, B, C = jnp.split(xbc_c, [inner, inner + G * S], axis=-1)
+        x = x.reshape(n, length, H, P)
+        B, C = B.reshape(n, length, G, S), C.reshape(n, length, G, S)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+        if valid is not None:
+            dt = dt * valid[..., None]
+        A = -jnp.exp(a_log.astype(jnp.float32))
+        with jax.named_scope("ssd"):
+            if step:
+                y, ssm = ssd_step(x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], state["ssm"])
+                y = y[:, None]
+            else:
+                y, ssm = ssd_chunked(x, dt, A, B, C, state["ssm"], self.chunk)
+        y = y.astype(jnp.float32) + skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+        y = y.reshape(n, length, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = _rms(y, norm_scale, self.eps, groups=G).astype(u.dtype)
+        out = _dense(self.d_model, "out_proj")(y)
+        return (out[:, 0] if step else out), {"ssm": ssm, "conv": new_tail.astype(jnp.float32)}
+
+
+class ExpertLayer(nn.Module):
+    d_model: int
+    n_experts: int
+    top_k: int
+    expert_width: int
+    shared_width: int
+    routed_scale: float
+    experts_held: int
+    expert_offset: int
+
+    @nn.compact
+    def __call__(self, h, valid=None):
+        """h (..., d) tokens, valid (...) -> (out, chosen (..., k) int32,
+        rows (held,) int32 the held experts computed)."""
+        lead, d = h.shape[:-1], h.shape[-1]
+        tokens = h.reshape(-1, d)
+        ok = jnp.ones(tokens.shape[:1], bool) if valid is None else valid.reshape(-1)
+        fan_in = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+                                                  batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(), (d, self.n_experts))
+        # chooses only; no gradient reaches it (top-k's indices carry none)
+        bias = self.param("score_bias", nn.initializers.zeros, (self.n_experts,))
+        w1 = self.param("w1", fan_in, (self.experts_held, d, self.expert_width))
+        w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d))
+        with jax.named_scope("route"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT))
+            chosen, gates = choose(scores, bias, self.top_k, self.routed_scale)
+        routed, rows = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype), w2.astype(h.dtype),
+                                self.expert_offset, self.n_experts)
+        with jax.named_scope("shared_expert"):
+            up = _dense(self.shared_width, "shared_up")(tokens)
+            shared = _dense(d, "shared_down")(jnp.square(nn.relu(up)))
+        return ((routed + shared).reshape(lead + (d,)),
+                chosen.reshape(lead + (self.top_k,)), rows)
+
+
+class GroupedQueryAttention(nn.Module):
+    d_model: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    memory_len: int
+
+    @nn.compact
+    def __call__(self, h, state, valid=None):
+        """Window mode: h (N, L, d) with ``valid`` a prefix mask, state
+        {"k", "v" (N, L0, kv, D), "n" (N,)} the observed steps before this
+        window.  Step mode: h (N, d), state {"k", "v" (N, memory_len, kv,
+        D), "pos" (N,)} a ring.  Returns (out, new state)."""
+        Hq, Hk, D = self.heads, self.kv_heads, self.head_dim
+        step = h.ndim == 2
+        if step:
+            h = h[:, None]
+        n, length = h.shape[:2]
+        q = _dense(Hq * D, "q")(h).reshape(n, length, Hk, Hq // Hk, D)
+        k = _dense(Hk * D, "k")(h).reshape(n, length, Hk, D)
+        v = _dense(Hk * D, "v")(h).reshape(n, length, Hk, D)
+        with jax.named_scope("gqa"):
+            if step:
+                S = self.memory_len
+                slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
+                hot = jax.nn.one_hot(slot, S, dtype=jnp.float32)[..., None, None]
+                keys = state["k"] * (1 - hot) + hot * k.astype(jnp.float32)
+                values = state["v"] * (1 - hot) + hot * v.astype(jnp.float32)
+                age = jnp.mod(slot[:, None] - jnp.arange(S)[None, :], S)
+                allowed = (age < jnp.minimum(state["pos"] + 1, S)[:, None])[:, None, :]
+                new_state = {"k": keys, "v": values}
+            else:
+                before = state["n"].astype(jnp.int32)
+                keys = jnp.concatenate([state["k"].astype(k.dtype), k], axis=1)
+                values = jnp.concatenate([state["v"].astype(v.dtype), v], axis=1)
+                past = state["k"].shape[1]
+                # positions count observed steps: the past's come first
+                key_pos = jnp.concatenate([
+                    jnp.broadcast_to(jnp.arange(past)[None, :], (n, past)),
+                    before[:, None] + jnp.arange(length)[None, :]], axis=1)
+                key_ok = jnp.concatenate([
+                    jnp.arange(past)[None, :] < before[:, None], valid], axis=1)
+                query_pos = before[:, None] + jnp.arange(length)[None, :]
+                gap = query_pos[:, :, None] - key_pos[:, None, :]
+                allowed = key_ok[:, None, :] & (gap >= 0) & (gap < self.memory_len)
+                new_state = {"k": keys, "v": values, "n": before + valid.sum(axis=1)}
+            scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, keys.astype(q.dtype),
+                                preferred_element_type=jnp.float32) / (D ** 0.5)
+            scores = jnp.where(allowed[:, None, None], scores, NEG_INF)
+            weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            out = jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype))
+        out = _dense(self.d_model, "o")(out.reshape(n, length, Hq * D))
+        return (out[:, 0] if step else out), new_state
+
+
+class Layer(nn.Module):
+    """``x + mixer(RMSNorm(x))``; a mixer that keeps no state hands the
+    state it was given back, one that routes says what it chose."""
+
+    mixer: nn.Module
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, state, valid):
+        h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],)), self.eps)
+        if isinstance(self.mixer, ExpertLayer):
+            y, chosen, rows = self.mixer(h, valid)
+            return x + y, state, (chosen, rows)
+        y, state = self.mixer(h, state, valid)
+        return x + y, state, None
+
+
+class HybridNet(nn.Module):
+    """``pattern`` spells the layers (``"MEMEM*EME"``); the widths default to
+    a size tests run and are set by ``env_args['net_args']``."""
+
+    num_actions: int
+    pattern: str = "ME*"
+    d_model: int = 64
+    with_return: bool = False
+    norm_eps: float = 1e-5
+    # M: Mamba-2
+    mamba_heads: int = 4
+    mamba_head_dim: int = 16
+    n_groups: int = 2
+    state_size: int = 16
+    conv_kernel: int = 4
+    chunk: int = 128
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    # E: routed experts and a shared one
+    n_experts: int = 8
+    top_k: int = 2
+    expert_width: int = 32
+    shared_width: int = 64
+    routed_scale: float = 2.5
+    experts_held: int = 8
+    expert_offset: int = 0
+    # *: grouped-query attention
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    memory_len: int = 32
+    supports_seq: bool = True  # train path may call with seq=True
+
+    def _mixer(self, kind: str):
+        # parentless: the Layer it is handed to adopts it, as ``mixer``
+        if kind == "M":
+            return Mamba2Mixer(
+                self.d_model, self.mamba_heads, self.mamba_head_dim, self.n_groups,
+                self.state_size, self.conv_kernel, self.chunk, self.norm_eps,
+                self.dt_min, self.dt_max, self.dt_floor, parent=None)
+        if kind == "E":
+            return ExpertLayer(
+                self.d_model, self.n_experts, self.top_k, self.expert_width, self.shared_width,
+                self.routed_scale, self.experts_held, self.expert_offset, parent=None)
+        return GroupedQueryAttention(
+            self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
+            parent=None)
+
+    @staticmethod
+    def _through(layers, x, states, valid):
+        """Every layer once over ``x`` ((N, L, d) with ``valid``, or (N, d)):
+        -> (x, new states, {layer: chosen}, {layer: rows})."""
+        new_states, chosen, rows = [], {}, {}
+        for layer, state in zip(layers, states):
+            x, state, routed = layer(x, state, valid)
+            new_states.append(state)
+            if routed is not None:
+                chosen[layer.name], rows[layer.name] = routed
+        return x, tuple(new_states), chosen, rows
+
+    def _heads(self, x):
+        h = _rms(x, self.param("norm_f", nn.initializers.ones, (self.d_model,)), self.norm_eps)
+        out: Dict[str, Any] = {
+            "policy": nn.Dense(self.num_actions, name="policy")(h),
+            "value": jnp.tanh(nn.Dense(1, name="value")(h)),
+        }
+        if self.with_return:
+            out["return"] = nn.Dense(1, name="return_head")(h)
+        return out
+
+    @nn.compact
+    def __call__(self, obs, hidden=None, train: bool = False, *,
+                 seq: bool = False, key_mask=None, burn_in: int = 0, remat: str = "none"):
+        if any(kind not in KINDS for kind in self.pattern):
+            raise ValueError(f"pattern {self.pattern!r}: a layer is one of {KINDS!r}")
+        def encode(flat):
+            # the trunk computes in its parameters' dtype (bf16 under
+            # compute_dtype: bfloat16): _flatten_obs hands float32 over
+            enc1 = nn.Dense(self.d_model, name="enc1")
+            x = nn.relu(enc1(flat))
+            x = x.astype(enc1.variables["params"]["kernel"].dtype)
+            return nn.Dense(self.d_model, name="enc2")(x)
+
+        layers = lambda cls: [  # noqa: E731
+            cls(self._mixer(kind), self.norm_eps, name=f"layer{i}")
+            for i, kind in enumerate(self.pattern)]
+        if not seq:
+            if hidden is None:
+                hidden = self.initial_state((jax.tree.leaves(obs)[0].shape[0],))
+            states = tuple(
+                dict(state, pos=hidden["pos"]) if kind == "*" else state
+                for kind, state in zip(self.pattern, hidden["layers"]))
+            x, states, _, _ = self._through(layers(Layer), encode(_flatten_obs(obs)), states, None)
+            out = self._heads(x)
+            out["hidden"] = {"layers": states, "pos": hidden["pos"] + 1.0}
+            return out
+
+        # -- whole window: (N, T, ...) -----------------------------------
+        if remat not in ("none", "block"):   # no rung of its own for one mixer
+            raise ValueError(f"HybridNet: remat={remat!r} not one of ('none', 'block')")
+        x = encode(_flatten_obs(obs, 2))
+        n, T = x.shape[:2]
+        if key_mask is None:
+            key_mask = jnp.ones((n, T), x.dtype)
+        states = self._window_state(n, x.dtype)
+        # one checkpoint per layer where asked: only a layer's input is kept
+        stack = layers(Layer if remat == "none" else nn.remat(Layer))
+        outs, chosen, rows = [], [], []
+        for lo, hi in ((0, burn_in), (burn_in, T)):
+            if lo == hi:
+                continue
+            place, valid = _compact(key_mask[:, lo:hi])
+            place = place.astype(x.dtype)
+            packed = jnp.einsum("nit,ntd->nid", place, x[:, lo:hi], precision=_EXACT)
+            y, states, picked, count = self._through(stack, packed, states, valid)
+            if hi == burn_in:   # scan parity: no gradient through what burn-in leaves
+                states = jax.lax.stop_gradient(states)
+            outs.append(jnp.einsum("nit,nid->ntd", place, y, precision=_EXACT))
+            spread = place.astype(jnp.int32)
+            chosen.append({k: jnp.einsum("nit,nik->ntk", spread, v) for k, v in picked.items()})
+            rows.append(count)
+        out = self._heads(jnp.concatenate(outs, axis=1))
+        if chosen[0]:
+            out["choices"] = {k: jnp.concatenate([c[k] for c in chosen], axis=1)
+                              for k in chosen[0]}
+            by_layer = jnp.stack([sum(r[k] for r in rows) for k in rows[0]])   # (layers, held)
+            out["counters"] = {
+                "rows_held": by_layer.sum().astype(jnp.float32),
+                "expert_rows_max": by_layer.max().astype(jnp.float32),
+                "expert_rows_mean": by_layer.astype(jnp.float32).mean(),
+            }
+        return out
+
+    @nn.nowrap
+    def _window_state(self, n: int, dtype):
+        """What each mixer carries into a window that nothing precedes."""
+        states = []
+        for kind, state in zip(self.pattern, self.initial_state((n,))["layers"]):
+            if kind == "*":
+                empty = jnp.zeros((n, 0, self.n_kv_heads, self.head_dim), dtype)
+                state = {"k": empty, "v": empty, "n": jnp.zeros((n,), jnp.int32)}
+            states.append(state)
+        return tuple(states)
+
+    @nn.nowrap
+    def initial_state(self, batch_dims: Sequence[int] = ()):
+        bd = tuple(batch_dims)
+        inner = self.mamba_heads * self.mamba_head_dim
+        conv_dim = inner + 2 * self.n_groups * self.state_size
+        zeros = lambda *shape: jnp.zeros(bd + shape, jnp.float32)  # noqa: E731
+        layers = []
+        for kind in self.pattern:
+            if kind == "M":
+                layers.append({
+                    "ssm": zeros(self.mamba_heads, self.mamba_head_dim, self.state_size),
+                    "conv": zeros(self.conv_kernel - 1, conv_dim)})
+            elif kind == "*":
+                layers.append({
+                    "k": zeros(self.memory_len, self.n_kv_heads, self.head_dim),
+                    "v": zeros(self.memory_len, self.n_kv_heads, self.head_dim)})
+            else:
+                layers.append({})
+        # pos is float32 so the train step's observation-mask arithmetic on
+        # the hidden carry (h * mask) never changes the carry dtype
+        return {"layers": tuple(layers), "pos": jnp.zeros(bd, jnp.float32)}
+
+    @nn.nowrap
+    def layout(self) -> Dict[str, Any]:
+        """What ``TrainContext`` records when it builds this net: the pattern,
+        the experts held of how many, and the trunk's parameters by kind."""
+        d = self.d_model
+        inner = self.mamba_heads * self.mamba_head_dim
+        conv_dim = inner + 2 * self.n_groups * self.state_size
+        each = {
+            "M": d + d * (inner + conv_dim + self.mamba_heads) + (self.conv_kernel + 1) * conv_dim
+            + 3 * self.mamba_heads + inner + inner * d,
+            "*": d + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
+            "E": d + d * self.n_experts + self.n_experts + 2 * d * self.shared_width
+            + 2 * self.experts_held * d * self.expert_width,
+        }
+        return {
+            "pattern": self.pattern, "experts_held": self.experts_held,
+            "experts": self.n_experts, "expert_offset": self.expert_offset,
+            **{f"params_{name}": self.pattern.count(kind) * each[kind]
+               for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"))},
+        }

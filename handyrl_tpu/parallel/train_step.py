@@ -163,6 +163,12 @@ def sums_own_grads(module, args: Dict[str, Any]) -> bool:
     )
 
 
+# beside the heads, a net's whole-window call may return these two: the
+# discrete choices a routed layer made (a pytree of integer arrays shaped
+# like a head, the chosen indices on the last axis) and scalars it counted
+CHOICES, COUNTERS = "choices", "counters"
+
+
 def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, Any],
                        sum_grads=None) -> Dict[str, Any]:
     """Run the net over a (B, T, P, ...) batch; returns post-burn-in outputs
@@ -176,7 +182,14 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
     With ``compute_dtype: bfloat16`` the forward runs in bf16 (params are
     cast by the caller; observations/hidden here) — MXU-rate compute with
     fp32 master weights.  Outputs are restored to fp32 before the masking
-    arithmetic (the 1e32 action mask is not bf16-representable)."""
+    arithmetic (the 1e32 action mask is not bf16-representable).
+
+    A net whose whole-window call returns ``choices`` or ``counters`` gets
+    them back under those keys: the choices cut to the forward steps like a
+    head and otherwise untouched (with burn-in, as ``{"forward": ...,
+    "window_start": ...}``: the forward steps', and the window's first
+    ``forward_steps`` steps', which hold the burn-in steps'), the counters as
+    they are."""
     cdt = _compute_dtype(args)
     obs = batch["observation"]
     if any(x.dtype == jnp.int8 for x in jax.tree.leaves(obs)):
@@ -244,18 +257,38 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
             # mesh shape + T divisibility are validated up front by
             # TrainContext.__init__ (fail-fast); args['_mesh'] is set there
             ring_mesh = args.get("_mesh")
-        outs = module.apply(
-            {"params": params}, obs_bp, None, seq=True, key_mask=km,
-            burn_in=burn_in, use_flash=mode == "flash", ring_mesh=ring_mesh,
-            remat=resolve_seq_remat(args, T),
+        keywords = dict(
+            seq=True, key_mask=km, burn_in=burn_in, use_flash=mode == "flash",
+            ring_mesh=ring_mesh, remat=resolve_seq_remat(args, T),
             blk_q=int(args.get("blk_q", 128)), blk_k=int(args.get("blk_k", 128)),
             **({"sum_grads": sum_grads} if sum_grads is not None else {}),
         )
+        # a net is handed the keywords its whole-window call takes
+        takes = inspect.signature(module.__call__).parameters
+        outs = module.apply(
+            {"params": params}, obs_bp, None,
+            **{k: v for k, v in keywords.items() if k in takes},
+        )
+        # what a net counts on the device rides beside its outputs; every
+        # other leaf is rows x steps (heads, and a routed net's choices)
+        counted = outs.pop(COUNTERS, None)
+        to_btp = lambda v: jnp.moveaxis(v.reshape((B, P1, T) + v.shape[2:]), 1, 2)  # noqa: E731
         outputs = {
-            k: jnp.moveaxis(v.reshape((B, P1, T) + v.shape[2:]), 1, 2)[:, burn_in:]
-            for k, v in outs.items()
+            k: tree_map(lambda v: to_btp(v)[:, burn_in:], v) for k, v in outs.items()
             if k != "hidden" and v is not None
         }
+        if burn_in and CHOICES in outputs:
+            # what a routed layer chose on the burn-in steps reaches the
+            # forward steps through the state those steps leave, so a reader
+            # of the choices gets them too: the window's first steps, in a
+            # second leaf of the same shape (it overlaps the first one where
+            # burn_in < forward_steps)
+            outputs[CHOICES] = {
+                "forward": outputs[CHOICES],
+                "window_start": tree_map(lambda v: to_btp(v)[:, :T - burn_in], outs[CHOICES]),
+            }
+        if counted is not None:
+            outputs[COUNTERS] = counted
     else:
         omask = batch["observation_mask"]
         assert omask.shape[2] == P1, (
@@ -337,6 +370,9 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
 
     masked = {}
     for k, v in outputs.items():
+        if k in (CHOICES, COUNTERS):  # a routed net's: untouched by the head masking
+            masked[k] = v
+            continue
         v = v.astype(jnp.float32)  # loss/target math stays fp32
         if k == "policy":
             v = v * tmask
@@ -439,6 +475,17 @@ class TrainContext:
                 "way — set observation: true, or turn_based_training: "
                 "false, to proceed.)"
             )
+        if hasattr(module, "layout"):
+            # a net that says how it is laid out (HybridNet: the pattern, the
+            # experts held of how many) holds its experts' rows on one chip:
+            # no expert exchange and no section-wise gradient sum exist yet
+            if mesh.size != 1:
+                raise ValueError(
+                    f"{type(module).__name__} trains on mesh {{'dp': 1}} only (got "
+                    f"{dict(mesh.shape)}): it has no sum_grads and its expert layers "
+                    "no exchange across chips"
+                )
+            trace_event("model.layout", 0.0, plane="learner", **module.layout())
         self.mesh = mesh
         self.tx = make_optimizer()
         self._replicated = replicated_sharding(mesh)
@@ -504,9 +551,13 @@ class TrainContext:
                 self.grad_sync = grad_sync_counts(fwd_params, mesh.shape[sync_axis])
                 trace_event("train.grad_sync", 0.0, plane="learner", **self.grad_sync)
             outputs = forward_prediction(self.module, fwd_params, batch, self.args, sync_sum)
+            outputs.pop(CHOICES, None)
+            counted = outputs.pop(COUNTERS, {})
             trimmed = trim_burn_in(batch, self.args["burn_in_steps"])
             losses, dcnt = compute_loss_from_outputs(outputs, trimmed, self.args)
             full = {k: losses.get(k, jnp.zeros(())) for k in loss_keys}
+            # what the net counted rides with the losses into the step's metrics
+            full.update({"counter_" + k: jax.lax.stop_gradient(v) for k, v in counted.items()})
             return losses["total"], (full, dcnt)
 
         # Divergence sentinel (config: sentinel, default on): finite-checks

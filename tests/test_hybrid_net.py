@@ -1,0 +1,417 @@
+"""HybridNet (models/hybrid.py: Mamba-2 mixers, routed experts with a shared
+one, grouped-query attention, by a layer-pattern string) at tiny widths on
+the CPU, against the plain reference of the configuration it was written
+for (benchmark/reference/nemotron_twotower_30b_a3b.py, which imports
+nothing from handyrl_tpu.models), through ``forward_prediction`` and the
+train step.
+"""
+
+import importlib.util
+import json
+import os
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from handyrl_tpu.config import normalize_args
+from handyrl_tpu.envs import make_env
+from handyrl_tpu.models import HybridNet
+from handyrl_tpu.models.hybrid import ExpertLayer
+from handyrl_tpu.ops.routed_experts import BLOCK, choose, held_mix, row_buffer
+from handyrl_tpu.ops.ssd import ssd_chunked, ssd_step
+from handyrl_tpu.parallel import TrainContext, make_mesh
+from handyrl_tpu.parallel.train_step import forward_prediction
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(*parts):
+    path = os.path.join(REPO, "benchmark", *parts)
+    spec = importlib.util.spec_from_file_location("hybrid_" + parts[-1][:-3], path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+REFERENCE = _load("reference", "nemotron_twotower_30b_a3b.py")
+FLOPS = _load("flops", "nemotron_h.py")
+
+NET = dict(
+    pattern="MEM*E", d_model=32, norm_eps=1e-5,
+    mamba_heads=4, mamba_head_dim=16, n_groups=2, state_size=16, conv_kernel=4, chunk=4,
+    n_experts=8, top_k=2, expert_width=32, shared_width=64, routed_scale=2.5,
+    experts_held=4, expert_offset=2,
+    n_heads=4, n_kv_heads=2, head_dim=16, memory_len=200,
+)
+
+
+def _config(**net):
+    return {"name": "tiny_hybrid", "env_args": {"env": "Geister", "net": "hybrid",
+                                                "net_args": dict(NET, **net)}}
+
+
+def _window(seed, rows=3, steps=10, width=7, observed=0.6):
+    """(obs, key_mask): ``rows`` sequences of ``steps`` steps, each step
+    observed with probability ``observed``."""
+    rng = np.random.RandomState(seed)
+    obs = {"a": jnp.asarray(rng.randn(rows, steps, width), jnp.float32)}
+    return obs, jnp.asarray(rng.rand(rows, steps) < observed, jnp.float32)
+
+
+def _params(module, obs, seed=0, score_bias=0.0):
+    params = module.init(jax.random.PRNGKey(seed), jax.tree.map(lambda x: x[:, 0], obs), None)["params"]
+    if score_bias:
+        rng = np.random.RandomState(seed)
+        for name in [n for n in params if n.startswith("layer")]:
+            if "score_bias" in params[name]["mixer"]:
+                bias = score_bias * rng.randn(*params[name]["mixer"]["score_bias"].shape)
+                params[name]["mixer"]["score_bias"] = jnp.asarray(bias, jnp.float32)
+    return params
+
+
+# -- the system against the plain reference, float32 ----------------------
+
+
+@pytest.mark.parametrize("pattern", ["M", "E", "*", "MEM*E", "MEMEM*EME"])
+def test_window_matches_the_reference_per_mixer_and_whole(pattern):
+    config = _config(pattern=pattern)
+    module = HybridNet(num_actions=5, with_return=True, **config["env_args"]["net_args"])
+    obs, mask = _window(1)
+    params = _params(module, obs, score_bias=0.2)
+    got = module.apply({"params": params}, obs, None, seq=True, key_mask=mask)
+    want = REFERENCE.forward(params, obs, mask, config)
+    for head in ("policy", "value", "return"):
+        np.testing.assert_allclose(
+            np.asarray(got[head]) * np.asarray(mask)[..., None],
+            np.asarray(want[head]) * np.asarray(mask)[..., None], atol=2e-5)
+    if "E" in pattern:
+        for layer, chosen in want["choices"].items():
+            assert np.array_equal(np.sort(np.asarray(got["choices"][layer]), -1),
+                                  np.sort(np.asarray(chosen), -1))
+
+
+@pytest.mark.parametrize("steps", [6, 12])
+def test_a_window_of_one_and_a_half_and_of_three_chunks(steps):
+    """The state is passed between chunks of 4: 6 steps are 1.5 chunks, 12 are 3."""
+    config = _config(pattern="MM")
+    module = HybridNet(num_actions=5, **config["env_args"]["net_args"])
+    obs, _ = _window(2, steps=steps)
+    mask = jnp.ones((3, steps))
+    params = _params(module, obs)
+    got = module.apply({"params": params}, obs, None, seq=True, key_mask=mask)
+    want = REFERENCE.forward(params, obs, mask, config)
+    np.testing.assert_allclose(got["policy"], want["policy"], atol=2e-5)
+
+
+def test_chunked_scan_is_the_recurrence():
+    rng = np.random.RandomState(0)
+    n, length, h, p, g, s = 2, 11, 4, 8, 2, 6
+    x = jnp.asarray(rng.randn(n, length, h, p), jnp.float32)
+    B, C = (jnp.asarray(rng.randn(n, length, g, s), jnp.float32) for _ in range(2))
+    dt = jnp.asarray(rng.rand(n, length, h) * (rng.rand(n, length, 1) > 0.3), jnp.float32)
+    A = -jnp.asarray(rng.rand(h) * 4 + 0.5, jnp.float32)
+    state = jnp.asarray(rng.randn(n, h, p, s), jnp.float32)
+    y, last = ssd_chunked(x, dt, A, B, C, state, 4)
+    want = []
+    for t in range(length):
+        y_t, state = ssd_step(x[:, t], dt[:, t], A, B[:, t], C[:, t], state)
+        want.append(y_t)
+    np.testing.assert_allclose(y, jnp.stack(want, axis=1), atol=1e-4)
+    np.testing.assert_allclose(last, state, atol=1e-4)
+
+
+# -- whole window against step mode, through forward_prediction ------------
+
+
+def _geister(train_args, seed=1, **net):
+    config = _config(**net)
+    cfg = normalize_args({"env_args": dict(config["env_args"]),
+                          "train_args": dict(train_args, observation=True, seed=seed)})
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    random.seed(seed)
+    np.random.seed(seed)
+    env = make_env(args["env"])
+    return config, args, env, env.net()
+
+
+@pytest.fixture(scope="module")
+def geister():
+    from benchmark import traffic
+
+    config, args, env, module = _geister(
+        {"batch_size": 3, "burn_in_steps": 3, "forward_steps": 9})
+    assert isinstance(module, HybridNet) and module.with_return
+    params = traffic.seeded_params(module, env, 1)
+    batch = traffic.random_play_batches(env, module, args, 1, 4)[0]
+    # Geister's players observe on their own turns: unobserved steps abound
+    assert 0.2 < float(np.mean(batch["observation_mask"])) < 0.8
+    return config, args, module, params, batch
+
+
+def test_whole_window_matches_the_scan_path_with_unobserved_steps_and_burn_in(geister):
+    _, args, module, params, batch = geister
+    window = jax.jit(lambda p, b: forward_prediction(module, p, b, args))(params, batch)
+    scan = jax.jit(lambda p, b: forward_prediction(
+        module, p, b, dict(args, seq_forward=False)))(params, batch)
+    for head in ("policy", "value", "return"):
+        np.testing.assert_allclose(window[head], scan[head], atol=2e-5)
+    assert "choices" not in scan and set(window["choices"]) == {"forward", "window_start"}
+    chosen = window["choices"]["forward"]
+    assert set(chosen) == {"layer1", "layer4"} and chosen["layer1"].shape == (3, 9, 2, 2)
+    assert chosen["layer1"].dtype == jnp.int32
+
+
+def test_a_remat_rung_the_net_lacks_is_refused_by_name():
+    obs, mask = _window(0)
+    module = HybridNet(num_actions=3, **NET)
+    params = _params(module, obs)
+    with pytest.raises(ValueError, match=r"HybridNet: remat='attn' not one of"):
+        module.apply({"params": params}, obs, None, seq=True, key_mask=mask, remat="attn")
+
+
+def test_burn_in_stops_gradients_through_every_carried_state(geister):
+    """The scan path's burn-in rule: what the burn-in steps leave carries no
+    gradient, so the window path's parameter gradient equals the scan's."""
+    _, args, module, params, batch = geister
+
+    def loss(p, seq_forward):
+        out = forward_prediction(module, p, batch, dict(args, seq_forward=seq_forward))
+        return sum(jnp.sum(jnp.square(jnp.where(jnp.abs(out[k]) < 1e6, out[k], 0.0)))
+                   for k in ("policy", "value", "return"))
+
+    window = jax.grad(loss)(params, True)
+    scan = jax.grad(loss)(params, False)
+    for a, b in zip(jax.tree.leaves(window), jax.tree.leaves(scan)):
+        np.testing.assert_allclose(a, b, atol=5e-4 * max(1.0, float(jnp.abs(b).max())))
+
+
+def test_the_three_comparisons_hold_and_the_faults_fail(geister):
+    """``harness.judge_forward`` on the system as it is, then with an 8-bit
+    forward, a dropped layer and a router that reads the wrong column."""
+    from benchmark import harness
+
+    config, args, module, params, batch = geister
+    config = dict(config, reference_tolerance=1e-4, choices_agreement_floor=0.99,
+                  reference_tolerance_f32=1e-4)
+    burn_in = args["burn_in_steps"]
+    legal = (batch["action_mask"][:, burn_in:] == 0) & (batch["turn_mask"][:, burn_in:] > 0)
+    observed = batch["observation_mask"][:, burn_in:] > 0
+
+    def judge(system):
+        checks, _, compared = harness.judge_forward(
+            system, REFERENCE.forward_rows, params, batch, config, burn_in,
+            mask_of=lambda head: legal if head == "policy" else observed, system_f32=system)
+        return checks, compared
+
+    sound = lambda p, b: forward_prediction(module, p, b, args)  # noqa: E731
+    checks, compared = judge(sound)
+    assert all(checks.values()) and set(checks) == {
+        "matches_reference", "choices_agree", "matches_reference_f32"}, (checks, compared)
+
+    def eight_bits(p, b):
+        p = jax.tree.map(lambda x: x.astype(jnp.float8_e4m3fn).astype(jnp.float32), p)
+        return forward_prediction(module, p, b, args)
+
+    def dropped_layer(p, b):
+        idle = jax.tree.map(jnp.zeros_like, p["layer2"]["mixer"]["out_proj"])
+        return forward_prediction(module, dict(p, layer2=dict(p["layer2"], mixer=dict(
+            p["layer2"]["mixer"], out_proj=idle))), b, args)
+
+    def wrong_column(p, b):
+        mixer = p["layer1"]["mixer"]
+        router = jnp.roll(mixer["router"], 1, axis=1)
+        return forward_prediction(module, dict(p, layer1=dict(p["layer1"], mixer=dict(
+            mixer, router=router))), b, args)
+
+    assert not judge(eight_bits)[0]["matches_reference"]
+    assert not judge(dropped_layer)[0]["matches_reference"]
+    checks, compared = judge(wrong_column)
+    assert not checks["choices_agree"] and not checks["matches_reference_f32"], compared
+
+
+def test_gradients_match_the_references(geister):
+    config, args, module, params, batch = geister
+    args = dict(args, burn_in_steps=0)
+
+    def system(p):
+        out = forward_prediction(module, p, batch, args)
+        return sum(jnp.sum(jnp.square(out[k] * batch["observation_mask"]))
+                   for k in ("value", "return"))
+
+    def reference(p):
+        out = REFERENCE.forward_rows(p, batch, config, 0)
+        return sum(jnp.sum(jnp.square(out[k] * batch["observation_mask"]))
+                   for k in ("value", "return"))
+
+    got, want = jax.grad(system)(params), jax.grad(reference)(params)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, atol=2e-4 * max(1.0, float(jnp.abs(b).max())), err_msg=str(path))
+
+
+# -- the expert layer's share -----------------------------------------------
+
+
+def _expert_layer(held, offset, experts=32, top_k=6):
+    return ExpertLayer(d_model=16, n_experts=experts, top_k=top_k, expert_width=8,
+                       shared_width=24, routed_scale=2.5, experts_held=held, expert_offset=offset)
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer():
+    """Each of sixteen chips holds 2 of 32 experts, routes over all 32 and
+    adds its own experts' terms: the shares' routed parts, with the shared
+    expert counted once, are the uncut reference's whole layer."""
+    h = jnp.asarray(np.random.RandomState(3).randn(5, 7, 16), jnp.float32)
+    whole = _expert_layer(32, 0)
+    params = whole.init(jax.random.PRNGKey(0), h)["params"]
+    net = dict(top_k=6, routed_scale=2.5, experts_held=32, expert_offset=0)
+    want, chosen = REFERENCE.experts(params, h, net)
+    shared = jnp.square(jax.nn.relu(h @ params["shared_up"]["kernel"])) @ params["shared_down"]["kernel"]
+    total = shared
+    for share in range(16):
+        held = dict(params, w1=params["w1"][2 * share:2 * share + 2],
+                    w2=params["w2"][2 * share:2 * share + 2])
+        out, picked, rows = _expert_layer(2, 2 * share).apply({"params": held}, h)
+        assert np.array_equal(np.sort(picked, -1), np.sort(chosen, -1))     # routes over all
+        assert int(rows.sum()) == int(((chosen >= 2 * share) & (chosen < 2 * share + 2)).sum())
+        total = total + (out - shared)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+def test_the_score_bias_changes_choices_and_not_gates():
+    scores = jax.nn.sigmoid(jnp.asarray(np.random.RandomState(4).randn(50, 16), jnp.float32))
+    bias = jnp.zeros(16).at[3].set(5.0)
+    plain, _ = choose(scores, jnp.zeros(16), 4, 2.5)
+    chosen, gates = choose(scores, bias, 4, 2.5)
+    assert bool((chosen == 3).any(axis=-1).all()) and not np.array_equal(plain, chosen)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)      # the bias is not in the gates
+    np.testing.assert_allclose(gates, 2.5 * picked / picked.sum(axis=-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(gates.sum(axis=-1), 2.5, rtol=1e-6)
+
+
+@pytest.mark.parametrize("tokens", [40, 3000])
+def test_one_expert_given_every_token_drops_none(tokens):
+    """Every token chooses the same two held experts: the rows outgrow the
+    buffer (a uniform router's share and a block of padding an expert) and
+    further passes take them."""
+    rng = np.random.RandomState(5)
+    d, width, held, experts, k = 16, 8, 4, 32, 2
+    h = jnp.asarray(rng.randn(tokens, d), jnp.float32)
+    w1 = jnp.asarray(rng.randn(held, d, width) / 4, jnp.float32)
+    w2 = jnp.asarray(rng.randn(held, width, d) / 3, jnp.float32)
+    chosen = jnp.tile(jnp.asarray([[9, 10]], jnp.int32), (tokens, 1))
+    gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    valid = jnp.asarray(rng.rand(tokens) > 0.1)
+    blocks, passes = row_buffer(tokens, k, held, experts)
+    assert (passes > 1 and blocks * BLOCK < int(valid.sum()) * k) == (tokens > 1000)
+    out, rows = jax.jit(lambda *a: held_mix(*a, 8, experts))(h, chosen, gates, valid, w1, w2)
+    assert rows.tolist() == [0, int(valid.sum()), int(valid.sum()), 0]
+    act = lambda e: jnp.square(jax.nn.relu(h @ w1[e])) @ w2[e]  # noqa: E731
+    want = valid[:, None] * (gates[:, :1] * act(1) + gates[:, 1:] * act(2))
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    # and its gradient
+    grad = jax.grad(lambda w: jnp.sum(held_mix(h, chosen, gates, valid, w, w2, 8, experts)[0] ** 2))(w1)
+    want = jax.grad(lambda w: jnp.sum((valid[:, None] * (
+        gates[:, :1] * (jnp.square(jax.nn.relu(h @ w[1])) @ w2[1])
+        + gates[:, 1:] * (jnp.square(jax.nn.relu(h @ w[2])) @ w2[2]))) ** 2))(w1)
+    np.testing.assert_allclose(grad, want, atol=2e-4 * float(jnp.abs(want).max()))
+
+
+# -- the train step -----------------------------------------------------------
+
+
+def test_train_step_counts_rows_and_records_its_layout(geister, tmp_path):
+    from handyrl_tpu.utils import trace
+
+    _, args, module, params, batch = geister
+    trace.configure({"enabled": True, "path": str(tmp_path / "trace.jsonl")})
+    try:
+        ctx = TrainContext(module, args, make_mesh({"dp": 1}))
+    finally:
+        trace.shutdown()
+    layout = [r for r in trace.read_trace(str(tmp_path / "trace.jsonl"))
+              if r["name"] == "model.layout"]
+    assert len(layout) == 1
+    layout = [record["attrs"] for record in layout]
+    assert layout[0]["pattern"] == "MEM*E" and layout[0]["experts_held"] == 4
+    assert layout[0]["experts"] == 8 and layout[0]["params_mamba"] > 0
+    trunk = sum(x.size for name, sub in params.items() if name.startswith("layer")
+                for x in jax.tree.leaves(sub))
+    assert sum(layout[0][k] for k in ("params_mamba", "params_attention", "params_experts")) == trunk
+
+    state = ctx.init_state(params)
+    state, metrics = ctx.train_step(state, ctx.put_batch(batch), 1e-4)
+    metrics = jax.device_get(metrics)
+    assert np.isfinite(metrics["total"]) and metrics["sentinel_bad"] == 0
+    observed = float(np.sum(batch["observation_mask"]))
+    # two routed layers, top-2 of 8 with 4 held: about half of the choices
+    assert 0.2 * 2 * 2 * observed < metrics["counter_rows_held"] < 0.8 * 2 * 2 * observed
+    assert metrics["counter_expert_rows_max"] >= metrics["counter_expert_rows_mean"] > 0
+    assert metrics["counter_rows_held"] == pytest.approx(2 * 4 * metrics["counter_expert_rows_mean"])
+
+
+def test_a_mesh_other_than_dp_1_is_refused_by_name(geister):
+    _, args, module, _, _ = geister
+    with pytest.raises(ValueError, match=r"HybridNet trains on mesh \{'dp': 1\} only"):
+        TrainContext(module, args, make_mesh({"dp": 2}))
+
+
+def test_an_unknown_layer_kind_is_refused():
+    module = HybridNet(num_actions=3, pattern="MX")
+    with pytest.raises(ValueError, match="a layer is one of"):
+        module.init(jax.random.PRNGKey(0), {"a": jnp.zeros((1, 4))}, None)
+
+
+def test_step_mode_acts_through_the_inference_model():
+    from handyrl_tpu.models import InferenceModel, init_variables
+
+    env = make_env({"env": "TicTacToe", "net": "hybrid", "net_args": dict(NET, memory_len=4)})
+    module = env.net()
+    model = InferenceModel(module, init_variables(module, env))
+    env.reset()
+    hidden = model.init_hidden()
+    first = model.inference(env.observation(0), hidden)
+    assert first["policy"].shape == (9,) and float(first["hidden"]["pos"]) == 1.0
+    env.play(4)
+    again = model.inference(env.observation(0), first["hidden"])
+    fresh = model.inference(env.observation(0), hidden)
+    assert not np.allclose(again["policy"], fresh["policy"], atol=1e-5)   # the state matters
+
+
+# -- the count of its work ----------------------------------------------------
+
+
+def test_flops_of_the_published_cell_against_a_hand_count():
+    with open(os.path.join(REPO, "benchmark", "configs", "nemotron_twotower_30b_a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "workloads", "nemotron_twotower_train_t192.json")) as f:
+        cell = json.load(f)
+    work = FLOPS.train_update(config, cell)
+    # parameters, by hand: the issue's arithmetic
+    mamba = 2688 + 2688 * 10304 + 5 * 6144 + 3 * 64 + 4096 + 4096 * 2688
+    attention = 2688 + 2 * 2688 * 128 * 34
+    experts = 2688 + 2688 * 128 + 128 + 2 * 2688 * 3712 + 2 * 8 * 2688 * 1856
+    rest = 270 * 2688 + 2688 + 2688 * 2688 + 2688 + 2688 + 2689 * 216
+    assert work["parameters"] == 4 * mamba + attention + 4 * experts + rest == 587_420_376
+    # a token is a step that carries an observation: 0.413 of the 184 forward
+    # steps, 0.127 of the 8 burn-in steps (the configuration's shapes)
+    trained, burn = 64 * 184 * 0.413, 64 * 8 * 0.127
+    assert work["tokens"] == pytest.approx(trained + burn)
+    # multiply-adds a token, by hand
+    ssd = 64.5 * (8 * 128 + 64 * 64) + 2 * 64 * 64 * 128
+    m = 2688 * 10304 + 4096 * 2688 + 4 * 6144 + ssd
+    e = 2688 * 128 + 2 * 2688 * 3712 + 6 * 8 / 128 * 2 * 2688 * 1856
+    a = 2 * 2688 * 128 * 34 + 2 * ((184 * 0.413 + 8 * 0.127 + 1) / 2) * 32 * 128
+    per_token = 270 * 2688 + 2688 * 2688 + 2688 * 216 + 4 * m + 4 * e + a
+    assert work["flops"] == pytest.approx(2 * per_token * (3 * trained + burn))
+    # the issue's 22 TFLOP an update counts all 12,288 steps as tokens
+    assert 20e12 < work["flops"] / 0.413 < 24e12
+    scopes = FLOPS.scope_work(config, cell)
+    assert scopes["experts"]["rows"] == pytest.approx(4 * (trained + burn) * 6 * 8 / 128)
+    assert scopes["experts"]["flops"] == pytest.approx(
+        scopes["experts"]["rows"] * 3 * 2 * 2 * 2688 * 1856)
+    assert scopes["ssd"]["flops"] == pytest.approx(2 * 4 * ssd * (3 * trained + burn))
+    assert sum(s["flops"] for s in scopes.values()) < work["flops"]
