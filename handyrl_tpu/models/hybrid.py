@@ -21,14 +21,18 @@ last ``memory_len`` keys and values for ``*``, nothing for ``E``.  Like
   (``_compact``), running every mixer on that prefix, and moving the
   results back; burn-in steps run first, as a window of their own, and what
   they leave (SSM state, conv tail, keys and values) is handed on under
-  ``stop_gradient``.
+  ``stop_gradient``.  A caller that knows, on the host, how many steps a
+  row observes at most hands the packing over (``packed_order``: per window
+  part the index of each row's i-th observed step, as many columns as that
+  most): the mixers then run over that many steps, not over the window's.
 
 ``E`` layers are told which experts they hold (``experts_held``,
 ``expert_offset``): they score and choose over all ``n_experts`` and add
 their own experts' terms only (``ops/routed_experts.py``).  The window mode
 returns, beside the heads, ``choices`` (per ``E`` layer the experts each
-token chose, (rows, T, top_k)) and ``counters`` (rows the held experts
-computed); ``forward_prediction`` hands both on.
+token chose, (rows, T, top_k)) and ``counters`` (the packed array's slots,
+the observed steps, those the packing left out, and with ``E`` layers the
+rows the held experts computed); ``forward_prediction`` hands both on.
 """
 
 from __future__ import annotations
@@ -60,15 +64,23 @@ def _rms(x, scale, eps: float, groups: int = 1):
     return (y.reshape(shape) * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _compact(key_mask):
-    """(place (N, T, T), valid (N, T)): ``place[n, i, t]`` is 1 where step
-    ``t`` is row ``n``'s ``i``-th observed step; ``valid[n, i]`` says there
-    is an ``i``-th."""
+def _compact(key_mask, order=None):
+    """(place (N, L, T), valid (N, L), dropped ()): ``place[n, i, t]`` is 1
+    where step ``t`` is row ``n``'s ``i``-th observed step; ``valid[n, i]``
+    says there is an ``i``-th.  ``order`` (N, L) int32 is that index where
+    the caller made it (``train_step.pack_order``: ``L`` the most steps a row
+    observes, ``T`` where a row has no ``i``-th); without it ``L`` is ``T`` and
+    the order is found here.  ``dropped`` counts observed steps that no slot
+    of a handed ``order`` holds: 0 unless it is too short."""
     seen = key_mask > 0
-    order = jnp.argsort(~seen, axis=1, stable=True)                 # observed steps first
-    valid = jnp.arange(seen.shape[1])[None, :] < seen.sum(axis=1, keepdims=True)
-    place = (order[:, :, None] == jnp.arange(seen.shape[1])[None, None, :]) & valid[:, :, None]
-    return place, valid
+    steps = jnp.arange(seen.shape[1])
+    found = order is None
+    if found:   # observed steps first, T past a row's last
+        there = steps[None, :] < seen.sum(axis=1, keepdims=True)
+        order = jnp.where(there, jnp.argsort(~seen, axis=1, stable=True), steps.size)
+    place = order[:, :, None] == steps[None, None, :]       # no step is step T
+    dropped = 0 if found else seen.sum() - (place.any(axis=1) & seen).sum()
+    return place, (order >= 0) & (order < steps.size), dropped
 
 
 class Mamba2Mixer(nn.Module):
@@ -325,7 +337,8 @@ class HybridNet(nn.Module):
 
     @nn.compact
     def __call__(self, obs, hidden=None, train: bool = False, *,
-                 seq: bool = False, key_mask=None, burn_in: int = 0, remat: str = "none"):
+                 seq: bool = False, key_mask=None, burn_in: int = 0, remat: str = "none",
+                 packed_order=None):
         if any(kind not in KINDS for kind in self.pattern):
             raise ValueError(f"pattern {self.pattern!r}: a layer is one of {KINDS!r}")
         def encode(flat):
@@ -361,10 +374,13 @@ class HybridNet(nn.Module):
         # one checkpoint per layer where asked: only a layer's input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
         outs, chosen, rows = [], [], []
-        for lo, hi in ((0, burn_in), (burn_in, T)):
+        slots = dropped = 0
+        for part, lo, hi in (("burn_in", 0, burn_in), ("forward", burn_in, T)):
             if lo == hi:
                 continue
-            place, valid = _compact(key_mask[:, lo:hi])
+            # (N, L, hi - lo): L the part's length, or what the host found
+            place, valid, lost = _compact(key_mask[:, lo:hi], (packed_order or {}).get(part))
+            slots, dropped = slots + valid.size, dropped + lost
             place = place.astype(x.dtype)
             packed = jnp.einsum("nit,ntd->nid", place, x[:, lo:hi], precision=_EXACT)
             y, states, picked, count = self._through(stack, packed, states, valid)
@@ -375,15 +391,22 @@ class HybridNet(nn.Module):
             chosen.append({k: jnp.einsum("nit,nik->ntk", spread, v) for k, v in picked.items()})
             rows.append(count)
         out = self._heads(jnp.concatenate(outs, axis=1))
+        # slots the mixers ran over, those of them that hold a token, and the
+        # tokens no slot held (a handed packed_order that is too short)
+        out["counters"] = {
+            "packed_slots": jnp.float32(slots),
+            "observed_steps": (key_mask > 0).sum().astype(jnp.float32),
+            "packed_dropped": jnp.asarray(dropped, jnp.float32),
+        }
         if chosen[0]:
             out["choices"] = {k: jnp.concatenate([c[k] for c in chosen], axis=1)
                               for k in chosen[0]}
             by_layer = jnp.stack([sum(r[k] for r in rows) for k in rows[0]])   # (layers, held)
-            out["counters"] = {
-                "rows_held": by_layer.sum().astype(jnp.float32),
-                "expert_rows_max": by_layer.max().astype(jnp.float32),
-                "expert_rows_mean": by_layer.astype(jnp.float32).mean(),
-            }
+            out["counters"].update(
+                rows_held=by_layer.sum().astype(jnp.float32),
+                expert_rows_max=by_layer.max().astype(jnp.float32),
+                expert_rows_mean=by_layer.astype(jnp.float32).mean(),
+            )
         return out
 
     @nn.nowrap

@@ -155,18 +155,43 @@ def whole_window(module, args: Dict[str, Any]) -> bool:
     )
 
 
+def _window_call_takes(module, args: Dict[str, Any], keyword: str) -> bool:
+    return whole_window(module, args) and (
+        keyword in inspect.signature(module.__call__).parameters
+    )
+
+
 def sums_own_grads(module, args: Dict[str, Any]) -> bool:
     """Whether ``forward_prediction`` can hand the net a ``sum_grads``:
     its whole-window call does, to a net whose ``__call__`` takes one."""
-    return whole_window(module, args) and (
-        "sum_grads" in inspect.signature(module.__call__).parameters
-    )
+    return _window_call_takes(module, args, "sum_grads")
+
+
+def takes_packed_order(module, args: Dict[str, Any]) -> bool:
+    """Whether ``put_batch`` makes a ``packed_order`` for the net: its
+    whole-window call runs, and takes one."""
+    return _window_call_takes(module, args, PACKED_ORDER)
 
 
 # beside the heads, a net's whole-window call may return these two: the
 # discrete choices a routed layer made (a pytree of integer arrays shaped
 # like a head, the chosen indices on the last axis) and scalars it counted
 CHOICES, COUNTERS = "choices", "counters"
+# a batch leaf put_batch makes for a net whose whole-window call takes the
+# keyword: per window part ("burn_in", "forward") an int32 (B, P, L) array,
+# the index within the part of each row's i-th observed step (the part's
+# length where it has none).  L, the most steps a row observes, is a shape:
+# the net runs its mixers over L steps and not over the part's
+PACKED_ORDER = "packed_order"
+PACK_MULTIPLE = 32      # L is rounded up to this, so that few programs exist
+
+
+def pack_order(seen: np.ndarray, length: int) -> np.ndarray:
+    """On the host: ``seen`` (..., T) bool -> (..., length) int32, the index
+    of each row's i-th observed step, and ``T`` where it has none."""
+    order = np.argsort(~seen, axis=-1, kind="stable")[..., :length]
+    there = np.arange(length) < seen.sum(axis=-1, keepdims=True)
+    return np.where(there, order, seen.shape[-1]).astype(np.int32)
 
 
 def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, Any],
@@ -263,6 +288,9 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
             blk_q=int(args.get("blk_q", 128)), blk_k=int(args.get("blk_k", 128)),
             **({"sum_grads": sum_grads} if sum_grads is not None else {}),
         )
+        if PACKED_ORDER in batch:   # (B, P, L) -> the net's rows
+            keywords[PACKED_ORDER] = tree_map(
+                lambda x: x.reshape((B * P1,) + x.shape[2:]), batch[PACKED_ORDER])
         # a net is handed the keywords its whole-window call takes
         takes = inspect.signature(module.__call__).parameters
         outs = module.apply(
@@ -385,7 +413,9 @@ def forward_prediction(module, params, batch: Dict[str, Any], args: Dict[str, An
 
 
 def trim_burn_in(batch: Dict[str, Any], burn_in: int) -> Dict[str, Any]:
-    """Drop burn-in steps from every time-majored batch array (train.py:222)."""
+    """Drop burn-in steps from every time-majored batch array (train.py:222).
+    ``packed_order`` is the forward pass's alone and is dropped whole."""
+    batch = {k: v for k, v in batch.items() if k != PACKED_ORDER}
     if burn_in == 0:
         return batch
     return {k: (v[:, burn_in:] if v.shape[1] > 1 else v) for k, v in batch.items() if k != "observation"} | {
@@ -501,6 +531,12 @@ class TrainContext:
             and args.get("burn_in_steps", 0) == 0
             and args.get("compact_padding", True)
         )
+        # A recurrent net whose whole-window call takes packed_order gets
+        # one from put_batch (_pack): per window part, the largest bound
+        # handed out so far.  It never falls, so a learner settles on one
+        # program and does not flip between two
+        self._packs = takes_packed_order(module, self.args)
+        self._packed_bounds: Dict[str, int] = {}
 
         loss_keys = ("p", "v", "r", "ent", "total")
 
@@ -735,6 +771,37 @@ class TrainContext:
             observation=tree_map(lambda x: x[:, :t_eff], batch["observation"]),
         )
 
+    def _pack(self, batches):
+        """Give each host batch of the list its ``packed_order`` (see
+        PACKED_ORDER), all at one bound per window part: the most steps any
+        (row, player) of them observes there, rounded up to
+        ``PACK_MULTIPLE``, at most the part's length and at least the
+        largest bound handed out before.  No leaf where no bound is under
+        its part's length: such a batch runs the program a batch without
+        the leaf runs.  Multi-process is left alone, as in _compact_ff."""
+        if not self._packs or jax.process_count() > 1:
+            return batches
+        seen = [np.moveaxis(np.asarray(b["observation_mask"])[..., 0] > 0, 1, 2)
+                for b in batches]                                    # (B, P, T)
+        burn_in = int(self.args["burn_in_steps"])
+        parts = [(name, lo, hi) for name, lo, hi in (
+            ("burn_in", 0, burn_in), ("forward", burn_in, seen[0].shape[-1])) if hi > lo]
+        bounds = {}
+        for name, lo, hi in parts:
+            most = max(int(s[..., lo:hi].sum(axis=-1).max()) for s in seen)
+            bound = min(hi - lo, -(-max(most, 1) // PACK_MULTIPLE) * PACK_MULTIPLE)
+            bounds[name] = max(bound, self._packed_bounds.get(name, 0))
+        if bounds != self._packed_bounds:   # a new program will be bound
+            self._packed_bounds = bounds
+            trace_event("train.packed_bound", 0.0, plane="learner", **bounds,
+                        **{name + "_steps": hi - lo for name, lo, hi in parts})
+        if all(bounds[name] == hi - lo for name, lo, hi in parts):
+            return batches
+        return [
+            dict(b, **{PACKED_ORDER: {
+                name: pack_order(s[..., lo:hi], bounds[name]) for name, lo, hi in parts}})
+            for b, s in zip(batches, seen)]
+
     def put_batch(self, batch: Dict[str, Any]):
         """Lay a host batch out dp-sharded.
 
@@ -744,6 +811,7 @@ class TrainContext:
         global array is built with make_array_from_process_local_data —
         no cross-host batch traffic."""
         batch = self._compact_ff(batch)
+        batch, = self._pack([batch])
         return self._put_sharded(batch, self._batch_shard, batch["action"].shape[0])
 
     def train_step(self, state, device_batch, lr: float):
@@ -761,6 +829,7 @@ class TrainContext:
         if self._ff_compact and jax.process_count() == 1:
             t_eff = max(self._live_steps(b) for b in host_batches)
             host_batches = [self._compact_ff(b, t_eff) for b in host_batches]
+        host_batches = self._pack(host_batches)
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *host_batches)
         shard = NamedSharding(self.mesh, PartitionSpec(None, "dp"))
         return self._put_sharded(stacked, shard, host_batches[0]["action"].shape[0])

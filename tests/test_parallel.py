@@ -440,6 +440,34 @@ def test_only_a_sectioned_whole_window_net_sums_its_own(net, seq_forward, own):
         type(module)(**{**env_args.get("net_args", {}), "sums_own_grads": False})
 
 
+@pytest.mark.parametrize("env_args,overrides", [
+    _SYNC_NETS["transformer"][:2],
+    _SYNC_NETS["geesenet"][:2],
+    ({"env": "Geister"}, {"observation": True, "burn_in_steps": 2}),      # DRC: a scan of steps
+], ids=["transformer", "geesenet", "drc"])
+def test_put_batch_packs_nothing_for_a_net_that_takes_no_packed_order(env_args, overrides):
+    """Only a net whose whole-window call takes ``packed_order`` gets the
+    leaf: these three get the batch they always got, and the step lowers to
+    the text it lowers to from the host batch laid out by hand."""
+    from handyrl_tpu.parallel import TrainContext
+    from handyrl_tpu.parallel.train_step import PACKED_ORDER, takes_packed_order
+
+    module, variables, batch, args = _env_batch(env_args, {**overrides, "forward_steps": 40})
+    assert not takes_packed_order(module, args)
+    ctx = TrainContext(module, args, make_mesh({"dp": 1}))
+    if env_args["env"] == "Geister":    # a row observes every second step: a packing net's leaf is 32 long
+        assert (batch["observation_mask"][..., 0] > 0).sum(axis=1).max() <= 32
+    put, stacked = ctx.put_batch(batch), ctx.put_batches([batch, batch])
+    assert PACKED_ORDER not in put and PACKED_ORDER not in stacked and ctx._packed_bounds == {}
+    by_hand = jax.device_put(ctx._compact_ff(batch), ctx._batch_shard)
+    assert jax.tree.structure(put) == jax.tree.structure(by_hand)
+    assert [x.shape for x in jax.tree.leaves(put)] == [x.shape for x in jax.tree.leaves(by_hand)]
+    state = ctx.init_state(variables["params"])
+    step = ctx._bind(state)
+    assert step.lower(state, put, jnp.float32(1e-4)).as_text() \
+        == step.lower(state, by_hand, jnp.float32(1e-4)).as_text()
+
+
 def test_grad_sync_event_reports_the_context_counts(tmp_path):
     from handyrl_tpu.utils import trace as trace_mod
 
