@@ -599,11 +599,17 @@ class TrainContext:
         # Divergence sentinel (config: sentinel, default on): finite-checks
         # of the loss, the gradient global-norm, and the lr are FUSED into
         # the compiled step — the verdict rides back with the existing
-        # metrics (no extra host sync on the happy path), and a bad step's
-        # update is suppressed under lax.cond so a single NaN/inf can never
-        # poison the params or the Adam moments.  The host (runtime/
-        # trainer.py) counts the flags at epoch end (sentinel_skipped_steps)
-        # and escalates a long bad streak to a verified-checkpoint rollback.
+        # metrics (no extra host sync on the happy path), and a bad step
+        # keeps every old leaf of params and optimizer state by a select on
+        # the verdict, so a single NaN/inf can never poison the params or
+        # the Adam moments.  A select and not a lax.cond: a conditional is
+        # a computation of its own, which fixes one layout per operand at
+        # its boundary (whole-array copies of the large leaves in and out)
+        # and hides the clip's norm from the one computed here (PERF.md,
+        # PR 38); the select sits inside each leaf's one update fusion.
+        # The host (runtime/trainer.py) counts the flags at epoch end
+        # (sentinel_skipped_steps) and escalates a long bad streak to a
+        # verified-checkpoint rollback.
         sentinel = bool(args.get("sentinel", True))
 
         _grad_fn = jax.value_and_grad(_loss_fn, has_aux=True)
@@ -626,13 +632,9 @@ class TrainContext:
 
         def _step(state, batch, lr):
             (loss, (losses, dcnt)), grads = _grad_fn(state["params"], batch)
-
-            def _apply(_):
-                updates, opt_state = self.tx.update(
-                    grads, state["opt_state"], state["params"]
-                )
-                updates = jax.tree.map(lambda u: -lr * u, updates)
-                return optax.apply_updates(state["params"], updates), opt_state
+            updates, opt_state = self.tx.update(grads, state["opt_state"], state["params"])
+            updates = jax.tree.map(lambda u: -lr * u, updates)
+            params = optax.apply_updates(state["params"], updates)
 
             metrics = dict(losses)
             metrics["dcnt"] = dcnt
@@ -641,11 +643,11 @@ class TrainContext:
                 bad = jnp.logical_not(
                     jnp.isfinite(loss) & jnp.isfinite(gnorm) & jnp.isfinite(lr)
                 )
-                params, opt_state = jax.lax.cond(
-                    bad,
-                    lambda _: (state["params"], state["opt_state"]),
-                    _apply,
-                    operand=None,
+                # a select passes nothing from the side it drops: the update
+                # above ran on the NaN and none of it is kept
+                params, opt_state = jax.tree.map(
+                    lambda old, new: jnp.where(bad, old, new),
+                    (state["params"], state["opt_state"]), (params, opt_state),
                 )
                 # a skipped step contributes nothing to the epoch's loss
                 # averages (a NaN loss summed once would poison them); its
@@ -654,8 +656,6 @@ class TrainContext:
                     lambda m: jnp.where(bad, jnp.zeros_like(m), m), metrics
                 )
                 metrics["sentinel_bad"] = bad.astype(jnp.float32)
-            else:
-                params, opt_state = _apply(None)
             new_state = {"params": params, "opt_state": opt_state, "steps": state["steps"] + 1}
             return new_state, metrics
 
@@ -932,6 +932,16 @@ def hbm_bandwidth_per_chip(device) -> float:
     return _by_device_kind(HBM_BW_BY_KIND, device)
 
 
+def sub_jaxprs(eqn):
+    """The jaxprs an equation holds in its params: a scan's or a while's
+    body, a pjit's, a cond's branches, a custom rule's."""
+    for val in eqn.params.values():
+        for v in val if isinstance(val, (tuple, list)) else (val,):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
 def jaxpr_flops(jaxpr) -> float:
     """Backend-free analytic flop count of a jaxpr: 2*MACs for every
     ``dot_general`` and ``conv_general_dilated``, recursing through
@@ -970,15 +980,7 @@ def jaxpr_flops(jaxpr) -> float:
             k_spatial = _np.prod([rhs_shape[i] for i in dn.rhs_spec[2:]], dtype=float)
             total += 2.0 * out_numel * in_feats * k_spatial
         else:
-            subs = []
-            for val in eqn.params.values():
-                vals = val if isinstance(val, (tuple, list)) else (val,)
-                for v in vals:
-                    inner = getattr(v, "jaxpr", None)
-                    if inner is not None and hasattr(inner, "eqns"):
-                        subs.append(inner)
-                    elif hasattr(v, "eqns"):
-                        subs.append(v)
+            subs = list(sub_jaxprs(eqn))
             if not subs:
                 continue
             if p == "scan":

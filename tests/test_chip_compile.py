@@ -230,6 +230,23 @@ def test_dp4_step_rings_its_gradient_under_compute(v5e_2x2):
         assert done >= due, (section, done, due)
 
 
+def test_dp1_step_updates_in_its_own_computation_under_one_norm(v5e_2x2):
+    """{dp: 1}, two blocks: the compiled step holds no ``conditional`` (the
+    sentinel's verdict is a select inside each leaf's update fusion; a
+    conditional's boundary fixes a layout per operand and hides its body
+    from CSE), so the clip's norm and the sentinel's are one: each leaf's
+    square sum is folded into the fusion that makes its gradient, and a
+    dozen reduce fusions of their own are left where the parent's branch
+    read every leaf a second time (70 with the ``lax.cond``, 9 without:
+    PERF.md, PR 38).  Not the bytes: at two blocks ``cost_analysis`` counts
+    the compiler's prefetch slices and does not fall."""
+    _, lowered = _lowered_step(v5e_2x2, dp=1, batch_size=_STEP["batch_size"], n_layers=2)
+    text = lowered.compile().as_text()
+    assert " conditional(" not in text
+    norms = re.findall(r"%?multiply_reduce_fusion[.\d]* = f32\[\][^ ]* fusion\(", text)
+    assert 0 < len(norms) <= 12, len(norms)
+
+
 def test_dp1_step_lowers_without_the_ring(v5e_2x2):
     """{dp: 1} (the one-chip cells) lowers to the program it always was:
     nothing of the sections' sums is in its text, all of it is in {dp: 4}'s."""

@@ -24,7 +24,7 @@ from handyrl_tpu.ops.routed_experts import BLOCK, choose, held_mix, row_buffer
 from handyrl_tpu.ops.ssd import ssd_chunked, ssd_step
 from handyrl_tpu.parallel import TrainContext, make_mesh
 from handyrl_tpu.parallel.train_step import (
-    PACK_MULTIPLE, PACKED_ORDER, forward_prediction, pack_order, trim_burn_in)
+    PACK_MULTIPLE, PACKED_ORDER, forward_prediction, pack_order, sub_jaxprs, trim_burn_in)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -577,6 +577,52 @@ def test_train_step_counts_rows_and_records_its_layout(geister, tmp_path):
     assert 0.2 * 2 * 2 * observed < metrics["counter_rows_held"] < 0.8 * 2 * 2 * observed
     assert metrics["counter_expert_rows_max"] >= metrics["counter_expert_rows_mean"] > 0
     assert metrics["counter_rows_held"] == pytest.approx(2 * 4 * metrics["counter_expert_rows_mean"])
+
+
+def _primitives(jaxpr):
+    """The name of every primitive in ``jaxpr`` and in the jaxprs its
+    equations hold."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for sub in sub_jaxprs(eqn):
+            found |= _primitives(sub)
+    return found
+
+
+@pytest.mark.parametrize("name,env_args,train_args", [
+    ("GeeseNet", {"env": "HungryGeese"}, {"turn_based_training": False}),
+    ("TransformerNet", {"env": "Geister", "net": "transformer",
+                        "net_args": {"d_model": 32, "n_heads": 2, "n_layers": 2, "memory_len": 8}},
+     {"observation": True, "burn_in_steps": 2, "seq_attention": "einsum"}),
+    ("HybridNet", _config()["env_args"], {"observation": True, "burn_in_steps": 2}),
+], ids=["GeeseNet", "TransformerNet", "HybridNet"])
+def test_the_update_is_straight_line_code_of_the_step(name, env_args, train_args):
+    """No net's step holds a ``cond``, sentinel on or off: the update runs
+    in the step's own computation and the verdict is a select on each leaf
+    (a conditional fixes a layout per operand at its boundary and hides the
+    clip's norm from the sentinel's: PERF.md, PR 38).  ``HybridNet``'s own
+    ``while`` over the expert buffer's passes stays."""
+    from benchmark import traffic
+
+    cfg = normalize_args({"env_args": dict(env_args), "train_args": dict(
+        train_args, batch_size=2, forward_steps=4, seed=3)})
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    random.seed(3)
+    np.random.seed(3)
+    env = make_env(args["env"])
+    module = env.net()
+    assert type(module).__name__ == name
+    batch = traffic.random_play_batches(env, module, args, 1, 2)[0]
+    params = jax.eval_shape(lambda: traffic.seeded_params(module, env, 3))
+    for sentinel in (True, False):
+        ctx = TrainContext(module, dict(args, sentinel=sentinel), make_mesh({"dp": 1}))
+        state = {"params": params, "opt_state": jax.eval_shape(ctx.tx.init, params),
+                 "steps": jax.ShapeDtypeStruct((), jnp.int32)}
+        found = _primitives(jax.make_jaxpr(ctx._step_fn)(state, batch, jnp.float32(1e-5)).jaxpr)
+        assert "cond" not in found, sorted(found)
+        assert "select_n" in found and "dot_general" in found     # the walk saw the step
+        assert ("while" in found) == (name == "HybridNet")
 
 
 def test_a_mesh_other_than_dp_1_is_refused_by_name(geister):

@@ -151,7 +151,9 @@ def test_resumed_run_repairs_truncated_metrics_tail(tmp_path):
 # ----------------------------------------------------- in-step finite check
 
 
-def _train_setup(sentinel: bool):
+def _train_setup(sentinel: bool, host_batches: int = 0):
+    """(context, state, device batch); with ``host_batches`` the third is
+    that many host batches instead, for the caller to spoil and to put."""
     from handyrl_tpu.envs import make_env
     from handyrl_tpu.models import InferenceModel, init_variables
     from handyrl_tpu.parallel import TrainContext, make_mesh
@@ -185,10 +187,11 @@ def _train_setup(sentinel: bool):
     mesh = make_mesh({"dp": -1})
     ctx = TrainContext(module, targs, mesh)
     state = ctx.init_state(variables["params"])
-    batch = ctx.put_batch(
+    batches = [
         make_batch([store.sample_window(8, 0, 4) for _ in range(8)], targs)
-    )
-    return ctx, state, batch
+        for _ in range(max(host_batches, 1))
+    ]
+    return ctx, state, batches if host_batches else ctx.put_batch(batches[0])
 
 
 def _leaves(tree):
@@ -230,6 +233,59 @@ def test_in_step_sentinel_skips_nonfinite_update():
         for a, b in zip(_leaves(host2["params"]), _leaves(host3["params"]))
     )
     assert moved
+
+
+def _spoiled(host_batch):
+    """The batch with every observation NaN: the loss and every gradient
+    leaf come out non-finite, whatever the lr."""
+    return dict(host_batch, observation=jax.tree.map(
+        lambda x: np.full_like(x, np.nan), host_batch["observation"]))
+
+
+def test_in_step_sentinel_skips_a_nonfinite_batch():
+    """The NaN comes from the data and not the lr: the update's arithmetic
+    runs on NaN gradients (it is straight-line code of the step) and the
+    select drops all of it: params and every leaf of the optimizer state,
+    Adam's count included, stay byte-identical."""
+    ctx, state, (good,) = _train_setup(sentinel=True, host_batches=1)
+    state1, _ = ctx.train_step(state, ctx.put_batch(good), 1e-5)
+    host1 = jax.device_get(state1)
+    assert [int(x) for x in _leaves(host1["opt_state"]) if x.ndim == 0] == [1]
+
+    state2, m2 = ctx.train_step(state1, ctx.put_batch(_spoiled(good)), 1e-5)
+    host2, m2 = jax.device_get((state2, m2))
+    assert float(m2["sentinel_bad"]) == 1.0
+    assert float(m2["total"]) == 0.0 and float(m2["dcnt"]) == 0.0
+    for key in ("params", "opt_state"):
+        before, after = _leaves(host1[key]), _leaves(host2[key])
+        assert len(before) == len(after) > 0
+        for a, b in zip(before, after):
+            assert np.isfinite(b).all() and np.array_equal(a, b)
+    assert int(host2["steps"]) == int(host1["steps"]) + 1
+
+
+def test_fused_steps_skip_the_bad_batch_in_the_middle():
+    """``train_steps`` over three stacked batches, the middle one spoiled,
+    ends where the two good ones alone end: the scan's carry takes the
+    select's old leaves through the bad step."""
+    ctx, state, (first, second) = _train_setup(sentinel=True, host_batches=2)
+    state3, m3 = ctx.train_steps(
+        state, ctx.put_batches([first, _spoiled(first), second]), 1e-3)
+    host3, m3 = jax.device_get((state3, m3))
+    ctx, state, _ = _train_setup(sentinel=True)
+    state2, m2 = ctx.train_steps(state, ctx.put_batches([first, second]), 1e-3)
+    host2, m2 = jax.device_get((state2, m2))
+
+    assert float(m3["sentinel_bad"]) == 1.0 and float(m2["sentinel_bad"]) == 0.0
+    assert (int(host3["steps"]), int(host2["steps"])) == (3, 2)
+    for key in ("total", "dcnt"):
+        np.testing.assert_allclose(m3[key], m2[key], rtol=1e-5)
+    for key in ("params", "opt_state"):
+        for a, b in zip(_leaves(host3[key]), _leaves(host2[key])):
+            # two scans of one body; Adam's count is an integer and exact
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            if a.ndim == 0:
+                assert int(a) == int(b) == 2
 
 
 def test_sentinel_off_reproduces_the_poisoning_failure_mode():
