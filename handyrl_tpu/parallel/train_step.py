@@ -45,6 +45,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ops import compute_loss_from_outputs
 from ..utils import tree_map
+from ..utils.compile_cache import scoped_program_options
 from ..utils.trace import trace_event
 from .mesh import (
     batch_sharding,
@@ -184,6 +185,10 @@ CHOICES, COUNTERS = "choices", "counters"
 # the net runs its mixers over L steps and not over the part's
 PACKED_ORDER = "packed_order"
 PACK_MULTIPLE = 32      # L is rounded up to this, so that few programs exist
+# the ``jax.named_scope`` round the update inside ``_step``: a component of
+# each of its ops' ``op_name`` in a device profile (the benchmark's
+# ``update_step_share`` imports it; docs/observability.md has the naming rule)
+UPDATE_SCOPE = "opt_update"
 
 
 def pack_order(seen: np.ndarray, length: int) -> np.ndarray:
@@ -632,23 +637,25 @@ class TrainContext:
 
         def _step(state, batch, lr):
             (loss, (losses, dcnt)), grads = _grad_fn(state["params"], batch)
-            updates, opt_state = self.tx.update(grads, state["opt_state"], state["params"])
-            updates = jax.tree.map(lambda u: -lr * u, updates)
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope(UPDATE_SCOPE):
+                updates, opt_state = self.tx.update(grads, state["opt_state"], state["params"])
+                updates = jax.tree.map(lambda u: -lr * u, updates)
+                params = optax.apply_updates(state["params"], updates)
+                if sentinel:
+                    gnorm = optax.global_norm(grads)
+                    bad = jnp.logical_not(
+                        jnp.isfinite(loss) & jnp.isfinite(gnorm) & jnp.isfinite(lr)
+                    )
+                    # a select passes nothing from the side it drops: the
+                    # update above ran on the NaN and none of it is kept
+                    params, opt_state = jax.tree.map(
+                        lambda old, new: jnp.where(bad, old, new),
+                        (state["params"], state["opt_state"]), (params, opt_state),
+                    )
 
             metrics = dict(losses)
             metrics["dcnt"] = dcnt
             if sentinel:
-                gnorm = optax.global_norm(grads)
-                bad = jnp.logical_not(
-                    jnp.isfinite(loss) & jnp.isfinite(gnorm) & jnp.isfinite(lr)
-                )
-                # a select passes nothing from the side it drops: the update
-                # above ran on the NaN and none of it is kept
-                params, opt_state = jax.tree.map(
-                    lambda old, new: jnp.where(bad, old, new),
-                    (state["params"], state["opt_state"]), (params, opt_state),
-                )
                 # a skipped step contributes nothing to the epoch's loss
                 # averages (a NaN loss summed once would poison them); its
                 # count rides in its own key instead
@@ -722,6 +729,7 @@ class TrainContext:
                 donate_argnums=(0,),
                 in_shardings=(ss, self._batch_shard, self._replicated),
                 out_shardings=(ss, self._replicated),
+                compiler_options=scoped_program_options(UPDATE_SCOPE),
             )
         return self._train_step
 
@@ -860,6 +868,7 @@ class TrainContext:
                 donate_argnums=(0,),
                 in_shardings=(ss, stacked_shard, self._replicated),
                 out_shardings=(ss, self._replicated),
+                compiler_options=scoped_program_options(UPDATE_SCOPE),
             )
         return dispatch_serialized(
             lambda: self._train_steps(state, stacked_device_batch, jnp.float32(lr)),

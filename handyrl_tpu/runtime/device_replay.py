@@ -97,6 +97,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..utils import tree_map
+from ..utils.compile_cache import scoped_program_options
 from ..utils.trace import trace_span
 
 ILLEGAL = 1e32
@@ -106,6 +107,17 @@ ILLEGAL = 1e32
 INGEST_PROGRAM = "ingest"
 TRAIN_PROGRAM = "replay_train"
 SAMPLE_PROGRAM = "replay_sample"
+# ``jax.named_scope``s inside the sampler: components of its ops' ``op_name``
+# in a device profile (the benchmark's ``sample_step_share`` imports them;
+# docs/observability.md has the naming rule).  The whole of ``_sample`` as
+# ``train_fn`` calls it; inside, the eligibility pass and the draw, the ring
+# gathers with their unpacking, the observation rebuild and its masking.
+# What is left of the first is the player pick, the masks and the returns
+SAMPLE_SCOPE = "sample"
+SAMPLE_DRAW_SCOPE = "sample_draw"
+SAMPLE_ROWS_SCOPE = "sample_rows"
+SAMPLE_OBS_SCOPE = "sample_obs"
+SAMPLE_PART_SCOPES = (SAMPLE_DRAW_SCOPE, SAMPLE_ROWS_SCOPE, SAMPLE_OBS_SCOPE)
 
 # record fields consumed positionally by the ring (everything else the
 # streaming fn emits is an env compact-obs field, stored as-is)
@@ -616,7 +628,8 @@ class DeviceReplay:
                 if "fn" not in holder:
                     ring_shard = _lane_sharding(self.mesh, self.rings)
                     holder["fn"] = jax.jit(
-                        fn, in_shardings=(ring_shard, rep), out_shardings=rep
+                        fn, in_shardings=(ring_shard, rep), out_shardings=rep,
+                        compiler_options=scoped_program_options(*SAMPLE_PART_SCOPES),
                     )
                 from ..parallel.mesh import dispatch_serialized
 
@@ -640,12 +653,14 @@ class DeviceReplay:
         if fused_steps in self._train_fns:
             return self._train_fns[fused_steps]
         from ..parallel.mesh import param_shardings
+        from ..parallel.train_step import UPDATE_SCOPE
 
         B = self.args["batch_size"]
         step_fn = ctx._step_fn
 
         def one(state, rings, key, lr):
-            batch = self._sample(rings, key, B)
+            with jax.named_scope(SAMPLE_SCOPE):
+                batch = self._sample(rings, key, B)
             return step_fn(state, batch, lr)
 
         def fn(state, rings, key, lr):
@@ -675,6 +690,8 @@ class DeviceReplay:
                     donate_argnums=(0,),
                     in_shardings=(ss, ring_shard, rep, rep),
                     out_shardings=(ss, rep),
+                    compiler_options=scoped_program_options(
+                        SAMPLE_SCOPE, *SAMPLE_PART_SCOPES, UPDATE_SCOPE),
                 )
             from ..parallel.mesh import dispatch_serialized
 
@@ -778,8 +795,9 @@ def _draw_windows(rings, fmt: RowFormat, key, batch_size: int,
     S = rings["valid"].shape[1]
     T = burn_in + forward_steps
 
-    ok = _eligibility(rings, forward_steps, burn_in)
-    lane, slot = _draw_starts(ok, key, batch_size)         # (N,) train_start
+    with jax.named_scope(SAMPLE_DRAW_SCOPE):
+        ok = _eligibility(rings, forward_steps, burn_in)
+        lane, slot = _draw_starts(ok, key, batch_size)     # (N,) train_start
 
     gs0 = _slot_gsteps(rings["g"], S)[slot]                # (N,) train_start g
     ep_start = rings["ep_start_g"][lane, slot]
@@ -792,11 +810,12 @@ def _draw_windows(rings, fmt: RowFormat, key, batch_size: int,
     live_b = (i_t >= 0) & (gstep <= ep_end[:, None])       # (N, T)
     wslots = (slot[:, None] - burn_in + j[None, :]) % S    # (N, T)
 
-    rec = fmt.unpack(rings["rec"][lane[:, None], wslots])  # leaves (N, T, ...)
-    # final outcome lives in the episode's END slot record (younger than
-    # train_start, so resident whenever train_start's valid flag survives)
-    end_slot = (slot + (ep_end - gs0)) % S
-    outcome = fmt.unpack(rings["rec"][lane, end_slot], ("outcome",))["outcome"]
+    with jax.named_scope(SAMPLE_ROWS_SCOPE):
+        rec = fmt.unpack(rings["rec"][lane[:, None], wslots])  # leaves (N, T, ...)
+        # final outcome lives in the episode's END slot record (younger than
+        # train_start, so resident whenever train_start's valid flag survives)
+        end_slot = (slot + (ep_end - gs0)) % S
+        outcome = fmt.unpack(rings["rec"][lane, end_slot], ("outcome",))["outcome"]
     out = {
         "lane": lane, "slot": slot, "i_t": i_t, "gstep": gstep,
         "ep_end": ep_end,
@@ -870,13 +889,14 @@ def _sample_batch(rings, fmt: RowFormat, key, batch_size: int, venv,
 
     # leaves (N, T, ...): single array for the vector envs, a pytree for
     # host-born episodes whose obs is structured (DeviceEpisodeStage)
-    planes = venv.view_obs(w["compact"], player)
-    obs = tree_map(
-        lambda x: (
-            x * omask.reshape(omask.shape + (1,) * (x.ndim - 2))
-        )[:, :, None],                                     # (N, T, 1, ...)
-        planes,
-    )
+    with jax.named_scope(SAMPLE_OBS_SCOPE):
+        planes = venv.view_obs(w["compact"], player)
+        obs = tree_map(
+            lambda x: (
+                x * omask.reshape(omask.shape + (1,) * (x.ndim - 2))
+            )[:, :, None],                                 # (N, T, 1, ...)
+            planes,
+        )
 
     amask = jnp.where(
         legal_p & (tmask[..., None] > 0), 0.0, ILLEGAL
@@ -935,10 +955,11 @@ def _sample_batch_turn(rings, fmt: RowFormat, key, batch_size: int, venv,
     act = live[..., None] * w["active"]                    # (N, T, P)
     obsv = live[..., None] * w["observing"]
 
-    planes = venv.view_obs_all(w["compact"])               # leaves (N, T, P, ...)
-    obs = tree_map(
-        lambda x: x * obsv.reshape(obsv.shape + (1,) * (x.ndim - 3)), planes
-    )
+    with jax.named_scope(SAMPLE_OBS_SCOPE):
+        planes = venv.view_obs_all(w["compact"])           # leaves (N, T, P, ...)
+        obs = tree_map(
+            lambda x: x * obsv.reshape(obsv.shape + (1,) * (x.ndim - 3)), planes
+        )
 
     amask = jnp.where(
         w["legal"] & (act[..., None] > 0), 0.0, ILLEGAL

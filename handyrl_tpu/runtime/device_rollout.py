@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import tree_map
+from ..utils.compile_cache import scoped_program_options
 from .replay import compress_block
 
 ILLEGAL = 1e32
@@ -37,6 +38,18 @@ ILLEGAL = 1e32
 # what the benchmark's per-layer readers look up
 STREAM_PROGRAM = "device_rollout"
 EPISODE_PROGRAM = "device_rollout_episodes"
+# ``jax.named_scope``s round the calls in the streaming program's scan body:
+# components of its ops' ``op_name`` in a device profile (the benchmark's
+# ``rollout_env_share`` imports them; docs/observability.md has the naming
+# rule).  They sit in the body, not in the env, so device eval, which calls
+# the same env, carries none of them
+RESET_SCOPE = "env_reset"
+OBSERVE_SCOPE = "env_observe"
+POLICY_SCOPE = "rollout_policy"
+ACT_SCOPE = "rollout_act"
+STEP_SCOPE = "env_step"
+ENV_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, STEP_SCOPE)
+STREAM_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, POLICY_SCOPE, ACT_SCOPE, STEP_SCOPE)
 
 
 def build_selfplay_fn(venv, module, n_games: int):
@@ -214,69 +227,74 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
             state, hidden = carry
             kr, ka, kf = jax.random.split(key_t, 3)
             reset = state["done"]
-            state = venv.reset_done(state, kr)
-            if hidden is not None:
-                # fresh games start from zero hidden (host: init_hidden)
-                hidden = tree_map(
-                    lambda h: h * ~reset.reshape((-1,) + (1,) * (h.ndim - 1)),
-                    hidden,
-                )
+            with jax.named_scope(RESET_SCOPE):
+                state = venv.reset_done(state, kr)
+                if hidden is not None:
+                    # fresh games start from zero hidden (host: init_hidden)
+                    hidden = tree_map(
+                        lambda h: h * ~reset.reshape((-1,) + (1,) * (h.ndim - 1)),
+                        hidden,
+                    )
             active = state["active"]                     # (B, P) acting mask
-            # observe_mask (observer views for non-acting players) applies
-            # only under ``observation: true`` — with it false the host
-            # generator records turn players only, and the device path must
-            # emit the same omask semantics into the shared replay store
-            observing = (
-                venv.observe_mask(state)
-                if use_observe_mask and hasattr(venv, "observe_mask")
-                else active
-            )
-            obs = venv.observation(state)                # leaves (B, P, ...)
             B = active.shape[0]
-            flat = tree_map(lambda x: x.reshape((B * P,) + x.shape[2:]), obs)
-            h_flat = (
-                None
-                if hidden is None
-                else tree_map(lambda h: h.reshape((B * P,) + h.shape[2:]), hidden)
-            )
-            out = module.apply({"params": params}, flat, h_flat)
-            if hidden is not None:
-                new_hidden = tree_map(
-                    lambda h: h.reshape((B, P) + h.shape[1:]), out["hidden"]
+            with jax.named_scope(OBSERVE_SCOPE):
+                # observe_mask (observer views for non-acting players) applies
+                # only under ``observation: true`` — with it false the host
+                # generator records turn players only, and the device path must
+                # emit the same omask semantics into the shared replay store
+                observing = (
+                    venv.observe_mask(state)
+                    if use_observe_mask and hasattr(venv, "observe_mask")
+                    else active
                 )
-                # commit where observed, keep elsewhere (train_step.py:146)
-                hidden = jax.tree.map(
-                    lambda h, nh: jnp.where(
-                        observing.reshape((B, P) + (1,) * (h.ndim - 2)), nh, h
-                    ),
-                    hidden,
-                    new_hidden,
+                obs = venv.observation(state)            # leaves (B, P, ...)
+                flat = tree_map(lambda x: x.reshape((B * P,) + x.shape[2:]), obs)
+            with jax.named_scope(POLICY_SCOPE):
+                h_flat = (
+                    None
+                    if hidden is None
+                    else tree_map(lambda h: h.reshape((B * P,) + h.shape[2:]), hidden)
                 )
-            logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
-            legal = venv.legal_mask_all(state)           # (B, P, A) bool
-            masked = jnp.where(legal, logits, logits - ILLEGAL)
-            # Gumbel-max == softmax sampling at temperature 1 (generation.py)
-            g = jax.random.gumbel(ka, masked.shape)
-            action = jnp.argmax(masked + g, axis=-1).astype(jnp.int32)
-            probs = jax.nn.softmax(masked, axis=-1)
-            prob = jnp.take_along_axis(probs, action[..., None], axis=-1)[..., 0]
-            value = (
-                out["value"].reshape(B, P)
-                if out.get("value") is not None
-                else jnp.zeros_like(prob)
-            )
-            record = {
-                "active": active,
-                "observing": observing,
-                "legal": legal,
-                "action": action.astype(jnp.int32),
-                "prob": prob,
-                "value": value,
-            }
-            record.update(venv.record(state))   # env's compact obs fields
-            state = venv.step(state, action, kf)
-            record["done"] = state["done"]   # reset_done cleared stale flags
-            record["outcome"] = venv.outcome_scores(state)  # final where done
+                out = module.apply({"params": params}, flat, h_flat)
+                if hidden is not None:
+                    new_hidden = tree_map(
+                        lambda h: h.reshape((B, P) + h.shape[1:]), out["hidden"]
+                    )
+                    # commit where observed, keep elsewhere (train_step.py:146)
+                    hidden = jax.tree.map(
+                        lambda h, nh: jnp.where(
+                            observing.reshape((B, P) + (1,) * (h.ndim - 2)), nh, h
+                        ),
+                        hidden,
+                        new_hidden,
+                    )
+            with jax.named_scope(ACT_SCOPE):
+                logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
+                legal = venv.legal_mask_all(state)       # (B, P, A) bool
+                masked = jnp.where(legal, logits, logits - ILLEGAL)
+                # Gumbel-max == softmax sampling at temperature 1 (generation.py)
+                g = jax.random.gumbel(ka, masked.shape)
+                action = jnp.argmax(masked + g, axis=-1).astype(jnp.int32)
+                probs = jax.nn.softmax(masked, axis=-1)
+                prob = jnp.take_along_axis(probs, action[..., None], axis=-1)[..., 0]
+                value = (
+                    out["value"].reshape(B, P)
+                    if out.get("value") is not None
+                    else jnp.zeros_like(prob)
+                )
+            with jax.named_scope(STEP_SCOPE):
+                record = {
+                    "active": active,
+                    "observing": observing,
+                    "legal": legal,
+                    "action": action.astype(jnp.int32),
+                    "prob": prob,
+                    "value": value,
+                }
+                record.update(venv.record(state))   # env's compact obs fields
+                state = venv.step(state, action, kf)
+                record["done"] = state["done"]   # reset_done cleared stale flags
+                record["outcome"] = venv.outcome_scores(state)  # final where done
             return (state, hidden), record
 
         # Stays a genuine loop on every backend: unrolling k_steps bodies
@@ -290,8 +308,9 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
         return state, hidden, records
 
     fn.__name__ = STREAM_PROGRAM
+    options = scoped_program_options(*STREAM_SCOPES)
     if mesh is None:
-        return jax.jit(fn, donate_argnums=(1, 2))
+        return jax.jit(fn, donate_argnums=(1, 2), compiler_options=options)
     from jax.sharding import NamedSharding, PartitionSpec
 
     lanes = NamedSharding(mesh, PartitionSpec("dp"))            # state: (B, ...)
@@ -302,6 +321,7 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
         donate_argnums=(1, 2),
         in_shardings=(rep, lanes, lanes, rep),
         out_shardings=(lanes, lanes, rec),
+        compiler_options=options,
     )
 
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import os
 import threading
+import zlib
 from typing import Dict, Optional
 
 import jax
@@ -43,6 +44,24 @@ def enable_compile_cache() -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+def scoped_program_options(*scopes: str) -> Dict[str, int]:
+    """``compiler_options`` for the ``jax.jit`` of a program that enters the
+    ``jax.named_scope``s ``scopes`` for a profile's readers.
+
+    jax keys the persistent cache by the computation with its locations
+    stripped, and a scope lives in locations alone: a program that only
+    gained or renamed a scope has the key it had, loads the executable
+    compiled before, and shows that one's op names in every profile
+    (PERF.md section 6, PR 39, met it on the chip;
+    tests/test_program_phases.py shows it on a toy).  Compile options are
+    part of the key, so the scopes' names go into one: the number of buffers
+    XLA lists when asked to print a buffer assignment, which is read by no
+    compiler pass.  A scope that moves without a new name keeps the key:
+    clear the cache, or rename it."""
+    digest = zlib.crc32(",".join(scopes).encode())
+    return {"xla_debug_buffer_assignment_show_max": 16 + digest % 1_000_000}
 
 
 class CompileCounters:

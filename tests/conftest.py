@@ -16,7 +16,8 @@ import pytest
 # instrument every PR is judged by; nothing else collects them): their
 # asserts are rewritten like any test module's
 pytest.register_assert_rewrite(
-    "benchmark.tests.test_layer_readers", "benchmark.tests.test_profile_wait",
+    "benchmark.tests.test_layer_readers", "benchmark.tests.test_phase_readers",
+    "benchmark.tests.test_profile_wait",
     "benchmark.tests.test_reference", "benchmark.tests.test_rehearsal",
     "benchmark.tests.test_trace_reduce",
 )

@@ -12,6 +12,9 @@ from benchmark.tests.test_profile_wait import *  # noqa: F401,F403  isort: skip
 from benchmark.tests.test_layer_readers import (  # noqa: F401  isort: skip
     test_rehearsed_loop_answers_rollout_wait_share,
 )
+from benchmark.tests.test_phase_readers import (  # noqa: F401  isort: skip
+    test_rehearsed_loop_answers_rollout_submit_share_and_no_phase,
+)
 
 # Collected in this order, but for the runners, moved to the end.  The five
 # cases that run the tiny loop cell need a whole epoch inside an 8 s window,

@@ -45,9 +45,12 @@ SCRIPTS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 
 @pytest.fixture(autouse=True)
 def _tracer_reset():
-    """Every test leaves the process tracer disarmed (the module singleton
-    is process-global state shared with any Learner the suite builds)."""
-    trace_mod.shutdown()
+    """Every test starts from a tracer disarmed and counted from zero, and
+    leaves it disarmed (the module singleton is process-global state shared
+    with any Learner or TrainContext the suite builds: a one-off event that
+    another file of this xdist worker recorded stays in ``trace_stats``
+    until the next ``configure``)."""
+    trace_mod.configure(None)
     yield
     trace_mod.shutdown()
 
