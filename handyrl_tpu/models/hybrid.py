@@ -32,7 +32,9 @@ their own experts' terms only (``ops/routed_experts.py``).  The window mode
 returns, beside the heads, ``choices`` (per ``E`` layer the experts each
 token chose, (rows, T, top_k)) and ``counters`` (the packed array's slots,
 the observed steps, those the packing left out, and with ``E`` layers the
-rows the held experts computed); ``forward_prediction`` hands both on.
+rows the held experts computed, the slots of the row buffers they were
+computed in and the passes past the first those took);
+``forward_prediction`` hands both on.
 """
 
 from __future__ import annotations
@@ -168,7 +170,8 @@ class ExpertLayer(nn.Module):
     @nn.compact
     def __call__(self, h, valid=None):
         """h (..., d) tokens, valid (...) -> (out, chosen (..., k) int32,
-        rows (held,) int32 the held experts computed)."""
+        counts: ``held_mix``'s, the rows the held experts computed and the
+        row buffer's passes and slots)."""
         lead, d = h.shape[:-1], h.shape[-1]
         tokens = h.reshape(-1, d)
         ok = jnp.ones(tokens.shape[:1], bool) if valid is None else valid.reshape(-1)
@@ -183,13 +186,13 @@ class ExpertLayer(nn.Module):
             scores = jax.nn.sigmoid(jnp.dot(
                 tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT))
             chosen, gates = choose(scores, bias, self.top_k, self.routed_scale)
-        routed, rows = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype), w2.astype(h.dtype),
-                                self.expert_offset, self.n_experts)
+        routed, counts = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype),
+                                  w2.astype(h.dtype), self.expert_offset, self.n_experts)
         with jax.named_scope("shared_expert"):
             up = _dense(self.shared_width, "shared_up")(tokens)
             shared = _dense(d, "shared_down")(jnp.square(nn.relu(up)))
         return ((routed + shared).reshape(lead + (d,)),
-                chosen.reshape(lead + (self.top_k,)), rows)
+                chosen.reshape(lead + (self.top_k,)), counts)
 
 
 class GroupedQueryAttention(nn.Module):
@@ -258,8 +261,8 @@ class Layer(nn.Module):
     def __call__(self, x, state, valid):
         h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],)), self.eps)
         if isinstance(self.mixer, ExpertLayer):
-            y, chosen, rows = self.mixer(h, valid)
-            return x + y, state, (chosen, rows)
+            y, chosen, counts = self.mixer(h, valid)
+            return x + y, state, (chosen, counts)
         y, state = self.mixer(h, state, valid)
         return x + y, state, None
 
@@ -316,14 +319,14 @@ class HybridNet(nn.Module):
     @staticmethod
     def _through(layers, x, states, valid):
         """Every layer once over ``x`` ((N, L, d) with ``valid``, or (N, d)):
-        -> (x, new states, {layer: chosen}, {layer: rows})."""
-        new_states, chosen, rows = [], {}, {}
+        -> (x, new states, {layer: chosen}, {layer: counts})."""
+        new_states, chosen, counts = [], {}, {}
         for layer, state in zip(layers, states):
             x, state, routed = layer(x, state, valid)
             new_states.append(state)
             if routed is not None:
-                chosen[layer.name], rows[layer.name] = routed
-        return x, tuple(new_states), chosen, rows
+                chosen[layer.name], counts[layer.name] = routed
+        return x, tuple(new_states), chosen, counts
 
     def _heads(self, x):
         h = _rms(x, self.param("norm_f", nn.initializers.ones, (self.d_model,)), self.norm_eps)
@@ -373,7 +376,7 @@ class HybridNet(nn.Module):
         states = self._window_state(n, x.dtype)
         # one checkpoint per layer where asked: only a layer's input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
-        outs, chosen, rows = [], [], []
+        outs, chosen, counts = [], [], []
         slots = dropped = 0
         for part, lo, hi in (("burn_in", 0, burn_in), ("forward", burn_in, T)):
             if lo == hi:
@@ -389,7 +392,7 @@ class HybridNet(nn.Module):
             outs.append(jnp.einsum("nit,nid->ntd", place, y, precision=_EXACT))
             spread = place.astype(jnp.int32)
             chosen.append({k: jnp.einsum("nit,nik->ntk", spread, v) for k, v in picked.items()})
-            rows.append(count)
+            counts.append(count)
         out = self._heads(jnp.concatenate(outs, axis=1))
         # slots the mixers ran over, those of them that hold a token, and the
         # tokens no slot held (a handed packed_order that is too short)
@@ -401,11 +404,17 @@ class HybridNet(nn.Module):
         if chosen[0]:
             out["choices"] = {k: jnp.concatenate([c[k] for c in chosen], axis=1)
                               for k in chosen[0]}
-            by_layer = jnp.stack([sum(r[k] for r in rows) for k in rows[0]])   # (layers, held)
+            by_layer = jnp.stack(      # (layers, held)
+                [sum(c[k]["rows"] for c in counts) for k in counts[0]])
+            buffers = [c[k] for c in counts for k in c]     # one a routed layer and window part
             out["counters"].update(
                 rows_held=by_layer.sum().astype(jnp.float32),
                 expert_rows_max=by_layer.max().astype(jnp.float32),
                 expert_rows_mean=by_layer.astype(jnp.float32).mean(),
+                # slots computed (every pass's), and the passes a buffer that
+                # sufficed would not have taken
+                buffer_slots=sum(b["slots"] for b in buffers).astype(jnp.float32),
+                expert_passes=sum(b["passes"] - 1 for b in buffers).astype(jnp.float32),
             )
         return out
 

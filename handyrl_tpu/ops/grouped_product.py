@@ -1,0 +1,166 @@
+"""Grouped matrix products over a row buffer in blocks of ``BLOCK`` rows:
+each block's rows are multiplied by the weights of the one group (expert)
+the block belongs to, read where they lie.
+
+``owner`` (blocks,) int32 names each block's group and is non-decreasing:
+a group's blocks are consecutive.  It is handed to the kernels as a
+prefetched scalar array that the weight operand's index map reads, so no
+block's weights are copied out.  Every block is computed, whatever it holds:
+the work is the buffer's, not the routing's.
+
+* ``grouped_dot(x, w, owner)``: x (m, k), w (groups, k, n) -> (m, n) float32,
+  ``out[block b] = x[block b] @ w[owner[b]]``.  The grid runs the row blocks
+  innermost, so a group's weight tile stays in VMEM across its blocks and is
+  read once a column tile.  Differentiable: the rows' cotangent is the same
+  kernel through the transposed weights (``w`` read as it lies), the
+  weights' gradient ``_weight_sums``.
+* ``_weight_sums(x, dy, owner, groups)``: (groups, k, n),
+  ``out[g] = sum over g's blocks of x[block]^T @ dy[block]``, accumulated in
+  float32 in VMEM and written once a group, by the kernel, zeros for a group
+  with no block.
+
+Operands go to the MXU as they are handed over (bf16 in the train step) and
+accumulate in float32.  Pallas on the TPU, the Pallas interpreter elsewhere
+(``interpret=None`` picks, as ``ops/flash_attention.py`` does).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+BLOCK = 128                 # rows of a block: the MXU's tile
+TILE = 4096                 # most columns of a weight tile held in VMEM
+_VMEM_LIMIT = 64 << 20      # over the compiler's default scope: a weight tile is double-buffered
+
+
+def _tile(n: int) -> int:
+    """A dimension whole where it fits a tile, else tiles of ``TILE`` (the
+    last one partial: what lies past an array's edge is never written)."""
+    return min(n, TILE)
+
+
+def _call(kernel, prefetched, grid, in_specs, out_spec, out_shape, scratch, interpret, *operands):
+    """``pallas_call`` whose first ``len(prefetched)`` operands are int32
+    vectors prefetched to SMEM: the index maps and the kernel read them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched), grid=grid, in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(*prefetched, *operands)
+
+
+def _rows_kernel(owner_ref, x_ref, w_ref, o_ref, *, transposed: bool):
+    del owner_ref   # read by the index maps
+    dims = (((1,), (1 if transposed else 0,)), ((), ()))
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], w_ref[...], dims, preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("transposed", "out_dtype", "interpret"))
+def _rows_times(x, w, owner, transposed: bool, out_dtype, interpret: bool):
+    """out[block b] = x[block b] @ w[owner[b]] (``transposed``: @ w[owner[b]]^T).
+    Jitted, as ``_weight_sums`` is: a net calls each at a few shapes many
+    times (layers, window parts, the replay), and a jitted callee is traced
+    and lowered to its kernel once a shape, not once a call."""
+    from jax.experimental import pallas as pl
+
+    (m, k), n = x.shape, w.shape[1 if transposed else 2]
+    tn = _tile(n)
+    if transposed:
+        w_spec = pl.BlockSpec((None, tn, k), lambda j, b, owner: (owner[b], j, 0))
+    else:
+        w_spec = pl.BlockSpec((None, k, tn), lambda j, b, owner: (owner[b], 0, j))
+    return _call(
+        functools.partial(_rows_kernel, transposed=transposed), (owner,),
+        (pl.cdiv(n, tn), m // BLOCK),      # row blocks innermost: a weight tile stays
+        [pl.BlockSpec((BLOCK, k), lambda j, b, owner: (b, 0)), w_spec],
+        pl.BlockSpec((BLOCK, tn), lambda j, b, owner: (b, j)),
+        jax.ShapeDtypeStruct((m, n), out_dtype), [], interpret, x, w)
+
+
+def _sums_kernel(group_ref, block_ref, x_ref, dy_ref, o_ref, acc_ref):
+    from jax.experimental import pallas as pl
+
+    del block_ref   # read by the index maps
+    step, last = pl.program_id(2), pl.num_programs(2) - 1
+    group = group_ref[step]
+    opens = (step == 0) | (group_ref[jnp.maximum(step - 1, 0)] != group)
+    closes = (step == last) | (group_ref[jnp.minimum(step + 1, last)] != group)
+
+    @pl.when(opens)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(jnp.logical_not(closes))    # a group's last step holds no block
+    def _():
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(closes)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "out_dtype", "interpret"))
+def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool):
+    """out[g] = sum over the blocks b with owner[b] == g of x[b]^T @ dy[b].
+
+    The grid's innermost axis walks each group's blocks and then one step
+    more that holds no block and writes the group's sum out: so a group
+    with no block is written too, as zeros, and no pass over the output
+    follows the kernel.  Step ``s`` of group ``g`` comes after one such
+    step of each earlier group: it is block ``s - g``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (m, k), n, blocks = x.shape, dy.shape[1], x.shape[0] // BLOCK
+    tk, tn = _tile(k), _tile(n)
+    group = jnp.sort(jnp.concatenate([owner, jnp.arange(groups, dtype=owner.dtype)]))
+    block = jnp.minimum(jnp.arange(blocks + groups, dtype=owner.dtype) - group, blocks - 1)
+    return _call(
+        _sums_kernel, (group, block),
+        (pl.cdiv(k, tk), pl.cdiv(n, tn), blocks + groups),
+        [pl.BlockSpec((BLOCK, tk), lambda i, j, s, group, block: (block[s], i)),
+         pl.BlockSpec((BLOCK, tn), lambda i, j, s, group, block: (block[s], j))],
+        pl.BlockSpec((None, tk, tn), lambda i, j, s, group, block: (group[s], i, j)),
+        jax.ShapeDtypeStruct((groups, k, n), out_dtype),
+        [pltpu.VMEM((tk, tn), jnp.float32)], interpret, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_dot(x, w, owner, interpret: Optional[bool] = None):
+    """x (m, k) in blocks of ``BLOCK`` rows, w (groups, k, n), owner
+    (m / BLOCK,) int32 non-decreasing -> (m, n) float32: each block's rows
+    times its group's weights."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _rows_times(x, w, owner, False, jnp.float32, interpret)
+
+
+def _grouped_fwd(x, w, owner, interpret):
+    return grouped_dot(x, w, owner, interpret), (x, w, owner)
+
+
+def _grouped_bwd(interpret, saved, dy):
+    x, w, owner = saved
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dy = dy.astype(x.dtype)     # the MXU's operand, as the weights are
+    return (_rows_times(dy, w, owner, True, x.dtype, interpret),
+            _weight_sums(x, dy, owner, w.shape[0], w.dtype, interpret), None)
+
+
+grouped_dot.defvjp(_grouped_fwd, _grouped_bwd)

@@ -10,33 +10,43 @@ chip it runs without the exchange; nothing stands in for the absent chips.
 No token is dropped at any imbalance.  The (token, choice) pairs that fall
 on held experts are sorted by expert into a row buffer in which every
 expert's rows start on a block of ``BLOCK`` rows, so a block belongs to one
-expert: the two products are batched matrix products over the blocks, each
-with its expert's weights (picked by a one-hot product, whose transpose sums
-the blocks' weight gradients back per expert).  Shapes are static and so is
-the work: the buffer holds a uniform router's share of rows and a block of
-padding for each held expert, and every block is computed whether rows fill
-it or not (the step's time does not move with the routing; what the chip
-showed of a grouped kernel that skips empty tiles is in PERF.md, PR 34).
-An update whose rows outgrow the buffer takes further passes over it, as
-many as its rows need (``_passes``: a ``lax.while_loop`` forward and
-backward, so the worst case, every token choosing ``min(top_k, held)`` held
-experts, costs time and no memory).  Filling the buffer and summing a
-token's rows back are each other's transpose and are written as gathers
-both ways (``_dispatch`` / ``_combine``), so no scatter runs forward or
-backward.
+expert: the two products are grouped products over the blocks, each block
+against its expert's weights read where they lie (``ops/grouped_product.py``:
+a Pallas kernel whose weight operand is indexed by the block's expert; its
+transpose sums the blocks' weight gradients once an expert).  That kernel
+takes bfloat16 operands; float32 operands (the judge's forward, tests) keep
+the plain ``jnp`` block products, at the precision their caller asks.
+Shapes are static and so is the work: the buffer holds ``SHARES`` times a
+uniform router's share of rows and a block of padding for each held expert,
+and every block is computed whether rows fill it or not, the blocks past the
+last row as the last expert's with gate 0 (the step's time does not move
+with the routing; what the chip showed of a grouped kernel that skips empty
+tiles is in PERF.md, PR 34).  An update whose rows outgrow the buffer takes
+further passes over it, as many as its rows need (``_passes``: a
+``lax.while_loop`` forward and backward, so the worst case, every token
+choosing ``min(top_k, held)`` held experts, costs time and no memory).
+Filling the buffer and summing a token's rows back are each other's
+transpose and are written as gathers both ways (``_dispatch`` /
+``_combine``), so no scatter runs forward or backward.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-BLOCK = 512         # rows of the buffer that share one expert's weights
-AT_ONCE = 6         # blocks whose weights are copied out together
-_EXACT = jax.lax.Precision.HIGHEST     # picking weights must not round them
+from .grouped_product import BLOCK, grouped_dot     # BLOCK: rows that share one expert's weights
+
+# uniform router's shares of rows the buffer holds.  At 2 the cell's routers,
+# drawn from the run's seed, outgrow the buffer in 2 of 14 seeds (counted on
+# the CPU), at 2.5 in none of those and in 2 of 20 on the chip (PERF.md, PR
+# 40): a further pass costs a whole buffer's work
+SHARES = 2.5
+EXPERTS_SCOPE = "experts"   # the two products, forward and backward (benchmark/scopes.py)
 
 
 def choose(scores, bias, top_k: int, scale: float):
@@ -96,12 +106,11 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def row_buffer(n: int, top_k: int, held: int, experts: int) -> Tuple[int, int]:
     """(blocks of the buffer, passes that cover the worst case) for ``n``
-    tokens: a uniform router's share of rows and a block of padding for each
-    held expert, and at most the worst case (every token choosing
-    ``min(top_k, held)`` held experts) with its padding."""
+    tokens: ``SHARES`` times a uniform router's share of rows and a block of
+    padding for each held expert, and at most the worst case (every token
+    choosing ``min(top_k, held)`` held experts) with its padding."""
     worst = -(-n * min(top_k, held) // BLOCK) + held
-    blocks = min(worst, -(-n * top_k * held // (experts * BLOCK)) + held)
-    blocks = -(-blocks // AT_ONCE) * AT_ONCE
+    blocks = min(worst, math.ceil(SHARES * n * top_k * held / (experts * BLOCK)) + held)
     return blocks, -(-worst // blocks)
 
 
@@ -112,8 +121,9 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int):
     float32, valid (n,) bool (a token that is padding is routed nowhere),
     w1 (held, d, w), w2 (held, w, d).  Returns (out (n, d) in h's dtype:
     sum over a token's chosen experts that are held of gate x
-    w2[e] relu(w1[e] h)^2; rows (held,) int32: the rows each held expert
-    computed)."""
+    w2[e] relu(w1[e] h)^2; counts: ``rows`` (held,) int32 the rows each held
+    expert computed, ``passes`` () int32 the passes over the row buffer that
+    took them, ``slots`` () int32 the buffer slots those passes computed)."""
     n, k = chosen.shape
     held = w1.shape[0]
     local = chosen - offset
@@ -130,43 +140,56 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int):
         slot = (base[key] + rank - jnp.append(first, 0)[key]).reshape(n, k)   # pair -> slot
     route = {"order": order, "slot": slot, "live": live, "rows": rows, "first": first,
              "ends": ends, "base": base}
-    return _passes(h, gates, w1, w2, route, row_buffer(n, k, held, experts)[0]), rows
+    blocks = row_buffer(n, k, held, experts)[0]
+    passes = _needed(route, blocks)
+    return (_passes(h, gates, w1, w2, route, blocks),
+            {"rows": rows, "passes": passes, "slots": passes * (blocks * BLOCK)})
+
+
+def _block_products(x, w1, w2, owner):
+    """x (m, d) in blocks of ``BLOCK`` rows, block ``b`` of expert
+    ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block."""
+    if x.dtype == jnp.bfloat16:
+        product = lambda rows, w: grouped_dot(rows, w, owner)   # noqa: E731
+    else:   # at the caller's matmul precision, which a kernel's dots would not see
+        def product(rows, w):
+            return jnp.einsum("brk,bkn->brn", rows.reshape(owner.size, BLOCK, -1), w[owner],
+                              preferred_element_type=jnp.float32).reshape(rows.shape[0], -1)
+    act = jnp.square(jax.nn.relu(product(x, w1))).astype(x.dtype)
+    return product(act, w2)
+
+
+def _owners(ends, start, blocks: int):
+    """owner (blocks,) int32, non-decreasing: the expert of each block of the
+    buffer slots [start, start + blocks x BLOCK).  Every block has one, so
+    that the grouped products' work is the buffer's: the blocks past the last
+    row are the last expert's (no row of his reaches them: their slots have
+    gate 0)."""
+    owner = jnp.searchsorted(ends, start + BLOCK * jnp.arange(blocks), side="right")
+    return jnp.minimum(owner, ends.size - 1).astype(jnp.int32)
 
 
 def _one_pass(h, gates, w1, w2, route, start, blocks: int):
     """Slots [start, start + blocks x BLOCK) of the row buffer ``route`` lays out."""
     n, k = route["slot"].shape
-    held, m = w1.shape[0], blocks * BLOCK
+    m = blocks * BLOCK
     rows, first, ends, base = (route[key] for key in ("rows", "first", "ends", "base"))
     with jax.named_scope("route"):
-        owner = jnp.searchsorted(ends, start + BLOCK * jnp.arange(blocks), side="right")
-        picks = (owner[:, None] == jnp.arange(held)[None, :]).astype(h.dtype)
-        expert = jnp.repeat(jnp.minimum(owner, held - 1), BLOCK)
+        owner = _owners(ends, start, blocks)
+        expert = jnp.repeat(owner, BLOCK)
         index = start + jnp.arange(m) - base[expert]                # a slot's row of its expert
-        used = (jnp.repeat(owner, BLOCK) < held) & (index < rows[expert])
+        used = index < rows[expert]
         pair = route["order"][jnp.clip(first[expert] + index, 0, n * k - 1)]
         tok = pair // k
         at = route["slot"] - start
         in_buffer = route["live"] & (at >= 0) & (at < m)
-        x = _dispatch(h, tok, at, in_buffer).reshape(blocks, BLOCK, -1)
+        x = _dispatch(h, tok, at, in_buffer)
         gate = jnp.where(used, gates.reshape(-1)[pair], 0.0)
-
-    def some_blocks(group):
-        x, picks = group
-        up = jnp.einsum("brd,bdw->brw", x, jnp.einsum("be,edw->bdw", picks, w1, precision=_EXACT),
-                        preferred_element_type=jnp.float32)
-        act = jnp.square(jax.nn.relu(up)).astype(h.dtype)
-        return jnp.einsum("brw,bwd->brd", act, jnp.einsum("be,ewd->bwd", picks, w2, precision=_EXACT),
-                          preferred_element_type=jnp.float32)
-
-    with jax.named_scope("experts"):
-        # a few blocks at a time: their experts' weights are copied out per
-        # block, and so are those copies' gradients
-        split = lambda a: a.reshape((blocks // AT_ONCE, AT_ONCE) + a.shape[1:])  # noqa: E731
-        down = jax.lax.map(some_blocks, (split(x), split(picks)))
+    with jax.named_scope(EXPERTS_SCOPE):
+        down = _block_products(x, w1, w2, owner)
     with jax.named_scope("route"):
         # a slot no row fills has gate 0
-        weighted = (down.reshape(m, -1) * gate[:, None]).astype(h.dtype)
+        weighted = (down * gate[:, None]).astype(h.dtype)
         return _combine(weighted, tok, at, in_buffer)
 
 
@@ -181,7 +204,11 @@ def _passes(h, gates, w1, w2, route, blocks: int):
     """Every pass the rows need, in a loop whose trip count is the update's
     own (``lax.while_loop``, differentiated by hand below): the usual update
     runs one pass and holds one pass's buffers, and the worst case, every
-    token on held experts, costs memory for one pass too."""
+    token on held experts, costs memory for one pass too.  (The first pass
+    is not taken out of the loop, though the loop's zeros and sums cost the
+    usual update 9 ms of 217: a second copy of the pass's kernels made the
+    step's executable a quarter larger and its load 8 s longer, PERF.md,
+    PR 40.)"""
     def one_more(carry):
         done, out = carry
         return done + 1, out + _one_pass(h, gates, w1, w2, route, done * blocks * BLOCK, blocks)

@@ -2,10 +2,14 @@
 collected where tests are run.  The rehearsal's cases run ``run.py`` on a
 workload of their own (``benchmark_out/tiny_hybrid_train``), so they share
 no output directory with tests/test_benchmark_rehearsals.py; the routed
-cell's case of test_phase_readers.py runs it too, so it is collected here."""
+cell's cases of test_phase_readers.py and test_expert_buffer_fill.py run it
+too, so they are collected here."""
 
 from benchmark.tests.test_hybrid_reference import *  # noqa: F401,F403
 from benchmark.tests.test_hybrid_rehearsal import *  # noqa: F401,F403  isort: skip
 from benchmark.tests.test_phase_readers import (  # noqa: F401  isort: skip
     hybrid_root, test_rehearsed_routed_cell_answers_packed_padding_share,
+)
+from benchmark.tests.test_expert_buffer_fill import (  # noqa: F401  isort: skip
+    test_rehearsed_routed_cell_answers_expert_buffer_fill,
 )

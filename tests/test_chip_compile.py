@@ -1,6 +1,7 @@
-"""Both Pallas attention kernels, and the d1536 train step on a {dp: 4}
+"""Both Pallas attention kernels, the routed experts' grouped products at
+nemotron_twotower_train_t192's widths, and the d1536 train step on a {dp: 4}
 mesh, compiled for a described (not attached) TPU v5e by the chip's own
-compiler, at the shapes chip_smoke.py pins.
+compiler, at the shapes chip_smoke.py and the benchmark's cells pin.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (a slice
 not aligned to the tiling, too much VMEM); these compiles can, at about
@@ -105,6 +106,54 @@ def test_kernel_compiles_for_v5e(v5e, kernel, shape, mode):
     assert "tpu_custom_call" in compiled.as_text(), (
         "the Pallas kernel is not in the compiled program"
     )
+
+
+# -- the routed experts' grouped products (ops/grouped_product.py) ---------
+
+# nemotron_twotower_30b_a3b as benchmark/configs/ has it: hidden 2,688, expert
+# width 1,856 = 14.5 x 128, 8 of 128 experts held, top-6
+_EXPERTS = dict(d=2688, width=1856, held=8, experts=128, top_k=6)
+
+
+@pytest.mark.parametrize("tokens", [6144, 512, 2], ids=["forward_part", "burn_in_part", "acting"])
+def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypatch, tokens):
+    """``held_mix`` with bf16 operands and its gradient at the published
+    widths: the cell's two window parts (64 rows x 96 packed steps, x 8
+    burn-in steps) and the handful of tokens step mode acts on.  The width is
+    no multiple of 128 and a weight tile is 10 MB, double-buffered: what the
+    interpreter cannot refuse.  Every product is the kernel (two forward,
+    two rows' cotangents, two weight sums), each of its calls carries the
+    ``experts`` scope the benchmark times it under, and no block's weights
+    are copied out (no (blocks, d, width) operand, no exact one-hot pick)."""
+    from benchmark import trace_reduce
+    from handyrl_tpu.ops.routed_experts import BLOCK, EXPERTS_SCOPE, held_mix, row_buffer
+
+    d, width, held, experts, k = (_EXPERTS[key] for key in ("d", "width", "held", "experts", "top_k"))
+    # the auto-pick would see this process's CPU backend and hand over the interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+
+    def loss(h, gates, w1, w2, chosen, valid):
+        out, _ = held_mix(h, chosen, gates, valid, w1, w2, 0, experts)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
+        aval((held, d, width), jnp.bfloat16), aval((held, width, d), jnp.bfloat16),
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    # the forward pass's two products; under the hand-written backward those
+    # two again, two rows' cotangents and two weight sums
+    assert len(calls) == 2 + 6, len(calls)
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert all(trace_reduce.scopes_of(n, [EXPERTS_SCOPE]) == [EXPERTS_SCOPE] for n in names), names
+    blocks = row_buffer(tokens, k, held, experts)[0]
+    assert blocks == {6144: 53, 512: 12, 2: 9}[tokens] and BLOCK == 128
+    for copied in ("[%d,%d,%d]" % (blocks, d, width), "[%d,%d,%d]" % (blocks, width, d)):
+        assert copied not in text
+    assert "precision_config" not in text or "HIGHEST" not in text
 
 
 # -- the d1536 train step on a described {dp: N} mesh ----------------------

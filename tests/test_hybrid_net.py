@@ -8,6 +8,7 @@ train step.
 
 import importlib.util
 import json
+import math
 import os
 import random
 
@@ -20,7 +21,9 @@ from handyrl_tpu.config import normalize_args
 from handyrl_tpu.envs import make_env
 from handyrl_tpu.models import HybridNet
 from handyrl_tpu.models.hybrid import ExpertLayer
-from handyrl_tpu.ops.routed_experts import BLOCK, choose, held_mix, row_buffer
+from handyrl_tpu.ops.grouped_product import grouped_dot
+from handyrl_tpu.ops.routed_experts import (
+    BLOCK, EXPERTS_SCOPE, SHARES, _owners, choose, held_mix, row_buffer)
 from handyrl_tpu.ops.ssd import ssd_chunked, ssd_step
 from handyrl_tpu.parallel import TrainContext, make_mesh
 from handyrl_tpu.parallel.train_step import (
@@ -338,7 +341,10 @@ def test_a_packed_window_equals_the_whole_one(long_windows, windows, bounds, slo
     assert counted.pop("packed_slots") == slots and counted["packed_dropped"] == 0
     assert whole["counters"]["packed_slots"] == 6 * (args["burn_in_steps"] + 40)
     assert counted["observed_steps"] == float(np.sum(batch["observation_mask"]))
-    assert counted == {k: v for k, v in whole["counters"].items() if k != "packed_slots"}
+    # the row buffers are sized from the slots the mixers run over
+    assert counted.pop("buffer_slots") <= whole["counters"]["buffer_slots"]
+    assert counted == {k: v for k, v in whole["counters"].items()
+                       if k not in ("packed_slots", "buffer_slots")}
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_grad),
                             jax.tree.leaves(whole_grad)):
         np.testing.assert_allclose(
@@ -500,9 +506,9 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
     for share in range(16):
         held = dict(params, w1=params["w1"][2 * share:2 * share + 2],
                     w2=params["w2"][2 * share:2 * share + 2])
-        out, picked, rows = _expert_layer(2, 2 * share).apply({"params": held}, h)
+        out, picked, counts = _expert_layer(2, 2 * share).apply({"params": held}, h)
         assert np.array_equal(np.sort(picked, -1), np.sort(chosen, -1))     # routes over all
-        assert int(rows.sum()) == int(((chosen >= 2 * share) & (chosen < 2 * share + 2)).sum())
+        assert int(counts["rows"].sum()) == int(((chosen >= 2 * share) & (chosen < 2 * share + 2)).sum())
         total = total + (out - shared)
     np.testing.assert_allclose(total, want, atol=2e-5)
 
@@ -518,7 +524,7 @@ def test_the_score_bias_changes_choices_and_not_gates():
     np.testing.assert_allclose(gates.sum(axis=-1), 2.5, rtol=1e-6)
 
 
-@pytest.mark.parametrize("tokens", [40, 3000])
+@pytest.mark.parametrize("tokens", [40, 600, 3000])
 def test_one_expert_given_every_token_drops_none(tokens):
     """Every token chooses the same two held experts: the rows outgrow the
     buffer (a uniform router's share and a block of padding an expert) and
@@ -532,9 +538,9 @@ def test_one_expert_given_every_token_drops_none(tokens):
     gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
     valid = jnp.asarray(rng.rand(tokens) > 0.1)
     blocks, passes = row_buffer(tokens, k, held, experts)
-    assert (passes > 1 and blocks * BLOCK < int(valid.sum()) * k) == (tokens > 1000)
-    out, rows = jax.jit(lambda *a: held_mix(*a, 8, experts))(h, chosen, gates, valid, w1, w2)
-    assert rows.tolist() == [0, int(valid.sum()), int(valid.sum()), 0]
+    assert (passes > 1 and blocks * BLOCK < int(valid.sum()) * k) == (tokens > 500)
+    out, counts = jax.jit(lambda *a: held_mix(*a, 8, experts))(h, chosen, gates, valid, w1, w2)
+    assert counts["rows"].tolist() == [0, int(valid.sum()), int(valid.sum()), 0]
     act = lambda e: jnp.square(jax.nn.relu(h @ w1[e])) @ w2[e]  # noqa: E731
     want = valid[:, None] * (gates[:, :1] * act(1) + gates[:, 1:] * act(2))
     np.testing.assert_allclose(out, want, atol=2e-5)
@@ -544,6 +550,199 @@ def test_one_expert_given_every_token_drops_none(tokens):
         gates[:, :1] * (jnp.square(jax.nn.relu(h @ w[1])) @ w2[1])
         + gates[:, 1:] * (jnp.square(jax.nn.relu(h @ w[2])) @ w2[2]))) ** 2))(w1)
     np.testing.assert_allclose(grad, want, atol=2e-4 * float(jnp.abs(want).max()))
+
+
+def _by_expert(h, chosen, gates, valid, w1, w2, offset):
+    """The plain loop: for every held expert, every token that chose it,
+    with the kernel's roundings (float32 accumulation, relu^2 in float32,
+    the operands' dtype between the products and into the sum)."""
+    out = jnp.zeros(h.shape, jnp.float32)
+    for e in range(w1.shape[0]):
+        up = jnp.dot(h, w1[e], preferred_element_type=jnp.float32)
+        act = jnp.square(jax.nn.relu(up)).astype(h.dtype)
+        down = jnp.dot(act, w2[e], preferred_element_type=jnp.float32)
+        gate = jnp.where((chosen == e + offset) & valid[:, None], gates, 0.0).sum(axis=1)
+        out = out + (down * gate[:, None]).astype(h.dtype)
+    return out.astype(h.dtype)
+
+
+def _close(got, want, dtype, what):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # bfloat16 keeps 8 bits: sums of a few hundred rounded terms in two orders
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got, want, atol=tol * max(1.0, float(np.abs(want).max())),
+                               err_msg=what)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("k,n", [(64, 192), (192, 64), (64, 1856)])
+def test_grouped_dot_is_each_blocks_rows_by_its_experts_weights(dtype, k, n):
+    """The kernel (in the interpreter) against a loop over the blocks, forward
+    and both gradients, at widths under a tile, not a multiple of 128, and
+    over a tile with a partial last one; an expert with no block gets a zero
+    gradient."""
+    rng = np.random.RandomState(11)
+    owner = jnp.asarray([0, 2, 2, 2, 4, 4], jnp.int32)       # 1 and 3 hold no block
+    x = jnp.asarray(rng.randn(owner.size * BLOCK, k), dtype)
+    w = jnp.asarray(rng.randn(5, k, n) / np.sqrt(k), dtype)
+
+    def loop(x, w):
+        blocks = x.reshape(owner.size, BLOCK, k)
+        return jnp.concatenate([
+            jnp.dot(blocks[b], w[int(e)], preferred_element_type=jnp.float32)
+            for b, e in enumerate(owner)])
+
+    _close(grouped_dot(x, w, owner, True), loop(x, w), dtype, "forward")
+    weigh = jnp.asarray(rng.randn(x.shape[0], n), jnp.float32)
+    grads = lambda fn: jax.grad(lambda x, w: jnp.sum(fn(x, w) * weigh), argnums=(0, 1))(x, w)  # noqa: E731
+    (dx, dw), (want_dx, want_dw) = grads(lambda x, w: grouped_dot(x, w, owner, True)), grads(loop)
+    assert dx.dtype == dtype and dw.dtype == dtype
+    _close(dx, want_dx, dtype, "rows' cotangent")
+    _close(dw, want_dw, dtype, "weights' gradient")
+    assert not np.asarray(dw[1], np.float32).any() and not np.asarray(dw[3], np.float32).any()
+
+
+def _routing(rng, tokens, rows_of, held, offset, k):
+    """chosen (tokens, k): expert ``offset + e`` is chosen by exactly
+    ``rows_of[e]`` tokens, no token choosing an expert twice; every other
+    choice falls on an expert that is not held."""
+    picks = np.concatenate([np.full(r, offset + e) for e, r in enumerate(rows_of)])
+    assert picks.size <= tokens * k
+    picks = np.concatenate([picks, np.full(tokens * k - picks.size, -1)]).reshape(k, tokens).T
+    picks = np.where(picks < 0, offset + held + np.arange(k)[None, :], picks)
+    assert (np.diff(np.sort(picks, axis=1), axis=1) > 0).all()
+    return jnp.asarray(picks[rng.permutation(tokens)], jnp.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows_of,passes", [
+    ((0, 128, 40, 300), 1),         # an expert with no row, one with exactly a block
+    ((500, 0, 257, 129), 2),        # the rows outgrow the buffer once
+], ids=["one_pass", "two_passes"])
+def test_held_mix_is_the_loop_over_experts_and_so_are_its_gradients(dtype, rows_of, passes):
+    """bfloat16 operands go through the grouped kernel (the interpreter
+    here), float32 ones through the plain block products: both are the loop
+    over experts, forward and for the gradients of ``h``, ``gates``, ``w1``
+    and ``w2``, at an expert width that is no multiple of 128."""
+    rng = np.random.RandomState(7)
+    tokens, d, width, held, experts, k, offset = 640, 32, 192, 4, 32, 2, 8
+    blocks, _ = row_buffer(tokens, k, held, experts)
+    assert blocks == math.ceil(SHARES * tokens * k * held / (experts * BLOCK)) + held == 8
+    h = jnp.asarray(rng.randn(tokens, d), dtype)
+    w1 = jnp.asarray(rng.randn(held, d, width) / 4, dtype)
+    w2 = jnp.asarray(rng.randn(held, width, d) / 8, dtype)
+    gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    valid = jnp.ones(tokens, bool)
+    chosen = _routing(rng, tokens, rows_of, held, offset, k)
+    weigh = jnp.asarray(rng.randn(tokens, d), jnp.float32)
+
+    out, counts = jax.jit(lambda *a: held_mix(*a, offset, experts))(h, chosen, gates, valid, w1, w2)
+    assert counts["rows"].tolist() == list(rows_of)
+    assert int(counts["passes"]) == passes and int(counts["slots"]) == passes * blocks * BLOCK
+    _close(out, _by_expert(h, chosen, gates, valid, w1, w2, offset), dtype, "forward")
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda h, g, a, b: jnp.sum(fn(h, g, a, b).astype(jnp.float32) * weigh),
+            argnums=(0, 1, 2, 3)))(h, gates, w1, w2)
+
+    got = grads(lambda h, g, a, b: held_mix(h, chosen, g, valid, a, b, offset, experts)[0])
+    want = grads(lambda h, g, a, b: _by_expert(h, chosen, g, valid, a, b, offset))
+    for name, a, b in zip(("h", "gates", "w1", "w2"), got, want):
+        assert a.dtype == b.dtype
+        _close(a, b, dtype, "gradient of " + name)
+    # the expert with no row: its weights get no gradient
+    empty = rows_of.index(0)
+    assert not np.asarray(got[2][empty], np.float32).any()
+    assert not np.asarray(got[3][empty], np.float32).any()
+
+
+def test_the_work_is_the_buffers_whatever_the_routing():
+    """Two routings of one shape lower to the same program, every block of
+    the buffer has an expert in both (the experts' blocks are consecutive and
+    add up to the buffer), and the slots computed are the same while the rows
+    differ."""
+    rng = np.random.RandomState(9)
+    tokens, d, width, held, experts, k, offset = 640, 16, 64, 4, 32, 2, 8
+    h = jnp.asarray(rng.randn(tokens, d), jnp.bfloat16)
+    w1 = jnp.asarray(rng.randn(held, d, width) / 4, jnp.bfloat16)
+    w2 = jnp.asarray(rng.randn(held, width, d) / 8, jnp.bfloat16)
+    gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    valid = jnp.ones(tokens, bool)
+    mix = jax.jit(lambda *a: held_mix(*a, offset, experts))
+    blocks, texts, counted = row_buffer(tokens, k, held, experts)[0], [], []
+    for rows_of in ((100, 100, 100, 100), (0, 3, 500, 129)):
+        chosen = _routing(rng, tokens, rows_of, held, offset, k)
+        texts.append(mix.lower(h, chosen, gates, valid, w1, w2).as_text())
+        counted.append(jax.device_get(mix(h, chosen, gates, valid, w1, w2)[1]))
+        padded = -(-np.asarray(rows_of) // BLOCK) * BLOCK
+        owner = np.asarray(_owners(jnp.cumsum(jnp.asarray(padded)), 0, blocks))
+        sizes = np.bincount(owner, minlength=held)
+        assert sizes.sum() == blocks and (np.diff(owner) >= 0).all()
+        # each expert has its padded rows' blocks, the last one the unfilled ones too
+        assert (sizes[:-1] * BLOCK == padded[:-1]).all() and sizes[-1] * BLOCK >= padded[-1]
+    assert texts[0] == texts[1]
+    assert counted[0]["slots"] == counted[1]["slots"] == blocks * BLOCK
+    assert counted[0]["passes"] == counted[1]["passes"] == 1
+    assert counted[0]["rows"].sum() == 400 and counted[1]["rows"].sum() == 632
+
+
+def test_the_net_counts_its_buffers_slots_and_the_passes_past_the_first():
+    """``counter_buffer_slots`` and ``counter_expert_passes`` of a window:
+    a router near uniform fills one pass of every buffer (0 passes past the
+    first), one whose bias sends every token to two held experts outgrows
+    the forward part's buffer once."""
+    net = dict(NET, pattern="E", n_experts=32, top_k=2, experts_held=4, expert_offset=8)
+    module = HybridNet(num_actions=5, **net)
+    obs, _ = _window(2, rows=6, steps=108, observed=1.1)
+    params = _params(module, obs)
+    seen = jnp.ones((6, 108), jnp.float32)
+
+    def counters(bias):
+        mixer = dict(params["layer0"]["mixer"], score_bias=jnp.asarray(bias, jnp.float32))
+        p = dict(params, layer0=dict(params["layer0"], mixer=mixer))
+        return jax.device_get(module.apply(
+            {"params": p}, obs, None, seq=True, key_mask=seen, burn_in=8)["counters"])
+
+    sizes = [row_buffer(n, 2, 4, 32)[0] * BLOCK for n in (6 * 8, 6 * 100)]
+    plain = counters(np.zeros(32))
+    assert plain["buffer_slots"] == sum(sizes) and plain["expert_passes"] == 0
+    assert 0 < plain["rows_held"] < 0.5 * 2 * 6 * 108
+    skewed = counters(np.eye(32)[[9, 10]].sum(axis=0) * 10.0)
+    assert skewed["rows_held"] == 2 * 6 * 108          # every choice of every token
+    # the forward part's 1,200 rows in two experts' 640 slots each, a 896-slot buffer: a second pass
+    assert skewed["expert_passes"] == 1 and skewed["buffer_slots"] == sizes[0] + 2 * sizes[1]
+
+
+def test_every_product_of_the_gradient_sits_under_the_experts_scope():
+    """Forward and backward: each product of the grouped kernel in the
+    compiled gradient (on the CPU the interpreter's ``dot``s, the only ones
+    ``held_mix`` has) carries ``experts`` as a component of its ``op_name``,
+    as ``benchmark.trace_reduce.scopes_of`` reads a profile: the custom
+    VJP's backward products inherit the scope their forward call was made
+    under (else ``experts_roofline`` would time the forward products alone).
+    tests/test_chip_compile.py reads the same off the kernel's calls in the
+    program compiled for a v5e."""
+    import re
+
+    from benchmark import trace_reduce
+
+    rng = np.random.RandomState(3)
+    tokens, d, width, held, experts, k, offset = 64, 16, 32, 4, 32, 2, 8
+    h = jnp.asarray(rng.randn(tokens, d), jnp.bfloat16)
+    w1 = jnp.asarray(rng.randn(held, d, width), jnp.bfloat16)
+    w2 = jnp.asarray(rng.randn(held, width, d), jnp.bfloat16)
+    gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    chosen = _routing(rng, tokens, (20, 0, 30, 5), held, offset, k)
+    loss = lambda h, g, a, b: jnp.sum(  # noqa: E731
+        held_mix(h, chosen, g, jnp.ones(tokens, bool), a, b, offset, experts)[0].astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(h, gates, w1, w2).compile().as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if re.search(r"= \S+ dot\(", line)]
+    # two forward products, their two rows' cotangents and two weight sums
+    assert len(names) == 6
+    outside = [n for n in names if trace_reduce.scopes_of(n, [EXPERTS_SCOPE]) != [EXPERTS_SCOPE]]
+    assert not outside, outside
 
 
 # -- the train step -----------------------------------------------------------
