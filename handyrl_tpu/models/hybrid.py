@@ -1,16 +1,28 @@
-"""A hybrid policy/value trunk built from a layer-pattern string, after the
-``nemotron_h`` family: ``M`` a Mamba-2 mixer, ``E`` a routed expert layer
-with a shared expert, ``*`` grouped-query causal attention.  Each layer is
-``x + mixer(RMSNorm(x))``; a final RMSNorm feeds the heads; no biases but
-the conv's.  One token is one player's observation at one env step: the
-flattened observation through ``enc1``/``enc2`` stands where a language
+"""A policy/value trunk built from a layer-pattern string: ``M`` a Mamba-2
+mixer, ``E`` a routed expert layer with a shared expert, ``*`` grouped-query
+causal attention (with rotary positions where ``rope_theta`` is set), ``-`` a
+dense gated MLP (SwiGLU).  Each layer is ``x + mixer(RMSNorm(x))``, or with
+``sandwich`` ``x + RMSNorm(mixer(RMSNorm(x)))``; no biases but the conv's.
+``out_scale_init`` is what the second norm's scale starts at: under 1, an
+untrained stack is nearer the identity, as deep residual nets are started.
+The stack is run ``loops`` times over its own weights: a final RMSNorm closes
+every pass, its output feeds the next pass and, after the last, the heads,
+and a gate on each pass's output gives the share of a token that would leave
+the loop there (``exit_t``; nothing reads it but a counter: the heads read
+the last pass).  ``loops`` 1, no ``sandwich`` and no ``rope_theta`` is the
+``nemotron_h`` family's tower; ``"*-"`` layers with all three are a looped
+dense transformer.  One token is one player's observation at one env step:
+the flattened observation through ``enc1``/``enc2`` stands where a language
 model has its embedding, the policy/value/return heads where it has its
 LM head (the same encoder and heads as ``TransformerNet``).
 
-The hidden pytree holds, per layer, what that mixer carries between steps:
-the SSM state (float32) and the conv's last inputs for ``M``, a ring of the
-last ``memory_len`` keys and values for ``*``, nothing for ``E``.  Like
-``TransformerNet`` it has two modes over one parameter set:
+Parameters are per layer (``layer{i}``, used ``loops`` times: their gradient
+is the sum over the uses); state is per *application*.  The hidden pytree
+holds, for every (pass, layer) pair in pass-major order, what that mixer
+carries between steps: the SSM state (float32) and the conv's last inputs
+for ``M``, a ring of the last ``memory_len`` keys (rotated, where they are)
+and values for ``*``, nothing for ``E`` and ``-``.  Like ``TransformerNet``
+it has two modes over one parameter set:
 
 * step mode — ``apply(obs, hidden)``: one step of every recurrence (acting,
   and the train step's scan path, which commits hidden only where observed);
@@ -25,16 +37,21 @@ last ``memory_len`` keys and values for ``*``, nothing for ``E``.  Like
   row observes at most hands the packing over (``packed_order``: per window
   part the index of each row's i-th observed step, as many columns as that
   most): the mixers then run over that many steps, not over the window's.
+  With ``loops`` over 1 the passes of a window are a ``lax.scan`` over the
+  pass index (``scanned``): the stack is in the program once, its
+  parameters closed over, the states and the ``remat: block`` checkpoints
+  (one per layer application) stacked by pass; step mode unrolls them.
 
 ``E`` layers are told which experts they hold (``experts_held``,
 ``expert_offset``): they score and choose over all ``n_experts`` and add
 their own experts' terms only (``ops/routed_experts.py``).  The window mode
 returns, beside the heads, ``choices`` (per ``E`` layer the experts each
 token chose, (rows, T, top_k)) and ``counters`` (the packed array's slots,
-the observed steps, those the packing left out, and with ``E`` layers the
+the observed steps, those the packing left out, with ``E`` layers the
 rows the held experts computed, the slots of the row buffers they were
-computed in and the passes past the first those took);
-``forward_prediction`` hands both on.
+computed in and the passes past the first those took, and with ``loops``
+over 1 the layer applications of a forward and the mean ``exit`` share the
+last pass is left with); ``forward_prediction`` hands both on.
 """
 
 from __future__ import annotations
@@ -50,7 +67,13 @@ from ..ops.routed_experts import choose, held_mix
 from ..ops.ssd import ssd_chunked, ssd_step
 from .transformer import NEG_INF, _flatten_obs
 
-KINDS = "ME*"
+KINDS = "ME*-"
+# ``jax.named_scope``s round the dense trunk's phases: a component of each of
+# their ops' ``op_name`` in a device profile, forward and backward (the
+# benchmark's ``mlp_roofline``, ``attn_step_share`` and ``norm_step_share``
+# import them; docs/observability.md has the naming rule).  ``attn`` holds
+# the projections, ``rope`` and ``gqa``
+ATTN_SCOPE, ROPE_SCOPE, GQA_SCOPE, MLP_SCOPE, NORM_SCOPE = "attn", "rope", "gqa", "mlp", "norm"
 _EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
 
 
@@ -64,6 +87,18 @@ def _rms(x, scale, eps: float, groups: int = 1):
     y = x.astype(jnp.float32).reshape(shape[:-1] + (groups, shape[-1] // groups))
     y = y * jax.lax.rsqrt(jnp.square(y).mean(axis=-1, keepdims=True) + eps)
     return (y.reshape(shape) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, pos, theta: float):
+    """Rotary positions over the whole last axis of ``x`` (N, L, ..., D),
+    rotate-half pairing (d with d + D/2), at ``pos`` (N, L); in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = pos.astype(jnp.float32)[..., None] * inv_freq            # (N, L, D/2)
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
 
 def _compact(key_mask, order=None):
@@ -201,13 +236,20 @@ class GroupedQueryAttention(nn.Module):
     kv_heads: int
     head_dim: int
     memory_len: int
+    rope_theta: float = 0.0     # 0: no positions (order is left to other mixers)
 
     @nn.compact
     def __call__(self, h, state, valid=None):
         """Window mode: h (N, L, d) with ``valid`` a prefix mask, state
         {"k", "v" (N, L0, kv, D), "n" (N,)} the observed steps before this
         window.  Step mode: h (N, d), state {"k", "v" (N, memory_len, kv,
-        D), "pos" (N,)} a ring.  Returns (out, new state)."""
+        D), "pos" (N,)} a ring.  Returns (out, new state).  With
+        ``rope_theta`` queries and keys are rotated by their position among
+        the row's observed steps, and the keys are kept rotated."""
+        with jax.named_scope(ATTN_SCOPE):
+            return self._attend(h, state, valid)
+
+    def _attend(self, h, state, valid):
         Hq, Hk, D = self.heads, self.kv_heads, self.head_dim
         step = h.ndim == 2
         if step:
@@ -216,7 +258,12 @@ class GroupedQueryAttention(nn.Module):
         q = _dense(Hq * D, "q")(h).reshape(n, length, Hk, Hq // Hk, D)
         k = _dense(Hk * D, "k")(h).reshape(n, length, Hk, D)
         v = _dense(Hk * D, "v")(h).reshape(n, length, Hk, D)
-        with jax.named_scope("gqa"):
+        if self.rope_theta:
+            with jax.named_scope(ROPE_SCOPE):
+                at = state["pos"][:, None] if step else (
+                    state["n"][:, None] + jnp.arange(length)[None, :])
+                q, k = _rope(q, at, self.rope_theta), _rope(k, at, self.rope_theta)
+        with jax.named_scope(GQA_SCOPE):
             if step:
                 S = self.memory_len
                 slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
@@ -250,26 +297,51 @@ class GroupedQueryAttention(nn.Module):
         return (out[:, 0] if step else out), new_state
 
 
+class GatedMLP(nn.Module):
+    """``down(silu(gate(h)) * up(h))``, no biases; keeps no state."""
+
+    d_model: int
+    width: int
+
+    @nn.compact
+    def __call__(self, h, state, valid=None):
+        with jax.named_scope(MLP_SCOPE):
+            gated = jax.nn.silu(_dense(self.width, "gate")(h)) * _dense(self.width, "up")(h)
+            return _dense(self.d_model, "down")(gated), state
+
+
 class Layer(nn.Module):
-    """``x + mixer(RMSNorm(x))``; a mixer that keeps no state hands the
-    state it was given back, one that routes says what it chose."""
+    """``x + mixer(RMSNorm(x))``, with ``sandwich`` ``x + RMSNorm(mixer(
+    RMSNorm(x)))``; a mixer that keeps no state hands the state it was given
+    back, one that routes says what it chose."""
 
     mixer: nn.Module
     eps: float
+    sandwich: bool = False
+    out_scale_init: float = 1.0     # what the second norm's scale starts at
 
     @nn.compact
     def __call__(self, x, state, valid):
-        h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],)), self.eps)
+        with jax.named_scope(NORM_SCOPE):
+            h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],)), self.eps)
+        routed = None
         if isinstance(self.mixer, ExpertLayer):
             y, chosen, counts = self.mixer(h, valid)
-            return x + y, state, (chosen, counts)
-        y, state = self.mixer(h, state, valid)
-        return x + y, state, None
+            routed = (chosen, counts)
+        else:
+            y, state = self.mixer(h, state, valid)
+        if self.sandwich:
+            with jax.named_scope(NORM_SCOPE):
+                y = _rms(y, self.param("norm_out", nn.initializers.constant(self.out_scale_init),
+                                       (x.shape[-1],)), self.eps)
+        return x + y, state, routed
 
 
 class HybridNet(nn.Module):
-    """``pattern`` spells the layers (``"MEMEM*EME"``); the widths default to
-    a size tests run and are set by ``env_args['net_args']``."""
+    """``pattern`` spells the layers of the stack (``"MEMEM*EME"``,
+    ``"*-*-"``), ``loops`` how many times it is run over its own weights;
+    the widths default to a size tests run and are set by
+    ``env_args['net_args']``."""
 
     num_actions: int
     pattern: str = "ME*"
@@ -300,6 +372,14 @@ class HybridNet(nn.Module):
     head_dim: int = 16
     memory_len: int = 32
     supports_seq: bool = True  # train path may call with seq=True
+    rope_theta: float = 0.0    # *: rotary positions at this base; 0: none
+    # -: dense gated MLP
+    mlp_width: int = 128
+    # the stack: a second norm on each mixer's output (and what its scale
+    # starts at), passes over the weights
+    sandwich: bool = False
+    out_scale_init: float = 1.0
+    loops: int = 1
 
     def _mixer(self, kind: str):
         # parentless: the Layer it is handed to adopts it, as ``mixer``
@@ -312,14 +392,17 @@ class HybridNet(nn.Module):
             return ExpertLayer(
                 self.d_model, self.n_experts, self.top_k, self.expert_width, self.shared_width,
                 self.routed_scale, self.experts_held, self.expert_offset, parent=None)
+        if kind == "-":
+            return GatedMLP(self.d_model, self.mlp_width, parent=None)
         return GroupedQueryAttention(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
-            parent=None)
+            self.rope_theta, parent=None)
 
     @staticmethod
     def _through(layers, x, states, valid):
-        """Every layer once over ``x`` ((N, L, d) with ``valid``, or (N, d)):
-        -> (x, new states, {layer: chosen}, {layer: counts})."""
+        """Every layer once over ``x`` ((N, L, d) with ``valid``, or (N, d)),
+        ``states`` this pass's: -> (x, new states, {layer: chosen}, {layer:
+        counts})."""
         new_states, chosen, counts = [], {}, {}
         for layer, state in zip(layers, states):
             x, state, routed = layer(x, state, valid)
@@ -328,8 +411,7 @@ class HybridNet(nn.Module):
                 chosen[layer.name], counts[layer.name] = routed
         return x, tuple(new_states), chosen, counts
 
-    def _heads(self, x):
-        h = _rms(x, self.param("norm_f", nn.initializers.ones, (self.d_model,)), self.norm_eps)
+    def _heads(self, h):
         out: Dict[str, Any] = {
             "policy": nn.Dense(self.num_actions, name="policy")(h),
             "value": jnp.tanh(nn.Dense(1, name="value")(h)),
@@ -344,6 +426,10 @@ class HybridNet(nn.Module):
                  packed_order=None):
         if any(kind not in KINDS for kind in self.pattern):
             raise ValueError(f"pattern {self.pattern!r}: a layer is one of {KINDS!r}")
+        if self.loops > 1 and "E" in self.pattern:
+            # the choices are keyed by layer, and no reference takes a pass's
+            raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
+                             "a routed layer is run once")
         def encode(flat):
             # the trunk computes in its parameters' dtype (bf16 under
             # compute_dtype: bfloat16): _flatten_obs hands float32 over
@@ -352,17 +438,92 @@ class HybridNet(nn.Module):
             x = x.astype(enc1.variables["params"]["kernel"].dtype)
             return nn.Dense(self.d_model, name="enc2")(x)
 
+        def layer(cls, kind, **where):
+            return cls(self._mixer(kind), self.norm_eps, self.sandwich, self.out_scale_init,
+                       **where)
+
+        # one module a layer, applied once a pass: its parameters exist once
         layers = lambda cls: [  # noqa: E731
-            cls(self._mixer(kind), self.norm_eps, name=f"layer{i}")
-            for i, kind in enumerate(self.pattern)]
+            layer(cls, kind, name=f"layer{i}") for i, kind in enumerate(self.pattern)]
+        norm_f = self.param("norm_f", nn.initializers.ones, (self.d_model,))
+        gate = nn.Dense(1, name="exit_gate") if self.loops > 1 else None
+
+        def close(x):       # the one final norm: ends every pass
+            with jax.named_scope(NORM_SCOPE):
+                return _rms(x, norm_f, self.norm_eps)
+
+        def passes(stack, x, states, valid):
+            """The stack ``loops`` times over ``x``, application (t, i) on
+            ``states[t * len(pattern) + i]``, unrolled: step mode, ``init``
+            (``scanned`` needs the parameters to exist), and a window without
+            a loop, whose program stays what it was before there were loops.
+            ``close`` ends every pass but the last, which the caller closes
+            (over the window's steps, where that program has it): -> (x, new
+            states, chosen, counts, stay), ``stay`` the share of each token
+            no gate before the last pass let go (``exit_T``; None without a
+            loop)."""
+            depth, new_states = len(self.pattern), ()
+            stay = None if gate is None else jnp.ones(x.shape[:-1], jnp.float32)
+            for t in range(self.loops):
+                x, new, chosen, counts = self._through(
+                    stack, x, states[t * depth:(t + 1) * depth], valid)
+                new_states += new
+                if t < self.loops - 1:
+                    x = close(x)
+                    stay = stay * (1.0 - jax.nn.sigmoid(gate(x)[..., 0].astype(jnp.float32)))
+            return x, new_states, chosen, counts, stay
+
+        def scanned(x, states, valid):
+            """A looped window's passes as a ``lax.scan`` over the pass index,
+            each one ``stack; close; gate``, the last closed too (over the
+            packed slots: the caller does not close again): the stack is in
+            the program once, not ``loops`` times (a quarter of the compile
+            and of the executable at four passes), its parameters closed
+            over, application (t, i)'s state row ``t`` of layer ``i``'s
+            stacked states, the checkpoints stacked likewise.  Each layer is
+            applied as a function of its parameters (a bound module cannot
+            be called under a jax transform), so they must exist: ``init``
+            goes through ``passes``."""
+            params, depth = self.variables["params"], len(self.pattern)
+
+            def application(kind):
+                free = layer(Layer, kind, parent=None)
+                fn = lambda p, x, state: free.apply({"params": p}, x, state, valid)[:2]  # noqa: E731
+                return fn if remat == "none" else jax.checkpoint(fn)
+
+            stack = [application(kind) for kind in self.pattern]
+            exit_gate = nn.Dense(1, parent=None)
+
+            def one_pass(carry, this):
+                (x, stay), (t, states_t) = carry, this
+                new = []
+                for i, apply in enumerate(stack):
+                    x, state = apply(params[f"layer{i}"], x, states_t[i])
+                    new.append(state)
+                x = close(x)
+                go = jax.nn.sigmoid(exit_gate.apply(
+                    {"params": params["exit_gate"]}, x)[..., 0].astype(jnp.float32))
+                # the last pass keeps what reaches it: its gate lets nothing go
+                stay = stay * jnp.where(t == self.loops - 1, 1.0, 1.0 - go)
+                return (x, stay), tuple(new)
+
+            by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[i::depth])
+                             for i in range(depth))
+            (x, stay), new = jax.lax.scan(
+                one_pass, (x, jnp.ones(x.shape[:-1], jnp.float32)),
+                (jnp.arange(self.loops), by_layer))
+            new_states = tuple(jax.tree.map(lambda rows: rows[t], new[i])
+                               for t in range(self.loops) for i in range(depth))
+            return x, new_states, {}, {}, stay
+
         if not seq:
             if hidden is None:
                 hidden = self.initial_state((jax.tree.leaves(obs)[0].shape[0],))
             states = tuple(
                 dict(state, pos=hidden["pos"]) if kind == "*" else state
-                for kind, state in zip(self.pattern, hidden["layers"]))
-            x, states, _, _ = self._through(layers(Layer), encode(_flatten_obs(obs)), states, None)
-            out = self._heads(x)
+                for kind, state in zip(self.pattern * self.loops, hidden["layers"]))
+            x, states, _, _, _ = passes(layers(Layer), encode(_flatten_obs(obs)), states, None)
+            out = self._heads(close(x))
             out["hidden"] = {"layers": states, "pos": hidden["pos"] + 1.0}
             return out
 
@@ -374,10 +535,12 @@ class HybridNet(nn.Module):
         if key_mask is None:
             key_mask = jnp.ones((n, T), x.dtype)
         states = self._window_state(n, x.dtype)
-        # one checkpoint per layer where asked: only a layer's input is kept
+        # one checkpoint per layer application where asked: only its input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
+        loop = gate is not None and not self.is_initializing()
         outs, chosen, counts = [], [], []
         slots = dropped = 0
+        stayed = 0.0
         for part, lo, hi in (("burn_in", 0, burn_in), ("forward", burn_in, T)):
             if lo == hi:
                 continue
@@ -386,14 +549,18 @@ class HybridNet(nn.Module):
             slots, dropped = slots + valid.size, dropped + lost
             place = place.astype(x.dtype)
             packed = jnp.einsum("nit,ntd->nid", place, x[:, lo:hi], precision=_EXACT)
-            y, states, picked, count = self._through(stack, packed, states, valid)
+            y, states, picked, count, stay = (
+                scanned(packed, states, valid) if loop else passes(stack, packed, states, valid))
             if hi == burn_in:   # scan parity: no gradient through what burn-in leaves
                 states = jax.lax.stop_gradient(states)
             outs.append(jnp.einsum("nit,nid->ntd", place, y, precision=_EXACT))
             spread = place.astype(jnp.int32)
             chosen.append({k: jnp.einsum("nit,nik->ntk", spread, v) for k, v in picked.items()})
             counts.append(count)
-        out = self._heads(jnp.concatenate(outs, axis=1))
+            if stay is not None:
+                stayed = stayed + jnp.where(valid, stay, 0.0).sum()
+        y = jnp.concatenate(outs, axis=1)
+        out = self._heads(y if loop else close(y))
         # slots the mixers ran over, those of them that hold a token, and the
         # tokens no slot held (a handed packed_order that is too short)
         out["counters"] = {
@@ -401,6 +568,14 @@ class HybridNet(nn.Module):
             "observed_steps": (key_mask > 0).sum().astype(jnp.float32),
             "packed_dropped": jnp.asarray(dropped, jnp.float32),
         }
+        if self.loops > 1:
+            # mixer applications a forward, and the mean over the packed
+            # tokens of the share no gate let go before the last pass
+            out["counters"].update(
+                layer_applications=jnp.float32(self.loops * len(self.pattern)),
+                exit_mass_last=stayed / jnp.maximum(
+                    out["counters"]["observed_steps"] - out["counters"]["packed_dropped"], 1.0),
+            )
         if chosen[0]:
             out["choices"] = {k: jnp.concatenate([c[k] for c in chosen], axis=1)
                               for k in chosen[0]}
@@ -420,9 +595,10 @@ class HybridNet(nn.Module):
 
     @nn.nowrap
     def _window_state(self, n: int, dtype):
-        """What each mixer carries into a window that nothing precedes."""
+        """What each mixer application carries into a window that nothing
+        precedes."""
         states = []
-        for kind, state in zip(self.pattern, self.initial_state((n,))["layers"]):
+        for kind, state in zip(self.pattern * self.loops, self.initial_state((n,))["layers"]):
             if kind == "*":
                 empty = jnp.zeros((n, 0, self.n_kv_heads, self.head_dim), dtype)
                 state = {"k": empty, "v": empty, "n": jnp.zeros((n,), jnp.int32)}
@@ -436,7 +612,7 @@ class HybridNet(nn.Module):
         conv_dim = inner + 2 * self.n_groups * self.state_size
         zeros = lambda *shape: jnp.zeros(bd + shape, jnp.float32)  # noqa: E731
         layers = []
-        for kind in self.pattern:
+        for kind in self.pattern * self.loops:      # one state a (pass, layer)
             if kind == "M":
                 layers.append({
                     "ssm": zeros(self.mamba_heads, self.mamba_head_dim, self.state_size),
@@ -454,20 +630,26 @@ class HybridNet(nn.Module):
     @nn.nowrap
     def layout(self) -> Dict[str, Any]:
         """What ``TrainContext`` records when it builds this net: the pattern,
-        the experts held of how many, and the trunk's parameters by kind."""
+        the passes over it and the mixer applications they make, the experts
+        held of how many, and the trunk's parameters by kind."""
         d = self.d_model
         inner = self.mamba_heads * self.mamba_head_dim
         conv_dim = inner + 2 * self.n_groups * self.state_size
+        norms = 2 * d if self.sandwich else d
         each = {
-            "M": d + d * (inner + conv_dim + self.mamba_heads) + (self.conv_kernel + 1) * conv_dim
-            + 3 * self.mamba_heads + inner + inner * d,
-            "*": d + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
-            "E": d + d * self.n_experts + self.n_experts + 2 * d * self.shared_width
+            "M": norms + d * (inner + conv_dim + self.mamba_heads)
+            + (self.conv_kernel + 1) * conv_dim + 3 * self.mamba_heads + inner + inner * d,
+            "*": norms + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
+            "E": norms + d * self.n_experts + self.n_experts + 2 * d * self.shared_width
             + 2 * self.experts_held * d * self.expert_width,
+            "-": norms + 3 * d * self.mlp_width,
         }
         return {
-            "pattern": self.pattern, "experts_held": self.experts_held,
+            "pattern": self.pattern, "loops": self.loops,
+            "applications": self.loops * len(self.pattern),
+            "experts_held": self.experts_held,
             "experts": self.n_experts, "expert_offset": self.expert_offset,
             **{f"params_{name}": self.pattern.count(kind) * each[kind]
-               for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"))},
+               for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"),
+                                  ("-", "mlp"))},
         }
