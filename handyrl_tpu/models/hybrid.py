@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 import numpy as np
 
+from ..ops import attention_core
 from ..ops.routed_experts import choose, held_mix
 from ..ops.ssd import ssd_chunked, ssd_step
 from .transformer import NEG_INF, _flatten_obs
@@ -255,9 +256,17 @@ class GroupedQueryAttention(nn.Module):
         if step:
             h = h[:, None]
         n, length = h.shape[:2]
-        q = _dense(Hq * D, "q")(h).reshape(n, length, Hk, Hq // Hk, D)
-        k = _dense(Hk * D, "k")(h).reshape(n, length, Hk, D)
-        v = _dense(Hk * D, "v")(h).reshape(n, length, Hk, D)
+        # (N, L, heads x D): head h is columns h x D .. (h + 1) x D
+        q, k, v = _dense(Hq * D, "q")(h), _dense(Hk * D, "k")(h), _dense(Hk * D, "v")(h)
+        # a window part whose rows the kernel holds whole runs rotation, scores,
+        # mask, softmax and mix there, on the projections' own layout: chosen
+        # from dtype and shape alone
+        if not step and attention_core.fits(q.dtype, length, state["k"].shape[1], Hq, Hk, D):
+            with jax.named_scope(GQA_SCOPE):
+                out, new_state = self._whole_rows(q, k, v, state, valid)
+            return _dense(self.d_model, "o")(out), new_state
+        q = q.reshape(n, length, Hk, Hq // Hk, D)
+        k, v = k.reshape(n, length, Hk, D), v.reshape(n, length, Hk, D)
         if self.rope_theta:
             with jax.named_scope(ROPE_SCOPE):
                 at = state["pos"][:, None] if step else (
@@ -295,6 +304,20 @@ class GroupedQueryAttention(nn.Module):
             out = jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype))
         out = _dense(self.d_model, "o")(out.reshape(n, length, Hq * D))
         return (out[:, 0] if step else out), new_state
+
+    def _whole_rows(self, q, k, v, state, valid):
+        """A window part through ``ops/attention_core.py``'s kernel, q, k and
+        v as the projections wrote them: -> (out (N, L, Hq x D), new state),
+        the state's keys rotated as the lines above keep them."""
+        (n, length), (past, Hk, D) = q.shape[:2], state["k"].shape[1:]
+        before, count = state["n"].astype(jnp.int32), valid.sum(axis=1).astype(jnp.int32)
+        past_k, past_v = state["k"].astype(k.dtype), state["v"].astype(v.dtype)
+        out, keys = attention_core.attention_core(
+            q, k, v, past_k.reshape(n, past, Hk * D), past_v.reshape(n, past, Hk * D),
+            before, count, (self.heads // Hk, D, self.memory_len, self.rope_theta))
+        return out, {"k": jnp.concatenate([past_k, keys.reshape(n, length, Hk, D)], axis=1),
+                     "v": jnp.concatenate([past_v, v.reshape(n, length, Hk, D)], axis=1),
+                     "n": before + count}
 
 
 class GatedMLP(nn.Module):

@@ -43,10 +43,10 @@ import optax
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..ops import compute_loss_from_outputs
+from ..ops import attention_core, compute_loss_from_outputs
 from ..utils import tree_map
 from ..utils.compile_cache import scoped_program_options
-from ..utils.trace import trace_event
+from ..utils.trace import enabled as trace_enabled, trace_event
 from .mesh import (
     batch_sharding,
     dispatch_serialized,
@@ -542,6 +542,8 @@ class TrainContext:
         # program and does not flip between two
         self._packs = takes_packed_order(module, self.args)
         self._packed_bounds: Dict[str, int] = {}
+        # the attention paths (attention_core.PATHS) already written out
+        self._attention_paths: set = set()
 
         loss_keys = ("p", "v", "r", "ent", "total")
 
@@ -827,9 +829,23 @@ class TrainContext:
         # rollout) must reach every device in one order — see
         # mesh.dispatch_serialized
         fn = self._bind(state)
-        return dispatch_serialized(
+        out = dispatch_serialized(
             lambda: fn(state, device_batch, jnp.float32(lr)), self.mesh
         )
+        self._record_attention_paths()
+        return out
+
+    def _record_attention_paths(self):
+        """One ``model.attention_path`` event for each static choice between
+        the whole-row attention kernel and the einsum lines that a trace of
+        the step has made (``ops/attention_core.py`` ``fits``: per window
+        part's operands) and no event of this context has said yet, once a
+        tracer is on to take it."""
+        if len(self._attention_paths) == len(attention_core.PATHS) or not trace_enabled():
+            return
+        for key in set(attention_core.PATHS) - self._attention_paths:
+            trace_event("model.attention_path", 0.0, plane="learner", **attention_core.PATHS[key])
+            self._attention_paths.add(key)
 
     def put_batches(self, host_batches):
         """Stack k host batches -> one (k, B, ...) device tree, B sharded
@@ -870,10 +886,12 @@ class TrainContext:
                 out_shardings=(ss, self._replicated),
                 compiler_options=scoped_program_options(UPDATE_SCOPE),
             )
-        return dispatch_serialized(
+        out = dispatch_serialized(
             lambda: self._train_steps(state, stacked_device_batch, jnp.float32(lr)),
             self.mesh,
         )
+        self._record_attention_paths()
+        return out
 
     def flops_per_step(self, state, device_batch) -> float:
         """Flops of one update (for MFU accounting): HLO cost analysis of

@@ -1,7 +1,8 @@
 """Both Pallas attention kernels, the routed experts' grouped products at
-nemotron_twotower_train_t192's widths, and the d1536 train step on a {dp: 4}
-mesh, compiled for a described (not attached) TPU v5e by the chip's own
-compiler, at the shapes chip_smoke.py and the benchmark's cells pin.
+nemotron_twotower_train_t192's widths, a window part's attention core at both
+HybridNet cells' widths, and the d1536 train step on a {dp: 4} mesh, compiled
+for a described (not attached) TPU v5e by the chip's own compiler, at the
+shapes chip_smoke.py and the benchmark's cells pin.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (a slice
 not aligned to the tiling, too much VMEM); these compiles can, at about
@@ -154,6 +155,58 @@ def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypa
     for copied in ("[%d,%d,%d]" % (blocks, d, width), "[%d,%d,%d]" % (blocks, width, d)):
         assert copied not in text
     assert "precision_config" not in text or "HIGHEST" not in text
+
+
+# -- a window part's attention core (ops/attention_core.py) ------------------
+
+# (d_model, query heads, KV heads) of the two HybridNet cells, heads of 128:
+# ouro_2_6b and nemotron_twotower_30b_a3b as benchmark/configs/ has them
+_ATTENTION = {"ouro": (2048, 16, 16, 1e6), "nemotron": (2688, 32, 2, 0.0)}
+
+
+@pytest.mark.parametrize("part,length,past", [("packed", 96, 8), ("unpacked", 184, 8)])
+@pytest.mark.parametrize("cell", sorted(_ATTENTION))
+def test_attention_core_compiles_for_v5e_where_the_projections_wrote(v5e, monkeypatch, cell, part,
+                                                                      length, past):
+    """``GroupedQueryAttention``'s window mode and its gradient at the two
+    cells' widths and forward parts (64 rows of 96 packed steps behind 8
+    burn-in steps, as the step runs them, and the 184 steps of the unpacked
+    window ``judge_forward`` runs; the 8 burn-in steps themselves are under
+    ``ROWS_MIN`` and keep the einsum lines): the forward and the backward
+    kernel are in the program, each under the ``gqa`` scope the benchmark times them
+    under, and no whole-array ``copy`` of a (64, steps, ...) operand stands
+    before or behind them: the kernel reads q, k and v where the
+    projections wrote them and writes where ``o`` reads."""
+    from benchmark import trace_reduce
+    from handyrl_tpu.models.hybrid import GQA_SCOPE, GroupedQueryAttention
+
+    d_model, heads, kv_heads, theta = _ATTENTION[cell]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    module = GroupedQueryAttention(d_model, heads, kv_heads, 128, 200, theta)
+    aval = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+    h, valid = aval((64, length, d_model)), aval((64, length), jnp.bool_)
+    state = {"k": aval((64, past, kv_heads, 128)), "v": aval((64, past, kv_heads, 128)),
+             "n": aval((64,), jnp.int32)}
+    params = jax.tree.map(
+        lambda x: aval(x.shape),
+        jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros(h.shape, h.dtype), {
+            "k": jnp.zeros(state["k"].shape, h.dtype), "v": jnp.zeros(state["v"].shape, h.dtype),
+            "n": jnp.zeros((64,), jnp.int32)}, jnp.ones(valid.shape, bool))))
+
+    def loss(params, h, past_k, past_v, n, valid):
+        out, new = module.apply(params, h, {"k": past_k, "v": past_v, "n": n}, valid)
+        return ((out.astype(jnp.float32) ** 2).sum() + (new["k"].astype(jnp.float32) ** 2).sum())
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        params, h, state["k"], state["v"], state["n"], valid).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2, len(calls)
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert all(trace_reduce.scopes_of(n, [GQA_SCOPE]) == [GQA_SCOPE] for n in names), names
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= bf16\[64,(%d|%d)," % (length, length + past), line) and " copy(" in line]
+    assert not copies, copies
 
 
 # -- the d1536 train step on a described {dp: N} mesh ----------------------

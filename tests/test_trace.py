@@ -238,6 +238,34 @@ def test_export_cli_writes_chrome_trace(tmp_path):
     assert any(e["name"] == "cli_span" for e in data["traceEvents"])
 
 
+def test_scope_split_sums_a_recorded_profile_by_scope_and_op(tmp_path):
+    """scripts/scope_split.py on the recorded bf16 capture: every op's self
+    time lands under the one scope given or under ``outside``, by op kind,
+    in milliseconds a run; the rows fall by cost."""
+    import subprocess
+
+    capture = os.path.join(
+        os.path.dirname(SCRIPTS), "docs", "captures", "bf16_profile_2026-08-01_0854", "bf16",
+        "plugins", "profile", "2026_08_01_08_56_05", "vm.xplane.pb")
+    out_path = tmp_path / "split.json"
+    proc = subprocess.run(
+        [sys.executable, os.path.join(SCRIPTS, "scope_split.py"), capture, "closed_call",
+         "-o", str(out_path)],
+        capture_output=True, text=True, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert "== closed_call: " in proc.stdout and "== outside: " in proc.stdout
+    split = json.loads(out_path.read_text())
+    inside, outside = (sum(ms for ms, _ in split["ms_per_run"][scope].values())
+                       for scope in ("closed_call", "outside"))
+    assert inside > outside > 0          # the scanned update's body holds most of the time
+    rows = split["rows"]
+    assert abs(sum(row[0] for row in rows) - inside - outside) < 1e-6
+    assert [row[0] for row in rows] == sorted((row[0] for row in rows), reverse=True)
+    assert {row[3] for row in rows} <= {"fwd", "bwd"}
+    assert any(row[4].startswith("fusion:") for row in rows) and any(row[4] == "copy" for row in rows)
+
+
 # -- the zero-overhead pin (acceptance) ---------------------------------------
 
 
