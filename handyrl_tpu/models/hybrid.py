@@ -11,7 +11,16 @@ and a gate on each pass's output gives the share of a token that would leave
 the loop there (``exit_t``; nothing reads it but a counter: the heads read
 the last pass).  ``loops`` 1, no ``sandwich`` and no ``rope_theta`` is the
 ``nemotron_h`` family's tower; ``"*-"`` layers with all three are a looped
-dense transformer.  One token is one player's observation at one env step:
+dense transformer; a mixer and an ``E`` sub-layer in every layer
+(``"MEME*E..."``) with ``residual_scale`` on every branch, ``embed_scale`` on
+the encoder's output, ``logits_divisor`` under the policy logits,
+``attn_score_scale`` on the attention scores, a ``"softmax"`` ``router`` over
+the chosen logits and ``gated_experts`` is the ``granitemoehybrid`` family's
+period.  Every parameter is made and held in ``param_dtype`` (the
+initialisers draw in it: a bfloat16 acting copy of a net too large for a
+float32 tree is never preceded by one) and compute follows the parameters;
+the state, decays, norms, router logits and softmaxes stay float32.  One
+token is one player's observation at one env step:
 the flattened observation through ``enc1``/``enc2`` stands where a language
 model has its embedding, the policy/value/return heads where it has its
 LM head (the same encoder and heads as ``TransformerNet``).
@@ -51,7 +60,11 @@ the observed steps, those the packing left out, with ``E`` layers the
 rows the held experts computed, the slots of the row buffers they were
 computed in and the passes past the first those took, and with ``loops``
 over 1 the layer applications of a forward and the mean ``exit`` share the
-last pass is left with); ``forward_prediction`` hands both on.
+last pass is left with); ``forward_prediction`` hands both on.  Step mode
+returns the heads and the hidden alone (its callers iterate over them): an
+``E`` layer *sows* its rows, its buffer's slots and its choices into the
+``counters`` and ``choices`` collections, which a caller that wants them makes
+mutable (``runtime/device_rollout.py`` ``build_streaming_fn(counters=True)``).
 """
 
 from __future__ import annotations
@@ -78,8 +91,14 @@ ATTN_SCOPE, ROPE_SCOPE, GQA_SCOPE, MLP_SCOPE, NORM_SCOPE = "attn", "rope", "gqa"
 _EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
 
 
-def _dense(features: int, name: str):
-    return nn.Dense(features, use_bias=False, name=name)
+def _dense(features: int, name: str, param_dtype=jnp.float32):
+    return nn.Dense(features, use_bias=False, name=name, param_dtype=param_dtype)
+
+
+def _gated(a_b):
+    """``silu(a) * b`` over the two halves of one fused product's output."""
+    a, b = jnp.split(a_b, 2, axis=-1)
+    return jax.nn.silu(a) * b
 
 
 def _rms(x, scale, eps: float, groups: int = 1):
@@ -133,6 +152,7 @@ class Mamba2Mixer(nn.Module):
     dt_min: float
     dt_max: float
     dt_floor: float
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, u, state, valid=None):
@@ -145,23 +165,25 @@ class Mamba2Mixer(nn.Module):
             u = u[:, None]
         n, length = u.shape[:2]
 
-        def dt_bias_init(key, shape):
+        def dt_bias_init(key, shape, dtype):
             # the inverse softplus of a log-uniform draw in [dt_min, dt_max]
             dt = jnp.exp(jax.random.uniform(key, shape)
                          * (np.log(self.dt_max) - np.log(self.dt_min)) + np.log(self.dt_min))
             dt = jnp.maximum(dt, self.dt_floor)
-            return dt + jnp.log(-jnp.expm1(-dt))
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
-        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (K, conv_dim))
-        conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
-        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
-        a_log = self.param(
-            "A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
-            (H,))
-        skip = self.param("D", nn.initializers.ones, (H,))
-        norm_scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        def a_log_init(key, shape, dtype):
+            return jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0)).astype(dtype)
 
-        z, xbc, dt = jnp.split(_dense(inner + conv_dim + H, "in_proj")(u),
+        kept = self.param_dtype
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (K, conv_dim), kept)
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), kept)
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,), kept)
+        a_log = self.param("A_log", a_log_init, (H,), kept)
+        skip = self.param("D", nn.initializers.ones, (H,), kept)
+        norm_scale = self.param("norm_scale", nn.initializers.ones, (inner,), kept)
+
+        z, xbc, dt = jnp.split(_dense(inner + conv_dim + H, "in_proj", kept)(u),
                                [inner, inner + conv_dim], axis=-1)
         # causal depthwise conv over the last K - 1 inputs and this one
         tail = state["conv"].astype(xbc.dtype)
@@ -189,7 +211,7 @@ class Mamba2Mixer(nn.Module):
         y = y.astype(jnp.float32) + skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
         y = y.reshape(n, length, inner) * jax.nn.silu(z.astype(jnp.float32))
         y = _rms(y, norm_scale, self.eps, groups=G).astype(u.dtype)
-        out = _dense(self.d_model, "out_proj")(y)
+        out = _dense(self.d_model, "out_proj", kept)(y)
         return (out[:, 0] if step else out), {"ssm": ssm, "conv": new_tail.astype(jnp.float32)}
 
 
@@ -202,31 +224,52 @@ class ExpertLayer(nn.Module):
     routed_scale: float
     experts_held: int
     expert_offset: int
+    router: str = "sigmoid"     # or "softmax": over the chosen logits, no bias
+    gated: bool = False         # experts and shared expert ``silu(a) * b``, not ``relu^2``
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, h, valid=None):
         """h (..., d) tokens, valid (...) -> (out, chosen (..., k) int32,
         counts: ``held_mix``'s, the rows the held experts computed and the
-        row buffer's passes and slots)."""
+        row buffer's passes and slots).  Applied with a mutable ``counters``
+        or ``choices`` collection (the acting path's callers that want
+        them: step mode returns the heads alone) it also sows the rows held,
+        the buffer's slots and the chosen there."""
         lead, d = h.shape[:-1], h.shape[-1]
         tokens = h.reshape(-1, d)
         ok = jnp.ones(tokens.shape[:1], bool) if valid is None else valid.reshape(-1)
         fan_in = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
                                                   batch_axis=(0,))
-        router = self.param("router", nn.initializers.lecun_normal(), (d, self.n_experts))
-        # chooses only; no gradient reaches it (top-k's indices carry none)
-        bias = self.param("score_bias", nn.initializers.zeros, (self.n_experts,))
-        w1 = self.param("w1", fan_in, (self.experts_held, d, self.expert_width))
-        w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d))
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router {self.router!r}: 'sigmoid' or 'softmax'")
+        kept, fused = self.param_dtype, 2 if self.gated else 1   # a gated input matrix holds a and b
+        router = self.param("router", nn.initializers.lecun_normal(), (d, self.n_experts), kept)
+        if self.router == "sigmoid":
+            # chooses only; no gradient reaches it (top-k's indices carry none)
+            bias = self.param("score_bias", nn.initializers.zeros, (self.n_experts,), kept)
+        w1 = self.param("w1", fan_in, (self.experts_held, d, fused * self.expert_width), kept)
+        w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d), kept)
         with jax.named_scope("route"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT))
-            chosen, gates = choose(scores, bias, self.top_k, self.routed_scale)
+            logits = jnp.dot(
+                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT)
+            if self.router == "sigmoid":
+                chosen, gates = choose(jax.nn.sigmoid(logits), bias, self.top_k, self.routed_scale)
+            else:   # the chosen's softmax: renormalising over them cancels the rest
+                chosen, gates = choose(jax.nn.softmax(logits, axis=-1),
+                                       jnp.zeros((self.n_experts,), jnp.float32), self.top_k,
+                                       self.routed_scale)
         routed, counts = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype),
-                                  w2.astype(h.dtype), self.expert_offset, self.n_experts)
+                                  w2.astype(h.dtype), self.expert_offset, self.n_experts,
+                                  self.gated)
         with jax.named_scope("shared_expert"):
-            up = _dense(self.shared_width, "shared_up")(tokens)
-            shared = _dense(d, "shared_down")(jnp.square(nn.relu(up)))
+            up = _dense(fused * self.shared_width, "shared_up", kept)(tokens)
+            shared = _dense(d, "shared_down", kept)(
+                _gated(up) if self.gated else jnp.square(nn.relu(up)))
+        if not self.is_initializing():  # no-ops but under a caller's ``mutable``
+            self.sow("counters", "rows_held", counts["rows"].sum())
+            self.sow("counters", "buffer_slots", counts["slots"])
+            self.sow("choices", "chosen", chosen.reshape(lead + (self.top_k,)))
         return ((routed + shared).reshape(lead + (d,)),
                 chosen.reshape(lead + (self.top_k,)), counts)
 
@@ -238,6 +281,8 @@ class GroupedQueryAttention(nn.Module):
     head_dim: int
     memory_len: int
     rope_theta: float = 0.0     # 0: no positions (order is left to other mixers)
+    score_scale: float = 0.0    # what scores are multiplied by; 0: 1 / sqrt(head_dim)
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, h, state, valid=None):
@@ -257,14 +302,17 @@ class GroupedQueryAttention(nn.Module):
             h = h[:, None]
         n, length = h.shape[:2]
         # (N, L, heads x D): head h is columns h x D .. (h + 1) x D
-        q, k, v = _dense(Hq * D, "q")(h), _dense(Hk * D, "k")(h), _dense(Hk * D, "v")(h)
+        kept = self.param_dtype
+        q, k, v = (_dense(Hq * D, "q", kept)(h), _dense(Hk * D, "k", kept)(h),
+                   _dense(Hk * D, "v", kept)(h))
         # a window part whose rows the kernel holds whole runs rotation, scores,
         # mask, softmax and mix there, on the projections' own layout: chosen
-        # from dtype and shape alone
-        if not step and attention_core.fits(q.dtype, length, state["k"].shape[1], Hq, Hk, D):
+        # from dtype and shape alone (the kernel scales by 1 / sqrt(head_dim))
+        if not step and not self.score_scale and attention_core.fits(
+                q.dtype, length, state["k"].shape[1], Hq, Hk, D):
             with jax.named_scope(GQA_SCOPE):
                 out, new_state = self._whole_rows(q, k, v, state, valid)
-            return _dense(self.d_model, "o")(out), new_state
+            return _dense(self.d_model, "o", kept)(out), new_state
         q = q.reshape(n, length, Hk, Hq // Hk, D)
         k, v = k.reshape(n, length, Hk, D), v.reshape(n, length, Hk, D)
         if self.rope_theta:
@@ -298,11 +346,12 @@ class GroupedQueryAttention(nn.Module):
                 allowed = key_ok[:, None, :] & (gap >= 0) & (gap < self.memory_len)
                 new_state = {"k": keys, "v": values, "n": before + valid.sum(axis=1)}
             scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, keys.astype(q.dtype),
-                                preferred_element_type=jnp.float32) / (D ** 0.5)
+                                preferred_element_type=jnp.float32)
+            scores = scores * self.score_scale if self.score_scale else scores / (D ** 0.5)
             scores = jnp.where(allowed[:, None, None], scores, NEG_INF)
             weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
             out = jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype))
-        out = _dense(self.d_model, "o")(out.reshape(n, length, Hq * D))
+        out = _dense(self.d_model, "o", kept)(out.reshape(n, length, Hq * D))
         return (out[:, 0] if step else out), new_state
 
     def _whole_rows(self, q, k, v, state, valid):
@@ -325,28 +374,35 @@ class GatedMLP(nn.Module):
 
     d_model: int
     width: int
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, h, state, valid=None):
+        kept = self.param_dtype
         with jax.named_scope(MLP_SCOPE):
-            gated = jax.nn.silu(_dense(self.width, "gate")(h)) * _dense(self.width, "up")(h)
-            return _dense(self.d_model, "down")(gated), state
+            gated = (jax.nn.silu(_dense(self.width, "gate", kept)(h))
+                     * _dense(self.width, "up", kept)(h))
+            return _dense(self.d_model, "down", kept)(gated), state
 
 
 class Layer(nn.Module):
     """``x + mixer(RMSNorm(x))``, with ``sandwich`` ``x + RMSNorm(mixer(
-    RMSNorm(x)))``; a mixer that keeps no state hands the state it was given
-    back, one that routes says what it chose."""
+    RMSNorm(x)))``, the mixer's branch times ``residual_scale``; a mixer
+    that keeps no state hands the state it was given back, one that routes
+    says what it chose."""
 
     mixer: nn.Module
     eps: float
     sandwich: bool = False
     out_scale_init: float = 1.0     # what the second norm's scale starts at
+    residual_scale: float = 1.0
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, state, valid):
         with jax.named_scope(NORM_SCOPE):
-            h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],)), self.eps)
+            h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],),
+                                   self.param_dtype), self.eps)
         routed = None
         if isinstance(self.mixer, ExpertLayer):
             y, chosen, counts = self.mixer(h, valid)
@@ -356,7 +412,9 @@ class Layer(nn.Module):
         if self.sandwich:
             with jax.named_scope(NORM_SCOPE):
                 y = _rms(y, self.param("norm_out", nn.initializers.constant(self.out_scale_init),
-                                       (x.shape[-1],)), self.eps)
+                                       (x.shape[-1],), self.param_dtype), self.eps)
+        if self.residual_scale != 1.0:
+            y = (self.residual_scale * y).astype(x.dtype)
         return x + y, state, routed
 
 
@@ -403,23 +461,39 @@ class HybridNet(nn.Module):
     sandwich: bool = False
     out_scale_init: float = 1.0
     loops: int = 1
+    # multipliers a published config may carry: on each mixer's branch, on the
+    # encoder's output, under the policy logits (a divisor), on the attention
+    # scores (0: 1 / sqrt(head_dim))
+    residual_scale: float = 1.0
+    embed_scale: float = 1.0
+    logits_divisor: float = 1.0
+    attn_score_scale: float = 0.0
+    # E: "sigmoid" scores with a choosing bias, or "softmax" over the chosen
+    # logits; experts and shared expert gated (``silu(a) * b``) or ``relu^2``
+    router: str = "sigmoid"
+    gated_experts: bool = False
+    # what every parameter is made and held in ("bfloat16": an acting copy
+    # that no float32 tree precedes); compute follows the parameters
+    param_dtype: str = "float32"
 
     def _mixer(self, kind: str):
         # parentless: the Layer it is handed to adopts it, as ``mixer``
+        kept = jnp.dtype(self.param_dtype)
         if kind == "M":
             return Mamba2Mixer(
                 self.d_model, self.mamba_heads, self.mamba_head_dim, self.n_groups,
                 self.state_size, self.conv_kernel, self.chunk, self.norm_eps,
-                self.dt_min, self.dt_max, self.dt_floor, parent=None)
+                self.dt_min, self.dt_max, self.dt_floor, kept, parent=None)
         if kind == "E":
             return ExpertLayer(
                 self.d_model, self.n_experts, self.top_k, self.expert_width, self.shared_width,
-                self.routed_scale, self.experts_held, self.expert_offset, parent=None)
+                self.routed_scale, self.experts_held, self.expert_offset, self.router,
+                self.gated_experts, kept, parent=None)
         if kind == "-":
-            return GatedMLP(self.d_model, self.mlp_width, parent=None)
+            return GatedMLP(self.d_model, self.mlp_width, kept, parent=None)
         return GroupedQueryAttention(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
-            self.rope_theta, parent=None)
+            self.rope_theta, self.attn_score_scale, kept, parent=None)
 
     @staticmethod
     def _through(layers, x, states, valid):
@@ -435,12 +509,14 @@ class HybridNet(nn.Module):
         return x, tuple(new_states), chosen, counts
 
     def _heads(self, h):
+        kept = jnp.dtype(self.param_dtype)
+        policy = nn.Dense(self.num_actions, name="policy", param_dtype=kept)(h)
         out: Dict[str, Any] = {
-            "policy": nn.Dense(self.num_actions, name="policy")(h),
-            "value": jnp.tanh(nn.Dense(1, name="value")(h)),
+            "policy": policy if self.logits_divisor == 1.0 else policy / self.logits_divisor,
+            "value": jnp.tanh(nn.Dense(1, name="value", param_dtype=kept)(h)),
         }
         if self.with_return:
-            out["return"] = nn.Dense(1, name="return_head")(h)
+            out["return"] = nn.Dense(1, name="return_head", param_dtype=kept)(h)
         return out
 
     @nn.compact
@@ -453,23 +529,26 @@ class HybridNet(nn.Module):
             # the choices are keyed by layer, and no reference takes a pass's
             raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
                              "a routed layer is run once")
+        kept = jnp.dtype(self.param_dtype)
+
         def encode(flat):
             # the trunk computes in its parameters' dtype (bf16 under
             # compute_dtype: bfloat16): _flatten_obs hands float32 over
-            enc1 = nn.Dense(self.d_model, name="enc1")
+            enc1 = nn.Dense(self.d_model, name="enc1", param_dtype=kept)
             x = nn.relu(enc1(flat))
             x = x.astype(enc1.variables["params"]["kernel"].dtype)
-            return nn.Dense(self.d_model, name="enc2")(x)
+            x = nn.Dense(self.d_model, name="enc2", param_dtype=kept)(x)
+            return x if self.embed_scale == 1.0 else x * self.embed_scale
 
         def layer(cls, kind, **where):
             return cls(self._mixer(kind), self.norm_eps, self.sandwich, self.out_scale_init,
-                       **where)
+                       self.residual_scale, kept, **where)
 
         # one module a layer, applied once a pass: its parameters exist once
         layers = lambda cls: [  # noqa: E731
             layer(cls, kind, name=f"layer{i}") for i, kind in enumerate(self.pattern)]
-        norm_f = self.param("norm_f", nn.initializers.ones, (self.d_model,))
-        gate = nn.Dense(1, name="exit_gate") if self.loops > 1 else None
+        norm_f = self.param("norm_f", nn.initializers.ones, (self.d_model,), kept)
+        gate = nn.Dense(1, name="exit_gate", param_dtype=kept) if self.loops > 1 else None
 
         def close(x):       # the one final norm: ends every pass
             with jax.named_scope(NORM_SCOPE):
@@ -663,8 +742,9 @@ class HybridNet(nn.Module):
             "M": norms + d * (inner + conv_dim + self.mamba_heads)
             + (self.conv_kernel + 1) * conv_dim + 3 * self.mamba_heads + inner + inner * d,
             "*": norms + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
-            "E": norms + d * self.n_experts + self.n_experts + 2 * d * self.shared_width
-            + 2 * self.experts_held * d * self.expert_width,
+            "E": norms + d * self.n_experts + (self.n_experts if self.router == "sigmoid" else 0)
+            + (3 if self.gated_experts else 2) * d
+            * (self.shared_width + self.experts_held * self.expert_width),
             "-": norms + 3 * d * self.mlp_width,
         }
         return {
@@ -672,6 +752,8 @@ class HybridNet(nn.Module):
             "applications": self.loops * len(self.pattern),
             "experts_held": self.experts_held,
             "experts": self.n_experts, "expert_offset": self.expert_offset,
+            "residual_scale": self.residual_scale, "router": self.router,
+            "param_dtype": self.param_dtype,
             **{f"params_{name}": self.pattern.count(kind) * each[kind]
                for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"),
                                   ("-", "mlp"))},

@@ -53,7 +53,8 @@ def choose(scores, bias, top_k: int, scale: float):
     """scores (n, E) float32 in (0, 1), bias (E,) -> (chosen (n, k) int32:
     the ``top_k`` largest of ``scores + bias``; gates (n, k) float32:
     ``scale`` x the chosen's own scores over their sum).  The bias chooses
-    only."""
+    only.  A softmax over the chosen logits is this on ``softmax(logits)``
+    with bias 0 and scale 1: the sum over the chosen cancels the rest."""
     _, chosen = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     return chosen.astype(jnp.int32), scale * picked / picked.sum(axis=-1, keepdims=True)
@@ -114,14 +115,15 @@ def row_buffer(n: int, top_k: int, held: int, experts: int) -> Tuple[int, int]:
     return blocks, -(-worst // blocks)
 
 
-def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int):
+def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: bool = False):
     """The held experts' part of the mix.
 
     h (n, d) tokens, chosen (n, k) int32 over all ``experts``, gates (n, k)
     float32, valid (n,) bool (a token that is padding is routed nowhere),
-    w1 (held, d, w), w2 (held, w, d).  Returns (out (n, d) in h's dtype:
-    sum over a token's chosen experts that are held of gate x
-    w2[e] relu(w1[e] h)^2; counts: ``rows`` (held,) int32 the rows each held
+    w1 (held, d, w), w2 (held, w, d); ``gated``: w1 (held, d, 2 w), one
+    fused input matrix whose halves are a and b.  Returns (out (n, d) in h's
+    dtype: sum over a token's chosen experts that are held of gate x
+    w2[e] relu(w1[e] h)^2, ``gated`` w2[e] (silu(a) b); counts: ``rows`` (held,) int32 the rows each held
     expert computed, ``passes`` () int32 the passes over the row buffer that
     took them, ``slots`` () int32 the buffer slots those passes computed)."""
     n, k = chosen.shape
@@ -142,20 +144,26 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int):
              "ends": ends, "base": base}
     blocks = row_buffer(n, k, held, experts)[0]
     passes = _needed(route, blocks)
-    return (_passes(h, gates, w1, w2, route, blocks),
+    return (_passes(h, gates, w1, w2, route, blocks, gated),
             {"rows": rows, "passes": passes, "slots": passes * (blocks * BLOCK)})
 
 
-def _block_products(x, w1, w2, owner):
+def _block_products(x, w1, w2, owner, gated: bool = False):
     """x (m, d) in blocks of ``BLOCK`` rows, block ``b`` of expert
-    ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block."""
+    ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block, ``gated``
+    w2[e] (silu(a) b) with [a, b] = w1[e] x."""
     if x.dtype == jnp.bfloat16:
         product = lambda rows, w: grouped_dot(rows, w, owner)   # noqa: E731
     else:   # at the caller's matmul precision, which a kernel's dots would not see
         def product(rows, w):
             return jnp.einsum("brk,bkn->brn", rows.reshape(owner.size, BLOCK, -1), w[owner],
                               preferred_element_type=jnp.float32).reshape(rows.shape[0], -1)
-    act = jnp.square(jax.nn.relu(product(x, w1))).astype(x.dtype)
+    up = product(x, w1)
+    if gated:
+        a, b = jnp.split(up, 2, axis=-1)
+        act = (jax.nn.silu(a) * b).astype(x.dtype)
+    else:
+        act = jnp.square(jax.nn.relu(up)).astype(x.dtype)
     return product(act, w2)
 
 
@@ -169,7 +177,7 @@ def _owners(ends, start, blocks: int):
     return jnp.minimum(owner, ends.size - 1).astype(jnp.int32)
 
 
-def _one_pass(h, gates, w1, w2, route, start, blocks: int):
+def _one_pass(h, gates, w1, w2, route, start, blocks: int, gated: bool = False):
     """Slots [start, start + blocks x BLOCK) of the row buffer ``route`` lays out."""
     n, k = route["slot"].shape
     m = blocks * BLOCK
@@ -186,7 +194,7 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int):
         x = _dispatch(h, tok, at, in_buffer)
         gate = jnp.where(used, gates.reshape(-1)[pair], 0.0)
     with jax.named_scope(EXPERTS_SCOPE):
-        down = _block_products(x, w1, w2, owner)
+        down = _block_products(x, w1, w2, owner, gated)
     with jax.named_scope("route"):
         # a slot no row fills has gate 0
         weighted = (down * gate[:, None]).astype(h.dtype)
@@ -199,8 +207,8 @@ def _needed(route, blocks: int):
     return jnp.maximum(1, -(-route["ends"][-1] // (blocks * BLOCK)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _passes(h, gates, w1, w2, route, blocks: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _passes(h, gates, w1, w2, route, blocks: int, gated: bool = False):
     """Every pass the rows need, in a loop whose trip count is the update's
     own (``lax.while_loop``, differentiated by hand below): the usual update
     runs one pass and holds one pass's buffers, and the worst case, every
@@ -211,24 +219,26 @@ def _passes(h, gates, w1, w2, route, blocks: int):
     PR 40.)"""
     def one_more(carry):
         done, out = carry
-        return done + 1, out + _one_pass(h, gates, w1, w2, route, done * blocks * BLOCK, blocks)
+        return done + 1, out + _one_pass(
+            h, gates, w1, w2, route, done * blocks * BLOCK, blocks, gated)
 
     return jax.lax.while_loop(
         lambda carry: carry[0] < _needed(route, blocks), one_more,
         (jnp.int32(0), jnp.zeros_like(h)))[1]
 
 
-def _passes_fwd(h, gates, w1, w2, route, blocks):
-    return _passes(h, gates, w1, w2, route, blocks), (h, gates, w1, w2, route)
+def _passes_fwd(h, gates, w1, w2, route, blocks, gated):
+    return _passes(h, gates, w1, w2, route, blocks, gated), (h, gates, w1, w2, route)
 
 
-def _passes_bwd(blocks, saved, d_out):
+def _passes_bwd(blocks, gated, saved, d_out):
     h, gates, w1, w2, route = saved
 
     def one_more(carry):
         done, sums = carry
         _, pull = jax.vjp(
-            lambda *a: _one_pass(*a, route, done * blocks * BLOCK, blocks), h, gates, w1, w2)
+            lambda *a: _one_pass(*a, route, done * blocks * BLOCK, blocks, gated),
+            h, gates, w1, w2)
         return done + 1, jax.tree.map(jnp.add, sums, pull(d_out))
 
     zeros = jax.tree.map(jnp.zeros_like, (h, gates, w1, w2))

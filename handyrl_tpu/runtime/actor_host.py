@@ -17,6 +17,18 @@ gone, and this process announces the fault and exits 75 (EX_TEMPFAIL) so
 a supervisor relaunches it once the learner is back — the params it
 would generate against are unowned until then.
 
+``actor_host_main`` is the process: the tracer, the signals, the exit code.
+``actor_loop`` is the plane itself, over the devices its caller hands it
+(``jax.local_devices()`` there; the benchmark's ``actor_stream`` runner hands
+it one chip and a loopback gateway).  Parameters live on the device in the
+dtype the module makes them in (``param_dtype``: a bfloat16 acting copy is
+never preceded by a float32 tree) and a polled tree is cast to that and put
+there once, when it is installed.  Spans on the loop's thread:
+``actor.dispatch``, ``actor.fetch``, ``actor.ship``, ``actor.poll``; events
+``actor.weights`` (parameters, bytes, dtype, when a tree is made or
+installed) and ``actor.counters`` (what the module's step mode counted over
+a dispatch; docs/observability.md).
+
 Rate coupling is structural: one record batch is in flight per host (the
 ship is a blocking request/reply), so a slow learner back-pressures the
 rollout loop without a budget protocol.
@@ -28,20 +40,87 @@ import signal
 import sys
 import threading
 import time
-from typing import Any, Dict
+from typing import Any, Dict, Sequence
 
 from ..envs import make_env, prepare_env
-from ..models import init_variables
 from ..utils import trace
 from ..utils.retry import retry_call
+from ..utils.trace import trace_event, trace_span
 
 # same convention as the learner's drain path (runtime/learner.py)
 EXIT_RESUMABLE = 75
 
 
+class GatewayLost(ConnectionError):
+    """The plane gateway's socket died under the loop: the learner tier is gone."""
+
+
 def actor_host_main(args: Dict[str, Any]) -> None:
     """Entry point for ``--train`` with ``distributed.role: actor``."""
     import jax
+
+    dist = dict(args["train_args"].get("distributed") or {})
+    rank = int(dist.get("process_id") or 0)
+    if trace.configure(args["train_args"].get("trace"), rank=1000 + rank):
+        print(f"trace: spans -> {trace.current_path()} (actor host {rank})")
+
+    stop = threading.Event()
+
+    def _stop_signal(signum, frame):
+        print(
+            f"[handyrl_tpu] actor host {rank}: signal {signum} — draining",
+            file=sys.stderr,
+        )
+        stop.set()
+
+    signal.signal(signal.SIGTERM, _stop_signal)
+    signal.signal(signal.SIGINT, _stop_signal)
+    try:
+        done = actor_loop(args, jax.local_devices(), stop)
+    except GatewayLost as e:
+        from ..parallel.health import announce_fault
+
+        announce_fault(str(e), "learner_loss", EXIT_RESUMABLE)
+        sys.exit(EXIT_RESUMABLE)
+    print(f"actor host {rank}: finished ({done['dispatches']} dispatches)")
+
+
+def _weights_event(params) -> None:
+    import jax
+
+    leaves = jax.tree.leaves(params)
+    trace_event(
+        "actor.weights", 0.0,
+        parameters=int(sum(x.size for x in leaves)),
+        bytes=int(sum(x.size * x.dtype.itemsize for x in leaves)),
+        dtype=",".join(sorted({x.dtype.name for x in leaves})),
+    )
+
+
+def _install(fresh, params, sharding):
+    """A polled host tree, leaf by leaf: cast on the host to the dtype the
+    held leaf has, put where it lies, and only then let the held leaf go (two
+    whole trees of a large net do not fit a chip).  No dispatch is in flight:
+    the loop fetched its records before it polled."""
+    import jax
+    import numpy as np
+
+    def one(new, old):
+        new = jax.device_put(np.asarray(new).astype(old.dtype), sharding)
+        old.delete()
+        return new
+
+    return jax.tree.map(one, fresh, params)
+
+
+def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
+               stop: threading.Event) -> Dict[str, Any]:
+    """The actor plane over ``devices`` until ``stop`` is set or the gateway
+    says so: dispatch the streaming rollout, fetch its records, ship them,
+    poll parameters when the gateway holds newer ones.  Returns what it ends
+    with: ``dispatches`` and the ``params`` it last acted on."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
 
     from ..parallel.mesh import dispatch_serialized, make_mesh
     from .device_rollout import build_streaming_fn
@@ -52,10 +131,6 @@ def actor_host_main(args: Dict[str, Any]) -> None:
     dist = dict(train_args.get("distributed") or {})
     seed = int(train_args["seed"])
     rank = int(dist.get("process_id") or 0)
-
-    if trace.configure(train_args.get("trace"), rank=1000 + rank):
-        print(f"trace: spans -> {trace.current_path()} (actor host {rank})")
-
     prepare_env(args["env_args"])
     env = make_env(args["env_args"])
     module = env.net()
@@ -79,7 +154,7 @@ def actor_host_main(args: Dict[str, Any]) -> None:
     games = int(train_args["device_rollout_games"]) // max(
         1, int(dist.get("num_processes") or 1)
     )
-    mesh = make_mesh({"dp": -1}, jax.local_devices())
+    mesh = make_mesh({"dp": -1}, list(devices))
     if games % mesh.size:
         raise ValueError(
             f"device_rollout_games {games} not divisible by this actor "
@@ -90,10 +165,21 @@ def actor_host_main(args: Dict[str, Any]) -> None:
         int(train_args["device_replay_k_steps"]),
         mesh=mesh if mesh.size > 1 else None,
         use_observe_mask=bool(train_args["observation"]),
+        counters=True,
     )
     # identical seed -> identical init params on every process: rollouts
-    # are on-policy-ish from step 0, before the first param poll lands
-    params = init_variables(module, env, seed)["params"]
+    # are on-policy-ish from step 0, before the first param poll lands.
+    # One jitted call of the module's own initialisers, onto the devices,
+    # in the dtype the module makes its parameters in
+    replicated = NamedSharding(mesh, PartitionSpec())
+    env.reset()
+    sample = jax.tree.map(lambda x: x[None], env.observation(env.players()[0]))
+    make = jax.jit(
+        lambda key: module.init(key, sample, module.initial_state((1,)))["params"],
+        out_shardings=replicated,
+    )
+    params = dispatch_serialized(lambda: make(jax.random.PRNGKey(seed)), mesh)
+    _weights_event(params)
 
     client = PlaneClient(dist)
     version = client.connect(
@@ -103,8 +189,6 @@ def actor_host_main(args: Dict[str, Any]) -> None:
         f"actor host {rank}: connected to plane gateway "
         f"(param version {version}); {games} lanes on {mesh.size} devices"
     )
-
-    stop = threading.Event()
 
     def _reconnect(i, exc):
         # one flaky syscall (EINTR, a reset mid-frame) must not cost an
@@ -125,16 +209,6 @@ def actor_host_main(args: Dict[str, Any]) -> None:
         client = PlaneClient(dist)
         client.connect(retry_for=30.0)
 
-    def _stop_signal(signum, frame):
-        print(
-            f"[handyrl_tpu] actor host {rank}: signal {signum} — draining",
-            file=sys.stderr,
-        )
-        stop.set()
-
-    signal.signal(signal.SIGTERM, _stop_signal)
-    signal.signal(signal.SIGINT, _stop_signal)
-
     # rank-decorrelated rollout stream, offset past the learner ranks'
     # seed + 1009*rank family so a co-hosted learner never shares a key
     key = jax.random.PRNGKey(seed + 0x5EED + 0xAC706 + 1009 * rank)
@@ -145,41 +219,43 @@ def actor_host_main(args: Dict[str, Any]) -> None:
     try:
         while not stop.is_set():
             key, sub = jax.random.split(key)
-            vstate, hidden, records = dispatch_serialized(
-                lambda: stream_fn(params, vstate, hidden, sub), mesh
-            )
-            # graftlint: allow[HS001] reason=the record batch leaves this machine over DCN — host materialization is the transport's input, one D2H per k_steps block
-            host_records = jax.device_get(records)
-            gateway_version = retry_call(
-                lambda: client.ship_records(host_records),
-                attempts=3, base_delay=0.1, on_retry=_reconnect,
-            )
+            with trace_span("actor.dispatch"):
+                vstate, hidden, records, counted = dispatch_serialized(
+                    lambda: stream_fn(params, vstate, hidden, sub), mesh
+                )
+            with trace_span("actor.fetch"):
+                # graftlint: allow[HS001] reason=the record batch leaves this machine over DCN — host materialization is the transport's input, one D2H per k_steps block
+                host_records, counted = jax.device_get((records, counted))
+            if counted:     # host scalars by now: fetched with the records
+                trace_event("actor.counters", 0.0,
+                            **{name: value.tolist() for name, value in counted.items()})
+            with trace_span("actor.ship"):
+                gateway_version = retry_call(
+                    lambda: client.ship_records(host_records),
+                    attempts=3, base_delay=0.1, on_retry=_reconnect,
+                )
             if gateway_version is None:
                 break  # clean stop from the gateway
             dispatches += 1
             if gateway_version > client.param_version:
-                got = retry_call(
-                    lambda: client.poll_params(),
-                    attempts=3, base_delay=0.1, on_retry=_reconnect,
-                )
-                if got is None:
-                    break
-                new_version, fresh = got
-                if fresh is not None:
-                    params = fresh
-                    print(
-                        f"actor host {rank}: params -> version {new_version}"
+                with trace_span("actor.poll"):
+                    got = retry_call(
+                        lambda: client.poll_params(),
+                        attempts=3, base_delay=0.1, on_retry=_reconnect,
                     )
+                    if got is None:
+                        break
+                    new_version, fresh = got
+                    if fresh is not None:
+                        params = _install(fresh, params, replicated)
+                        _weights_event(params)
+                        print(
+                            f"actor host {rank}: params -> version {new_version}"
+                        )
     except (ConnectionError, OSError) as e:
-        from ..parallel.health import announce_fault
-
-        announce_fault(
-            f"plane gateway lost after {dispatches} dispatches: {e}",
-            "learner_loss",
-            EXIT_RESUMABLE,
-        )
-        client.close()
-        sys.exit(EXIT_RESUMABLE)
+        raise GatewayLost(
+            f"plane gateway lost after {dispatches} dispatches: {e}"
+        ) from e
     finally:
         # await the in-flight async dispatch; exiting the process with an
         # XLA execution still running aborts it (see
@@ -189,5 +265,5 @@ def actor_host_main(args: Dict[str, Any]) -> None:
             jax.block_until_ready(vstate)
         except Exception:
             pass
-    client.close()
-    print(f"actor host {rank}: finished ({dispatches} dispatches)")
+        client.close()
+    return {"dispatches": dispatches, "params": params}

@@ -50,6 +50,13 @@ ACT_SCOPE = "rollout_act"
 STEP_SCOPE = "env_step"
 ENV_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, STEP_SCOPE)
 STREAM_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, POLICY_SCOPE, ACT_SCOPE, STEP_SCOPE)
+# a recurrent module's per-(lane, player) hidden: zeroed where a lane starts
+# again, committed where the player observed.  Whole-tree passes that are
+# neither the env's work nor the net's; a module without hidden has no such op
+COMMIT_SCOPE = "state_commit"
+# the flax collection a module's step mode may sow counters into (a routed
+# layer: the rows its held experts computed, its row buffer's slots)
+COUNTERS = "counters"
 
 
 def build_selfplay_fn(venv, module, n_games: int):
@@ -198,7 +205,7 @@ class DeviceRollout:
 
 
 def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
-                       use_observe_mask: bool = True):
+                       use_observe_mask: bool = True, counters: bool = False):
     """Compile-once streaming self-play step for a simultaneous-move vector
     env (``venv.simultaneous``): ``fn(params, state, key) -> (state, record)``
     scans ``k_steps`` game steps over ``n_lanes`` persistent lanes,
@@ -218,9 +225,16 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
     one-hots the turn player, e.g. VectorGeister); recurrent modules
     (DRC ConvLSTM) carry per-(lane, player) hidden state across steps,
     zeroed on lane reset and committed where the player observed —
-    matching the host generator's per-player hidden handling."""
+    matching the host generator's per-player hidden handling.
+
+    With ``counters`` the program has a fourth output: what the module's
+    step mode sows into its ``counters`` collection, each name summed over
+    the module's layers and the dispatch's steps ({} for a module that
+    sows nothing).  The records and the other outputs are what they are
+    without it."""
 
     P = venv.num_players
+    stateful = module.initial_state((1, 1)) is not None
 
     def fn(params, state, hidden, key):
         def body(carry, key_t):
@@ -229,7 +243,8 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
             reset = state["done"]
             with jax.named_scope(RESET_SCOPE):
                 state = venv.reset_done(state, kr)
-                if hidden is not None:
+            if hidden is not None:
+                with jax.named_scope(COMMIT_SCOPE):
                     # fresh games start from zero hidden (host: init_hidden)
                     hidden = tree_map(
                         lambda h: h * ~reset.reshape((-1,) + (1,) * (h.ndim - 1)),
@@ -255,19 +270,30 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
                     if hidden is None
                     else tree_map(lambda h: h.reshape((B * P,) + h.shape[2:]), hidden)
                 )
-                out = module.apply({"params": params}, flat, h_flat)
+                counted = {}
+                if counters:
+                    out, sown = module.apply(
+                        {"params": params}, flat, h_flat, mutable=[COUNTERS])
+                    for path, value in jax.tree_util.tree_leaves_with_path(
+                            sown.get(COUNTERS, {})):
+                        # .../<name>/<index of the call that sowed it>
+                        name = path[-2].key
+                        counted[name] = counted.get(name, 0.0) + value.astype(jnp.float32)
+                else:
+                    out = module.apply({"params": params}, flat, h_flat)
                 if hidden is not None:
                     new_hidden = tree_map(
                         lambda h: h.reshape((B, P) + h.shape[1:]), out["hidden"]
                     )
-                    # commit where observed, keep elsewhere (train_step.py:146)
-                    hidden = jax.tree.map(
-                        lambda h, nh: jnp.where(
-                            observing.reshape((B, P) + (1,) * (h.ndim - 2)), nh, h
-                        ),
-                        hidden,
-                        new_hidden,
-                    )
+                    with jax.named_scope(COMMIT_SCOPE):
+                        # commit where observed, keep elsewhere (train_step.py:146)
+                        hidden = jax.tree.map(
+                            lambda h, nh: jnp.where(
+                                observing.reshape((B, P) + (1,) * (h.ndim - 2)), nh, h
+                            ),
+                            hidden,
+                            new_hidden,
+                        )
             with jax.named_scope(ACT_SCOPE):
                 logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
                 legal = venv.legal_mask_all(state)       # (B, P, A) bool
@@ -278,7 +304,9 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
                 probs = jax.nn.softmax(masked, axis=-1)
                 prob = jnp.take_along_axis(probs, action[..., None], axis=-1)[..., 0]
                 value = (
-                    out["value"].reshape(B, P)
+                    # float32 like prob, whatever the net computes in: the
+                    # records' schema is the rings', not the net's
+                    out["value"].astype(jnp.float32).reshape(B, P)
                     if out.get("value") is not None
                     else jnp.zeros_like(prob)
                 )
@@ -295,20 +323,23 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
                 state = venv.step(state, action, kf)
                 record["done"] = state["done"]   # reset_done cleared stale flags
                 record["outcome"] = venv.outcome_scores(state)  # final where done
-            return (state, hidden), record
+            return (state, hidden), (record, counted)
 
         # Stays a genuine loop on every backend: unrolling k_steps bodies
         # here multiplies compile time by k (measured: minutes per shape on
         # the 1-core CPU host) for a path whose CPU throughput is a
         # fallback, not a target — unlike the RNN TRAIN scan, which is
         # unrolled on single-device CPU (see parallel/train_step.py).
-        (state, hidden), records = jax.lax.scan(
+        (state, hidden), (records, counted) = jax.lax.scan(
             body, (state, hidden), jax.random.split(key, k_steps)
         )
+        if counters:
+            return state, hidden, records, {k: v.sum() for k, v in counted.items()}
         return state, hidden, records
 
     fn.__name__ = STREAM_PROGRAM
-    options = scoped_program_options(*STREAM_SCOPES)
+    # a module without hidden keeps the options, and so the cache entry, it had
+    options = scoped_program_options(*STREAM_SCOPES, *((COMMIT_SCOPE,) if stateful else ()))
     if mesh is None:
         return jax.jit(fn, donate_argnums=(1, 2), compiler_options=options)
     from jax.sharding import NamedSharding, PartitionSpec
@@ -320,7 +351,7 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
         fn,
         donate_argnums=(1, 2),
         in_shardings=(rep, lanes, lanes, rep),
-        out_shardings=(lanes, lanes, rec),
+        out_shardings=(lanes, lanes, rec) + ((rep,) if counters else ()),
         compiler_options=options,
     )
 

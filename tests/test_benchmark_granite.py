@@ -1,0 +1,90 @@
+"""benchmark/tests/test_granite_rehearsal.py, collected where tests are run.
+Its rehearsals run ``run.py`` on a workload of their own
+(``benchmark_out/tiny_granite_actor``), so they share no output directory
+with the other files that do.
+
+Three cases of the benchmark's own files are restated here.  Each held an
+earlier PR's entries to a *position* in ``BENCHMARK.json``'s lists (the last
+cell of a list, the last entries of ``per_layer``) or every cell to a list,
+which the cell and the five metrics PR 44 appended end; a ``model_config`` PR
+may not edit a file the benchmark has, so tests/test_benchmark_hybrid.py,
+test_benchmark_ouro.py and test_benchmark_rehearsals.py drop the three and
+these ask what they meant (PERF.md section 7 leaves the edit to a
+``benchmark`` PR)."""
+
+import json
+import os
+
+from benchmark.tests.test_granite_rehearsal import *  # noqa: F401,F403
+from benchmark.tests.test_granite_rehearsal import BENCH, REPO, rehearsal
+
+ROUTED, LOOPED = "nemotron_twotower_train_t192", "ouro_train_t192"
+
+
+def _spec():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _lists(spec):
+    return {m["name"]: m["workloads"] for g in ("end_to_end", "per_layer") for m in spec[g]
+            if "workloads" in m}
+
+
+def test_every_new_metric_lists_the_cell_and_has_a_reader():
+    """PR 34's: the routed cell's own metrics list it alone; the accepted
+    ones it joined list it after the cells they had (and before what came
+    later); the cell's entry is its file's."""
+    spec = _spec()
+    lists = _lists(spec)
+    for name in ("ssd_roofline", "experts_roofline", "route_step_share", "ssd_step_share",
+                 "expert_rows_max_over_mean"):
+        assert lists[name] == [ROUTED]
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    for name in ("setup_compile_s", "train_step_device_ms", "train_mfu", "train_roofline_share",
+                 "device_idle_share"):
+        assert lists[name].index(ROUTED) > lists[name].index("xfmr_train_t64_dp4")
+    cell = rehearsal._load(os.path.join(BENCH, "workloads"))[ROUTED]
+    entry = next(w for w in spec["workloads"] if w["name"] == cell["name"])
+    assert (entry["config"], entry["traffic"], entry["chips"], entry["why"]) == (
+        cell["config"], cell["traffic"], cell["chips"], cell["why"])
+
+
+def test_the_looped_cells_entries_stand_where_they_were_appended():
+    """PR 41's: its configuration, its cell and its three metrics, one after
+    another, behind everything older and before PR 44's; its name behind the
+    routed cell's only in ``trained_steps_per_s``."""
+    spec = _spec()
+    configs = [c["name"] for c in spec["configs"]]
+    cells = [w["name"] for w in spec["workloads"]]
+    assert configs.index("ouro_2_6b") == 3 and cells.index(LOOPED) == 4
+    names = [m["name"] for m in spec["per_layer"]]
+    new = ["mlp_roofline", "attn_step_share", "norm_step_share"]
+    first = names.index(new[0])
+    assert names[first:first + 3] == new and first == 24
+    for metric in spec["per_layer"][first:first + 3]:
+        assert metric["workloads"] == [LOOPED] and metric["moves"] == "trained_steps_per_s"
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", metric["name"] + ".py"))
+    lists = _lists(spec)
+    listed = sorted(name for name, cells in lists.items() if LOOPED in cells)
+    assert listed == sorted(new + [
+        "trained_steps_per_s", "setup_compile_s", "train_step_device_ms", "train_mfu",
+        "train_roofline_share", "device_idle_share"])
+    for name in listed:
+        before_routed = lists[name].index(LOOPED) < lists[name].index(ROUTED) \
+            if ROUTED in lists[name] else False
+        assert before_routed == (name not in new + ["trained_steps_per_s"])
+
+
+def test_every_cell_lists_setup_compile_s_and_every_training_cell_device_idle_share():
+    """A per-layer metric lists the cells that report the end-to-end metric it
+    moves: ``setup_compile_s`` moves ``setup_s``, which every cell reports;
+    ``device_idle_share`` moves ``trained_steps_per_s``, which the acting cell
+    does not."""
+    spec = _spec()
+    cells = sorted(cell["name"] for cell in spec["workloads"])
+    lists = _lists(spec)
+    for metric in spec["per_layer"]:
+        assert "workloads" in metric, metric["name"]
+    assert sorted(lists["setup_compile_s"]) == cells
+    assert sorted(lists["device_idle_share"]) == sorted(lists["trained_steps_per_s"])

@@ -7,6 +7,10 @@ too, so they are collected here."""
 
 from benchmark.tests.test_hybrid_reference import *  # noqa: F401,F403
 from benchmark.tests.test_hybrid_rehearsal import *  # noqa: F401,F403  isort: skip
+
+# holds the routed cell to be the last of ``setup_compile_s``'s cells, which the
+# cell PR 44 appended ends: restated in tests/test_benchmark_granite.py
+del test_every_new_metric_lists_the_cell_and_has_a_reader  # noqa: F821
 from benchmark.tests.test_phase_readers import (  # noqa: F401  isort: skip
     hybrid_root, test_rehearsed_routed_cell_answers_packed_padding_share,
 )

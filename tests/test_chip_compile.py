@@ -362,3 +362,49 @@ def test_dp1_step_lowers_without_the_ring(v5e_2x2):
     assert ctx1.grad_sync is None
     for word in words + ("shard_map", "psum"):
         assert word not in text1, word
+
+
+# -- the actor cell's rollout program (granite_actor_b32) ---------------------
+
+def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v5e, monkeypatch):
+    """The streaming rollout of the benchmark's actor cell at its own sizes
+    (32 Geister lanes x 2 players, 16 steps, one period of the published
+    widths in bfloat16) compiles for a v5e with the routed experts' kernel in
+    it, and fits: the weights (9.14 GB) and one copy of the per-row state
+    (2.58 GB); the donated hidden tree is aliased through the scan and the
+    commit's select fused, so the temporaries stay under a gigabyte.  ISSUE
+    44's rule: over 15.5 GB the cell would run 16 lanes."""
+    import json
+
+    from handyrl_tpu.envs import make_env
+    from handyrl_tpu.runtime.device_rollout import build_streaming_fn
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs", "granite_4_0_h_small.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "benchmark", "workloads", "granite_actor_b32.json")) as f:
+        cell = json.load(f)["train_args"]
+    lanes, k = cell["device_rollout_games"], cell["device_replay_k_steps"]
+    env = make_env(config["env_args"])
+    module, venv = env.net(), env.vector_env()
+    env.reset()
+    obs = jax.tree.map(lambda x: jnp.asarray(x)[None], env.observation(env.players()[0]))
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+    params = described(jax.eval_shape(
+        lambda key: module.init(key, obs, module.initial_state((1,)))["params"],
+        jax.random.PRNGKey(0)))
+    vstate = described(jax.eval_shape(lambda key: venv.init(lanes, key), jax.random.PRNGKey(0)))
+    hidden = described(jax.eval_shape(lambda: module.initial_state((lanes, venv.num_players))))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernel, not its interpreter
+    fn = build_streaming_fn(venv, module, lanes, k, use_observe_mask=cell["observation"],
+                            counters=True)
+    compiled = fn.lower(params, vstate, hidden, key).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    assert 11.5e9 < held < 13.0e9, held                 # read 12.08 GB (PR 44); on the chip 11.76 in use
+    assert memory.alias_size_in_bytes > 2.5e9           # the hidden tree, donated
+    assert memory.temp_size_in_bytes < 1.0e9
