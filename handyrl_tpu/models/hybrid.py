@@ -35,6 +35,10 @@ it has two modes over one parameter set:
 
 * step mode — ``apply(obs, hidden)``: one step of every recurrence (acting,
   and the train step's scan path, which commits hidden only where observed);
+  with ``rows=(player, begun)`` the mixers' states (all but ``pos``) are
+  handed over per (row, player) and row ``player[n]`` of each is stepped
+  where it lies, read as zeros where ``begun`` (``rows_in_place``; the
+  streaming rollout of a game in which one player a lane acts);
 * whole-window mode — ``seq=True``: a (rows, T) window at once, the scan in
   its chunked matmul form.  The scan path's rules are kept exactly: an
   unobserved step (``key_mask`` 0) leaves every state as it was, which the
@@ -78,7 +82,8 @@ import numpy as np
 
 from ..ops import attention_core
 from ..ops.routed_experts import choose, held_mix
-from ..ops.ssd import ssd_chunked, ssd_step
+from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows
+from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
 from .transformer import NEG_INF, _flatten_obs
 
 KINDS = "ME*-"
@@ -157,7 +162,9 @@ class Mamba2Mixer(nn.Module):
     @nn.compact
     def __call__(self, u, state, valid=None):
         """u (N, L, d) with ``valid`` (N, L) a prefix mask, or (N, d) for
-        one step; state {"ssm", "conv"} -> (out, new state)."""
+        one step; state {"ssm", "conv"} -> (out, new state).  A step whose
+        state holds ``rows`` (player (N,), begun (N,)) is handed both per (row,
+        player) and steps row ``player[n]`` of each where it lies."""
         H, P, G, S, K = self.heads, self.head_dim, self.groups, self.state_size, self.conv_kernel
         inner, conv_dim = H * P, H * P + 2 * G * S
         step = u.ndim == 2
@@ -186,7 +193,12 @@ class Mamba2Mixer(nn.Module):
         z, xbc, dt = jnp.split(_dense(inner + conv_dim + H, "in_proj", kept)(u),
                                [inner, inner + conv_dim], axis=-1)
         # causal depthwise conv over the last K - 1 inputs and this one
-        tail = state["conv"].astype(xbc.dtype)
+        rows = state.get("rows")    # ``ssm`` and ``conv`` per (row, player): the acting one's
+        tail = state["conv"]
+        if rows is not None:
+            with jax.named_scope(COMMIT_SCOPE):
+                tail = acting_rows(tail, *rows)
+        tail = tail.astype(xbc.dtype)
         fed = jnp.concatenate([tail, xbc], axis=1)                   # (N, K - 1 + L, C)
         conv = sum(fed[:, k:k + length] * conv_w[k].astype(xbc.dtype) for k in range(K))
         xbc_c = jax.nn.silu(conv + conv_b.astype(xbc.dtype))
@@ -204,7 +216,11 @@ class Mamba2Mixer(nn.Module):
         A = -jnp.exp(a_log.astype(jnp.float32))
         with jax.named_scope("ssd"):
             if step:
-                y, ssm = ssd_step(x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], state["ssm"])
+                one = (x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], state["ssm"])
+                if rows is None:
+                    y, ssm = ssd_step(*one)
+                else:   # both leaves' acting rows written where they lie, by one kernel
+                    y, ssm, new_tail = ssd_step_rows(*one, *rows, (state["conv"], new_tail))
                 y = y[:, None]
             else:
                 y, ssm = ssd_chunked(x, dt, A, B, C, state["ssm"], self.chunk)
@@ -289,7 +305,9 @@ class GroupedQueryAttention(nn.Module):
         """Window mode: h (N, L, d) with ``valid`` a prefix mask, state
         {"k", "v" (N, L0, kv, D), "n" (N,)} the observed steps before this
         window.  Step mode: h (N, d), state {"k", "v" (N, memory_len, kv,
-        D), "pos" (N,)} a ring.  Returns (out, new state).  With
+        D), "pos" (N,)} a ring, or with ``rows`` (player (N,), begun (N,)) the
+        rings per (row, player), read and written at ``player[n]`` where they
+        lie.  Returns (out, new state).  With
         ``rope_theta`` queries and keys are rotated by their position among
         the row's observed steps, and the keys are kept rotated."""
         with jax.named_scope(ATTN_SCOPE):
@@ -325,11 +343,23 @@ class GroupedQueryAttention(nn.Module):
                 S = self.memory_len
                 slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
                 hot = jax.nn.one_hot(slot, S, dtype=jnp.float32)[..., None, None]
-                keys = state["k"] * (1 - hot) + hot * k.astype(jnp.float32)
-                values = state["v"] * (1 - hot) + hot * v.astype(jnp.float32)
+                rows, ring_k, ring_v = state.get("rows"), state["k"], state["v"]
+                if rows is not None:    # rings per (row, player): the acting player's of each
+                    with jax.named_scope(COMMIT_SCOPE):
+                        ring_k, ring_v = acting_rows(ring_k, *rows), acting_rows(ring_v, *rows)
+                keys = ring_k * (1 - hot) + hot * k.astype(jnp.float32)
+                values = ring_v * (1 - hot) + hot * v.astype(jnp.float32)
                 age = jnp.mod(slot[:, None] - jnp.arange(S)[None, :], S)
                 allowed = (age < jnp.minimum(state["pos"] + 1, S)[:, None])[:, None, :]
-                new_state = {"k": keys, "v": values}
+                if rows is None:
+                    new_state = {"k": keys, "v": values}
+                else:   # what ``keys`` and ``values`` are, written where the rings lie: the
+                    # step's slot alone, over zeros where the row's game has just begun
+                    with jax.named_scope(COMMIT_SCOPE):
+                        player, begun = rows
+                        new_state = {name: begin_rows(state[name], player, begun, whole=True).at[
+                            jnp.arange(n), player, slot].set(new[:, 0].astype(jnp.float32))
+                            for name, new in (("k", k), ("v", v))}
             else:
                 before = state["n"].astype(jnp.int32)
                 keys = jnp.concatenate([state["k"].astype(k.dtype), k], axis=1)
@@ -522,7 +552,7 @@ class HybridNet(nn.Module):
     @nn.compact
     def __call__(self, obs, hidden=None, train: bool = False, *,
                  seq: bool = False, key_mask=None, burn_in: int = 0, remat: str = "none",
-                 packed_order=None):
+                 packed_order=None, rows=None):
         if any(kind not in KINDS for kind in self.pattern):
             raise ValueError(f"pattern {self.pattern!r}: a layer is one of {KINDS!r}")
         if self.loops > 1 and "E" in self.pattern:
@@ -621,8 +651,13 @@ class HybridNet(nn.Module):
         if not seq:
             if hidden is None:
                 hidden = self.initial_state((jax.tree.leaves(obs)[0].shape[0],))
+            # step mode's ``rows`` (player (N,), begun (N,)): the leaves that
+            # ``rows_in_place`` names are per (row, player), to be stepped at
+            # ``player[n]`` where they lie and read as zeros where ``begun``
+            where = {} if rows is None else {"rows": rows}
+            given = {"M": where, "*": dict(where, pos=hidden["pos"])}
             states = tuple(
-                dict(state, pos=hidden["pos"]) if kind == "*" else state
+                dict(state, **given.get(kind, {}))
                 for kind, state in zip(self.pattern * self.loops, hidden["layers"]))
             x, states, _, _, _ = passes(layers(Layer), encode(_flatten_obs(obs)), states, None)
             out = self._heads(close(x))
@@ -728,6 +763,17 @@ class HybridNet(nn.Module):
         # pos is float32 so the train step's observation-mask arithmetic on
         # the hidden carry (h * mask) never changes the carry dtype
         return {"layers": tuple(layers), "pos": jnp.zeros(bd, jnp.float32)}
+
+    @nn.nowrap
+    def rows_in_place(self, hidden):
+        """Of a hidden tree per (row, player), the leaves that step mode
+        takes whole under ``rows`` and steps one player's row of where it
+        lies: a tree of bools, the SSM states' and conv tails' (``ops/ssd.py``
+        ``ssd_step_rows``) and the key and value rings' (a step writes one
+        slot): all but ``pos``.  A caller gathers the acting player's row of
+        every other."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: path[-1].key in ("ssm", "conv", "k", "v"), hidden)
 
     @nn.nowrap
     def layout(self) -> Dict[str, Any]:

@@ -122,6 +122,7 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
+    from ..ops import ssd
     from ..parallel.mesh import dispatch_serialized, make_mesh
     from .device_rollout import build_streaming_fn
     from .plane import PlaneClient
@@ -215,7 +216,7 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
     key, k0 = jax.random.split(key)
     vstate = venv.init(games, k0)
     hidden = module.initial_state((games, venv.num_players))
-    dispatches = 0
+    dispatches, written = 0, set()
     try:
         while not stop.is_set():
             key, sub = jax.random.split(key)
@@ -229,6 +230,12 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
             if counted:     # host scalars by now: fetched with the records
                 trace_event("actor.counters", 0.0,
                             **{name: value.tolist() for name, value in counted.items()})
+            if trace.enabled() and len(written) < len(ssd.ROW_PATHS):
+                # how the program steps a (lane, player) state's acting rows:
+                # chosen from dtype and shape when it was traced, once each
+                for chosen in set(ssd.ROW_PATHS) - written:
+                    trace_event("model.ssd_rows_path", 0.0, **ssd.ROW_PATHS[chosen])
+                    written.add(chosen)
             with trace_span("actor.ship"):
                 gateway_version = retry_call(
                     lambda: client.ship_records(host_records),

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.rows import COMMIT_SCOPE, acting_rows, put_rows
 from ..utils import tree_map
 from ..utils.compile_cache import scoped_program_options
 from .replay import compress_block
@@ -52,11 +53,15 @@ ENV_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, STEP_SCOPE)
 STREAM_SCOPES = (RESET_SCOPE, OBSERVE_SCOPE, POLICY_SCOPE, ACT_SCOPE, STEP_SCOPE)
 # a recurrent module's per-(lane, player) hidden: zeroed where a lane starts
 # again, committed where the player observed.  Whole-tree passes that are
-# neither the env's work nor the net's; a module without hidden has no such op
-COMMIT_SCOPE = "state_commit"
+# neither the env's work nor the net's, or, where one player a lane observes,
+# the gather and the scatters of the acting rows (``ops/rows.py``, whose
+# constant this is; not of a leaf's rows that the module steps where they
+# lie: ``rows_in_place``); a module without hidden has no such op
 # the flax collection a module's step mode may sow counters into (a routed
-# layer: the rows its held experts computed, its row buffer's slots)
+# layer: the rows its held experts computed, its row buffer's slots); the scan
+# body adds ``ROWS_APPLIED``, the rows it applied the net to
 COUNTERS = "counters"
+ROWS_APPLIED = "rows_applied"
 
 
 def build_selfplay_fn(venv, module, n_games: int):
@@ -227,14 +232,33 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
     zeroed on lane reset and committed where the player observed —
     matching the host generator's per-player hidden handling.
 
+    Where exactly one player a lane observes (a recurrent module on a
+    strict-alternation env without ``use_observe_mask``: ``observing`` is
+    ``active``, one-hot once ``reset_done`` has run), a step is the acting
+    rows' alone: the net is applied to ``n_lanes`` rows, not ``n_lanes x P``;
+    of the hidden tree the acting player's row of each lane is gathered, read
+    as zeros where the lane's game has just begun, stepped and scattered
+    back, and the lane's other rows are written as zeros there and then; a
+    leaf the module says it steps in place (``rows_in_place``: a ``HybridNet``'s
+    mixers' states, off a mesh) is handed over whole with ``rows=(player,
+    begun)`` and comes back whole.  The other rows of a step's ``action``, ``prob`` and
+    ``value`` are what an episode holds for a player who does not act (0, 1,
+    0); the Gumbel draw keeps its ``(n_lanes, P, A)`` shape, so the acting
+    rows draw what they drew.  The same commit-where-observed, with a row
+    count that is static here and not elsewhere.
+
     With ``counters`` the program has a fourth output: what the module's
     step mode sows into its ``counters`` collection, each name summed over
-    the module's layers and the dispatch's steps ({} for a module that
-    sows nothing).  The records and the other outputs are what they are
-    without it."""
+    the module's layers and the dispatch's steps, and ``rows_applied``, the
+    rows the net was applied to over the dispatch's steps.  The records and
+    the other outputs are what they are without it."""
 
     P = venv.num_players
     stateful = module.initial_state((1, 1)) is not None
+    by_row = (stateful and not getattr(venv, "simultaneous", True)
+              and not (use_observe_mask and hasattr(venv, "observe_mask")))
+    # a kernel's operand is not GSPMD's to shard: on a mesh every leaf is gathered
+    claims = getattr(module, "rows_in_place", None) if by_row and mesh is None else None
 
     def fn(params, state, hidden, key):
         def body(carry, key_t):
@@ -243,7 +267,7 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
             reset = state["done"]
             with jax.named_scope(RESET_SCOPE):
                 state = venv.reset_done(state, kr)
-            if hidden is not None:
+            if hidden is not None and not by_row:
                 with jax.named_scope(COMMIT_SCOPE):
                     # fresh games start from zero hidden (host: init_hidden)
                     hidden = tree_map(
@@ -263,25 +287,50 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
                     else active
                 )
                 obs = venv.observation(state)            # leaves (B, P, ...)
-                flat = tree_map(lambda x: x.reshape((B * P,) + x.shape[2:]), obs)
+                if by_row:
+                    lanes, player = jnp.arange(B), jnp.argmax(active, axis=1).astype(jnp.int32)
+                    acting = lambda x: x[lanes, player]  # noqa: E731  (B, P, ...) -> (B, ...)
+                    flat = tree_map(acting, obs)
+                else:
+                    flat = tree_map(lambda x: x.reshape((B * P,) + x.shape[2:]), obs)
             with jax.named_scope(POLICY_SCOPE):
-                h_flat = (
-                    None
-                    if hidden is None
-                    else tree_map(lambda h: h.reshape((B * P,) + h.shape[2:]), hidden)
-                )
+                how = {}
+                if by_row:
+                    if claims is None:
+                        whole = tree_map(lambda h: False, hidden)
+                    else:
+                        whole, how = claims(hidden), {"rows": (player, reset)}
+                    with jax.named_scope(COMMIT_SCOPE):
+                        # fresh games start from zero hidden (host: init_hidden)
+                        h_flat = tree_map(
+                            lambda h, kept: h if kept else acting_rows(h, player, reset),
+                            hidden, whole)
+                else:
+                    h_flat = (
+                        None
+                        if hidden is None
+                        else tree_map(lambda h: h.reshape((B * P,) + h.shape[2:]), hidden)
+                    )
                 counted = {}
                 if counters:
                     out, sown = module.apply(
-                        {"params": params}, flat, h_flat, mutable=[COUNTERS])
+                        {"params": params}, flat, h_flat, mutable=[COUNTERS], **how)
                     for path, value in jax.tree_util.tree_leaves_with_path(
                             sown.get(COUNTERS, {})):
                         # .../<name>/<index of the call that sowed it>
                         name = path[-2].key
                         counted[name] = counted.get(name, 0.0) + value.astype(jnp.float32)
+                    counted[ROWS_APPLIED] = jnp.float32(B if by_row else B * P)
                 else:
-                    out = module.apply({"params": params}, flat, h_flat)
-                if hidden is not None:
+                    out = module.apply({"params": params}, flat, h_flat, **how)
+                if by_row:
+                    with jax.named_scope(COMMIT_SCOPE):
+                        # the acting row back where it lay; the lane's other
+                        # rows zeroed where its game has just begun
+                        hidden = tree_map(
+                            lambda h, nh, kept: nh if kept else put_rows(h, nh, player, reset),
+                            hidden, out["hidden"], whole)
+                elif hidden is not None:
                     new_hidden = tree_map(
                         lambda h: h.reshape((B, P) + h.shape[1:]), out["hidden"]
                     )
@@ -295,21 +344,31 @@ def build_streaming_fn(venv, module, n_lanes: int, k_steps: int, mesh=None,
                             new_hidden,
                         )
             with jax.named_scope(ACT_SCOPE):
-                logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
-                legal = venv.legal_mask_all(state)       # (B, P, A) bool
-                masked = jnp.where(legal, logits, logits - ILLEGAL)
-                # Gumbel-max == softmax sampling at temperature 1 (generation.py)
-                g = jax.random.gumbel(ka, masked.shape)
+                if by_row:
+                    logits = out["policy"].astype(jnp.float32)      # (B, A): the acting rows'
+                    legal = venv.legal_mask_all(state)
+                    masked = jnp.where(acting(legal), logits, logits - ILLEGAL)
+                    g = acting(jax.random.gumbel(ka, legal.shape))
+                else:
+                    logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
+                    legal = venv.legal_mask_all(state)       # (B, P, A) bool
+                    masked = jnp.where(legal, logits, logits - ILLEGAL)
+                    # Gumbel-max == softmax sampling at temperature 1 (generation.py)
+                    g = jax.random.gumbel(ka, masked.shape)
                 action = jnp.argmax(masked + g, axis=-1).astype(jnp.int32)
                 probs = jax.nn.softmax(masked, axis=-1)
                 prob = jnp.take_along_axis(probs, action[..., None], axis=-1)[..., 0]
                 value = (
                     # float32 like prob, whatever the net computes in: the
                     # records' schema is the rings', not the net's
-                    out["value"].astype(jnp.float32).reshape(B, P)
+                    out["value"].astype(jnp.float32).reshape(prob.shape)
                     if out.get("value") is not None
                     else jnp.zeros_like(prob)
                 )
+                if by_row:  # (B,) -> (B, P): what an episode holds for the other rows
+                    action, prob, value = (
+                        jnp.where(active, x[:, None], idle)
+                        for x, idle in ((action, 0), (prob, 1.0), (value, 0.0)))
             with jax.named_scope(STEP_SCOPE):
                 record = {
                     "active": active,

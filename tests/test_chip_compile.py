@@ -366,6 +366,34 @@ def test_dp1_step_lowers_without_the_ring(v5e_2x2):
 
 # -- the actor cell's rollout program (granite_actor_b32) ---------------------
 
+@pytest.mark.parametrize("players", [2, 4])
+def test_ssd_step_rows_compiles_for_v5e_and_writes_the_buffer_it_read(v5e, players):
+    """``ops/ssd.py``'s in-place step at the published Mamba-2 widths (128
+    heads of 64 x 128, float32) for 32 lanes: Mosaic takes the kernel (a
+    4.2 MB row read and written, both double-buffered, under the 64 MB scope it
+    asks for), the donated state is the output's buffer and the program
+    holds no second copy of it."""
+    from handyrl_tpu.ops import ssd
+
+    n, h, p, g, s = 32, 128, 64, 1, 128
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+    width = h * p + 2 * g * s       # the conv tail beside it: 3 rows of 8,448
+    fn = jax.jit(
+        lambda x, dt, A, B, C, state, player, fresh, leaf, rows: ssd.ssd_step_rows(
+            x, dt, A, B, C, state, player, fresh, (leaf, rows), interpret=False),
+        donate_argnums=(5, 8))
+    compiled = fn.lower(
+        aval((n, h, p), jnp.bfloat16), aval((n, h), jnp.float32), aval((h,), jnp.float32),
+        aval((n, g, s), jnp.bfloat16), aval((n, g, s), jnp.bfloat16),
+        aval((n, players, h, p, s), jnp.float32), aval((n,), jnp.int32), aval((n,), jnp.bool_),
+        aval((n, players, 3, width), jnp.float32), aval((n, 3, width), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory, state = compiled.memory_analysis(), 4 * n * players * (h * p * s + 3 * width)
+    assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < state // 8
+
+
+
 def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v5e, monkeypatch):
     """The streaming rollout of the benchmark's actor cell at its own sizes
     (32 Geister lanes x 2 players, 16 steps, one period of the published
@@ -401,10 +429,33 @@ def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v
     fn = build_streaming_fn(venv, module, lanes, k, use_observe_mask=cell["observation"],
                             counters=True)
     compiled = fn.lower(params, vstate, hidden, key).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     memory = compiled.memory_analysis()
     held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
             + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
-    assert 11.5e9 < held < 13.0e9, held                 # read 12.08 GB (PR 44); on the chip 11.76 in use
+    assert 11.5e9 < held < 13.0e9, held                 # read 12.09 GB (PR 45); on the chip 11.76 in use
     assert memory.alias_size_in_bytes > 2.5e9           # the hidden tree, donated
     assert memory.temp_size_in_bytes < 1.0e9
+    # one player a lane observes (PR 45): a Mamba-2 layer's state for all lanes
+    # and both players is stepped where it lies, the acting player's row of
+    # each lane through ``ops/ssd.py``'s kernel (which sees it as lanes x
+    # players x (heads x head_dim) x S: a bitcast).  Nothing else yields or
+    # reads an array of its whole shape: no copy, no select, no multiply, no
+    # fusion, no scatter
+    rows = module.mamba_heads * module.mamba_head_dim
+    whole = ("f32[%d,%d,%d,%d,%d]" % (lanes, venv.num_players, module.mamba_heads,
+                                      module.mamba_head_dim, module.state_size),
+             "f32[%d,%d,%d,%d]" % (lanes, venv.num_players, rows, module.state_size))
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\(?[^=]*?\)?) ([\w-]+)\((.*)$", text, flags=re.M)
+    touch = lambda s: any(leaf in s for leaf in whole)  # noqa: E731
+    yields = {op for shape, op, _ in ops if touch(shape) and not shape.startswith("(s32[]")}
+    reads = {op for shape, op, rest in ops
+             if touch(rest.split(", metadata=")[0].split(", custom_call_target=")[0])
+             and not touch(shape)}
+    steps = [rest for shape, op, rest in ops if op == "custom-call" and touch(shape)]
+    assert len(steps) == module.pattern.count("M") == 9, len(steps)
+    assert yields == {"custom-call", "get-tuple-element", "parameter", "bitcast"}, yields
+    assert not reads, reads
+    # and the kernel writes the buffer it read: the scan's carry is its output
+    assert all("output_to_operand_aliasing" in rest for rest in steps), steps[0][:400]

@@ -363,16 +363,22 @@ def rollout():
 def test_the_rollout_counts_what_the_step_mode_sowed_and_keeps_its_records(rollout):
     module, params, venv, plain, _, outs = rollout
     for records, counted in outs:
-        assert set(counted) == {"rows_held", "buffer_slots"}
+        assert set(counted) == {"rows_held", "buffer_slots", "rows_applied"}
+        # one player a lane observes: the net is applied to the 4 acting rows of 8
+        assert counted["rows_applied"] == 8 * 4
         # three routed sub-layers, 8 steps, one buffer of (blocks x 128) slots each
         from handyrl_tpu.ops.routed_experts import BLOCK, row_buffer
 
-        blocks = row_buffer(8, 3, 4, 8)[0]
+        blocks = row_buffer(4, 3, 4, 8)[0]
         assert counted["buffer_slots"] == 3 * 8 * blocks * BLOCK
         # every row the net is applied to chooses 3 of 8, 4 held: at most 3 a row
-        assert 0 < counted["rows_held"] <= 3 * 8 * 8 * 3
+        assert 0 < counted["rows_held"] <= 3 * 8 * 4 * 3
         assert records["value"].dtype == np.float32 and records["prob"].dtype == np.float32
         assert np.isfinite(records["value"]).all() and np.isfinite(records["prob"]).all()
+        # the rows that do not act hold what an episode holds for them
+        idle = ~records["active"]
+        assert (records["action"][idle] == 0).all() and (records["prob"][idle] == 1).all()
+        assert (records["value"][idle] == 0).all() and records["action"].shape == (8, 4, 2)
     # without ``counters`` the program has three outputs, and the same records
     state, hidden = venv.init(4, jax.random.PRNGKey(0)), module.initial_state((4, 2))
     again = plain(params, state, hidden, jax.random.fold_in(jax.random.PRNGKey(5), 0))
@@ -382,19 +388,153 @@ def test_the_rollout_counts_what_the_step_mode_sowed_and_keeps_its_records(rollo
 
 
 def test_the_hidden_trees_passes_bear_state_commit_and_the_nets_scopes(rollout):
-    """In the compiled rollout: the reset multiply and the commit's select
-    under ``state_commit`` (not under ``env_reset``), the net's scopes inside
-    ``rollout_policy``; a net without hidden has no such op."""
+    """In the compiled rollout: the acting rows' gather and the scatters back
+    under ``state_commit`` where the net runs (``rollout_policy``), and no
+    pass over the tree outside it: the reset's multiply is the gathered rows';
+    the net's scopes inside ``rollout_policy``; a net without hidden has no
+    such op."""
     *_, lowered, _ = rollout
-    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    text = lowered.compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
     commit = [n for n in names if f"/{device_rollout.COMMIT_SCOPE}/" in n + "/"]
     assert commit and not any(device_rollout.RESET_SCOPE in n for n in commit)
-    assert any(device_rollout.POLICY_SCOPE in n for n in commit)      # the select, where the net ran
-    assert any(device_rollout.POLICY_SCOPE not in n for n in commit)  # the reset's multiply
+    assert all(device_rollout.POLICY_SCOPE in n for n in commit)
+    assert any("scatter" in n for n in commit)
     policy = [n for n in names if f"/{device_rollout.POLICY_SCOPE}/" in n]
     for scope in ("ssd", "route", "experts", "shared_expert", "attn", "norm"):
         assert any(f"/{scope}/" in n + "/" for n in policy), scope
     assert device_rollout.COMMIT_SCOPE not in device_rollout.STREAM_SCOPES
+    # nothing but a scatter (and the fusion round it) yields an array of a
+    # leaf's whole (lanes, players, ...) shape: no select and no multiply goes
+    # over both players' rows
+    module = rollout[0]
+    shapes = {"f32[%s]" % ",".join(map(str, leaf.shape))
+              for leaf in jax.tree.leaves(module.initial_state((4, 2))) if leaf.ndim > 2}
+    made = {op for shape, op in re.findall(r"= (f32\[[0-9,]+\])\S* (\w[\w-]*)\(", text)
+            if shape in shapes}
+    # (XLA:CPU copies a ring between its two scatters; what the TPU's compiler
+    # makes of the cell's own program is tests/test_chip_compile.py's)
+    assert "scatter" in made and made <= {
+        "scatter", "fusion", "get-tuple-element", "parameter", "copy"}, made
+
+
+# -- the row path against the whole tree's reset and select ----------------------
+
+
+def _whole_tree_step(module, venv):
+    """One step of the streaming rollout as it was before a step was the
+    acting rows' alone: the net on all lanes x players rows, the whole hidden
+    tree multiplied by ``~reset`` and selected over where observed.  Written
+    here from ``module.apply``; -> jitted (params, state, hidden, the step's
+    key) -> (state, hidden, record)."""
+    def step(params, state, hidden, key_t):
+        kr, ka, kf = jax.random.split(key_t, 3)
+        reset = state["done"]
+        state = venv.reset_done(state, kr)
+        hidden = jax.tree.map(lambda h: h * ~reset.reshape((-1,) + (1,) * (h.ndim - 1)), hidden)
+        active = state["active"]
+        B, P = active.shape
+        flat = lambda tree: jax.tree.map(  # noqa: E731
+            lambda x: x.reshape((B * P,) + x.shape[2:]), tree)
+        out = module.apply({"params": params}, flat(venv.observation(state)), flat(hidden))
+        hidden = jax.tree.map(
+            lambda h, nh: jnp.where(active.reshape((B, P) + (1,) * (h.ndim - 2)),
+                                    nh.reshape(h.shape), h), hidden, out["hidden"])
+        logits = out["policy"].astype(jnp.float32).reshape(B, P, -1)
+        masked = jnp.where(venv.legal_mask_all(state), logits, logits - device_rollout.ILLEGAL)
+        action = jnp.argmax(masked + jax.random.gumbel(ka, masked.shape), axis=-1).astype(jnp.int32)
+        prob = jnp.take_along_axis(jax.nn.softmax(masked, axis=-1), action[..., None], axis=-1)[..., 0]
+        record = {"active": active, "reset": reset, "action": action, "prob": prob,
+                  "value": out["value"].astype(jnp.float32).reshape(B, P)}
+        state = venv.step(state, action, kf)
+        record.update(done=state["done"], outcome=venv.outcome_scores(state))
+        return state, hidden, record
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("state_size", [16, 128], ids=["lines", "kernel"])
+def test_a_step_of_the_acting_rows_is_a_step_of_the_whole_tree(state_size):
+    """Float32, 4 lanes, a dispatch a step until a lane's game has ended,
+    begun again and both its players have moved: every dispatch's records at
+    the acting rows and the hidden tree it leaves equal the whole tree's
+    reset, apply and select on the same inputs: the row that does not act
+    bit for bit (zeros where the lane's game has just begun: both players
+    start from a zero state), the acting row to float32 rounding.  With
+    ``state_size`` 128 the SSM leaves go through ``ops/ssd.py``'s kernel (its
+    interpreter), with 16 through the lines round a gather."""
+    from benchmark import traffic
+    from handyrl_tpu.ops import ssd
+
+    _, _, env, module = _geister({"batch_size": 2, "burn_in_steps": 0, "forward_steps": 8},
+                                 state_size=state_size, mamba_heads=16, mamba_head_dim=8,
+                                 memory_len=8)
+    params, venv = traffic.seeded_params(module, env, 3), env.vector_env()
+    fn = build_streaming_fn(venv, module, 4, 1, use_observe_mask=False, counters=True)
+    whole = _whole_tree_step(module, venv)
+    state, hidden = venv.init(4, jax.random.PRNGKey(2)), module.initial_state((4, 2))
+    begun_lanes, moved_since = set(), {}
+    for i in range(260):
+        key = jax.random.fold_in(jax.random.PRNGKey(11), i)
+        want_state, want_hidden, want = jax.device_get(
+            whole(params, state, hidden, jax.random.split(key, 1)[0]))
+        state, hidden, records, counted = fn(params, state, hidden, key)    # donates both
+        got_hidden, got = jax.device_get((hidden, records))
+        assert counted[device_rollout.ROWS_APPLIED] == 4
+        acting, reset = want["active"], want["reset"]
+        assert (acting.sum(axis=1) == 1).all()
+        np.testing.assert_array_equal(got["active"][0], acting)
+        np.testing.assert_array_equal(got["action"][0][acting], want["action"][acting])
+        np.testing.assert_allclose(got["prob"][0][acting], want["prob"][acting], rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(got["value"][0][acting], want["value"][acting], rtol=1e-4, atol=1e-5)
+        np.testing.assert_array_equal(got["done"][0], want["done"])
+        np.testing.assert_array_equal(got["outcome"][0], want["outcome"])
+        for (path, leaf), ref in zip(jax.tree_util.tree_leaves_with_path(got_hidden),
+                                     jax.tree.leaves(want_hidden)):
+            np.testing.assert_array_equal(leaf[~acting], ref[~acting], err_msg=str(path))
+            np.testing.assert_allclose(leaf[acting], ref[acting], rtol=1e-4, atol=1e-5,
+                                       err_msg=str(path))
+            assert not leaf[~acting][reset].any(), path      # begun: the other player's row is zeros
+        for name, leaf in want_state.items():
+            np.testing.assert_array_equal(np.asarray(state[name]), leaf, err_msg=name)
+        for lane in np.flatnonzero(reset):
+            begun_lanes.add(int(lane))
+            moved_since[int(lane)] = set()
+        for lane in moved_since:
+            moved_since[lane].add(int(np.argmax(acting[lane])))
+        if any(len(moved) == 2 for moved in moved_since.values()) and i > 8:
+            break
+    assert begun_lanes and any(len(moved) == 2 for moved in moved_since.values()), (
+        "no game ended and began again inside 260 steps")
+    assert ssd.ROW_PATHS[("float32", 2, 16, 8, state_size, 1)]["path"] == (
+        "kernel" if state_size == 128 else "gather")
+
+
+def test_a_dispatch_of_many_steps_is_as_many_steps_of_the_whole_tree():
+    """The scan's carry: one dispatch of 12 steps (the SSM leaves through the
+    kernel's interpreter, stepped where the carry lies) leaves the records and
+    the hidden tree that 12 steps of the whole tree's reset, apply and select
+    leave on the same keys."""
+    from benchmark import traffic
+
+    _, _, env, module = _geister({"batch_size": 2, "burn_in_steps": 0, "forward_steps": 8},
+                                 state_size=128, mamba_heads=16, mamba_head_dim=8, memory_len=8)
+    params, venv = traffic.seeded_params(module, env, 3), env.vector_env()
+    many = build_streaming_fn(venv, module, 3, 12, use_observe_mask=False)
+    whole = _whole_tree_step(module, venv)
+    key = jax.random.PRNGKey(4)
+    state, hidden = venv.init(3, jax.random.PRNGKey(2)), module.initial_state((3, 2))
+    _, got_hidden, records = many(params, venv.init(3, jax.random.PRNGKey(2)),
+                                  module.initial_state((3, 2)), key)
+    for t, key_t in enumerate(jax.random.split(key, 12)):
+        state, hidden, want = whole(params, state, hidden, key_t)
+        acting = np.asarray(want["active"])
+        np.testing.assert_array_equal(records["action"][t][acting], want["action"][acting])
+        np.testing.assert_allclose(records["prob"][t][acting], want["prob"][acting],
+                                   rtol=1e-4, atol=1e-6)
+        np.testing.assert_array_equal(records["done"][t], want["done"])
+    for a, b in zip(jax.tree.leaves(got_hidden), jax.tree.leaves(hidden)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
 def test_a_net_without_hidden_keeps_its_program_and_its_options():
@@ -485,3 +625,11 @@ def test_the_actor_loop_ships_whole_dispatches_and_installs_polled_weights_in_pa
     assert all(w == {"parameters": size, "bytes": 2 * size, "dtype": "bfloat16"} for w in weights)
     counted = [r["attrs"] for r in records if r["name"] == "actor.counters"]
     assert all(c["buffer_slots"] > c["rows_held"] > 0 for c in counted)
+    # ``observation: false`` on Geister: a step is the 4 acting rows' of 8
+    assert all(c["rows_applied"] == 8 * 4 for c in counted)
+    # and, once, how the acting rows of the SSM states are stepped: the tiny
+    # state (16 wide) keeps the lines round a gather
+    paths = [r["attrs"] for r in records if r["name"] == "model.ssd_rows_path"]
+    assert [p["path"] for p in paths if p["state_size"] == 16 and p["heads"] == 4
+            and p["head_dim"] == 16] == ["gather"]
+    assert len(paths) == len({tuple(sorted(p.items())) for p in paths})
