@@ -1,9 +1,13 @@
-"""Grouped matrix products over a row buffer in blocks of ``BLOCK`` rows:
+"""Grouped matrix products over a row buffer in blocks of equal height:
 each block's rows are multiplied by the weights of the one group (expert)
 the block belongs to, read where they lie.
 
 ``owner`` (blocks,) int32 names each block's group and is non-decreasing:
-a group's blocks are consecutive.  It is handed to the kernels as a
+a group's blocks are consecutive.  A block's height is the buffer's rows
+over ``owner``'s length, the caller's to choose: ``BLOCK`` where a group's
+rows fill the MXU's tile, ``FEW_ROWS`` where a group gets a handful and a
+taller block would be padding that is written, read and multiplied
+(``ops/routed_experts.py`` ``block_rows``).  It is handed to the kernels as a
 prefetched scalar array that the weight operand's index map reads, so no
 block's weights are copied out.  Every block is computed, whatever it holds:
 the work is the buffer's, not the routing's.
@@ -33,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 BLOCK = 128                 # rows of a block: the MXU's tile
+FEW_ROWS = 16               # the least a block can be: a bfloat16 tile's sublanes
 TILE = 4096                 # most columns of a weight tile held in VMEM
 _VMEM_LIMIT = 64 << 20      # over the compiler's default scope: a weight tile is double-buffered
 
@@ -77,16 +82,16 @@ def _rows_times(x, w, owner, transposed: bool, out_dtype, interpret: bool):
     from jax.experimental import pallas as pl
 
     (m, k), n = x.shape, w.shape[1 if transposed else 2]
-    tn = _tile(n)
+    tn, rows = _tile(n), m // owner.size
     if transposed:
         w_spec = pl.BlockSpec((None, tn, k), lambda j, b, owner: (owner[b], j, 0))
     else:
         w_spec = pl.BlockSpec((None, k, tn), lambda j, b, owner: (owner[b], 0, j))
     return _call(
         functools.partial(_rows_kernel, transposed=transposed), (owner,),
-        (pl.cdiv(n, tn), m // BLOCK),      # row blocks innermost: a weight tile stays
-        [pl.BlockSpec((BLOCK, k), lambda j, b, owner: (b, 0)), w_spec],
-        pl.BlockSpec((BLOCK, tn), lambda j, b, owner: (b, j)),
+        (pl.cdiv(n, tn), owner.size),      # row blocks innermost: a weight tile stays
+        [pl.BlockSpec((rows, k), lambda j, b, owner: (b, 0)), w_spec],
+        pl.BlockSpec((rows, tn), lambda j, b, owner: (b, j)),
         jax.ShapeDtypeStruct((m, n), out_dtype), [], interpret, x, w)
 
 
@@ -126,15 +131,15 @@ def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (m, k), n, blocks = x.shape, dy.shape[1], x.shape[0] // BLOCK
-    tk, tn = _tile(k), _tile(n)
+    (m, k), n, blocks = x.shape, dy.shape[1], owner.size
+    tk, tn, rows = _tile(k), _tile(n), m // blocks
     group = jnp.sort(jnp.concatenate([owner, jnp.arange(groups, dtype=owner.dtype)]))
     block = jnp.minimum(jnp.arange(blocks + groups, dtype=owner.dtype) - group, blocks - 1)
     return _call(
         _sums_kernel, (group, block),
         (pl.cdiv(k, tk), pl.cdiv(n, tn), blocks + groups),
-        [pl.BlockSpec((BLOCK, tk), lambda i, j, s, group, block: (block[s], i)),
-         pl.BlockSpec((BLOCK, tn), lambda i, j, s, group, block: (block[s], j))],
+        [pl.BlockSpec((rows, tk), lambda i, j, s, group, block: (block[s], i)),
+         pl.BlockSpec((rows, tn), lambda i, j, s, group, block: (block[s], j))],
         pl.BlockSpec((None, tk, tn), lambda i, j, s, group, block: (group[s], i, j)),
         jax.ShapeDtypeStruct((groups, k, n), out_dtype),
         [pltpu.VMEM((tk, tn), jnp.float32)], interpret, x, dy)
@@ -142,9 +147,9 @@ def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def grouped_dot(x, w, owner, interpret: Optional[bool] = None):
-    """x (m, k) in blocks of ``BLOCK`` rows, w (groups, k, n), owner
-    (m / BLOCK,) int32 non-decreasing -> (m, n) float32: each block's rows
-    times its group's weights."""
+    """x (m, k) in ``owner.size`` blocks of equal height, w (groups, k, n),
+    owner (blocks,) int32 non-decreasing -> (m, n) float32: each block's
+    rows times its group's weights."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _rows_times(x, w, owner, False, jnp.float32, interpret)

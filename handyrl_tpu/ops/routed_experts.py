@@ -9,8 +9,13 @@ chip it runs without the exchange; nothing stands in for the absent chips.
 
 No token is dropped at any imbalance.  The (token, choice) pairs that fall
 on held experts are sorted by expert into a row buffer in which every
-expert's rows start on a block of ``BLOCK`` rows, so a block belongs to one
-expert: the two products are grouped products over the blocks, each block
+expert's rows start on a block, so a block belongs to one expert.  A block's
+height follows the rows an expert gets (``block_rows``: ``BLOCK`` rows where
+a uniform router fills one, ``FEW_ROWS`` where it gives an expert a handful,
+as a rollout step of a few dozen rows does: the buffer is written, read and
+multiplied whole, and at 128 rows a held expert's block of padding was 97%
+of it, PERF.md, PR 46).  The two products are grouped products over the
+blocks, each block
 against its expert's weights read where they lie (``ops/grouped_product.py``:
 a Pallas kernel whose weight operand is indexed by the block's expert; its
 transpose sums the blocks' weight gradients once an expert).  That kernel
@@ -39,7 +44,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .grouped_product import BLOCK, grouped_dot     # BLOCK: rows that share one expert's weights
+from .grouped_product import BLOCK, FEW_ROWS, grouped_dot
 
 # uniform router's shares of rows the buffer holds.  At 2 the cell's routers,
 # drawn from the run's seed, outgrow the buffer in 2 of 14 seeds (counted on
@@ -105,13 +110,24 @@ def _combine_bwd(tok, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def row_buffer(n: int, top_k: int, held: int, experts: int) -> Tuple[int, int]:
+def block_rows(n: int, top_k: int, experts: int, dtype) -> int:
+    """Rows of a block, the rows that share one expert's weights, for ``n``
+    tokens of ``dtype``: ``FEW_ROWS`` where a uniform router gives an expert
+    fewer than that and the products are the kernel's (bfloat16), else
+    ``BLOCK``.  The float32 block products copy a block's weights out
+    (``w[owner]``), so more and lower blocks would cost them memory."""
+    few = dtype == jnp.bfloat16 and n * top_k < FEW_ROWS * experts
+    return FEW_ROWS if few else BLOCK
+
+
+def row_buffer(n: int, top_k: int, held: int, experts: int, block: int) -> Tuple[int, int]:
     """(blocks of the buffer, passes that cover the worst case) for ``n``
-    tokens: ``SHARES`` times a uniform router's share of rows and a block of
-    padding for each held expert, and at most the worst case (every token
-    choosing ``min(top_k, held)`` held experts) with its padding."""
-    worst = -(-n * min(top_k, held) // BLOCK) + held
-    blocks = min(worst, math.ceil(SHARES * n * top_k * held / (experts * BLOCK)) + held)
+    tokens in blocks of ``block`` rows: ``SHARES`` times a uniform router's
+    share of rows and a block of padding for each held expert, and at most
+    the worst case (every token choosing ``min(top_k, held)`` held experts)
+    with its padding."""
+    worst = -(-n * min(top_k, held) // block) + held
+    blocks = min(worst, math.ceil(SHARES * n * top_k * held / (experts * block)) + held)
     return blocks, -(-worst // blocks)
 
 
@@ -128,6 +144,7 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
     took them, ``slots`` () int32 the buffer slots those passes computed)."""
     n, k = chosen.shape
     held = w1.shape[0]
+    block = block_rows(n, k, experts, h.dtype)
     local = chosen - offset
     live = (local >= 0) & (local < held) & valid[:, None]
     with jax.named_scope("route"):
@@ -136,27 +153,28 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
         rank = jnp.argsort(order).astype(jnp.int32)                # pair -> sorted row
         rows = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0).astype(jnp.int32)
         first = jnp.cumsum(rows) - rows                            # an expert's first sorted row
-        padded = -(-rows // BLOCK) * BLOCK
+        padded = -(-rows // block) * block
         ends = jnp.cumsum(padded)                                  # past an expert's last slot
         base = jnp.append(ends - padded, 0)                        # an expert's first slot
         slot = (base[key] + rank - jnp.append(first, 0)[key]).reshape(n, k)   # pair -> slot
     route = {"order": order, "slot": slot, "live": live, "rows": rows, "first": first,
              "ends": ends, "base": base}
-    blocks = row_buffer(n, k, held, experts)[0]
-    passes = _needed(route, blocks)
-    return (_passes(h, gates, w1, w2, route, blocks, gated),
-            {"rows": rows, "passes": passes, "slots": passes * (blocks * BLOCK)})
+    blocks = row_buffer(n, k, held, experts, block)[0]
+    passes = _needed(route, blocks * block)
+    return (_passes(h, gates, w1, w2, route, blocks, block, gated),
+            {"rows": rows, "passes": passes, "slots": passes * (blocks * block)})
 
 
 def _block_products(x, w1, w2, owner, gated: bool = False):
-    """x (m, d) in blocks of ``BLOCK`` rows, block ``b`` of expert
-    ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block, ``gated``
-    w2[e] (silu(a) b) with [a, b] = w1[e] x."""
+    """x (m, d) in ``owner.size`` blocks of equal height, block ``b`` of
+    expert ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block,
+    ``gated`` w2[e] (silu(a) b) with [a, b] = w1[e] x."""
     if x.dtype == jnp.bfloat16:
         product = lambda rows, w: grouped_dot(rows, w, owner)   # noqa: E731
     else:   # at the caller's matmul precision, which a kernel's dots would not see
         def product(rows, w):
-            return jnp.einsum("brk,bkn->brn", rows.reshape(owner.size, BLOCK, -1), w[owner],
+            blocks = rows.reshape(owner.size, -1, rows.shape[1])
+            return jnp.einsum("brk,bkn->brn", blocks, w[owner],
                               preferred_element_type=jnp.float32).reshape(rows.shape[0], -1)
     up = product(x, w1)
     if gated:
@@ -167,24 +185,24 @@ def _block_products(x, w1, w2, owner, gated: bool = False):
     return product(act, w2)
 
 
-def _owners(ends, start, blocks: int):
+def _owners(ends, start, blocks: int, block: int):
     """owner (blocks,) int32, non-decreasing: the expert of each block of the
-    buffer slots [start, start + blocks x BLOCK).  Every block has one, so
+    buffer slots [start, start + blocks x block).  Every block has one, so
     that the grouped products' work is the buffer's: the blocks past the last
     row are the last expert's (no row of his reaches them: their slots have
     gate 0)."""
-    owner = jnp.searchsorted(ends, start + BLOCK * jnp.arange(blocks), side="right")
+    owner = jnp.searchsorted(ends, start + block * jnp.arange(blocks), side="right")
     return jnp.minimum(owner, ends.size - 1).astype(jnp.int32)
 
 
-def _one_pass(h, gates, w1, w2, route, start, blocks: int, gated: bool = False):
-    """Slots [start, start + blocks x BLOCK) of the row buffer ``route`` lays out."""
+def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bool = False):
+    """Slots [start, start + blocks x block) of the row buffer ``route`` lays out."""
     n, k = route["slot"].shape
-    m = blocks * BLOCK
+    m = blocks * block
     rows, first, ends, base = (route[key] for key in ("rows", "first", "ends", "base"))
     with jax.named_scope("route"):
-        owner = _owners(ends, start, blocks)
-        expert = jnp.repeat(owner, BLOCK)
+        owner = _owners(ends, start, blocks, block)
+        expert = jnp.repeat(owner, block)
         index = start + jnp.arange(m) - base[expert]                # a slot's row of its expert
         used = index < rows[expert]
         pair = route["order"][jnp.clip(first[expert] + index, 0, n * k - 1)]
@@ -201,14 +219,14 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int, gated: bool = False):
         return _combine(weighted, tok, at, in_buffer)
 
 
-def _needed(route, blocks: int):
-    """Passes over the buffer that the rows laid out take: one, but for an
-    update whose rows outgrow it."""
-    return jnp.maximum(1, -(-route["ends"][-1] // (blocks * BLOCK)))
+def _needed(route, slots: int):
+    """Passes over a buffer of ``slots`` that the rows laid out take: one,
+    but for an update whose rows outgrow it."""
+    return jnp.maximum(1, -(-route["ends"][-1] // slots))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _passes(h, gates, w1, w2, route, blocks: int, gated: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _passes(h, gates, w1, w2, route, blocks: int, block: int, gated: bool = False):
     """Every pass the rows need, in a loop whose trip count is the update's
     own (``lax.while_loop``, differentiated by hand below): the usual update
     runs one pass and holds one pass's buffers, and the worst case, every
@@ -220,30 +238,31 @@ def _passes(h, gates, w1, w2, route, blocks: int, gated: bool = False):
     def one_more(carry):
         done, out = carry
         return done + 1, out + _one_pass(
-            h, gates, w1, w2, route, done * blocks * BLOCK, blocks, gated)
+            h, gates, w1, w2, route, done * blocks * block, blocks, block, gated)
 
     return jax.lax.while_loop(
-        lambda carry: carry[0] < _needed(route, blocks), one_more,
+        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
         (jnp.int32(0), jnp.zeros_like(h)))[1]
 
 
-def _passes_fwd(h, gates, w1, w2, route, blocks, gated):
-    return _passes(h, gates, w1, w2, route, blocks, gated), (h, gates, w1, w2, route)
+def _passes_fwd(h, gates, w1, w2, route, blocks, block, gated):
+    return _passes(h, gates, w1, w2, route, blocks, block, gated), (h, gates, w1, w2, route)
 
 
-def _passes_bwd(blocks, gated, saved, d_out):
+def _passes_bwd(blocks, block, gated, saved, d_out):
     h, gates, w1, w2, route = saved
 
     def one_more(carry):
         done, sums = carry
         _, pull = jax.vjp(
-            lambda *a: _one_pass(*a, route, done * blocks * BLOCK, blocks, gated),
+            lambda *a: _one_pass(*a, route, done * blocks * block, blocks, block, gated),
             h, gates, w1, w2)
         return done + 1, jax.tree.map(jnp.add, sums, pull(d_out))
 
     zeros = jax.tree.map(jnp.zeros_like, (h, gates, w1, w2))
     sums = jax.lax.while_loop(
-        lambda carry: carry[0] < _needed(route, blocks), one_more, (jnp.int32(0), zeros))[1]
+        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
+        (jnp.int32(0), zeros))[1]
     return (*sums, None)
 
 

@@ -3,13 +3,14 @@
 Usage::
 
     python scripts/scope_split.py benchmark_out/<cell>/profile/trace.xplane.pb \
-        attn gqa rope [-o split.json]
+        attn gqa rope [-o split.json] [--program jit_device_rollout]
 
 For each scope (a ``jax.named_scope`` that is a component of an op's
 ``op_name``, as ``benchmark/trace_reduce.py`` ``scopes_of`` reads it) and for
 ``outside`` (ops under none of the scopes given): the self time of the
 device's ops inside the traced window, in milliseconds a run of the train
-program (``jit__step``), summed by the op's kind (a fusion by its name's
+program (``jit__step``; ``--program`` names another, as the actor cell's
+``jit_device_rollout``), summed by the op's kind (a fusion by its name's
 stem: ``copy_dynamic-update-slice_fusion``) and, in the JSON, by phase
 (``bwd``: the ``op_name`` holds ``transpose(``, which the replay of a
 checkpoint does too) and result shape.  What ``attn_step_share``'s notes
@@ -34,7 +35,7 @@ PROGRAM = "jit__step"
 WINDOW = ("bench.window_begin", "bench.window_end")     # benchmark/harness.py's markers
 
 
-def split(path, scopes):
+def split(path, scopes, program=PROGRAM):
     """{"runs", "ms_per_run": {scope: {op kind: [ms, count]}}, "rows": [[ms,
     count, scope, phase, kind, shape], ...] by falling ms}."""
     trace = trace_reduce.load_xplane(path, scopes=scopes)
@@ -42,7 +43,7 @@ def split(path, scopes):
     lo, hi = (begin[0][2], end[-1][1]) if begin and end else (0.0, float("inf"))
     device = trace["devices"][min(trace["devices"])]
     ops, names = device["ops"], device["op_names"]
-    runs = sum(PROGRAM in name and min(e, hi) > max(s, lo)
+    runs = sum(program in name and min(e, hi) > max(s, lo)
                for name, s, e in device["modules"]) or 1
     order, self_s, _ = trace_reduce.self_times(ops)
     table = {}
@@ -75,8 +76,9 @@ def main(argv=None):
     parser.add_argument("xplane")
     parser.add_argument("scopes", nargs="+")
     parser.add_argument("-o", "--out", help="write the whole split here as JSON")
+    parser.add_argument("--program", default=PROGRAM, help="the program whose runs divide the times")
     args = parser.parse_args(argv)
-    result = split(args.xplane, args.scopes)
+    result = split(args.xplane, args.scopes, args.program)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(result, f)

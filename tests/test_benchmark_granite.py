@@ -10,15 +10,75 @@ which the cell and the five metrics PR 44 appended end; a ``model_config`` PR
 may not edit a file the benchmark has, so tests/test_benchmark_hybrid.py,
 test_benchmark_ouro.py and test_benchmark_rehearsals.py drop the three and
 these ask what they meant (PERF.md section 7 leaves the edit to a
-``benchmark`` PR)."""
+``benchmark`` PR).
+
+A fourth is restated since PR 46: ``test_the_actor_cell_rehearses_on_cpu``
+holds a traced rehearsal's ``counter_buffer_slots`` to be at least a block of
+128 rows a routed layer and step, and the tiny cell's 12 pairs a step over 8
+experts now lie in blocks of 16 (``ops/routed_experts.py`` ``block_rows``):
+the case below is the benchmark's own, line for line, but for that count,
+which it holds to the program's own ``row_buffer``."""
 
 import json
 import os
 
+import pytest
+
 from benchmark.tests.test_granite_rehearsal import *  # noqa: F401,F403
-from benchmark.tests.test_granite_rehearsal import BENCH, REPO, rehearsal
+from benchmark.tests.test_granite_rehearsal import BENCH, CELLS, REPO, _run, rehearsal
 
 ROUTED, LOOPED = "nemotron_twotower_train_t192", "ouro_train_t192"
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_the_actor_cell_rehearses_on_cpu(root, trace):  # noqa: F811  (the benchmark's, restated)
+    import jax.numpy as jnp
+
+    from handyrl_tpu.ops.routed_experts import block_rows, row_buffer
+
+    proc = _run(root, "tiny_granite_actor", trace)
+    assert proc.returncode == 4, proc.stderr[-4000:]
+    lines = proc.stdout.strip().splitlines()
+    last, earlier = json.loads(lines[-1]), json.loads(lines[-2])
+    assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    assert last["correct"] is False and last["metrics"] == {}
+    assert last["attempted"] > 0 and last["failed"] == 0
+    # the replay against the window, then the three limits of a routed net
+    compared = last["compared"]
+    assert {"replay_prob", "replay_value", "policy", "value", "return", "choices_agreement",
+            "f32_policy", "f32_value", "f32_return"} <= set(compared)
+    for name in ("choices_agreement", "f32_choices_agreement"):     # higher is better
+        agreement, floor = compared.pop(name)
+        assert agreement >= floor
+    assert all(number <= limit for number, limit in compared.values()), compared
+    checks = earlier["checks"]
+    assert checks.pop("device_is_tpu") is False
+    checks.pop("device_ran", None)       # a CPU trace has no device plane
+    assert all(checks.values()), (checks, earlier["notes"])
+    assert checks["replay_matches_window"] and checks["matches_reference"] \
+        and checks["choices_agree"] and checks["matches_reference_f32"] \
+        and checks["no_compile_in_window"] and checks["actor_loop_ended"]
+    # whole dispatches of lanes x k, through the gateway
+    counters, cell = earlier["counters"], CELLS["tiny_granite_actor"]["train_args"]
+    lanes, k = cell["device_rollout_games"], cell["device_replay_k_steps"]
+    assert counters["game_steps"] == counters["dispatches"] * lanes * k > 0
+    assert counters["dispatches_before_window"] >= 3
+    assert earlier["notes"]["param_dtypes"] == ["bfloat16"]
+    assert earlier["notes"]["judged_games"]["observed_steps"] > 4
+    answered = set(earlier["notes"]["metrics_answered"])
+    assert answered >= set(CELLS["tiny_granite_actor"]["answers"]["traced" if trace else "untraced"])
+    if trace:
+        # what the step mode counted reached the run: three routed layers, every
+        # row the rollout applies the net to (the acting player's of each lane),
+        # top-3 of 8 with 4 held, one row buffer a layer and step
+        assert 0 < counters["counter_rows_held"] <= 3 * 3 * lanes * 2 * k
+        block = block_rows(lanes, 3, 8, jnp.bfloat16)
+        slots = row_buffer(lanes, 3, 4, 8, block)[0] * block
+        assert counters["counter_buffer_slots"] == 3 * k * slots == 3 * k * 5 * 16
+        # no device plane, no program, no scope: those readers leave their metrics out
+        assert not answered & {"rollout_roofline_share", "rollout_experts_share",
+                               "rollout_state_share", "rollout_device_share",
+                               "rollout_ms_per_dispatch", "rollout_env_share"}
 
 
 def _spec():

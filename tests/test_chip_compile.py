@@ -127,7 +127,7 @@ def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypa
     ``experts`` scope the benchmark times it under, and no block's weights
     are copied out (no (blocks, d, width) operand, no exact one-hot pick)."""
     from benchmark import trace_reduce
-    from handyrl_tpu.ops.routed_experts import BLOCK, EXPERTS_SCOPE, held_mix, row_buffer
+    from handyrl_tpu.ops.routed_experts import EXPERTS_SCOPE, block_rows, held_mix, row_buffer
 
     d, width, held, experts, k = (_EXPERTS[key] for key in ("d", "width", "held", "experts", "top_k"))
     # the auto-pick would see this process's CPU backend and hand over the interpreter
@@ -150,11 +150,44 @@ def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypa
     assert len(calls) == 2 + 6, len(calls)
     names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
     assert all(trace_reduce.scopes_of(n, [EXPERTS_SCOPE]) == [EXPERTS_SCOPE] for n in names), names
-    blocks = row_buffer(tokens, k, held, experts)[0]
-    assert blocks == {6144: 53, 512: 12, 2: 9}[tokens] and BLOCK == 128
+    # the window's parts in the MXU's tile; 12 pairs over 128 experts in the least a block can be
+    block = block_rows(tokens, k, experts, jnp.bfloat16)
+    blocks = row_buffer(tokens, k, held, experts, block)[0]
+    assert (blocks, block) == {6144: (53, 128), 512: (12, 128), 2: (9, 16)}[tokens]
+    assert "[%d,%d]" % (blocks * block, d) in text
     for copied in ("[%d,%d,%d]" % (blocks, d, width), "[%d,%d,%d]" % (blocks, width, d)):
         assert copied not in text
     assert "precision_config" not in text or "HIGHEST" not in text
+
+
+def test_acting_rows_routed_experts_compile_for_v5e_in_blocks_of_sixteen(v5e, monkeypatch):
+    """``held_mix`` as ``granite_actor_b32``'s rollout step calls it (32
+    acting rows x 4,096 in bfloat16, top-10 of 72 with 36 held, ``gated``
+    experts of 768): ~4.5 rows a held expert, so the row buffer lies in
+    blocks of 16, 56 of them = 896 slots, where blocks of 128 made it 39 x
+    128 = 4,992 (PERF.md, PR 46).  Both products are the kernel, under the
+    ``experts`` scope, and nothing in the program has 4,992 rows."""
+    from benchmark import trace_reduce
+    from handyrl_tpu.ops.routed_experts import EXPERTS_SCOPE, block_rows, held_mix, row_buffer
+
+    tokens, d, width, held, experts, k = 32, 4096, 768, 36, 72, 10
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+    text = jax.jit(lambda h, gates, w1, w2, chosen, valid: held_mix(
+        h, chosen, gates, valid, w1, w2, 0, experts, True)).lower(
+        aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
+        aval((held, d, 2 * width), jnp.bfloat16), aval((held, width, d), jnp.bfloat16),
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2, len(calls)
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert all(trace_reduce.scopes_of(n, [EXPERTS_SCOPE]) == [EXPERTS_SCOPE] for n in names), names
+    block = block_rows(tokens, k, experts, jnp.bfloat16)
+    blocks, passes = row_buffer(tokens, k, held, experts, block)
+    assert (block, blocks, passes) == (16, 56, 1)      # one pass covers every pair on held experts
+    assert "f32[896,%d]" % (2 * width) in text and "f32[896,%d]" % d in text
+    assert "[4992," not in text and "[%d,%d,%d]" % (blocks, d, 2 * width) not in text
 
 
 # -- a window part's attention core (ops/attention_core.py) ------------------
@@ -431,6 +464,8 @@ def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v
     compiled = fn.lower(params, vstate, hidden, key).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # ~4.5 rows a held expert: row buffers of 56 blocks of 16, not 39 of 128 (PR 46)
+    assert "[896,4096]" in text and "[4992," not in text
     memory = compiled.memory_analysis()
     held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
             + memory.temp_size_in_bytes - memory.alias_size_in_bytes)

@@ -21,9 +21,10 @@ from handyrl_tpu.config import normalize_args
 from handyrl_tpu.envs import make_env
 from handyrl_tpu.models import HybridNet
 from handyrl_tpu.models.hybrid import ExpertLayer
-from handyrl_tpu.ops.grouped_product import grouped_dot
+from handyrl_tpu.ops import routed_experts
+from handyrl_tpu.ops.grouped_product import BLOCK, FEW_ROWS, grouped_dot
 from handyrl_tpu.ops.routed_experts import (
-    BLOCK, EXPERTS_SCOPE, SHARES, _owners, choose, held_mix, row_buffer)
+    EXPERTS_SCOPE, SHARES, _owners, block_rows, choose, held_mix, row_buffer)
 from handyrl_tpu.ops.ssd import ssd_chunked, ssd_step
 from handyrl_tpu.parallel import TrainContext, make_mesh
 from handyrl_tpu.parallel.train_step import (
@@ -537,7 +538,7 @@ def test_one_expert_given_every_token_drops_none(tokens):
     chosen = jnp.tile(jnp.asarray([[9, 10]], jnp.int32), (tokens, 1))
     gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
     valid = jnp.asarray(rng.rand(tokens) > 0.1)
-    blocks, passes = row_buffer(tokens, k, held, experts)
+    blocks, passes = row_buffer(tokens, k, held, experts, BLOCK)
     assert (passes > 1 and blocks * BLOCK < int(valid.sum()) * k) == (tokens > 500)
     out, counts = jax.jit(lambda *a: held_mix(*a, 8, experts))(h, chosen, gates, valid, w1, w2)
     assert counts["rows"].tolist() == [0, int(valid.sum()), int(valid.sum()), 0]
@@ -626,7 +627,8 @@ def test_held_mix_is_the_loop_over_experts_and_so_are_its_gradients(dtype, rows_
     and ``w2``, at an expert width that is no multiple of 128."""
     rng = np.random.RandomState(7)
     tokens, d, width, held, experts, k, offset = 640, 32, 192, 4, 32, 2, 8
-    blocks, _ = row_buffer(tokens, k, held, experts)
+    assert block_rows(tokens, k, experts, dtype) == BLOCK   # 40 rows an expert: the MXU's tile
+    blocks, _ = row_buffer(tokens, k, held, experts, BLOCK)
     assert blocks == math.ceil(SHARES * tokens * k * held / (experts * BLOCK)) + held == 8
     h = jnp.asarray(rng.randn(tokens, d), dtype)
     w1 = jnp.asarray(rng.randn(held, d, width) / 4, dtype)
@@ -670,13 +672,13 @@ def test_the_work_is_the_buffers_whatever_the_routing():
     gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
     valid = jnp.ones(tokens, bool)
     mix = jax.jit(lambda *a: held_mix(*a, offset, experts))
-    blocks, texts, counted = row_buffer(tokens, k, held, experts)[0], [], []
+    blocks, texts, counted = row_buffer(tokens, k, held, experts, BLOCK)[0], [], []
     for rows_of in ((100, 100, 100, 100), (0, 3, 500, 129)):
         chosen = _routing(rng, tokens, rows_of, held, offset, k)
         texts.append(mix.lower(h, chosen, gates, valid, w1, w2).as_text())
         counted.append(jax.device_get(mix(h, chosen, gates, valid, w1, w2)[1]))
         padded = -(-np.asarray(rows_of) // BLOCK) * BLOCK
-        owner = np.asarray(_owners(jnp.cumsum(jnp.asarray(padded)), 0, blocks))
+        owner = np.asarray(_owners(jnp.cumsum(jnp.asarray(padded)), 0, blocks, BLOCK))
         sizes = np.bincount(owner, minlength=held)
         assert sizes.sum() == blocks and (np.diff(owner) >= 0).all()
         # each expert has its padded rows' blocks, the last one the unfilled ones too
@@ -685,6 +687,116 @@ def test_the_work_is_the_buffers_whatever_the_routing():
     assert counted[0]["slots"] == counted[1]["slots"] == blocks * BLOCK
     assert counted[0]["passes"] == counted[1]["passes"] == 1
     assert counted[0]["rows"].sum() == 400 and counted[1]["rows"].sum() == 632
+
+
+# the acting cell's shape cut down (granite_actor_b32: 32 rows a step, top-10 of 72, 36 held)
+_FEW = dict(tokens=32, d=64, width=24, held=36, experts=72, k=10)
+
+
+def _few_rows_routing(case):
+    """chosen (32, 10) over 72 experts of which the first 36 are held."""
+    rng, tokens, held, experts, k = np.random.RandomState(13), *(
+        _FEW[key] for key in ("tokens", "held", "experts", "k"))
+    if case == "cell":              # a router of the cell's kind: any ten of 72 a token
+        picks = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
+    elif case == "one_expert":      # every pair on one held expert
+        picks = np.full((tokens, k), 7)
+    elif case == "all_held":        # every pair on held experts: the worst case
+        picks = np.stack([rng.permutation(held)[:k] for _ in range(tokens)])
+    else:                           # "none_held"
+        picks = np.stack([held + rng.permutation(experts - held)[:k] for _ in range(tokens)])
+    return jnp.asarray(picks, jnp.int32)
+
+
+FEW_ROWS_CASES = ["cell", "one_expert", "all_held", "none_held"]
+
+
+@pytest.mark.parametrize("tokens,k,experts,held,dtype,block,blocks", [
+    (32, 10, 72, 36, jnp.bfloat16, 16, 56),      # granite_actor_b32's window: 896 slots, not 4,992
+    (64, 10, 72, 36, jnp.bfloat16, 16, 76),      # its replay
+    (32, 10, 72, 36, jnp.float32, 128, 39),      # float32 products copy a block's weights out
+    (128, 10, 72, 36, jnp.bfloat16, 128, 46),    # 17.8 rows an expert: the MXU's tile
+    (6144, 6, 128, 8, jnp.bfloat16, 128, 53),    # nemotron_twotower_train_t192's two parts
+    (512, 6, 128, 8, jnp.bfloat16, 128, 12),
+    (2, 6, 128, 8, jnp.bfloat16, 16, 9),
+])
+def test_a_blocks_height_follows_the_rows_an_expert_gets(tokens, k, experts, held, dtype, block,
+                                                         blocks):
+    """``block_rows`` reads shapes and dtype alone: 16 rows where a uniform
+    router gives an expert fewer and the products are the kernel's, else 128;
+    ``row_buffer`` counts its blocks in that height, and one pass of it
+    covers every pair on held experts where the blocks are low."""
+    assert (FEW_ROWS, BLOCK) == (16, 128)
+    assert block_rows(tokens, k, experts, dtype) == block
+    got, passes = row_buffer(tokens, k, held, experts, block)
+    assert got == blocks
+    assert passes * got * block >= tokens * min(k, held) and (passes == 1 or block == BLOCK)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", FEW_ROWS_CASES)
+def test_held_mix_in_blocks_of_sixteen_is_held_mix_in_blocks_of_128(monkeypatch, case, dtype):
+    """The layout moves no number: a row's products, its ``silu(a) b`` and a
+    token's sum choice by choice are the same whichever slot the row lies
+    in, forward and for every gradient: bit for bit in bfloat16 (the kernel
+    in the interpreter), to float32's last digits through the block products
+    (the CPU's ``dot`` sums a row in another order at another height); and
+    ``counts["slots"]`` is the passes x blocks x rows of a block."""
+    rng = np.random.RandomState(17)
+    tokens, d, width, held, experts, k = (
+        _FEW[key] for key in ("tokens", "d", "width", "held", "experts", "k"))
+    h = jnp.asarray(rng.randn(tokens, d), dtype)
+    w1 = jnp.asarray(rng.randn(held, d, 2 * width) / 8, dtype)
+    w2 = jnp.asarray(rng.randn(held, width, d) / 5, dtype)
+    gates = jnp.asarray(rng.rand(tokens, k), jnp.float32)
+    valid = jnp.asarray(np.arange(tokens) != 5)
+    chosen = _few_rows_routing(case)
+    weigh = jnp.asarray(rng.randn(tokens, d), jnp.float32)
+    live = int(((np.asarray(chosen) < held) & np.asarray(valid)[:, None]).sum())
+
+    def both(block):
+        monkeypatch.setattr(routed_experts, "block_rows", lambda *a: block)
+        mix = lambda h, g, a, b: held_mix(h, chosen, g, valid, a, b, 0, experts, True)  # noqa: E731
+        out, counts = jax.jit(mix)(h, gates, w1, w2)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(mix(*a)[0].astype(jnp.float32) * weigh), argnums=(0, 1, 2, 3)))(
+                h, gates, w1, w2)
+        blocks, passes = row_buffer(tokens, k, held, experts, block)
+        assert int(counts["rows"].sum()) == live
+        assert int(counts["slots"]) == int(counts["passes"]) * blocks * block
+        return out, grads, int(counts["passes"]), passes
+
+    low, low_grads, low_passes, covers = both(FEW_ROWS)
+    tall, tall_grads, _, _ = both(BLOCK)
+    assert covers == 1 and low_passes == 1     # 56 blocks of 16 hold 320 rows on any 36 experts
+    assert low.dtype == dtype and (np.asarray(low, np.float32).any() == (case != "none_held"))
+    for name, a, b in zip(("out", "h", "gates", "w1", "w2"), (low, *low_grads), (tall, *tall_grads)):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        tol = 0.0 if dtype == jnp.bfloat16 else 2e-6 * max(1.0, float(np.abs(b).max()))
+        np.testing.assert_allclose(a, b, rtol=0, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("case", FEW_ROWS_CASES)
+def test_a_block_of_sixteen_belongs_to_one_expert(case):
+    """``_owners`` at 16 rows a block: non-decreasing, every block has an
+    owner, and each held expert's rows lie in blocks that are his alone."""
+    tokens, held, experts, k = (_FEW[key] for key in ("tokens", "held", "experts", "k"))
+    chosen = np.asarray(_few_rows_routing(case))
+    rows = np.bincount(chosen[chosen < held], minlength=held)
+    padded = -(-rows // FEW_ROWS) * FEW_ROWS
+    blocks = row_buffer(tokens, k, held, experts, FEW_ROWS)[0]
+    assert padded.sum() <= blocks * FEW_ROWS       # one pass
+    owner = np.asarray(_owners(jnp.cumsum(jnp.asarray(padded)), 0, blocks, FEW_ROWS))
+    assert owner.shape == (blocks,) and (np.diff(owner) >= 0).all()
+    assert owner.min() >= 0 and owner.max() < held
+    base = np.cumsum(padded) - padded
+    for e in np.flatnonzero(rows):
+        mine = np.arange(base[e] // FEW_ROWS, (base[e] + padded[e]) // FEW_ROWS)
+        assert (owner[mine] == e).all()
+        assert (np.flatnonzero(owner == e)[:mine.size] == mine).all()   # and no block before them
+    # an expert with no row has no block, but the last, who owns what no row fills
+    assert not np.isin(owner, np.flatnonzero(rows[:-1] == 0)).any()
 
 
 def test_the_net_counts_its_buffers_slots_and_the_passes_past_the_first():
@@ -704,7 +816,7 @@ def test_the_net_counts_its_buffers_slots_and_the_passes_past_the_first():
         return jax.device_get(module.apply(
             {"params": p}, obs, None, seq=True, key_mask=seen, burn_in=8)["counters"])
 
-    sizes = [row_buffer(n, 2, 4, 32)[0] * BLOCK for n in (6 * 8, 6 * 100)]
+    sizes = [row_buffer(n, 2, 4, 32, BLOCK)[0] * BLOCK for n in (6 * 8, 6 * 100)]   # float32: 128
     plain = counters(np.zeros(32))
     assert plain["buffer_slots"] == sum(sizes) and plain["expert_passes"] == 0
     assert 0 < plain["rows_held"] < 0.5 * 2 * 6 * 108
