@@ -124,8 +124,8 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
 
     from ..ops import ssd
     from ..parallel.mesh import dispatch_serialized, make_mesh
-    from .device_rollout import build_streaming_fn
     from .plane import PlaneClient
+    from .rollout_plane import Lanes, vector_env_of
 
     train_args = dict(args["train_args"])
     train_args["env"] = args["env_args"]
@@ -135,19 +135,6 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
     prepare_env(args["env_args"])
     env = make_env(args["env_args"])
     module = env.net()
-    vector_env = getattr(env, "vector_env", None)
-    if vector_env is None:
-        raise ValueError(
-            f"distributed.role: actor needs a vector env; "
-            f"{args['env_args'].get('env')} exposes no vector_env()"
-        )
-    venv = vector_env()
-    if not hasattr(venv, "record"):
-        raise ValueError(
-            "distributed.role: actor needs a STREAMING vector env "
-            "(record/reset_done/step hooks); "
-            f"{getattr(venv, '__name__', type(venv).__name__)} lacks them"
-        )
     # match the learner tier's PER-PROCESS lane count: the gateway ingests
     # into rings built for device_rollout_games / num_processes lanes
     # (config.py validated the divisibility), and a mismatched record
@@ -156,17 +143,9 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
         1, int(dist.get("num_processes") or 1)
     )
     mesh = make_mesh({"dp": -1}, list(devices))
-    if games % mesh.size:
-        raise ValueError(
-            f"device_rollout_games {games} not divisible by this actor "
-            f"host's {mesh.size} local devices (lanes shard over them)"
-        )
-    stream_fn = build_streaming_fn(
-        venv, module, games,
-        int(train_args["device_replay_k_steps"]),
-        mesh=mesh if mesh.size > 1 else None,
-        use_observe_mask=bool(train_args["observation"]),
-        counters=True,
+    lanes = Lanes(
+        vector_env_of(env, train_args, mesh, games, streaming=True),
+        module, train_args, mesh, games, counters=True,
     )
     # identical seed -> identical init params on every process: rollouts
     # are on-policy-ish from step 0, before the first param poll lands.
@@ -211,19 +190,15 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
         client.connect(retry_for=30.0)
 
     # rank-decorrelated rollout stream, offset past the learner ranks'
-    # seed + 1009*rank family so a co-hosted learner never shares a key
-    key = jax.random.PRNGKey(seed + 0x5EED + 0xAC706 + 1009 * rank)
-    key, k0 = jax.random.split(key)
-    vstate = venv.init(games, k0)
-    hidden = module.initial_state((games, venv.num_players))
+    # seed + 1009*rank family so a co-hosted learner never shares a key; the
+    # first dispatch places key, state and hidden where its program runs
+    stream = lanes.stream(
+        jax.random.PRNGKey(seed + 0x5EED + 0xAC706 + 1009 * rank)
+    )
     dispatches, written = 0, set()
     try:
         while not stop.is_set():
-            key, sub = jax.random.split(key)
-            with trace_span("actor.dispatch"):
-                vstate, hidden, records, counted = dispatch_serialized(
-                    lambda: stream_fn(params, vstate, hidden, sub), mesh
-                )
+            records, counted = stream.step(params, trace_span("actor.dispatch"))
             with trace_span("actor.fetch"):
                 # graftlint: allow[HS001] reason=the record batch leaves this machine over DCN — host materialization is the transport's input, one D2H per k_steps block
                 host_records, counted = jax.device_get((records, counted))
@@ -264,13 +239,6 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
             f"plane gateway lost after {dispatches} dispatches: {e}"
         ) from e
     finally:
-        # await the in-flight async dispatch; exiting the process with an
-        # XLA execution still running aborts it (see
-        # StreamingDeviceRollout.drain)
-        try:
-            # graftlint: allow[HS001] reason=teardown drain: the loop has exited; awaiting the last in-flight rollout is the point (aborting a live XLA execute at interpreter exit crashes)
-            jax.block_until_ready(vstate)
-        except Exception:
-            pass
+        stream.drain()
         client.close()
     return {"dispatches": dispatches, "params": params}

@@ -106,7 +106,10 @@ class BatchedInferenceEngine:
             self._thread.start()
         return self
 
-    def stop(self) -> None:
+    def stop(self, join: float = 0.0) -> None:
+        """``join``: seconds to wait for the serve thread to leave its last
+        batch (a caller about to let the interpreter go: torn down with a
+        daemon thread inside a jax call, the process aborts)."""
         with self._lifecycle:
             if self._stop.is_set():
                 return  # idempotent; the first stop already arranged the drain
@@ -116,6 +119,8 @@ class BatchedInferenceEngine:
         if thread is None:
             # never started: there is no serve thread to own the drain
             self._fail_pending()
+        elif join > 0:
+            thread.join(timeout=join)
 
     def _fail_pending(self) -> None:
         """Fail every queued request.  Called exactly once, by the drain

@@ -30,7 +30,6 @@ import sys
 import threading
 import time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as FutureTimeoutError  # plain Exception subclass until py3.11
 from typing import Any, Dict, List, Optional
 
 from ..envs import make_env, prepare_env
@@ -46,6 +45,7 @@ from .checkpoint import (
     save_epoch_snapshot,
     verify_state,
 )
+from .rollout_plane import RolloutPlane
 from .trainer import Trainer
 from .worker import LocalModelServer, LocalWorkerPool
 
@@ -54,15 +54,6 @@ from .worker import LocalModelServer, LocalWorkerPool
 # with ``restart_epoch: -1``.  75 = BSD EX_TEMPFAIL ("temporary failure,
 # retry"), the conventional please-reschedule-me code supervisors honor.
 EXIT_RESUMABLE = 75
-
-# cumulative plane-watchdog event counters in metrics.jsonl (same
-# convention as pipe_batcher_* / sentinel_*: rare events diffed per epoch
-# would mostly print zeros)
-WATCHDOG_EVENT_KEYS = (
-    "plane_watchdog_stalls",
-    "plane_watchdog_restarts",
-    "plane_watchdog_degraded",
-)
 
 
 class Learner:
@@ -168,13 +159,8 @@ class Learner:
         # device-plane topology: 'fused' trains and self-plays time-sliced
         # on one mesh; 'split' carves disjoint learner/actor meshes so both
         # planes dispatch concurrently (per-device locks, parallel/mesh.py)
-        self._plane = self.args.get("plane", "fused")
         self._actor_mesh = None
-        self._param_cache = None       # versioned params on the actor mesh
-        self._record_xfer = None       # actor -> learner record transfer
-        self._plane_stats = None
-        self._plane_stats0: Dict[str, float] = {}
-        if self._plane == "split":
+        if self.args.get("plane", "fused") == "split":
             from ..parallel import split_mesh
 
             mesh, self._actor_mesh = split_mesh(
@@ -331,180 +317,41 @@ class Learner:
         self._drain_stopped = False     # trainer.stop() issued for the drain
         self._prev_handlers: Dict[int, Any] = {}
 
-        # -- plane watchdog ------------------------------------------------
-        # Liveness supervision of the device-rollout plane: a rollout
-        # thread that dies or stops making progress for plane_stall_timeout
-        # (or actor params lagging past plane_param_lag_bound) is restarted
-        # up to plane_max_restarts times; past the budget a split-plane run
-        # degrades split -> fused LOUDLY (the shm-batcher degrade pattern).
-        self._rollout_thread: Optional[threading.Thread] = None
-        self._rollout_gen = 0           # generation token: stale loops exit
-        self._rollout_progress_t = time.monotonic()
-        self._watchdog_events: Dict[str, int] = {k: 0 for k in WATCHDOG_EVENT_KEYS}
-        self._fault_wedge = faults.wedge_rollout()
-
-        # fully on-device self-play (runtime/device_rollout.py): env
-        # stepping + inference + sampling in one jit call per batch of
-        # games; workers then mostly evaluate
-        self._device_games = int(self.args.get("device_rollout_games", 0))
-        if self._dist_nprocs > 1 and self._device_games > 0:
-            # pod-slice rung 1: device_rollout_games is the GLOBAL lane
-            # count; each process runs its 1/nprocs share on its LOCAL
-            # devices (divisibility validated in config.py) and the
-            # shards meet in the collective train step via put_batch
-            self._device_games //= self._dist_nprocs
-        self._replay = None        # set below in device_replay mode
-        self._data_mesh = None     # local mesh the data plane runs on
-        self._plane_gateway = None  # rung-2 actor-host transport (run())
+        # -- device rollout plane (runtime/rollout_plane.py) ---------------
+        # fully on-device self-play: env stepping + inference + sampling in
+        # one jit call per batch of games, on a thread of its own beside
+        # this server loop, with a watchdog over it; workers then mostly
+        # evaluate.  Pod-slice rung 1: device_rollout_games is the GLOBAL
+        # lane count; each process runs its 1/nprocs share on its LOCAL
+        # devices (divisibility validated in config.py) and the shards meet
+        # in the collective train step via put_batch
+        self._next_update_episodes = (
+            self.args["minimum_episodes"] + self.args["update_episodes"]
+        )
         # per-epoch device self-play volume -> mean episode length in
         # metrics.jsonl (the survival signal on episode-length envs)
         self._device_epoch_eps = 0
         self._device_epoch_steps = 0
-        self._next_update_episodes = (
-            self.args["minimum_episodes"] + self.args["update_episodes"]
-        )
-        if self._plane == "split" and self._device_games <= 0:
-            raise ValueError(
-                "plane: split needs device_rollout_games > 0 (the actor "
-                "plane generates with the on-device streaming rollout)"
-            )
-        if self._device_games > 0:
-            vector_env = getattr(self.env, "vector_env", None)
-            if vector_env is None:
-                raise ValueError(
-                    f"device_rollout_games set but env "
-                    f"{args['env_args'].get('env')} exposes no vector_env()"
-                )
-            self._venv = vector_env()
-            n_verify = int(self.args.get("autovec_verify_games", 0))
-            if n_verify > 0 and getattr(self._venv, "__autovec__", False):
-                # autovec-lifted twin: refuse to train on a divergent lift
-                # (random-game step-parity vs the numpy rules; raises
-                # AutovecError naming the diverged observable)
-                self._venv.verify(n_verify, int(self.args["seed"]))
-                print(
-                    f"autovec twin verified: {self._venv.__name__} parity "
-                    f"over {n_verify} random games"
-                )
-            if self._plane == "split" and not hasattr(self._venv, "record"):
-                raise ValueError(
-                    "plane: split needs a STREAMING vector env (record/"
-                    "reset_done/step hooks) — the episodic driver runs on "
-                    f"the default device, not the actor mesh; "
-                    f"{getattr(self._venv, '__name__', type(self._venv).__name__)} "
-                    "lacks them"
-                )
-            if (
-                self._actor_mesh is not None
-                and self._device_games % self._actor_mesh.size
-            ):
-                # fail HERE, not as a sharding error inside the rollout
-                # daemon thread — lanes shard over the actor mesh's dp
-                raise ValueError(
-                    f"device_rollout_games {self._device_games} not "
-                    f"divisible by actor_chips {self._actor_mesh.size} "
-                    "(plane: split shards the lanes over the actor mesh)"
-                )
-            if self.args["observation"] and not hasattr(self._venv, "observe_mask"):
-                raise ValueError(
-                    "device_rollout_games with observation: true requires a "
-                    "vector env that records observer views (an observe_mask "
-                    f"hook); {type(self._venv).__name__ if not isinstance(self._venv, type) else self._venv.__name__} "
-                    "records acting players only — use host actors instead"
-                )
-            # pod-slice rung 1: under multi-process SPMD the data plane
-            # (rollout lanes, rings, record transfer) is PER PROCESS on
-            # this host's local learner devices — only the train step is
-            # collective, and the local shard it samples enters via
-            # TrainContext.put_batch's make_array_from_process_local_data
-            # seam.  Single-process: the data plane IS the learner mesh.
-            if self._dist_nprocs > 1:
-                local = [
-                    d
-                    for d in self.trainer.ctx.mesh.devices.flat
-                    if d.process_index == jax.process_index()
-                ]
-                self._data_mesh = make_mesh({"dp": -1}, local)
-            else:
-                self._data_mesh = self.trainer.ctx.mesh
-            # constructed HERE so misconfiguration (e.g. lane count not
-            # divisible by the mesh's dp axis) fails the run at startup
+        self.rollout: Optional[RolloutPlane] = None
+        games = int(self.args.get("device_rollout_games", 0)) // self._dist_nprocs
+        if games > 0:
+            # constructed HERE so misconfiguration fails the run at startup
             # instead of silently killing the rollout daemon thread
-            if self.args.get("device_replay"):
-                # data stays on device end to end: rollout records ->
-                # ring buffers -> sampled batches -> SGD, one dispatch
-                # each (runtime/device_replay.py); DeviceReplay validates
-                # the env/net/config constraints here, at startup
-                from .device_replay import DeviceReplay
-                from .device_rollout import build_streaming_fn
-
-                mesh = self._data_mesh
-                # rings (and the ingest/train donation contract) live on
-                # the LEARNER data mesh (this process's learner devices);
-                # under plane: split the rollout program runs on the actor
-                # mesh and its records cross over
-                self._replay = DeviceReplay(
-                    self._venv, self.module, self.args, mesh,
-                    self._device_games,
-                    slots=self.args["device_replay_slots"],
-                )
-                roll_mesh = (
-                    self._actor_mesh
-                    if self._actor_mesh is not None
-                    else (mesh if mesh.size > 1 else None)
-                )
-                self._stream_fn = build_streaming_fn(
-                    self._venv, self.module, self._device_games,
-                    self.args["device_replay_k_steps"],
-                    mesh=roll_mesh,
-                    use_observe_mask=bool(self.args["observation"]),
-                )
-                self.trainer.device_replay = self._replay
-                self._device_roll = None
-                if self._actor_mesh is not None:
-                    from .plane import RecordTransfer
-
-                    self._record_xfer = RecordTransfer(mesh)
-            else:
-                from .device_rollout import make_device_rollout
-
-                self._device_roll = make_device_rollout(
-                    self._venv, self.module, self.args, self._device_games,
-                    mesh=self._actor_mesh
-                    if self._actor_mesh is not None
-                    else self._data_mesh,
-                )
-            if self._actor_mesh is not None:
-                from .plane import PlaneParamCache, PlaneStats
-
-                self._param_cache = PlaneParamCache(self._actor_mesh)
-                self._plane_stats = PlaneStats()
-                self.trainer.param_cache = self._param_cache
-            # pod-slice rung 2: the coordinator fronts the cross-host
-            # plane — record batches from distributed.actor_hosts land in
-            # its device rings, versioned params go back over DCN
-            # (runtime/plane.py).  Followers never host it: actor hosts
-            # dial the one coordinator-derived plane port.
-            dist_args = self.args.get("distributed") or {}
-            if int(dist_args.get("actor_hosts") or 0) > 0 and not self._dist_follower:
-                if self._replay is None:
-                    raise ValueError(
-                        "distributed.actor_hosts > 0 needs device_replay: "
-                        "true on the learner tier — actor-host record "
-                        "batches land in the device replay rings "
-                        "(docs/performance.md §Pod-slice topology)"
-                    )
-                from .plane import PlaneGateway
-
-                self._plane_gateway = PlaneGateway(
-                    dist_args,
-                    on_records=self._gateway_on_records,
-                    inner=self._param_cache,
-                )
-                # one publish surface feeds both transports: the gateway
-                # delegates to the local actor-mesh cache when plane:
-                # split is also active on this host
-                self.trainer.param_cache = self._plane_gateway
+            self.rollout = RolloutPlane(
+                self.env, self.module, self.args, games,
+                self.trainer.ctx.mesh, self._actor_mesh, self._dist_rank,
+                live=lambda: not self.shutdown_flag,
+                budget_met=lambda: (
+                    self.num_returned_episodes >= self._next_update_episodes
+                ),
+                snapshot=self.model_server.latest_snapshot,
+                steps=lambda: self.trainer.steps,
+                submit=self._submit,
+                set_publisher=lambda cache: setattr(
+                    self.trainer, "param_cache", cache
+                ),
+            )
+            self.trainer.device_replay = self.rollout.replay
             if self.trainer.param_cache is not None:
                 # version 0 .. steps: the resumed step count keeps publish
                 # versions monotone across restarts
@@ -613,13 +460,18 @@ class Learner:
 
     # -- request plumbing ---------------------------------------------------
 
-    def handle(self, req: str, data: Any, timeout: Optional[float] = None) -> Any:
-        """Thread-safe entry point for workers; blocks until served (or
-        until ``timeout`` — used by the device-rollout thread, whose
-        submission can race server shutdown)."""
+    def _submit(self, req: str, data: Any) -> Future:
+        """A request onto the server loop's queue; the loop answers through
+        the Future (the rollout plane waits on it with patience, the plane
+        gateway's serve thread does not wait at all)."""
         fut: Future = Future()
         self._requests.put((req, data, fut))
-        return fut.result(timeout=timeout)
+        return fut
+
+    def handle(self, req: str, data: Any, timeout: Optional[float] = None) -> Any:
+        """Thread-safe entry point for workers; blocks until served (or
+        until ``timeout``)."""
+        return self._submit(req, data).result(timeout=timeout)
 
     # -- bookkeeping (train.py:457-500) -------------------------------------
 
@@ -744,22 +596,14 @@ class Learner:
             record["device_mean_episode_len"] = self._device_epoch_steps / self._device_epoch_eps
             self._device_epoch_eps = 0
             self._device_epoch_steps = 0
-        if self._replay is not None:
-            # cumulative host ints the rollout thread already keeps: game
-            # steps the rings have booked and the ingests that booked them
-            record["device_game_steps"] = self._replay.counters["game_steps"]
-            record["device_rollout_dispatches"] = self._replay.counters["ingests"]
+        if self.rollout is not None:
+            record.update(self.rollout.books())
         substituted = getattr(self.model_server, "substituted_snapshots", 0)
         if substituted:
             # cumulative: N old-snapshot requests were served LATEST params
             # instead (missing/corrupt file) — eval results attributed to
             # those epochs are suspect, and the books must say so
             record["serve_snapshot_substituted"] = substituted
-        if self._device_games > 0:
-            # live plane topology (flips split -> fused after a watchdog
-            # degradation) + cumulative watchdog events
-            record["plane"] = self._plane
-            record.update(self._watchdog_events)
         if self._dist_nprocs > 1:
             # cross-host health (cumulative, like the other event
             # counters): nonzero anywhere in the run means the plane saw
@@ -768,14 +612,6 @@ class Learner:
             # another boundary
             record["dist_processes"] = self._dist_nprocs
             record.update(self._dist_events())
-        if self._plane_gateway is not None:
-            # cross-host actor tier health: live producer count plus the
-            # cumulative losses (each one a degrade the survivors absorbed)
-            record["dist_actor_hosts"] = int(self._plane_gateway.actor_hosts)
-            record["dist_actor_host_losses"] = int(
-                self._plane_gateway.actor_host_losses
-            )
-        if self._dist_nprocs > 1:
             if self._health is not None and self._rank_metrics:
                 snap = self._rank_snapshot(steps)
                 if self._dist_follower:
@@ -789,43 +625,10 @@ class Learner:
             # tracer health next to the data it may be dropping: a nonzero
             # trace_dropped means the ring was outrun this run
             record.update(trace.trace_stats())
-        # local refs: a concurrent watchdog degrade nulls these attributes
-        # between the None-check and the reads (same hazard as
-        # _actor_params) — the epoch record must not die on the very
-        # degrade it is reporting
-        plane_stats = self._plane_stats
-        param_cache = self._param_cache
-        record_xfer = self._record_xfer
-        gateway = self._plane_gateway
-        if gateway is not None or (
-            plane_stats is not None and param_cache is not None
-        ):
-            # per-epoch plane health (diffed cumulative counters): realized
-            # actor-plane duty, mean param staleness at dispatch, and the
-            # cross-plane transfer rate (records learner-ward + params
-            # actor-ward) — the plane_* keys soaks watch next to pipe_*.
-            # The gateway's byte count already folds in the local cache
-            # (``inner``), so it substitutes rather than adds.
-            snap = plane_stats.snapshot() if plane_stats is not None else {}
-            cache_bytes = (
-                gateway.bytes_transferred
-                if gateway is not None
-                else param_cache.bytes_transferred
+        if self.rollout is not None:
+            record.update(
+                self.rollout.epoch_stats(max(now - self._epoch_t0, 1e-6))
             )
-            snap["xfer_bytes"] = cache_bytes + (
-                record_xfer.bytes_transferred if record_xfer else 0
-            )
-            prev, dt = self._plane_stats0, max(now - self._epoch_t0, 1e-6)
-            diff = lambda k: snap.get(k, 0.0) - prev.get(k, 0.0)
-            if plane_stats is not None:
-                record["plane_actor_busy_frac"] = round(diff("actor_busy_s") / dt, 4)
-                record["plane_actor_idle_frac"] = round(diff("actor_idle_s") / dt, 4)
-            record["plane_xfer_bytes_per_sec"] = round(diff("xfer_bytes") / dt, 1)
-            if diff("actor_dispatches"):
-                record["plane_param_lag_mean"] = round(
-                    diff("param_lag_sum") / diff("actor_dispatches"), 2
-                )
-            self._plane_stats0 = snap
         self._epoch_t0 = now
         self._epoch_steps0 = steps
         self._epoch_episodes0 = self.num_returned_episodes
@@ -936,7 +739,7 @@ class Learner:
         # could not enter the ring buffers — they would be stored but never
         # trained on, while racing the epoch cadence), so host workers
         # evaluate only
-        if self._replay is not None or self.num_results < self.eval_rate * self.num_episodes:
+        if self.trainer.device_replay is not None or self.num_results < self.eval_rate * self.num_episodes:
             args["role"] = "e"
             players = self.env.players()
             me = players[self.num_results % len(players)]
@@ -1056,44 +859,6 @@ class Learner:
             "train_steps_per_sec": stats.get("train_steps_per_sec"),
             "input_wait_frac": stats.get("input_wait_frac"),
         }
-
-    def _gateway_on_records(self, records: Dict[str, Any]) -> None:
-        """Plane-gateway ingest (runs on a gateway serve thread): validate
-        the lane width, ingest into this process's device rings, and book
-        the counters through the same server-loop request the local
-        rollout thread uses.
-
-        ``defer=False`` on purpose: the deferred-stats FIFO belongs to the
-        local rollout thread (``ingest_counted(defer=True)`` pairs each
-        dispatch with a LATER fetch), and a second writer interleaving
-        would misattribute both streams' stats.  One synchronous scalar
-        fetch per record batch is noise next to the DCN payload it rode
-        in on."""
-        import jax
-
-        widths = {x.shape[1] for x in jax.tree.leaves(records)}
-        if widths != {self._device_games}:
-            raise ValueError(
-                f"plane gateway: record batch lane width {sorted(widths)} "
-                f"!= this learner's {self._device_games} per-process lanes "
-                "(device_rollout_games / num_processes must match on both "
-                "tiers)"
-            )
-        stats = self._replay.ingest_counted(records, defer=False)
-        episodes = int(stats["episodes"])
-        if episodes <= 0 and int(stats["game_steps"]) <= 0:
-            return
-        counts = {
-            "episodes": episodes,
-            "players": self._venv.num_players,
-            "model_id": self.model_epoch,
-            "game_steps": int(stats["game_steps"]),
-            "outcome_sum": float(stats["outcome_sum"].sum()),
-            "outcome_sq_sum": float(stats["outcome_sq_sum"]),
-        }
-        # fire-and-forget: the serve thread must keep answering its actor
-        # host; the server loop books the counts when it gets there
-        self._requests.put(("device_counts", counts, Future()))
 
     def _dist_events(self) -> Dict[str, int]:
         """Cumulative cross-host health counters for the dist_* metrics."""
@@ -1347,460 +1112,6 @@ class Learner:
             self._write_drain_checkpoint()
         print("finished server")
 
-    # -- rollout plane: generation-tokened loop + watchdog --------------------
-
-    def _start_rollout_thread(self) -> threading.Thread:
-        """(Re)start the device-rollout thread under a fresh generation
-        token.  A superseded generation exits at its next liveness check
-        (a thread truly wedged inside a dispatch cannot be killed from
-        Python — it is abandoned and its generation invalidated, which is
-        the best any host-side supervisor can do)."""
-        self._rollout_gen += 1
-        gen = self._rollout_gen
-        self._rollout_progress_t = time.monotonic()
-        # stall detection arms only after this generation's FIRST dispatch
-        # completes: the first call pays jit compilation (minutes for a
-        # big model on TPU), and declaring that a stall would burn the
-        # whole restart budget on a healthy warm-up (a thread that DIES
-        # during compile is still caught by the dead-thread check)
-        self._rollout_dispatched = False
-        t = threading.Thread(
-            target=self._device_rollout_loop, args=(gen,), daemon=True,
-            name=f"device-rollout-{gen}",
-        )
-        self._rollout_thread = t
-        t.start()
-        return t
-
-    def _rollout_live(self, gen: int) -> bool:
-        return not self.shutdown_flag and self._rollout_gen == gen
-
-    def _rollout_beat(self) -> None:
-        """Progress heartbeat for the plane watchdog: every dispatch,
-        backpressure sleep, and server patience-wait counts as liveness —
-        only a thread that stops doing ALL of those is stalled."""
-        self._rollout_progress_t = time.monotonic()
-
-    def _maybe_wedge(self, gen: int, dispatches: int) -> bool:
-        """HANDYRL_FAULT_WEDGE_ROLLOUT: after N successful dispatches this
-        generation stops heartbeating (simulating a wedged XLA execute) but
-        politely exits once superseded or shut down.  Returns True when the
-        caller should return."""
-        w = self._fault_wedge
-        if w is None or dispatches < w[0] or (not w[1] and gen != 1):
-            return False
-        print(
-            f"[fault] wedging rollout thread generation {gen} after "
-            f"{dispatches} dispatches (HANDYRL_FAULT_WEDGE_ROLLOUT)",
-            file=sys.stderr,
-        )
-        while self._rollout_live(gen):
-            time.sleep(0.05)  # no _rollout_beat: the watchdog must notice
-        return True
-
-    def _watchdog_loop(self) -> None:
-        """Split/fused plane liveness supervision (runs whenever a device
-        rollout thread exists).  Detects a dead rollout thread, a stalled
-        one (no progress beat within plane_stall_timeout), or actor params
-        lagging past plane_param_lag_bound; restarts the thread up to
-        plane_max_restarts, then degrades split -> fused loudly."""
-        timeout = float(self.args.get("plane_stall_timeout", 120.0))
-        max_restarts = int(self.args.get("plane_max_restarts", 2))
-        lag_bound = int(self.args.get("plane_param_lag_bound", 0))
-        restarts = 0
-        tick = max(0.05, min(1.0, timeout / 4.0))
-        while not self.shutdown_flag:
-            time.sleep(tick)
-            if self.shutdown_flag or self._drain_requested:
-                return
-            thread = self._rollout_thread
-            if thread is None:
-                continue
-            dead = not thread.is_alive()
-            stall_s = time.monotonic() - self._rollout_progress_t
-            # pre-first-dispatch silence is compile time, not a stall
-            stalled = stall_s > timeout and self._rollout_dispatched
-            cache = self._param_cache
-            lagged = (
-                lag_bound > 0
-                and cache is not None
-                and cache.lag(self.trainer.steps) > lag_bound
-            )
-            if not (dead or stalled or lagged):
-                continue
-            reason = (
-                "thread died"
-                if dead
-                else f"no progress for {stall_s:.1f}s (> plane_stall_timeout)"
-                if stalled
-                else f"param lag {cache.lag(self.trainer.steps)} > "
-                f"plane_param_lag_bound {lag_bound}"
-            )
-            self._watchdog_events["plane_watchdog_stalls"] += 1
-            print(
-                f"[handyrl_tpu] plane watchdog: rollout plane unhealthy "
-                f"({reason})",
-                file=sys.stderr,
-            )
-            if restarts < max_restarts:
-                restarts += 1
-                self._watchdog_events["plane_watchdog_restarts"] += 1
-                print(
-                    f"[handyrl_tpu] plane watchdog: restarting rollout "
-                    f"thread ({restarts}/{max_restarts})",
-                    file=sys.stderr,
-                )
-                self._start_rollout_thread()
-            elif self._plane == "split":
-                self._degrade_to_fused()
-            else:
-                print(
-                    "[handyrl_tpu] plane watchdog: restart budget exhausted "
-                    "on the fused plane; giving up on the rollout thread "
-                    "(host actors keep generating if configured)",
-                    file=sys.stderr,
-                )
-                return
-
-    def _degrade_to_fused(self) -> None:
-        """Split -> fused degradation (mirrors the shm-batcher degrade
-        pattern): stop the cross-plane param/record flows, rebuild the
-        rollout program on the LEARNER mesh, and restart the rollout
-        thread there.  Training continues throughout — the learner plane
-        never depended on the actor mesh."""
-        self._rollout_gen += 1  # invalidate any live generation FIRST
-        print(
-            "[handyrl_tpu] plane watchdog: restart budget exhausted; "
-            "degrading split -> fused (rollouts move to the learner mesh; "
-            "cross-plane param/record flows stop)",
-            file=sys.stderr,
-        )
-        if self._plane_gateway is not None:
-            # the cross-HOST plane outlives a local split->fused degrade:
-            # drop only the actor-mesh delegate, keep publishing to the
-            # gateway so remote actor hosts still get fresh params
-            self._plane_gateway.inner = None
-            self.trainer.param_cache = self._plane_gateway
-        else:
-            self.trainer.param_cache = None
-        self._param_cache = None
-        self._record_xfer = None
-        self._plane_stats = None
-        self._actor_mesh = None
-        self._plane = "fused"
-        self._watchdog_events["plane_watchdog_degraded"] = 1
-        mesh = (
-            self._data_mesh
-            if self._data_mesh is not None
-            else self.trainer.ctx.mesh
-        )
-        try:
-            if self._replay is not None:
-                from .device_rollout import build_streaming_fn
-
-                self._stream_fn = build_streaming_fn(
-                    self._venv, self.module, self._device_games,
-                    self.args["device_replay_k_steps"],
-                    mesh=mesh if mesh.size > 1 else None,
-                    use_observe_mask=bool(self.args["observation"]),
-                )
-            else:
-                from .device_rollout import make_device_rollout
-
-                self._device_roll = make_device_rollout(
-                    self._venv, self.module, self.args, self._device_games,
-                    mesh=mesh,
-                )
-        except Exception:
-            import traceback
-
-            traceback.print_exc()
-            print(
-                "[handyrl_tpu] plane watchdog: learner-mesh rollout rebuild "
-                "failed (above); device generation stops (training continues "
-                "on already-ingested data / host actors)",
-                file=sys.stderr,
-            )
-            return
-        self._start_rollout_thread()
-
-    def _device_rollout_loop(self, gen: int) -> None:
-        """Generate device self-play batches up to each epoch boundary
-        (backpressure: pause once the boundary's episode budget is met, so
-        the chip alternates between rollouts and train steps instead of
-        flooding the store).  ``gen`` is this thread's generation token:
-        the loop exits once the watchdog supersedes it."""
-        import jax
-
-        # a restarted generation must not replay the superseded stream;
-        # the 1009 * rank fold decorrelates the per-process lane shares
-        # (each rank generates DIFFERENT games into its local rings)
-        key = jax.random.PRNGKey(
-            self.args["seed"]
-            + 0x5EED
-            + 0x1009 * (gen - 1)
-            + 1009 * self._dist_rank
-        )
-        if self._device_roll is None:          # device_replay mode
-            try:
-                self._device_replay_inner(key, gen)
-            finally:
-                if self._rollout_gen == gen:  # superseded: new gen owns it
-                    self._replay.drain()
-            return
-        roll = self._device_roll
-        try:
-            self._device_rollout_inner(roll, key, gen)
-        finally:
-            # await the in-flight async dispatch; exiting the process with
-            # an XLA execution still running aborts it (see
-            # StreamingDeviceRollout.drain)
-            if hasattr(roll, "drain") and self._rollout_gen == gen:
-                roll.drain()
-
-    def _actor_params(self):
-        """(model_id, params) for the next rollout dispatch: under plane:
-        split the versioned actor-mesh cache (bumping the realized-lag
-        counter), else the model server's epoch snapshot."""
-        cache = self._param_cache       # local refs: a concurrent watchdog
-        stats = self._plane_stats       # degrade nulls these attributes
-        if cache is None:
-            return self.model_server.latest_snapshot()
-        version, params = cache.latest()
-        if stats is not None:
-            stats.bump(
-                actor_dispatches=1,
-                param_lag_sum=max(0, self.trainer.steps - version),
-            )
-        return self.model_epoch, params
-
-    def _device_replay_inner(self, key, gen: int) -> None:
-        """Streaming rollout -> device-ring ingest; only scalar counters
-        reach the host, reported to the server loop for the books.
-
-        Under plane: split the rollout dispatch holds only the ACTOR
-        mesh's locks — it overlaps the learner plane's train dispatches —
-        and the record batch crosses to the learner mesh before ingest
-        (which shares the learner locks with training, preserving the
-        ring donation contract per plane).
-
-        Split/fused and the meshes are resolved at ENTRY, so a watchdog
-        restart after a split -> fused degradation re-enters here and
-        picks up the learner-mesh plumbing."""
-        import jax
-
-        from ..parallel.mesh import dispatch_serialized
-
-        split = self._param_cache is not None
-        roll_mesh = (
-            self._actor_mesh if split else self._data_mesh
-        )
-        # entry-captured refs: a concurrent watchdog degrade nulls the
-        # attributes, and a late-waking superseded thread must die at its
-        # liveness check, not on a None deref mid-iteration
-        record_xfer = self._record_xfer
-        plane_stats = self._plane_stats
-        key, k0 = jax.random.split(key)
-        vstate = self._venv.init(self._device_games, k0)
-        hidden = self.module.initial_state(
-            (self._device_games, self._venv.num_players)
-        )
-        if roll_mesh is not None:
-            # commit every dispatch input onto the rollout mesh UP FRONT:
-            # the loop's args then match the program's pinned in_shardings
-            # exactly, so no dispatch triggers an implicit host->mesh
-            # reshard.  That implicit copy is not just a per-dispatch
-            # transfer on the hot path — under plane: split it races the
-            # async ingest running on the OTHER plane's devices (observed
-            # on the multi-process CPU backend as Execute() placement
-            # errors killing the rollout thread), and committed args keep
-            # every cross-device move explicit and plane-owned.  The key
-            # stays mesh-resident too: split() of a committed key runs on
-            # the actor mesh and its outputs inherit the placement.
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            rep = NamedSharding(roll_mesh, PartitionSpec())
-            lanes = NamedSharding(roll_mesh, PartitionSpec("dp"))
-            key = jax.device_put(key, rep)
-            vstate = jax.device_put(vstate, lanes)
-            if hidden is not None:
-                hidden = jax.device_put(hidden, lanes)
-        from collections import deque
-
-        pending_steps = 0   # game steps from batches that finished 0 episodes
-        dispatches = 0
-        # model epoch per in-flight deferred ingest, aligned with
-        # DeviceReplay's stats FIFO: the stats that come back are one
-        # dispatch old, and booking them under the CURRENT epoch would
-        # misattribute one k_steps block's generation stats at every
-        # model publish
-        epoch_fifo: deque = deque()
-        try:
-            while self._rollout_live(gen):
-                if self.num_returned_episodes >= self._next_update_episodes:
-                    with trace_span("rollout.budget_wait"):
-                        time.sleep(0.02)   # epoch episode budget met: yield the chip
-                    self._rollout_beat()  # backpressure idle is healthy
-                    if split:
-                        plane_stats.bump(actor_idle_s=0.02)
-                    continue
-                if self._maybe_wedge(gen, dispatches):
-                    return
-                epoch, params = self._actor_params()
-                t_busy = time.perf_counter()
-                key, sub = jax.random.split(key)
-                with trace_span("rollout.dispatch", epoch=epoch):
-                    vstate, hidden, records = dispatch_serialized(
-                        lambda: self._stream_fn(params, vstate, hidden, sub),
-                        roll_mesh,
-                    )
-                if split:
-                    records = record_xfer(records)
-                # deferred stats (the direct-ingest hot path): the records
-                # go straight into the learner-mesh rings and the scalar
-                # fetch for dispatch N happens only after N+1 is enqueued —
-                # the rollout thread never synchronizes on an ingest.  The
-                # returned stats are therefore ONE DISPATCH OLD (None on
-                # the first), which only lags the books by one k_steps
-                # block — their model epoch rides epoch_fifo so the
-                # generation-stats attribution stays exact; the tail is
-                # flushed in the finally below.
-                epoch_fifo.append(epoch)
-                with trace_span("rollout.ingest", epoch=epoch):
-                    stats = self._replay.ingest_counted(records, defer=True)
-                dispatches += 1
-                self._rollout_dispatched = True  # arms stall detection
-                self._rollout_beat()
-                if split:
-                    plane_stats.bump(
-                        actor_busy_s=time.perf_counter() - t_busy
-                    )
-                if not self._rollout_live(gen):
-                    return
-                if stats is None:
-                    continue
-                stats_epoch = epoch_fifo.popleft()  # the dispatch they're from
-                n = int(stats["episodes"])
-                pending_steps += int(stats["game_steps"])
-                if n == 0:
-                    continue   # steps stay in pending_steps for the next report
-                counts = {
-                    "episodes": n,
-                    "players": self._venv.num_players,
-                    "model_id": stats_epoch,
-                    "game_steps": pending_steps,
-                    # graftlint: allow[HS001] reason=stats are host numpy from the deferred ingest fetch (one dispatch old), not device values
-                    "outcome_sum": float(stats["outcome_sum"].sum()),
-                    # graftlint: allow[HS001] reason=stats are host numpy from the deferred ingest fetch (one dispatch old), not device values
-                    "outcome_sq_sum": float(stats["outcome_sq_sum"]),
-                }
-                pending_steps = 0
-                if not self._submit_counts(counts, gen):
-                    return
-        finally:
-            # settle the deferred tail so its episodes still reach the
-            # books — but only while the run is live (a watchdog restart):
-            # a shutdown-time submission could push num_returned_episodes
-            # over the next boundary and conjure a spurious extra epoch
-            # out of the drain (pre-deferral behavior dropped the tail)
-            try:
-                left = self._replay.flush_counted()
-            except Exception:
-                left = None
-            if self.shutdown_flag:
-                left = None
-            if left and (int(left["episodes"]) > 0 or pending_steps):
-                counts = {
-                    "episodes": int(left["episodes"]),
-                    "players": self._venv.num_players,
-                    # oldest in-flight dispatch's epoch, not the current
-                    # model_epoch: a restart racing a model publish would
-                    # otherwise book the tail under a model that never
-                    # generated it (the tail can span several epochs; the
-                    # oldest is the closest single attribution)
-                    "model_id": int(epoch_fifo[0]) if epoch_fifo else self.model_epoch,
-                    "game_steps": pending_steps + int(left["game_steps"]),
-                    "outcome_sum": float(left["outcome_sum"]),
-                    "outcome_sq_sum": float(left["outcome_sq_sum"]),
-                }
-                # same submission protocol as the loop body (patience while
-                # this generation is live; a superseded/stopping thread
-                # gives up instead of blocking teardown)
-                self._submit_counts(counts, gen)
-
-    def _submit_counts(self, counts: Dict[str, Any], gen: int) -> bool:
-        """Report ingest counters to the server loop with the same patience
-        loop as _device_rollout_inner (the server can be busy for minutes
-        at an epoch boundary).  False = stop the rollout loop."""
-        fut: Future = Future()
-        # the server loop serves no request while it runs an epoch boundary
-        # (update(): snapshot wait, checkpoint, eval), so the first submit
-        # after the one that closed the epoch stands here until it is over
-        with trace_span("rollout.submit"):
-            self._requests.put(("device_counts", counts, fut))
-            while not fut.done():
-                try:
-                    fut.result(timeout=5.0)
-                    self._rollout_beat()  # served: the wait was the server's
-                except (TimeoutError, FutureTimeoutError):
-                    self._rollout_beat()  # waiting on a busy server ≠ a stall
-                    if not self._rollout_live(gen):
-                        return False
-                except Exception:
-                    return False
-        return True
-
-    def _device_rollout_inner(self, roll, key, gen: int) -> None:
-        import jax
-
-        roll_mesh = getattr(roll, "mesh", None)
-        if roll_mesh is not None:
-            # mesh-resident key, same contract as _device_replay_inner:
-            # dispatch args never ride an implicit host->mesh reshard
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            key = jax.device_put(key, NamedSharding(roll_mesh, PartitionSpec()))
-        dispatches = 0
-        while self._rollout_live(gen):
-            if self.num_returned_episodes >= self._next_update_episodes:
-                time.sleep(0.02)
-                self._rollout_beat()  # backpressure idle is healthy
-                if self._plane_stats is not None:
-                    self._plane_stats.bump(actor_idle_s=0.02)
-                continue
-            if self._maybe_wedge(gen, dispatches):
-                return
-            epoch, params = self._actor_params()
-            t_busy = time.perf_counter()
-            key, sub = jax.random.split(key)
-            episodes = roll.generate(params, sub)
-            dispatches += 1
-            self._rollout_dispatched = True  # arms stall detection
-            self._rollout_beat()
-            if self._plane_stats is not None:
-                self._plane_stats.bump(actor_busy_s=time.perf_counter() - t_busy)
-            for ep in episodes:
-                ep["args"]["model_id"] = {p: epoch for p in ep["players"]}
-            if not self._rollout_live(gen):
-                return
-            # submit once and wait on the SAME future with a patience loop:
-            # the server loop can be busy for minutes at an epoch boundary
-            # (trainer snapshot + first-epoch jit compile), and re-raising
-            # on a fixed timeout would silently kill on-device generation
-            # for the rest of the run
-            fut: Future = Future()
-            self._requests.put(("device_episodes", episodes, fut))
-            while not fut.done():
-                try:
-                    fut.result(timeout=5.0)
-                    self._rollout_beat()
-                except (TimeoutError, FutureTimeoutError):
-                    self._rollout_beat()  # waiting on a busy server ≠ a stall
-                    if not self._rollout_live(gen):
-                        return  # server draining/exited; nothing to feed
-                except Exception:
-                    return
-
     def run(self) -> int:
         """Run to completion.  Returns 0 on a normal finish, EXIT_RESUMABLE
         (75) after a preemption-safe drain — callers (train_main) exit with
@@ -1811,35 +1122,24 @@ class Learner:
                 self._health.start()
             if self._collective_watchdog is not None:
                 self._collective_watchdog.start()
-            if self._plane_gateway is not None:
-                self._plane_gateway.start()
             self._trainer_thread = threading.Thread(
                 target=self.trainer.run, daemon=True, name="trainer"
             )
             self._trainer_thread.start()
             self.worker.run()
             self._active_workers = len(getattr(self.worker, "threads", [])) or self.args["worker"]["num_parallel"]
-            if self._device_games > 0:
-                self._start_rollout_thread()
-                threading.Thread(
-                    target=self._watchdog_loop, daemon=True, name="plane-watchdog"
-                ).start()
+            if self.rollout is not None:
+                self.rollout.start()
             self._start_flywheel_ingest()
             self.server()
-            if self._plane_gateway is not None:
-                # run concluding: answer every further actor-host request
-                # with a clean stop (they exit 0, not as counted losses)
-                self._plane_gateway.begin_stop()
-            if self._rollout_thread is not None:
-                # let an in-flight device call drain: tearing down the
-                # interpreter while a daemon thread is inside an XLA execute
-                # aborts the process (C++ exception at exit).  Under a drain
-                # the join is bounded by the remaining deadline.
+            if self.rollout is not None:
+                # let an in-flight device call drain, the watchdog with it;
+                # under a drain the wait is bounded by the remaining deadline
                 timeout = 120.0
                 if self._drain_requested:
                     left = self.drain_deadline - (time.time() - self._drain_t0)
                     timeout = max(5.0, min(120.0, left))
-                self._rollout_thread.join(timeout=timeout)
+                self.rollout.stop(timeout)
         finally:
             if self._flywheel_ingestor is not None:
                 self._flywheel_ingestor.stop()
@@ -1847,8 +1147,8 @@ class Learner:
                 self._health.stop()
             if self._collective_watchdog is not None:
                 self._collective_watchdog.stop()
-            if self._plane_gateway is not None:
-                self._plane_gateway.stop()
+            if self.rollout is not None:
+                self.rollout.close()
             self._restore_signal_handlers()
             trace.shutdown()  # flush the span ring tail; a no-op when off
         return EXIT_RESUMABLE if self._drain_requested else 0
@@ -1905,24 +1205,16 @@ class Learner:
         return bool(getattr(self.trainer, "drain_agreed", False))
 
 
-def _finish_distributed(learner: "Learner") -> None:
+def train_main(args: Dict[str, Any], remote: bool = False) -> None:
     from ..parallel.distributed import shutdown_distributed
 
+    learner = Learner(args, remote=remote)
+    code = learner.run()
     if learner.shutdown_coherent:
         shutdown_distributed()
-
-
-def train_main(args: Dict[str, Any]) -> None:
-    learner = Learner(args)
-    code = learner.run()
-    _finish_distributed(learner)
     if code:
         sys.exit(code)
 
 
 def train_server_main(args: Dict[str, Any]) -> None:
-    learner = Learner(args, remote=True)
-    code = learner.run()
-    _finish_distributed(learner)
-    if code:
-        sys.exit(code)
+    train_main(args, remote=True)

@@ -110,7 +110,7 @@ class BatchPipeline:
         self.stop_event = stop_event or threading.Event()
         self._host_queue: queue.Queue = queue.Queue(maxsize=max(2, args["num_batchers"]))
         self._device_queue: queue.Queue = queue.Queue(maxsize=args.get("prefetch_batches", 2))
-        self._started = False
+        self._threads: List[threading.Thread] = []
         self._stats_lock = threading.Lock()
         self._stats: Dict[str, float] = {k: 0.0 for k in PIPE_STAT_KEYS}
         self._stats.update({k: 0.0 for k in PIPE_EVENT_KEYS})
@@ -122,12 +122,15 @@ class BatchPipeline:
         self._local_batch = local_batch_size(args["batch_size"])
 
     def start(self):
-        if self._started:
+        if self._threads:
             return
-        self._started = True
-        for _ in range(max(1, self.args["num_batchers"])):
-            threading.Thread(target=self._assemble_loop, daemon=True).start()
-        threading.Thread(target=self._device_put_loop, daemon=True).start()
+        loops = [self._assemble_loop] * max(1, self.args["num_batchers"])
+        self._threads = [
+            threading.Thread(target=loop, daemon=True)
+            for loop in loops + [self._device_put_loop]
+        ]
+        for thread in self._threads:
+            thread.start()
 
     def _sample_windows(self):
         windows = []
@@ -233,7 +236,12 @@ class BatchPipeline:
         return self._get(self._device_queue)
 
     def stop(self):
+        """Every loop looks at the event within half a second; a put in
+        flight ends first.  Waited for: an interpreter torn down while a
+        daemon thread is inside a jax call aborts the process."""
         self.stop_event.set()
+        for thread in self._threads:
+            thread.join(timeout=10.0)
 
     def stats(self) -> Dict[str, float]:
         with self._stats_lock:
@@ -1140,8 +1148,8 @@ class Trainer:
     def stop(self):
         self._stop_requested = True
         self.stop_event.set()
-        # process batchers need an explicit join + shm unlink; the
-        # threaded pipeline's stop() is just the event set again
+        # every pipeline joins what it started (process batchers also
+        # unlink their shm)
         self.batcher.stop()
 
     def run(self):

@@ -76,7 +76,7 @@ class LocalModelServer:
     def stop(self) -> None:
         """Release the serving plane (Learner teardown); subclasses with
         more resident machinery (the league's router engines) extend it."""
-        self.engine.stop()
+        self.engine.stop(join=30.0)
 
     def get(self, model_id: int):
         if model_id == 0:

@@ -506,20 +506,10 @@ with open(os.path.join(outdir, f"done_{pid}{extra.get('tag', '')}.json"), "w") a
 from handyrl_tpu.parallel.distributed import shutdown_distributed
 
 shutdown_distributed()
-# Learner.run() sets its planes' stop events and joins none of their
-# threads (the batch pipeline's _device_put_loop, a follower's engine
-# _serve_loop); each ends within a second, but an interpreter torn down
-# while one is still inside a jax call aborts ("FATAL: exception not
-# rethrown", rc -6: 2 of 6 two-process runs on a quiet box).  Wait for
-# them; train_main does not (ROADMAP D14).
-import threading, time
-
-deadline = time.monotonic() + 30.0
-others = [t for t in threading.enumerate() if t is not threading.current_thread()]
-for t in others:
-    t.join(max(0.0, deadline - time.monotonic()))
-left = [t.name for t in others if t.is_alive()]
-assert not left, f"threads still alive 30 s after Learner.run(): {left}"
+# Learner.run() has joined what it started that calls jax (the trainer, the
+# batch pipeline's loops, the engine's serve loop, the rollout plane): the
+# interpreter is not torn down with one of them inside a jax call ("FATAL:
+# exception not rethrown", rc -6, was 2 of 6 runs; ROADMAP D14(a), PR 47)
 sys.exit(code)
 """
 

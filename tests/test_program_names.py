@@ -189,7 +189,7 @@ def traced_run(tmp_path_factory):
         with open("metrics.jsonl") as f:
             records = [json.loads(line) for line in f]
         return dict(spans=spans, records=records,
-                    counters=dict(learner._replay.counters))
+                    counters=dict(learner.rollout.replay.counters))
     finally:
         trace_mod.configure(None)   # disarmed and counted from zero again
         os.chdir(cwd)

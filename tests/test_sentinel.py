@@ -392,36 +392,36 @@ def test_watchdog_restarts_then_degrades():
     """A dead rollout thread burns the restart budget, then a split-plane
     run degrades to fused and the watchdog keeps supervising the new
     plane (returning only once it is fused AND out of budget)."""
-    from handyrl_tpu.runtime.learner import WATCHDOG_EVENT_KEYS, Learner
+    from handyrl_tpu.runtime.rollout_plane import WATCHDOG_EVENT_KEYS, RolloutPlane
 
-    lrn = object.__new__(Learner)
+    lrn = object.__new__(RolloutPlane)
     lrn.args = {"plane_stall_timeout": 0.2, "plane_max_restarts": 1,
                 "plane_param_lag_bound": 0}
-    lrn.shutdown_flag = False
-    lrn._drain_requested = False
-    lrn._plane = "split"
+    lrn._live = lambda: True
+    lrn._halt = threading.Event()
+    lrn.topology = "split"
     lrn._param_cache = None
-    lrn._watchdog_events = {k: 0 for k in WATCHDOG_EVENT_KEYS}
-    lrn._rollout_progress_t = time.monotonic()
+    lrn.events = {k: 0 for k in WATCHDOG_EVENT_KEYS}
+    lrn._progress_t = time.monotonic()
     calls = {"restarts": 0, "degrades": 0}
 
     dead = threading.Thread(target=lambda: None)
     dead.start()
     dead.join()
-    lrn._rollout_thread = dead
+    lrn.thread = dead
 
     def fake_restart():
         calls["restarts"] += 1
-        lrn._watchdog_events["plane_watchdog_restarts"] += 1
-        lrn._rollout_progress_t = time.monotonic()
+        lrn.events["plane_watchdog_restarts"] += 1
+        lrn._progress_t = time.monotonic()
         return dead  # the restarted thread dies again immediately
 
     def fake_degrade():
         calls["degrades"] += 1
-        lrn._watchdog_events["plane_watchdog_degraded"] = 1
-        lrn._plane = "fused"  # the real degrade flips the topology
+        lrn.events["plane_watchdog_degraded"] = 1
+        lrn.topology = "fused"  # the real degrade flips the topology
 
-    lrn._start_rollout_thread = fake_restart
+    lrn._start_thread = fake_restart
     lrn._degrade_to_fused = fake_degrade
 
     t = threading.Thread(target=lrn._watchdog_loop, daemon=True)
@@ -429,8 +429,8 @@ def test_watchdog_restarts_then_degrades():
     t.join(timeout=30.0)
     assert not t.is_alive(), "watchdog never escalated through its ladder"
     assert calls == {"restarts": 1, "degrades": 1}
-    assert lrn._watchdog_events["plane_watchdog_stalls"] >= 2
-    assert lrn._watchdog_events["plane_watchdog_degraded"] == 1
+    assert lrn.events["plane_watchdog_stalls"] >= 2
+    assert lrn.events["plane_watchdog_degraded"] == 1
 
 
 def test_watchdog_stall_waits_for_first_dispatch():
@@ -438,23 +438,23 @@ def test_watchdog_stall_waits_for_first_dispatch():
     thread that has not completed a dispatch yet must never trip the
     stall detector (restarting mid-compile would burn the whole budget on
     a healthy warm-up); the first completed dispatch arms it."""
-    from handyrl_tpu.runtime.learner import WATCHDOG_EVENT_KEYS, Learner
+    from handyrl_tpu.runtime.rollout_plane import WATCHDOG_EVENT_KEYS, RolloutPlane
 
-    lrn = object.__new__(Learner)
+    lrn = object.__new__(RolloutPlane)
     lrn.args = {"plane_stall_timeout": 0.15, "plane_max_restarts": 5,
                 "plane_param_lag_bound": 0}
-    lrn.shutdown_flag = False
-    lrn._drain_requested = False
-    lrn._plane = "fused"
+    lrn._live = lambda: True
+    lrn._halt = threading.Event()
+    lrn.topology = "fused"
     lrn._param_cache = None
-    lrn._watchdog_events = {k: 0 for k in WATCHDOG_EVENT_KEYS}
-    lrn._rollout_progress_t = time.monotonic()
-    lrn._rollout_dispatched = False      # "still compiling"
+    lrn.events = {k: 0 for k in WATCHDOG_EVENT_KEYS}
+    lrn._progress_t = time.monotonic()
+    lrn._dispatched = False      # "still compiling"
     stop = threading.Event()
     alive = threading.Thread(target=stop.wait, daemon=True)
     alive.start()
-    lrn._rollout_thread = alive
-    lrn._start_rollout_thread = lambda: (_ for _ in ()).throw(
+    lrn.thread = alive
+    lrn._start_thread = lambda: (_ for _ in ()).throw(
         AssertionError("restarted a compiling thread")
     )
 
@@ -462,22 +462,22 @@ def test_watchdog_stall_waits_for_first_dispatch():
     t.start()
     try:
         time.sleep(0.6)  # 4x the timeout with no beat: still no stall
-        assert lrn._watchdog_events["plane_watchdog_stalls"] == 0
+        assert lrn.events["plane_watchdog_stalls"] == 0
         # first dispatch lands -> detection arms -> the next silent
         # window IS a stall
-        lrn._start_rollout_thread = lambda: setattr(
-            lrn, "_rollout_progress_t", time.monotonic()
+        lrn._start_thread = lambda: setattr(
+            lrn, "_progress_t", time.monotonic()
         )
-        lrn._rollout_dispatched = True
+        lrn._dispatched = True
         deadline = time.monotonic() + 10.0
         while (
-            not lrn._watchdog_events["plane_watchdog_stalls"]
+            not lrn.events["plane_watchdog_stalls"]
             and time.monotonic() < deadline
         ):
             time.sleep(0.05)
-        assert lrn._watchdog_events["plane_watchdog_stalls"] >= 1
+        assert lrn.events["plane_watchdog_stalls"] >= 1
     finally:
-        lrn.shutdown_flag = True
+        lrn._halt.set()
         stop.set()
         t.join(timeout=10.0)
 
@@ -606,7 +606,7 @@ def test_wedged_split_plane_degrades_to_fused_and_finishes(tmp_path, monkeypatch
     assert last["plane"] == "fused"               # topology flipped loudly
     assert last["plane_watchdog_stalls"] >= 1
     assert last["plane_watchdog_degraded"] == 1
-    assert learner._plane == "fused"
+    assert learner.rollout.topology == "fused"
     for v in (last.get("loss") or {}).values():
         assert np.isfinite(v)
 

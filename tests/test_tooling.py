@@ -303,3 +303,21 @@ def test_docs_name_only_files_and_commands_that_exist(doc, repo_files):
     benchmark the README described for twenty-nine PRs was one)."""
     with open(os.path.join(REPO, doc)) as f:
         assert _missing(f.read(), repo_files) == []
+
+
+def test_the_rollout_plane_imports_none_of_its_hosts():
+    """``runtime/rollout_plane.py`` is driven by ``Learner`` and by
+    ``actor_loop``; what it needs of a host it is handed as callables."""
+    import ast
+
+    path = os.path.join(REPO, "handyrl_tpu", "runtime", "rollout_plane.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            imported |= {(node.module or "").split(".")[-1]} | {a.name for a in node.names}
+        elif isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[-1] for a in node.names}
+    assert not imported & {"learner", "trainer", "server", "actor_host"}
+    assert {"device_rollout", "mesh", "faults", "trace"} <= imported

@@ -243,6 +243,7 @@ class LintConfig:
         # boundary, not an accidental sync
         "handyrl_tpu/runtime/plane.py",
         "handyrl_tpu/runtime/actor_host.py",
+        "handyrl_tpu/runtime/rollout_plane.py",
         # the flywheel's harvest capture seams run INSIDE the serving
         # request path (_do_infer / _reply) and its quality tick inside
         # the watch loop: a stray host sync is a per-request regression
@@ -270,6 +271,7 @@ class LintConfig:
         # the actor host's streaming rollout dispatches onto its local
         # mesh concurrently with param polls: same lock discipline
         "handyrl_tpu/runtime/actor_host.py",
+        "handyrl_tpu/runtime/rollout_plane.py",
         "handyrl_tpu/runtime/shm_batch.py",
         "handyrl_tpu/parallel/train_step.py",
         # per-model serving engines share chips with each other (and, co-
@@ -320,6 +322,7 @@ class LintConfig:
     met006_registry: str = "handyrl_tpu/utils/metrics.py"
     met006_writers: Tuple[str, ...] = (
         "handyrl_tpu/runtime/learner.py",
+        "handyrl_tpu/runtime/rollout_plane.py",
         "handyrl_tpu/runtime/trainer.py",
         "handyrl_tpu/serving/server.py",
         "handyrl_tpu/league/learner.py",
