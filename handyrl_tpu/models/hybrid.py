@@ -1,7 +1,10 @@
 """A policy/value trunk built from a layer-pattern string: ``M`` a Mamba-2
-mixer, ``E`` a routed expert layer with a shared expert, ``*`` grouped-query
-causal attention (with rotary positions where ``rope_theta`` is set), ``-`` a
-dense gated MLP (SwiGLU).  Each layer is ``x + mixer(RMSNorm(x))``, or with
+mixer, ``E`` a routed expert layer (with a shared expert where
+``shared_width`` is not 0), ``*`` grouped-query causal attention (with
+rotary positions where ``rope_theta`` is set), ``-`` a dense gated MLP
+(SwiGLU), ``C`` compressed convolutional attention (grouped-query attention
+in a latent whose queries and keys two causal convolutions mix over the last
+steps: ``CompressedConvAttention``).  Each layer is ``x + mixer(RMSNorm(x))``, or with
 ``sandwich`` ``x + RMSNorm(mixer(RMSNorm(x)))``; no biases but the conv's.
 ``out_scale_init`` is what the second norm's scale starts at: under 1, an
 untrained stack is nearer the identity, as deep residual nets are started.
@@ -16,7 +19,12 @@ dense transformer; a mixer and an ``E`` sub-layer in every layer
 the encoder's output, ``logits_divisor`` under the policy logits,
 ``attn_score_scale`` on the attention scores, a ``"softmax"`` ``router`` over
 the chosen logits and ``gated_experts`` is the ``granitemoehybrid`` family's
-period.  Every parameter is made and held in ``param_dtype`` (the
+period; ``"CE"`` layers with a rotation of half of each head
+(``rotary_factor``), top-1 gated experts, no shared expert and the ``"mlp"``
+``router`` (a small MLP on a ``router_width``-wide representation that each
+``E`` layer hands the next one's router, beside ``x`` and through every
+checkpoint; gates the chosen expert's own probability, not renormalised) is
+the ``zaya`` family's layer.  Every parameter is made and held in ``param_dtype`` (the
 initialisers draw in it: a bfloat16 acting copy of a net too large for a
 float32 tree is never preceded by one) and compute follows the parameters;
 the state, decays, norms, router logits and softmaxes stay float32.  One
@@ -30,7 +38,9 @@ is the sum over the uses); state is per *application*.  The hidden pytree
 holds, for every (pass, layer) pair in pass-major order, what that mixer
 carries between steps: the SSM state (float32) and the conv's last inputs
 for ``M``, a ring of the last ``memory_len`` keys (rotated, where they are)
-and values for ``*``, nothing for ``E`` and ``-``.  Like ``TransformerNet``
+and values for ``*``, for ``C`` that ring in its latent, the last rows of
+queries and keys its convolutions look back on (``tail``) and the last
+step's shifted value (``prev_v``), nothing for ``E`` and ``-``.  Like ``TransformerNet``
 it has two modes over one parameter set:
 
 * step mode — ``apply(obs, hidden)``: one step of every recurrence (acting,
@@ -45,8 +55,8 @@ it has two modes over one parameter set:
   window form gets by moving each row's observed steps to the front
   (``_compact``), running every mixer on that prefix, and moving the
   results back; burn-in steps run first, as a window of their own, and what
-  they leave (SSM state, conv tail, keys and values) is handed on under
-  ``stop_gradient``.  A caller that knows, on the host, how many steps a
+  they leave (SSM state, conv tails, last value, keys and values) is
+  handed on under ``stop_gradient``.  A caller that knows, on the host, how many steps a
   row observes at most hands the packing over (``packed_order``: per window
   part the index of each row's i-th observed step, as many columns as that
   most): the mixers then run over that many steps, not over the window's.
@@ -82,17 +92,19 @@ import numpy as np
 
 from ..ops import attention_core
 from ..ops.routed_experts import choose, held_mix
-from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows
+from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
 from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
 from .transformer import NEG_INF, _flatten_obs
 
-KINDS = "ME*-"
+KINDS = "ME*-C"     # Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention
 # ``jax.named_scope``s round the dense trunk's phases: a component of each of
 # their ops' ``op_name`` in a device profile, forward and backward (the
 # benchmark's ``mlp_roofline``, ``attn_step_share`` and ``norm_step_share``
 # import them; docs/observability.md has the naming rule).  ``attn`` holds
-# the projections, ``rope`` and ``gqa``
+# the projections, ``rope`` and ``gqa``, and in a ``C`` mixer ``cca_mix``: what
+# it does to queries, keys and values between the projections and the rotation
 ATTN_SCOPE, ROPE_SCOPE, GQA_SCOPE, MLP_SCOPE, NORM_SCOPE = "attn", "rope", "gqa", "mlp", "norm"
+CCA_SCOPE = "cca_mix"
 _EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
 
 
@@ -124,6 +136,14 @@ def _rope(x, pos, theta: float):
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def _period(pattern: str) -> str:
+    """The shortest string that ``pattern`` repeats (itself where none does)."""
+    for width in range(1, len(pattern) + 1):
+        if len(pattern) % width == 0 and pattern[:width] * (len(pattern) // width) == pattern:
+            return pattern[:width]
+    return pattern
 
 
 def _compact(key_mask, order=None):
@@ -236,58 +256,104 @@ class ExpertLayer(nn.Module):
     n_experts: int
     top_k: int
     expert_width: int
-    shared_width: int
+    shared_width: int           # 0: no shared expert
     routed_scale: float
     experts_held: int
     expert_offset: int
-    router: str = "sigmoid"     # or "softmax": over the chosen logits, no bias
+    router: str = "sigmoid"     # or "softmax": over the chosen logits, no bias; or "mlp"
     gated: bool = False         # experts and shared expert ``silu(a) * b``, not ``relu^2``
     param_dtype: Any = jnp.float32
+    router_width: int = 0       # "mlp": the width of the router's own representation
+    eps: float = 1e-5           # "mlp": of the RMSNorm on that representation
+
+    def _mlp_scores(self, tokens, carry):
+        """The ``mlp`` router: tokens (n, d), carry (n, router_width) the
+        ``E`` layer before's representation or None -> (softmax scores (n, E)
+        float32, this layer's representation: the next one's carry).
+        ``r = tokens Wd + bd``, plus ``carry_scale`` x the carry where there
+        is one; the scores are a two-hidden-layer GELU MLP's on ``RMSNorm(r)``.
+        All of it float32 at exact precision: the scores choose."""
+        kept, wide = self.param_dtype, self.router_width
+
+        def dense(x, name, features, bias=True):
+            kernel = self.param(name, nn.initializers.lecun_normal(), (x.shape[-1], features), kept)
+            y = jnp.dot(x, kernel.astype(jnp.float32), precision=_EXACT)
+            if bias:
+                y = y + self.param(name + "_bias", nn.initializers.zeros, (features,),
+                                   kept).astype(jnp.float32)
+            return y
+
+        r = dense(tokens.astype(jnp.float32), "router_down", wide)
+        if carry is not None:
+            r = r + self.param("carry_scale", nn.initializers.ones, (wide,),
+                               kept).astype(jnp.float32) * carry
+        z = _rms(r, self.param("router_norm", nn.initializers.ones, (wide,), kept), self.eps)
+        z = jax.nn.gelu(dense(z, "router_fc1", wide), approximate=False)
+        z = jax.nn.gelu(dense(z, "router_fc2", wide), approximate=False)
+        return jax.nn.softmax(dense(z, "router_out", self.n_experts, bias=False), axis=-1), r
 
     @nn.compact
-    def __call__(self, h, valid=None):
-        """h (..., d) tokens, valid (...) -> (out, chosen (..., k) int32,
-        counts: ``held_mix``'s, the rows the held experts computed and the
-        row buffer's passes and slots).  Applied with a mutable ``counters``
-        or ``choices`` collection (the acting path's callers that want
-        them: step mode returns the heads alone) it also sows the rows held,
-        the buffer's slots and the chosen there."""
+    def __call__(self, h, valid=None, carry=None):
+        """h (..., d) tokens, valid (...), carry (..., router_width) float32
+        or None (the ``mlp`` router's second stream: the router's
+        representation of the ``E`` layer before, None at the first) ->
+        (out, chosen (..., k) int32, counts: ``held_mix``'s, the rows the held
+        experts computed and the row buffer's passes and slots, with the
+        ``mlp`` router also the valid tokens' ``gates`` summed and their
+        count; this layer's carry, None for the other routers).  Applied with
+        a mutable ``counters`` or ``choices`` collection (the acting path's
+        callers that want them: step mode returns the heads alone) it also
+        sows the rows held, the buffer's slots and the chosen there."""
         lead, d = h.shape[:-1], h.shape[-1]
         tokens = h.reshape(-1, d)
         ok = jnp.ones(tokens.shape[:1], bool) if valid is None else valid.reshape(-1)
         fan_in = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
                                                   batch_axis=(0,))
-        if self.router not in ("sigmoid", "softmax"):
-            raise ValueError(f"router {self.router!r}: 'sigmoid' or 'softmax'")
+        if self.router not in ("sigmoid", "softmax", "mlp"):
+            raise ValueError(f"router {self.router!r}: 'sigmoid', 'softmax' or 'mlp'")
         kept, fused = self.param_dtype, 2 if self.gated else 1   # a gated input matrix holds a and b
-        router = self.param("router", nn.initializers.lecun_normal(), (d, self.n_experts), kept)
-        if self.router == "sigmoid":
+        if self.router != "mlp":
+            router = self.param("router", nn.initializers.lecun_normal(), (d, self.n_experts), kept)
+        if self.router != "softmax":
             # chooses only; no gradient reaches it (top-k's indices carry none)
             bias = self.param("score_bias", nn.initializers.zeros, (self.n_experts,), kept)
         w1 = self.param("w1", fan_in, (self.experts_held, d, fused * self.expert_width), kept)
         w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d), kept)
         with jax.named_scope("route"):
-            logits = jnp.dot(
-                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT)
-            if self.router == "sigmoid":
-                chosen, gates = choose(jax.nn.sigmoid(logits), bias, self.top_k, self.routed_scale)
-            else:   # the chosen's softmax: renormalising over them cancels the rest
-                chosen, gates = choose(jax.nn.softmax(logits, axis=-1),
-                                       jnp.zeros((self.n_experts,), jnp.float32), self.top_k,
-                                       self.routed_scale)
-        routed, counts = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype),
-                                  w2.astype(h.dtype), self.expert_offset, self.n_experts,
-                                  self.gated)
-        with jax.named_scope("shared_expert"):
-            up = _dense(fused * self.shared_width, "shared_up", kept)(tokens)
-            shared = _dense(d, "shared_down", kept)(
-                _gated(up) if self.gated else jnp.square(nn.relu(up)))
+            if self.router == "mlp":
+                # a gate is the chosen's own probability among all experts
+                scores, carry = self._mlp_scores(
+                    tokens, None if carry is None else carry.reshape(-1, self.router_width))
+                chosen, gates = choose(scores, bias, self.top_k, self.routed_scale,
+                                       renormalise=False)
+                carry = carry.reshape(lead + (self.router_width,))
+            else:
+                logits = jnp.dot(
+                    tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_EXACT)
+                if self.router == "sigmoid":
+                    chosen, gates = choose(
+                        jax.nn.sigmoid(logits), bias, self.top_k, self.routed_scale)
+                else:   # the chosen's softmax: renormalising over them cancels the rest
+                    chosen, gates = choose(jax.nn.softmax(logits, axis=-1),
+                                           jnp.zeros((self.n_experts,), jnp.float32), self.top_k,
+                                           self.routed_scale)
+        out, counts = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype),
+                               w2.astype(h.dtype), self.expert_offset, self.n_experts,
+                               self.gated)
+        if self.router == "mlp":
+            counts = dict(counts, gates=jnp.where(ok[:, None], gates, 0.0).sum(),
+                          gated=ok.sum() * self.top_k)
+        if self.shared_width:
+            with jax.named_scope("shared_expert"):
+                up = _dense(fused * self.shared_width, "shared_up", kept)(tokens)
+                out = out + _dense(d, "shared_down", kept)(
+                    _gated(up) if self.gated else jnp.square(nn.relu(up)))
         if not self.is_initializing():  # no-ops but under a caller's ``mutable``
             self.sow("counters", "rows_held", counts["rows"].sum())
             self.sow("counters", "buffer_slots", counts["slots"])
             self.sow("choices", "chosen", chosen.reshape(lead + (self.top_k,)))
-        return ((routed + shared).reshape(lead + (d,)),
-                chosen.reshape(lead + (self.top_k,)), counts)
+        return (out.reshape(lead + (d,)), chosen.reshape(lead + (self.top_k,)), counts,
+                carry if self.router == "mlp" else None)
 
 
 class GroupedQueryAttention(nn.Module):
@@ -329,7 +395,8 @@ class GroupedQueryAttention(nn.Module):
         if not step and not self.score_scale and attention_core.fits(
                 q.dtype, length, state["k"].shape[1], Hq, Hk, D):
             with jax.named_scope(GQA_SCOPE):
-                out, new_state = self._whole_rows(q, k, v, state, valid)
+                out, new_state = _whole_rows(q, k, v, state, valid, Hq, self.memory_len,
+                                             self.rope_theta)
             return _dense(self.d_model, "o", kept)(out), new_state
         q = q.reshape(n, length, Hk, Hq // Hk, D)
         k, v = k.reshape(n, length, Hk, D), v.reshape(n, length, Hk, D)
@@ -339,64 +406,198 @@ class GroupedQueryAttention(nn.Module):
                     state["n"][:, None] + jnp.arange(length)[None, :])
                 q, k = _rope(q, at, self.rope_theta), _rope(k, at, self.rope_theta)
         with jax.named_scope(GQA_SCOPE):
-            if step:
-                S = self.memory_len
-                slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
-                hot = jax.nn.one_hot(slot, S, dtype=jnp.float32)[..., None, None]
-                rows, ring_k, ring_v = state.get("rows"), state["k"], state["v"]
-                if rows is not None:    # rings per (row, player): the acting player's of each
-                    with jax.named_scope(COMMIT_SCOPE):
-                        ring_k, ring_v = acting_rows(ring_k, *rows), acting_rows(ring_v, *rows)
-                keys = ring_k * (1 - hot) + hot * k.astype(jnp.float32)
-                values = ring_v * (1 - hot) + hot * v.astype(jnp.float32)
-                age = jnp.mod(slot[:, None] - jnp.arange(S)[None, :], S)
-                allowed = (age < jnp.minimum(state["pos"] + 1, S)[:, None])[:, None, :]
-                if rows is None:
-                    new_state = {"k": keys, "v": values}
-                else:   # what ``keys`` and ``values`` are, written where the rings lie: the
-                    # step's slot alone, over zeros where the row's game has just begun
-                    with jax.named_scope(COMMIT_SCOPE):
-                        player, begun = rows
-                        new_state = {name: begin_rows(state[name], player, begun, whole=True).at[
-                            jnp.arange(n), player, slot].set(new[:, 0].astype(jnp.float32))
-                            for name, new in (("k", k), ("v", v))}
-            else:
-                before = state["n"].astype(jnp.int32)
-                keys = jnp.concatenate([state["k"].astype(k.dtype), k], axis=1)
-                values = jnp.concatenate([state["v"].astype(v.dtype), v], axis=1)
-                past = state["k"].shape[1]
-                # positions count observed steps: the past's come first
-                key_pos = jnp.concatenate([
-                    jnp.broadcast_to(jnp.arange(past)[None, :], (n, past)),
-                    before[:, None] + jnp.arange(length)[None, :]], axis=1)
-                key_ok = jnp.concatenate([
-                    jnp.arange(past)[None, :] < before[:, None], valid], axis=1)
-                query_pos = before[:, None] + jnp.arange(length)[None, :]
-                gap = query_pos[:, :, None] - key_pos[:, None, :]
-                allowed = key_ok[:, None, :] & (gap >= 0) & (gap < self.memory_len)
-                new_state = {"k": keys, "v": values, "n": before + valid.sum(axis=1)}
-            scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, keys.astype(q.dtype),
-                                preferred_element_type=jnp.float32)
-            scores = scores * self.score_scale if self.score_scale else scores / (D ** 0.5)
-            scores = jnp.where(allowed[:, None, None], scores, NEG_INF)
-            weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            out = jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype))
+            out, new_state = _grouped_rows(q, k, v, state, valid, step, self.memory_len,
+                                           self.score_scale)
         out = _dense(self.d_model, "o", kept)(out.reshape(n, length, Hq * D))
         return (out[:, 0] if step else out), new_state
 
-    def _whole_rows(self, q, k, v, state, valid):
-        """A window part through ``ops/attention_core.py``'s kernel, q, k and
-        v as the projections wrote them: -> (out (N, L, Hq x D), new state),
-        the state's keys rotated as the lines above keep them."""
-        (n, length), (past, Hk, D) = q.shape[:2], state["k"].shape[1:]
-        before, count = state["n"].astype(jnp.int32), valid.sum(axis=1).astype(jnp.int32)
-        past_k, past_v = state["k"].astype(k.dtype), state["v"].astype(v.dtype)
-        out, keys = attention_core.attention_core(
-            q, k, v, past_k.reshape(n, past, Hk * D), past_v.reshape(n, past, Hk * D),
-            before, count, (self.heads // Hk, D, self.memory_len, self.rope_theta))
-        return out, {"k": jnp.concatenate([past_k, keys.reshape(n, length, Hk, D)], axis=1),
-                     "v": jnp.concatenate([past_v, v.reshape(n, length, Hk, D)], axis=1),
-                     "n": before + count}
+
+def _grouped_rows(q, k, v, state, valid, step: bool, memory_len: int, score_scale: float = 0.0):
+    """Grouped-query attention's einsum lines, q (N, L, Hk, Hq / Hk, D) and
+    k, v (N, L, Hk, D) rotated where they are to be: the ring write (step
+    mode) or the hand-off's concatenation (window mode), mask, scores,
+    softmax and mix -> (out (N, L, Hk, Hq / Hk, D), new state: ``k``, ``v``
+    and in window mode ``n``)."""
+    (n, length), D = q.shape[:2], q.shape[-1]
+    if step:
+        S = memory_len
+        slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
+        hot = jax.nn.one_hot(slot, S, dtype=jnp.float32)[..., None, None]
+        rows, ring_k, ring_v = state.get("rows"), state["k"], state["v"]
+        if rows is not None:    # rings per (row, player): the acting player's of each
+            with jax.named_scope(COMMIT_SCOPE):
+                ring_k, ring_v = acting_rows(ring_k, *rows), acting_rows(ring_v, *rows)
+        keys = ring_k * (1 - hot) + hot * k.astype(jnp.float32)
+        values = ring_v * (1 - hot) + hot * v.astype(jnp.float32)
+        age = jnp.mod(slot[:, None] - jnp.arange(S)[None, :], S)
+        allowed = (age < jnp.minimum(state["pos"] + 1, S)[:, None])[:, None, :]
+        if rows is None:
+            new_state = {"k": keys, "v": values}
+        else:   # what ``keys`` and ``values`` are, written where the rings lie: the
+            # step's slot alone, over zeros where the row's game has just begun
+            with jax.named_scope(COMMIT_SCOPE):
+                player, begun = rows
+                new_state = {name: begin_rows(state[name], player, begun, whole=True).at[
+                    jnp.arange(n), player, slot].set(new[:, 0].astype(jnp.float32))
+                    for name, new in (("k", k), ("v", v))}
+    else:
+        before = state["n"].astype(jnp.int32)
+        keys = jnp.concatenate([state["k"].astype(k.dtype), k], axis=1)
+        values = jnp.concatenate([state["v"].astype(v.dtype), v], axis=1)
+        past = state["k"].shape[1]
+        # positions count observed steps: the past's come first
+        key_pos = jnp.concatenate([
+            jnp.broadcast_to(jnp.arange(past)[None, :], (n, past)),
+            before[:, None] + jnp.arange(length)[None, :]], axis=1)
+        key_ok = jnp.concatenate([
+            jnp.arange(past)[None, :] < before[:, None], valid], axis=1)
+        query_pos = before[:, None] + jnp.arange(length)[None, :]
+        gap = query_pos[:, :, None] - key_pos[:, None, :]
+        allowed = key_ok[:, None, :] & (gap >= 0) & (gap < memory_len)
+        new_state = {"k": keys, "v": values, "n": before + valid.sum(axis=1)}
+    scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, keys.astype(q.dtype),
+                        preferred_element_type=jnp.float32)
+    scores = scores * score_scale if score_scale else scores / (D ** 0.5)
+    scores = jnp.where(allowed[:, None, None], scores, NEG_INF)
+    weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype)), new_state
+
+
+def _whole_rows(q, k, v, state, valid, heads: int, memory_len: int, rope_theta: float):
+    """A window part through ``ops/attention_core.py``'s kernel, q, k and
+    v as the projections wrote them: -> (out (N, L, Hq x D), new state),
+    the state's keys rotated as the einsum lines keep them."""
+    (n, length), (past, Hk, D) = q.shape[:2], state["k"].shape[1:]
+    before, count = state["n"].astype(jnp.int32), valid.sum(axis=1).astype(jnp.int32)
+    past_k, past_v = state["k"].astype(k.dtype), state["v"].astype(v.dtype)
+    out, keys = attention_core.attention_core(
+        q, k, v, past_k.reshape(n, past, Hk * D), past_v.reshape(n, past, Hk * D),
+        before, count, (heads // Hk, D, memory_len, rope_theta))
+    return out, {"k": jnp.concatenate([past_k, keys.reshape(n, length, Hk, D)], axis=1),
+                 "v": jnp.concatenate([past_v, v.reshape(n, length, Hk, D)], axis=1),
+                 "n": before + count}
+
+
+class CompressedConvAttention(nn.Module):
+    """Compressed convolutional attention (``C``): grouped-query attention
+    in a latent of ``heads`` query and ``kv_heads`` key/value heads, whose
+    queries and keys are mixed over the last steps by two causal
+    convolutions before they meet.  With h the layer's normed input:
+
+    * ``[q~; k~] = h [Wq; Wk]``; values with a shift: ``v = [h_t Wv1;
+      h_{t-1} Wv2]``, each half ``kv_heads x head_dim / 2`` wide (with two
+      key/value heads, head 0 is the current token's and head 1 the one
+      before's);
+    * ``z1`` a depthwise convolution of ``[q~; k~]`` over ``time0`` steps,
+      ``z2`` a convolution of ``z1`` over ``time1`` steps in which each
+      head's channels mix among themselves, both with bias, causal, the
+      steps before a row's first read as zero rows of ``[q~; k~]``;
+    * ``q = z2_q + (q~ + k~ of its key head) / 2``, ``k = z2_k + (the mean
+      of its query heads' q~ + k~) / 2``;
+    * per head ``q <- sqrt(D) q / |q|`` and ``k <- temp x sqrt(D) k / |k|``,
+      ``temp`` one learned scalar a key head; rotary positions on the first
+      ``rotary_dim`` of a head's dimensions, the keys kept rotated;
+    * causal attention over the last ``memory_len`` observed steps, scores
+      ``q . k / sqrt(D)``; ``o`` maps the ``heads x head_dim`` result back.
+
+    Beside the key and value rings it carries ``tail``, the last ``time0 +
+    time1 - 2`` rows of ``[q~; k~]``, and ``prev_v``, the last step's
+    ``h Wv2``.  The value shift, both convolutions, the mean, the norms and
+    the temperature are under ``cca_mix``; ``rope`` and ``gqa`` as in
+    ``GroupedQueryAttention``, whose einsum lines and kernel it shares (the
+    kernel is handed rotated operands and rotates nothing)."""
+
+    d_model: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    memory_len: int
+    rope_theta: float
+    rotary_dim: int
+    time0: int
+    time1: int
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, state, valid=None):
+        """As ``GroupedQueryAttention``; state also ``tail`` (N, time0 +
+        time1 - 2, (heads + kv_heads) x D) and ``prev_v`` (N, kv_heads x D
+        / 2), with ``rows`` per (row, player) as the rings."""
+        with jax.named_scope(ATTN_SCOPE):
+            return self._attend(h, state, valid)
+
+    def _attend(self, h, state, valid):
+        Hq, Hk, D, K0, K1 = self.heads, self.kv_heads, self.head_dim, self.time0, self.time1
+        G, R, kept = Hq + Hk, Hq // Hk, self.param_dtype
+        step = h.ndim == 2
+        if step:
+            h = h[:, None]
+        n, length = h.shape[:2]
+        grouped = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=(0, 2), out_axis=3, batch_axis=(1,))
+        w0 = self.param("conv0_kernel", nn.initializers.lecun_normal(), (K0, G * D), kept)
+        b0 = self.param("conv0_bias", nn.initializers.zeros, (G * D,), kept)
+        w1 = self.param("conv1_kernel", grouped, (K1, G, D, D), kept)
+        b1 = self.param("conv1_bias", nn.initializers.zeros, (G * D,), kept)
+        temp = self.param("temp", nn.initializers.ones, (Hk,), kept)
+        qk = jnp.concatenate([_dense(Hq * D, "q", kept)(h), _dense(Hk * D, "k", kept)(h)], axis=-1)
+        v_now, v_next = (_dense(Hk * D // 2, "v_now", kept)(h),
+                         _dense(Hk * D // 2, "v_prev", kept)(h))
+        rows = state.get("rows")    # ``tail`` and ``prev_v`` per (row, player): the acting one's
+        tail, prev_v = state["tail"], state["prev_v"]
+        if rows is not None:
+            with jax.named_scope(COMMIT_SCOPE):
+                tail, prev_v = acting_rows(tail, *rows), acting_rows(prev_v, *rows)
+        with jax.named_scope(CCA_SCOPE):
+            fed = jnp.concatenate([tail.astype(qk.dtype), qk], axis=1)   # (N, K0 + K1 - 2 + L, C)
+            shifted = jnp.concatenate([prev_v.astype(qk.dtype)[:, None], v_next], axis=1)
+            if valid is None:
+                new_tail, new_prev = fed[:, length:], shifted[:, length]
+            else:   # what the observed prefix leaves
+                count = valid.sum(axis=1)
+                last = count[:, None] + jnp.arange(K0 + K1 - 2)[None, :]
+                new_tail = jnp.take_along_axis(fed, last[..., None], axis=1)
+                new_prev = jnp.take_along_axis(shifted, count[:, None, None], axis=1)[:, 0]
+            v = jnp.concatenate([v_now, shifted[:, :length]], axis=-1).reshape(n, length, Hk, D)
+            span = length + K1 - 1
+            z1 = sum(fed[:, j:j + span] * w0[j].astype(qk.dtype) for j in range(K0))
+            z1 = (z1 + b0.astype(qk.dtype)).reshape(n, span, G, D)
+            z2 = sum(jnp.einsum("nlgd,gde->nlge", z1[:, j:j + length], w1[j].astype(qk.dtype))
+                     for j in range(K1)) + b1.astype(qk.dtype).reshape(G, D)
+            # float32 from here to the rotation: the mean, the norms, the temperature
+            z2 = z2.astype(jnp.float32)
+            q_in = qk[..., :Hq * D].astype(jnp.float32).reshape(n, length, Hk, R, D)
+            k_in = qk[..., Hq * D:].astype(jnp.float32).reshape(n, length, Hk, D)
+            q = z2[:, :, :Hq].reshape(n, length, Hk, R, D) + (q_in + k_in[:, :, :, None]) / 2
+            k = z2[:, :, Hq:] + (q_in.mean(axis=3) + k_in) / 2
+            unit = lambda x: x * jax.lax.rsqrt(                     # noqa: E731
+                jnp.square(x).sum(axis=-1, keepdims=True) + 1e-12) * (D ** 0.5)
+            q, k = unit(q), unit(k) * temp.astype(jnp.float32)[:, None]
+        with jax.named_scope(ROPE_SCOPE):
+            at = state["pos"][:, None] if step else (
+                state["n"][:, None] + jnp.arange(length)[None, :])
+            turn = lambda x: jnp.concatenate(                       # noqa: E731
+                [_rope(x[..., :self.rotary_dim], at, self.rope_theta),
+                 x[..., self.rotary_dim:]], axis=-1).astype(qk.dtype)
+            q, k = turn(q), turn(k)
+        with jax.named_scope(GQA_SCOPE):
+            # a window part whose rows the kernel holds whole: from dtype and
+            # shape alone, as in ``GroupedQueryAttention``
+            if not step and attention_core.fits(q.dtype, length, state["k"].shape[1], Hq, Hk, D):
+                out, new_state = _whole_rows(
+                    q.reshape(n, length, Hq * D), k.reshape(n, length, Hk * D),
+                    v.reshape(n, length, Hk * D), state, valid, Hq, self.memory_len, 0.0)
+            else:
+                out, new_state = _grouped_rows(q, k, v, state, valid, step, self.memory_len)
+        if rows is None:
+            new_state.update(tail=new_tail.astype(jnp.float32), prev_v=new_prev.astype(jnp.float32))
+        else:
+            with jax.named_scope(COMMIT_SCOPE):
+                new_state.update(
+                    tail=put_rows(state["tail"], new_tail.astype(jnp.float32), *rows),
+                    prev_v=put_rows(state["prev_v"], new_prev.astype(jnp.float32), *rows))
+        out = _dense(self.d_model, "o", kept)(out.reshape(n, length, Hq * D))
+        return (out[:, 0] if step else out), new_state
 
 
 class GatedMLP(nn.Module):
@@ -419,7 +620,9 @@ class Layer(nn.Module):
     """``x + mixer(RMSNorm(x))``, with ``sandwich`` ``x + RMSNorm(mixer(
     RMSNorm(x)))``, the mixer's branch times ``residual_scale``; a mixer
     that keeps no state hands the state it was given back, one that routes
-    says what it chose."""
+    says what it chose.  ``carry`` is the stack's second stream: what an
+    ``E`` layer's router hands the next one's (``router: mlp``; None
+    elsewhere), passed through by every other mixer."""
 
     mixer: nn.Module
     eps: float
@@ -429,13 +632,13 @@ class Layer(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, state, valid):
+    def __call__(self, x, state, valid, carry=None):
         with jax.named_scope(NORM_SCOPE):
             h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],),
                                    self.param_dtype), self.eps)
         routed = None
         if isinstance(self.mixer, ExpertLayer):
-            y, chosen, counts = self.mixer(h, valid)
+            y, chosen, counts, carry = self.mixer(h, valid, carry)
             routed = (chosen, counts)
         else:
             y, state = self.mixer(h, state, valid)
@@ -445,7 +648,7 @@ class Layer(nn.Module):
                                        (x.shape[-1],), self.param_dtype), self.eps)
         if self.residual_scale != 1.0:
             y = (self.residual_scale * y).astype(x.dtype)
-        return x + y, state, routed
+        return x + y, state, routed, carry
 
 
 class HybridNet(nn.Module):
@@ -498,10 +701,19 @@ class HybridNet(nn.Module):
     embed_scale: float = 1.0
     logits_divisor: float = 1.0
     attn_score_scale: float = 0.0
-    # E: "sigmoid" scores with a choosing bias, or "softmax" over the chosen
-    # logits; experts and shared expert gated (``silu(a) * b``) or ``relu^2``
+    # E: "sigmoid" scores with a choosing bias, "softmax" over the chosen
+    # logits, or "mlp": a small MLP's softmax on a ``router_width``-wide
+    # representation that each ``E`` layer hands the next, the gates the
+    # chosen's own probabilities; experts and shared expert gated
+    # (``silu(a) * b``) or ``relu^2``
     router: str = "sigmoid"
+    router_width: int = 32
     gated_experts: bool = False
+    # C: the two convolutions' steps, and the share of a head's dimensions
+    # that is rotated (heads, ``memory_len`` and ``rope_theta`` are ``*``'s)
+    cca_time0: int = 2
+    cca_time1: int = 2
+    rotary_factor: float = 1.0
     # what every parameter is made and held in ("bfloat16": an acting copy
     # that no float32 tree precedes); compute follows the parameters
     param_dtype: str = "float32"
@@ -518,9 +730,14 @@ class HybridNet(nn.Module):
             return ExpertLayer(
                 self.d_model, self.n_experts, self.top_k, self.expert_width, self.shared_width,
                 self.routed_scale, self.experts_held, self.expert_offset, self.router,
-                self.gated_experts, kept, parent=None)
+                self.gated_experts, kept, self.router_width, self.norm_eps, parent=None)
         if kind == "-":
             return GatedMLP(self.d_model, self.mlp_width, kept, parent=None)
+        if kind == "C":
+            return CompressedConvAttention(
+                self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
+                self.rope_theta, 2 * int(self.head_dim * self.rotary_factor / 2), self.cca_time0,
+                self.cca_time1, kept, parent=None)
         return GroupedQueryAttention(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
             self.rope_theta, self.attn_score_scale, kept, parent=None)
@@ -529,10 +746,11 @@ class HybridNet(nn.Module):
     def _through(layers, x, states, valid):
         """Every layer once over ``x`` ((N, L, d) with ``valid``, or (N, d)),
         ``states`` this pass's: -> (x, new states, {layer: chosen}, {layer:
-        counts})."""
-        new_states, chosen, counts = [], {}, {}
+        counts}).  An ``mlp`` router's representation goes from each ``E``
+        layer to the next beside ``x``, through every checkpoint between."""
+        new_states, chosen, counts, carry = [], {}, {}, None
         for layer, state in zip(layers, states):
-            x, state, routed = layer(x, state, valid)
+            x, state, routed, carry = layer(x, state, valid, carry)
             new_states.append(state)
             if routed is not None:
                 chosen[layer.name], counts[layer.name] = routed
@@ -559,6 +777,9 @@ class HybridNet(nn.Module):
             # the choices are keyed by layer, and no reference takes a pass's
             raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
                              "a routed layer is run once")
+        if self.loops > 1 and "C" in self.pattern:
+            raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
+                             "a compressed convolutional attention layer is run once")
         kept = jnp.dtype(self.param_dtype)
 
         def encode(flat):
@@ -648,6 +869,63 @@ class HybridNet(nn.Module):
                                for t in range(self.loops) for i in range(depth))
             return x, new_states, {}, {}, stay
 
+        def periods(x, states, valid):
+            """A window's stack of equal periods (``"CECECE"``: three of
+            ``"CE"``) as a ``lax.scan`` over the period index: one period is in
+            the program, not every layer (unrolled, the twelve sub-layers of
+            ``zaya1_train_t192``'s step were a 518 MB executable, which jax's
+            compile cache refuses, and two minutes of compile in every run:
+            PERF.md, PR 48).  The periods' parameters, states and checkpoints
+            are stacked by period, layer by layer; an ``mlp`` router's carry
+            rides in the scan's carry beside ``x``, zeros into the first
+            period under a ``carry_scale`` of zeros (the first ``E`` layer has
+            none: it adds nothing).  As in ``scanned`` each layer is applied
+            as a function of its parameters, so they must exist."""
+            params, width = self.variables["params"], len(repeat)
+            count = len(self.pattern) // width
+            wide = self.router == "mlp" and "E" in repeat
+
+            def application(kind):
+                free = layer(Layer, kind, parent=None)
+                fn = lambda p, x, state, carry: free.apply(  # noqa: E731
+                    {"params": p}, x, state, valid, carry)
+                return fn if remat == "none" else jax.checkpoint(fn)
+
+            def of_period(i):
+                """Layer ``i`` of every period, its leaves stacked."""
+                each = [params[f"layer{t * width + i}"] for t in range(count)]
+                if wide and repeat[i] == "E" and "carry_scale" not in each[0]["mixer"]:
+                    first = dict(each[0]["mixer"], carry_scale=jnp.zeros_like(
+                        each[1]["mixer"]["carry_scale"]))
+                    each[0] = dict(each[0], mixer=first)
+                return jax.tree.map(lambda *rows: jnp.stack(rows), *each)
+
+            stack = [application(kind) for kind in repeat]
+
+            def one_period(carry, this):
+                (x, handed), (p_t, states_t) = carry, this
+                new, routed = [], []
+                for i, apply in enumerate(stack):
+                    x, state, chose, handed = apply(p_t[i], x, states_t[i], handed)
+                    new.append(state)
+                    routed.append(chose)
+                return (x, handed), (tuple(new), tuple(routed))
+
+            handed = jnp.zeros(x.shape[:-1] + (self.router_width,), jnp.float32) if wide else None
+            by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[i::width])
+                             for i in range(width))
+            (x, _), (new, routed) = jax.lax.scan(
+                one_period, (x, handed), (tuple(of_period(i) for i in range(width)), by_layer))
+            at = lambda tree, t: jax.tree.map(lambda rows: rows[t], tree)  # noqa: E731
+            new_states = tuple(at(new[i], t) for t in range(count) for i in range(width))
+            chosen, counts = {}, {}
+            for t in range(count):
+                for i in range(width):
+                    if routed[i] is not None:
+                        chosen[f"layer{t * width + i}"], counts[f"layer{t * width + i}"] = at(
+                            routed[i], t)
+            return x, new_states, chosen, counts, None
+
         if not seq:
             if hidden is None:
                 hidden = self.initial_state((jax.tree.leaves(obs)[0].shape[0],))
@@ -655,7 +933,8 @@ class HybridNet(nn.Module):
             # ``rows_in_place`` names are per (row, player), to be stepped at
             # ``player[n]`` where they lie and read as zeros where ``begun``
             where = {} if rows is None else {"rows": rows}
-            given = {"M": where, "*": dict(where, pos=hidden["pos"])}
+            given = {"M": where, "*": dict(where, pos=hidden["pos"]),
+                     "C": dict(where, pos=hidden["pos"])}
             states = tuple(
                 dict(state, **given.get(kind, {}))
                 for kind, state in zip(self.pattern * self.loops, hidden["layers"]))
@@ -675,6 +954,10 @@ class HybridNet(nn.Module):
         # one checkpoint per layer application where asked: only its input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
         loop = gate is not None and not self.is_initializing()
+        # a stack of ``C`` layers' periods, three or more, runs as a scan over them
+        repeat = _period(self.pattern)
+        repeats = ("C" in repeat and len(self.pattern) >= 3 * len(repeat)
+                   and self.loops == 1 and not self.is_initializing())
         outs, chosen, counts = [], [], []
         slots = dropped = 0
         stayed = 0.0
@@ -687,7 +970,8 @@ class HybridNet(nn.Module):
             place = place.astype(x.dtype)
             packed = jnp.einsum("nit,ntd->nid", place, x[:, lo:hi], precision=_EXACT)
             y, states, picked, count, stay = (
-                scanned(packed, states, valid) if loop else passes(stack, packed, states, valid))
+                scanned(packed, states, valid) if loop else periods(packed, states, valid)
+                if repeats else passes(stack, packed, states, valid))
             if hi == burn_in:   # scan parity: no gradient through what burn-in leaves
                 states = jax.lax.stop_gradient(states)
             outs.append(jnp.einsum("nit,nid->ntd", place, y, precision=_EXACT))
@@ -728,6 +1012,10 @@ class HybridNet(nn.Module):
                 buffer_slots=sum(b["slots"] for b in buffers).astype(jnp.float32),
                 expert_passes=sum(b["passes"] - 1 for b in buffers).astype(jnp.float32),
             )
+            if "gates" in buffers[0]:   # ``mlp``: the mean gate a token's result was scaled by
+                out["counters"]["router_gate_mean"] = (
+                    sum(b["gates"] for b in buffers)
+                    / jnp.maximum(sum(b["gated"] for b in buffers), 1)).astype(jnp.float32)
         return out
 
     @nn.nowrap
@@ -736,9 +1024,9 @@ class HybridNet(nn.Module):
         precedes."""
         states = []
         for kind, state in zip(self.pattern * self.loops, self.initial_state((n,))["layers"]):
-            if kind == "*":
+            if kind in "*C":    # C keeps its tail and last value as they start
                 empty = jnp.zeros((n, 0, self.n_kv_heads, self.head_dim), dtype)
-                state = {"k": empty, "v": empty, "n": jnp.zeros((n,), jnp.int32)}
+                state = dict(state, k=empty, v=empty, n=jnp.zeros((n,), jnp.int32))
             states.append(state)
         return tuple(states)
 
@@ -754,10 +1042,16 @@ class HybridNet(nn.Module):
                 layers.append({
                     "ssm": zeros(self.mamba_heads, self.mamba_head_dim, self.state_size),
                     "conv": zeros(self.conv_kernel - 1, conv_dim)})
-            elif kind == "*":
+            elif kind in "*C":
                 layers.append({
                     "k": zeros(self.memory_len, self.n_kv_heads, self.head_dim),
                     "v": zeros(self.memory_len, self.n_kv_heads, self.head_dim)})
+                if kind == "C":
+                    # the rows of ``[q~; k~]`` the convolutions look back on, the last value
+                    layers[-1].update(
+                        tail=zeros(self.cca_time0 + self.cca_time1 - 2,
+                                   (self.n_heads + self.n_kv_heads) * self.head_dim),
+                        prev_v=zeros(self.n_kv_heads * self.head_dim // 2))
             else:
                 layers.append({})
         # pos is float32 so the train step's observation-mask arithmetic on
@@ -770,10 +1064,11 @@ class HybridNet(nn.Module):
         takes whole under ``rows`` and steps one player's row of where it
         lies: a tree of bools, the SSM states' and conv tails' (``ops/ssd.py``
         ``ssd_step_rows``) and the key and value rings' (a step writes one
-        slot): all but ``pos``.  A caller gathers the acting player's row of
-        every other."""
+        slot) and a ``C`` mixer's ``tail`` and ``prev_v`` (their acting row
+        gathered and written back by the mixer): all but ``pos``.  A caller
+        gathers the acting player's row of every other."""
         return jax.tree_util.tree_map_with_path(
-            lambda path, _: path[-1].key in ("ssm", "conv", "k", "v"), hidden)
+            lambda path, _: path[-1].key in ("ssm", "conv", "k", "v", "tail", "prev_v"), hidden)
 
     @nn.nowrap
     def layout(self) -> Dict[str, Any]:
@@ -784,14 +1079,25 @@ class HybridNet(nn.Module):
         inner = self.mamba_heads * self.mamba_head_dim
         conv_dim = inner + 2 * self.n_groups * self.state_size
         norms = 2 * d if self.sandwich else d
+        wide, n_e = self.router_width, self.pattern.count("E")
+        latent = (self.n_heads + self.n_kv_heads) * self.head_dim
+        # an ``E`` layer's router; past the first, an ``mlp`` router scales a carry
+        router = {
+            "sigmoid": d * self.n_experts + self.n_experts, "softmax": d * self.n_experts,
+            "mlp": (d + 2) * wide + 2 * (wide + 1) * wide + (wide + 1) * self.n_experts,
+        }[self.router]
+        carries = max(n_e - 1, 0) * wide if self.router == "mlp" else 0
         each = {
             "M": norms + d * (inner + conv_dim + self.mamba_heads)
             + (self.conv_kernel + 1) * conv_dim + 3 * self.mamba_heads + inner + inner * d,
             "*": norms + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
-            "E": norms + d * self.n_experts + (self.n_experts if self.router == "sigmoid" else 0)
-            + (3 if self.gated_experts else 2) * d
+            "E": norms + router + (3 if self.gated_experts else 2) * d
             * (self.shared_width + self.experts_held * self.expert_width),
             "-": norms + 3 * d * self.mlp_width,
+            # q, k, the two value maps, o; the two convolutions; the temperatures
+            "C": norms + d * (latent + self.n_kv_heads * self.head_dim)
+            + self.n_heads * self.head_dim * d + (self.cca_time0 + 1) * latent
+            + (self.cca_time1 * self.head_dim + 1) * latent + self.n_kv_heads,
         }
         return {
             "pattern": self.pattern, "loops": self.loops,
@@ -801,6 +1107,17 @@ class HybridNet(nn.Module):
             "residual_scale": self.residual_scale, "router": self.router,
             "param_dtype": self.param_dtype,
             **{f"params_{name}": self.pattern.count(kind) * each[kind]
+               + (carries if kind == "E" else 0)
                for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"),
-                                  ("-", "mlp"))},
+                                  ("-", "mlp"), ("C", "cca"))},
+            # of ``params_experts``, the routers' own
+            "params_router": n_e * router + carries,
         }
+
+    @nn.nowrap
+    def program_scopes(self):
+        """The ``jax.named_scope``s this net alone brings to a program that
+        applies it, for ``utils.compile_cache.scoped_program_options``: a
+        scope that came with its mixer needs a cache key of its own only
+        where the mixer is (the older kinds' programs keep theirs)."""
+        return (CCA_SCOPE,) if "C" in self.pattern else ()

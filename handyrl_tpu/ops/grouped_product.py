@@ -40,6 +40,11 @@ BLOCK = 128                 # rows of a block: the MXU's tile
 FEW_ROWS = 16               # the least a block can be: a bfloat16 tile's sublanes
 TILE = 4096                 # most columns of a weight tile held in VMEM
 _VMEM_LIMIT = 64 << 20      # over the compiler's default scope: a weight tile is double-buffered
+# what ``_weight_sums``' float32 sum and its double-buffered output tile may
+# take of that scope, 8 bytes an element of the tile: a (2048, 4096) tile
+# (gated experts of width 2,048 on a 2,048-wide stream) is 64 MB and is
+# refused by the compiler; (4096, 1536) and (2688, 1856) fit whole
+_SUMS_BYTES = 56 << 20
 
 
 def _tile(n: int) -> int:
@@ -133,6 +138,8 @@ def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool):
 
     (m, k), n, blocks = x.shape, dy.shape[1], owner.size
     tk, tn, rows = _tile(k), _tile(n), m // blocks
+    while 8 * tk * tn > _SUMS_BYTES and tn % 256 == 0:
+        tn //= 2    # more column tiles: the rows' blocks are read once more each
     group = jnp.sort(jnp.concatenate([owner, jnp.arange(groups, dtype=owner.dtype)]))
     block = jnp.minimum(jnp.arange(blocks + groups, dtype=owner.dtype) - group, blocks - 1)
     return _call(

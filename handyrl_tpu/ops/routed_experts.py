@@ -3,8 +3,8 @@ the mix that the experts held here give.
 
 The layer is told which experts it holds (``w1``/``w2`` carry ``held`` of
 them, ``offset`` is the first one's index among all).  It scores and chooses
-over all experts, normalises the gates over all the chosen, and adds only
-its own experts' terms: what expert parallelism asks of one chip.  On one
+over all experts, normalises the gates over all the chosen (where the
+router's family does: ``choose``), and adds only its own experts' terms: what expert parallelism asks of one chip.  On one
 chip it runs without the exchange; nothing stands in for the absent chips.
 
 No token is dropped at any imbalance.  The (token, choice) pairs that fall
@@ -54,14 +54,21 @@ SHARES = 2.5
 EXPERTS_SCOPE = "experts"   # the two products, forward and backward (benchmark/scopes.py)
 
 
-def choose(scores, bias, top_k: int, scale: float):
+def choose(scores, bias, top_k: int, scale: float, renormalise: bool = True):
     """scores (n, E) float32 in (0, 1), bias (E,) -> (chosen (n, k) int32:
     the ``top_k`` largest of ``scores + bias``; gates (n, k) float32:
     ``scale`` x the chosen's own scores over their sum).  The bias chooses
     only.  A softmax over the chosen logits is this on ``softmax(logits)``
-    with bias 0 and scale 1: the sum over the chosen cancels the rest."""
+    with bias 0 and scale 1: the sum over the chosen cancels the rest.
+    Gates are renormalised over the chosen where the family does so (the
+    ``sigmoid`` and ``softmax`` routers); without ``renormalise`` (the ``mlp``
+    router) a gate is ``scale`` x the chosen's own score: at ``top_k`` 1 a
+    renormalised gate is 1.0 whatever the scores, and no gradient reaches
+    the router."""
     _, chosen = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    if not renormalise:
+        return chosen.astype(jnp.int32), scale * picked
     return chosen.astype(jnp.int32), scale * picked / picked.sum(axis=-1, keepdims=True)
 
 

@@ -544,6 +544,9 @@ class TrainContext:
         self._packed_bounds: Dict[str, int] = {}
         # the attention paths (attention_core.PATHS) already written out
         self._attention_paths: set = set()
+        # scopes a net brings that older programs of its class lack (a
+        # ``HybridNet`` with ``C`` layers: ``cca_mix``): part of the step's cache key
+        self._net_scopes = tuple(getattr(module, "program_scopes", tuple)())
 
         loss_keys = ("p", "v", "r", "ent", "total")
 
@@ -731,7 +734,7 @@ class TrainContext:
                 donate_argnums=(0,),
                 in_shardings=(ss, self._batch_shard, self._replicated),
                 out_shardings=(ss, self._replicated),
-                compiler_options=scoped_program_options(UPDATE_SCOPE),
+                compiler_options=scoped_program_options(UPDATE_SCOPE, *self._net_scopes),
             )
         return self._train_step
 
@@ -884,7 +887,7 @@ class TrainContext:
                 donate_argnums=(0,),
                 in_shardings=(ss, stacked_shard, self._replicated),
                 out_shardings=(ss, self._replicated),
-                compiler_options=scoped_program_options(UPDATE_SCOPE),
+                compiler_options=scoped_program_options(UPDATE_SCOPE, *self._net_scopes),
             )
         out = dispatch_serialized(
             lambda: self._train_steps(state, stacked_device_batch, jnp.float32(lr)),

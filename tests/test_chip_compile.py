@@ -190,11 +190,84 @@ def test_acting_rows_routed_experts_compile_for_v5e_in_blocks_of_sixteen(v5e, mo
     assert "[4992," not in text and "[%d,%d,%d]" % (blocks, d, 2 * width) not in text
 
 
+@pytest.mark.parametrize("tokens", [6144, 512], ids=["forward_part", "burn_in_part"])
+def test_gated_top1_experts_and_their_gradient_compile_for_v5e_at_zaya1s_widths(v5e, monkeypatch,
+                                                                                 tokens):
+    """``held_mix`` with ``gated`` bf16 operands and its gradient at
+    ``zaya1_8b``'s widths (a 2,048-wide stream, 8 of 16 experts of width 2,048
+    held, one choice a token): the fused gate-and-up matrix is (2048, 4096),
+    whose weight sum as one tile (a float32 sum and a double-buffered output)
+    is 64 MB and over the kernel's scope, so ``_weight_sums`` takes it in two
+    column tiles: what the interpreter cannot refuse and this compile did (PR
+    48).  Six kernel calls under the backward beside the forward's two, and
+    the forward part's 56 blocks are its worst case: one pass."""
+    from handyrl_tpu.ops.routed_experts import block_rows, held_mix, row_buffer
+
+    d, width, held, experts, k = 2048, 2048, 8, 16, 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+
+    def loss(h, gates, w1, w2, chosen, valid):
+        out, _ = held_mix(h, chosen, gates, valid, w1, w2, 0, experts, True)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
+        aval((held, d, 2 * width), jnp.bfloat16), aval((held, width, d), jnp.bfloat16),
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2 + 6, len(calls)
+    block = block_rows(tokens, k, experts, jnp.bfloat16)
+    assert (block,) + row_buffer(tokens, k, held, experts, block) == {
+        6144: (128, 56, 1), 512: (128, 12, 1)}[tokens]
+
+
 # -- a window part's attention core (ops/attention_core.py) ------------------
 
 # (d_model, query heads, KV heads) of the two HybridNet cells, heads of 128:
 # ouro_2_6b and nemotron_twotower_30b_a3b as benchmark/configs/ has them
 _ATTENTION = {"ouro": (2048, 16, 16, 1e6), "nemotron": (2688, 32, 2, 0.0)}
+
+
+@pytest.mark.parametrize("part,length,past", [("packed", 96, 8), ("unpacked", 184, 8)])
+def test_compressed_convolutional_attention_compiles_for_v5e_round_the_kernel(v5e, monkeypatch, part,
+                                                                              length, past):
+    """``CompressedConvAttention``'s window mode and its gradient at
+    ``zaya1_8b``'s widths (8 query and 2 key/value heads of 128 on a
+    2,048-wide stream, half of each head rotated, two convolutions over
+    two steps) and the cell's forward parts: the attention core is
+    ``ops/attention_core.py``'s kernel, handed operands the mixer normed and
+    rotated itself (forward and backward call under ``gqa``), and the
+    grouped convolution, the norms and the rotation compile beside it."""
+    from benchmark import trace_reduce
+    from handyrl_tpu.models.hybrid import CCA_SCOPE, GQA_SCOPE, CompressedConvAttention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    module = CompressedConvAttention(2048, 8, 2, 128, 200, 5e6, 64, 2, 2)
+    aval = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+    h, valid = aval((64, length, 2048)), aval((64, length), jnp.bool_)
+    state = {"k": aval((64, past, 2, 128)), "v": aval((64, past, 2, 128)),
+             "n": aval((64,), jnp.int32), "tail": aval((64, 2, 1280), jnp.float32),
+             "prev_v": aval((64, 128), jnp.float32)}
+    zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+    params = jax.tree.map(lambda x: aval(x.shape), jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros(h.shape, h.dtype), zeros, jnp.ones(valid.shape, bool))))
+
+    def loss(params, h, state, valid):
+        out, new = module.apply(params, h, state, valid)
+        return (out.astype(jnp.float32) ** 2).sum() + sum(
+            (x.astype(jnp.float32) ** 2).sum() for x in jax.tree.leaves(new))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, h, state, valid).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2, len(calls)
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert all(trace_reduce.scopes_of(n, [GQA_SCOPE]) == [GQA_SCOPE] for n in names), names
+    mixed = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if trace_reduce.scopes_of(n, [CCA_SCOPE]) == [CCA_SCOPE]]
+    assert any("dot_general" in n for n in mixed) and any("transpose(jvp" in n for n in mixed)
 
 
 @pytest.mark.parametrize("part,length,past", [("packed", 96, 8), ("unpacked", 184, 8)])

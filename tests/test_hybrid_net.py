@@ -507,7 +507,7 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
     for share in range(16):
         held = dict(params, w1=params["w1"][2 * share:2 * share + 2],
                     w2=params["w2"][2 * share:2 * share + 2])
-        out, picked, counts = _expert_layer(2, 2 * share).apply({"params": held}, h)
+        out, picked, counts, _ = _expert_layer(2, 2 * share).apply({"params": held}, h)
         assert np.array_equal(np.sort(picked, -1), np.sort(chosen, -1))     # routes over all
         assert int(counts["rows"].sum()) == int(((chosen >= 2 * share) & (chosen < 2 * share + 2)).sum())
         total = total + (out - shared)
