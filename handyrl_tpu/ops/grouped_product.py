@@ -21,7 +21,13 @@ the work is the buffer's, not the routing's.
 * ``_weight_sums(x, dy, owner, groups)``: (groups, k, n),
   ``out[g] = sum over g's blocks of x[block]^T @ dy[block]``, accumulated in
   float32 in VMEM and written once a group, by the kernel, zeros for a group
-  with no block.
+  with no block.  Handed the sum a loop over passes carries (``into``,
+  ``first``; ``grouped_dot``'s ``into``), it writes into that sum's own
+  buffer: the first pass its sums alone (of what the buffer held it fetches
+  one tile and uses none), a later pass ``into[g]`` plus them, added in
+  float32 before the one cast.  So the loop's body holds no pass of XLA's
+  over an array of the weights' shape, and the usual update, one pass, pays
+  for none (PERF.md, PR 50).
 
 Operands go to the MXU as they are handed over (bf16 in the train step) and
 accumulate in float32.  Pallas on the TPU, the Pallas interpreter elsewhere
@@ -31,6 +37,7 @@ accumulate in float32.  Pallas on the TPU, the Pallas interpreter elsewhere
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -41,9 +48,13 @@ FEW_ROWS = 16               # the least a block can be: a bfloat16 tile's sublan
 TILE = 4096                 # most columns of a weight tile held in VMEM
 _VMEM_LIMIT = 64 << 20      # over the compiler's default scope: a weight tile is double-buffered
 # what ``_weight_sums``' float32 sum and its double-buffered output tile may
-# take of that scope, 8 bytes an element of the tile: a (2048, 4096) tile
-# (gated experts of width 2,048 on a 2,048-wide stream) is 64 MB and is
-# refused by the compiler; (4096, 1536) and (2688, 1856) fit whole
+# take of that scope, 8 bytes an element of the tile in bfloat16, 10 with a
+# carried sum's single-buffered tile beside them: a (2048, 4096) tile (gated
+# experts of width 2,048 on a 2,048-wide stream) is 64 MB, 84 with the
+# carried sum's, and is refused by the compiler, so its columns go in two
+# tiles of 42 MB; (2688, 1856) fits whole at 50 MB with the carried sum's
+# (its 1,856 columns could not be halved: were that tile double-buffered
+# too, 12 bytes an element, it would not fit), (4096, 1536) whole without
 _SUMS_BYTES = 56 << 20
 
 
@@ -53,9 +64,12 @@ def _tile(n: int) -> int:
     return min(n, TILE)
 
 
-def _call(kernel, prefetched, grid, in_specs, out_spec, out_shape, scratch, interpret, *operands):
+def _call(kernel, prefetched, grid, in_specs, out_spec, out_shape, scratch, interpret, *operands,
+          aliases=None):
     """``pallas_call`` whose first ``len(prefetched)`` operands are int32
-    vectors prefetched to SMEM: the index maps and the kernel read them."""
+    vectors prefetched to SMEM: the index maps and the kernel read them.
+    ``aliases``: operand (counted with the prefetched) -> the output whose
+    buffer it is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -65,6 +79,7 @@ def _call(kernel, prefetched, grid, in_specs, out_spec, out_shape, scratch, inte
             num_scalar_prefetch=len(prefetched), grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
         out_shape=out_shape,
+        input_output_aliases=aliases or {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
@@ -100,14 +115,18 @@ def _rows_times(x, w, owner, transposed: bool, out_dtype, interpret: bool):
         jax.ShapeDtypeStruct((m, n), out_dtype), [], interpret, x, w)
 
 
-def _sums_kernel(group_ref, block_ref, x_ref, dy_ref, o_ref, acc_ref):
+def _sums_kernel(group_ref, block_ref, first_ref, x_ref, dy_ref, *refs):
+    """``refs``: the output's tile and the float32 sum; with a carried sum,
+    before them that sum's tile."""
     from jax.experimental import pallas as pl
 
     del block_ref   # read by the index maps
+    into_ref, o_ref, acc_ref = refs if len(refs) == 3 else (None, *refs)
     step, last = pl.program_id(2), pl.num_programs(2) - 1
     group = group_ref[step]
     opens = (step == 0) | (group_ref[jnp.maximum(step - 1, 0)] != group)
     closes = (step == last) | (group_ref[jnp.minimum(step + 1, last)] != group)
+    first = first_ref[0] != 0
 
     @pl.when(opens)
     def _():
@@ -119,60 +138,95 @@ def _sums_kernel(group_ref, block_ref, x_ref, dy_ref, o_ref, acc_ref):
             x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(closes)
+    @pl.when(closes & first)
     def _():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
+    if into_ref is not None:
+        # in strips of rows under a loop: written out whole, as the first pass's cast is,
+        # this rare branch was 0.3 MB more of every call site's code (PERF.md, PR 50)
+        strip = math.gcd(acc_ref.shape[0], 256)
+        strip = strip if strip % 16 == 0 else acc_ref.shape[0]     # whole packed bfloat16 sublanes
+
+        @pl.when(closes & jnp.logical_not(first))
+        def _():
+            def add(r, _):
+                rows = pl.ds(pl.multiple_of(r * strip, strip), strip)
+                o_ref[rows, :] = (acc_ref[rows, :] + into_ref[rows, :].astype(jnp.float32)
+                                  ).astype(o_ref.dtype)
+
+            jax.lax.fori_loop(0, acc_ref.shape[0] // strip, add, None)
+
 
 @functools.partial(jax.jit, static_argnames=("groups", "out_dtype", "interpret"))
-def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool):
-    """out[g] = sum over the blocks b with owner[b] == g of x[b]^T @ dy[b].
+def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool, into=None, first=None):
+    """out[g] = sum over the blocks b with owner[b] == g of x[b]^T @ dy[b];
+    with ``into`` (groups, k, n) in ``out_dtype`` and ``first`` () bool, the
+    sum a loop carries: ``into``'s buffer is the output's, and out[g] is that
+    sum alone where ``first`` (whatever ``into`` holds), else ``into[g]`` plus
+    it, added in float32 before the one cast.
 
     The grid's innermost axis walks each group's blocks and then one step
     more that holds no block and writes the group's sum out: so a group
-    with no block is written too, as zeros, and no pass over the output
-    follows the kernel.  Step ``s`` of group ``g`` comes after one such
-    step of each earlier group: it is block ``s - g``."""
+    with no block is written too, as zeros (past the first pass as
+    ``into[g]`` was), and no pass over the output follows the kernel.  Step
+    ``s`` of group ``g`` comes after one such step of each earlier group: it
+    is block ``s - g``.  ``into``'s tile is single-buffered, and where
+    ``first`` its index map stands on the first tile whatever the step: the
+    usual update, one pass, reads that one tile and not the sum."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     (m, k), n, blocks = x.shape, dy.shape[1], owner.size
     tk, tn, rows = _tile(k), _tile(n), m // blocks
-    while 8 * tk * tn > _SUMS_BYTES and tn % 256 == 0:
+    while (8 if into is None else 10) * tk * tn > _SUMS_BYTES and tn % 256 == 0:
         tn //= 2    # more column tiles: the rows' blocks are read once more each
     group = jnp.sort(jnp.concatenate([owner, jnp.arange(groups, dtype=owner.dtype)]))
     block = jnp.minimum(jnp.arange(blocks + groups, dtype=owner.dtype) - group, blocks - 1)
+    out = jax.ShapeDtypeStruct((groups, k, n), out_dtype)
+    in_specs = [pl.BlockSpec((rows, tk), lambda i, j, s, group, block, first: (block[s], i)),
+                pl.BlockSpec((rows, tn), lambda i, j, s, group, block, first: (block[s], j))]
+    operands, aliases = (x, dy), None
+    if into is None:
+        first = True
+    else:
+        assert (into.shape, into.dtype) == (out.shape, out.dtype), (into.shape, into.dtype)
+        in_specs.append(pl.BlockSpec(
+            (None, tk, tn), lambda i, j, s, group, block, first: tuple(
+                jnp.where(first[0] != 0, 0, at) for at in (group[s], i, j)),
+            pipeline_mode=pl.Buffered(1)))
+        operands, aliases = (x, dy, into), {5: 0}    # counted from the prefetched three
     return _call(
-        _sums_kernel, (group, block),
-        (pl.cdiv(k, tk), pl.cdiv(n, tn), blocks + groups),
-        [pl.BlockSpec((rows, tk), lambda i, j, s, group, block: (block[s], i)),
-         pl.BlockSpec((rows, tn), lambda i, j, s, group, block: (block[s], j))],
-        pl.BlockSpec((None, tk, tn), lambda i, j, s, group, block: (group[s], i, j)),
-        jax.ShapeDtypeStruct((groups, k, n), out_dtype),
-        [pltpu.VMEM((tk, tn), jnp.float32)], interpret, x, dy)
+        _sums_kernel, (group, block, jnp.reshape(first, 1).astype(jnp.int32)),
+        (pl.cdiv(k, tk), pl.cdiv(n, tn), blocks + groups), in_specs,
+        pl.BlockSpec((None, tk, tn), lambda i, j, s, group, block, first: (group[s], i, j)),
+        out, [pltpu.VMEM((tk, tn), jnp.float32)], interpret, *operands, aliases=aliases)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def grouped_dot(x, w, owner, interpret: Optional[bool] = None):
+def grouped_dot(x, w, owner, interpret: Optional[bool] = None, into=None):
     """x (m, k) in ``owner.size`` blocks of equal height, w (groups, k, n),
     owner (blocks,) int32 non-decreasing -> (m, n) float32: each block's
-    rows times its group's weights."""
+    rows times its group's weights.  ``into``: (a sum of ``w``'s shape and
+    dtype that the caller's loop carries, first () bool); ``w``'s cotangent
+    is then that sum with this call's gradient added (where ``first``: the
+    gradient alone, whatever the sum holds), in the sum's own buffer."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _rows_times(x, w, owner, False, jnp.float32, interpret)
 
 
-def _grouped_fwd(x, w, owner, interpret):
-    return grouped_dot(x, w, owner, interpret), (x, w, owner)
+def _grouped_fwd(x, w, owner, interpret, into):
+    return grouped_dot(x, w, owner, interpret), (x, w, owner, into)
 
 
 def _grouped_bwd(interpret, saved, dy):
-    x, w, owner = saved
+    x, w, owner, into = saved
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     dy = dy.astype(x.dtype)     # the MXU's operand, as the weights are
     return (_rows_times(dy, w, owner, True, x.dtype, interpret),
-            _weight_sums(x, dy, owner, w.shape[0], w.dtype, interpret), None)
+            _weight_sums(x, dy, owner, w.shape[0], w.dtype, interpret, *(into or ())), None, None)
 
 
 grouped_dot.defvjp(_grouped_fwd, _grouped_bwd)

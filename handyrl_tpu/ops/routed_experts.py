@@ -117,13 +117,20 @@ def _combine_bwd(tok, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _in_kernel(dtype) -> bool:
+    """Whether operands of ``dtype`` go through the grouped kernel: bfloat16
+    ones; the plain block products run at the caller's matmul precision,
+    which a kernel's dots would not see."""
+    return dtype == jnp.bfloat16
+
+
 def block_rows(n: int, top_k: int, experts: int, dtype) -> int:
     """Rows of a block, the rows that share one expert's weights, for ``n``
     tokens of ``dtype``: ``FEW_ROWS`` where a uniform router gives an expert
     fewer than that and the products are the kernel's (bfloat16), else
     ``BLOCK``.  The float32 block products copy a block's weights out
     (``w[owner]``), so more and lower blocks would cost them memory."""
-    few = dtype == jnp.bfloat16 and n * top_k < FEW_ROWS * experts
+    few = _in_kernel(dtype) and n * top_k < FEW_ROWS * experts
     return FEW_ROWS if few else BLOCK
 
 
@@ -172,24 +179,27 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
             {"rows": rows, "passes": passes, "slots": passes * (blocks * block)})
 
 
-def _block_products(x, w1, w2, owner, gated: bool = False):
+def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None)):
     """x (m, d) in ``owner.size`` blocks of equal height, block ``b`` of
     expert ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block,
-    ``gated`` w2[e] (silu(a) b) with [a, b] = w1[e] x."""
-    if x.dtype == jnp.bfloat16:
-        product = lambda rows, w: grouped_dot(rows, w, owner)   # noqa: E731
-    else:   # at the caller's matmul precision, which a kernel's dots would not see
-        def product(rows, w):
+    ``gated`` w2[e] (silu(a) b) with [a, b] = w1[e] x.  ``into`` (the kernel's
+    products only): ``grouped_dot``'s ``into`` for w1's product and for
+    w2's, the sums of their gradients that a loop over passes carries."""
+    if _in_kernel(x.dtype):
+        def product(rows, w, into):
+            return grouped_dot(rows, w, owner, None, into)
+    else:
+        def product(rows, w, _):
             blocks = rows.reshape(owner.size, -1, rows.shape[1])
             return jnp.einsum("brk,bkn->brn", blocks, w[owner],
                               preferred_element_type=jnp.float32).reshape(rows.shape[0], -1)
-    up = product(x, w1)
+    up = product(x, w1, into[0])
     if gated:
         a, b = jnp.split(up, 2, axis=-1)
         act = (jax.nn.silu(a) * b).astype(x.dtype)
     else:
         act = jnp.square(jax.nn.relu(up)).astype(x.dtype)
-    return product(act, w2)
+    return product(act, w2, into[1])
 
 
 def _owners(ends, start, blocks: int, block: int):
@@ -202,8 +212,10 @@ def _owners(ends, start, blocks: int, block: int):
     return jnp.minimum(owner, ends.size - 1).astype(jnp.int32)
 
 
-def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bool = False):
-    """Slots [start, start + blocks x block) of the row buffer ``route`` lays out."""
+def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bool = False,
+              into=(None, None)):
+    """Slots [start, start + blocks x block) of the row buffer ``route`` lays
+    out.  ``into``: ``_block_products``'."""
     n, k = route["slot"].shape
     m = blocks * block
     rows, first, ends, base = (route[key] for key in ("rows", "first", "ends", "base"))
@@ -219,7 +231,7 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bo
         x = _dispatch(h, tok, at, in_buffer)
         gate = jnp.where(used, gates.reshape(-1)[pair], 0.0)
     with jax.named_scope(EXPERTS_SCOPE):
-        down = _block_products(x, w1, w2, owner, gated)
+        down = _block_products(x, w1, w2, owner, gated, into)
     with jax.named_scope("route"):
         # a slot no row fills has gate 0
         weighted = (down * gate[:, None]).astype(h.dtype)
@@ -237,11 +249,15 @@ def _passes(h, gates, w1, w2, route, blocks: int, block: int, gated: bool = Fals
     """Every pass the rows need, in a loop whose trip count is the update's
     own (``lax.while_loop``, differentiated by hand below): the usual update
     runs one pass and holds one pass's buffers, and the worst case, every
-    token on held experts, costs memory for one pass too.  (The first pass
-    is not taken out of the loop, though the loop's zeros and sums cost the
-    usual update 9 ms of 217: a second copy of the pass's kernels made the
-    step's executable a quarter larger and its load 8 s longer, PERF.md,
-    PR 40.)"""
+    token on held experts, costs memory for one pass too.  The backward
+    loop carries the sums of the passes' gradients; the weights' two, each
+    the size of the held experts' weights, are summed by the kernel that
+    computes them, in the carry's own buffers, which start as allocations
+    that the first pass writes whole (``ops/grouped_product.py``
+    ``_weight_sums``): no pass of XLA's over a weight-shaped array is in
+    the loop's body or before it.  (The first pass is not taken out of the
+    loop: a second copy of the pass's kernels made the step's executable a
+    quarter larger and its load 8 s longer, PERF.md, PR 40.)"""
     def one_more(carry):
         done, out = carry
         return done + 1, out + _one_pass(
@@ -258,18 +274,25 @@ def _passes_fwd(h, gates, w1, w2, route, blocks, block, gated):
 
 def _passes_bwd(blocks, block, gated, saved, d_out):
     h, gates, w1, w2, route = saved
+    carried = _in_kernel(h.dtype)   # else the plain products' gradients, summed here
 
     def one_more(carry):
-        done, sums = carry
+        done, (d_h, d_gates, d_w1, d_w2) = carry
+        first = done == 0
+        into = ((d_w1, first), (d_w2, first)) if carried else (None, None)
         _, pull = jax.vjp(
-            lambda *a: _one_pass(*a, route, done * blocks * block, blocks, block, gated),
+            lambda *a: _one_pass(*a, route, done * blocks * block, blocks, block, gated, into),
             h, gates, w1, w2)
-        return done + 1, jax.tree.map(jnp.add, sums, pull(d_out))
+        more_h, more_gates, sum_w1, sum_w2 = pull(d_out)
+        if not carried:
+            sum_w1, sum_w2 = d_w1 + sum_w1, d_w2 + sum_w2
+        return done + 1, (d_h + more_h, d_gates + more_gates, sum_w1, sum_w2)
 
-    zeros = jax.tree.map(jnp.zeros_like, (h, gates, w1, w2))
+    # the first pass's kernels write the weights' sums whole, whatever the buffers hold
+    fresh = (lambda a: jax.lax.empty(a.shape, a.dtype)) if carried else jnp.zeros_like
     sums = jax.lax.while_loop(
         lambda carry: carry[0] < _needed(route, blocks * block), one_more,
-        (jnp.int32(0), zeros))[1]
+        (jnp.int32(0), (jnp.zeros_like(h), jnp.zeros_like(gates), fresh(w1), fresh(w2))))[1]
     return (*sums, None)
 
 

@@ -35,13 +35,22 @@ ROUTED, LOOPED, ZAYA = "nemotron_twotower_train_t192", "ouro_train_t192", "zaya1
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_the_actor_cell_rehearses_on_cpu(root, trace):  # noqa: F811  (the benchmark's, restated)
+def test_the_actor_cell_rehearses_on_cpu(root, trace, tmp_path, monkeypatch):  # noqa: F811  (the benchmark's, restated)
     import jax.numpy as jnp
 
     from handyrl_tpu.ops.routed_experts import block_rows, row_buffer
 
+    if not trace:   # the run leaves its programs as lowered: ``_run`` hands the environment on
+        monkeypatch.setenv("JAX_DUMP_IR_TO", str(tmp_path))
+        monkeypatch.setenv("JAX_DUMP_IR_MODES", "stablehlo")
     proc = _run(root, "tiny_granite_actor", trace)
     assert proc.returncode == 4, proc.stderr[-4000:]
+    if not trace:
+        # the acting step is the routed layer's forward half alone: the rows' kernel, and
+        # nothing of the backward loop, whose weight sums carry an aliased operand (PR 50)
+        rollouts = [path.read_text() for path in tmp_path.glob("*jit_device_rollout*")]
+        assert rollouts and all("call @_rows_times" in text for text in rollouts)
+        assert not any("_weight_sums" in text for text in rollouts)
     lines = proc.stdout.strip().splitlines()
     last, earlier = json.loads(lines[-1]), json.loads(lines[-2])
     assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "compared"]

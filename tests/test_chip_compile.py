@@ -116,6 +116,26 @@ def test_kernel_compiles_for_v5e(v5e, kernel, shape, mode):
 _EXPERTS = dict(d=2688, width=1856, held=8, experts=128, top_k=6)
 
 
+def _no_pass_over_the_weights_sums(lowered, compiled_text, *shapes):
+    """The backward loop of a routed part carries the sums of the weights'
+    gradients, each of a weight's ``shape``: the kernel that computes a
+    gradient adds it to its sum where the sum lies and writes the first
+    pass's without reading, so no ``add`` of two weight-shaped operands is in
+    the program as lowered (the loop's body is where one was, PERF.md, PR
+    50), and as compiled no ``add`` or zero ``broadcast`` gives a
+    weight-shaped result: the carry starts as an allocation."""
+    for shape in shapes:
+        tensor = "tensor<%sxbf16>" % "x".join(map(str, shape))
+        adds = [line.strip() for line in lowered.as_text().splitlines()
+                if "stablehlo.add" in line and tensor in line]
+        assert not adds, adds[:2]
+        result = re.compile(r"= bf16\[%s\]\S* (add|broadcast)\(" % ",".join(map(str, shape)))
+        passes = [line.strip()[:160] for line in compiled_text.splitlines() if result.search(line)]
+        assert not passes, passes[:2]
+        assert re.search(r"= bf16\[%s\]\S* custom-call\(\), custom_call_target=\"AllocateBuffer\""
+                         % ",".join(map(str, shape)), compiled_text), shape
+
+
 @pytest.mark.parametrize("tokens", [6144, 512, 2], ids=["forward_part", "burn_in_part", "acting"])
 def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypatch, tokens):
     """``held_mix`` with bf16 operands and its gradient at the published
@@ -138,11 +158,12 @@ def test_routed_experts_compile_for_v5e_through_the_grouped_kernel(v5e, monkeypa
         out, _ = held_mix(h, chosen, gates, valid, w1, w2, 0, experts)
         return (out.astype(jnp.float32) ** 2).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
         aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
         aval((held, d, width), jnp.bfloat16), aval((held, width, d), jnp.bfloat16),
-        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_)).compile()
-    text = compiled.as_text()
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_))
+    text = lowered.compile().as_text()
+    _no_pass_over_the_weights_sums(lowered, text, (held, d, width), (held, width, d))
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
     # the forward pass's two products; under the hand-written backward those
@@ -186,6 +207,10 @@ def test_acting_rows_routed_experts_compile_for_v5e_in_blocks_of_sixteen(v5e, mo
     block = block_rows(tokens, k, experts, jnp.bfloat16)
     blocks, passes = row_buffer(tokens, k, held, experts, block)
     assert (block, blocks, passes) == (16, 56, 1)      # one pass covers every pair on held experts
+    # the forward half is the rows' kernel alone: no weight sum, whose carried sum is an
+    # operand aliased to the output (the backward loop's, PR 50)
+    assert "_rows_times" in text and "_weight_sums" not in text
+    assert not any("output_to_operand_aliasing" in line for line in calls)
     assert "f32[896,%d]" % (2 * width) in text and "f32[896,%d]" % d in text
     assert "[4992," not in text and "[%d,%d,%d]" % (blocks, d, 2 * width) not in text
 
@@ -211,13 +236,15 @@ def test_gated_top1_experts_and_their_gradient_compile_for_v5e_at_zaya1s_widths(
         out, _ = held_mix(h, chosen, gates, valid, w1, w2, 0, experts, True)
         return (out.astype(jnp.float32) ** 2).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
         aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
         aval((held, d, 2 * width), jnp.bfloat16), aval((held, width, d), jnp.bfloat16),
-        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_)).compile().as_text()
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_))
+    text = lowered.compile().as_text()
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
     assert len(calls) == 2 + 6, len(calls)
+    _no_pass_over_the_weights_sums(lowered, text, (held, d, 2 * width), (held, width, d))
     block = block_rows(tokens, k, experts, jnp.bfloat16)
     assert (block,) + row_buffer(tokens, k, held, experts, block) == {
         6144: (128, 56, 1), 512: (128, 12, 1)}[tokens]

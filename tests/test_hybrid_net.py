@@ -603,6 +603,42 @@ def test_grouped_dot_is_each_blocks_rows_by_its_experts_weights(dtype, k, n):
     assert not np.asarray(dw[1], np.float32).any() and not np.asarray(dw[3], np.float32).any()
 
 
+@pytest.mark.parametrize("k,n,tiles", [(64, 192, 1), (2048, 4096, 2)],
+                         ids=["whole_tile", "column_tiles"])
+def test_weight_sums_add_to_the_sum_a_loop_carries(k, n, tiles):
+    """``_weight_sums`` with ``into`` (the interpreter), at a shape that is
+    one tile and at ``zaya1_8b``'s fused (2048, 4096), which goes in two
+    column tiles: past the first pass a group with blocks gets ``into`` plus
+    its plain sum, added in float32 and rounded once, a group with none
+    keeps ``into``; on the first pass the result is the plain sum whatever
+    ``into`` holds (NaNs here), bit for bit what the kernel gives without
+    ``into``."""
+    from handyrl_tpu.ops import grouped_product
+    from handyrl_tpu.ops.grouped_product import _weight_sums
+
+    # 10 bytes an element with the carried sum's tile: ``_weight_sums``' rule
+    assert (10 * k * n > grouped_product._SUMS_BYTES) == (tiles > 1)
+    key = jax.random.PRNGKey(k)
+    x = jax.random.normal(key, (3 * 16, k), jnp.bfloat16)
+    dy = jax.random.normal(jax.random.fold_in(key, 1), (3 * 16, n), jnp.bfloat16)
+    owner = jnp.array([0, 2, 2], jnp.int32)        # 1 and 3 hold no block
+    into = 8 * jax.random.normal(jax.random.fold_in(key, 2), (4, k, n), jnp.bfloat16)
+    plain = _weight_sums(x, dy, owner, 4, jnp.bfloat16, True)
+    exact = _weight_sums(x, dy, owner, 4, jnp.float32, True)
+    assert np.asarray(exact[0]).any() and np.asarray(exact[2]).any()
+    assert not np.asarray(exact[1]).any() and not np.asarray(exact[3]).any()
+
+    later = _weight_sums(x, dy, owner, 4, jnp.bfloat16, True, into, jnp.bool_(False))
+    want = (into.astype(jnp.float32) + exact).astype(jnp.bfloat16)
+    assert later.dtype == jnp.bfloat16 and bool((later == want).all())
+    assert bool((later[1] == into[1]).all()) and bool((later[3] == into[3]).all())
+    assert not bool((later[0] == plain[0]).all())
+
+    first = _weight_sums(x, dy, owner, 4, jnp.bfloat16, True,
+                         jnp.full_like(into, jnp.nan), jnp.bool_(True))
+    assert bool((first == plain).all())
+
+
 def _routing(rng, tokens, rows_of, held, offset, k):
     """chosen (tokens, k): expert ``offset + e`` is chosen by exactly
     ``rows_of[e]`` tokens, no token choosing an expert twice; every other
@@ -616,20 +652,28 @@ def _routing(rng, tokens, rows_of, held, offset, k):
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("rows_of,passes", [
-    ((0, 128, 40, 300), 1),         # an expert with no row, one with exactly a block
-    ((500, 0, 257, 129), 2),        # the rows outgrow the buffer once
-], ids=["one_pass", "two_passes"])
-def test_held_mix_is_the_loop_over_experts_and_so_are_its_gradients(dtype, rows_of, passes):
+@pytest.mark.parametrize("rows_of,passes,experts", [
+    ((0, 128, 40, 300), 1, 32),         # an expert with no row, one with exactly a block
+    ((500, 0, 257, 129), 2, 32),        # the rows outgrow the buffer once
+    ((513, 1, 385, 381), 3, 64),        # and twice: 13 blocks of rows in a buffer of 6
+], ids=["one_pass", "two_passes", "three_passes"])
+def test_held_mix_is_the_loop_over_experts_and_so_are_its_gradients(monkeypatch, dtype, rows_of,
+                                                                    passes, experts):
     """bfloat16 operands go through the grouped kernel (the interpreter
     here), float32 ones through the plain block products: both are the loop
     over experts, forward and for the gradients of ``h``, ``gates``, ``w1``
-    and ``w2``, at an expert width that is no multiple of 128."""
+    and ``w2``, at an expert width that is no multiple of 128.  The kernel
+    sums the weights' gradients into the backward loop's carry: with one
+    pass they are bit for bit what one pass outside any loop gives, its
+    kernels called once without a carried sum."""
+    from handyrl_tpu.ops import routed_experts
+
     rng = np.random.RandomState(7)
-    tokens, d, width, held, experts, k, offset = 640, 32, 192, 4, 32, 2, 8
-    assert block_rows(tokens, k, experts, dtype) == BLOCK   # 40 rows an expert: the MXU's tile
+    tokens, d, width, held, k, offset = 640, 32, 192, 4, 2, 8
+    assert block_rows(tokens, k, experts, dtype) == BLOCK   # 20 or 40 rows an expert: the MXU's tile
     blocks, _ = row_buffer(tokens, k, held, experts, BLOCK)
-    assert blocks == math.ceil(SHARES * tokens * k * held / (experts * BLOCK)) + held == 8
+    assert blocks == math.ceil(SHARES * tokens * k * held / (experts * BLOCK)) + held
+    assert blocks == {32: 8, 64: 6}[experts]
     h = jnp.asarray(rng.randn(tokens, d), dtype)
     w1 = jnp.asarray(rng.randn(held, d, width) / 4, dtype)
     w2 = jnp.asarray(rng.randn(held, width, d) / 8, dtype)
@@ -653,10 +697,16 @@ def test_held_mix_is_the_loop_over_experts_and_so_are_its_gradients(dtype, rows_
     for name, a, b in zip(("h", "gates", "w1", "w2"), got, want):
         assert a.dtype == b.dtype
         _close(a, b, dtype, "gradient of " + name)
-    # the expert with no row: its weights get no gradient
-    empty = rows_of.index(0)
-    assert not np.asarray(got[2][empty], np.float32).any()
-    assert not np.asarray(got[3][empty], np.float32).any()
+    if 0 in rows_of:     # the expert with no row: its weights get no gradient
+        empty = rows_of.index(0)
+        assert not np.asarray(got[2][empty], np.float32).any()
+        assert not np.asarray(got[3][empty], np.float32).any()
+    if dtype == jnp.bfloat16 and passes == 1:
+        monkeypatch.setattr(
+            routed_experts, "_passes", lambda h, gates, w1, w2, route, blocks, block, gated:
+            routed_experts._one_pass(h, gates, w1, w2, route, 0, blocks, block, gated))
+        once = grads(lambda h, g, a, b: held_mix(h, chosen, g, valid, a, b, offset, experts)[0])
+        assert bool((got[2] == once[2]).all()) and bool((got[3] == once[3]).all())
 
 
 def test_the_work_is_the_buffers_whatever_the_routing():

@@ -548,7 +548,7 @@ def test_the_published_layer_holds_what_the_issue_counted():
 def test_a_weight_sum_wider_than_its_scope_is_taken_in_column_tiles():
     """``ops/grouped_product.py`` ``_weight_sums`` at the cell's fused
     gate-and-up shape, (2048, 4096): one tile's float32 sum and output would
-    be 64 MB, so the columns go in two tiles (the interpreter here; the
+    be 64 MB (84 with a carried sum's tile), so the columns go in two tiles (the interpreter here; the
     described-v5e compile is tests/test_chip_compile.py's): every group's sum
     is the plain one, a group without a block zeros."""
     from handyrl_tpu.ops import grouped_product
@@ -559,6 +559,7 @@ def test_a_weight_sum_wider_than_its_scope_is_taken_in_column_tiles():
     owner = jnp.array([0, 2, 2], jnp.int32)
     got = grouped_product._weight_sums(x, dy, owner, 4, jnp.float32, True)
     assert 8 * 2048 * 4096 > grouped_product._SUMS_BYTES >= 8 * 4096 * 1536
+    assert grouped_product._SUMS_BYTES >= 10 * 2688 * 1856      # with a carried sum's tile, whole too
     blocks = lambda a: a.astype(jnp.float32).reshape(3, 16, -1)  # noqa: E731
     each = jnp.einsum("brk,brn->bkn", blocks(x), blocks(dy))
     want = jnp.stack([each[0], jnp.zeros_like(each[0]), each[1] + each[2], jnp.zeros_like(each[0])])
