@@ -91,7 +91,7 @@ import flax.linen as nn
 import numpy as np
 
 from ..ops import attention_core
-from ..ops.routed_experts import choose, held_mix
+from ..ops.routed_experts import choose, held_mix, open_sinks, reads_in_place
 from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
 from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
 from .transformer import NEG_INF, _flatten_obs
@@ -293,14 +293,18 @@ class ExpertLayer(nn.Module):
         return jax.nn.softmax(dense(z, "router_out", self.n_experts, bias=False), axis=-1), r
 
     @nn.compact
-    def __call__(self, h, valid=None, carry=None):
+    def __call__(self, h, valid=None, carry=None, stacked=None):
         """h (..., d) tokens, valid (...), carry (..., router_width) float32
         or None (the ``mlp`` router's second stream: the router's
         representation of the ``E`` layer before, None at the first) ->
         (out, chosen (..., k) int32, counts: ``held_mix``'s, the rows the held
         experts computed and the row buffer's passes and slots, with the
         ``mlp`` router also the valid tokens' ``gates`` summed and their
-        count; this layer's carry, None for the other routers).  Applied with
+        count; this layer's carry, None for the other routers).  ``stacked``
+        (a scan over periods, ``HybridNet.periods``): (the periods' ``w1``
+        stacked, their ``w2``, the period, ``held_mix``'s ``sinks``) in the
+        place of this layer's own ``w1`` and ``w2``, which its parameters
+        then lack; the sinks come back in ``counts``.  Applied with
         a mutable ``counters`` or ``choices`` collection (the acting path's
         callers that want them: step mode returns the heads alone) it also
         sows the rows held, the buffer's slots and the chosen there."""
@@ -317,8 +321,12 @@ class ExpertLayer(nn.Module):
         if self.router != "softmax":
             # chooses only; no gradient reaches it (top-k's indices carry none)
             bias = self.param("score_bias", nn.initializers.zeros, (self.n_experts,), kept)
-        w1 = self.param("w1", fan_in, (self.experts_held, d, fused * self.expert_width), kept)
-        w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d), kept)
+        if stacked is None:
+            w1 = self.param("w1", fan_in, (self.experts_held, d, fused * self.expert_width), kept)
+            w2 = self.param("w2", fan_in, (self.experts_held, self.expert_width, d), kept)
+            at = ()
+        else:
+            w1, w2, *at = stacked
         with jax.named_scope("route"):
             if self.router == "mlp":
                 # a gate is the chosen's own probability among all experts
@@ -339,7 +347,7 @@ class ExpertLayer(nn.Module):
                                            self.routed_scale)
         out, counts = held_mix(tokens, chosen, gates, ok, w1.astype(h.dtype),
                                w2.astype(h.dtype), self.expert_offset, self.n_experts,
-                               self.gated)
+                               self.gated, *at)
         if self.router == "mlp":
             counts = dict(counts, gates=jnp.where(ok[:, None], gates, 0.0).sum(),
                           gated=ok.sum() * self.top_k)
@@ -622,7 +630,8 @@ class Layer(nn.Module):
     that keeps no state hands the state it was given back, one that routes
     says what it chose.  ``carry`` is the stack's second stream: what an
     ``E`` layer's router hands the next one's (``router: mlp``; None
-    elsewhere), passed through by every other mixer."""
+    elsewhere), passed through by every other mixer.  ``stacked`` is an
+    ``ExpertLayer``'s."""
 
     mixer: nn.Module
     eps: float
@@ -632,13 +641,13 @@ class Layer(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, state, valid, carry=None):
+    def __call__(self, x, state, valid, carry=None, stacked=None):
         with jax.named_scope(NORM_SCOPE):
             h = _rms(x, self.param("norm", nn.initializers.ones, (x.shape[-1],),
                                    self.param_dtype), self.eps)
         routed = None
         if isinstance(self.mixer, ExpertLayer):
-            y, chosen, counts, carry = self.mixer(h, valid, carry)
+            y, chosen, counts, carry = self.mixer(h, valid, carry, stacked)
             routed = (chosen, counts)
         else:
             y, state = self.mixer(h, state, valid)
@@ -880,42 +889,69 @@ class HybridNet(nn.Module):
             rides in the scan's carry beside ``x``, zeros into the first
             period under a ``carry_scale`` of zeros (the first ``E`` layer has
             none: it adds nothing).  As in ``scanned`` each layer is applied
-            as a function of its parameters, so they must exist."""
+            as a function of its parameters, so they must exist.
+
+            The scan slices a period's leaves out of those stacks, and XLA
+            fuses such a slice into a product of its own; a kernel's operand
+            it copies out first.  So where the experts' products are the
+            grouped kernel's (``reads_in_place``: bfloat16) an ``E`` layer's
+            ``w1`` and ``w2`` do not go through the scan: it closes over their
+            stacks, the kernel reads the period it is told where it lies,
+            and the stacks' gradient comes back through sinks in the scan's
+            carry, written a period at a time where it lies (``ops/
+            routed_experts.py`` ``held_mix``; PERF.md, PR 51: the copies out
+            and back were 21 of ``zaya1_train_t192``'s 122.5 ms)."""
             params, width = self.variables["params"], len(repeat)
             count = len(self.pattern) // width
             wide = self.router == "mlp" and "E" in repeat
+            held = ("w1", "w2") if reads_in_place(x.dtype) else ()
 
             def application(kind):
                 free = layer(Layer, kind, parent=None)
-                fn = lambda p, x, state, carry: free.apply(  # noqa: E731
-                    {"params": p}, x, state, valid, carry)
+                fn = lambda p, x, state, carry, stacked: free.apply(  # noqa: E731
+                    {"params": p}, x, state, valid, carry, stacked)
                 return fn if remat == "none" else jax.checkpoint(fn)
 
             def of_period(i):
-                """Layer ``i`` of every period, its leaves stacked."""
+                """Layer ``i`` of every period, its leaves stacked: the scan's
+                (all but an ``E`` layer's ``held``) -> the held ones' too."""
                 each = [params[f"layer{t * width + i}"] for t in range(count)]
                 if wide and repeat[i] == "E" and "carry_scale" not in each[0]["mixer"]:
                     first = dict(each[0]["mixer"], carry_scale=jnp.zeros_like(
                         each[1]["mixer"]["carry_scale"]))
                     each[0] = dict(each[0], mixer=first)
-                return jax.tree.map(lambda *rows: jnp.stack(rows), *each)
+                apart = None
+                if repeat[i] == "E" and held:
+                    apart = tuple(jnp.stack([p["mixer"][w].astype(x.dtype) for p in each])
+                                  for w in held)
+                    each = [dict(p, mixer={k: v for k, v in p["mixer"].items() if k not in held})
+                            for p in each]
+                return jax.tree.map(lambda *rows: jnp.stack(rows), *each), apart
 
             stack = [application(kind) for kind in repeat]
+            scanned_over, apart = zip(*(of_period(i) for i in range(width)))
+            read = jax.lax.stop_gradient(apart)
 
             def one_period(carry, this):
-                (x, handed), (p_t, states_t) = carry, this
-                new, routed = [], []
+                (x, handed, sinks), (t, p_t, states_t) = carry, this
+                new, routed, sinks = [], [], list(sinks)
                 for i, apply in enumerate(stack):
-                    x, state, chose, handed = apply(p_t[i], x, states_t[i], handed)
+                    stacked = None if read[i] is None else (*read[i], t, sinks[i])
+                    x, state, chose, handed = apply(p_t[i], x, states_t[i], handed, stacked)
+                    if stacked is not None:     # the sinks go on in the carry, not out with the counts
+                        chose = chose[0], dict(chose[1])
+                        sinks[i] = chose[1].pop("sinks")
                     new.append(state)
                     routed.append(chose)
-                return (x, handed), (tuple(new), tuple(routed))
+                return (x, handed, tuple(sinks)), (tuple(new), tuple(routed))
 
             handed = jnp.zeros(x.shape[:-1] + (self.router_width,), jnp.float32) if wide else None
             by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[i::width])
                              for i in range(width))
-            (x, _), (new, routed) = jax.lax.scan(
-                one_period, (x, handed), (tuple(of_period(i) for i in range(width)), by_layer))
+            # the sinks go in as the stacks themselves: what comes back for them is the gradient
+            (x, _, sinks), (new, routed) = jax.lax.scan(
+                one_period, (x, handed, apart), (jnp.arange(count), scanned_over, by_layer))
+            x = open_sinks(x, sinks)
             at = lambda tree, t: jax.tree.map(lambda rows: rows[t], tree)  # noqa: E731
             new_states = tuple(at(new[i], t) for t in range(count) for i in range(width))
             chosen, counts = {}, {}
@@ -1012,6 +1048,10 @@ class HybridNet(nn.Module):
                 buffer_slots=sum(b["slots"] for b in buffers).astype(jnp.float32),
                 expert_passes=sum(b["passes"] - 1 for b in buffers).astype(jnp.float32),
             )
+            if "in_place" in buffers[0]:
+                # routed layer applications whose kernels read their weights in the periods' stack
+                out["counters"]["expert_stack_reads"] = sum(
+                    b["in_place"] for b in buffers).astype(jnp.float32)
             if "gates" in buffers[0]:   # ``mlp``: the mean gate a token's result was scaled by
                 out["counters"]["router_gate_mean"] = (
                     sum(b["gates"] for b in buffers)

@@ -12,6 +12,17 @@ prefetched scalar array that the weight operand's index map reads, so no
 block's weights are copied out.  Every block is computed, whatever it holds:
 the work is the buffer's, not the routing's.
 
+A ``period`` (a traced int32 scalar, prefetched beside ``owner``) is one more
+coordinate of the same maps: the weights are then a stack over periods,
+``(periods, groups, k, n)``, of which the kernels read period ``period`` where
+it lies and write that period of the stacked gradient they are handed, the
+other periods' bytes left as they are.  A scan over periods hands a kernel no
+copy that way: XLA fuses a ``dynamic-slice`` of a stacked operand into a
+product of its own and cannot fuse one into a custom call, so each period's
+weights were copied out of the stack four times an update and each period's
+gradient copied into a stacked one (PERF.md, PR 51).  Without a period every
+call is what it was, to its lowered text.
+
 * ``grouped_dot(x, w, owner)``: x (m, k), w (groups, k, n) -> (m, n) float32,
   ``out[block b] = x[block b] @ w[owner[b]]``.  The grid runs the row blocks
   innermost, so a group's weight tile stays in VMEM across its blocks and is
@@ -27,7 +38,8 @@ the work is the buffer's, not the routing's.
   one tile and uses none), a later pass ``into[g]`` plus them, added in
   float32 before the one cast.  So the loop's body holds no pass of XLA's
   over an array of the weights' shape, and the usual update, one pass, pays
-  for none (PERF.md, PR 50).
+  for none (PERF.md, PR 50).  With a ``period`` ``into`` is the stacked sum
+  ``(periods, groups, k, n)`` and the same holds of its period ``period``.
 
 Operands go to the MXU as they are handed over (bf16 in the train step) and
 accumulate in float32.  Pallas on the TPU, the Pallas interpreter elsewhere
@@ -86,41 +98,54 @@ def _call(kernel, prefetched, grid, in_specs, out_spec, out_shape, scratch, inte
     )(*prefetched, *operands)
 
 
-def _rows_kernel(owner_ref, x_ref, w_ref, o_ref, *, transposed: bool):
-    del owner_ref   # read by the index maps
+def _at(period):
+    """What a stacked operand's index maps are handed beside the other
+    prefetched vectors: the period, (1,) int32; nothing without one."""
+    return () if period is None else (jnp.reshape(period, 1).astype(jnp.int32),)
+
+
+def _rows_kernel(*refs, transposed: bool):
+    x_ref, w_ref, o_ref = refs[-3:]     # before them the prefetched, which the index maps read
     dims = (((1,), (1 if transposed else 0,)), ((), ()))
     o_ref[...] = jax.lax.dot_general(
         x_ref[...], w_ref[...], dims, preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("transposed", "out_dtype", "interpret"))
-def _rows_times(x, w, owner, transposed: bool, out_dtype, interpret: bool):
-    """out[block b] = x[block b] @ w[owner[b]] (``transposed``: @ w[owner[b]]^T).
+def _rows_times(x, w, owner, transposed: bool, out_dtype, interpret: bool, period=None):
+    """out[block b] = x[block b] @ w[owner[b]] (``transposed``: @ w[owner[b]]^T);
+    with a ``period`` w is (periods, groups, ...) and read at that period.
     Jitted, as ``_weight_sums`` is: a net calls each at a few shapes many
     times (layers, window parts, the replay), and a jitted callee is traced
     and lowered to its kernel once a shape, not once a call."""
     from jax.experimental import pallas as pl
 
-    (m, k), n = x.shape, w.shape[1 if transposed else 2]
+    (m, k), n = x.shape, w.shape[-2 if transposed else -1]
     tn, rows = _tile(n), m // owner.size
+    at = _at(period)
+    stacked = (None,) * len(at)     # the stack's dimension, at the period: ``t`` is () or (period,)
     if transposed:
-        w_spec = pl.BlockSpec((None, tn, k), lambda j, b, owner: (owner[b], j, 0))
+        w_spec = pl.BlockSpec(stacked + (None, tn, k),
+                              lambda j, b, owner, *t: (*(p[0] for p in t), owner[b], j, 0))
     else:
-        w_spec = pl.BlockSpec((None, k, tn), lambda j, b, owner: (owner[b], 0, j))
+        w_spec = pl.BlockSpec(stacked + (None, k, tn),
+                              lambda j, b, owner, *t: (*(p[0] for p in t), owner[b], 0, j))
     return _call(
-        functools.partial(_rows_kernel, transposed=transposed), (owner,),
+        functools.partial(_rows_kernel, transposed=transposed), (owner, *at),
         (pl.cdiv(n, tn), owner.size),      # row blocks innermost: a weight tile stays
-        [pl.BlockSpec((rows, k), lambda j, b, owner: (b, 0)), w_spec],
-        pl.BlockSpec((rows, tn), lambda j, b, owner: (b, j)),
+        [pl.BlockSpec((rows, k), lambda j, b, *_: (b, 0)), w_spec],
+        pl.BlockSpec((rows, tn), lambda j, b, *_: (b, j)),
         jax.ShapeDtypeStruct((m, n), out_dtype), [], interpret, x, w)
 
 
-def _sums_kernel(group_ref, block_ref, first_ref, x_ref, dy_ref, *refs):
-    """``refs``: the output's tile and the float32 sum; with a carried sum,
-    before them that sum's tile."""
+def _sums_kernel(group_ref, block_ref, first_ref, *refs, stacked: bool):
+    """``refs``: the rows' and their cotangent's blocks, the output's tile and
+    the float32 sum; with a carried sum, before the last two that sum's
+    tile; where that sum is ``stacked``, first of all the prefetched period."""
     from jax.experimental import pallas as pl
 
-    del block_ref   # read by the index maps
+    del block_ref   # read by the index maps, as the period is
+    x_ref, dy_ref, *refs = refs[1:] if stacked else refs
     into_ref, o_ref, acc_ref = refs if len(refs) == 3 else (None, *refs)
     step, last = pl.program_id(2), pl.num_programs(2) - 1
     group = group_ref[step]
@@ -159,12 +184,15 @@ def _sums_kernel(group_ref, block_ref, first_ref, x_ref, dy_ref, *refs):
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "out_dtype", "interpret"))
-def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool, into=None, first=None):
+def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool, into=None, first=None,
+                 period=None):
     """out[g] = sum over the blocks b with owner[b] == g of x[b]^T @ dy[b];
     with ``into`` (groups, k, n) in ``out_dtype`` and ``first`` () bool, the
     sum a loop carries: ``into``'s buffer is the output's, and out[g] is that
     sum alone where ``first`` (whatever ``into`` holds), else ``into[g]`` plus
-    it, added in float32 before the one cast.
+    it, added in float32 before the one cast.  With a ``period``, ``into`` is
+    (periods, groups, k, n) and all of that is said of ``out[period]``: no
+    other period's tile is on the grid, so what ``into`` held there stays.
 
     The grid's innermost axis walks each group's blocks and then one step
     more that holds no block and writes the group's sum out: so a group
@@ -183,50 +211,61 @@ def _weight_sums(x, dy, owner, groups: int, out_dtype, interpret: bool, into=Non
         tn //= 2    # more column tiles: the rows' blocks are read once more each
     group = jnp.sort(jnp.concatenate([owner, jnp.arange(groups, dtype=owner.dtype)]))
     block = jnp.minimum(jnp.arange(blocks + groups, dtype=owner.dtype) - group, blocks - 1)
-    out = jax.ShapeDtypeStruct((groups, k, n), out_dtype)
-    in_specs = [pl.BlockSpec((rows, tk), lambda i, j, s, group, block, first: (block[s], i)),
-                pl.BlockSpec((rows, tn), lambda i, j, s, group, block, first: (block[s], j))]
+    at = _at(period)
+    stacked = (None,) * len(at)     # the stack's dimension, at the period: ``t`` is () or (period,)
+    out = jax.ShapeDtypeStruct((groups, k, n) if period is None else into.shape, out_dtype)
+    in_specs = [pl.BlockSpec((rows, tk), lambda i, j, s, group, block, *_: (block[s], i)),
+                pl.BlockSpec((rows, tn), lambda i, j, s, group, block, *_: (block[s], j))]
     operands, aliases = (x, dy), None
     if into is None:
         first = True
     else:
-        assert (into.shape, into.dtype) == (out.shape, out.dtype), (into.shape, into.dtype)
+        assert (into.shape[-3:], into.dtype) == ((groups, k, n), out.dtype), (into.shape, into.dtype)
         in_specs.append(pl.BlockSpec(
-            (None, tk, tn), lambda i, j, s, group, block, first: tuple(
-                jnp.where(first[0] != 0, 0, at) for at in (group[s], i, j)),
+            stacked + (None, tk, tn), lambda i, j, s, group, block, first, *t: tuple(
+                jnp.where(first[0] != 0, 0, index)
+                for index in (*(p[0] for p in t), group[s], i, j)),
             pipeline_mode=pl.Buffered(1)))
-        operands, aliases = (x, dy, into), {5: 0}    # counted from the prefetched three
+        operands, aliases = (x, dy, into), {5 + len(at): 0}    # counted from the prefetched
     return _call(
-        _sums_kernel, (group, block, jnp.reshape(first, 1).astype(jnp.int32)),
+        functools.partial(_sums_kernel, stacked=bool(at)),
+        (group, block, jnp.reshape(first, 1).astype(jnp.int32), *at),
         (pl.cdiv(k, tk), pl.cdiv(n, tn), blocks + groups), in_specs,
-        pl.BlockSpec((None, tk, tn), lambda i, j, s, group, block, first: (group[s], i, j)),
+        pl.BlockSpec(stacked + (None, tk, tn),
+                     lambda i, j, s, group, block, first, *t: (*(p[0] for p in t), group[s], i, j)),
         out, [pltpu.VMEM((tk, tn), jnp.float32)], interpret, *operands, aliases=aliases)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def grouped_dot(x, w, owner, interpret: Optional[bool] = None, into=None):
+def grouped_dot(x, w, owner, interpret: Optional[bool] = None, into=None, period=None):
     """x (m, k) in ``owner.size`` blocks of equal height, w (groups, k, n),
     owner (blocks,) int32 non-decreasing -> (m, n) float32: each block's
     rows times its group's weights.  ``into``: (a sum of ``w``'s shape and
     dtype that the caller's loop carries, first () bool); ``w``'s cotangent
     is then that sum with this call's gradient added (where ``first``: the
-    gradient alone, whatever the sum holds), in the sum's own buffer."""
+    gradient alone, whatever the sum holds), in the sum's own buffer.
+    ``period`` () int32: w is (periods, groups, k, n), the weights are its
+    period ``period``, and of ``w``'s cotangent, the stacked sum, this call
+    writes that period alone (with no ``into``: into zeros)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _rows_times(x, w, owner, False, jnp.float32, interpret)
+    return _rows_times(x, w, owner, False, jnp.float32, interpret, period)
 
 
-def _grouped_fwd(x, w, owner, interpret, into):
-    return grouped_dot(x, w, owner, interpret), (x, w, owner, into)
+def _grouped_fwd(x, w, owner, interpret, into, period):
+    return grouped_dot(x, w, owner, interpret, None, period), (x, w, owner, into, period)
 
 
 def _grouped_bwd(interpret, saved, dy):
-    x, w, owner, into = saved
+    x, w, owner, into, period = saved
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if period is not None and into is None:
+        into = (jnp.zeros_like(w), True)
     dy = dy.astype(x.dtype)     # the MXU's operand, as the weights are
-    return (_rows_times(dy, w, owner, True, x.dtype, interpret),
-            _weight_sums(x, dy, owner, w.shape[0], w.dtype, interpret, *(into or ())), None, None)
+    return (_rows_times(dy, w, owner, True, x.dtype, interpret, period),
+            _weight_sums(x, dy, owner, w.shape[-3], w.dtype, interpret, *(into or ()), period=period),
+            None, None, None)
 
 
 grouped_dot.defvjp(_grouped_fwd, _grouped_bwd)

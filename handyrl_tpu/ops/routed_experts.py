@@ -30,6 +30,9 @@ tiles is in PERF.md, PR 34).  An update whose rows outgrow the buffer takes
 further passes over it, as many as its rows need (``_passes``: a
 ``lax.while_loop`` forward and backward, so the worst case, every token
 choosing ``min(top_k, held)`` held experts, costs time and no memory).
+A caller that scans over equal periods hands ``held_mix`` the periods'
+stacked weights and the period, and the kernels read and write that period
+where it lies (``_passes_at``, ``open_sinks``; PERF.md, PR 51).
 Filling the buffer and summing a token's rows back are each other's
 transpose and are written as gathers both ways (``_dispatch`` /
 ``_combine``), so no scatter runs forward or backward.
@@ -145,7 +148,15 @@ def row_buffer(n: int, top_k: int, held: int, experts: int, block: int) -> Tuple
     return blocks, -(-worst // blocks)
 
 
-def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: bool = False):
+def reads_in_place(dtype) -> bool:
+    """Whether ``held_mix`` can be handed the stack of a scan's periods and
+    the period (its ``period``), not the period's weights: where the products
+    are the kernel's, which reads a period where it lies."""
+    return _in_kernel(dtype)
+
+
+def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: bool = False,
+             period=None, sinks=None):
     """The held experts' part of the mix.
 
     h (n, d) tokens, chosen (n, k) int32 over all ``experts``, gates (n, k)
@@ -155,9 +166,21 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
     dtype: sum over a token's chosen experts that are held of gate x
     w2[e] relu(w1[e] h)^2, ``gated`` w2[e] (silu(a) b); counts: ``rows`` (held,) int32 the rows each held
     expert computed, ``passes`` () int32 the passes over the row buffer that
-    took them, ``slots`` () int32 the buffer slots those passes computed)."""
+    took them, ``slots`` () int32 the buffer slots those passes computed).
+
+    ``period`` () int32 with ``sinks`` (two arrays of ``w1``'s and ``w2``'s
+    shape and dtype), for a caller that scans over equal periods
+    (``reads_in_place``): w1 (periods, held, d, w) and w2 (periods, held, w,
+    d) are the periods' stacks, which the scan closes over with no gradient
+    path (``stop_gradient``: a stack with one has its cotangent summed whole
+    every iteration), and the weights are their period ``period``, read where
+    they lie.  The gradient goes through the sinks instead: they ride the
+    scan's carry untouched and come back under ``counts["sinks"]``, so their
+    cotangent is a carry of the backward scan, the stacked gradient, of
+    which this call's backward writes period ``period`` in place (``_passes_at``;
+    ``open_sinks`` starts it).  ``counts["in_place"]`` is then 1."""
     n, k = chosen.shape
-    held = w1.shape[0]
+    held = w1.shape[-3]
     block = block_rows(n, k, experts, h.dtype)
     local = chosen - offset
     live = (local >= 0) & (local < held) & valid[:, None]
@@ -175,19 +198,25 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
              "ends": ends, "base": base}
     blocks = row_buffer(n, k, held, experts, block)[0]
     passes = _needed(route, blocks * block)
-    return (_passes(h, gates, w1, w2, route, blocks, block, gated),
-            {"rows": rows, "passes": passes, "slots": passes * (blocks * block)})
+    if period is None:
+        out, more = _passes(h, gates, w1, w2, route, blocks, block, gated), {}
+    else:
+        assert reads_in_place(h.dtype), h.dtype
+        out, sinks = _passes_at(h, gates, w1, w2, route, period, sinks, blocks, block, gated)
+        more = {"sinks": sinks, "in_place": jnp.int32(1)}
+    return out, {"rows": rows, "passes": passes, "slots": passes * (blocks * block), **more}
 
 
-def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None)):
+def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None), period=None):
     """x (m, d) in ``owner.size`` blocks of equal height, block ``b`` of
     expert ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block,
     ``gated`` w2[e] (silu(a) b) with [a, b] = w1[e] x.  ``into`` (the kernel's
     products only): ``grouped_dot``'s ``into`` for w1's product and for
-    w2's, the sums of their gradients that a loop over passes carries."""
+    w2's, the sums of their gradients that a loop over passes carries;
+    ``period`` (the same only): ``grouped_dot``'s, w1 and w2 stacks."""
     if _in_kernel(x.dtype):
         def product(rows, w, into):
-            return grouped_dot(rows, w, owner, None, into)
+            return grouped_dot(rows, w, owner, None, into, period)
     else:
         def product(rows, w, _):
             blocks = rows.reshape(owner.size, -1, rows.shape[1])
@@ -213,9 +242,9 @@ def _owners(ends, start, blocks: int, block: int):
 
 
 def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bool = False,
-              into=(None, None)):
+              into=(None, None), period=None):
     """Slots [start, start + blocks x block) of the row buffer ``route`` lays
-    out.  ``into``: ``_block_products``'."""
+    out.  ``into``, ``period``: ``_block_products``'."""
     n, k = route["slot"].shape
     m = blocks * block
     rows, first, ends, base = (route[key] for key in ("rows", "first", "ends", "base"))
@@ -231,7 +260,7 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bo
         x = _dispatch(h, tok, at, in_buffer)
         gate = jnp.where(used, gates.reshape(-1)[pair], 0.0)
     with jax.named_scope(EXPERTS_SCOPE):
-        down = _block_products(x, w1, w2, owner, gated, into)
+        down = _block_products(x, w1, w2, owner, gated, into, period)
     with jax.named_scope("route"):
         # a slot no row fills has gate 0
         weighted = (down * gate[:, None]).astype(h.dtype)
@@ -242,6 +271,47 @@ def _needed(route, slots: int):
     """Passes over a buffer of ``slots`` that the rows laid out take: one,
     but for an update whose rows outgrow it."""
     return jnp.maximum(1, -(-route["ends"][-1] // slots))
+
+
+def _every_pass(h, gates, w1, w2, route, blocks: int, block: int, gated: bool, period=None):
+    """The forward loop: one more pass while the rows laid out need one."""
+    def one_more(carry):
+        done, out = carry
+        return done + 1, out + _one_pass(
+            h, gates, w1, w2, route, done * blocks * block, blocks, block, gated, period=period)
+
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
+        (jnp.int32(0), jnp.zeros_like(h)))[1]
+
+
+def _every_pass_back(blocks: int, block: int, gated: bool, saved, d_out, period=None, sums=None):
+    """The backward loop -> the cotangents of (h, gates, w1, w2), the last
+    two the sums this loop carries: fresh allocations, or with a ``period``
+    ``sums``, the stacked gradients handed in, whose period ``period`` the
+    passes' kernels write."""
+    h, gates, w1, w2, route = saved
+    carried = _in_kernel(h.dtype)   # else the plain products' gradients, summed here
+
+    def one_more(carry):
+        done, (d_h, d_gates, d_w1, d_w2) = carry
+        first = done == 0
+        into = ((d_w1, first), (d_w2, first)) if carried else (None, None)
+        _, pull = jax.vjp(
+            lambda *a: _one_pass(
+                *a, route, done * blocks * block, blocks, block, gated, into, period),
+            h, gates, w1, w2)
+        more_h, more_gates, sum_w1, sum_w2 = pull(d_out)
+        if not carried:
+            sum_w1, sum_w2 = d_w1 + sum_w1, d_w2 + sum_w2
+        return done + 1, (d_h + more_h, d_gates + more_gates, sum_w1, sum_w2)
+
+    # the first pass's kernels write the weights' sums whole, whatever the buffers hold
+    fresh = (lambda a: jax.lax.empty(a.shape, a.dtype)) if carried else jnp.zeros_like
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
+        (jnp.int32(0), (jnp.zeros_like(h), jnp.zeros_like(gates),
+                        *(sums or (fresh(w1), fresh(w2))))))[1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -257,15 +327,10 @@ def _passes(h, gates, w1, w2, route, blocks: int, block: int, gated: bool = Fals
     ``_weight_sums``): no pass of XLA's over a weight-shaped array is in
     the loop's body or before it.  (The first pass is not taken out of the
     loop: a second copy of the pass's kernels made the step's executable a
-    quarter larger and its load 8 s longer, PERF.md, PR 40.)"""
-    def one_more(carry):
-        done, out = carry
-        return done + 1, out + _one_pass(
-            h, gates, w1, w2, route, done * blocks * block, blocks, block, gated)
-
-    return jax.lax.while_loop(
-        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
-        (jnp.int32(0), jnp.zeros_like(h)))[1]
+    quarter larger and its load 8 s longer, PERF.md, PR 40.)  Both loops are
+    ``_passes_at``'s too, which runs them over one period of stacked weights
+    and carries the stacked sums from call to call."""
+    return _every_pass(h, gates, w1, w2, route, blocks, block, gated)
 
 
 def _passes_fwd(h, gates, w1, w2, route, blocks, block, gated):
@@ -273,27 +338,51 @@ def _passes_fwd(h, gates, w1, w2, route, blocks, block, gated):
 
 
 def _passes_bwd(blocks, block, gated, saved, d_out):
-    h, gates, w1, w2, route = saved
-    carried = _in_kernel(h.dtype)   # else the plain products' gradients, summed here
-
-    def one_more(carry):
-        done, (d_h, d_gates, d_w1, d_w2) = carry
-        first = done == 0
-        into = ((d_w1, first), (d_w2, first)) if carried else (None, None)
-        _, pull = jax.vjp(
-            lambda *a: _one_pass(*a, route, done * blocks * block, blocks, block, gated, into),
-            h, gates, w1, w2)
-        more_h, more_gates, sum_w1, sum_w2 = pull(d_out)
-        if not carried:
-            sum_w1, sum_w2 = d_w1 + sum_w1, d_w2 + sum_w2
-        return done + 1, (d_h + more_h, d_gates + more_gates, sum_w1, sum_w2)
-
-    # the first pass's kernels write the weights' sums whole, whatever the buffers hold
-    fresh = (lambda a: jax.lax.empty(a.shape, a.dtype)) if carried else jnp.zeros_like
-    sums = jax.lax.while_loop(
-        lambda carry: carry[0] < _needed(route, blocks * block), one_more,
-        (jnp.int32(0), (jnp.zeros_like(h), jnp.zeros_like(gates), fresh(w1), fresh(w2))))[1]
-    return (*sums, None)
+    return (*_every_pass_back(blocks, block, gated, saved, d_out), None)
 
 
 _passes.defvjp(_passes_fwd, _passes_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _passes_at(h, gates, w1, w2, route, period, sinks, blocks: int, block: int, gated: bool):
+    """``_passes`` over period ``period`` of the stacks w1 and w2, which a
+    scan over periods closes over: -> (out, ``sinks`` as they came).  The
+    backward loop's two weight-shaped carries are the sinks' cotangents, the
+    stacked gradients that the backward scan carries from period to period:
+    a pass's kernels write period ``period`` of them where it lies (a
+    period's first pass its sums alone, whatever the buffer held), so the
+    scan's transpose neither copies a period's gradient into a stacked one
+    nor fills that with zeros first, and the stacks themselves get none."""
+    return _every_pass(h, gates, w1, w2, route, blocks, block, gated, period), sinks
+
+
+def _passes_at_fwd(h, gates, w1, w2, route, period, sinks, blocks, block, gated):
+    return (_passes_at(h, gates, w1, w2, route, period, sinks, blocks, block, gated),
+            ((h, gates, w1, w2, route), period))
+
+
+def _passes_at_bwd(blocks, block, gated, saved, cotangents):
+    (saved, period), (d_out, d_sinks) = saved, cotangents
+    d_h, d_gates, *sums = _every_pass_back(blocks, block, gated, saved, d_out, period, d_sinks)
+    return d_h, d_gates, None, None, None, None, tuple(sums)
+
+
+_passes_at.defvjp(_passes_at_fwd, _passes_at_bwd)
+
+
+def open_sinks(out, sinks):
+    """``out``, past the last call that was handed ``sinks`` (a scan's final
+    carry): their cotangent starts here, and as allocations, not zeros: the
+    backward scan's first pass over a period writes that period whole, and it
+    comes to every period."""
+    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), sinks)
+
+    @jax.custom_vjp
+    def opened(out, sinks):
+        return out
+
+    opened.defvjp(
+        lambda out, sinks: (out, None),
+        lambda _, d_out: (d_out, jax.tree.map(lambda a: jax.lax.empty(a.shape, a.dtype), like)))
+    return opened(out, sinks)

@@ -250,6 +250,121 @@ def test_gated_top1_experts_and_their_gradient_compile_for_v5e_at_zaya1s_widths(
         6144: (128, 56, 1), 512: (128, 12, 1)}[tokens]
 
 
+@pytest.mark.parametrize("tokens", [6144, 512], ids=["forward_part", "burn_in_part"])
+def test_a_scan_over_periods_of_stacked_experts_compiles_for_v5e_with_no_copy_of_a_period(
+        v5e, monkeypatch, tokens):
+    """``held_mix`` under a ``lax.scan`` over three periods at ``zaya1_8b``'s
+    widths (the test above's), handed the periods' stacked weights, the period
+    and the sinks, and its gradient: the kernels compile inside their VMEM
+    scope with the period as one more prefetched coordinate, and outside them
+    nothing in the compiled program is rooted at a stacked or a per-period
+    weight shape that copies, slices, adds or zero-fills one: no
+    ``dynamic-slice`` (the scan's way out of a stack, which XLA cannot fuse
+    into a custom call), no ``dynamic-update-slice`` (its way back in), no
+    ``copy`` at a loop's boundary, no ``add`` of a closed-over stack's
+    cotangent, no ``broadcast`` of zeros; the stacked gradients start as
+    allocations (PERF.md, PR 51).  Each kernel is in the program once a call
+    site: two forward, and under the backward scan those two again, two rows'
+    cotangents and two weight sums whose stacked sum is aliased to the
+    output."""
+    from handyrl_tpu.ops.routed_experts import held_mix, open_sinks
+
+    d, width, held, experts, k, periods = 2048, 2048, 8, 16, 1, 3
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+
+    def loss(h, gates, w1, w2, chosen, valid):
+        read = jax.lax.stop_gradient((w1, w2))
+
+        def one_period(carry, t):
+            h, sinks = carry
+            out, counts = held_mix(h, chosen, gates, valid, *read, 0, experts, True, t, sinks)
+            return (h + out, counts["sinks"]), None
+
+        (h, sinks), _ = jax.lax.scan(one_period, (h, (w1, w2)), jnp.arange(periods))
+        return (open_sinks(h, sinks).astype(jnp.float32) ** 2).sum()
+
+    shapes = ((periods, held, d, 2 * width), (periods, held, width, d))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        aval((tokens, d), jnp.bfloat16), aval((tokens, k), jnp.float32),
+        aval(shapes[0], jnp.bfloat16), aval(shapes[1], jnp.bfloat16),
+        aval((tokens, k), jnp.int32), aval((tokens,), jnp.bool_))
+    text = lowered.compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2 + 6, len(calls)
+    sums = [line for line in calls if "output_to_operand_aliasing" in line]
+    assert len(sums) == 2 and all("_weight_sums" in line for line in sums), len(sums)
+    for shape in shapes + tuple(shape[1:] for shape in shapes):
+        dims = ",".join(map(str, shape))
+        # a slice of a stack keeps a leading 1 until a bitcast drops it
+        rooted = re.compile(r"= bf16\[(1,)?%s\]\S* (dynamic-slice|dynamic-update-slice|copy|"
+                            r"copy-start|add|broadcast)\(" % dims)
+        found = [line.strip()[:160] for line in text.splitlines() if rooted.search(line)]
+        assert not found, found[:3]
+    _no_pass_over_the_weights_sums(lowered, text, *shapes)
+
+
+# sha256 of the tiny ``nemotron_*`` cell's train step as lowered on the CPU
+# (``benchmark/tests/tiny_hybrid/``: ``MEM*E``, no period to scan) at the
+# commit before PR 51, in float32 (the plain block products) and in bfloat16
+# (the grouped kernel in the interpreter).  A change that means to alter that
+# program replaces these; one that only adds a path for another net must not.
+_TINY_ROUTED_STEP = {
+    "float32": "fdedff4cf118631fbfbff7ce838de40fa8ee926dead04e1e06ec8b38f6654b4c",
+    "bfloat16": "6a6ae1e8c8c3aea56d01e256fa44a6c5d1b590ab991330419f319de98b41c6dd",
+}
+
+
+@pytest.mark.parametrize("dtype", sorted(_TINY_ROUTED_STEP))
+def test_a_routed_step_without_periods_lowers_to_the_text_it_had(dtype):
+    """The grouped kernels take a period only where they are handed one: the
+    tiny ``nemotron_*`` cell's step, which unrolls its stack and calls them
+    without, lowers to the text it had before they could (PERF.md, PR 51: a
+    Pallas program's cache key holds its callers, so the routed cells' first
+    runs are cold after any edit there; the program they compile is not to
+    change with it)."""
+    import hashlib
+    import json
+    import random
+
+    import numpy as np
+
+    from benchmark import traffic
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.envs import make_env
+    from handyrl_tpu.parallel import TrainContext, make_mesh
+
+    tiny = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "tests", "tiny_hybrid")
+    with open(os.path.join(tiny, "workloads", "tiny_hybrid_train.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(tiny, "configs", "tiny_hybrid.json")) as f:
+        config = json.load(f)
+    cfg = normalize_args({"env_args": dict(config["env_args"]),
+                          "train_args": dict(cell["train_args"], compute_dtype=dtype, seed=1)})
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    random.seed(1)
+    np.random.seed(1)
+    env = make_env(args["env"])
+    module = env.net()
+    ctx = TrainContext(module, args, make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    env.reset()
+    obs = jax.tree.map(lambda x: jnp.asarray(x)[None], env.observation(env.players()[0]))
+    params = jax.eval_shape(
+        lambda key: module.init(key, obs, module.initial_state((1,)))["params"],
+        jax.random.PRNGKey(0))
+    state = {"params": params, "opt_state": jax.eval_shape(ctx.tx.init, params),
+             "steps": jax.ShapeDtypeStruct((), jnp.int32)}
+    small = traffic.random_play_batches(env, module, dict(args, batch_size=2), 1, 4)[0]
+    batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (int(args["batch_size"]),) + np.shape(x)[1:], np.asarray(x).dtype), small)
+    text = jax.jit(ctx._step_fn, donate_argnums=(0,)).lower(
+        state, batch, jax.ShapeDtypeStruct((), jnp.float32)).as_text()
+    assert "stablehlo.while" in text and "4x32x32x" in text      # the pass loops, the held experts
+    assert hashlib.sha256(text.encode()).hexdigest() == _TINY_ROUTED_STEP[dtype]
+
+
 # -- a window part's attention core (ops/attention_core.py) ------------------
 
 # (d_model, query heads, KV heads) of the two HybridNet cells, heads of 128:

@@ -297,6 +297,76 @@ def test_the_carry_crosses_remat_block(toy):
     assert float(jnp.abs(heard - unheard).max()) > 1e-6
 
 
+def _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in):
+    """The toy net's loss over the value and policy heads, its counters and
+    every leaf's gradient, weights and stream in bfloat16 (the experts'
+    products are then the grouped kernel's, in the interpreter)."""
+    to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
+
+    def loss(p):
+        out = module.apply({"params": to(p, jnp.bfloat16)}, to(obs, jnp.bfloat16), None, seq=True,
+                           key_mask=mask, burn_in=burn_in, remat=remat)
+        return (jnp.sum(jnp.square(out["value"].astype(jnp.float32) * mask[..., None]))
+                + 0.1 * jnp.sum(out["policy"].astype(jnp.float32) * mask[..., None]),
+                out["counters"])
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+
+@pytest.mark.parametrize("burn_in", [0, 4])
+@pytest.mark.parametrize("remat", ["none", "block"])
+def test_the_scan_reads_the_stacked_experts_in_place_and_is_the_unrolled_stack(
+        toy, monkeypatch, remat, burn_in):
+    """In bfloat16 the scan over periods closes over the ``E`` layers' stacked
+    ``w1`` and ``w2``, the grouped kernel reads a period where it lies and
+    the stacked gradient comes back through the sinks in the scan's carry
+    (PERF.md, PR 51): loss and every leaf's gradient are the unrolled
+    stack's (``passes``, which a pattern with no period takes) within the
+    bfloat16 tolerance, with and without a checkpoint a layer, with and
+    without a burn-in part.  ``counters["expert_stack_reads"]`` counts the
+    routed layer applications that read in place: three periods a window
+    part."""
+    module, params, obs, mask, _ = toy
+    (loss, counters), grads = _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in)
+    assert float(counters["expert_stack_reads"]) == (6 if burn_in else 3)
+    assert float(counters["expert_passes"]) == 0
+    monkeypatch.setattr(hybrid, "_period", lambda pattern: pattern)     # no period: unrolled
+    (want, unrolled), want_grads = _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in)
+    assert "expert_stack_reads" not in unrolled
+    for name in ("rows_held", "buffer_slots", "router_gate_mean"):
+        assert float(counters[name]) == pytest.approx(float(unrolled[name]), rel=0.02), name
+    assert abs(float(loss) - float(want)) < BF16_TOLERANCE * max(1.0, abs(float(want)))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want_grads)):
+        assert a.dtype == b.dtype and bool(jnp.isfinite(a).all()), path
+        assert float(jnp.abs(a - b).max()) < BF16_TOLERANCE * max(1.0, float(jnp.abs(b).max())), path
+    reached = [layer for layer in ("layer1", "layer3", "layer5")   # periods whose held experts got rows
+               if float(jnp.abs(want_grads[layer]["mixer"]["w1"]).max()) > 0]
+    assert len(reached) >= 2, reached
+    for layer in reached:       # the sinks' cotangent came back for each of them
+        for name in ("w1", "w2"):
+            assert float(jnp.abs(grads[layer]["mixer"][name]).max()) > 0, (layer, name)
+
+
+def test_a_float32_scan_and_a_stack_without_periods_read_no_stack_in_place(toy):
+    """Float32 operands keep the plain block products and the scan's own
+    slices of every leaf, and a ``MEME`` stack scans nothing: neither counts
+    a read in place."""
+    module, params, obs, mask, _ = toy
+    out = jax.jit(lambda p: module.apply({"params": p}, obs, None, seq=True, key_mask=mask,
+                                         burn_in=4))(params)
+    assert "expert_stack_reads" not in out["counters"] and "rows_held" in out["counters"]
+    plain = HybridNet(num_actions=7, pattern="MEME", d_model=32, n_experts=8, top_k=2,
+                      expert_width=16, shared_width=16, experts_held=4)
+    weights = plain.init(jax.random.PRNGKey(0), {"a": jnp.ones((ROWS, 5))},
+                         plain.initial_state((ROWS,)))["params"]
+    to = lambda tree: jax.tree.map(lambda x: x.astype(jnp.bfloat16), tree)  # noqa: E731
+    out = jax.jit(lambda p: plain.apply({"params": p}, to(obs), None, seq=True, key_mask=mask,
+                                        burn_in=4))(to(weights))
+    assert "rows_held" in out["counters"]
+    assert float(out["counters"].get("expert_stack_reads", 0.0)) == 0.0
+
+
 def test_the_two_shares_of_a_layer_add_up_to_the_uncut_reference(toy):
     """Offsets 0 and 4 of the two-chip deployment: each share scores and
     chooses over all eight experts with the whole router (the same choices,
