@@ -4,7 +4,9 @@ mixer, ``E`` a routed expert layer (with a shared expert where
 rotary positions where ``rope_theta`` is set), ``-`` a dense gated MLP
 (SwiGLU), ``C`` compressed convolutional attention (grouped-query attention
 in a latent whose queries and keys two causal convolutions mix over the last
-steps: ``CompressedConvAttention``).  Each layer is ``x + mixer(RMSNorm(x))``, or with
+steps: ``CompressedConvAttention``), ``L`` multi-head latent attention (keys
+and values made from one low-rank latent a token, beside one rotated key part
+that all heads share: ``LatentAttention``).  Each layer is ``x + mixer(RMSNorm(x))``, or with
 ``sandwich`` ``x + RMSNorm(mixer(RMSNorm(x)))``; no biases but the conv's.
 ``out_scale_init`` is what the second norm's scale starts at: under 1, an
 untrained stack is nearer the identity, as deep residual nets are started.
@@ -40,7 +42,9 @@ carries between steps: the SSM state (float32) and the conv's last inputs
 for ``M``, a ring of the last ``memory_len`` keys (rotated, where they are)
 and values for ``*``, for ``C`` that ring in its latent, the last rows of
 queries and keys its convolutions look back on (``tail``) and the last
-step's shifted value (``prev_v``), nothing for ``E`` and ``-``.  Like ``TransformerNet``
+step's shifted value (``prev_v``), for ``L`` a ring of the last ``memory_len``
+latents with their rotated key part (``latent``: nothing per head), nothing
+for ``E`` and ``-``.  Like ``TransformerNet``
 it has two modes over one parameter set:
 
 * step mode — ``apply(obs, hidden)``: one step of every recurrence (acting,
@@ -70,7 +74,9 @@ it has two modes over one parameter set:
 their own experts' terms only (``ops/routed_experts.py``).  The window mode
 returns, beside the heads, ``choices`` (per ``E`` layer the experts each
 token chose, (rows, T, top_k)) and ``counters`` (the packed array's slots,
-the observed steps, those the packing left out, with ``E`` layers the
+the observed steps, those the packing left out, with ``L`` layers the values
+they hand from burn-in to the forward part and what a head's keys and values
+of those steps would be, with ``E`` layers the
 rows the held experts computed, the slots of the row buffers they were
 computed in and the passes past the first those took, and with ``loops``
 over 1 the layer applications of a forward and the mean ``exit`` share the
@@ -96,15 +102,20 @@ from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
 from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
 from .transformer import NEG_INF, _flatten_obs
 
-KINDS = "ME*-C"     # Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention
+# Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention, latent attention
+KINDS = "ME*-CL"
 # ``jax.named_scope``s round the dense trunk's phases: a component of each of
 # their ops' ``op_name`` in a device profile, forward and backward (the
 # benchmark's ``mlp_roofline``, ``attn_step_share`` and ``norm_step_share``
 # import them; docs/observability.md has the naming rule).  ``attn`` holds
 # the projections, ``rope`` and ``gqa``, and in a ``C`` mixer ``cca_mix``: what
-# it does to queries, keys and values between the projections and the rotation
+# it does to queries, keys and values between the projections and the rotation;
+# in an ``L`` mixer ``mla_proj`` (its projections, the latent's norm, and what
+# the latent-to-heads map does in either form) and ``mla_core`` (scores, mask,
+# softmax and mix)
 ATTN_SCOPE, ROPE_SCOPE, GQA_SCOPE, MLP_SCOPE, NORM_SCOPE = "attn", "rope", "gqa", "mlp", "norm"
 CCA_SCOPE = "cca_mix"
+MLA_PROJ_SCOPE, MLA_CORE_SCOPE = "mla_proj", "mla_core"
 _EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
 
 
@@ -129,13 +140,29 @@ def _rms(x, scale, eps: float, groups: int = 1):
 def _rope(x, pos, theta: float):
     """Rotary positions over the whole last axis of ``x`` (N, L, ..., D),
     rotate-half pairing (d with d + D/2), at ``pos`` (N, L); in float32."""
+    cos, sin = _turns(x, pos, theta)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def _turns(x, pos, theta: float):
+    """(cos, sin) of the angles the D/2 pairs of ``x`` (N, L, ..., D) turn
+    by at ``pos`` (N, L), shaped to broadcast against a half of ``x``."""
     half = x.shape[-1] // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = pos.astype(jnp.float32)[..., None] * inv_freq            # (N, L, D/2)
     angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (half,))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rope_pairs(x, pos, theta: float):
+    """Rotary positions over the whole last axis of ``x`` (N, L, ..., R),
+    adjacent pairing (2j with 2j + 1, by ``pos * theta ** (-2j / R)``), at
+    ``pos`` (N, L); float32 in and out."""
+    cos, sin = _turns(x, pos, theta)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1).reshape(x.shape)
 
 
 def _period(pattern: str) -> str:
@@ -144,6 +171,17 @@ def _period(pattern: str) -> str:
         if len(pattern) % width == 0 and pattern[:width] * (len(pattern) // width) == pattern:
             return pattern[:width]
     return pattern
+
+
+def _periods(pattern: str):
+    """(lead, repeat): the fewest leading layers behind which ``pattern`` is
+    three or more repetitions of ``repeat`` (``"L-LELELELE"``: 2, ``"LE"``);
+    (the pattern's length, ``""``) where it never is."""
+    for lead in range(len(pattern)):
+        repeat = _period(pattern[lead:])
+        if len(pattern) - lead >= 3 * len(repeat):
+            return lead, repeat
+    return len(pattern), ""
 
 
 def _compact(key_mask, order=None):
@@ -452,16 +490,7 @@ def _grouped_rows(q, k, v, state, valid, step: bool, memory_len: int, score_scal
         before = state["n"].astype(jnp.int32)
         keys = jnp.concatenate([state["k"].astype(k.dtype), k], axis=1)
         values = jnp.concatenate([state["v"].astype(v.dtype), v], axis=1)
-        past = state["k"].shape[1]
-        # positions count observed steps: the past's come first
-        key_pos = jnp.concatenate([
-            jnp.broadcast_to(jnp.arange(past)[None, :], (n, past)),
-            before[:, None] + jnp.arange(length)[None, :]], axis=1)
-        key_ok = jnp.concatenate([
-            jnp.arange(past)[None, :] < before[:, None], valid], axis=1)
-        query_pos = before[:, None] + jnp.arange(length)[None, :]
-        gap = query_pos[:, :, None] - key_pos[:, None, :]
-        allowed = key_ok[:, None, :] & (gap >= 0) & (gap < memory_len)
+        allowed = _seen_from(before, state["k"].shape[1], valid, memory_len)
         new_state = {"k": keys, "v": values, "n": before + valid.sum(axis=1)}
     scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, keys.astype(q.dtype),
                         preferred_element_type=jnp.float32)
@@ -469,6 +498,23 @@ def _grouped_rows(q, k, v, state, valid, step: bool, memory_len: int, score_scal
     scores = jnp.where(allowed[:, None, None], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("ngrqk,nkgd->nqgrd", weights, values.astype(q.dtype)), new_state
+
+
+def _seen_from(before, past: int, valid, memory_len: int):
+    """(N, L, past + L) bool: which of a window part's keys (the ``past``
+    slots handed over, ``before`` (N,) of them observed, then the part's own
+    under ``valid`` (N, L)) each of its queries sees: those at or before it
+    among the row's observed steps, fewer than ``memory_len`` back."""
+    n, length = valid.shape
+    # positions count observed steps: the past's come first
+    key_pos = jnp.concatenate([
+        jnp.broadcast_to(jnp.arange(past)[None, :], (n, past)),
+        before[:, None] + jnp.arange(length)[None, :]], axis=1)
+    key_ok = jnp.concatenate([
+        jnp.arange(past)[None, :] < before[:, None], valid], axis=1)
+    query_pos = before[:, None] + jnp.arange(length)[None, :]
+    gap = query_pos[:, :, None] - key_pos[:, None, :]
+    return key_ok[:, None, :] & (gap >= 0) & (gap < memory_len)
 
 
 def _whole_rows(q, k, v, state, valid, heads: int, memory_len: int, rope_theta: float):
@@ -608,6 +654,135 @@ class CompressedConvAttention(nn.Module):
         return (out[:, 0] if step else out), new_state
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``L``; ``deepseek_v3`` without a query
+    latent).  With x the layer's normed input, per token at position p (its
+    index among the row's observed steps):
+
+    * ``q = x Wq``, a head ``[qn (qk_nope); qr (qk_rope)]``;
+    * ``[c~; kr~] = x Wkva``, ``c = RMSNorm(c~)`` the latent (``kv_latent``);
+    * ``qr`` and ``kr~`` turn by p, adjacent pairs; ``kr`` is one key part
+      for every head;
+    * a head's ``[kn; v] = c Wkvb`` (``qk_nope`` and ``v_head`` wide);
+    * scores ``(qn . kn + qr . kr) / sqrt(qk_nope + qk_rope)``, as two
+      products summed (no key of both parts is ever made), causal over the
+      last ``memory_len`` observed steps; ``o`` maps the ``heads x v_head``
+      mix back.
+
+    What it carries is ``latent``, ``[c; kr]`` a step in float32: nothing per
+    head.  A window runs the *expanded* form (keys and values made from the
+    latents, the past's with the part's own); a step the *absorbed* one: the
+    query taken into the latent by ``Wkvb``'s key half, scores and mix
+    against the ring as it lies, the value half applied to the mix.
+    ``mla_proj`` holds the projections, the latent's norm and what ``Wkvb``
+    does in either form, ``mla_core`` the scores, mask, softmax and mix."""
+
+    d_model: int
+    heads: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    kv_latent: int
+    memory_len: int
+    rope_theta: float
+    eps: float
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, state, valid=None):
+        """Window mode: h (N, L, d) with ``valid`` a prefix mask, state
+        {"latent" (N, L0, kv_latent + qk_rope), "n" (N,)} the observed steps
+        before this window.  Step mode: h (N, d), state {"latent" (N,
+        memory_len, kv_latent + qk_rope), "pos" (N,)} a ring, with ``rows``
+        per (row, player) as ``GroupedQueryAttention``'s.  Returns (out, new
+        state)."""
+        with jax.named_scope(ATTN_SCOPE):
+            return self._attend(h, state, valid)
+
+    def expanded(self, values: int) -> int:
+        """What every head's key of both parts and value would hold of the
+        slots that ``values`` kept values (``kv_latent + qk_rope`` a slot) are."""
+        return values // (self.kv_latent + self.qk_rope) * self.heads * (
+            self.qk_nope + self.qk_rope + self.v_head)
+
+    def _attend(self, h, state, valid):
+        H, Dn, Dr, Dv, C = self.heads, self.qk_nope, self.qk_rope, self.v_head, self.kv_latent
+        kept, f32 = self.param_dtype, jnp.float32
+        step = h.ndim == 2
+        if step:
+            h = h[:, None]
+        n, length = h.shape[:2]
+        with jax.named_scope(MLA_PROJ_SCOPE):
+            q = _dense(H * (Dn + Dr), "q", kept)(h).reshape(n, length, H, Dn + Dr)
+            qn, qr = q[..., :Dn], q[..., Dn:]
+            c, kr = jnp.split(_dense(C + Dr, "kv_a", kept)(h).astype(f32), [C], axis=-1)
+            c = _rms(c, self.param("kv_norm", nn.initializers.ones, (C,), kept), self.eps)
+            # a head's columns: its key part's, then its value's
+            kv_b = self.param("kv_b", nn.initializers.lecun_normal(), (C, H * (Dn + Dv)), kept)
+            kv_b = kv_b.astype(h.dtype).reshape(C, H, Dn + Dv)
+        with jax.named_scope(ROPE_SCOPE):
+            at = state["pos"][:, None] if step else (
+                state["n"][:, None] + jnp.arange(length)[None, :])
+            qr = _rope_pairs(qr, at, self.rope_theta).astype(h.dtype)
+            kr = _rope_pairs(kr, at, self.rope_theta)
+        new = jnp.concatenate([c, kr], axis=-1)         # (N, L, C + Dr) float32: all that is kept
+        scale = (Dn + Dr) ** -0.5
+        weigh = lambda scores: jax.nn.softmax(      # noqa: E731  (N, H, L, keys) under ``allowed``
+            jnp.where(allowed[:, None], scores, NEG_INF), axis=-1).astype(h.dtype)
+        if step:
+            S = self.memory_len
+            slot = jnp.mod(state["pos"], float(S)).astype(jnp.int32)
+            hot = jax.nn.one_hot(slot, S, dtype=f32)[..., None]
+            rows, ring = state.get("rows"), state["latent"]
+            if rows is not None:    # rings per (row, player): the acting player's
+                with jax.named_scope(COMMIT_SCOPE):
+                    ring = acting_rows(ring, *rows)
+            latents = ring * (1 - hot) + hot * new
+            age = jnp.mod(slot[:, None] - jnp.arange(S)[None, :], S)
+            allowed = (age < jnp.minimum(state["pos"] + 1, S)[:, None])[:, None, :]
+            if rows is None:
+                new_state = {"latent": latents}
+            else:   # the step's slot alone, over zeros where the row's game has just begun
+                with jax.named_scope(COMMIT_SCOPE):
+                    player, begun = rows
+                    new_state = {"latent": begin_rows(
+                        state["latent"], player, begun, whole=True).at[
+                        jnp.arange(n), player, slot].set(new[:, 0])}
+            if not self.is_initializing():  # no-ops but under a caller's ``mutable``
+                self.sow("counters", "latent_state_values", jnp.float32(latents.size))
+                self.sow("counters", "expanded_state_values", jnp.float32(
+                    self.expanded(latents.size)))
+            with jax.named_scope(MLA_PROJ_SCOPE):       # the query into the latent
+                q_in = jnp.einsum("nqhd,chd->nqhc", qn, kv_b[..., :Dn],
+                                  preferred_element_type=f32).astype(h.dtype)
+            with jax.named_scope(MLA_CORE_SCOPE):
+                past = latents.astype(h.dtype)
+                scores = (jnp.einsum("nqhc,nkc->nhqk", q_in, past[..., :C],
+                                     preferred_element_type=f32)
+                          + jnp.einsum("nqhr,nkr->nhqk", qr, past[..., C:],
+                                       preferred_element_type=f32)) * scale
+                mix = jnp.einsum("nhqk,nkc->nqhc", weigh(scores), past[..., :C],
+                                 preferred_element_type=f32).astype(h.dtype)
+            with jax.named_scope(MLA_PROJ_SCOPE):       # the value half, after the mix
+                out = jnp.einsum("nqhc,chd->nqhd", mix, kv_b[..., Dn:])
+        else:
+            before = state["n"].astype(jnp.int32)
+            allowed = _seen_from(before, state["latent"].shape[1], valid, self.memory_len)
+            latents = jnp.concatenate([state["latent"].astype(f32), new], axis=1)
+            new_state = {"latent": latents, "n": before + valid.sum(axis=1)}
+            with jax.named_scope(MLA_PROJ_SCOPE):       # the past's keys and values with the part's own
+                kv = jnp.einsum("nkc,chd->nkhd", latents[..., :C].astype(h.dtype), kv_b)
+            with jax.named_scope(MLA_CORE_SCOPE):
+                scores = (jnp.einsum("nqhd,nkhd->nhqk", qn, kv[..., :Dn],
+                                     preferred_element_type=f32)
+                          + jnp.einsum("nqhr,nkr->nhqk", qr, latents[..., C:].astype(h.dtype),
+                                       preferred_element_type=f32)) * scale
+                out = jnp.einsum("nhqk,nkhd->nqhd", weigh(scores), kv[..., Dn:])
+        with jax.named_scope(MLA_PROJ_SCOPE):
+            out = _dense(self.d_model, "o", kept)(out.reshape(n, length, H * Dv))
+        return (out[:, 0] if step else out), new_state
+
+
 class GatedMLP(nn.Module):
     """``down(silu(gate(h)) * up(h))``, no biases; keeps no state."""
 
@@ -723,6 +898,12 @@ class HybridNet(nn.Module):
     cca_time0: int = 2
     cca_time1: int = 2
     rotary_factor: float = 1.0
+    # L: a head's unrotated and rotated query/key parts and its value, and the
+    # latent they are made from (heads, ``memory_len``, ``rope_theta``: ``*``'s)
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    kv_latent: int = 32
     # what every parameter is made and held in ("bfloat16": an acting copy
     # that no float32 tree precedes); compute follows the parameters
     param_dtype: str = "float32"
@@ -747,6 +928,10 @@ class HybridNet(nn.Module):
                 self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
                 self.rope_theta, 2 * int(self.head_dim * self.rotary_factor / 2), self.cca_time0,
                 self.cca_time1, kept, parent=None)
+        if kind == "L":
+            return LatentAttention(
+                self.d_model, self.n_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim,
+                self.kv_latent, self.memory_len, self.rope_theta, self.norm_eps, kept, parent=None)
         return GroupedQueryAttention(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
             self.rope_theta, self.attn_score_scale, kept, parent=None)
@@ -789,6 +974,12 @@ class HybridNet(nn.Module):
         if self.loops > 1 and "C" in self.pattern:
             raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
                              "a compressed convolutional attention layer is run once")
+        if self.loops > 1 and "L" in self.pattern:
+            raise ValueError(f"pattern {self.pattern!r} with loops {self.loops}: "
+                             "a latent attention layer is run once")
+        if "L" in self.pattern and not self.rope_theta:
+            raise ValueError(f"pattern {self.pattern!r}: a latent attention layer's rotated "
+                             "part needs rope_theta")
         kept = jnp.dtype(self.param_dtype)
 
         def encode(flat):
@@ -880,12 +1071,14 @@ class HybridNet(nn.Module):
 
         def periods(x, states, valid):
             """A window's stack of equal periods (``"CECECE"``: three of
-            ``"CE"``) as a ``lax.scan`` over the period index: one period is in
-            the program, not every layer (unrolled, the twelve sub-layers of
-            ``zaya1_train_t192``'s step were a 518 MB executable, which jax's
-            compile cache refuses, and two minutes of compile in every run:
-            PERF.md, PR 48).  The periods' parameters, states and checkpoints
-            are stacked by period, layer by layer; an ``mlp`` router's carry
+            ``"CE"``; ``"L-LELELELE"``: four of ``"LE"`` behind two leading
+            layers, which run unrolled first) as a ``lax.scan`` over the period
+            index: one period is in the program, not every layer (unrolled, the
+            twelve sub-layers of ``zaya1_train_t192``'s step were a 518 MB
+            executable, which jax's compile cache refuses, and two minutes of
+            compile in every run: PERF.md, PR 48; the ten of
+            ``kanana2_train_t192``'s left a cold run 21 s of its 330: PR 52).
+            The periods' parameters, states and checkpoints are stacked by period, layer by layer; an ``mlp`` router's carry
             rides in the scan's carry beside ``x``, zeros into the first
             period under a ``carry_scale`` of zeros (the first ``E`` layer has
             none: it adds nothing).  As in ``scanned`` each layer is applied
@@ -902,7 +1095,8 @@ class HybridNet(nn.Module):
             routed_experts.py`` ``held_mix``; PERF.md, PR 51: the copies out
             and back were 21 of ``zaya1_train_t192``'s 122.5 ms)."""
             params, width = self.variables["params"], len(repeat)
-            count = len(self.pattern) // width
+            count = (len(self.pattern) - lead) // width
+            x, led, chosen, counts = self._through(stack[:lead], x, states[:lead], valid)
             wide = self.router == "mlp" and "E" in repeat
             held = ("w1", "w2") if reads_in_place(x.dtype) else ()
 
@@ -915,7 +1109,7 @@ class HybridNet(nn.Module):
             def of_period(i):
                 """Layer ``i`` of every period, its leaves stacked: the scan's
                 (all but an ``E`` layer's ``held``) -> the held ones' too."""
-                each = [params[f"layer{t * width + i}"] for t in range(count)]
+                each = [params[f"layer{lead + t * width + i}"] for t in range(count)]
                 if wide and repeat[i] == "E" and "carry_scale" not in each[0]["mixer"]:
                     first = dict(each[0]["mixer"], carry_scale=jnp.zeros_like(
                         each[1]["mixer"]["carry_scale"]))
@@ -928,14 +1122,14 @@ class HybridNet(nn.Module):
                             for p in each]
                 return jax.tree.map(lambda *rows: jnp.stack(rows), *each), apart
 
-            stack = [application(kind) for kind in repeat]
+            period = [application(kind) for kind in repeat]
             scanned_over, apart = zip(*(of_period(i) for i in range(width)))
             read = jax.lax.stop_gradient(apart)
 
             def one_period(carry, this):
                 (x, handed, sinks), (t, p_t, states_t) = carry, this
                 new, routed, sinks = [], [], list(sinks)
-                for i, apply in enumerate(stack):
+                for i, apply in enumerate(period):
                     stacked = None if read[i] is None else (*read[i], t, sinks[i])
                     x, state, chose, handed = apply(p_t[i], x, states_t[i], handed, stacked)
                     if stacked is not None:     # the sinks go on in the carry, not out with the counts
@@ -946,20 +1140,19 @@ class HybridNet(nn.Module):
                 return (x, handed, tuple(sinks)), (tuple(new), tuple(routed))
 
             handed = jnp.zeros(x.shape[:-1] + (self.router_width,), jnp.float32) if wide else None
-            by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[i::width])
+            by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[lead + i::width])
                              for i in range(width))
             # the sinks go in as the stacks themselves: what comes back for them is the gradient
             (x, _, sinks), (new, routed) = jax.lax.scan(
                 one_period, (x, handed, apart), (jnp.arange(count), scanned_over, by_layer))
             x = open_sinks(x, sinks)
             at = lambda tree, t: jax.tree.map(lambda rows: rows[t], tree)  # noqa: E731
-            new_states = tuple(at(new[i], t) for t in range(count) for i in range(width))
-            chosen, counts = {}, {}
+            new_states = led + tuple(at(new[i], t) for t in range(count) for i in range(width))
             for t in range(count):
                 for i in range(width):
                     if routed[i] is not None:
-                        chosen[f"layer{t * width + i}"], counts[f"layer{t * width + i}"] = at(
-                            routed[i], t)
+                        name = f"layer{lead + t * width + i}"
+                        chosen[name], counts[name] = at(routed[i], t)
             return x, new_states, chosen, counts, None
 
         if not seq:
@@ -969,8 +1162,8 @@ class HybridNet(nn.Module):
             # ``rows_in_place`` names are per (row, player), to be stepped at
             # ``player[n]`` where they lie and read as zeros where ``begun``
             where = {} if rows is None else {"rows": rows}
-            given = {"M": where, "*": dict(where, pos=hidden["pos"]),
-                     "C": dict(where, pos=hidden["pos"])}
+            at = dict(where, pos=hidden["pos"])
+            given = {"M": where, "*": at, "C": at, "L": at}
             states = tuple(
                 dict(state, **given.get(kind, {}))
                 for kind, state in zip(self.pattern * self.loops, hidden["layers"]))
@@ -990,12 +1183,15 @@ class HybridNet(nn.Module):
         # one checkpoint per layer application where asked: only its input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
         loop = gate is not None and not self.is_initializing()
-        # a stack of ``C`` layers' periods, three or more, runs as a scan over them
-        repeat = _period(self.pattern)
-        repeats = ("C" in repeat and len(self.pattern) >= 3 * len(repeat)
-                   and self.loops == 1 and not self.is_initializing())
+        # three or more periods with a ``C`` or an ``L`` layer run as a scan over
+        # them, behind what leads them (an ``mlp`` router's carry does not
+        # cross from a leading ``E`` layer into the scan)
+        lead, repeat = _periods(self.pattern)
+        repeats = (("C" in repeat or "L" in repeat) and self.loops == 1
+                   and not (self.router == "mlp" and "E" in self.pattern[:lead])
+                   and not self.is_initializing())
         outs, chosen, counts = [], [], []
-        slots = dropped = 0
+        slots = dropped = handed = 0
         stayed = 0.0
         for part, lo, hi in (("burn_in", 0, burn_in), ("forward", burn_in, T)):
             if lo == hi:
@@ -1010,6 +1206,7 @@ class HybridNet(nn.Module):
                 if repeats else passes(stack, packed, states, valid))
             if hi == burn_in:   # scan parity: no gradient through what burn-in leaves
                 states = jax.lax.stop_gradient(states)
+                handed = sum(state["latent"].size for state in states if "latent" in state)
             outs.append(jnp.einsum("nit,nid->ntd", place, y, precision=_EXACT))
             spread = place.astype(jnp.int32)
             chosen.append({k: jnp.einsum("nit,nik->ntk", spread, v) for k, v in picked.items()})
@@ -1033,6 +1230,12 @@ class HybridNet(nn.Module):
                 exit_mass_last=stayed / jnp.maximum(
                     out["counters"]["observed_steps"] - out["counters"]["packed_dropped"], 1.0),
             )
+        if "L" in self.pattern:
+            # what the ``L`` layers handed from burn-in to the forward part, and
+            # what keys and values a head of the same steps would have been
+            out["counters"].update(
+                latent_state_values=jnp.float32(handed),
+                expanded_state_values=jnp.float32(self._mixer("L").expanded(handed)))
         if chosen[0]:
             out["choices"] = {k: jnp.concatenate([c[k] for c in chosen], axis=1)
                               for k in chosen[0]}
@@ -1048,10 +1251,10 @@ class HybridNet(nn.Module):
                 buffer_slots=sum(b["slots"] for b in buffers).astype(jnp.float32),
                 expert_passes=sum(b["passes"] - 1 for b in buffers).astype(jnp.float32),
             )
-            if "in_place" in buffers[0]:
+            if any("in_place" in b for b in buffers):   # not a leading layer's: it has no stack
                 # routed layer applications whose kernels read their weights in the periods' stack
                 out["counters"]["expert_stack_reads"] = sum(
-                    b["in_place"] for b in buffers).astype(jnp.float32)
+                    b.get("in_place", 0) for b in buffers).astype(jnp.float32)
             if "gates" in buffers[0]:   # ``mlp``: the mean gate a token's result was scaled by
                 out["counters"]["router_gate_mean"] = (
                     sum(b["gates"] for b in buffers)
@@ -1067,6 +1270,8 @@ class HybridNet(nn.Module):
             if kind in "*C":    # C keeps its tail and last value as they start
                 empty = jnp.zeros((n, 0, self.n_kv_heads, self.head_dim), dtype)
                 state = dict(state, k=empty, v=empty, n=jnp.zeros((n,), jnp.int32))
+            elif kind == "L":   # latents are handed on as the ring keeps them: float32
+                state = {"latent": state["latent"][:, :0], "n": jnp.zeros((n,), jnp.int32)}
             states.append(state)
         return tuple(states)
 
@@ -1092,6 +1297,8 @@ class HybridNet(nn.Module):
                         tail=zeros(self.cca_time0 + self.cca_time1 - 2,
                                    (self.n_heads + self.n_kv_heads) * self.head_dim),
                         prev_v=zeros(self.n_kv_heads * self.head_dim // 2))
+            elif kind == "L":   # [c; kr] a slot: nothing per head
+                layers.append({"latent": zeros(self.memory_len, self.kv_latent + self.qk_rope_dim)})
             else:
                 layers.append({})
         # pos is float32 so the train step's observation-mask arithmetic on
@@ -1104,11 +1311,13 @@ class HybridNet(nn.Module):
         takes whole under ``rows`` and steps one player's row of where it
         lies: a tree of bools, the SSM states' and conv tails' (``ops/ssd.py``
         ``ssd_step_rows``) and the key and value rings' (a step writes one
-        slot) and a ``C`` mixer's ``tail`` and ``prev_v`` (their acting row
-        gathered and written back by the mixer): all but ``pos``.  A caller
+        slot, as an ``L`` mixer's latent ring's does) and a ``C`` mixer's
+        ``tail`` and ``prev_v`` (their acting row gathered and written back by
+        the mixer): all but ``pos``.  A caller
         gathers the acting player's row of every other."""
         return jax.tree_util.tree_map_with_path(
-            lambda path, _: path[-1].key in ("ssm", "conv", "k", "v", "tail", "prev_v"), hidden)
+            lambda path, _: path[-1].key in (
+                "ssm", "conv", "k", "v", "tail", "prev_v", "latent"), hidden)
 
     @nn.nowrap
     def layout(self) -> Dict[str, Any]:
@@ -1138,6 +1347,11 @@ class HybridNet(nn.Module):
             "C": norms + d * (latent + self.n_kv_heads * self.head_dim)
             + self.n_heads * self.head_dim * d + (self.cca_time0 + 1) * latent
             + (self.cca_time1 * self.head_dim + 1) * latent + self.n_kv_heads,
+            # q, the map to the latent and its norm, the map from it, o
+            "L": norms + d * self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+            + (d + 1) * self.kv_latent + d * self.qk_rope_dim
+            + self.n_heads * (self.kv_latent * (self.qk_nope_dim + self.v_head_dim)
+                              + self.v_head_dim * d),
         }
         return {
             "pattern": self.pattern, "loops": self.loops,
@@ -1149,7 +1363,7 @@ class HybridNet(nn.Module):
             **{f"params_{name}": self.pattern.count(kind) * each[kind]
                + (carries if kind == "E" else 0)
                for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"),
-                                  ("-", "mlp"), ("C", "cca"))},
+                                  ("-", "mlp"), ("C", "cca"), ("L", "latent"))},
             # of ``params_experts``, the routers' own
             "params_router": n_e * router + carries,
         }
@@ -1160,4 +1374,5 @@ class HybridNet(nn.Module):
         applies it, for ``utils.compile_cache.scoped_program_options``: a
         scope that came with its mixer needs a cache key of its own only
         where the mixer is (the older kinds' programs keep theirs)."""
-        return (CCA_SCOPE,) if "C" in self.pattern else ()
+        return ((CCA_SCOPE,) if "C" in self.pattern else ()) + (
+            (MLA_PROJ_SCOPE, MLA_CORE_SCOPE) if "L" in self.pattern else ())

@@ -32,6 +32,7 @@ from benchmark.tests.test_granite_rehearsal import BENCH, CELLS, REPO, _run, reh
 del test_the_entries_are_appended_and_nothing_else_moved  # noqa: F821
 
 ROUTED, LOOPED, ZAYA = "nemotron_twotower_train_t192", "ouro_train_t192", "zaya1_train_t192"
+KANANA = "kanana2_train_t192"   # PR 52's cell: behind PR 48's wherever both are listed
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -106,15 +107,15 @@ def _lists(spec):
 
 def test_every_new_metric_lists_the_cell_and_has_a_reader():
     """PR 34's: the routed cell's own metrics list it first (alone, but
-    where PR 48's cell, which runs the same routed layer, joined it); the
-    accepted ones it joined list it after the cells they had (and before what
+    where PR 48's and PR 52's cells, which run the same routed layer, joined
+    it); the accepted ones it joined list it after the cells they had (and before what
     came later); the cell's entry is its file's."""
     spec = _spec()
     lists = _lists(spec)
     for name in ("ssd_roofline", "experts_roofline", "route_step_share", "ssd_step_share",
                  "expert_rows_max_over_mean"):
         assert lists[name][0] == ROUTED
-        assert lists[name][1:] == ([] if name.startswith("ssd") else [ZAYA])
+        assert lists[name][1:] == ([] if name.startswith("ssd") else [ZAYA, KANANA])
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
     for name in ("setup_compile_s", "train_step_device_ms", "train_mfu", "train_roofline_share",
                  "device_idle_share"):
@@ -138,8 +139,9 @@ def test_the_looped_cells_entries_stand_where_they_were_appended():
     first = names.index(new[0])
     assert names[first:first + 3] == new and first == 24
     for metric in spec["per_layer"][first:first + 3]:
-        # alone, but where PR 48's cell, which has an ``attn`` scope too, joined it
-        assert metric["workloads"] == [LOOPED] + [ZAYA] * (metric["name"] == "attn_step_share")
+        # alone, but where PR 48's and PR 52's cells, which have an ``attn`` scope too, joined it
+        assert metric["workloads"] == [LOOPED] + [ZAYA, KANANA] * (
+            metric["name"] == "attn_step_share")
         assert metric["moves"] == "trained_steps_per_s"
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", metric["name"] + ".py"))
     lists = _lists(spec)

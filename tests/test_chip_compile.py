@@ -463,6 +463,17 @@ def _lowered_step(topo, dp, batch_size, n_layers=2):
     """The cell's train step (xfmr_train_t64*: d1536, T64, bf16, einsum) at
     ``n_layers`` blocks, lowered for a {dp: dp} mesh of the described
     chips from shapes alone.  Returns (context, lowered)."""
+    return _lowered(
+        topo, dp, {"env": "Geister", "net": "transformer",
+                   "net_args": dict(_NET, n_layers=n_layers)},
+        dict(_STEP, batch_size=batch_size, seq_attention="einsum"))
+
+
+def _lowered(topo, dp, env_args, train_args, packed=None):
+    """A train step of ``env_args``'s net under ``train_args``, lowered for a
+    {dp: dp} mesh of the described chips from shapes alone; ``packed``
+    (burn-in slots, forward slots) gives the batch the ``packed_order`` leaf
+    ``put_batch`` makes for a net that takes one.  Returns (context, lowered)."""
     import random
 
     import numpy as np
@@ -473,12 +484,9 @@ def _lowered_step(topo, dp, batch_size, n_layers=2):
     from handyrl_tpu.envs import make_env
     from handyrl_tpu.parallel import TrainContext, make_mesh, param_shardings
 
-    cfg = normalize_args({
-        "env_args": {"env": "Geister", "net": "transformer",
-                     "net_args": dict(_NET, n_layers=n_layers)},
-        "train_args": dict(_STEP, batch_size=batch_size, seq_attention="einsum"),
-    })
+    cfg = normalize_args({"env_args": env_args, "train_args": train_args})
     args = dict(cfg["train_args"], env=cfg["env_args"])
+    batch_size = args["batch_size"]
     env = make_env(args["env"])
     module = env.net()
     mesh = make_mesh({"dp": dp}, devices=topo.devices)
@@ -506,11 +514,53 @@ def _lowered_step(topo, dp, batch_size, n_layers=2):
             (batch_size,) + np.shape(x)[1:], np.asarray(x).dtype, sharding=rows),
         small,
     )
+    if packed is not None:
+        players = np.shape(small["action"])[2]
+        batch["packed_order"] = {
+            part: jax.ShapeDtypeStruct((batch_size, players, slots), jnp.int32, sharding=rows)
+            for part, slots in zip(("burn_in", "forward"), packed)}
     lowered = jax.jit(
         ctx._step_fn, donate_argnums=(0,),
         in_shardings=(layout, rows, rep), out_shardings=(layout, rep),
     ).lower(state, batch, jax.ShapeDtypeStruct((), jnp.float32, sharding=rep))
     return ctx, lowered
+
+
+def test_the_latent_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, monkeypatch):
+    """``kanana2_train_t192``'s train step as its files give it (pattern
+    ``L-LELELELE`` at the published widths, B32 x 2p x T192 packed to 8 + 96
+    slots, ``remat: block``, bfloat16: two leading layers and a scan over four
+    ``LE`` periods) compiles for a described v5e: the grouped kernels take
+    experts 768 wide where they lie in the periods' stack, the latent
+    attention's einsum lines need no kernel, the program's peak is under the
+    chip's 16.9 GB with room (9.64 GB when this was written, 6.22 of it the
+    arguments; 155 MB of generated code and 57 s of compile alone on this
+    host; unrolled it was 8.78 GB, 384 MB and 85 s, and a cold run on the chip
+    left 21 s of its 330: PR 52), and no whole leaf of an expert layer's
+    weights, or of their stack, is copied."""
+    import json
+    import os
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "workloads", "kanana2_train_t192.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(bench, "configs", cell["config"] + ".json")) as f:
+        config = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    _, lowered = _lowered(v5e_2x2, 1, dict(config["env_args"]),
+                          dict(config["train_args"], **cell["train_args"]), packed=(8, 96))
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert 6.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 12.0e9
+    text = compiled.as_text()
+    # one period in the program: a window part has two products forward, those again
+    # under the backward scan, two rows' cotangents and two weight sums
+    assert text.count("tpu_custom_call") == 2 * 8
+    held = config["env_args"]["net_args"]["experts_held"]
+    copies = re.compile(
+        r"= (bf16|f32)\[(4,)?%d,(2048,1536|768,2048)\]\S* (copy|copy-start)\(" % held)
+    found = [line.strip()[:160] for line in text.splitlines() if copies.search(line)]
+    assert not found, found[:3]
 
 
 def _entry_ops(hlo_text):
