@@ -22,4 +22,15 @@ Architecture differences from the reference (TPU-first, not a port):
   reference only pads short windows.
 """
 
+import time as _time
+
+_T_IMPORT = _time.monotonic()   # ``setup.import`` begins: nothing is imported above
+
 __version__ = "0.1.0"
+
+# the last line: the package's own import as a phase (docs/observability.md).
+# ``utils`` is what every other part of the package imports first, and jax with
+# it where this process had not imported it
+from .utils.trace import trace_phase_since as _trace_phase_since  # noqa: E402
+
+_trace_phase_since("setup.import", _T_IMPORT)

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List
 
+from ..utils.trace import trace_phase
+
 
 class BaseEnvironment:
     """Abstract game interface.
@@ -108,7 +110,19 @@ class BaseEnvironment:
 
     # -- model factory ------------------------------------------------------
 
+    _net_phase_done = False     # the process's first ``net()`` is a phase
+
     def net(self):
+        """The Flax module for this game: ``_net()``, the first call of a
+        process under the phase ``setup.net`` (it imports ``models``, and flax
+        with it)."""
+        if BaseEnvironment._net_phase_done:
+            return self._net()
+        BaseEnvironment._net_phase_done = True
+        with trace_phase("setup.net", net=str(self.args.get("net") or "default")):
+            return self._net()
+
+    def _net(self):
         """Return the Flax module for this game (policy/value net).
 
         Honors ``env_args['net'] == 'transformer'`` (and ``'hybrid'``, the

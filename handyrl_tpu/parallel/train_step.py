@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import time
 from typing import Any, Dict, Optional
 
 import jax
@@ -46,7 +47,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..ops import attention_core, compute_loss_from_outputs
 from ..utils import tree_map
 from ..utils.compile_cache import scoped_program_options
-from ..utils.trace import enabled as trace_enabled, trace_event
+from ..utils.trace import (
+    enabled as trace_enabled,
+    trace_event,
+    trace_phase,
+    trace_phase_since,
+)
 from .mesh import (
     batch_sharding,
     dispatch_serialized,
@@ -443,6 +449,11 @@ class TrainContext:
     """Owns the mesh, the optimizer, and the compiled train step."""
 
     def __init__(self, module, args: Dict[str, Any], mesh):
+        # once a context: the mesh, the shardings, the step bound, ``model.layout``
+        with trace_phase("setup.train_context", plane="learner"):
+            self._build(module, args, mesh)
+
+    def _build(self, module, args: Dict[str, Any], mesh):
         self.module = module
         # '_mesh' rides in the (untraced) args dict so forward_prediction
         # can hand the mesh to sequence-parallel attention paths
@@ -739,6 +750,12 @@ class TrainContext:
         return self._train_step
 
     def init_state(self, params) -> Dict[str, Any]:
+        # once a state (a run's first, a sentinel rollback's): float32 weights,
+        # the optimizer's moments, their copies onto the mesh
+        with trace_phase("setup.init_state", plane="learner"):
+            return self._init_state(params)
+
+    def _init_state(self, params) -> Dict[str, Any]:
         params = self._fresh_put(params)
         # optimizer moments inherit the params' layout (same shape-based
         # 'mp' rule, pinned so the state enters _bind's layout exactly);
@@ -832,11 +849,25 @@ class TrainContext:
         # rollout) must reach every device in one order — see
         # mesh.dispatch_serialized
         fn = self._bind(state)
+        t0, bound = time.monotonic(), fn._cache_size()
         out = dispatch_serialized(
             lambda: fn(state, device_batch, jnp.float32(lr)), self.mesh
         )
+        self._first_step(fn, t0, bound)
         self._record_attention_paths()
         return out
+
+    @staticmethod
+    def _first_step(fn, t0: float, bound: int) -> None:
+        """The phase ``setup.first_step``, said once the call is back: ``fn``
+        holds a program more than the ``bound`` it held at ``t0``, so this
+        call traced, lowered and loaded or built one (a packed bound, a live
+        prefix or a ``fused_steps`` the context had not stepped: a handful a
+        process) while its caller waited.  Every later call of that program
+        costs the two reads."""
+        if fn._cache_size() != bound:
+            trace_phase_since("setup.first_step", t0, plane="learner",
+                              program=getattr(fn, "__name__", "?"))
 
     def _record_attention_paths(self):
         """One ``model.attention_path`` event for each static choice between
@@ -889,10 +920,12 @@ class TrainContext:
                 out_shardings=(ss, self._replicated),
                 compiler_options=scoped_program_options(UPDATE_SCOPE, *self._net_scopes),
             )
+        fn = self._train_steps
+        t0, bound = time.monotonic(), fn._cache_size()
         out = dispatch_serialized(
-            lambda: self._train_steps(state, stacked_device_batch, jnp.float32(lr)),
-            self.mesh,
+            lambda: fn(state, stacked_device_batch, jnp.float32(lr)), self.mesh,
         )
+        self._first_step(fn, t0, bound)
         self._record_attention_paths()
         return out
 

@@ -40,12 +40,12 @@ import signal
 import sys
 import threading
 import time
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 from ..envs import make_env, prepare_env
 from ..utils import trace
 from ..utils.retry import retry_call
-from ..utils.trace import trace_event, trace_span
+from ..utils.trace import trace_event, trace_phase, trace_phase_since, trace_span
 
 # same convention as the learner's drain path (runtime/learner.py)
 EXIT_RESUMABLE = 75
@@ -158,7 +158,10 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
         lambda key: module.init(key, sample, module.initial_state((1,)))["params"],
         out_shardings=replicated,
     )
-    params = dispatch_serialized(lambda: make(jax.random.PRNGKey(seed)), mesh)
+    with trace_phase("setup.actor_weights", plane="actor"):
+        # graftlint: allow[HS001] reason=set-up, once a process: the phase ends when the seeded weights are on the device, before the loop's first dispatch
+        params = jax.block_until_ready(
+            dispatch_serialized(lambda: make(jax.random.PRNGKey(seed)), mesh))
     _weights_event(params)
 
     client = PlaneClient(dist)
@@ -196,12 +199,19 @@ def actor_loop(args: Dict[str, Any], devices: Sequence[Any],
         jax.random.PRNGKey(seed + 0x5EED + 0xAC706 + 1009 * rank)
     )
     dispatches, written = 0, set()
+    # the phase ``setup.actor_first_dispatch``: from here to the first
+    # dispatch's fetched records (the rollout program traced, lowered, loaded
+    # or built, and run once); None from then on
+    t_first: Optional[float] = time.monotonic()
     try:
         while not stop.is_set():
             records, counted = stream.step(params, trace_span("actor.dispatch"))
             with trace_span("actor.fetch"):
                 # graftlint: allow[HS001] reason=the record batch leaves this machine over DCN — host materialization is the transport's input, one D2H per k_steps block
                 host_records, counted = jax.device_get((records, counted))
+            if t_first is not None:
+                trace_phase_since("setup.actor_first_dispatch", t_first, plane="actor")
+                t_first = None
             if counted:     # host scalars by now: fetched with the records
                 trace_event("actor.counters", 0.0,
                             **{name: value.tolist() for name, value in counted.items()})

@@ -36,7 +36,7 @@ from ..envs import make_env, prepare_env
 from ..models import init_variables
 from ..parallel import is_coordinator, make_mesh
 from ..utils import trace
-from ..utils.trace import trace_span
+from ..utils.trace import trace_phase, trace_span
 from . import faults
 from .checkpoint import (
     gc_snapshots,
@@ -58,6 +58,11 @@ EXIT_RESUMABLE = 75
 
 class Learner:
     def __init__(self, args: Dict[str, Any], net=None, remote: bool = False):
+        # once a learner: the planes, the rings, the engines, the restore
+        with trace_phase("setup.learner", plane="learner"):
+            self._build(args, net, remote)
+
+    def _build(self, args: Dict[str, Any], net, remote: bool):
         train_args = dict(args["train_args"])
         train_args["env"] = args["env_args"]
         self.args = train_args

@@ -33,6 +33,11 @@ Design constraints, in order:
    ``trace.annotate_device`` is true), so the host-side spans land inside
    XLA device profiles captured with ``profile_dir``.
 
+Set-up has spans of its own, ``trace_phase``: for work that runs a bounded
+number of times a process, recorded whether or not a tracer is on (most
+entry points configure theirs after set-up is over) and written to the
+sink once there is one.  Its contract is in its docstring.
+
 ``scripts/trace_export.py`` converts one or more trace.jsonl files (one
 per rank in a multi-process run) into Chrome trace-event JSON that opens
 directly in ``chrome://tracing`` / Perfetto.  Span catalog and workflow:
@@ -56,6 +61,9 @@ __all__ = [
     "enabled",
     "trace_span",
     "trace_event",
+    "trace_phase",
+    "trace_phase_since",
+    "phases",
     "trace_stats",
     "read_trace",
     "META_NAME",
@@ -84,17 +92,22 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_attrs", "_ts", "_t0", "_ann")
+    """An enabled ``trace_span``, or a ``trace_phase`` (``phase``: recorded
+    whether or not the tracer is on)."""
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Optional[Dict[str, Any]]):
+    __slots__ = ("_tracer", "_name", "_attrs", "_phase", "_ts", "_t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: Optional[Dict[str, Any]],
+                 phase: bool = False):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._phase = phase
         self._ann = None
 
     def __enter__(self) -> "_Span":
         tracer = self._tracer
-        ann_cls = tracer._annotation
+        ann_cls = tracer._annotation if tracer.enabled else None
         if ann_cls is not None:
             # enter the XLA annotation FIRST so the device profile's span
             # brackets the same wall window the host span records
@@ -115,7 +128,7 @@ class _Span:
                 self._ann.__exit__(*exc)
             except Exception:
                 pass
-        self._tracer._record(self._name, self._ts, self._t0, dur, self._attrs)
+        self._tracer._record(self._name, self._ts, self._t0, dur, self._attrs, self._phase)
         return False
 
 
@@ -136,6 +149,11 @@ class Tracer:
         self.rank = 0
         self.spans = 0
         self.dropped = 0
+        # once-a-process phases (``trace_phase``): kept whether or not the
+        # tracer is configured, so that set-up has spans before a sink exists
+        self.max_phases = 64
+        self.phases: List[Dict[str, Any]] = []
+        self.phases_dropped = 0
         self._annotation = None      # jax.profiler.TraceAnnotation when armed
         self._ring: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
@@ -197,7 +215,11 @@ class Tracer:
             "rank": self.rank,
             "pid": os.getpid(),
         }
-        f.write(json.dumps(meta) + "\n")
+        # right behind it, the phases the process recorded before it had a
+        # sink: set-up is over by the time most entry points configure
+        with self._lock:
+            earlier = list(self.phases)
+        f.write("".join(json.dumps(r, default=float) + "\n" for r in [meta] + earlier))
         f.flush()
         self._stop = threading.Event()
         self.enabled = True
@@ -231,7 +253,7 @@ class Tracer:
     # -- recording -----------------------------------------------------------
 
     def _record(self, name: str, ts: float, t0: float, dur: float,
-                attrs: Optional[Dict[str, Any]]) -> None:
+                attrs: Optional[Dict[str, Any]], phase: bool = False) -> None:
         rec: Dict[str, Any] = {
             "name": name,
             "ts": round(ts, 6),
@@ -243,6 +265,16 @@ class Tracer:
         if attrs:
             rec["attrs"] = attrs
         with self._lock:
+            if phase:
+                # kept for the process's life, tracer or no tracer; the ring
+                # takes it too while there is a sink to drain it into
+                rec["phase"] = True
+                if len(self.phases) >= self.max_phases:
+                    self.phases_dropped += 1
+                else:
+                    self.phases.append(rec)
+                if not self.enabled:
+                    return
             if len(self._ring) >= self.ring_size:
                 # NEVER block a hot path on the flusher: drop + count
                 self.dropped += 1
@@ -322,6 +354,40 @@ def trace_event(name: str, dur_s: float, t0: Optional[float] = None,
     now = time.monotonic()
     start = now - dur_s if t0 is None else t0
     tracer._record(name, time.time() - (now - start), start, dur_s, attrs or None)
+
+
+def trace_phase(name: str, **attrs: Any):
+    """Span context manager for work that runs a bounded number of times a
+    process: set-up (the package's import, building a net, a context, a
+    state, a program's first call, a learner, an actor's weights).
+
+    Unlike ``trace_span`` it records whether or not a tracer is on: into a
+    list the tracer keeps for the process's life (``phases()``; 64 records,
+    further ones dropped and counted), as a span's record with ``"phase":
+    true``.  ``configure()`` writes the phases recorded before it right
+    behind the meta line; one recorded while a tracer is on also goes
+    through the ring, and brackets its body in a ``TraceAnnotation``.
+
+    The contract, which tests/test_trace.py holds: **no function that a
+    window's loop calls enters one.**  N updates, N dispatches, N epochs
+    leave the count of phases where it was.  Anything that repeats takes
+    ``trace_span``, whose off path is free."""
+    return _Span(_TRACER, name, attrs or None, phase=True)
+
+
+def trace_phase_since(name: str, t0: float, **attrs: Any) -> None:
+    """Record as a phase (see ``trace_phase``) what began at ``t0`` on
+    ``time.monotonic()`` and ends now: for a site that learns only afterwards
+    that it was a phase (a step's first call for a program), or that cannot
+    take a ``with`` (a module's import, first line to last)."""
+    now = time.monotonic()
+    _TRACER._record(name, time.time() - (now - t0), t0, now - t0, attrs or None, phase=True)
+
+
+def phases() -> List[Dict[str, Any]]:
+    """A copy of the phases this process has recorded so far."""
+    with _TRACER._lock:
+        return [dict(r) for r in _TRACER.phases]
 
 
 def trace_stats() -> Dict[str, int]:

@@ -18,7 +18,10 @@ Mapping (deterministic, golden-pinned by tests/test_trace.py):
   microseconds relative to the earliest span across all inputs;
 * ``pid`` = the span's rank (so Perfetto groups tracks per process),
   ``tid`` = a stable per-rank index over the sorted thread names;
-* ``cat`` = the span's ``plane`` attr when present, else ``trace``;
+* ``cat`` = ``setup`` for a set-up phase (``trace_phase``: the record says
+  ``phase``) and ``compile`` for a ``compile.*`` event (a program traced,
+  lowered, built or loaded, on the thread that waited for it); else the
+  span's ``plane`` attr when present, else ``trace``;
 * process/thread name metadata events (``ph: "M"``) label the tracks.
 """
 
@@ -50,6 +53,14 @@ except ImportError:  # standalone use outside the repo: same tail tolerance
         return out
 
 
+def _category(record: Dict[str, Any]) -> str:
+    if record.get("phase"):
+        return "setup"
+    if str(record.get("name", "")).startswith("compile."):
+        return "compile"
+    return str((record.get("attrs") or {}).get("plane", "trace"))
+
+
 def export_chrome(record_lists: List[List[Dict[str, Any]]]) -> Dict[str, Any]:
     """Convert per-file span record lists into one Chrome trace dict."""
     # place every span on the shared wall-clock axis: wall_start =
@@ -69,6 +80,7 @@ def export_chrome(record_lists: List[List[Dict[str, Any]]]) -> Dict[str, Any]:
                 "rank": int(r.get("rank", 0)),
                 "thread": str(r.get("thread", "?")),
                 "attrs": r.get("attrs") or {},
+                "cat": _category(r),
             })
     base = min((s["start"] for s in spans), default=0.0)
     threads: Dict[int, List[str]] = {}
@@ -95,7 +107,7 @@ def export_chrome(record_lists: List[List[Dict[str, Any]]]) -> Dict[str, Any]:
     for s in sorted(spans, key=lambda s: (s["rank"], s["start"], s["name"])):
         events.append({
             "name": s["name"],
-            "cat": str(s["attrs"].get("plane", "trace")),
+            "cat": s["cat"],
             "ph": "X",
             "ts": round((s["start"] - base) * 1e6, 3),
             "dur": round(s["dur"] * 1e6, 3),

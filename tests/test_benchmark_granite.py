@@ -26,6 +26,7 @@ import pytest
 
 from benchmark.tests.test_granite_rehearsal import *  # noqa: F401,F403
 from benchmark.tests.test_granite_rehearsal import BENCH, CELLS, REPO, _run, rehearsal
+from test_setup_readers import before_pr55
 
 # holds PR 44's entries to be the last of their lists, which PR 48's appended
 # entries end: restated in tests/test_benchmark_zaya.py
@@ -96,8 +97,11 @@ def test_the_actor_cell_rehearses_on_cpu(root, trace, tmp_path, monkeypatch):  #
 
 
 def _spec():
+    """``BENCHMARK.json`` without PR 55's four ``setup_*`` metrics, which stand
+    last and list every cell (``before_pr55`` holds them to it): the cases
+    below ask of what is left what they asked before."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+        return before_pr55(json.load(f))
 
 
 def _lists(spec):

@@ -6,6 +6,7 @@ pure cases are in test_benchmark_readers.py, test_benchmark_reducer.py
 and test_benchmark_reference.py."""
 
 import os
+import time
 
 import pytest
 
@@ -22,6 +23,44 @@ from benchmark.tests.test_phase_readers import (  # noqa: F401  isort: skip
 # ``trained_steps_per_s``; a cell that trains nothing (PR 44's) reports no such
 # metric and may not list it: restated in tests/test_benchmark_granite.py
 del test_every_cell_lists_device_idle_share_and_setup_compile_s  # noqa: F821
+
+# holds the readers that answer without a profile to an exact list, which PR
+# 55's three counter-fed ``setup_*`` readers join (``setup_cache_load_s`` does
+# not: a CPU rehearsal has no compile cache): restated below, line for line
+# but for the list
+del test_a_stop_that_never_returns_fails_by_name  # noqa: F821
+
+
+def test_a_stop_that_never_returns_fails_by_name(rehearse):  # noqa: F811,F405
+    """The watcher is still inside ``stop_profile`` when the run's time is
+    up but for the reserve: ``profile_collected`` is false, the note says
+    how long the wait was, and the run still ends with its two lines."""
+    def stuck_stop(run, release):
+        run.notes["profile_window_s"] = time.monotonic() - run.profile_t0
+        # the learner takes a few of these seconds to stop; the rest is the wait
+        run.deadline = time.monotonic() + harness.PROFILE_RESERVE_S + 12.0  # noqa: F405
+        release.wait()
+        real_stop(run)          # noqa: F405  the test is over: close the session
+
+    code, run, earlier, last = rehearse(stuck_stop)
+    assert code == entry.EXIT_REHEARSAL and last["correct"] is False  # noqa: F405
+    assert run.xplane is None
+    assert earlier["checks"]["profile_collected"] is False
+    note = earlier["notes"]["profile_not_collected"]
+    assert 0.5 < note["waited_s"] < 12.0
+    assert note["seconds_left"] == pytest.approx(harness.PROFILE_RESERVE_S, abs=0.5)  # noqa: F405
+    assert note["stop_began"] is True and note["profile_bytes"] == 0
+    assert "reduce_s" not in earlier["notes"]
+    # the rest of the run is whole: the reference check ran, and the
+    # readers that need no profile answered
+    assert earlier["checks"]["matches_reference"] is True
+    assert earlier["notes"]["metrics_answered"] == [
+        "rollout_wait_share", "setup_compile_s", "setup_compile_wall_s", "setup_trace_lower_s",
+        "setup_unspanned_s", "train_mfu"]
+    # and said where the set-up went, the learner's own phase with it
+    assert {p["phase"] for p in earlier["notes"]["setup_phases"]} >= {"setup.learner"}
+    assert earlier["notes"]["setup_programs"] and earlier["notes"]["compile_records_dropped"] == 0
+
 
 # Collected in this order, but for the runners, moved to the end.  The five
 # cases that run the tiny loop cell need a whole epoch inside an 8 s window,

@@ -25,6 +25,7 @@ import pytest
 
 from benchmark import harness
 from benchmark.tests import test_rehearsal as rehearsal
+from test_setup_readers import before_pr55
 from benchmark.tests.test_granite_rehearsal import APPENDED as ACTOR_APPENDED
 from benchmark.tests.test_granite_rehearsal import NEW_READERS as ACTOR_READERS
 
@@ -253,8 +254,10 @@ def test_the_count_is_of_tokens_only():
 
 def _before(spec):
     """``spec`` as it stood before PR 52's entries, which are held to stand
-    last: its configuration, its cell, its metrics (each lists its cell
+    last (behind them only PR 55's four ``setup_*`` metrics, which list every
+    cell: ``before_pr55``): its configuration, its cell, its metrics (each lists its cell
     alone), and its cell's name at the end of every older list it joined."""
+    spec = before_pr55(spec)
     assert spec["configs"].pop()["name"] == LATER_CONFIG
     assert spec["workloads"].pop()["name"] == LATER_CELL
     for _ in range(LATER_METRICS):

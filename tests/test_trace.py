@@ -49,10 +49,16 @@ def _tracer_reset():
     leaves it disarmed (the module singleton is process-global state shared
     with any Learner or TrainContext the suite builds: a one-off event that
     another file of this xdist worker recorded stays in ``trace_stats``
-    until the next ``configure``)."""
+    until the next ``configure``).  The phase list is the process's too (its
+    import, every context another file built, up to the list's bound, and
+    ``configure`` writes them into a new sink): emptied here, put back after."""
     trace_mod.configure(None)
+    tracer = trace_mod._TRACER
+    kept, dropped = tracer.phases, tracer.phases_dropped
+    tracer.phases, tracer.phases_dropped = [], 0
     yield
     trace_mod.shutdown()
+    tracer.phases, tracer.phases_dropped = kept, dropped
 
 
 def _configure(tmp_path, rank=0, **over):
@@ -335,3 +341,251 @@ def test_trace_enabled_window_records_spans_without_recompiles(tmp_path):
     finally:
         stop.set()
         pipe.stop()
+
+
+# -- set-up phases (docs/observability.md "Set-up: compile records and phases") ---
+
+
+@pytest.fixture()
+def phase_list():
+    """The tracer, whose phase list ``_tracer_reset`` emptied for the test."""
+    return trace_mod._TRACER
+
+
+def _phases_so_far():
+    """Phases recorded, kept or dropped: what no loop may move."""
+    tracer = trace_mod._TRACER
+    return len(tracer.phases) + tracer.phases_dropped
+
+
+def test_a_phase_is_recorded_with_no_tracer_and_costs_no_file(phase_list, tmp_path, monkeypatch):
+    from handyrl_tpu.utils.trace import trace_phase, trace_phase_since
+
+    monkeypatch.chdir(tmp_path)
+    assert not trace_mod.enabled()
+    before = time.monotonic()
+    with trace_phase("setup.unit", plane="learner"):
+        time.sleep(0.01)
+    trace_phase_since("setup.since", before)
+    first, second = trace_mod.phases()
+    assert first["name"] == "setup.unit" and first["phase"] is True
+    assert first["attrs"] == {"plane": "learner"} and first["thread"] == "MainThread"
+    assert before <= first["t_mono"] and 0.01 <= first["dur_s"] < 5.0
+    assert abs((first["ts"] - first["t_mono"]) - (time.time() - time.monotonic())) < 0.05
+    assert second["name"] == "setup.since" and "attrs" not in second
+    assert second["t_mono"] == pytest.approx(before, abs=1e-6)
+    assert second["dur_s"] >= first["dur_s"]
+    # a copy: the caller's edits are its own
+    trace_mod.phases()[0]["name"] = "edited"
+    assert trace_mod.phases()[0]["name"] == "setup.unit"
+    assert os.listdir(tmp_path) == [] and trace_stats() == {"trace_spans": 0, "trace_dropped": 0}
+    # and ``trace_span`` off is still the shared null span
+    assert trace_span("a") is trace_mod._NULL_SPAN
+
+
+def test_the_phase_list_is_bounded_and_counts_what_it_drops(phase_list):
+    from handyrl_tpu.utils.trace import trace_phase
+
+    phase_list.max_phases, was = 3, phase_list.max_phases
+    try:
+        for i in range(5):
+            with trace_phase("setup.unit", i=i):
+                pass
+    finally:
+        phase_list.max_phases = was
+    assert [p["attrs"]["i"] for p in trace_mod.phases()] == [0, 1, 2]
+    assert phase_list.phases_dropped == 2 and _phases_so_far() == 5
+
+
+def test_configure_writes_earlier_phases_behind_the_meta_line(phase_list, tmp_path):
+    from handyrl_tpu.utils.trace import trace_phase
+
+    with trace_phase("setup.before", n=1):
+        pass
+    path = _configure(tmp_path)
+    with trace_span("a_span"):
+        pass
+    with trace_phase("setup.after"):
+        pass
+    trace_mod.shutdown()
+    records = read_trace(path)
+    assert [r["name"] for r in records] == [META_NAME, "setup.before", "a_span", "setup.after"]
+    before, span, after = records[1:]
+    assert before["phase"] is True and before["attrs"] == {"n": 1}
+    assert before["t_mono"] < records[0]["t_mono"]      # it ended before the sink opened
+    assert "phase" not in span and after["phase"] is True
+    # both are in the process's list, whichever way they reached the file
+    assert [p["name"] for p in trace_mod.phases()] == ["setup.before", "setup.after"]
+    # the one recorded while the tracer was on went through the ring
+    assert trace_stats()["trace_spans"] == 2
+    # a second sink gets every phase so far behind its meta line
+    again = _configure(tmp_path / "..", path=str(tmp_path / "again.jsonl"))
+    trace_mod.shutdown()
+    assert [r["name"] for r in read_trace(again)] == [META_NAME, "setup.before", "setup.after"]
+
+
+def test_the_export_shows_phases_and_compile_events_on_their_threads(phase_list, tmp_path):
+    """A file that holds both: a phase recorded before the sink opened, a
+    phase recorded after, and the ``compile.*`` events of a program compiled
+    on a thread of its own, each on the thread that ran it."""
+    import jax
+    import jax.numpy as jnp
+
+    from handyrl_tpu.utils.compile_cache import CompileCounters
+    from handyrl_tpu.utils.trace import trace_phase
+
+    with trace_phase("setup.before"):
+        pass
+    path = _configure(tmp_path)
+    counters = CompileCounters()
+
+    def compile_one():
+        def exported_body(x):
+            for _ in range(300):
+                x = jnp.sin(x) * 1.015625
+            return x
+        with trace_phase("setup.on_a_thread"):
+            jax.jit(exported_body)(jnp.ones((9,), jnp.float32))
+
+    try:
+        thread = threading.Thread(target=compile_one, name="compiler")
+        thread.start()
+        thread.join()
+    finally:
+        counters.close()
+        trace_mod.shutdown()
+    out = _export_chrome()([read_trace(path)])
+    tid = {e["args"]["name"]: e["tid"] for e in out["traceEvents"] if e["name"] == "thread_name"}
+    assert set(tid) == {"MainThread", "compiler"}
+    xs = {e["name"]: e for e in out["traceEvents"] if e["ph"] == "X"
+          and e["args"].get("program", "jit(exported_body)") == "jit(exported_body)"}
+    assert xs["setup.before"]["tid"] == tid["MainThread"] and xs["setup.before"]["cat"] == "setup"
+    assert xs["setup.on_a_thread"]["tid"] == tid["compiler"]
+    assert xs["compile.build"]["tid"] == tid["compiler"] and xs["compile.build"]["cat"] == "compile"
+    assert xs["compile.build"]["args"] == {"program": "jit(exported_body)", "cache": None}
+    # the build lies inside the phase that waited for it
+    phase, build = xs["setup.on_a_thread"], xs["compile.build"]
+    assert phase["ts"] <= build["ts"] and build["ts"] + build["dur"] <= phase["ts"] + phase["dur"] + 1
+    traced = [e for e in out["traceEvents"] if e["name"] == "compile.trace"]
+    assert [e["args"]["program"] for e in traced] == ["exported_body"]
+
+
+def test_twenty_train_steps_add_no_phase(phase_list):
+    """The contract of ``trace_phase``: a context, its state and its step's
+    first call are phases, once each; the updates behind them are not."""
+    import jax
+
+    from benchmark import traffic
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.envs import make_env
+    from handyrl_tpu.parallel import TrainContext, make_mesh
+
+    cfg = normalize_args({
+        "env_args": {"env": "TicTacToe", "net": "transformer",
+                     "net_args": {"d_model": 32, "n_heads": 2, "n_layers": 1, "memory_len": 8}},
+        "train_args": {"batch_size": 4, "forward_steps": 4, "burn_in_steps": 2,
+                       "observation": True, "seq_attention": "einsum"}})
+    args = dict(cfg["train_args"], env=cfg["env_args"])
+    env = make_env(args["env"])
+    module = env.net()
+    params = traffic.seeded_params(module, env, 5)
+    host_batches = traffic.random_play_batches(env, module, args, 2, 16)
+    ctx = TrainContext(module, args, make_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    state = ctx.init_state(params)
+    batches = [ctx.put_batch(b) for b in host_batches]
+    state, _ = ctx.train_step(state, batches[0], 1e-5)
+    names = [p["name"] for p in trace_mod.phases()]
+    assert names[-3:] == ["setup.train_context", "setup.init_state", "setup.first_step"]
+    assert set(names[:-3]) <= {"setup.net"}     # the process's first net, if this built it
+    first = trace_mod.phases()[-1]
+    assert first["attrs"] == {"plane": "learner", "program": "_step"} and first["dur_s"] > 0.05
+    so_far = _phases_so_far()
+    for i in range(20):
+        state, metrics = ctx.train_step(state, batches[i % 2], 1e-5)
+    jax.block_until_ready(metrics)
+    # one phase for each program the context bound (a second packed bound, if
+    # the second batch brought one), none for an update
+    assert _phases_so_far() - so_far == ctx._train_step._cache_size() - 1 <= 1
+
+
+def test_five_dispatches_of_an_actor_loop_add_no_phase(phase_list):
+    """``actor_loop`` against a loopback gateway: its weights and its first
+    dispatch are phases; by the first record batch the gateway sees, every
+    phase of the loop is recorded, and five more dispatches add none."""
+    import socket
+
+    import jax
+
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.runtime import actor_host
+    from handyrl_tpu.runtime.plane import PlaneGateway
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    dist = {"role": "actor", "coordinator_address": f"127.0.0.1:{port}", "plane_port": port,
+            "initialization_timeout": 30.0}
+    cfg = normalize_args({"env_args": {"env": "HungryGeese"}, "train_args": {
+        "turn_based_training": False, "observation": False, "device_rollout_games": 4,
+        "device_replay_k_steps": 4, "seed": 7, "distributed": dist}})
+    seen = []
+
+    def on_records(records):
+        seen.append(_phases_so_far())
+        if len(seen) == 6:
+            gateway.begin_stop()
+
+    gateway = PlaneGateway(dist, on_records=on_records)
+    gateway.start()
+    try:
+        done = actor_host.actor_loop(cfg, jax.devices()[:1], threading.Event())
+    finally:
+        gateway.stop()
+    assert done["dispatches"] == 6 and len(seen) == 6
+    assert seen == [seen[0]] * 6 and _phases_so_far() == seen[0]
+    names = [p["name"] for p in trace_mod.phases()]
+    assert names[-2:] == ["setup.actor_weights", "setup.actor_first_dispatch"]
+    assert set(names[:-2]) <= {"setup.net"}
+    weights, first = trace_mod.phases()[-2:]
+    assert weights["attrs"] == first["attrs"] == {"plane": "actor"}
+    assert weights["t_mono"] + weights["dur_s"] <= first["t_mono"] and first["dur_s"] > 0.05
+
+
+def test_three_epochs_of_a_learner_add_no_phase(phase_list, tmp_path, monkeypatch):
+    """The device-replay ``Learner``: its construction is a phase (with the
+    context and the state inside it); from its first epoch's end to its
+    fourth's, rollouts, ingests, updates, evals and epoch boundaries add none."""
+    from handyrl_tpu.config import normalize_args
+    from handyrl_tpu.runtime.learner import Learner
+
+    monkeypatch.chdir(tmp_path)
+    cfg = normalize_args({
+        "env_args": {"env": "HungryGeese"},
+        "train_args": {
+            "turn_based_training": False, "observation": False, "batch_size": 8,
+            "forward_steps": 8, "minimum_episodes": 10, "update_episodes": 30,
+            "maximum_episodes": 1000, "epochs": 4, "eval_rate": 0.0,
+            "device_rollout_games": 8, "device_replay": True, "device_replay_slots": 256,
+            "device_replay_k_steps": 16, "mesh": {"dp": 1}, "worker": {"num_parallel": 1},
+        },
+    })
+    learner = Learner(cfg)
+    built = [p["name"] for p in trace_mod.phases()]
+    assert built[-1] == "setup.learner" and built.count("setup.learner") == 1
+    assert {"setup.train_context", "setup.init_state"} <= set(built[:-1])
+    outer = trace_mod.phases()[-1]
+    assert all(outer["t_mono"] <= p["t_mono"] and
+               p["t_mono"] + p["dur_s"] <= outer["t_mono"] + outer["dur_s"] + 1e-6
+               for p in trace_mod.phases()[:-1] if p["name"] != "setup.net")
+    after_epoch = {}
+    thread = threading.Thread(target=learner.run, daemon=True)
+    thread.start()
+    deadline = time.monotonic() + 300
+    while thread.is_alive() and time.monotonic() < deadline:
+        after_epoch.setdefault(learner.model_epoch, _phases_so_far())
+        time.sleep(0.05)
+    thread.join(timeout=60)
+    assert not thread.is_alive() and learner.model_epoch == 4
+    after_epoch[4] = _phases_so_far()
+    assert 1 in after_epoch, sorted(after_epoch)
+    assert after_epoch[4] == after_epoch[1]
