@@ -6,7 +6,14 @@ rotary positions where ``rope_theta`` is set), ``-`` a dense gated MLP
 in a latent whose queries and keys two causal convolutions mix over the last
 steps: ``CompressedConvAttention``), ``L`` multi-head latent attention (keys
 and values made from one low-rank latent a token, beside one rotated key part
-that all heads share: ``LatentAttention``).  Each layer is ``x + mixer(RMSNorm(x))``, or with
+that all heads share: ``LatentAttention``), ``W`` local attention: a ``*``
+layer that sees ``window`` observed steps back, its own included (its step-mode
+ring holds the fewer of ``window`` and ``memory_len`` slots); with
+``rope_local_only`` the ``W`` layers alone rotate and the ``*`` layers beside
+them rotate nothing.  ``*`` and ``W`` layers take two more options: ``qk_norm``
+(an RMSNorm over each head of queries and keys, one scale of ``head_dim`` each,
+before the rotation) and ``attn_gate`` (the core's output times the sigmoid of a
+fifth projection of the layer's input, before ``o``).  Each layer is ``x + mixer(RMSNorm(x))``, or with
 ``sandwich`` ``x + RMSNorm(mixer(RMSNorm(x)))``; no biases but the conv's.
 ``out_scale_init`` is what the second norm's scale starts at: under 1, an
 untrained stack is nearer the identity, as deep residual nets are started.
@@ -26,7 +33,10 @@ period; ``"CE"`` layers with a rotation of half of each head
 ``router`` (a small MLP on a ``router_width``-wide representation that each
 ``E`` layer hands the next one's router, beside ``x`` and through every
 checkpoint; gates the chosen expert's own probability, not renormalised) is
-the ``zaya`` family's layer.  Every parameter is made and held in ``param_dtype`` (the
+the ``zaya`` family's layer; ``W`` and ``*`` layers in one pattern
+(``"W-*EWEWEWE"``) with ``rope_local_only``, ``qk_norm``, ``attn_gate``,
+``sandwich``, ``embed_scale`` and renormalised ``sigmoid`` gates is the
+``afmoe`` family's.  Every parameter is made and held in ``param_dtype`` (the
 initialisers draw in it: a bfloat16 acting copy of a net too large for a
 float32 tree is never preceded by one) and compute follows the parameters;
 the state, decays, norms, router logits and softmaxes stay float32.  One
@@ -40,7 +50,9 @@ is the sum over the uses); state is per *application*.  The hidden pytree
 holds, for every (pass, layer) pair in pass-major order, what that mixer
 carries between steps: the SSM state (float32) and the conv's last inputs
 for ``M``, a ring of the last ``memory_len`` keys (rotated, where they are)
-and values for ``*``, for ``C`` that ring in its latent, the last rows of
+and values for ``*`` (for ``W`` of the last ``min(window, memory_len)``: the
+pytree holds a ring as long as each layer sees back, ``ring(kind)``, and
+``layout()`` lists them), for ``C`` that ring in its latent, the last rows of
 queries and keys its convolutions look back on (``tail``) and the last
 step's shifted value (``prev_v``), for ``L`` a ring of the last ``memory_len``
 latents with their rotated key part (``latent``: nothing per head), nothing
@@ -68,13 +80,20 @@ it has two modes over one parameter set:
   pass index (``scanned``): the stack is in the program once, its
   parameters closed over, the states and the ``remat: block`` checkpoints
   (one per layer application) stacked by pass; step mode unrolls them.
+  Three or more equal periods with a ``C``, ``L`` or ``W`` layer behind what
+  leads them are a ``lax.scan`` over the period index (``periods``); beside a
+  ``W`` layer a ``*`` layer counts as one (``scanned_periods``): the period's
+  attention layer is *told*, as data stacked by period, how far it sees and
+  whether it rotates, so a global and a local layer share its program.
 
 ``E`` layers are told which experts they hold (``experts_held``,
 ``expert_offset``): they score and choose over all ``n_experts`` and add
 their own experts' terms only (``ops/routed_experts.py``).  The window mode
 returns, beside the heads, ``choices`` (per ``E`` layer the experts each
 token chose, (rows, T, top_k)) and ``counters`` (the packed array's slots,
-the observed steps, those the packing left out, with ``L`` layers the values
+the observed steps, those the packing left out, with ``W`` layers the
+query-key pairs causality lets through in them and those of these their window
+masks, with ``attn_gate`` the mean gate, with ``L`` layers the values
 they hand from burn-in to the forward part and what a head's keys and values
 of those steps would be, with ``E`` layers the
 rows the held experts computed, the slots of the row buffers they were
@@ -102,8 +121,9 @@ from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
 from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
 from .transformer import NEG_INF, _flatten_obs
 
-# Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention, latent attention
-KINDS = "ME*-CL"
+# Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention, latent attention,
+# local (windowed) attention
+KINDS = "ME*-CLW"
 # ``jax.named_scope``s round the dense trunk's phases: a component of each of
 # their ops' ``op_name`` in a device profile, forward and backward (the
 # benchmark's ``mlp_roofline``, ``attn_step_share`` and ``norm_step_share``
@@ -112,8 +132,11 @@ KINDS = "ME*-CL"
 # it does to queries, keys and values between the projections and the rotation;
 # in an ``L`` mixer ``mla_proj`` (its projections, the latent's norm, and what
 # the latent-to-heads map does in either form) and ``mla_core`` (scores, mask,
-# softmax and mix)
+# softmax and mix); in a ``*`` or ``W`` mixer ``attn_proj`` (q, k, v, o and the gate's
+# projection), ``qk_norm`` (the per-head norms of queries and keys) and
+# ``attn_gate`` (the gate's sigmoid and its product with the core's output)
 ATTN_SCOPE, ROPE_SCOPE, GQA_SCOPE, MLP_SCOPE, NORM_SCOPE = "attn", "rope", "gqa", "mlp", "norm"
+ATTN_PROJ_SCOPE, QK_NORM_SCOPE, ATTN_GATE_SCOPE = "attn_proj", "qk_norm", "attn_gate"
 CCA_SCOPE = "cca_mix"
 MLA_PROJ_SCOPE, MLA_CORE_SCOPE = "mla_proj", "mla_core"
 _EXACT = jax.lax.Precision.HIGHEST     # moving rows about must not round them
@@ -407,10 +430,13 @@ class GroupedQueryAttention(nn.Module):
     heads: int
     kv_heads: int
     head_dim: int
-    memory_len: int
+    memory_len: int             # the steps back a query sees: a local layer's window
     rope_theta: float = 0.0     # 0: no positions (order is left to other mixers)
     score_scale: float = 0.0    # what scores are multiplied by; 0: 1 / sqrt(head_dim)
     param_dtype: Any = jnp.float32
+    qk_norm: bool = False       # an RMSNorm a head on queries and keys, before the rotation
+    gated: bool = False         # the core's output times ``sigmoid(gate(h))``, before ``o``
+    eps: float = 1e-5           # ``qk_norm``'s
 
     @nn.compact
     def __call__(self, h, state, valid=None):
@@ -421,7 +447,17 @@ class GroupedQueryAttention(nn.Module):
         rings per (row, player), read and written at ``player[n]`` where they
         lie.  Returns (out, new state).  With
         ``rope_theta`` queries and keys are rotated by their position among
-        the row's observed steps, and the keys are kept rotated."""
+        the row's observed steps, and the keys are kept rotated; with
+        ``qk_norm`` each head of both is normed over its ``head_dim`` values
+        first (``q_norm``, ``k_norm``: one scale each, every head's); with
+        ``gated`` the core's output is multiplied by the sigmoid of a fifth
+        projection of ``h`` (``gate``, heads x D wide) before ``o``, and a
+        window's new state also holds ``gate``: the valid tokens' mean gate,
+        summed (the net takes it out again, for a counter).  A window's state
+        may *tell* the layer what its fields otherwise fix (a scanned period's
+        layers share one program: ``HybridNet`` ``periods``): ``reach`` ()
+        int32 in the place of ``memory_len``, ``turn`` () int32 that positions
+        are multiplied by before the rotation (0: nothing turns)."""
         with jax.named_scope(ATTN_SCOPE):
             return self._attend(h, state, valid)
 
@@ -433,8 +469,15 @@ class GroupedQueryAttention(nn.Module):
         n, length = h.shape[:2]
         # (N, L, heads x D): head h is columns h x D .. (h + 1) x D
         kept = self.param_dtype
-        q, k, v = (_dense(Hq * D, "q", kept)(h), _dense(Hk * D, "k", kept)(h),
-                   _dense(Hk * D, "v", kept)(h))
+        with jax.named_scope(ATTN_PROJ_SCOPE):
+            q, k, v = (_dense(Hq * D, "q", kept)(h), _dense(Hk * D, "k", kept)(h),
+                       _dense(Hk * D, "v", kept)(h))
+            gate = _dense(Hq * D, "gate", kept)(h) if self.gated else None
+        if self.qk_norm:
+            with jax.named_scope(QK_NORM_SCOPE):
+                q, k = (_rms(x.reshape(n, length, -1, D), self.param(
+                    name, nn.initializers.ones, (D,), kept), self.eps).reshape(x.shape)
+                    for x, name in ((q, "q_norm"), (k, "k_norm")))
         # a window part whose rows the kernel holds whole runs rotation, scores,
         # mask, softmax and mix there, on the projections' own layout: chosen
         # from dtype and shape alone (the kernel scales by 1 / sqrt(head_dim))
@@ -443,18 +486,29 @@ class GroupedQueryAttention(nn.Module):
             with jax.named_scope(GQA_SCOPE):
                 out, new_state = _whole_rows(q, k, v, state, valid, Hq, self.memory_len,
                                              self.rope_theta)
-            return _dense(self.d_model, "o", kept)(out), new_state
-        q = q.reshape(n, length, Hk, Hq // Hk, D)
-        k, v = k.reshape(n, length, Hk, D), v.reshape(n, length, Hk, D)
-        if self.rope_theta:
-            with jax.named_scope(ROPE_SCOPE):
-                at = state["pos"][:, None] if step else (
-                    state["n"][:, None] + jnp.arange(length)[None, :])
-                q, k = _rope(q, at, self.rope_theta), _rope(k, at, self.rope_theta)
-        with jax.named_scope(GQA_SCOPE):
-            out, new_state = _grouped_rows(q, k, v, state, valid, step, self.memory_len,
-                                           self.score_scale)
-        out = _dense(self.d_model, "o", kept)(out.reshape(n, length, Hq * D))
+        else:
+            q = q.reshape(n, length, Hk, Hq // Hk, D)
+            k, v = k.reshape(n, length, Hk, D), v.reshape(n, length, Hk, D)
+            reach = state.get("reach", self.memory_len)
+            if self.rope_theta:
+                with jax.named_scope(ROPE_SCOPE):
+                    at = state["pos"][:, None] if step else (
+                        state["n"][:, None] + jnp.arange(length)[None, :])
+                    if "turn" in state:     # a told layer: 0 leaves every angle 0
+                        at = at * state["turn"]
+                    q, k = _rope(q, at, self.rope_theta), _rope(k, at, self.rope_theta)
+            with jax.named_scope(GQA_SCOPE):
+                out, new_state = _grouped_rows(q, k, v, state, valid, step, reach,
+                                               self.score_scale)
+            out = out.reshape(n, length, Hq * D)
+        if gate is not None:
+            with jax.named_scope(ATTN_GATE_SCOPE):
+                opened = jax.nn.sigmoid(gate.astype(jnp.float32))
+                out = (out.astype(jnp.float32) * opened).astype(out.dtype)
+                if not step:
+                    new_state["gate"] = jnp.where(valid, opened.mean(axis=-1), 0.0).sum()
+        with jax.named_scope(ATTN_PROJ_SCOPE):
+            out = _dense(self.d_model, "o", kept)(out)
         return (out[:, 0] if step else out), new_state
 
 
@@ -520,13 +574,16 @@ def _seen_from(before, past: int, valid, memory_len: int):
 def _whole_rows(q, k, v, state, valid, heads: int, memory_len: int, rope_theta: float):
     """A window part through ``ops/attention_core.py``'s kernel, q, k and
     v as the projections wrote them: -> (out (N, L, Hq x D), new state),
-    the state's keys rotated as the einsum lines keep them."""
+    the state's keys rotated as the einsum lines keep them.  A state that
+    tells the layer its ``reach`` and ``turn`` hands both to the kernel."""
     (n, length), (past, Hk, D) = q.shape[:2], state["k"].shape[1:]
     before, count = state["n"].astype(jnp.int32), valid.sum(axis=1).astype(jnp.int32)
     past_k, past_v = state["k"].astype(k.dtype), state["v"].astype(v.dtype)
+    told = () if "reach" not in state else (
+        None, jnp.stack([state["reach"], state["turn"]]).astype(jnp.int32))
     out, keys = attention_core.attention_core(
         q, k, v, past_k.reshape(n, past, Hk * D), past_v.reshape(n, past, Hk * D),
-        before, count, (heads // Hk, D, memory_len, rope_theta))
+        before, count, (heads // Hk, D, memory_len, rope_theta), *told)
     return out, {"k": jnp.concatenate([past_k, keys.reshape(n, length, Hk, D)], axis=1),
                  "v": jnp.concatenate([past_v, v.reshape(n, length, Hk, D)], axis=1),
                  "n": before + count}
@@ -897,6 +954,15 @@ class HybridNet(nn.Module):
     memory_len: int = 32
     supports_seq: bool = True  # train path may call with seq=True
     rope_theta: float = 0.0    # *: rotary positions at this base; 0: none
+    # W: a ``*`` layer that sees ``window`` observed steps back (its ring holds
+    # the fewer of that and ``memory_len``); with ``rope_local_only`` the ``W``
+    # layers alone rotate and ``*`` layers rotate nothing.  * and W: an RMSNorm
+    # a head on queries and keys (``qk_norm``), a sigmoid gate on the core's
+    # output (``attn_gate``)
+    window: int = 0
+    rope_local_only: bool = False
+    qk_norm: bool = False
+    attn_gate: bool = False
     # -: dense gated MLP
     mlp_width: int = 128
     # the stack: a second norm on each mixer's output (and what its scale
@@ -959,8 +1025,28 @@ class HybridNet(nn.Module):
                 self.d_model, self.n_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim,
                 self.kv_latent, self.memory_len, self.rope_theta, self.norm_eps, kept, parent=None)
         return GroupedQueryAttention(
-            self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.memory_len,
-            self.rope_theta, self.attn_score_scale, kept, parent=None)
+            self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.ring(kind),
+            self.rope_theta if self.turns(kind) else 0.0, self.attn_score_scale, kept,
+            self.qk_norm, self.attn_gate, self.norm_eps, parent=None)
+
+    @nn.nowrap
+    def turns(self, kind: str) -> int:
+        """1 where an attention layer of ``kind`` rotates (at ``rope_theta``), else 0."""
+        return int(kind == "W" or not self.rope_local_only)
+
+    @nn.nowrap
+    def scanned_periods(self):
+        """``_periods`` of the pattern; beside a ``W`` layer a ``*`` layer
+        counts as one (``"W-*EWEWEWE"``: 2, ``"WE"``): a scanned period's
+        attention layer is told, as data, how far it sees and whether it
+        rotates, so a local and a global layer share the period's program."""
+        return _periods(self.pattern.replace("*", "W") if "W" in self.pattern else self.pattern)
+
+    @nn.nowrap
+    def ring(self, kind: str) -> int:
+        """The steps back a mixer of ``kind`` sees, and the slots of its
+        step-mode ring: a local layer's window where that is the fewer."""
+        return min(self.window, self.memory_len) if kind == "W" else self.memory_len
 
     @staticmethod
     def _through(layers, x, states, valid):
@@ -1006,6 +1092,8 @@ class HybridNet(nn.Module):
         if "L" in self.pattern and not self.rope_theta:
             raise ValueError(f"pattern {self.pattern!r}: a latent attention layer's rotated "
                              "part needs rope_theta")
+        if "W" in self.pattern and self.window < 1:
+            raise ValueError(f"pattern {self.pattern!r}: a local attention layer needs a window")
         kept = jnp.dtype(self.param_dtype)
 
         def encode(flat):
@@ -1166,8 +1254,17 @@ class HybridNet(nn.Module):
                 return (x, handed, tuple(sinks)), (tuple(new), tuple(routed))
 
             handed = jnp.zeros(x.shape[:-1] + (self.router_width,), jnp.float32) if wide else None
-            by_layer = tuple(jax.tree.map(lambda *rows: jnp.stack(rows), *states[lead + i::width])
-                             for i in range(width))
+            def told(i):
+                """What layer ``i`` of each period is told beside its state: an
+                attention layer how far it sees and whether it turns."""
+                kinds = self.pattern[lead + i::width]
+                return {} if repeat[i] != "W" else {
+                    "reach": jnp.array([self.ring(kind) for kind in kinds], jnp.int32),
+                    "turn": jnp.array([self.turns(kind) for kind in kinds], jnp.int32)}
+
+            by_layer = tuple(
+                dict(jax.tree.map(lambda *rows: jnp.stack(rows), *states[lead + i::width]),
+                     **told(i)) for i in range(width))
             # the sinks go in as the stacks themselves: what comes back for them is the gradient
             (x, _, sinks), (new, routed) = jax.lax.scan(
                 one_period, (x, handed, apart), (jnp.arange(count), scanned_over, by_layer))
@@ -1189,7 +1286,7 @@ class HybridNet(nn.Module):
             # ``player[n]`` where they lie and read as zeros where ``begun``
             where = {} if rows is None else {"rows": rows}
             at = dict(where, pos=hidden["pos"])
-            given = {"M": where, "*": at, "C": at, "L": at}
+            given = {"M": where, "*": at, "C": at, "L": at, "W": at}
             states = tuple(
                 dict(state, **given.get(kind, {}))
                 for kind, state in zip(self.pattern * self.loops, hidden["layers"]))
@@ -1209,16 +1306,17 @@ class HybridNet(nn.Module):
         # one checkpoint per layer application where asked: only its input is kept
         stack = layers(Layer if remat == "none" else nn.remat(Layer))
         loop = gate is not None and not self.is_initializing()
-        # three or more periods with a ``C`` or an ``L`` layer run as a scan over
-        # them, behind what leads them (an ``mlp`` router's carry does not
+        # three or more periods with a ``C``, an ``L`` or a ``W`` layer run as a scan
+        # over them, behind what leads them (an ``mlp`` router's carry does not
         # cross from a leading ``E`` layer into the scan)
-        lead, repeat = _periods(self.pattern)
-        repeats = (("C" in repeat or "L" in repeat) and self.loops == 1
+        lead, repeat = self.scanned_periods()
+        repeats = (any(kind in repeat for kind in "CLW") and self.loops == 1
                    and not (self.router == "mlp" and "E" in self.pattern[:lead])
                    and not self.is_initializing())
         outs, chosen, counts = [], [], []
-        slots = dropped = handed = 0
-        stayed = 0.0
+        slots = dropped = handed = pairs = cut = 0
+        stayed = gates = 0.0
+        before = jnp.zeros((n,), jnp.int32)     # a row's observed steps before the part
         for part, lo, hi in (("burn_in", 0, burn_in), ("forward", burn_in, T)):
             if lo == hi:
                 continue
@@ -1230,6 +1328,17 @@ class HybridNet(nn.Module):
             y, states, picked, count, stay = (
                 scanned(packed, states, valid) if loop else periods(packed, states, valid)
                 if repeats else passes(stack, packed, states, valid))
+            # what the gated attention layers left beside their rings, taken out again
+            gates = gates + sum(state["gate"] for state in states if "gate" in state)
+            states = tuple({k: v for k, v in state.items() if k != "gate"} for state in states)
+            if "W" in self.pattern:
+                # the keys causality lets each query see (those at or before it among
+                # the row's observed steps), and those of them ``window`` or more steps
+                # back (the window's cut alone: what ``memory_len`` cuts is not counted)
+                keys = jnp.where(valid, before[:, None] + jnp.arange(valid.shape[1]) + 1, 0)
+                pairs = pairs + keys.sum()
+                cut = cut + jnp.maximum(keys - self.window, 0).sum()
+                before = before + valid.sum(axis=1).astype(jnp.int32)
             if hi == burn_in:   # scan parity: no gradient through what burn-in leaves
                 states = jax.lax.stop_gradient(states)
                 handed = sum(state["latent"].size for state in states if "latent" in state)
@@ -1256,6 +1365,18 @@ class HybridNet(nn.Module):
                 exit_mass_last=stayed / jnp.maximum(
                     out["counters"]["observed_steps"] - out["counters"]["packed_dropped"], 1.0),
             )
+        if "W" in self.pattern:
+            # over the local layers: the query-key pairs causality lets through, and
+            # those of them that the window masks
+            local = self.loops * self.pattern.count("W")
+            out["counters"].update(causal_pairs=(local * pairs).astype(jnp.float32),
+                                   window_pairs_cut=(local * cut).astype(jnp.float32))
+        if self.attn_gate and any(kind in self.pattern for kind in "*W"):
+            # the mean of a gated attention layer's gate over its tokens and heads
+            tokens = out["counters"]["observed_steps"] - out["counters"]["packed_dropped"]
+            gated = self.loops * sum(self.pattern.count(kind) for kind in "*W")
+            out["counters"]["attn_gate_mean"] = (
+                gates / jnp.maximum(gated * tokens, 1.0)).astype(jnp.float32)
         if "L" in self.pattern:
             # what the ``L`` layers handed from burn-in to the forward part, and
             # what keys and values a head of the same steps would have been
@@ -1293,7 +1414,7 @@ class HybridNet(nn.Module):
         precedes."""
         states = []
         for kind, state in zip(self.pattern * self.loops, self.initial_state((n,))["layers"]):
-            if kind in "*C":    # C keeps its tail and last value as they start
+            if kind in "*CW":   # C keeps its tail and last value as they start
                 empty = jnp.zeros((n, 0, self.n_kv_heads, self.head_dim), dtype)
                 state = dict(state, k=empty, v=empty, n=jnp.zeros((n,), jnp.int32))
             elif kind == "L":   # latents are handed on as the ring keeps them: float32
@@ -1313,10 +1434,10 @@ class HybridNet(nn.Module):
                 layers.append({
                     "ssm": zeros(self.mamba_heads, self.mamba_head_dim, self.state_size),
                     "conv": zeros(self.conv_kernel - 1, conv_dim)})
-            elif kind in "*C":
+            elif kind in "*CW":  # a ring as long as the layer sees back
                 layers.append({
-                    "k": zeros(self.memory_len, self.n_kv_heads, self.head_dim),
-                    "v": zeros(self.memory_len, self.n_kv_heads, self.head_dim)})
+                    "k": zeros(self.ring(kind), self.n_kv_heads, self.head_dim),
+                    "v": zeros(self.ring(kind), self.n_kv_heads, self.head_dim)})
                 if kind == "C":
                     # the rows of ``[q~; k~]`` the convolutions look back on, the last value
                     layers[-1].update(
@@ -1365,7 +1486,10 @@ class HybridNet(nn.Module):
         each = {
             "M": norms + d * (inner + conv_dim + self.mamba_heads)
             + (self.conv_kernel + 1) * conv_dim + 3 * self.mamba_heads + inner + inner * d,
-            "*": norms + 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads),
+            # q, k, v, o; the gate's projection and the two per-head norms where it has them
+            "*": norms + d * self.head_dim * ((3 if self.attn_gate else 2) * self.n_heads
+                                              + 2 * self.n_kv_heads)
+            + (2 * self.head_dim if self.qk_norm else 0),
             "E": norms + router + (3 if self.gated_experts else 2) * d
             * (self.shared_width + self.experts_held * self.expert_width),
             "-": norms + 3 * d * self.mlp_width,
@@ -1379,17 +1503,20 @@ class HybridNet(nn.Module):
             + self.n_heads * (self.kv_latent * (self.qk_nope_dim + self.v_head_dim)
                               + self.v_head_dim * d),
         }
+        each["W"] = each["*"]
         return {
             "pattern": self.pattern, "loops": self.loops,
             "applications": self.loops * len(self.pattern),
+            # the slots of each attention application's ring, in the hidden pytree's order
+            "rings": [self.ring(kind) for kind in self.pattern * self.loops if kind in "*CLW"],
             "experts_held": self.experts_held,
             "experts": self.n_experts, "expert_offset": self.expert_offset,
             "residual_scale": self.residual_scale, "router": self.router,
             "param_dtype": self.param_dtype,
-            **{f"params_{name}": self.pattern.count(kind) * each[kind]
-               + (carries if kind == "E" else 0)
-               for kind, name in (("M", "mamba"), ("*", "attention"), ("E", "experts"),
-                                  ("-", "mlp"), ("C", "cca"), ("L", "latent"))},
+            **{f"params_{name}": sum(self.pattern.count(kind) * each[kind] for kind in kinds)
+               + (carries if kinds == "E" else 0)
+               for kinds, name in (("M", "mamba"), ("*W", "attention"), ("E", "experts"),
+                                   ("-", "mlp"), ("C", "cca"), ("L", "latent"))},
             # of ``params_experts``, the routers' own
             "params_router": n_e * router + carries,
         }
@@ -1400,5 +1527,8 @@ class HybridNet(nn.Module):
         applies it, for ``utils.compile_cache.scoped_program_options``: a
         scope that came with its mixer needs a cache key of its own only
         where the mixer is (the older kinds' programs keep theirs)."""
+        attends = any(kind in self.pattern for kind in "*W")
         return ((CCA_SCOPE,) if "C" in self.pattern else ()) + (
-            (MLA_PROJ_SCOPE, MLA_CORE_SCOPE) if "L" in self.pattern else ())
+            (MLA_PROJ_SCOPE, MLA_CORE_SCOPE) if "L" in self.pattern else ()) + (
+            (ATTN_PROJ_SCOPE, QK_NORM_SCOPE, ATTN_GATE_SCOPE)
+            if attends and (self.qk_norm or self.attn_gate) else ())

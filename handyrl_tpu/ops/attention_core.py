@@ -20,6 +20,14 @@ mask, softmax, values) as one Pallas kernel, a program a row.
 * with ``rope_theta`` queries and new keys are rotated (rotate-half pairing,
   float32, cast back to the operands' dtype) before the scores.
 
+* with ``layer`` (2,) int32, ``[reach, turn]``, the call is *told* what the
+  static ``memory_len`` and the rotation otherwise fix: queries see ``reach``
+  steps back, and positions are multiplied by ``turn`` (0: every angle is 0 and
+  nothing turns; 1: as without it).  One program then serves layers that
+  differ in both (a local and a global layer of one scanned period,
+  ``models/hybrid.py`` ``periods``): the two numbers are prefetched beside
+  ``before`` and ``count``.
+
 Returns ``(out (n, L, Hq x D), keys (n, L, Hk x D))``: the attention output
 in the layout the ``o`` projection reads, and the new keys as the state keeps
 them (rotated; ``k`` itself without ``rope_theta``).  Scores, softmax and both
@@ -89,12 +97,14 @@ def fits(dtype, length: int, past: int, heads: int, kv_heads: int, head_dim: int
     return not why
 
 
-def _rotation(before, length: int, freq_ref):
-    """(cos, signed sin) (L, D) of positions ``before + i``: ``freq_ref``
-    (1, D) holds the D / 2 inverse frequencies twice, the sine's sign is
-    that of the rotate-half pairing."""
-    at = (before + jax.lax.broadcasted_iota(jnp.int32, (length, 1), 0)).astype(jnp.float32)
-    angle = at * freq_ref[...]
+def _rotation(before, length: int, freq_ref, turn=None):
+    """(cos, signed sin) (L, D) of positions ``before + i`` (times ``turn``
+    where the call is told one): ``freq_ref`` (1, D) holds the D / 2 inverse
+    frequencies twice, the sine's sign is that of the rotate-half pairing."""
+    at = before + jax.lax.broadcasted_iota(jnp.int32, (length, 1), 0)
+    if turn is not None:
+        at = at * turn
+    angle = at.astype(jnp.float32) * freq_ref[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, angle.shape, 1)
     return jnp.cos(angle), jnp.where(lane < angle.shape[1] // 2, -1.0, 1.0) * jnp.sin(angle)
 
@@ -136,26 +146,30 @@ def _weights(q, keys, allowed, keys_first: bool = False):
     return weights / weights.sum(axis=over, keepdims=True)
 
 
-def _unpack(refs, rotary: bool, past: int, operands: int):
-    """(freq, the ``operands`` per-row inputs, past_k, past_v, the outputs)
-    of a kernel's refs: the frequencies and the past are there only where
-    the call has them."""
+def _unpack(refs, rotary: bool, past: int, operands: int, told: bool = False):
+    """(freq, the ``operands`` per-row inputs, past_k, past_v, the outputs,
+    [reach, turn]) of a kernel's refs: the frequencies, the past and what
+    the layer is told are there only where the call has them."""
     refs = list(refs)
+    layer = refs.pop(0) if told else None      # prefetched, behind ``before`` and ``count``
     freq = refs.pop(0) if rotary else None
     rows, refs = refs[:operands], refs[operands:]
     past_k, past_v = (refs.pop(0), refs.pop(0)) if past else (None, None)
-    return freq, rows, past_k, past_v, refs
+    return freq, rows, past_k, past_v, refs, layer
 
 
-def _forward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory_len, rotary):
+def _forward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory_len, rotary,
+                    told=False):
     from jax.experimental import pallas as pl
 
-    freq_ref, (q_ref, k_ref, v_ref), pk_ref, pv_ref, outs = _unpack(refs, rotary, past, 3)
+    freq_ref, (q_ref, k_ref, v_ref), pk_ref, pv_ref, outs, layer = _unpack(
+        refs, rotary, past, 3, told)
     o_ref, keys_ref = outs if rotary else (outs[0], None)
     row = pl.program_id(0)
     length, D = q_ref.shape[0], head_dim
-    allowed = _allowed(before_ref[row], count_ref[row], length, past, memory_len)
-    rotation = _rotation(before_ref[row], length, freq_ref) if rotary else None
+    reach, turn = (layer[0], layer[1]) if told else (memory_len, None)
+    allowed = _allowed(before_ref[row], count_ref[row], length, past, reach)
+    rotation = _rotation(before_ref[row], length, freq_ref, turn) if rotary else None
     for g in range(k_ref.shape[1] // D):
         cols = slice(g * D, (g + 1) * D)
         keys, values = k_ref[:, cols], v_ref[:, cols]
@@ -173,18 +187,20 @@ def _forward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory_
                 weights, values, preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _backward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory_len, rotary):
+def _backward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory_len, rotary,
+                     told=False):
     from jax.experimental import pallas as pl
 
-    freq_ref, (q_ref, k_ref, v_ref, do_ref, dkeys_ref), pk_ref, pv_ref, outs = _unpack(
-        refs, rotary, past, 5)
+    freq_ref, (q_ref, k_ref, v_ref, do_ref, dkeys_ref), pk_ref, pv_ref, outs, layer = _unpack(
+        refs, rotary, past, 5, told)
     dq_ref, dk_ref, dv_ref = outs[:3]
     row = pl.program_id(0)
     length, D = q_ref.shape[0], head_dim
+    reach, turn = (layer[0], layer[1]) if told else (memory_len, None)
     # the score tile with the keys down its rows: the softmax's sums and
     # the two cotangents that sum over queries then need no transpose
-    allowed = _allowed(before_ref[row], count_ref[row], length, past, memory_len, keys_first=True)
-    rotation = _rotation(before_ref[row], length, freq_ref) if rotary else None
+    allowed = _allowed(before_ref[row], count_ref[row], length, past, reach, keys_first=True)
+    rotation = _rotation(before_ref[row], length, freq_ref, turn) if rotary else None
     for g in range(k_ref.shape[1] // D):
         kv = slice(g * D, (g + 1) * D)
         keys, values = k_ref[:, kv], v_ref[:, kv]      # the keys as the forward left them
@@ -217,10 +233,11 @@ def _backward_kernel(before_ref, count_ref, *refs, group, head_dim, past, memory
             outs[4][:, kv] = d_values[length:].astype(outs[4].dtype)
 
 
-def _run(kernel, rows, past_k, past_v, outs, before, count, static, interpret):
+def _run(kernel, rows, past_k, past_v, outs, before, count, static, interpret, layer=None):
     """One program a row over ``rows`` (each (n, L, columns)) and the past,
     where there is one: -> arrays of the ``outs`` shapes.  ``static`` is
-    (group, head_dim, memory_len, rope_theta)."""
+    (group, head_dim, memory_len, rope_theta); ``layer`` the module
+    docstring's."""
     from jax.experimental import pallas as pl
 
     group, D, memory_len, rope_theta = static
@@ -233,52 +250,57 @@ def _run(kernel, rows, past_k, past_v, outs, before, count, static, interpret):
         specs.insert(0, pl.BlockSpec((1, D), lambda r, *_: (0, 0)))
     return _call(
         functools.partial(kernel, group=group, head_dim=D, past=past_k.shape[1],
-                          memory_len=memory_len, rotary=bool(rope_theta)),
-        (before, count), (rows[0].shape[0],), specs, [whole(x) for x in outs], outs, [],
-        interpret, *operands)
+                          memory_len=memory_len, rotary=bool(rope_theta), told=layer is not None),
+        (before, count) + (() if layer is None else (layer,)), (rows[0].shape[0],), specs,
+        [whole(x) for x in outs], outs, [], interpret, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("static", "interpret"))
-def _forward(q, k, v, past_k, past_v, before, count, static, interpret):
+def _forward(q, k, v, past_k, past_v, before, count, static, interpret, layer=None):
     """(out, keys): jitted, as ``grouped_product``'s callees are (a net calls
     it at two shapes, a layer, a pass and a replay at a time)."""
     outs = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if static[3]:
         outs.append(jax.ShapeDtypeStruct(k.shape, k.dtype))
-    got = _run(_forward_kernel, (q, k, v), past_k, past_v, outs, before, count, static, interpret)
+    got = _run(_forward_kernel, (q, k, v), past_k, past_v, outs, before, count, static, interpret,
+               layer)
     return got[0], (got[1] if static[3] else k)
 
 
 @functools.partial(jax.jit, static_argnames=("static", "interpret"))
-def _backward(q, keys, v, past_k, past_v, before, count, d_out, d_keys, static, interpret):
+def _backward(q, keys, v, past_k, past_v, before, count, d_out, d_keys, static, interpret,
+              layer=None):
     outs = [jax.ShapeDtypeStruct(x.shape, x.dtype)
             for x in (q, keys, v) + ((past_k, past_v) if past_k.shape[1] else ())]
     got = _run(_backward_kernel, (q, keys, v, d_out, d_keys), past_k, past_v, outs, before,
-               count, static, interpret)
+               count, static, interpret, layer)
     return tuple(got) if past_k.shape[1] else tuple(got) + (past_k, past_v)   # nothing: (n, 0, ..)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def attention_core(q, k, v, past_k, past_v, before, count, static, interpret: Optional[bool] = None):
+def attention_core(q, k, v, past_k, past_v, before, count, static,
+                   interpret: Optional[bool] = None, layer=None):
     """See the module's docstring; ``static`` is (group = Hq / Hk, head_dim,
-    memory_len, rope_theta (0: no rotation)).  -> (out, keys)."""
-    return _core_fwd(q, k, v, past_k, past_v, before, count, static, interpret)[0]
+    memory_len, rope_theta (0: no rotation)), ``layer`` None or ``[reach,
+    turn]`` in the place of the static ``memory_len`` and of a rotation that
+    always turns.  -> (out, keys)."""
+    return _core_fwd(q, k, v, past_k, past_v, before, count, static, interpret, layer)[0]
 
 
-def _core_fwd(q, k, v, past_k, past_v, before, count, static, interpret):
+def _core_fwd(q, k, v, past_k, past_v, before, count, static, interpret, layer):
     if interpret is None:   # picked before the jitted callee, whose cache it keys
         interpret = jax.default_backend() != "tpu"
-    out, keys = _forward(q, k, v, past_k, past_v, before, count, static, interpret)
-    return (out, keys), (q, keys, v, past_k, past_v, before, count)
+    out, keys = _forward(q, k, v, past_k, past_v, before, count, static, interpret, layer)
+    return (out, keys), (q, keys, v, past_k, past_v, before, count, layer)
 
 
 def _core_bwd(static, interpret, saved, cotangents):
-    q, keys, v, past_k, past_v, before, count = saved
+    q, keys, v, past_k, past_v, before, count, layer = saved
     d_out, d_keys = cotangents
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _backward(q, keys, v, past_k, past_v, before, count, d_out.astype(q.dtype),
-                     d_keys.astype(keys.dtype), static, interpret) + (None, None)
+                     d_keys.astype(keys.dtype), static, interpret, layer) + (None, None, None)
 
 
 attention_core.defvjp(_core_fwd, _core_bwd)

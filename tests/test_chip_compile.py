@@ -573,6 +573,55 @@ def test_the_latent_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, mo
     assert not found, found[:3]
 
 
+def test_the_local_and_global_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, monkeypatch):
+    """``trinity_mini_train_t192``'s train step as its files give it (pattern
+    ``W-*EWEWEWE`` at the published widths, B32 x 2p x T192 packed to 8 + 96
+    slots, ``remat: block``, bfloat16: two leading layers and a scan over four
+    attention-and-experts periods whose one attention layer is told, as data,
+    that it is the global or a local one) compiles for a described v5e: the
+    forward part's attention core is ``ops/attention_core.py``'s kernel in the
+    leading layer (its window and rotation static) and in the period (both
+    prefetched beside the rows' counts), each forward, replayed under its
+    checkpoint and backward; the grouped kernels take experts 1,024 wide where
+    they lie in the periods' stack; the program's peak is under the chip's
+    16.9 GB with room (10.92 GB, 7.33 of it the arguments, 162 MB of generated
+    code, under the 201 MB jax caches, and 59 s of compile alone on this host,
+    PR 58; with a program for each kind, four leading layers unrolled and three
+    ``WE`` periods scanned, the same row buffers, it was 10.42 GB, 244 MB and
+    85 s), and no whole
+    leaf of an expert layer's weights, or of their stack, is copied."""
+    import json
+    import os
+
+    from handyrl_tpu.models.hybrid import GQA_SCOPE
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "workloads", "trinity_mini_train_t192.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(bench, "configs", cell["config"] + ".json")) as f:
+        config = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    _, lowered = _lowered(v5e_2x2, 1, dict(config["env_args"]),
+                          dict(config["train_args"], **cell["train_args"]), packed=(8, 96))
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert 7.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 13.0e9
+    assert memory.generated_code_size_in_bytes < 201e6      # what jax's compile cache takes
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    cores = [line for line in calls if "/" + GQA_SCOPE + "/" in line]
+    # the leading layer's and the period's, each forward, replayed and backward
+    assert len(cores) == 6 and sum("/while/" in line for line in cores) == 3
+    assert len(calls) - len(cores) == 20        # the period's grouped products, both window parts
+    assert "f32[64,32,96,104]" not in text      # no score tile outside the kernel
+    held = config["env_args"]["net_args"]["experts_held"]
+    copies = re.compile(
+        r"= (bf16|f32)\[(4,)?%d,(2048,2048|1024,2048)\]\S* (copy|copy-start)\(" % held)
+    found = [line.strip()[:160] for line in text.splitlines() if copies.search(line)]
+    assert not found, found[:3]
+
+
 def _entry_ops(hlo_text):
     """The entry computation's instructions, in schedule order."""
     body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo_text, re.S | re.M).group(1)

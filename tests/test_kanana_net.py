@@ -336,21 +336,43 @@ def test_the_periods_behind_the_leading_layers_scan_and_are_the_unrolled_stack(m
 # -- the deployment: eight chips share each layer ------------------------------
 
 
-def test_the_eight_shares_add_up_to_the_uncut_reference():
+def _kanana_share():
+    """kanana_2_30b_a3b's: top-6, scale 2.448, behind its ``L`` mixer."""
+    mixer = LatentAttention(32, 4, 8, 4, 6, 12, 6, 1e4, 1e-6)
+    empty = {"latent": jnp.zeros((2, 0, SLOT)), "n": jnp.zeros((2,), jnp.int32)}
+    return REFERENCE, NET, 6, 2.448, mixer, empty, REFERENCE.mla
+
+
+def _trinity_share():
+    """trinity_mini's: top-8, scale 2.826, behind a local gated attention
+    layer with per-head q/k norms (``benchmark/reference/trinity_mini.py``)."""
+    reference = _load("reference", "trinity_mini.py")
+    net = dict(n_heads=4, n_kv_heads=2, head_dim=8, window=4, memory_len=6, rope_theta=1e4,
+               norm_eps=1e-6, routed_scale=2.826, expert_offset=0)
+    mixer = hybrid.GroupedQueryAttention(32, 4, 2, 8, 4, 1e4, qk_norm=True, gated=True, eps=1e-6)
+    empty = {"k": jnp.zeros((2, 0, 2, 8)), "v": jnp.zeros((2, 0, 2, 8)),
+             "n": jnp.zeros((2,), jnp.int32)}
+    return reference, net, 8, 2.826, mixer, empty, lambda p, h, observed, net: (
+        reference.attention(p, h, observed, True, net))
+
+
+@pytest.mark.parametrize("family", [_kanana_share, _trinity_share], ids=["kanana", "trinity"])
+def test_the_eight_shares_add_up_to_the_uncut_reference(family):
     """Offsets 0, 16 .. 112 of the eight-chip deployment at a small width:
     each share scores and chooses over all 128 experts with the whole router
     (the same choices) and adds its own 16 experts' terms; the eight routed
-    terms, with the shared expert and the ``L`` mixer counted once, add up to
-    the reference's layer whose 128 experts are on one chip."""
-    d, experts, held, k, width, shared = 32, 128, 16, 6, 16, 24
-    net = dict(NET, n_experts=experts, top_k=k, experts_held=experts, expert_width=width,
+    terms, with the shared expert and the attention mixer counted once, add up
+    to the reference's layer whose 128 experts are on one chip.  Both
+    configurations that stand for that deployment: each its own ``top_k``,
+    scale, mixer and reference."""
+    reference, base, k, scale, mixer, empty, attention = family()
+    d, experts, held, width, shared = 32, 128, 16, 16, 24
+    net = dict(base, n_experts=experts, top_k=k, experts_held=experts, expert_width=width,
                shared_width=shared)
     key = jax.random.PRNGKey(7)
     x = jax.random.normal(key, (2, 9, d))
     observed = jnp.ones((2, 9), jnp.float32)
-    mixer = LatentAttention(d, 4, 8, 4, 6, 12, 6, 1e4, 1e-6)
     attend = Layer(mixer, 1e-6)
-    empty = {"latent": jnp.zeros((2, 0, SLOT)), "n": jnp.zeros((2,), jnp.int32)}
     p_l = attend.init(jax.random.fold_in(key, 1), x, empty, observed > 0)["params"]
     whole = {
         "router": 3 * jax.random.normal(jax.random.fold_in(key, 2), (d, experts)),
@@ -362,9 +384,9 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
     }
     norm = 1.0 + 0.3 * jax.random.normal(jax.random.fold_in(key, 9), (d,))
     with jax.default_matmul_precision("highest"):
-        x1 = x + REFERENCE.mla(p_l["mixer"], REFERENCE.rms_norm(x, p_l["norm"], 1e-6), observed, net)
-        h = REFERENCE.rms_norm(x1, norm, 1e-6)
-        routed_and_shared, chosen = REFERENCE.experts(whole, h, net)
+        x1 = x + attention(p_l["mixer"], reference.rms_norm(x, p_l["norm"], 1e-6), observed, net)
+        h = reference.rms_norm(x1, norm, 1e-6)
+        routed_and_shared, chosen = reference.experts(whole, h, net)
         want = x1 + routed_and_shared
         # every share is given rows
         assert len(np.unique(chosen)) > 16 and len(np.unique(np.asarray(chosen) // held)) == 8
@@ -377,7 +399,7 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
                        w2=whole["w2"][offset:offset + held])
             if offset:      # what every chip computes alike is counted once
                 own = {k: v for k, v in own.items() if not k.startswith("shared")}
-            layer = ExpertLayer(d, experts, k, width, 0 if offset else shared, 2.448, held, offset,
+            layer = ExpertLayer(d, experts, k, width, 0 if offset else shared, scale, held, offset,
                                 "sigmoid", True)
             out, picked, counts, _ = jax.jit(lambda p: layer.apply({"params": p}, h))(own)
             np.testing.assert_array_equal(np.sort(picked, axis=-1), np.sort(chosen, axis=-1))
