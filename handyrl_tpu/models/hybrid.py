@@ -420,6 +420,7 @@ class ExpertLayer(nn.Module):
         if not self.is_initializing():  # no-ops but under a caller's ``mutable``
             self.sow("counters", "rows_held", counts["rows"].sum())
             self.sow("counters", "buffer_slots", counts["slots"])
+            self.sow("counters", "slots_run", counts["blocks_run"])
             self.sow("choices", "chosen", chosen.reshape(lead + (self.top_k,)))
         return (out.reshape(lead + (d,)), chosen.reshape(lead + (self.top_k,)), counts,
                 carry if self.router == "mlp" else None)
@@ -1393,9 +1394,11 @@ class HybridNet(nn.Module):
                 rows_held=by_layer.sum().astype(jnp.float32),
                 expert_rows_max=by_layer.max().astype(jnp.float32),
                 expert_rows_mean=by_layer.astype(jnp.float32).mean(),
-                # slots computed (every pass's), and the passes a buffer that
+                # the buffers' slots (every pass's), those of them in blocks that hold
+                # a row, which the kernels ran, and the passes a buffer that
                 # sufficed would not have taken
                 buffer_slots=sum(b["slots"] for b in buffers).astype(jnp.float32),
+                slots_run=sum(b["blocks_run"] for b in buffers).astype(jnp.float32),
                 expert_passes=sum(b["passes"] - 1 for b in buffers).astype(jnp.float32),
             )
             if any("in_place" in b for b in buffers):   # not a leading layer's: it has no stack

@@ -21,12 +21,18 @@ a Pallas kernel whose weight operand is indexed by the block's expert; its
 transpose sums the blocks' weight gradients once an expert).  That kernel
 takes bfloat16 operands; float32 operands (the judge's forward, tests) keep
 the plain ``jnp`` block products, at the precision their caller asks.
-Shapes are static and so is the work: the buffer holds ``SHARES`` times a
-uniform router's share of rows and a block of padding for each held expert,
-and every block is computed whether rows fill it or not, the blocks past the
-last row as the last expert's with gate 0 (the step's time does not move
-with the routing; what the chip showed of a grouped kernel that skips empty
-tiles is in PERF.md, PR 34).  An update whose rows outgrow the buffer takes
+Shapes are static: the buffer holds ``SHARES`` times a uniform router's
+share of rows and a block of padding for each held expert.  The work is the
+routing's: every expert's rows start on a block, so the blocks that hold a
+row are the buffer's first ``ends[-1] / block`` and the empty ones a suffix;
+``_one_pass`` counts the live ones (``live``) and the kernels' grid steps
+past them do nothing and move no bytes, forward, in the replay and in both
+backward kernels (the step's time follows the rows the routers send: PERF.md,
+PRs 59 and 60; in blocks of ``FEW_ROWS`` every block is still run,
+``_skips``).  What the kernels return for those blocks' rows is
+uninitialised memory, so everything here that sums over or gathers from a
+buffer row takes it by selection on ``used`` or on the pair's ``live``, never
+by a product with a zero gate.  An update whose rows outgrow the buffer takes
 further passes over it, as many as its rows need (``_passes``: a
 ``lax.while_loop`` forward and backward, so the worst case, every token
 choosing ``min(top_k, held)`` held experts, costs time and no memory).
@@ -120,6 +126,30 @@ def _combine_bwd(tok, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _weigh(down, gate, used, dtype):
+    """weighted[r] = gate[r] x down[r] in ``dtype`` where slot ``r`` is
+    ``used``, else 0: a select, since past the live blocks ``down`` is
+    uninitialised and a NaN times a gate of 0 is a NaN.  Backward the gate's
+    cotangent, a sum over such a row, is selected the same way, and
+    ``down``'s is the token's times a gate that is 0 there."""
+    return jnp.where(used[:, None], down * gate[:, None], 0.0).astype(dtype)
+
+
+def _weigh_fwd(down, gate, used, dtype):
+    return _weigh(down, gate, used, dtype), (down, gate, used)
+
+
+def _weigh_bwd(dtype, saved, d_weighted):
+    down, gate, used = saved
+    d_weighted = d_weighted.astype(down.dtype)
+    return (d_weighted * gate[:, None],
+            jnp.where(used, (d_weighted * down).sum(axis=-1), 0.0).astype(gate.dtype), None)
+
+
+_weigh.defvjp(_weigh_fwd, _weigh_bwd)
+
+
 def _in_kernel(dtype) -> bool:
     """Whether operands of ``dtype`` go through the grouped kernel: bfloat16
     ones; the plain block products run at the caller's matmul precision,
@@ -166,7 +196,9 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
     dtype: sum over a token's chosen experts that are held of gate x
     w2[e] relu(w1[e] h)^2, ``gated`` w2[e] (silu(a) b); counts: ``rows`` (held,) int32 the rows each held
     expert computed, ``passes`` () int32 the passes over the row buffer that
-    took them, ``slots`` () int32 the buffer slots those passes computed).
+    took them, ``slots`` () int32 the buffer slots of those passes,
+    ``blocks_run`` () int32 the slots of them whose blocks the products ran:
+    where they skip (``_skips``) the blocks that hold a row, else all).
 
     ``period`` () int32 with ``sinks`` (two arrays of ``w1``'s and ``w2``'s
     shape and dtype), for a caller that scans over equal periods
@@ -204,19 +236,27 @@ def held_mix(h, chosen, gates, valid, w1, w2, offset: int, experts: int, gated: 
         assert reads_in_place(h.dtype), h.dtype
         out, sinks = _passes_at(h, gates, w1, w2, route, period, sinks, blocks, block, gated)
         more = {"sinks": sinks, "in_place": jnp.int32(1)}
-    return out, {"rows": rows, "passes": passes, "slots": passes * (blocks * block), **more}
+    # every expert's rows start on a block and the passes cut the slots laid
+    # out at whole blocks: the live blocks of all passes are ``ends[-1]`` slots
+    slots = passes * (blocks * block)
+    return out, {"rows": rows, "passes": passes, "slots": slots,
+                 "blocks_run": ends[-1] if _skips(block, h.dtype) else slots, **more}
 
 
-def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None), period=None):
+def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None), period=None,
+                    live=None):
     """x (m, d) in ``owner.size`` blocks of equal height, block ``b`` of
     expert ``owner[b]`` -> (m, d) float32: w2[e] relu(w1[e] x)^2 a block,
     ``gated`` w2[e] (silu(a) b) with [a, b] = w1[e] x.  ``into`` (the kernel's
     products only): ``grouped_dot``'s ``into`` for w1's product and for
     w2's, the sums of their gradients that a loop over passes carries;
-    ``period`` (the same only): ``grouped_dot``'s, w1 and w2 stacks."""
+    ``period`` (the same only): ``grouped_dot``'s, w1 and w2 stacks; ``live``
+    (the same only): ``grouped_dot``'s, the blocks that hold a row: the
+    others' rows come back uninitialised (every line between the two products
+    is a row's own, so they reach no other row)."""
     if _in_kernel(x.dtype):
         def product(rows, w, into):
-            return grouped_dot(rows, w, owner, None, into, period)
+            return grouped_dot(rows, w, owner, None, into, period, live)
     else:
         def product(rows, w, _):
             blocks = rows.reshape(owner.size, -1, rows.shape[1])
@@ -233,12 +273,36 @@ def _block_products(x, w1, w2, owner, gated: bool = False, into=(None, None), pe
 
 def _owners(ends, start, blocks: int, block: int):
     """owner (blocks,) int32, non-decreasing: the expert of each block of the
-    buffer slots [start, start + blocks x block).  Every block has one, so
-    that the grouped products' work is the buffer's: the blocks past the last
-    row are the last expert's (no row of his reaches them: their slots have
-    gate 0)."""
+    buffer slots [start, start + blocks x block).  Every block has one: the
+    blocks past the last row are the last expert's, whose weight tile the
+    kernels then hold already; they are past ``_one_pass``'s ``live`` and the
+    kernels run none of them."""
     owner = jnp.searchsorted(ends, start + block * jnp.arange(blocks), side="right")
     return jnp.minimum(owner, ends.size - 1).astype(jnp.int32)
+
+
+def _skips(block: int, dtype) -> bool:
+    """Whether the products run the blocks that hold a row and no others:
+    the kernel's, in blocks of ``BLOCK``.  The plain products multiply every
+    block, and so do the kernels in blocks of ``FEW_ROWS``: a rollout step's
+    products are bound by the held experts' bytes, and
+    ``granite_actor_b32``'s ``rollout_roofline_share`` counts every held
+    expert's, at 95% of the roofline with the trailing blocks multiplied; run
+    without them the step read over 100% (PERF.md, PR 60), so they stay
+    until that count is the read experts'."""
+    return _in_kernel(dtype) and block != FEW_ROWS
+
+
+def _live(ends, start, blocks: int, block: int, dtype):
+    """() int32: the blocks of the buffer slots [start, start + blocks x
+    block) that the products run: those that hold a row, which are the
+    pass's first (every expert's rows start on a block, so the slots laid
+    out are the buffer's first ``ends[-1]``; 0 blocks where no token chose a
+    held expert or the pass starts past them); every block where the
+    products do not skip (``_skips``)."""
+    if not _skips(block, dtype):
+        return jnp.int32(blocks)
+    return jnp.clip(-(-(ends[-1] - start) // block), 0, blocks).astype(jnp.int32)
 
 
 def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bool = False,
@@ -250,6 +314,7 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bo
     rows, first, ends, base = (route[key] for key in ("rows", "first", "ends", "base"))
     with jax.named_scope("route"):
         owner = _owners(ends, start, blocks, block)
+        live = _live(ends, start, blocks, block, h.dtype)
         expert = jnp.repeat(owner, block)
         index = start + jnp.arange(m) - base[expert]                # a slot's row of its expert
         used = index < rows[expert]
@@ -260,11 +325,9 @@ def _one_pass(h, gates, w1, w2, route, start, blocks: int, block: int, gated: bo
         x = _dispatch(h, tok, at, in_buffer)
         gate = jnp.where(used, gates.reshape(-1)[pair], 0.0)
     with jax.named_scope(EXPERTS_SCOPE):
-        down = _block_products(x, w1, w2, owner, gated, into, period)
+        down = _block_products(x, w1, w2, owner, gated, into, period, live)
     with jax.named_scope("route"):
-        # a slot no row fills has gate 0
-        weighted = (down * gate[:, None]).astype(h.dtype)
-        return _combine(weighted, tok, at, in_buffer)
+        return _combine(_weigh(down, gate, used, h.dtype), tok, at, in_buffer)
 
 
 def _needed(route, slots: int):

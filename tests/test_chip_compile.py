@@ -306,13 +306,16 @@ def test_a_scan_over_periods_of_stacked_experts_compiles_for_v5e_with_no_copy_of
 
 
 # sha256 of the tiny ``nemotron_*`` cell's train step as lowered on the CPU
-# (``benchmark/tests/tiny_hybrid/``: ``MEM*E``, no period to scan) at the
-# commit before PR 51, in float32 (the plain block products) and in bfloat16
-# (the grouped kernel in the interpreter).  A change that means to alter that
-# program replaces these; one that only adds a path for another net must not.
+# (``benchmark/tests/tiny_hybrid/``: ``MEM*E``, no period to scan), in
+# float32 (the plain block products) and in bfloat16 (the grouped kernel in
+# the interpreter).  A change that means to alter that program replaces
+# these; one that only adds a path for another net must not.  They stood from
+# the commit before PR 51 to PR 60, which meant to: the kernels are handed
+# ``live``, ``down`` is weighed by the gate through ``_weigh``, and the step
+# counts ``slots_run`` (float32: the last two).
 _TINY_ROUTED_STEP = {
-    "float32": "fdedff4cf118631fbfbff7ce838de40fa8ee926dead04e1e06ec8b38f6654b4c",
-    "bfloat16": "6a6ae1e8c8c3aea56d01e256fa44a6c5d1b590ab991330419f319de98b41c6dd",
+    "float32": "e9ffdf3376824601e36a0b1c44b58da1533a1da08ec2d7844a794b6ef5f57322",
+    "bfloat16": "da0fd3df5ec1223fc9aadf23493b973195d876edf9f47d2187fe90e27fe28996",
 }
 
 

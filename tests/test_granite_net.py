@@ -363,7 +363,7 @@ def rollout():
 def test_the_rollout_counts_what_the_step_mode_sowed_and_keeps_its_records(rollout):
     module, params, venv, plain, _, outs = rollout
     for records, counted in outs:
-        assert set(counted) == {"rows_held", "buffer_slots", "rows_applied"}
+        assert set(counted) == {"rows_held", "buffer_slots", "slots_run", "rows_applied"}
         # one player a lane observes: the net is applied to the 4 acting rows of 8
         assert counted["rows_applied"] == 8 * 4
         # three routed sub-layers, 8 steps, one buffer of blocks x block slots each:
@@ -373,6 +373,7 @@ def test_the_rollout_counts_what_the_step_mode_sowed_and_keeps_its_records(rollo
         block = block_rows(4, 3, 8, jnp.bfloat16)
         blocks = row_buffer(4, 3, 4, 8, block)[0]
         assert counted["buffer_slots"] == 3 * 8 * blocks * block == 3 * 8 * 5 * 16
+        assert counted["slots_run"] == counted["buffer_slots"]      # blocks of 16: all are run
         # every row the net is applied to chooses 3 of 8, 4 held: at most 3 a row
         assert 0 < counted["rows_held"] <= 3 * 8 * 4 * 3
         assert records["value"].dtype == np.float32 and records["prob"].dtype == np.float32

@@ -344,8 +344,10 @@ def test_a_packed_window_equals_the_whole_one(long_windows, windows, bounds, slo
     assert counted["observed_steps"] == float(np.sum(batch["observation_mask"]))
     # the row buffers are sized from the slots the mixers run over
     assert counted.pop("buffer_slots") <= whole["counters"]["buffer_slots"]
+    # float32: the plain products run every slot of a buffer
+    assert counted.pop("slots_run") <= whole["counters"]["slots_run"]
     assert counted == {k: v for k, v in whole["counters"].items()
-                       if k not in ("packed_slots", "buffer_slots")}
+                       if k not in ("packed_slots", "buffer_slots", "slots_run")}
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_grad),
                             jax.tree.leaves(whole_grad)):
         np.testing.assert_allclose(
@@ -814,6 +816,12 @@ def test_held_mix_in_blocks_of_sixteen_is_held_mix_in_blocks_of_128(monkeypatch,
         blocks, passes = row_buffer(tokens, k, held, experts, block)
         assert int(counts["rows"].sum()) == live
         assert int(counts["slots"]) == int(counts["passes"]) * blocks * block
+        # the kernels in blocks of 128 run the blocks that hold a row; in blocks of 16, and the
+        # plain products, every block
+        skips = dtype == jnp.bfloat16 and block == BLOCK
+        assert int(counts["blocks_run"]) == (
+            int((-(-np.asarray(counts["rows"]) // block) * block).sum()) if skips
+            else int(counts["slots"]))
         return out, grads, int(counts["passes"]), passes
 
     low, low_grads, low_passes, covers = both(FEW_ROWS)
@@ -869,6 +877,7 @@ def test_the_net_counts_its_buffers_slots_and_the_passes_past_the_first():
     sizes = [row_buffer(n, 2, 4, 32, BLOCK)[0] * BLOCK for n in (6 * 8, 6 * 100)]   # float32: 128
     plain = counters(np.zeros(32))
     assert plain["buffer_slots"] == sum(sizes) and plain["expert_passes"] == 0
+    assert plain["slots_run"] == plain["buffer_slots"]      # float32: the plain products skip none
     assert 0 < plain["rows_held"] < 0.5 * 2 * 6 * 108
     skewed = counters(np.eye(32)[[9, 10]].sum(axis=0) * 10.0)
     assert skewed["rows_held"] == 2 * 6 * 108          # every choice of every token

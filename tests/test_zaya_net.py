@@ -333,8 +333,11 @@ def test_the_scan_reads_the_stacked_experts_in_place_and_is_the_unrolled_stack(
     monkeypatch.setattr(hybrid, "_period", lambda pattern: pattern)     # no period: unrolled
     (want, unrolled), want_grads = _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in)
     assert "expert_stack_reads" not in unrolled
-    for name in ("rows_held", "buffer_slots", "router_gate_mean"):
+    for name in ("rows_held", "buffer_slots", "slots_run", "router_gate_mean"):
         assert float(counters[name]) == pytest.approx(float(unrolled[name]), rel=0.02), name
+    # the toy's handful of rows lie in blocks of 16, all of which are run
+    assert float(counters["rows_held"]) <= float(counters["slots_run"]) == float(
+        counters["buffer_slots"])
     assert abs(float(loss) - float(want)) < BF16_TOLERANCE * max(1.0, abs(float(want)))
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(want_grads)):
