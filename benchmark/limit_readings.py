@@ -5,8 +5,10 @@ through the comparison that decides ``correct``.
 
 A ``train_step`` runner judges one seed a run and has no switch for the
 control.  This is that runner's last paragraph alone, many seeds in one
-process: for each seed the seeded weights and one batch row of seeded random
-play, handed to ``harness.judge_forward`` twice: once as they are (``sound``)
+process: for each seed the runner's own set-up (the seeded weights, the cell's
+batches of seeded random play, the routers' selection biases balanced on them:
+``traffic.balanced_params``) and the first batch's first row, the row a run of
+that seed judges, handed to ``harness.judge_forward`` twice: once as they are (``sound``)
 and once with the system's two forwards reading weights rounded leaf by leaf to
 float8 e4m3 (``8bit``: rounded eagerly, outside any jit, since inside one XLA
 keeps the excess precision and rounds nothing).  The reference always reads the
@@ -15,9 +17,8 @@ sound weights.  One JSON line a reading on stdout (``seed``, ``weights``,
 own key), appended to ``benchmark_out/limit_readings/<cell>.jsonl`` too.
 
 Exit 0 where every sound reading passes every check and every 8-bit one fails
-at least one, else 1.  The row comes from 8 episodes and a batch of 2 where the
-cell draws its batches from ``fill_episodes``: another row than a run of the
-same seed judges, from the same play.  ``judge_forward`` jits its programs
+at least one, else 1.  The 8-bit control rounds the balanced biases with every
+other leaf.  ``judge_forward`` jits its programs
 anew in every call, so a reading pays their traces again (the compile cache
 holds what they lower to): about a minute a seed at the published sizes.
 """
@@ -49,6 +50,7 @@ def readings(root: str, workload: str, seeds):
     from benchmark import harness, traffic
     from handyrl_tpu.config import normalize_args
     from handyrl_tpu.envs import make_env
+    from handyrl_tpu.parallel import TrainContext, make_mesh
     from handyrl_tpu.parallel.train_step import forward_prediction
 
     run = harness.Run(root, workload, seeds[0], 1.0, False, True, time.monotonic())
@@ -70,14 +72,18 @@ def readings(root: str, workload: str, seeds):
         run.require_module(module)
 
         def system(weights, batch, dtype=args.get("compute_dtype")):
-            read = weights["read"]
-            if dtype == "bfloat16":
-                read = jax.tree.map(lambda x: x.astype(jnp.bfloat16), read)
-            return forward_prediction(module, read, batch, dict(args, compute_dtype=dtype))
+            return forward_prediction(module, traffic.in_compute_dtype(weights["read"], dtype),
+                                      batch, dict(args, compute_dtype=dtype))
 
-        params = traffic.seeded_params(module, env, seed)
-        batch = traffic.random_play_batches(env, module, dict(args, batch_size=2), 1, 8)[0]
-        row = jax.tree.map(lambda x: np.asarray(x)[:1], batch)
+        # the runner's set-up: the cell's batches, the routers balanced on them
+        host_batches = traffic.random_play_batches(
+            env, module, args, int(cell["n_batches"]), int(cell["fill_episodes"]))
+        ctx = TrainContext(module, args, make_mesh(cell["mesh"], devices=jax.devices()[:run.chips]))
+        params, _, balance = traffic.balanced_params(
+            module, traffic.seeded_params(module, env, seed), ctx.args,
+            [ctx.put_batch(b) for b in host_batches])
+        row = jax.tree.map(lambda x: np.asarray(x)[:1], host_batches[0])
+        del host_batches
         burn_in = int(args["burn_in_steps"])
         legal = (row["action_mask"][:, burn_in:] == 0) & (row["turn_mask"][:, burn_in:] > 0)
         observed = row["observation_mask"][:, burn_in:] > 0
@@ -88,7 +94,7 @@ def readings(root: str, workload: str, seeds):
                 mask_of=lambda head: legal if head == "policy" else observed,
                 system_f32=lambda w, b: system(w, b, "float32"))
             yield {"seed": seed, "weights": weights, "tokens": int(observed.sum()),
-                   "checks": checks, "compared": compared}
+                   "checks": checks, "compared": compared, "router_balance": balance}
         del params, rounded
 
 

@@ -4,7 +4,10 @@ device batches, and nothing else of the program.
 Set-up: weights from ``--seed`` made on the device in one jitted call;
 ``n_batches`` host batches of seeded random-play windows (the program's
 own Generator -> EpisodeStore -> make_batch path), put on the device once;
-two warm-up updates, the first of which compiles or loads the one program.
+a routed net's selection biases balanced on them
+(``traffic.balance_routers``: rounds and the worst held expert's load over
+its share go to ``notes.router_balance``); two warm-up updates, the first
+of which compiles or loads the one program.
 Window: updates back to back, rotating the staged batches, at most
 ``in_flight`` dispatched ahead of the host; the clock stops after the last
 update's ``block_until_ready``.  A traced run measures ``trace_seconds``
@@ -12,10 +15,13 @@ under the profiler instead of ``--seconds``.
 
 After the window, outside it: every update's loss is fetched and checked
 (with them the step's ``counter_*`` metrics, whose mean per update goes to
-``run.counters``), and the forward pass the train step runs
+``run.counters`` and whose first and last update's to
+``notes.counters_first_last``), and the forward pass the train step runs
 (``forward_prediction``, in the cell's compute dtype) is compared with the
 configuration's plain float32 reference on one batch row
-(``harness.judge_forward``; a routed net hands its choices to the reference).
+(``harness.judge_forward``; a routed net hands its choices to the reference),
+on the seeded weights with the biases the window was timed on
+(``judged_biases_are_the_timed``: the state's, read before the first update).
 """
 
 from __future__ import annotations
@@ -28,7 +34,6 @@ from benchmark import harness, traffic
 
 def run(run: harness.Run) -> None:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from handyrl_tpu.config import normalize_args
@@ -54,9 +59,14 @@ def run(run: harness.Run) -> None:
     host_batches = traffic.random_play_batches(
         env, module, args, int(cell["n_batches"]), int(cell["fill_episodes"]))
     ctx = TrainContext(module, args, make_mesh(cell["mesh"], devices=run.devices))
+    batches = [ctx.put_batch(b) for b in host_batches]
+    # a routed net's selection biases, balanced on the staged batches: what
+    # the whole window chooses by (no gradient reaches them)
+    params, biases, run.notes["router_balance"] = traffic.balanced_params(
+        module, params, ctx.args, batches)
     state = ctx.init_state(params)
     del params
-    batches = [ctx.put_batch(b) for b in host_batches]
+    timed_biases = traffic.router_biases(state["params"])
     lr = float(cell["lr"])
     for i in range(2):          # the first compiles or loads; the second proves it
         state, metrics = ctx.train_step(state, batches[i % len(batches)], lr)
@@ -101,10 +111,13 @@ def run(run: harness.Run) -> None:
     run.values["trained_steps_per_s"] = steps / window_s
     run.counters.update(updates=updates, updates_per_s=updates / window_s,
                         window_s=window_s)
-    # what the step counts on the device (rows routed, a buffer's bound): mean per update
+    # what the step counts on the device (rows routed, a buffer's bound): mean per
+    # update, and the window's first and last update's (a router that drifts shows)
     for key in fetched[0]:
         if key.startswith("counter_"):
             run.counters[key] = float(np.mean([float(m[key]) for m in fetched]))
+            run.notes.setdefault("counters_first_last", {})[key] = [
+                float(fetched[0][key]), float(fetched[-1][key])]
     # when each update was seen to end: a run that reads far off says whether
     # every update was slower or a few stalled (2 of 22 read 4% and 9% low in
     # PR 33 and left nothing to tell by)
@@ -123,14 +136,17 @@ def run(run: harness.Run) -> None:
     row = jax.tree.map(lambda x: np.asarray(x)[:1], host_batches[0])
 
     def system_forward(p, batch, dtype=args.get("compute_dtype")):
-        if dtype == "bfloat16":
-            p = jax.tree.map(lambda x: x.astype(jnp.bfloat16), p)
-        return forward_prediction(module, p, batch, dict(ctx.args, compute_dtype=dtype))
+        return forward_prediction(module, traffic.in_compute_dtype(p, dtype), batch,
+                                  dict(ctx.args, compute_dtype=dtype))
 
-    # on the seeded weights, not the trained ones: lr 1e-5 on random weights
-    # saturates the value head within tens of updates
+    # on the seeded weights, drawn a second time (the state's are the window's
+    # updates old: at lr 1e-5 they saturated the value head within tens of
+    # them), under the biases set-up balanced and the window was timed on
     del state
-    params = traffic.seeded_params(module, env, run.seed)
+    params = traffic.with_router_biases(
+        traffic.seeded_params(module, env, run.seed), biases)
+    run.checks["judged_biases_are_the_timed"] = traffic.same_biases(
+        timed_biases, traffic.router_biases(params))
     burn_in = int(args["burn_in_steps"])
     legal = (row["action_mask"][:, burn_in:] == 0) & (row["turn_mask"][:, burn_in:] > 0)
     observed = row["observation_mask"][:, burn_in:] > 0

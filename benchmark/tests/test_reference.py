@@ -317,3 +317,9 @@ def test_choices_agreement_counts_sets_over_the_tokens_that_count():
         harness.choices_agreement(ours, {"a": theirs["a"]})
     with pytest.raises(ValueError, match="float32"):
         harness.choices_agreement({"a": np.zeros((1, 2, 2), np.float32)}, {"a": theirs["a"]})
+
+
+# ``traffic.balance_routers``' cases (``balanced_router_cases.py``: a name pytest
+# does not collect by, so each case runs once, wherever this file is collected;
+# a shim of their own in ``tests/`` is owed by a PR that may add one: PERF.md)
+from balanced_router_cases import *  # noqa: E402,F401,F403  (HERE is on sys.path: routed_toy above)
