@@ -3,6 +3,7 @@ paths are exercised without TPU hardware.  Both variables are read when
 jax is first imported, which is after this file.  The repo root goes on
 ``sys.path`` for the tests that import ``chip_smoke`` and ``benchmark``."""
 
+import collections
 import itertools
 import os
 import socket
@@ -16,6 +17,7 @@ import pytest
 # instrument every PR is judged by; nothing else collects them): their
 # asserts are rewritten like any test module's
 pytest.register_assert_rewrite(
+    "benchmark.tests.balanced_router_cases",
     "benchmark.tests.test_layer_readers", "benchmark.tests.test_phase_readers",
     "benchmark.tests.test_profile_wait",
     "benchmark.tests.test_reference", "benchmark.tests.test_rehearsal",
@@ -52,3 +54,42 @@ def free_port() -> int:
                 continue
         return port
     raise RuntimeError("no free port in this worker's range")
+
+
+# The driver runs the suite under ``-n 6 --dist loadfile`` inside a time limit.
+# A file stays on one worker, and xdist hands files out by their number of
+# cases, most first: a long file of few cases starts last and ends the run
+# alone (PR 58 met it, PR 66's run was cut by it).  So the files that take a
+# worker minutes go out first, longest first, whatever they count, and the
+# others after them in xdist's order.  The order is by the seconds a worker
+# spent on each under the driver's command on the builder's machine (PR 67:
+# CHANGES.md has the table); a file that grows past two minutes joins the list.
+_LONGEST_FIRST = (
+    "test_benchmark_rehearsals.py",     # every case that runs benchmark/run.py, on one worker
+    "test_parallel_grad_sync.py",
+    "test_zaya_net.py",
+    "test_benchmark_hybrid.py",
+    "test_chip_compile_steps.py",
+    "test_chip_compile.py",
+    "test_trinity_net.py",
+    "test_zaya_stack.py",
+    "test_granite_net.py",
+    "test_kanana_periods.py",
+    "test_looped_net.py",
+    "test_device_replay.py",
+    "test_device_rollout.py",
+    "test_chip_compile_cells.py",
+    "test_kanana_net.py",
+)
+
+
+def pytest_configure(config):
+    if hasattr(config.option, "loadscopereorder"):      # xdist is there: keep the order made below
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    cases = collections.Counter(item.path.name for item in items)
+    rank = {name: at for at, name in enumerate(_LONGEST_FIRST)}
+    # stable: a file's cases stay together and in their order
+    items.sort(key=lambda item: (rank.get(item.path.name, len(rank)), -cases[item.path.name]))

@@ -1,29 +1,30 @@
-"""Both Pallas attention kernels, the routed experts' grouped products at
-nemotron_twotower_train_t192's widths, a window part's attention core at both
-HybridNet cells' widths, and the d1536 train step on a {dp: 4} mesh, compiled
-for a described (not attached) TPU v5e by the chip's own compiler, at the
-shapes chip_smoke.py and the benchmark's cells pin.
+"""The Pallas kernels of the main paths at the shapes chip_smoke.py and the
+benchmark's cells pin, compiled for a described (not attached) TPU v5e by the
+chip's own compiler: both attention kernels, the routed experts' grouped
+products at ``nemotron_twotower_train_t192``'s and ``zaya1_8b``'s widths, a
+window part's attention core at the ``HybridNet`` cells' widths, and the
+acting rows' SSM step.  The whole programs are beside it since PR 67 (so that
+no one xdist worker compiles them all): the d1536 step's ring and
+``kanana2_train_t192``'s step in tests/test_chip_compile_steps.py,
+``trinity_mini_train_t192``'s step and the actor cell's rollout in
+tests/test_chip_compile_cells.py.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (a slice
 not aligned to the tiling, too much VMEM); these compiles can, at about
 two seconds each and no chip time.  Nothing executes, so this checks that
 the kernel is IN the program (``tpu_custom_call``) and that its forward
-and its custom-VJP backward compile — not results, not times.  The dp
-step's compile shows the schedule the chip will run: which collectives are in it and what runs between a
-collective-permute's start and its done.
+and its custom-VJP backward compile — not results, not times.
 """
 
 import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
-
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
+from described_v5e import _no_compile_cache, v5e, v5e_2x2  # noqa: F401  (fixtures)
 from handyrl_tpu.ops.flash_attention import flash_attention, masked_flash_attention
 
 _NET = chip_smoke.TRANSFORMER_TPU_NET_ARGS
@@ -41,36 +42,6 @@ PINNED = [(2 * _STEP["batch_size"], _STEP["burn_in_steps"] + _STEP["forward_step
 ]
 # T not a multiple of the 128 tile: only the masked kernel pads T
 UNALIGNED = (8, 200, 16, 96)
-
-
-@pytest.fixture(scope="module")
-def v5e_2x2():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as exc:  # no libtpu in this installation
-        pytest.skip(f"cannot describe a v5e topology here: {exc}")
-
-
-@pytest.fixture(scope="module")
-def v5e(v5e_2x2):
-    return SingleDeviceSharding(v5e_2x2.devices[0])
-
-
-@pytest.fixture(autouse=True)
-def _no_compile_cache():
-    """A compile for a described device is written to the persistent cache
-    but cannot be read back without the chip (the next run would warn and
-    recompile), so the cache is off around these."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
 
 
 def _kernel_fn(kernel, shape, sharding):
@@ -460,270 +431,6 @@ def test_attention_core_compiles_for_v5e_where_the_projections_wrote(v5e, monkey
     assert not copies, copies
 
 
-# -- the d1536 train step on a described {dp: N} mesh ----------------------
-
-def _lowered_step(topo, dp, batch_size, n_layers=2):
-    """The cell's train step (xfmr_train_t64*: d1536, T64, bf16, einsum) at
-    ``n_layers`` blocks, lowered for a {dp: dp} mesh of the described
-    chips from shapes alone.  Returns (context, lowered)."""
-    return _lowered(
-        topo, dp, {"env": "Geister", "net": "transformer",
-                   "net_args": dict(_NET, n_layers=n_layers)},
-        dict(_STEP, batch_size=batch_size, seq_attention="einsum"))
-
-
-def _lowered(topo, dp, env_args, train_args, packed=None):
-    """A train step of ``env_args``'s net under ``train_args``, lowered for a
-    {dp: dp} mesh of the described chips from shapes alone; ``packed``
-    (burn-in slots, forward slots) gives the batch the ``packed_order`` leaf
-    ``put_batch`` makes for a net that takes one.  Returns (context, lowered)."""
-    import random
-
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from benchmark import traffic
-    from handyrl_tpu.config import normalize_args
-    from handyrl_tpu.envs import make_env
-    from handyrl_tpu.parallel import TrainContext, make_mesh, param_shardings
-
-    cfg = normalize_args({"env_args": env_args, "train_args": train_args})
-    args = dict(cfg["train_args"], env=cfg["env_args"])
-    batch_size = args["batch_size"]
-    env = make_env(args["env"])
-    module = env.net()
-    mesh = make_mesh({"dp": dp}, devices=topo.devices)
-    ctx = TrainContext(module, args, mesh)
-    rows, rep = NamedSharding(mesh, PartitionSpec("dp")), NamedSharding(mesh, PartitionSpec())
-
-    env.reset()
-    obs = jax.tree.map(lambda x: jnp.asarray(x)[None], env.observation(env.players()[0]))
-    params = jax.eval_shape(
-        lambda key: module.init(key, obs, module.initial_state((1,)))["params"],
-        jax.random.PRNGKey(0),
-    )
-    state = {"params": params, "opt_state": jax.eval_shape(ctx.tx.init, params),
-             "steps": jax.ShapeDtypeStruct((), jnp.int32)}
-    layout = param_shardings(mesh, state)
-    state = jax.tree.map(
-        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), state, layout
-    )
-    # a two-window batch of random play gives every leaf's shape and dtype
-    random.seed(0)
-    np.random.seed(0)
-    small = traffic.random_play_batches(env, module, dict(args, batch_size=2), 1, 4)[0]
-    batch = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            (batch_size,) + np.shape(x)[1:], np.asarray(x).dtype, sharding=rows),
-        small,
-    )
-    if packed is not None:
-        players = np.shape(small["action"])[2]
-        batch["packed_order"] = {
-            part: jax.ShapeDtypeStruct((batch_size, players, slots), jnp.int32, sharding=rows)
-            for part, slots in zip(("burn_in", "forward"), packed)}
-    lowered = jax.jit(
-        ctx._step_fn, donate_argnums=(0,),
-        in_shardings=(layout, rows, rep), out_shardings=(layout, rep),
-    ).lower(state, batch, jax.ShapeDtypeStruct((), jnp.float32, sharding=rep))
-    return ctx, lowered
-
-
-def test_the_latent_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, monkeypatch):
-    """``kanana2_train_t192``'s train step as its files give it (pattern
-    ``L-LELELELE`` at the published widths, B32 x 2p x T192 packed to 8 + 96
-    slots, ``remat: block``, bfloat16: two leading layers and a scan over four
-    ``LE`` periods) compiles for a described v5e: the grouped kernels take
-    experts 768 wide where they lie in the periods' stack, the latent
-    attention's forward part runs ``ops/latent_core.py``'s kernel (no float32
-    scores of (64, 32, 96, 104) and no re-laid q in the program; the burn-in
-    part keeps the einsum lines), the program's peak is under the
-    chip's 16.9 GB with room (9.34 GB, 6.22 of it the arguments, 141 MB of
-    generated code and 60-80 s of compile alone on this host, PR 56; 9.64 GB
-    and 155 MB on the einsum lines, PR 52; unrolled it was 8.78 GB, 384 MB
-    and 85 s, and a cold run on the chip left 21 s of its 330: PR 52), and no
-    whole leaf of an expert layer's weights, or of their stack, is copied."""
-    import json
-    import os
-
-    from handyrl_tpu.models.hybrid import MLA_CORE_SCOPE
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "workloads", "kanana2_train_t192.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(bench, "configs", cell["config"] + ".json")) as f:
-        config = json.load(f)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
-    _, lowered = _lowered(v5e_2x2, 1, dict(config["env_args"]),
-                          dict(config["train_args"], **cell["train_args"]), packed=(8, 96))
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    assert 6.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 12.0e9
-    text = compiled.as_text()
-    # one period in the program: a window part has two products forward, those again
-    # under the backward scan, two rows' cotangents and two weight sums; and the forward
-    # part's latent attention core (``ops/latent_core.py``, PR 56) in the leading layer
-    # and in the period, each forward, replayed under its checkpoint and backward
-    assert text.count("tpu_custom_call") == 2 * 8 + 2 * 3
-    cores = [line for line in text.splitlines() if "custom-call(" in line
-             and "tpu_custom_call" in line and MLA_CORE_SCOPE in line]
-    assert len(cores) == 6
-    assert "f32[64,32,96,104]" not in text and "bf16[64,96,32,192]" not in text
-    held = config["env_args"]["net_args"]["experts_held"]
-    copies = re.compile(
-        r"= (bf16|f32)\[(4,)?%d,(2048,1536|768,2048)\]\S* (copy|copy-start)\(" % held)
-    found = [line.strip()[:160] for line in text.splitlines() if copies.search(line)]
-    assert not found, found[:3]
-
-
-def test_the_local_and_global_attention_cells_step_compiles_for_a_v5e_and_fits(v5e_2x2, monkeypatch):
-    """``trinity_mini_train_t192``'s train step as its files give it (pattern
-    ``W-*EWEWEWE`` at the published widths, B32 x 2p x T192 packed to 8 + 96
-    slots, ``remat: block``, bfloat16: two leading layers and a scan over four
-    attention-and-experts periods whose one attention layer is told, as data,
-    that it is the global or a local one) compiles for a described v5e: the
-    forward part's attention core is ``ops/attention_core.py``'s kernel in the
-    leading layer (its window and rotation static) and in the period (both
-    prefetched beside the rows' counts), each forward, replayed under its
-    checkpoint and backward; the grouped kernels take experts 1,024 wide where
-    they lie in the periods' stack; the program's peak is under the chip's
-    16.9 GB with room (10.92 GB, 7.33 of it the arguments, 162 MB of generated
-    code, under the 201 MB jax caches, and 59 s of compile alone on this host,
-    PR 58; with a program for each kind, four leading layers unrolled and three
-    ``WE`` periods scanned, the same row buffers, it was 10.42 GB, 244 MB and
-    85 s), and no whole
-    leaf of an expert layer's weights, or of their stack, is copied."""
-    import json
-    import os
-
-    from handyrl_tpu.models.hybrid import GQA_SCOPE
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "workloads", "trinity_mini_train_t192.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(bench, "configs", cell["config"] + ".json")) as f:
-        config = json.load(f)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
-    _, lowered = _lowered(v5e_2x2, 1, dict(config["env_args"]),
-                          dict(config["train_args"], **cell["train_args"]), packed=(8, 96))
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    assert 7.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 13.0e9
-    assert memory.generated_code_size_in_bytes < 201e6      # what jax's compile cache takes
-    text = compiled.as_text()
-    calls = [line for line in text.splitlines()
-             if "custom-call(" in line and "tpu_custom_call" in line]
-    cores = [line for line in calls if "/" + GQA_SCOPE + "/" in line]
-    # the leading layer's and the period's, each forward, replayed and backward
-    assert len(cores) == 6 and sum("/while/" in line for line in cores) == 3
-    assert len(calls) - len(cores) == 20        # the period's grouped products, both window parts
-    assert "f32[64,32,96,104]" not in text      # no score tile outside the kernel
-    held = config["env_args"]["net_args"]["experts_held"]
-    copies = re.compile(
-        r"= (bf16|f32)\[(4,)?%d,(2048,2048|1024,2048)\]\S* (copy|copy-start)\(" % held)
-    found = [line.strip()[:160] for line in text.splitlines() if copies.search(line)]
-    assert not found, found[:3]
-
-
-def _entry_ops(hlo_text):
-    """The entry computation's instructions, in schedule order."""
-    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo_text, re.S | re.M).group(1)
-    return [line.strip() for line in body.splitlines() if " = " in line]
-
-
-def _bytes(shape_text):
-    """Bytes of every array in an HLO result type, tuples included."""
-    widths = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
-    total = 0
-    for dtype, dims in re.findall(r"\b(bf16|f16|f32|s32|u32|pred|s8|u8)\[([\d,]*)\]", shape_text):
-        count = 1
-        for d in filter(None, dims.split(",")):
-            count *= int(d)
-        total += count * widths[dtype]
-    return total
-
-
-def test_dp4_step_rings_its_gradient_under_compute(v5e_2x2):
-    """{dp: 4}: no all-reduce over 2 MB is left in the program; every ring
-    hop's collective-permute has ops scheduled between its start and its
-    done; and the hops sit INSIDE the backward pass, where the chip's trace
-    measured them (PERF.md, PR 31): a section's backward pass starts only
-    once every hop of the sections two or more nearer the loss is done, so
-    each section's ring has the next section's backward pass to run under.
-    (With nothing in the backward pass waiting for a sum, or with the wait
-    folded away, the scheduler runs every hop behind the last backward op,
-    and the chip's trace shows them all exposed there.)"""
-    n_layers = 3
-    ctx, lowered = _lowered_step(
-        v5e_2x2, dp=4, batch_size=4 * _STEP["batch_size"], n_layers=n_layers
-    )
-    assert ctx.grad_sync["ring_leaves"] == n_layers * 6 + 2    # the blocks, enc2, policy
-    ops = _entry_ops(lowered.compile().as_text())
-    started, gaps, done_at, backward_from = {}, [], [], {}
-    for at, op in enumerate(ops):
-        name = re.match(r"(?:ROOT )?%?([\w.\-]+) = ", op).group(1)
-        if " collective-permute-start(" in op:
-            started[name] = at
-        elif " collective-permute-done(" in op:
-            source = re.search(r"collective-permute-done\([^%]*%([\w.\-]+)", op).group(1)
-            gaps.append(at - started[source])
-            done_at.append(at)
-        elif re.search(r" all-reduce(-start)?\(", op):
-            result_type = op.partition(" = ")[2].partition(" all-reduce")[0]
-            assert _bytes(result_type) <= 2 << 20, op[:200]
-        # the first op of each section's backward pass, by the name jax gave it
-        section = re.search(
-            r'op_name="[^"]*transpose\(jvp\(TransformerNet\.(\w+)\)\)/[a-z_]+(\d*)/', op
-        )
-        if section:
-            which = section.group(1)    # heads, encode, or a block and its number
-            backward_from.setdefault(which + section.group(2) * (which == "block"), at)
-    # a section's hops: one buffer a row shape (a block has rows of 1536 and
-    # of 6144), both ways, both rounds
-    hops = {"heads": 1, "encode": 1, **{f"block{i}": 2 for i in range(n_layers)}}
-    hops = {k: v * 2 * 2 * (4 - 1) for k, v in hops.items()}
-    assert len(gaps) == sum(hops.values())
-    assert min(gaps) >= 2, "a collective-permute's done sits right behind its start"
-    backward = ["heads"] + [f"block{i}" for i in reversed(range(n_layers))] + ["encode"]
-    assert sorted(backward_from, key=backward_from.get) == backward
-    for k, section in enumerate(backward[2:]):
-        due = sum(hops[s] for s in backward[:k + 1])
-        done = sum(at < backward_from[section] for at in done_at)
-        assert done >= due, (section, done, due)
-
-
-def test_dp1_step_updates_in_its_own_computation_under_one_norm(v5e_2x2):
-    """{dp: 1}, two blocks: the compiled step holds no ``conditional`` (the
-    sentinel's verdict is a select inside each leaf's update fusion; a
-    conditional's boundary fixes a layout per operand and hides its body
-    from CSE), so the clip's norm and the sentinel's are one: each leaf's
-    square sum is folded into the fusion that makes its gradient, and a
-    dozen reduce fusions of their own are left where the parent's branch
-    read every leaf a second time (70 with the ``lax.cond``, 9 without:
-    PERF.md, PR 38).  Not the bytes: at two blocks ``cost_analysis`` counts
-    the compiler's prefetch slices and does not fall."""
-    _, lowered = _lowered_step(v5e_2x2, dp=1, batch_size=_STEP["batch_size"], n_layers=2)
-    text = lowered.compile().as_text()
-    assert " conditional(" not in text
-    norms = re.findall(r"%?multiply_reduce_fusion[.\d]* = f32\[\][^ ]* fusion\(", text)
-    assert 0 < len(norms) <= 12, len(norms)
-
-
-def test_dp1_step_lowers_without_the_ring(v5e_2x2):
-    """{dp: 1} (the one-chip cells) lowers to the program it always was:
-    nothing of the sections' sums is in its text, all of it is in {dp: 4}'s."""
-    words = ("collective_permute", "all_reduce", "manual_computation")
-    ctx4, lowered4 = _lowered_step(v5e_2x2, dp=4, batch_size=8, n_layers=1)
-    text4 = lowered4.as_text()
-    for word in words:
-        assert word in text4, word
-    ctx1, lowered1 = _lowered_step(v5e_2x2, dp=1, batch_size=8, n_layers=1)
-    text1 = lowered1.as_text()
-    assert ctx1.grad_sync is None
-    for word in words + ("shard_map", "psum"):
-        assert word not in text1, word
-
-
 # -- the actor cell's rollout program (granite_actor_b32) ---------------------
 
 @pytest.mark.parametrize("players", [2, 4])
@@ -751,73 +458,3 @@ def test_ssd_step_rows_compiles_for_v5e_and_writes_the_buffer_it_read(v5e, playe
     assert "tpu_custom_call" in compiled.as_text()
     memory, state = compiled.memory_analysis(), 4 * n * players * (h * p * s + 3 * width)
     assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < state // 8
-
-
-
-def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v5e, monkeypatch):
-    """The streaming rollout of the benchmark's actor cell at its own sizes
-    (32 Geister lanes x 2 players, 16 steps, one period of the published
-    widths in bfloat16) compiles for a v5e with the routed experts' kernel in
-    it, and fits: the weights (9.14 GB) and one copy of the per-row state
-    (2.58 GB); the donated hidden tree is aliased through the scan and the
-    commit's select fused, so the temporaries stay under a gigabyte.  ISSUE
-    44's rule: over 15.5 GB the cell would run 16 lanes."""
-    import json
-
-    from handyrl_tpu.envs import make_env
-    from handyrl_tpu.runtime.device_rollout import build_streaming_fn
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmark", "configs", "granite_4_0_h_small.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(here, "benchmark", "workloads", "granite_actor_b32.json")) as f:
-        cell = json.load(f)["train_args"]
-    lanes, k = cell["device_rollout_games"], cell["device_replay_k_steps"]
-    env = make_env(config["env_args"])
-    module, venv = env.net(), env.vector_env()
-    env.reset()
-    obs = jax.tree.map(lambda x: jnp.asarray(x)[None], env.observation(env.players()[0]))
-    described = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
-    params = described(jax.eval_shape(
-        lambda key: module.init(key, obs, module.initial_state((1,)))["params"],
-        jax.random.PRNGKey(0)))
-    vstate = described(jax.eval_shape(lambda key: venv.init(lanes, key), jax.random.PRNGKey(0)))
-    hidden = described(jax.eval_shape(lambda: module.initial_state((lanes, venv.num_players))))
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernel, not its interpreter
-    fn = build_streaming_fn(venv, module, lanes, k, use_observe_mask=cell["observation"],
-                            counters=True)
-    compiled = fn.lower(params, vstate, hidden, key).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    # ~4.5 rows a held expert: row buffers of 56 blocks of 16, not 39 of 128 (PR 46)
-    assert "[896,4096]" in text and "[4992," not in text
-    memory = compiled.memory_analysis()
-    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
-            + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
-    assert 11.5e9 < held < 13.0e9, held                 # read 12.09 GB (PR 45); on the chip 11.76 in use
-    assert memory.alias_size_in_bytes > 2.5e9           # the hidden tree, donated
-    assert memory.temp_size_in_bytes < 1.0e9
-    # one player a lane observes (PR 45): a Mamba-2 layer's state for all lanes
-    # and both players is stepped where it lies, the acting player's row of
-    # each lane through ``ops/ssd.py``'s kernel (which sees it as lanes x
-    # players x (heads x head_dim) x S: a bitcast).  Nothing else yields or
-    # reads an array of its whole shape: no copy, no select, no multiply, no
-    # fusion, no scatter
-    rows = module.mamba_heads * module.mamba_head_dim
-    whole = ("f32[%d,%d,%d,%d,%d]" % (lanes, venv.num_players, module.mamba_heads,
-                                      module.mamba_head_dim, module.state_size),
-             "f32[%d,%d,%d,%d]" % (lanes, venv.num_players, rows, module.state_size))
-    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\(?[^=]*?\)?) ([\w-]+)\((.*)$", text, flags=re.M)
-    touch = lambda s: any(leaf in s for leaf in whole)  # noqa: E731
-    yields = {op for shape, op, _ in ops if touch(shape) and not shape.startswith("(s32[]")}
-    reads = {op for shape, op, rest in ops
-             if touch(rest.split(", metadata=")[0].split(", custom_call_target=")[0])
-             and not touch(shape)}
-    steps = [rest for shape, op, rest in ops if op == "custom-call" and touch(shape)]
-    assert len(steps) == module.pattern.count("M") == 9, len(steps)
-    assert yields == {"custom-call", "get-tuple-element", "parameter", "bitcast"}, yields
-    assert not reads, reads
-    # and the kernel writes the buffer it read: the scan's carry is its output
-    assert all("output_to_operand_aliasing" in rest for rest in steps), steps[0][:400]

@@ -9,10 +9,9 @@ handyrl_tpu), in both modes, through the train step, the streaming rollout
 and the actor host's loop.
 """
 
-import importlib.util
+import functools
 import json
 import os
-import random
 import re
 import threading
 
@@ -21,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import nets
 from handyrl_tpu.config import normalize_args
 from handyrl_tpu.envs import make_env
 from handyrl_tpu.models import HybridNet
@@ -29,20 +29,7 @@ from handyrl_tpu.parallel.train_step import forward_prediction
 from handyrl_tpu.runtime import actor_host, device_rollout
 from handyrl_tpu.runtime.device_rollout import build_streaming_fn
 from handyrl_tpu.utils import trace
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(*parts):
-    path = os.path.join(REPO, "benchmark", *parts)
-    spec = importlib.util.spec_from_file_location("granite_" + parts[-1][:-3], path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REFERENCE = _load("reference", "granite_4_0_h_small.py")
-FLOPS = _load("flops", "granite_moe_hybrid.py")
+from nets import HEADS, REPO, SCAN, _load, _predict
 
 NET = dict(
     pattern="ME*EME", d_model=32, norm_eps=1e-5,
@@ -52,23 +39,10 @@ NET = dict(
     n_heads=4, n_kv_heads=2, head_dim=16, memory_len=200,
     attn_score_scale=0.0625, residual_scale=0.22, embed_scale=12.0, logits_divisor=16.0,
 )
-HEADS = ("policy", "value", "return")
-
-
-def _config(**net):
-    return {"name": "tiny_granite", "env_args": {"env": "Geister", "net": "hybrid",
-                                                 "net_args": dict(NET, **net)}}
-
-
-def _geister(train_args, seed=1, **net):
-    config = _config(**net)
-    cfg = normalize_args({"env_args": dict(config["env_args"]),
-                          "train_args": dict(train_args, observation=True, seed=seed)})
-    args = dict(cfg["train_args"], env=cfg["env_args"])
-    random.seed(seed)
-    np.random.seed(seed)
-    env = make_env(args["env"])
-    return config, args, env, env.net()
+GRANITE = nets.Family("tiny_granite", NET, "granite_4_0_h_small.py")
+REFERENCE = GRANITE.REFERENCE
+FLOPS = _load("flops", "granite_moe_hybrid.py")
+_config, _geister = (functools.partial(f, GRANITE) for f in (nets._config, nets._geister))
 
 
 @pytest.fixture(scope="module")
@@ -77,15 +51,14 @@ def geister():
     observing on its own turns (the scan path unrolls its steps on one CPU
     device, so a longer window is minutes of compile; whole games in step
     mode are the rehearsal's: benchmark/tests/test_granite_rehearsal.py)."""
-    from benchmark import traffic
+    return nets._geister_windows(GRANITE, batch_size=3, burn_in_steps=0, forward_steps=16)
 
-    config, args, env, module = _geister(
-        {"batch_size": 3, "burn_in_steps": 0, "forward_steps": 16})
-    assert isinstance(module, HybridNet) and module.with_return
-    params = traffic.seeded_params(module, env, 1)
-    batch = traffic.random_play_batches(env, module, args, 1, 4)[0]
-    assert 0.2 < float(np.mean(batch["observation_mask"])) < 0.8
-    return config, args, module, params, batch
+
+@pytest.fixture(scope="module")
+def reference_rows(geister):
+    """The reference's outputs on the fixture's windows, its own choices."""
+    config, _, _, params, batch = geister
+    return jax.jit(lambda p, b: REFERENCE.forward_rows(p, b, config, 0))(params, batch)
 
 
 def _masks(batch):
@@ -104,12 +77,11 @@ def _worst(got, want, batch):
 # -- both modes against the plain reference ---------------------------------
 
 
-def test_step_mode_is_window_mode_is_the_reference_in_float32(geister):
+def test_step_mode_is_window_mode_is_the_reference_in_float32(geister, reference_rows):
     config, args, module, params, batch = geister
-    window = jax.jit(lambda p, b: forward_prediction(module, p, b, args))(params, batch)
-    steps = jax.jit(lambda p, b: forward_prediction(
-        module, p, b, dict(args, seq_forward=False)))(params, batch)
-    want = REFERENCE.forward_rows(params, batch, config, 0)
+    window = _predict(module, args)(params, batch)
+    steps = _predict(module, args, **SCAN)(params, batch)
+    want = reference_rows
     for got in (window, steps):
         assert max(_worst(got, want, batch).values()) < 1e-5
     # the reference chose what the window mode chose, in every routed sub-layer
@@ -149,11 +121,11 @@ def test_bfloat16_parameters_are_made_so_and_both_modes_hold_to_the_reference(ge
     # (the router's 32 x 8 is read in float32, as its logits are computed; enc1
     # meets float32 observations)
     assert len(trunk) >= 8 and wide & trunk <= {params["layer1"]["mixer"]["router"].shape}
-    window = jax.jit(lambda p, b: forward_prediction(module, p, b, args))(params, batch)
-    steps = jax.jit(lambda p, b: forward_prediction(
-        module, p, b, dict(args, seq_forward=False)))(params, batch)
+    window = _predict(module, args)(params, batch)
+    steps = _predict(module, args, **SCAN)(params, batch)
     widened = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-    forced = REFERENCE.forward_rows(widened, batch, config, 0, choices=window["choices"])
+    forced = jax.jit(lambda p, b, c: REFERENCE.forward_rows(p, b, config, 0, choices=c))(
+        widened, batch, window["choices"])
     for name, got in (("window", window), ("steps", steps)):
         worst = _worst(got, forced, batch)
         assert all(worst[head] <= BF16[head] for head in HEADS), (name, worst)
@@ -172,13 +144,13 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_net_with_one_thing_left_out_fails_the_comparison(geister, fault):
+def test_a_net_with_one_thing_left_out_fails_the_comparison(geister, reference_rows, fault):
     """The same parameters (where the shapes allow: an ungated expert reads
     the first half of each fused input matrix) through a net that lacks one
     piece of the family's mathematics: past the float32 tolerance the sound
     net holds."""
     config, args, module, params, batch = geister
-    want = REFERENCE.forward_rows(params, batch, config, 0)
+    want = reference_rows
     other = HybridNet(num_actions=module.num_actions, with_return=True, **dict(NET, **FAULTS[fault]))
     p = params
     if fault == "a dropped sub-layer":
@@ -192,7 +164,7 @@ def test_a_net_with_one_thing_left_out_fails_the_comparison(geister, fault):
             v["mixer"], w1=v["mixer"]["w1"][..., :16],
             shared_up={"kernel": v["mixer"]["shared_up"]["kernel"][:, :32]}))
             if k.startswith("layer") and "router" in v["mixer"] else v) for k, v in params.items()}
-    got = jax.jit(lambda p, b: forward_prediction(other, p, b, args))(p, batch)
+    got = _predict(other, args)(p, batch)
     worst = _worst(got, want, batch)
     assert any(worst[head] > 1e-4 for head in HEADS), worst     # ten times what the sound net reads
 
@@ -273,7 +245,8 @@ def test_the_train_steps_loss_and_gradients_are_the_references(geister):
 
     window = loss_of(lambda p: forward_prediction(module, p, batch, args))
     reference = loss_of(lambda p: REFERENCE.forward_rows(p, batch, config, 0))
-    (want_loss, want), (got_loss, got) = (jax.value_and_grad(f)(params) for f in (reference, window))
+    (want_loss, want), (got_loss, got) = (
+        jax.jit(jax.value_and_grad(f))(params) for f in (reference, window))
     assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-5)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(
@@ -600,9 +573,15 @@ def test_the_actor_loop_ships_whole_dispatches_and_installs_polled_weights_in_pa
     gateway = PlaneGateway(dist, on_records=on_records)
     gateway.start()
     trace.configure({"enabled": True, "path": str(tmp_path / "trace.jsonl")})
+    # the case's own time limit: a gateway that never says stop ends the loop by
+    # the event it watches (then ``dispatches`` below is not 5), not the worker's run
+    limit = threading.Timer(240.0, stop.set)
+    limit.daemon = True
+    limit.start()
     try:
         done = actor_host.actor_loop(cfg, jax.devices()[:1], stop)
     finally:
+        limit.cancel()
         gateway.stop()
         trace.shutdown()
     assert done["dispatches"] == len(batches) == 5 and gateway.actor_host_losses == 0

@@ -6,36 +6,21 @@ plain reference of the configuration it was written for
 handyrl_tpu.models), through ``forward_prediction`` and the train step.
 """
 
-import importlib.util
+import functools
 import json
 import os
-import random
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from handyrl_tpu.config import normalize_args
+import nets
 from handyrl_tpu.envs import make_env
 from handyrl_tpu.models import HybridNet
 from handyrl_tpu.parallel import TrainContext, make_mesh
 from handyrl_tpu.parallel.train_step import PACKED_ORDER, forward_prediction, pack_order
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(*parts):
-    path = os.path.join(REPO, "benchmark", *parts)
-    spec = importlib.util.spec_from_file_location("looped_" + parts[-1][:-3], path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REFERENCE = _load("reference", "ouro_2_6b.py")
-FLOPS = _load("flops", "ouro.py")
-HEADS = ("policy", "value", "return")
+from nets import HEADS, REPO, SCAN, _apart, _load, _predict, _random_window, _scan, _window
 
 NET = dict(
     pattern="*-*-", loops=4, sandwich=True, d_model=64, norm_eps=1e-6,
@@ -43,39 +28,23 @@ NET = dict(
 )
 
 
-def _config(**net):
-    return {"name": "tiny_ouro", "env_args": {"env": "Geister", "net": "hybrid",
-                                              "net_args": dict(NET, **net)}}
-
-
-def _net(**net):
-    return HybridNet(num_actions=5, with_return=True, **dict(NET, **net))
-
-
-def _window(seed, rows=3, steps=10, width=7, observed=0.6):
-    rng = np.random.RandomState(seed)
-    obs = {"a": jnp.asarray(rng.randn(rows, steps, width), jnp.float32)}
-    return obs, jnp.asarray(rng.rand(rows, steps) < observed, jnp.float32)
-
-
-def _params(module, obs, seed=0):
-    """Seeded parameters with every norm scale and bias moved off its
-    initial 1 or 0, so that a dropped or misplaced one shows."""
-    params = module.init(jax.random.PRNGKey(seed), jax.tree.map(lambda x: x[:, 0], obs), None)["params"]
+def _moved(params, seed):
+    """Every norm scale and bias moved off its initial 1 or 0, so that a
+    dropped or misplaced one shows."""
     rng = np.random.RandomState(seed)
     return jax.tree.map(
         lambda x: x + 0.2 * jnp.asarray(rng.randn(*x.shape), x.dtype) if x.ndim == 1 else x, params)
 
 
-def _apart(got, want, mask):
-    """Largest difference per head over the observed steps, over the head's
-    scale (at least 1): what ``harness.compare_outputs`` holds to a tolerance."""
-    seen = np.asarray(mask)[..., None] > 0
-    worst = 0.0
-    for head in HEADS:
-        a, b = np.asarray(got[head], np.float32), np.asarray(want[head], np.float32)
-        worst = max(worst, float(np.abs((a - b) * seen).max() / max(1.0, np.abs(b * seen).max())))
-    return worst
+OURO = nets.Family("tiny_ouro", NET, "ouro_2_6b.py", lively=_moved, actions=5)
+REFERENCE = OURO.REFERENCE
+FLOPS = _load("flops", "ouro.py")
+_net, _geister, _reference = (
+    functools.partial(f, OURO) for f in (nets._module, nets._geister, nets._reference))
+
+
+def _params(module, obs, seed=0):
+    return _moved(nets._params(module, obs, seed), seed)
 
 
 # -- the system against the plain reference --------------------------------
@@ -83,13 +52,12 @@ def _apart(got, want, mask):
 
 @pytest.mark.parametrize("pattern,loops", [("*-", 1), ("*-*-", 1), ("*-", 4), ("*-*-", 4)])
 def test_window_matches_the_reference_in_float32(pattern, loops):
-    config = _config(pattern=pattern, loops=loops)
     module = _net(pattern=pattern, loops=loops)
-    obs, mask = _window(1)
+    obs, mask = _random_window(1)
     params = _params(module, obs)
     assert ("exit_gate" in params) == (loops > 1)
-    got = module.apply({"params": params}, obs, None, seq=True, key_mask=mask)
-    want = REFERENCE.forward(params, obs, mask, config)
+    got = _window(module, params, obs, mask)
+    want = _reference(params, obs, mask, pattern=pattern, loops=loops)
     assert _apart(got, want, mask) < 1e-5
     assert ("layer_applications" in got["counters"]) == (loops > 1)
     if loops > 1:
@@ -104,7 +72,7 @@ def test_one_sub_layer_is_the_references(kind):
     """``x + RMSNorm(mixer(RMSNorm(x)))`` for each mixer alone, against the
     reference's own functions, and by hand for the rotation."""
     module = _net(pattern=kind, loops=1)
-    obs, mask = _window(2)
+    obs, mask = _random_window(2)
     params = _params(module, obs)
     net, eps = dict(NET, pattern=kind, loops=1), NET["norm_eps"]
     dense = lambda p, x: x @ p["kernel"] + p["bias"]  # noqa: E731
@@ -116,7 +84,7 @@ def test_one_sub_layer_is_the_references(kind):
     h = REFERENCE.rms_norm(x + REFERENCE.rms_norm(mixed, p["norm_out"], eps), params["norm_f"], eps)
     want = {"policy": dense(params["policy"], h), "value": jnp.tanh(dense(params["value"], h)),
             "return": dense(params["return_head"], h)}
-    got = module.apply({"params": params}, obs, None, seq=True, key_mask=mask)
+    got = _window(module, params, obs, mask)
     assert _apart(got, want, mask) < 1e-5
 
 
@@ -152,66 +120,42 @@ def test_window_matches_the_reference_in_bfloat16_at_a_stated_tolerance():
     seeds read 0.029 to 0.060 of the scale, the same forward from 8-bit
     weights 0.33 to 0.42: the tolerance lies between, twice over the one and
     under a third of the other."""
-    module, config = _net(), _config()
+    module = _net()
     to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
     sound, rough = [], []
     for seed in range(3):
-        obs, mask = _window(10 + seed, rows=4, steps=12)
+        obs, mask = _random_window(10 + seed, rows=4, steps=12)
         params = _params(module, obs, seed)
-        want = REFERENCE.forward(params, obs, mask, config)
-        got = module.apply({"params": to(params, jnp.bfloat16)}, to(obs, jnp.bfloat16), None,
-                           seq=True, key_mask=mask)
-        sound.append(_apart(got, want, mask))
+        want = _reference(params, obs, mask)
         eight = to(to(params, jnp.float8_e4m3fn), jnp.bfloat16)
-        got = module.apply({"params": eight}, to(obs, jnp.bfloat16), None, seq=True, key_mask=mask)
-        rough.append(_apart(got, want, mask))
+        for weights, readings in ((to(params, jnp.bfloat16), sound), (eight, rough)):
+            got = _window(module, weights, to(obs, jnp.bfloat16), mask)
+            readings.append(_apart(got, want, mask))
     assert max(sound) < BF16_TOLERANCE < min(rough), (sound, rough)
 
 
 # -- the faults the whole-net comparison must tell ---------------------------
 
 
-def _scan(module, params, obs, mask, count_every_step=False):
-    """Step mode over the window by hand, as the train step's scan path
-    does it: the hidden state is committed only where a step was observed.
-    ``count_every_step`` is the fault: the position moves on unobserved
-    steps too."""
-    rows, steps = mask.shape
-    hidden = module.initial_state((rows,))
-    outs = []
-    for t in range(steps):
-        out = module.apply({"params": params}, jax.tree.map(lambda x: x[:, t], obs), hidden)
-        new = out.pop("hidden")
-        seen = mask[:, t]
-        keep = lambda old, fresh: jnp.where(  # noqa: E731
-            seen.reshape((rows,) + (1,) * (old.ndim - 1)) > 0, fresh, old)
-        hidden = jax.tree.map(keep, hidden, new)
-        if count_every_step:
-            hidden = dict(hidden, pos=new["pos"])
-        outs.append(out)
-    return {head: jnp.stack([o[head] for o in outs], axis=1) for head in HEADS}
-
-
 def test_three_faults_each_fail_the_whole_net_comparison():
-    module, config = _net(), _config()
-    obs, mask = _window(4, rows=4, steps=12, observed=0.5)
+    module = _net()
+    obs, mask = _random_window(4, rows=4, steps=12, observed=0.5)
     assert 0 < float(mask.sum()) < mask.size and float(mask[:, 0].min()) == 0
     params = _params(module, obs)
-    want = REFERENCE.forward(params, obs, mask, config)
-    window = lambda net: net.apply({"params": params}, obs, None, seq=True, key_mask=mask)  # noqa: E731
+    want = _reference(params, obs, mask)
+    window = lambda net: _window(net, params, obs, mask)  # noqa: E731
     assert _apart(window(module), want, mask) < 1e-5
-    assert _apart(_scan(module, params, obs, mask), want, mask) < 1e-5
+    assert _apart(_scan(module, params, obs, mask)[0], want, mask) < 1e-5
     faults = {
         "three passes": window(_net(loops=3)),
         "no second norm": window(_net(sandwich=False)),
-        "positions over all steps": _scan(module, params, obs, mask, count_every_step=True),
+        "positions over all steps": _scan(module, params, obs, mask, count_every_step=True)[0],
     }
     for name, got in faults.items():
         assert _apart(got, want, mask) > 1e-3, name
     # and one layer's second norm alone: its scale at 1 where the weights' is not
     flat = dict(params, layer2=dict(params["layer2"], norm_out=jnp.ones_like(params["layer2"]["norm_out"])))
-    got = module.apply({"params": flat}, obs, None, seq=True, key_mask=mask)
-    assert _apart(got, want, mask) > 1e-3
+    assert _apart(_window(module, flat, obs, mask), want, mask) > 1e-3
 
 
 # -- state per application -------------------------------------------------
@@ -219,7 +163,7 @@ def test_three_faults_each_fail_the_whole_net_comparison():
 
 def test_ring_t_i_is_written_by_application_t_i_only():
     module = _net(memory_len=4)
-    obs, _ = _window(5, rows=2, steps=3)
+    obs, _ = _random_window(5, rows=2, steps=3)
     params = _params(module, obs)
     hidden = module.initial_state((2,))
     kinds = NET["pattern"] * NET["loops"]
@@ -267,7 +211,7 @@ def test_step_mode_acts_through_the_inference_model():
 
 
 def test_a_routed_layer_in_a_looped_stack_is_refused_by_name():
-    obs, _ = _window(0)
+    obs, _ = _random_window(0)
     module = HybridNet(num_actions=3, pattern="E*", loops=2)
     with pytest.raises(ValueError, match="a routed layer is run once"):
         module.init(jax.random.PRNGKey(0), jax.tree.map(lambda x: x[:, 0], obs), None)
@@ -277,11 +221,11 @@ def test_defaults_build_the_tower_they_always_did():
     """No loop, no second norm, no rotation unless asked: the parameters and
     the counters of a net that names none of the new fields are the old ones."""
     module = HybridNet(num_actions=3, pattern="*M", d_model=32)
-    obs, mask = _window(6)
+    obs, mask = _random_window(6)
     params = module.init(jax.random.PRNGKey(0), jax.tree.map(lambda x: x[:, 0], obs), None)["params"]
     assert set(params) == {"enc1", "enc2", "layer0", "layer1", "norm_f", "policy", "value"}
     assert set(params["layer0"]) == {"norm", "mixer"}
-    out = module.apply({"params": params}, obs, None, seq=True, key_mask=mask)
+    out = _window(module, params, obs, mask)
     assert set(out["counters"]) == {"packed_slots", "observed_steps", "packed_dropped"}
     assert len(module.initial_state((1,))["layers"]) == 2
 
@@ -289,7 +233,7 @@ def test_defaults_build_the_tower_they_always_did():
 def test_the_second_norms_scale_starts_where_it_is_told():
     """``out_scale_init`` is an initial value and nothing else: the forward
     of given parameters is the same net's whatever it says."""
-    obs, mask = _window(9)
+    obs, mask = _random_window(9)
     plain, small = _net(), _net(out_scale_init=0.5)
     init = lambda net: net.init(jax.random.PRNGKey(0), jax.tree.map(lambda x: x[:, 0], obs), None)["params"]  # noqa: E731
     ones, halves = init(plain), init(small)
@@ -298,20 +242,19 @@ def test_the_second_norms_scale_starts_where_it_is_told():
         assert np.array_equal(halves[name]["norm_out"], np.full(64, 0.5))
         assert np.array_equal(halves[name]["norm"], np.ones(64))
     assert np.array_equal(halves["norm_f"], np.ones(64))
-    a = plain.apply({"params": halves}, obs, None, seq=True, key_mask=mask)
-    b = small.apply({"params": halves}, obs, None, seq=True, key_mask=mask)
+    a, b = _window(plain, halves, obs, mask), _window(small, halves, obs, mask)
     assert np.array_equal(a["policy"], b["policy"])
-    assert _apart(b, REFERENCE.forward(halves, obs, mask, _config()), mask) < 1e-5
+    assert _apart(b, _reference(halves, obs, mask), mask) < 1e-5
     # nearer the identity: the branches move the encoder's output less
     moved = lambda net, params: float(jnp.abs(  # noqa: E731
-        net.apply({"params": params}, obs, None, seq=True, key_mask=mask)["policy"]
-        - _net(loops=1, pattern="").apply({"params": params}, obs, None, seq=True, key_mask=mask)["policy"]).mean())
+        _window(net, params, obs, mask)["policy"]
+        - _window(_net(loops=1, pattern=""), params, obs, mask)["policy"]).mean())
     assert moved(small, halves) < moved(plain, ones)
 
 
 def test_layout_counts_the_parameters_by_kind_and_the_applications():
     module = _net()
-    obs, _ = _window(7)
+    obs, _ = _random_window(7)
     params = _params(module, obs)
     layout = module.layout()
     assert (layout["pattern"], layout["loops"], layout["applications"]) == ("*-*-", 4, 16)
@@ -324,32 +267,12 @@ def test_layout_counts_the_parameters_by_kind_and_the_applications():
 # -- through forward_prediction and the train step ----------------------------
 
 
-def _geister(train_args, seed=1, **net):
-    config = _config(**net)
-    cfg = normalize_args({"env_args": dict(config["env_args"]),
-                          "train_args": dict(train_args, observation=True, seed=seed)})
-    args = dict(cfg["train_args"], env=cfg["env_args"])
-    random.seed(seed)
-    np.random.seed(seed)
-    env = make_env(args["env"])
-    return config, args, env, env.net()
-
-
 @pytest.fixture(scope="module")
 def geister():
-    from benchmark import traffic
-
-    config, args, env, module = _geister(
-        {"batch_size": 2, "burn_in_steps": 8, "forward_steps": 10})
-    assert isinstance(module, HybridNet) and module.with_return and module.loops == 4
-    params = traffic.seeded_params(module, env, 1)
-    rng = np.random.RandomState(1)
-    params = jax.tree.map(
-        lambda x: x + 0.2 * jnp.asarray(rng.randn(*x.shape), x.dtype) if x.ndim == 1 else x, params)
-    batch = traffic.random_play_batches(env, module, args, 1, 4)[0]
-    # Geister's players observe on their own turns: unobserved steps abound
-    assert 0.2 < float(np.mean(batch["observation_mask"])) < 0.8
-    return config, args, module, params, batch
+    config, args, module, params, batch = nets._geister_windows(
+        OURO, batch_size=2, burn_in_steps=8, forward_steps=10)
+    assert module.loops == 4
+    return config, args, module, _moved(params, 1), batch
 
 
 def _with_order(batch, burn_in, bounds):
@@ -366,11 +289,10 @@ def test_whole_window_matches_the_scan_path_and_the_reference(geister, burn_in):
     seen = np.asarray(batch["observation_mask"])[:, burn_in:] > 0
     most = int(np.moveaxis(seen[..., 0], 1, 2).sum(axis=-1).max())
     bounds = dict({"forward": most}, **({"burn_in": 8} if burn_in else {}))
-    window = jax.jit(lambda p, b: forward_prediction(module, p, b, args))
+    window = _predict(module, args)
     whole, packed = window(params, batch), window(params, _with_order(batch, burn_in, bounds))
-    scan = jax.jit(lambda p, b: forward_prediction(
-        module, p, b, dict(args, seq_forward=False)))(params, batch)
-    want = REFERENCE.forward_rows(params, batch, config, burn_in)
+    scan = _predict(module, args, **SCAN)(params, batch)
+    want = jax.jit(lambda p, b: REFERENCE.forward_rows(p, b, config, burn_in))(params, batch)
     for head in ("value", "return"):
         np.testing.assert_allclose(whole[head], scan[head], atol=2e-5)
         np.testing.assert_allclose(packed[head], scan[head], atol=2e-5)
@@ -396,6 +318,18 @@ def _loss(module, args, batch):
     return loss
 
 
+_GRADS = {}
+
+
+def _grad(module, args, batch, **over):
+    """Jitted params -> (loss, its gradient) of ``_loss`` under ``args`` with
+    ``over``: one program a (net, burn-in steps, ``over``), whichever case
+    asks (every case hands the fixture's ``args`` and ``batch``)."""
+    loss = _loss(module, args, batch)
+    key = (module, args["burn_in_steps"], tuple(sorted(over.items())))
+    return _GRADS.setdefault(key, jax.jit(jax.value_and_grad(lambda p: loss(p, **over))))
+
+
 def _close(got, want, rel):
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(
@@ -408,22 +342,21 @@ def test_the_gradient_of_a_shared_weight_sums_its_four_uses(geister):
     is read by no loss."""
     config, args, module, params, batch = geister
     args = dict(args, burn_in_steps=0)
-    loss = _loss(module, args, batch)
-
     def reference(p):
         out = REFERENCE.forward_rows(p, batch, config, 0)
         return sum(jnp.sum(jnp.square(out[k] * batch["observation_mask"]))
                    for k in ("value", "return"))
 
-    window, scan = jax.grad(loss)(params), jax.grad(lambda p: loss(p, seq_forward=False))(params)
-    want = jax.grad(reference)(params)
+    window = _grad(module, args, batch)(params)[1]
+    scan = _grad(module, args, batch, **SCAN)(params)[1]
+    want = jax.jit(jax.grad(reference))(params)
     _close(window, want, 2e-4)
     _close(scan, want, 2e-4)
     assert float(jnp.abs(want["layer1"]["mixer"]["up"]["kernel"]).max()) > 1e-3
     assert not jax.tree.leaves(jax.tree.map(lambda g: bool(jnp.any(g != 0)), window["exit_gate"]))[0]
     # four uses: the gradient of one pass alone is another
     single = HybridNet(num_actions=module.num_actions, with_return=True, **dict(NET, loops=1))
-    one = jax.grad(_loss(single, args, batch))({k: v for k, v in params.items() if k != "exit_gate"})
+    one = _grad(single, args, batch)({k: v for k, v in params.items() if k != "exit_gate"})[1]
     assert not np.allclose(one["layer1"]["mixer"]["up"]["kernel"],
                            window["layer1"]["mixer"]["up"]["kernel"], atol=1e-4)
 
@@ -435,16 +368,15 @@ def test_burn_in_hands_no_gradient_and_remat_changes_nothing(geister):
     what it dropped."""
     _, args, module, params, batch = geister
     assert args["burn_in_steps"] == 8
-    loss = _loss(module, args, batch)
-    value, window = jax.value_and_grad(loss)(params)
-    scan = jax.grad(lambda p: loss(p, seq_forward=False))(params)
+    value, window = _grad(module, args, batch)(params)
+    scan = _grad(module, args, batch, **SCAN)(params)[1]
     _close(window, scan, 5e-4)
-    again, block = jax.value_and_grad(lambda p: loss(p, remat="block"))(params)
+    again, block = _grad(module, args, batch, remat="block")(params)
     assert float(again) == pytest.approx(float(value), rel=1e-6)
     _close(block, window, 1e-5)
     # with the hand-off's gradient let through, the gradient is another
-    grads = lambda b: jax.grad(lambda p: _loss(module, dict(args, burn_in_steps=b), batch)(p))(params)  # noqa: E731
-    assert float(jnp.abs(grads(8)["enc1"]["kernel"]).sum()) < float(jnp.abs(grads(0)["enc1"]["kernel"]).sum())
+    through = _grad(module, dict(args, burn_in_steps=0), batch)(params)[1]
+    assert float(jnp.abs(window["enc1"]["kernel"]).sum()) < float(jnp.abs(through["enc1"]["kernel"]).sum())
 
 
 def test_train_step_packs_counts_and_records_its_layout(geister, tmp_path):
@@ -511,7 +443,7 @@ def test_the_trunks_phases_are_named_and_nothing_else_bears_the_names(monkeypatc
               hybrid.NORM_SCOPE)
     assert scopes == ("attn", "rope", "gqa", "mlp", "norm")
     module = _net()
-    obs, mask = _window(8)
+    obs, mask = _random_window(8)
     params = _params(module, obs)
     names = _op_names(module, params, obs, mask)
     for scope in scopes:

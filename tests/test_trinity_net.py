@@ -9,42 +9,23 @@ row observes 16 steps, the two ring lengths of the hidden pytree, what the
 step counts of the window, and the faults the comparison must tell."""
 
 import functools
-import importlib.util
 import json
 import os
-import random
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from handyrl_tpu.config import normalize_args
-from handyrl_tpu.envs import make_env
+import nets
 from handyrl_tpu.models import hybrid
 from handyrl_tpu.models.hybrid import (ATTN_GATE_SCOPE, ATTN_PROJ_SCOPE, QK_NORM_SCOPE,
                                        HybridNet)
 from handyrl_tpu.ops import attention_core
 from handyrl_tpu.ops.routed_experts import choose
-from handyrl_tpu.parallel import TrainContext, make_mesh
-from handyrl_tpu.parallel.train_step import forward_prediction, pack_order
-from handyrl_tpu.runtime import checkpoint
-from handyrl_tpu.utils import trace
+from handyrl_tpu.parallel.train_step import pack_order
 from handyrl_tpu.utils.compile_cache import scoped_program_options
-from test_kanana_net import _apart, _at, _scan      # over ``HEADS``: the same three here
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(*parts):
-    path = os.path.join(REPO, "benchmark", *parts)
-    spec = importlib.util.spec_from_file_location("trinity_" + parts[-1][:-3], path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REFERENCE = _load("reference", "trinity_mini.py")
+from nets import REPO, _apart, _bf16_loss_and_grads, _load, _scan, _window
 
 # heads x head_dim as wide as the stream, so that a gate applied after ``o``
 # can be written at all; a window a fifth of the steps, a ring that holds them
@@ -55,78 +36,30 @@ NET = dict(
     n_experts=16, top_k=4, expert_width=16, shared_width=16, routed_scale=2.826,
     experts_held=8, expert_offset=4, router="sigmoid", gated_experts=True,
 )
-HEADS = ("policy", "value", "return")
-ROWS, STEPS = 3, 20
+# ``lively``: the routers scaled up, ``q_norm`` and ``k_norm`` moved with every
+# vector (moved past a rotation, one shows); a row observes four windows of steps
+TRINITY = nets.Family(
+    "tiny_trinity", NET, "trinity_mini.py", on_geister={"memory_len": 200, "window": 6},
+    lively=functools.partial(nets._lively, routers={"router": 4}, bias_noise=0.1),
+    steps=20, observed=0.8)
+REFERENCE = TRINITY.REFERENCE
+_module, _init, _reference = (functools.partial(f, TRINITY) for f in (
+    nets._module, nets._init, nets._reference))
+_inputs = functools.partial(nets._inputs, TRINITY)
+ROWS, STEPS = TRINITY.rows, TRINITY.steps
 # float32 under "highest": the sound forward reads 1e-6 of a head's scale
 F32_TOLERANCE = 2e-4
 # bfloat16 weights and stream: sound, and weights rounded to 8 bits first
 BF16_TOLERANCE = 0.05
 
 
-def _config(**net):
-    return {"name": "tiny_trinity", "env_args": {"env": "Geister", "net": "hybrid",
-                                                 "net_args": dict(NET, **net)}}
-
-
-def _module(**net):
-    return HybridNet(num_actions=7, with_return=True, **dict(NET, **net))
-
-
-def _lively(params, seed=5):
-    """Every vector leaf (biases, norm scales, ``q_norm``, ``k_norm``,
-    ``score_bias``) moved off its initial zeros or ones, so that leaving one
-    out or moving it past a rotation shows, and the routers scaled up, so that
-    the scores spread the tokens over the experts."""
-    paths, treedef = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(paths))
-
-    def moved(path, leaf, key):
-        name = path[-1].key
-        if name == "router":
-            return 4 * leaf
-        noise = 0.1 if name == "score_bias" else 0.3
-        return leaf + noise * jax.random.normal(key, leaf.shape) if leaf.ndim == 1 else leaf
-
-    return jax.tree.unflatten(treedef, [moved(p, l, k) for (p, l), k in zip(paths, keys)])
-
-
-@functools.lru_cache(maxsize=None)
-def _seeded(module):
-    return jax.jit(lambda seed: _lively(
-        module.init(jax.random.PRNGKey(seed), {"a": jnp.ones((ROWS, 5))},
-                    module.initial_state((ROWS,)))["params"], seed + 5))
-
-
-def _init(module, seed=0):
-    return _seeded(module)(seed)
-
-
-def _inputs():
-    obs = {"a": jax.random.normal(jax.random.PRNGKey(1), (ROWS, STEPS, 5))}
-    mask = (jax.random.uniform(jax.random.PRNGKey(3), (ROWS, STEPS)) < 0.8).astype(jnp.float32)
-    return obs, mask
-
-
 @pytest.fixture(scope="module")
 def toy():
-    module = _module()
-    obs, mask = _inputs()
-    params = _init(module)
+    made = nets._toy(TRINITY)
+    mask = made[3]
     # a row observes sixteen steps or more: four times the window, under the ring
     assert 16 <= int(mask.sum(axis=1).max()) <= NET["memory_len"] and float(mask.mean()) < 0.9
-    return module, params, obs, mask, _reference(params, obs, mask, _config())
-
-
-def _window(module, params, obs, mask, **how):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, o, m: module.apply(
-            {"params": p}, o, None, seq=True, key_mask=m, **how))(params, obs, mask)
-
-
-def _reference(params, obs, mask, config, **given):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, o, m, **kw: REFERENCE.forward(p, o, m, config, **kw))(
-            params, obs, mask, **given)
+    return made
 
 
 # -- the three modes against the plain reference --------------------------------
@@ -141,7 +74,7 @@ def test_window_mode_is_the_reference_in_float32(toy, choices):
     module, params, obs, mask, want = toy
     got = _window(module, params, obs, mask)
     if choices == "forced":
-        want = _reference(params, obs, mask, _config(), choices=got["choices"])
+        want = _reference(params, obs, mask, choices=got["choices"])
     assert _apart(got, want, mask) < 2e-5
     seen = np.asarray(mask) > 0
     assert sorted(got["choices"]) == ["layer3", "layer5"]
@@ -206,31 +139,8 @@ def test_rows_steps_the_acting_players_rings_in_place(toy):
     player's rings of either length read and written where they lie (as zeros
     where the row's game has just begun), the other player's left as they
     were, or zeroed where it begins."""
-    module, params, obs, mask, _ = toy
-    assert all(jax.tree.leaves(module.rows_in_place(
-        {"layers": module.initial_state((1,))["layers"]})))
-    filled = jax.tree.map(
-        lambda x: jax.random.normal(jax.random.PRNGKey(x.size), x.shape),
-        module.initial_state((ROWS, 2)))
-    filled["pos"] = jnp.array([[3.0, 1.0], [7.0, 2.0], [0.0, 5.0]])
-    player, begun = jnp.array([1, 0, 1], jnp.int32), jnp.array([False, False, True])
-    step_obs = {"a": obs["a"][:, 0]}
-    lanes = jnp.arange(ROWS)
-    acting = jax.tree.map(lambda x: x[lanes, player] * ~begun.reshape(
-        (-1,) + (1,) * (x.ndim - 2)), filled)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda h: module.apply({"params": params}, step_obs, h))(acting)
-        got = jax.jit(lambda h, r: module.apply({"params": params}, step_obs, h, rows=r))(
-            dict(filled, pos=acting["pos"]), (player, begun))
-    for head in HEADS:
-        np.testing.assert_allclose(got[head], want[head], atol=1e-5)
-    for new, old, stepped in zip(got["hidden"]["layers"], filled["layers"],
-                                 want["hidden"]["layers"]):
-        for name in new:
-            np.testing.assert_allclose(new[name][lanes, player], stepped[name], atol=1e-5)
-            rest = np.array(old[name][lanes, 1 - player])
-            rest[np.asarray(begun)] = 0.0
-            np.testing.assert_array_equal(new[name][lanes, 1 - player], rest)
+    module, params, obs, _, _ = toy
+    nets._rows_stepped_in_place(module, params, obs)
 
 
 # -- the window where it binds ----------------------------------------------------
@@ -345,24 +255,15 @@ def test_the_periods_behind_the_leading_layers_scan_and_are_the_unrolled_stack(m
     assert _module().scanned_periods() == (6, "") and _module(
         pattern="M*EM*EM*E").scanned_periods() == (0, "M*E")
     got = _window(module, params, obs, mask, burn_in=5, remat="block")
-    want = _reference(params, obs, mask, _config(pattern="W-*EWEWEWE"), choices=got["choices"])
+    want = _reference(params, obs, mask, choices=got["choices"], pattern="W-*EWEWEWE")
     assert _apart(got, want, mask) < 2e-5
     assert sorted(got["choices"]) == ["layer3", "layer5", "layer7", "layer9"]
     assert float(got["counters"]["causal_pairs"]) == 4 * int(
         (mask.sum(axis=1) * (mask.sum(axis=1) + 1) // 2).sum())
-    to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
-
-    def loss(p):
-        out = module.apply({"params": to(p, jnp.bfloat16)}, to(obs, jnp.bfloat16), None, seq=True,
-                           key_mask=mask, burn_in=5, remat="block")
-        return (jnp.sum(jnp.square(out["value"].astype(jnp.float32) * mask[..., None]))
-                + 0.1 * jnp.sum(out["policy"].astype(jnp.float32) * mask[..., None]),
-                out["counters"])
-
-    (value, counters), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    (value, counters), grads = _bf16_loss_and_grads(module, params, obs, mask, "block", 5)
     assert float(counters["expert_stack_reads"]) == 8       # four periods, two window parts
     monkeypatch.setattr(hybrid, "_periods", lambda pattern: (len(pattern), ""))     # unrolled
-    (want, unrolled), want_grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    (want, unrolled), want_grads = _bf16_loss_and_grads(module, params, obs, mask, "block", 5)
     assert "expert_stack_reads" not in unrolled
     for name in ("causal_pairs", "window_pairs_cut"):
         assert float(counters[name]) == float(unrolled[name])
@@ -427,8 +328,9 @@ def test_a_layer_with_one_thing_wrong_fails_the_comparison(toy, fault, monkeypat
         monkeypatch.setattr(hybrid, *how["system"])
     if "reference" in how:
         monkeypatch.setattr(REFERENCE, *how["reference"])
-        want = _reference(params, obs, mask, _config())
-    got = _window(_module(**how.get("net", {})), params, obs, mask)
+        want = _reference(params, obs, mask, fresh=True)
+    # a patched side is traced anew, under the patch
+    got = _window(_module(**how.get("net", {})), params, obs, mask, fresh="system" in how)
     assert _apart(got, want, mask) > 5 * F32_TOLERANCE
 
 
@@ -436,70 +338,24 @@ def test_the_eight_bit_control_fails_where_bfloat16_holds(toy):
     """bfloat16 weights and stream hold to the reference forced to their
     choices; weights rounded leaf by leaf to float8 e4m3 first do not."""
     module, _, obs, mask, _ = toy
-    to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
-    sound, rough = [], []
-    forward = jax.jit(lambda w: module.apply(
-        {"params": w}, to(obs, jnp.bfloat16), None, seq=True, key_mask=mask))
-    reference = jax.jit(lambda p, choices: REFERENCE.forward(
-        p, obs, mask, _config(), choices=choices))
-    for seed in range(3):
-        p = _init(module, seed)
-        for weights, readings in ((to(p, jnp.bfloat16), sound),
-                                  (to(to(p, jnp.float8_e4m3fn), jnp.bfloat16), rough)):
-            got = forward(weights)
-            with jax.default_matmul_precision("highest"):
-                want = reference(p, got["choices"])
-            readings.append(_apart(got, want, mask))
+    sound, rough = nets._eight_bit_readings(TRINITY, module, obs, mask)
     assert max(sound) < BF16_TOLERANCE < min(rough), (sound, rough)
 
 
 # -- the system's entry points --------------------------------------------------
 
 
-def _geister(train_args, seed=1, **net):
-    config = _config(**dict({"memory_len": 200, "window": 6}, **net))
-    cfg = normalize_args({"env_args": dict(config["env_args"]),
-                          "train_args": dict(train_args, observation=True, seed=seed)})
-    args = dict(cfg["train_args"], env=cfg["env_args"])
-    random.seed(seed)
-    np.random.seed(seed)
-    env = make_env(args["env"])
-    return config, args, env, env.net()
-
-
 @pytest.fixture(scope="module")
 def geister():
-    from benchmark import traffic
-
-    config, args, env, module = _geister(
-        {"batch_size": 3, "burn_in_steps": 4, "forward_steps": 20})
-    assert isinstance(module, HybridNet) and module.with_return and module.pattern == "W-*EWE"
-    params = traffic.seeded_params(module, env, 1)
-    batch = traffic.random_play_batches(env, module, args, 1, 4)[0]
-    assert 0.2 < float(np.mean(batch["observation_mask"])) < 0.8
-    return config, args, module, params, batch
+    return nets._geister_windows(TRINITY, batch_size=3, burn_in_steps=4, forward_steps=20)
 
 
 def test_the_scan_path_and_the_window_path_are_the_reference_on_geister(geister):
     """``forward_prediction`` through ``env.net()``: the whole-window call and
     the train step's scan over step mode, burn-in 4, a window of 6 that binds,
     against ``forward_rows``."""
-    config, args, module, params, batch = geister
-    predict = lambda seq: jax.jit(lambda p, b: forward_prediction(  # noqa: E731
-        module, p, b, dict(args, seq_forward=seq)))(params, batch)
-    with jax.default_matmul_precision("highest"):
-        window, scan = predict(True), predict(False)
-        want = jax.jit(lambda p, b, c: REFERENCE.forward_rows(p, b, config, 4, choices=c))(
-            params, batch, window["choices"])
+    window = nets._both_paths_on_geister(TRINITY, geister)
     assert float(window["counters"]["window_pairs_cut"]) > 0
-    observed = batch["observation_mask"][:, 4:]
-    legal = (batch["action_mask"][:, 4:] == 0) & (batch["turn_mask"][:, 4:] > 0)
-    for head in HEADS:
-        keep = legal if head == "policy" else observed > 0
-        for got in (window, scan):
-            diff = np.where(keep, np.asarray(got[head]) - np.asarray(want[head]) * (
-                1 if head == "policy" else observed), 0.0)
-            assert float(np.abs(diff).max()) < 1e-4, head
 
 
 def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geister, tmp_path):
@@ -509,23 +365,11 @@ def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geist
     bias does not; the step counts its pairs and its gates; the state saved
     and loaded is the state; the layout says each ring's length; and the
     step's cache key knows the new scopes."""
-    config, args, module, params, batch = geister
-    args = dict(args, seq_forward=True, remat="block")
-    trace.configure({"enabled": True, "path": str(tmp_path / "trace.jsonl")})
-    try:
-        ctx = TrainContext(module, args, make_mesh({"dp": 1}))
-        device_batch = ctx.put_batch(batch)
-        before = jax.device_get(params)
-        state, metrics = ctx.train_step(ctx.init_state(params), device_batch, 1e-3)
-        metrics, after = jax.device_get(metrics), jax.device_get(state["params"])
-    finally:
-        trace.shutdown()
-    assert np.isfinite(metrics["total"]) and metrics["sentinel_bad"] == 0
+    _, _, module, params, _ = geister
+    metrics, moved, records = nets._update_and_checkpoint(geister, tmp_path)
     assert metrics["counter_rows_held"] > 0
     assert 0 < metrics["counter_window_pairs_cut"] < metrics["counter_causal_pairs"]
     assert 0.3 < metrics["counter_attn_gate_mean"] < 0.7
-    moved = lambda *path: not np.allclose(  # noqa: E731
-        np.asarray(_at(after, path)), np.asarray(_at(before, path)))
     for path in (("layer0", "mixer", "q", "kernel"), ("layer0", "mixer", "gate", "kernel"),
                  ("layer2", "mixer", "k", "kernel"), ("layer2", "mixer", "v", "kernel"),
                  ("layer4", "mixer", "o", "kernel"), ("layer0", "mixer", "q_norm"),
@@ -535,13 +379,6 @@ def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geist
                  ("layer3", "mixer", "w1"), ("layer5", "mixer", "w2")):
         assert moved(*path), path
     assert not moved("layer3", "mixer", "score_bias")
-
-    checkpoint.save_train_state(str(tmp_path / "state.ckpt"), state)
-    loaded = checkpoint.load_train_state(str(tmp_path / "state.ckpt"), jax.device_get(state))
-    for a, b in zip(jax.tree.leaves(loaded), jax.tree.leaves(jax.device_get(state))):
-        np.testing.assert_array_equal(a, b)
-
-    records = trace.read_trace(str(tmp_path / "trace.jsonl"))
     layout, = [r["attrs"] for r in records if r["name"] == "model.layout"]
     size = lambda *names: sum(  # noqa: E731
         x.size for name in names for x in jax.tree.leaves(params[name]))

@@ -3,52 +3,35 @@
 layer's router to the next, no shared expert) against the plain reference of
 ``zaya1_8b`` (``benchmark/reference/zaya1_8b.py``), at a small size on the
 CPU: both modes, the burn-in hand-off, the acting rows stepped in place, the
-gradient a top-1 gate gives its router, the carry across ``remat: block``,
-the two shares of the two-chip deployment, and the faults the comparison must
-tell."""
+gradient a top-1 gate gives its router, the carry across ``remat: block``, the
+faults the comparison must tell, and the system's entry points.  The scan over
+periods that reads the stacked experts in place and the two shares of the
+two-chip deployment are in tests/test_zaya_stack.py since PR 67."""
 
-import importlib.util
+import functools
+import json
 import os
-import random
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from handyrl_tpu.config import normalize_args
-from handyrl_tpu.envs import make_env
+import nets
 from handyrl_tpu.models import hybrid
-from handyrl_tpu.models.hybrid import CCA_SCOPE, ExpertLayer, HybridNet
+from handyrl_tpu.models.hybrid import CCA_SCOPE, HybridNet
+from handyrl_tpu.ops import attention_core, grouped_product
 from handyrl_tpu.ops.routed_experts import choose
 from handyrl_tpu.parallel import TrainContext, make_mesh
-from handyrl_tpu.parallel.train_step import forward_prediction, pack_order
-from handyrl_tpu.runtime import checkpoint
-from handyrl_tpu.utils import trace
+from handyrl_tpu.parallel.train_step import pack_order
 from handyrl_tpu.utils.compile_cache import scoped_program_options
+from nets import REPO, ZAYA, _apart, _scan, _window
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(*parts):
-    path = os.path.join(REPO, "benchmark", *parts)
-    spec = importlib.util.spec_from_file_location("zaya_" + parts[-1][:-3], path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REFERENCE = _load("reference", "zaya1_8b.py")
-
-NET = dict(
-    pattern="CECECE", d_model=32, norm_eps=1e-5,
-    n_heads=4, n_kv_heads=2, head_dim=8, memory_len=6, rope_theta=1e4, rotary_factor=0.5,
-    cca_time0=2, cca_time1=2,
-    n_experts=8, top_k=1, expert_width=16, shared_width=0, routed_scale=1.0,
-    experts_held=4, expert_offset=0, router="mlp", router_width=8, gated_experts=True,
-)
-HEADS = ("policy", "value", "return")
-ROWS, STEPS = 3, 14
+NET = ZAYA.net
+REFERENCE = ZAYA.REFERENCE
+_module, _init, _reference, _geister = (functools.partial(f, ZAYA) for f in (
+    nets._module, nets._init, nets._reference, nets._geister))
+ROWS, STEPS = ZAYA.rows, ZAYA.steps
 # float32 under "highest": the sound forward reads 4e-6 of a head's scale,
 # the mildest fault below 2e-2
 F32_TOLERANCE = 2e-4
@@ -56,64 +39,11 @@ F32_TOLERANCE = 2e-4
 BF16_TOLERANCE = 0.06
 
 
-def _config(**net):
-    return {"name": "tiny_zaya", "env_args": {"env": "Geister", "net": "hybrid",
-                                              "net_args": dict(NET, **net)}}
-
-
-def _module(**net):
-    return HybridNet(num_actions=7, with_return=True, **dict(NET, **net))
-
-
-def _lively(params, seed=5):
-    """Every vector leaf (biases, norm scales, ``carry_scale``, ``temp``,
-    ``score_bias``) moved off its initial zeros or ones, so that leaving one
-    out shows, and the routers' last maps scaled up, so that the scores, and
-    not the choosing bias, spread the tokens over the experts."""
-    paths, treedef = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(paths))
-
-    def moved(path, leaf, key):
-        name = path[-1].key
-        if name == "router_out":
-            return 8 * leaf
-        noise = 0.03 if name == "score_bias" else 0.3
-        return leaf + noise * jax.random.normal(key, leaf.shape) if leaf.ndim == 1 else leaf
-
-    return jax.tree.unflatten(treedef, [moved(p, l, k) for (p, l), k in zip(paths, keys)])
-
-
 @pytest.fixture(scope="module")
 def toy():
-    module = _module()
-    obs = {"a": jax.random.normal(jax.random.PRNGKey(1), (ROWS, STEPS, 5))}
-    params = _lively(module.init(jax.random.PRNGKey(0), {"a": jnp.ones((ROWS, 5))},
-                                 module.initial_state((ROWS,)))["params"])
-    mask = (jax.random.uniform(jax.random.PRNGKey(3), (ROWS, STEPS)) < 0.6).astype(jnp.float32)
-    assert 0.3 < float(mask.mean()) < 0.8
-    return module, params, obs, mask, _reference(params, obs, mask, _config())
-
-
-def _window(module, params, obs, mask, **how):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, o, m: module.apply(
-            {"params": p}, o, None, seq=True, key_mask=m, **how))(params, obs, mask)
-
-
-def _reference(params, obs, mask, config, **given):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, o, m, **kw: REFERENCE.forward(p, o, m, config, **kw))(
-            params, obs, mask, **given)
-
-
-def _apart(got, want, mask):
-    """Largest difference over the observed steps, in units of a head's scale."""
-    worst = 0.0
-    for head in HEADS:
-        a, b = np.asarray(got[head], np.float32), np.asarray(want[head], np.float32)
-        diff = np.abs(a - b) * np.asarray(mask)[..., None]
-        worst = max(worst, float(diff.max()) / max(1.0, float(np.abs(b).max())))
-    return worst
+    made = nets._toy(ZAYA)
+    assert 0.3 < float(made[3].mean()) < 0.8
+    return made
 
 
 # -- both modes against the plain reference ---------------------------------
@@ -127,7 +57,7 @@ def test_window_mode_is_the_reference_in_float32(toy, choices):
     module, params, obs, mask, want = toy
     got = _window(module, params, obs, mask)
     if choices == "forced":
-        want = _reference(params, obs, mask, _config(), choices=got["choices"])
+        want = _reference(params, obs, mask, choices=got["choices"])
     assert _apart(got, want, mask) < 2e-5
     seen = np.asarray(mask) > 0
     for name, chosen in got["choices"].items():
@@ -136,26 +66,6 @@ def test_window_mode_is_the_reference_in_float32(toy, choices):
     assert len({int(e) for c in got["choices"].values() for e in np.asarray(c)[seen].ravel()}) > 2
     gate = float(got["counters"]["router_gate_mean"])
     assert 1 / NET["n_experts"] < gate < 1
-
-
-def _scan(module, params, obs, mask):
-    """Step mode over the window by hand, as the train step's scan path does
-    it: the hidden state is committed only where a step was observed."""
-    rows, steps = mask.shape
-
-    @jax.jit
-    def step(hidden, obs_t, seen):
-        out = module.apply({"params": params}, obs_t, hidden)
-        new = out.pop("hidden")
-        return jax.tree.map(lambda old, fresh: jnp.where(
-            seen.reshape((rows,) + (1,) * (old.ndim - 1)) > 0, fresh, old), hidden, new), out
-
-    hidden, outs = module.initial_state((rows,)), []
-    with jax.default_matmul_precision("highest"):
-        for t in range(steps):
-            hidden, out = step(hidden, jax.tree.map(lambda x: x[:, t], obs), mask[:, t])
-            outs.append(out)
-    return {head: jnp.stack([o[head] for o in outs], axis=1) for head in HEADS}, hidden
 
 
 def test_step_by_step_is_the_whole_window(toy):
@@ -211,31 +121,8 @@ def test_rows_steps_the_acting_players_leaves_in_place(toy):
     player's tail, last value and ring read and written where they lie (as
     zeros where the row's game has just begun), the other player's rows left
     as they were, or zeroed where it begins."""
-    module, params, obs, mask, _ = toy
-    assert all(jax.tree.leaves(module.rows_in_place(
-        {"layers": module.initial_state((1,))["layers"]})))
-    filled = jax.tree.map(
-        lambda x: jax.random.normal(jax.random.PRNGKey(x.size), x.shape),
-        module.initial_state((ROWS, 2)))
-    filled["pos"] = jnp.array([[3.0, 1.0], [7.0, 2.0], [0.0, 5.0]])
-    player, begun = jnp.array([1, 0, 1], jnp.int32), jnp.array([False, False, True])
-    step_obs = {"a": obs["a"][:, 0]}
-    lanes = jnp.arange(ROWS)
-    acting = jax.tree.map(lambda x: x[lanes, player] * ~begun.reshape(
-        (-1,) + (1,) * (x.ndim - 2)), filled)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda h: module.apply({"params": params}, step_obs, h))(acting)
-        got = jax.jit(lambda h, r: module.apply({"params": params}, step_obs, h, rows=r))(
-            dict(filled, pos=acting["pos"]), (player, begun))
-    for head in HEADS:
-        np.testing.assert_allclose(got[head], want[head], atol=1e-5)
-    for new, old, stepped in zip(got["hidden"]["layers"], filled["layers"],
-                                 want["hidden"]["layers"]):
-        for name in new:
-            np.testing.assert_allclose(new[name][lanes, player], stepped[name], atol=1e-5)
-            rest = np.array(old[name][lanes, 1 - player])
-            rest[np.asarray(begun)] = 0.0
-            np.testing.assert_array_equal(new[name][lanes, 1 - player], rest)
+    module, params, obs, _, _ = toy
+    nets._rows_stepped_in_place(module, params, obs)
 
 
 # -- the router: a top-1 gate, the carry -------------------------------------
@@ -247,8 +134,7 @@ def test_the_router_gets_a_gradient_through_a_top1_gate(toy):
     as the other routers' gates are, a top-1 gate is 1.0 and gives none."""
     _, _, obs, mask, _ = toy
     module = _module(experts_held=8)    # uncut: whatever a token chooses is held
-    params = _lively(module.init(jax.random.PRNGKey(0), {"a": jnp.ones((ROWS, 5))},
-                                 module.initial_state((ROWS,)))["params"])
+    params = _init(module)
 
     def loss(p):
         out = module.apply({"params": p}, obs, None, seq=True, key_mask=mask)
@@ -295,111 +181,6 @@ def test_the_carry_crosses_remat_block(toy):
     heard, unheard = (grad(p, "block")["layer1"]["mixer"]["router_down"]
                       for p in (params, silent))
     assert float(jnp.abs(heard - unheard).max()) > 1e-6
-
-
-def _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in):
-    """The toy net's loss over the value and policy heads, its counters and
-    every leaf's gradient, weights and stream in bfloat16 (the experts'
-    products are then the grouped kernel's, in the interpreter)."""
-    to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
-
-    def loss(p):
-        out = module.apply({"params": to(p, jnp.bfloat16)}, to(obs, jnp.bfloat16), None, seq=True,
-                           key_mask=mask, burn_in=burn_in, remat=remat)
-        return (jnp.sum(jnp.square(out["value"].astype(jnp.float32) * mask[..., None]))
-                + 0.1 * jnp.sum(out["policy"].astype(jnp.float32) * mask[..., None]),
-                out["counters"])
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-
-
-@pytest.mark.parametrize("burn_in", [0, 4])
-@pytest.mark.parametrize("remat", ["none", "block"])
-def test_the_scan_reads_the_stacked_experts_in_place_and_is_the_unrolled_stack(
-        toy, monkeypatch, remat, burn_in):
-    """In bfloat16 the scan over periods closes over the ``E`` layers' stacked
-    ``w1`` and ``w2``, the grouped kernel reads a period where it lies and
-    the stacked gradient comes back through the sinks in the scan's carry
-    (PERF.md, PR 51): loss and every leaf's gradient are the unrolled
-    stack's (``passes``, which a pattern with no period takes) within the
-    bfloat16 tolerance, with and without a checkpoint a layer, with and
-    without a burn-in part.  ``counters["expert_stack_reads"]`` counts the
-    routed layer applications that read in place: three periods a window
-    part."""
-    module, params, obs, mask, _ = toy
-    (loss, counters), grads = _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in)
-    assert float(counters["expert_stack_reads"]) == (6 if burn_in else 3)
-    assert float(counters["expert_passes"]) == 0
-    monkeypatch.setattr(hybrid, "_period", lambda pattern: pattern)     # no period: unrolled
-    (want, unrolled), want_grads = _bf16_loss_and_grads(module, params, obs, mask, remat, burn_in)
-    assert "expert_stack_reads" not in unrolled
-    for name in ("rows_held", "buffer_slots", "slots_run", "router_gate_mean"):
-        assert float(counters[name]) == pytest.approx(float(unrolled[name]), rel=0.02), name
-    # the toy's handful of rows lie in blocks of 16, all of which are run
-    assert float(counters["rows_held"]) <= float(counters["slots_run"]) == float(
-        counters["buffer_slots"])
-    assert abs(float(loss) - float(want)) < BF16_TOLERANCE * max(1.0, abs(float(want)))
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
-                            jax.tree.leaves(want_grads)):
-        assert a.dtype == b.dtype and bool(jnp.isfinite(a).all()), path
-        assert float(jnp.abs(a - b).max()) < BF16_TOLERANCE * max(1.0, float(jnp.abs(b).max())), path
-    reached = [layer for layer in ("layer1", "layer3", "layer5")   # periods whose held experts got rows
-               if float(jnp.abs(want_grads[layer]["mixer"]["w1"]).max()) > 0]
-    assert len(reached) >= 2, reached
-    for layer in reached:       # the sinks' cotangent came back for each of them
-        for name in ("w1", "w2"):
-            assert float(jnp.abs(grads[layer]["mixer"][name]).max()) > 0, (layer, name)
-
-
-def test_a_float32_scan_and_a_stack_without_periods_read_no_stack_in_place(toy):
-    """Float32 operands keep the plain block products and the scan's own
-    slices of every leaf, and a ``MEME`` stack scans nothing: neither counts
-    a read in place."""
-    module, params, obs, mask, _ = toy
-    out = jax.jit(lambda p: module.apply({"params": p}, obs, None, seq=True, key_mask=mask,
-                                         burn_in=4))(params)
-    assert "expert_stack_reads" not in out["counters"] and "rows_held" in out["counters"]
-    plain = HybridNet(num_actions=7, pattern="MEME", d_model=32, n_experts=8, top_k=2,
-                      expert_width=16, shared_width=16, experts_held=4)
-    weights = plain.init(jax.random.PRNGKey(0), {"a": jnp.ones((ROWS, 5))},
-                         plain.initial_state((ROWS,)))["params"]
-    to = lambda tree: jax.tree.map(lambda x: x.astype(jnp.bfloat16), tree)  # noqa: E731
-    out = jax.jit(lambda p: plain.apply({"params": p}, to(obs), None, seq=True, key_mask=mask,
-                                        burn_in=4))(to(weights))
-    assert "rows_held" in out["counters"]
-    assert float(out["counters"].get("expert_stack_reads", 0.0)) == 0.0
-
-
-def test_the_two_shares_of_a_layer_add_up_to_the_uncut_reference(toy):
-    """Offsets 0 and 4 of the two-chip deployment: each share scores and
-    chooses over all eight experts with the whole router (the same choices,
-    the same carry) and adds its own four experts' terms; the two terms add
-    up to the layer whose eight experts are on one chip."""
-    _, params, _, _, _ = toy
-    whole = jax.tree.map(lambda x: x, params["layer3"]["mixer"])
-    key = jax.random.PRNGKey(7)
-    whole["w1"] = jax.random.normal(key, (8, 32, 32)) / 6
-    whole["w2"] = jax.random.normal(jax.random.fold_in(key, 1), (8, 16, 32)) / 4
-    whole["router_out"] = 3 * jax.random.normal(jax.random.fold_in(key, 4), (8, 8))
-    h = jax.random.normal(jax.random.fold_in(key, 2), (2, 9, 32))
-    carry = jax.random.normal(jax.random.fold_in(key, 3), (2, 9, 8))
-    net = dict(NET, experts_held=8)
-    with jax.default_matmul_precision("highest"):
-        want, chosen, r = REFERENCE.experts(whole, h, carry, net)
-        assert len(np.unique(chosen)) > 2 and (np.asarray(chosen) >= 4).any()
-        total = 0.0
-        for offset in (0, 4):
-            share = dict(whole, w1=whole["w1"][offset:offset + 4], w2=whole["w2"][offset:offset + 4])
-            layer = ExpertLayer(32, 8, 1, 16, 0, 1.0, 4, offset, "mlp", True, jnp.float32, 8, 1e-5)
-            out, picked, counts, handed = jax.jit(
-                lambda p: layer.apply({"params": p}, h, None, carry))(share)
-            np.testing.assert_array_equal(picked, chosen)
-            np.testing.assert_allclose(handed, r, atol=1e-5)
-            assert int(counts["rows"].sum()) == int(((chosen >= offset) & (chosen < offset + 4)).sum())
-            np.testing.assert_allclose(
-                out, REFERENCE.experts(share, h, carry, dict(NET, expert_offset=offset))[0], atol=1e-5)
-            total = total + out
-    np.testing.assert_allclose(total, want, atol=1e-5)
 
 
 # -- the faults the comparison must tell ---------------------------------------
@@ -452,83 +233,42 @@ def test_a_net_with_one_thing_left_out_fails_the_comparison(toy, fault, monkeypa
     """Each fault reads over the float32 limit that the sound net is 50 times
     under, with the choices forced to the system's as ``correct`` does it."""
     module, params, obs, mask, _ = toy
+    faulty, theirs = module, params
     if fault == "unshifted_value":      # both value halves the current token's
         real = REFERENCE.back
         monkeypatch.setattr(REFERENCE, "back", lambda x, observed, pos, j: (
             x if x.shape[-1] == 8 else real(x, observed, pos, j)))
-        faulty, theirs = module, params
     elif fault == "renormalised_gate":  # a top-1 gate of 1.0, as the other routers' ``choose`` makes it
         monkeypatch.setattr(hybrid, "choose", lambda *a, **how: choose(*a))
-        faulty, theirs = module, params
     else:
         net, change = FAULTS[fault]
         faulty, theirs = _module(**net), change(params) if change else params
-    got = _window(faulty, theirs, obs, mask)
-    want = _reference(params, obs, mask, _config(), choices=got["choices"])
+    # a patched side is traced anew, under the patch
+    got = _window(faulty, theirs, obs, mask, fresh=fault == "renormalised_gate")
+    want = _reference(params, obs, mask, choices=got["choices"], fresh=fault == "unshifted_value")
     assert _apart(got, want, mask) > 5 * F32_TOLERANCE
 
 
 def test_the_eight_bit_control_fails_where_bfloat16_holds(toy):
     """bfloat16 weights and stream hold to the reference forced to their
     choices; weights rounded leaf by leaf to float8 e4m3 first do not."""
-    module, params, obs, mask, _ = toy
-    to = lambda tree, dtype: jax.tree.map(lambda x: x.astype(dtype), tree)  # noqa: E731
-    sound, rough = [], []
-    for seed in range(3):
-        p = _lively(module.init(jax.random.PRNGKey(seed), {"a": jnp.ones((ROWS, 5))},
-                                module.initial_state((ROWS,)))["params"], seed)
-        for weights, readings in ((to(p, jnp.bfloat16), sound),
-                                  (to(to(p, jnp.float8_e4m3fn), jnp.bfloat16), rough)):
-            got = jax.jit(lambda w: module.apply(
-                {"params": w}, to(obs, jnp.bfloat16), None, seq=True, key_mask=mask))(weights)
-            want = _reference(p, obs, mask, _config(), choices=got["choices"])
-            readings.append(_apart(got, want, mask))
+    module, _, obs, mask, _ = toy
+    sound, rough = nets._eight_bit_readings(ZAYA, module, obs, mask)
     assert max(sound) < BF16_TOLERANCE < min(rough), (sound, rough)
 
 
 # -- the system's entry points --------------------------------------------------
 
 
-def _geister(train_args, seed=1, **net):
-    config = _config(**dict({"memory_len": 200}, **net))
-    cfg = normalize_args({"env_args": dict(config["env_args"]),
-                          "train_args": dict(train_args, observation=True, seed=seed)})
-    args = dict(cfg["train_args"], env=cfg["env_args"])
-    random.seed(seed)
-    np.random.seed(seed)
-    env = make_env(args["env"])
-    return config, args, env, env.net()
-
-
 @pytest.fixture(scope="module")
 def geister():
-    from benchmark import traffic
-
-    config, args, env, module = _geister(
-        {"batch_size": 3, "burn_in_steps": 4, "forward_steps": 12})
-    assert isinstance(module, HybridNet) and module.with_return and module.pattern == "CECECE"
-    params = traffic.seeded_params(module, env, 1)
-    batch = traffic.random_play_batches(env, module, args, 1, 4)[0]
-    assert 0.2 < float(np.mean(batch["observation_mask"])) < 0.8
-    return config, args, module, params, batch
+    return nets._geister_windows(ZAYA, batch_size=3, burn_in_steps=4, forward_steps=12)
 
 
 def test_the_scan_path_and_the_window_path_are_the_reference_on_geister(geister):
     """``forward_prediction`` through ``env.net()``: the whole-window call and
     the train step's scan over step mode, burn-in 4, against ``forward_rows``."""
-    config, args, module, params, batch = geister
-    with jax.default_matmul_precision("highest"):
-        window = forward_prediction(module, params, batch, dict(args, seq_forward=True))
-        scan = forward_prediction(module, params, batch, dict(args, seq_forward=False))
-        want = REFERENCE.forward_rows(params, batch, config, 4, choices=window["choices"])
-    observed = batch["observation_mask"][:, 4:]
-    legal = (batch["action_mask"][:, 4:] == 0) & (batch["turn_mask"][:, 4:] > 0)
-    for head in HEADS:
-        keep = legal if head == "policy" else observed > 0
-        for got in (window, scan):
-            diff = np.where(keep, np.asarray(got[head]) - np.asarray(want[head]) * (
-                1 if head == "policy" else observed), 0.0)
-            assert float(np.abs(diff).max()) < 1e-4, head
+    nets._both_paths_on_geister(ZAYA, geister)
 
 
 def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geister, tmp_path):
@@ -536,35 +276,18 @@ def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geist
     experts move, the step counts its gates; the state saved and loaded is
     the state; the layout says what the family added; and the step's cache
     key knows the new scope."""
-    config, args, module, params, batch = geister
-    args = dict(args, seq_forward=True, remat="block")
-    trace.configure({"enabled": True, "path": str(tmp_path / "trace.jsonl")})
-    try:
-        ctx = TrainContext(module, args, make_mesh({"dp": 1}))
-        device_batch = ctx.put_batch(batch)
-        before = jax.device_get(params)
-        state, metrics = ctx.train_step(ctx.init_state(params), device_batch, 1e-3)
-        metrics, after = jax.device_get(metrics), jax.device_get(state["params"])
-    finally:
-        trace.shutdown()
-    assert np.isfinite(metrics["total"]) and metrics["sentinel_bad"] == 0
+    _, _, module, params, _ = geister
+    # the process's record of attention paths: what a file before this one chose is not this step's
+    metrics, moved, records = nets._update_and_checkpoint(
+        geister, tmp_path, clear=(attention_core.PATHS,))
     assert metrics["counter_rows_held"] > 0 and metrics["counter_expert_passes"] == 0
     assert 1 / 8 < metrics["counter_router_gate_mean"] < 1
-    moved = lambda *path: not np.allclose(  # noqa: E731
-        np.asarray(_at(after, path)), np.asarray(_at(before, path)))
     for path in (("layer0", "mixer", "conv0_kernel"), ("layer0", "mixer", "conv1_kernel"),
                  ("layer2", "mixer", "temp"), ("layer2", "mixer", "v_prev", "kernel"),
                  ("layer1", "mixer", "router_down"), ("layer3", "mixer", "carry_scale"),
                  ("layer5", "mixer", "router_out"), ("layer1", "mixer", "w1")):
         assert moved(*path), path
     assert not moved("layer1", "mixer", "score_bias")
-
-    checkpoint.save_train_state(str(tmp_path / "state.ckpt"), state)
-    loaded = checkpoint.load_train_state(str(tmp_path / "state.ckpt"), jax.device_get(state))
-    for a, b in zip(jax.tree.leaves(loaded), jax.tree.leaves(jax.device_get(state))):
-        np.testing.assert_array_equal(a, b)
-
-    records = trace.read_trace(str(tmp_path / "trace.jsonl"))
     layout, = [r["attrs"] for r in records if r["name"] == "model.layout"]
     trunk = sum(x.size for k, v in params.items() if k.startswith("layer")
                 for x in jax.tree.leaves(v))
@@ -579,12 +302,6 @@ def test_a_train_step_moves_every_new_part_and_a_checkpoint_brings_it_back(geist
     assert module.program_scopes() == (CCA_SCOPE,)
     assert _module(pattern="M*E").program_scopes() == ()
     assert scoped_program_options("opt_update", CCA_SCOPE) != scoped_program_options("opt_update")
-
-
-def _at(tree, path):
-    for key in path:
-        tree = tree[key]
-    return tree
 
 
 def test_what_the_net_refuses_it_refuses_by_name():
@@ -618,21 +335,24 @@ def test_the_published_layer_holds_what_the_issue_counted():
     assert state["prev_v"].shape == (1, 128)
 
 
-def test_a_weight_sum_wider_than_its_scope_is_taken_in_column_tiles():
+def test_a_weight_sum_wider_than_its_scope_is_taken_in_column_tiles(monkeypatch):
     """``ops/grouped_product.py`` ``_weight_sums`` at the cell's fused
     gate-and-up shape, (2048, 4096): one tile's float32 sum and output would
-    be 64 MB (84 with a carried sum's tile), so the columns go in two tiles (the interpreter here; the
-    described-v5e compile is tests/test_chip_compile.py's): every group's sum
-    is the plain one, a group without a block zeros."""
-    from handyrl_tpu.ops import grouped_product
-
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (3 * 16, 2048), jnp.bfloat16)
-    dy = jax.random.normal(jax.random.fold_in(key, 1), (3 * 16, 4096), jnp.bfloat16)
-    owner = jnp.array([0, 2, 2], jnp.int32)
-    got = grouped_product._weight_sums(x, dy, owner, 4, jnp.float32, True)
+    be 64 MB (84 with a carried sum's tile), so the columns go in two tiles:
+    the rule held to the published shapes by arithmetic (the described-v5e
+    compile of them is tests/test_chip_compile.py's), and the kernel run (the
+    interpreter) at a small shape under a scope cut down to it, where the same
+    halving gives two column tiles: every group's sum is the plain one, a
+    group without a block zeros."""
     assert 8 * 2048 * 4096 > grouped_product._SUMS_BYTES >= 8 * 4096 * 1536
     assert grouped_product._SUMS_BYTES >= 10 * 2688 * 1856      # with a carried sum's tile, whole too
+    k, n = 64, 512
+    monkeypatch.setattr(grouped_product, "_SUMS_BYTES", 8 * k * n // 2)
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (3 * 16, k), jnp.bfloat16)
+    dy = jax.random.normal(jax.random.fold_in(key, 1), (3 * 16, n), jnp.bfloat16)
+    owner = jnp.array([0, 2, 2], jnp.int32)
+    got = grouped_product._weight_sums(x, dy, owner, 4, jnp.float32, True)
     blocks = lambda a: a.astype(jnp.float32).reshape(3, 16, -1)  # noqa: E731
     each = jnp.einsum("brk,brn->bkn", blocks(x), blocks(dy))
     want = jnp.stack([each[0], jnp.zeros_like(each[0]), each[1] + each[2], jnp.zeros_like(each[0])])
