@@ -66,7 +66,9 @@ it has two modes over one parameter set:
   where it lies, read as zeros where ``begun`` (``rows_in_place``; the
   streaming rollout of a game in which one player a lane acts);
 * whole-window mode — ``seq=True``: a (rows, T) window at once, the scan in
-  its chunked matmul form.  The scan path's rules are kept exactly: an
+  its chunked matmul form (a bfloat16 part's scan, skip, gate and norm as
+  ``ops/ssd.py``'s one kernel where ``window_fits``; float32 keeps the lines
+  to the bit).  The scan path's rules are kept exactly: an
   unobserved step (``key_mask`` 0) leaves every state as it was, which the
   window form gets by moving each row's observed steps to the front
   (``_compact``), running every mixer on that prefix, and moving the
@@ -118,7 +120,7 @@ import numpy as np
 from ..ops import attention_core, latent_core
 from ..ops.routed_experts import choose, held_mix, open_sinks, reads_in_place
 from ..ops.rows import COMMIT_SCOPE, acting_rows, begin_rows, put_rows
-from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows
+from ..ops.ssd import ssd_chunked, ssd_step, ssd_step_rows, ssd_window, window_fits
 from .transformer import NEG_INF, _flatten_obs
 
 # Mamba-2, routed experts, attention, gated MLP, compressed convolutional attention, latent attention,
@@ -295,6 +297,8 @@ class Mamba2Mixer(nn.Module):
         if valid is not None:
             dt = dt * valid[..., None]
         A = -jnp.exp(a_log.astype(jnp.float32))
+        # a bfloat16 part's scan, skip, gate and norm as one kernel; float32 keeps the lines
+        whole = not step and window_fits(x.dtype, length, H, P, G, S)
         with jax.named_scope("ssd"):
             if step:
                 one = (x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], state["ssm"])
@@ -303,11 +307,14 @@ class Mamba2Mixer(nn.Module):
                 else:   # both leaves' acting rows written where they lie, by one kernel
                     y, ssm, new_tail = ssd_step_rows(*one, *rows, (state["conv"], new_tail))
                 y = y[:, None]
+            elif whole:
+                y, ssm = ssd_window(x, dt, A, B, C, z, skip, norm_scale, state["ssm"], self.eps)
             else:
                 y, ssm = ssd_chunked(x, dt, A, B, C, state["ssm"], self.chunk)
-        y = y.astype(jnp.float32) + skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-        y = y.reshape(n, length, inner) * jax.nn.silu(z.astype(jnp.float32))
-        y = _rms(y, norm_scale, self.eps, groups=G).astype(u.dtype)
+        if not whole:
+            y = y.astype(jnp.float32) + skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+            y = y.reshape(n, length, inner) * jax.nn.silu(z.astype(jnp.float32))
+            y = _rms(y, norm_scale, self.eps, groups=G).astype(u.dtype)
         out = _dense(self.d_model, "out_proj", kept)(y)
         return (out[:, 0] if step else out), {"ssm": ssm, "conv": new_tail.astype(jnp.float32)}
 

@@ -8,7 +8,11 @@ rotated key part all heads share; bfloat16 parts of ``ROWS_MIN`` queries or
 more a row at widths of whole 128-lane tiles: float32, the burn-in part and
 step mode keep ``LatentAttention``'s einsum lines).  Both build their mask
 with ``attention_core._allowed`` and call Pallas through
-``grouped_product._call``."""
+``grouped_product._call``.  Beside them ``ssd.ssd_window``, a ``M`` layer's
+window core (the chunked scan, the skip, the gate and the group norm) as one
+kernel a (row, group), chosen by ``ssd.window_fits`` for bfloat16 parts of 16
+steps or more (a ``model.ssd_window_path`` event says which): float32, the
+burn-in part and step mode keep ``ssd_chunked`` and ``Mamba2Mixer``'s lines."""
 
 from .targets import compute_target
 from .losses import compute_loss_from_outputs

@@ -14,6 +14,12 @@ state ``S`` (P x S) carried in float32.
   "state-space duality"): inside a chunk of ``chunk`` steps the output is a
   decay-masked ``(C B^T) x`` product, and one state per chunk is passed on.
   The decays are computed in float32 whatever the operands' dtype.
+* ``ssd_window`` — a bfloat16 window part's whole core as one Pallas program
+  a (row, group): ``ssd_chunked``'s products on a part that is one chunk,
+  then the mixer's skip, gate and group norm, with the decay and score tiles
+  held in VMEM; ``window_fits`` chooses it from dtype and shape alone, and
+  every other part (float32 first of all) keeps ``ssd_chunked`` and the
+  mixer's own lines.
 
 A step with ``dt == 0`` is the identity on the state (``exp(0) = 1`` and
 nothing is added), which is how callers skip padding and unobserved steps.
@@ -271,3 +277,366 @@ def _ssd_chunked(x, dt, A, B, C, state, chunk: int):
     since = jnp.moveaxis(jnp.exp(cum), 3, 2).reshape(n, nc, q, g, r, 1)
     y = (y + since * carried).reshape(n, nc * q, h, p)[:, :length]
     return y.astype(x.dtype), state.reshape(n, h, p, s)
+
+
+# -- a bfloat16 window part's whole core, one program a (row, group) ----------
+
+WINDOW_MIN = 16         # steps a part: a bfloat16 tile's rows; a shorter part keeps the lines
+HEADS_UNROLLED = 16     # a group's heads are unrolled in the kernels' bodies: at most so many
+LANES = 128
+# what a block's operands, results, scratch and tiles may take of VMEM (the
+# kernels ask for grouped_product's 64 MB scope): the cell's 96 steps of 512
+# channels take 5.4 MB, its judge's 184 steps 9.3 MB, and 312 steps are the most
+VMEM_WINDOW = 16 << 20
+# the static choices made in this process: (dtype, L, H, head, G, S) -> {"path",
+# "why", ...}, what ``TrainContext`` writes out as ``model.ssd_window_path``
+WINDOW_PATHS: Dict[Tuple, Dict] = {}
+
+
+def _window_bytes(length: int, channels: int, state_size: int, heads: int) -> int:
+    """VMEM a (row, group) block takes in the backward program, the larger
+    of the two: per (L, channels) element five bfloat16 blocks double-buffered,
+    a float32 and two bfloat16 scratch and eight float32 temporaries of the
+    epilogue; B, C and their cotangents; the state, its cotangent and the new
+    state's; eight (L, L) float32 tiles; dt and its sums both ways, a head a
+    lane (padded to 128) and a head a row."""
+    return (60 * length * channels + 16 * length * state_size + 28 * channels * state_size
+            + 32 * length * length + 32 * length * (LANES + max(heads, 8)))
+
+
+def window_fits(dtype, length: int, heads: int, head_dim: int, groups: int,
+                state_size: int) -> bool:
+    """Whether a window part of these operands runs ``ssd_window``'s kernel:
+    bfloat16 (float32 keeps ``ssd_chunked`` and the mixer's lines to the
+    bit), ``WINDOW_MIN`` steps or more in whole tiles of 8 rows, a group's
+    channels and its state whole 128-lane tiles, at most ``HEADS_UNROLLED``
+    heads a group and a block under ``VMEM_WINDOW``.  From dtype and shape
+    alone; the choice and its reason are kept in ``WINDOW_PATHS``."""
+    name = jnp.dtype(dtype).name
+    served = heads // groups
+    channels = served * head_dim
+    blocks = _window_bytes(length, channels, state_size, served)
+    refused = (
+        (name != "bfloat16", "the part is %s, not bfloat16" % name),
+        (length < WINDOW_MIN, "%d steps a part, under %d" % (length, WINDOW_MIN)),
+        (length % 8, "%d steps are not whole tiles of 8 rows" % length),
+        (channels % LANES or state_size % LANES, "a group's %d channels and its state of %d are "
+         "not whole tiles of %d lanes" % (channels, state_size, LANES)),
+        (served > HEADS_UNROLLED,
+         "a group serves %d heads, over the %d a program unrolls" % (served, HEADS_UNROLLED)),
+        (blocks > VMEM_WINDOW,
+         "a block takes %d bytes of VMEM, over %d" % (blocks, VMEM_WINDOW)),
+    )
+    why = next((text for failed, text in refused if failed), "")
+    WINDOW_PATHS[(name, length, heads, head_dim, groups, state_size)] = {
+        "path": "lines" if why else "kernel",
+        "why": why or "bfloat16 part of %d steps, a group's %d heads of %d x %d in VMEM" % (
+            length, served, head_dim, state_size),
+        "length": length, "heads": heads, "head_dim": head_dim, "groups": groups,
+        "state_size": state_size, "dtype": name}
+    return not why
+
+
+def _contract(a, b, a_axis: int, b_axis: int):
+    """``a`` and ``b`` contracted over one axis each, accumulated in float32."""
+    return jax.lax.dot_general(a, b, (((a_axis,), (b_axis,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _down(one, rows: int):
+    """A (1, 1) value down ``rows`` rows: Mosaic broadcasts one way at a
+    time, so what meets a tile goes down the rows first."""
+    return jnp.broadcast_to(one, (rows, 1))
+
+
+def _head(h: int, head_dim: int, scores, cumc_ref, dtr_ref, cumr_ref):
+    """Head ``h`` of a block: (its columns, cum down the rows (L, 1), dt
+    along the lanes (1, L), its causal decay (L, L): ``exp(cum_i - cum_j)``
+    where ``j <= i`` (a difference that is never positive, whatever the
+    part's length) and 0 elsewhere, and the float32 weights ``decay x scores
+    x dt_j``)."""
+    cum_i, dt_j = cumc_ref[:, h:h + 1], dtr_ref[h:h + 1, :]
+    i = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    decay = jnp.exp(jnp.where(j <= i, cum_i - cumr_ref[h:h + 1, :], -jnp.inf))
+    return slice(h * head_dim, (h + 1) * head_dim), cum_i, dt_j, decay, decay * scores * dt_j
+
+
+def _scan_block(x_ref, b_ref, c_ref, cumc_ref, dtr_ref, cumr_ref, s_ref, y_ref, head_dim: int):
+    """The scan's output of one (row, group) block, ``_ssd_chunked``'s
+    products on a part that is one chunk, their operands rounded where its
+    lines round them: head by head into ``y_ref`` (L, channels) float32.  ->
+    (scores ``C B^T`` (L, L), carried ``C . state`` (L, channels)), which the
+    group's heads share."""
+    B, C = b_ref[...], c_ref[...]
+    scores = _contract(C, B, 1, 1)
+    carried = _contract(C, s_ref[...].astype(C.dtype), 1, 1)
+    for h in range(dtr_ref.shape[0]):
+        cols, cum_i, _, _, weights = _head(h, head_dim, scores, cumc_ref, dtr_ref, cumr_ref)
+        x = x_ref[:, cols]
+        y_ref[:, cols] = _contract(weights.astype(x.dtype), x, 1, 0) \
+            + jnp.exp(cum_i) * carried[:, cols]
+    return scores, carried
+
+
+def _to_end(h: int, dtc_ref, cumc_ref):
+    """Head ``h``: (cum at the last step (1, 1), whose ``exp`` is what
+    survives the part of the state handed in, ``exp(cum_last - cum_j)``
+    (L, 1), and that times dt_j: what step j adds to the state handed on)."""
+    length = cumc_ref.shape[0]
+    last = cumc_ref[length - 1:length, h:h + 1]
+    end = jnp.exp(_down(last, length) - cumc_ref[:, h:h + 1])
+    return last, end, dtc_ref[:, h:h + 1] * end
+
+
+def _window_state_kernel(x_ref, b_ref, dtc_ref, cumc_ref, s_ref, new_ref, *, head_dim):
+    """The state a (row, group) block hands on, a program of its own so that
+    a caller who drops the state (a train step's forward part) runs none:
+    ``exp(cum_last) state + sum_j to_end_j x_j B_j^T``, head by head (rows
+    of the state are the columns of x)."""
+    B = b_ref[...]
+    for h in range(dtc_ref.shape[1]):
+        cols = slice(h * head_dim, (h + 1) * head_dim)
+        last, _, to_end = _to_end(h, dtc_ref, cumc_ref)
+        x = x_ref[:, cols]
+        scaled = (x.astype(jnp.float32) * to_end).astype(x.dtype)
+        new_ref[cols, :] = jnp.exp(_down(last, head_dim)) * s_ref[cols, :] \
+            + _contract(scaled, B, 0, 0)
+
+
+def _gated(y_ref, x_ref, z_ref, skip_ref, eps: float):
+    """(x, z, the gate's sigmoid, skipped ``y + D x``, gated ``skipped x
+    silu(z)``, the group's ``1 / rms`` (L, 1)), all float32."""
+    x, z = x_ref[...].astype(jnp.float32), z_ref[...].astype(jnp.float32)
+    opened = jax.nn.sigmoid(z)
+    skipped = y_ref[...] + skip_ref[...] * x
+    gated = skipped * (z * opened)
+    return x, z, opened, skipped, gated, jax.lax.rsqrt(
+        jnp.mean(gated * gated, axis=1, keepdims=True) + eps)
+
+
+def _window_forward_kernel(x_ref, z_ref, b_ref, c_ref, dtc_ref, cumc_ref, dtr_ref, cumr_ref,
+                           skip_ref, scale_ref, s_ref, o_ref, y_ref, *, head_dim, eps):
+    del dtc_ref
+    _scan_block(x_ref, b_ref, c_ref, cumc_ref, dtr_ref, cumr_ref, s_ref, y_ref, head_dim)
+    *_, gated, inverse = _gated(y_ref, x_ref, z_ref, skip_ref, eps)
+    o_ref[...] = (gated * inverse * scale_ref[...]).astype(o_ref.dtype)
+
+
+def _window_backward_kernel(x_ref, z_ref, b_ref, c_ref, dtc_ref, cumc_ref, dtr_ref, cumr_ref,
+                            skip_ref, scale_ref, s_ref, do_ref, *refs, head_dim, eps):
+    """The block's decays and pre-norm output again, then every cotangent:
+    of dt and of its cumulative sum down the rows (what sums over a row's
+    lanes) and along the lanes (what sums over a column), which the wrapper
+    adds; of ``skip`` and ``norm_scale`` this program's share.  ``refs``
+    begin with the new state's cotangent where the caller used that state:
+    without one (a train step's forward part) its terms are not computed."""
+    dnew_ref = refs[0] if len(refs) == 15 else None
+    (dx_ref, dz_ref, db_ref, dc_ref, ds_ref, ddtc_ref, dcumc_ref, ddtr_ref, dcumr_ref, dskip_ref,
+     dscale_ref, y_ref, dcar_ref, xs_ref) = refs[-14:]
+    f32, narrow = jnp.float32, x_ref.dtype
+    length = x_ref.shape[0]
+    B, C = b_ref[...], c_ref[...]
+    scores, carried = _scan_block(x_ref, b_ref, c_ref, cumc_ref, dtr_ref, cumr_ref, s_ref, y_ref,
+                                  head_dim)
+    x, z, opened, skipped, gated, inverse = _gated(y_ref, x_ref, z_ref, skip_ref, eps)
+    # the norm, the gate, the skip
+    d_out = do_ref[...].astype(f32)
+    dscale_ref[...] = jnp.sum(d_out * gated * inverse, axis=0, keepdims=True)
+    scaled = d_out * scale_ref[...]
+    d_gated = inverse * (scaled - gated * (inverse * inverse) * jnp.mean(
+        scaled * gated, axis=1, keepdims=True))
+    dz_ref[...] = (d_gated * skipped * opened * (1.0 + z * (1.0 - opened))).astype(dz_ref.dtype)
+    y_ref[...] = d_gated * (z * opened)         # the scan's output's cotangent, in its place
+    dskip_ref[...] = jnp.sum(y_ref[...] * x, axis=0, keepdims=True)
+    if dnew_ref is not None:
+        d_new = dnew_ref[...].astype(narrow)
+        d_scaled = _contract(B, d_new, 1, 1)    # (L, channels): of ``scaled``, every head's
+    d_scores = jnp.zeros_like(scores)
+    last_row = jax.lax.broadcasted_iota(jnp.int32, (length, 1), 0) == length - 1
+    ddt_i, dcum_i = [], []
+    for h in range(dtr_ref.shape[0]):
+        cols, cum_i, dt_j, decay, weights = _head(h, head_dim, scores, cumc_ref, dtr_ref, cumr_ref)
+        x_h, xf_h = x_ref[:, cols], x[:, cols]
+        dy = y_ref[:, cols]
+        dy_n = dy.astype(narrow)
+        # across the part's start: y += exp(cum_i) C . state
+        since = jnp.exp(cum_i)
+        dcum = jnp.sum(dy * since * carried[:, cols], axis=1, keepdims=True)
+        dcar_ref[:, cols] = (since * dy).astype(narrow)
+        dx = skip_ref[:, cols] * dy
+        if dnew_ref is None:
+            ddt_i.append(jnp.zeros_like(dcum))
+        else:   # the state handed on: exp(last) state + sum_j to_end_j x_j B_j^T
+            last, end, to_end = _to_end(h, dtc_ref, cumc_ref)
+            xs_ref[:, cols] = (xf_h * to_end).astype(narrow)
+            d_to_end = jnp.sum(d_scaled[:, cols] * xf_h, axis=1, keepdims=True)
+            moved = d_to_end * to_end
+            ds_ref[cols, :] = jnp.exp(_down(last, head_dim)) * dnew_ref[cols, :]
+            d_last = jnp.sum(moved, keepdims=True) + jnp.exp(last) * jnp.sum(
+                dnew_ref[cols, :] * s_ref[cols, :], keepdims=True)
+            dcum = dcum - moved + jnp.where(last_row, d_last, 0.0)
+            ddt_i.append(d_to_end * end)
+            dx = dx + d_scaled[:, cols] * to_end
+        # inside the part: y += (decay x scores x dt_j) x
+        masked = _contract(dy_n, x_h, 1, 1) * decay
+        d_scores = d_scores + masked * dt_j
+        masked = masked * scores
+        ddt_j = jnp.sum(masked, axis=0, keepdims=True)
+        ddtr_ref[h:h + 1, :] = ddt_j
+        dcumr_ref[h:h + 1, :] = -(dt_j * ddt_j)
+        dcum_i.append(dcum + jnp.sum(masked * dt_j, axis=1, keepdims=True))
+        dx_ref[:, cols] = (dx + _contract(weights.astype(narrow), dy_n, 0, 0)).astype(dx_ref.dtype)
+    ddtc_ref[...] = jnp.concatenate(ddt_i, axis=1)
+    dcumc_ref[...] = jnp.concatenate(dcum_i, axis=1)
+    d_scores, d_carried = d_scores.astype(narrow), dcar_ref[...]
+    dc_ref[...] = (_contract(d_scores, B, 1, 0) + _contract(
+        d_carried, s_ref[...].astype(narrow), 1, 0)).astype(dc_ref.dtype)
+    if dnew_ref is None:
+        db_ref[...] = _contract(d_scores, C, 0, 0).astype(db_ref.dtype)
+        ds_ref[...] = _contract(d_carried, C, 0, 0)
+    else:
+        db_ref[...] = (_contract(d_scores, C, 0, 0)
+                       + _contract(xs_ref[...], d_new, 1, 0)).astype(db_ref.dtype)
+        ds_ref[...] += _contract(d_carried, C, 0, 0)
+
+
+def _window_call(kernel, operands, outs, scratch, groups: int, interpret: bool):
+    """One program a (row, group) over ``operands`` -> arrays of the ``outs``
+    shapes.  An array (n, groups, ...) is blocked by its two leading axes;
+    (n, rows, columns) by row and, its columns, by group; (1, columns) is
+    every row's, its columns by group."""
+    from jax.experimental import pallas as pl
+
+    from .grouped_product import _call
+
+    def spec(a):
+        if len(a.shape) == 4:
+            return pl.BlockSpec((None, None) + a.shape[2:], lambda n, g: (n, g, 0, 0))
+        if len(a.shape) == 2:
+            return pl.BlockSpec((1, a.shape[1] // groups), lambda n, g: (0, g))
+        return pl.BlockSpec((None, a.shape[1], a.shape[2] // groups), lambda n, g: (n, 0, g))
+
+    return _call(kernel, (), (operands[0].shape[0], groups), [spec(a) for a in operands],
+                 [spec(a) for a in outs], outs, scratch, interpret, *operands)
+
+
+def _by_group(a, groups: int, along_lanes: bool = False):
+    """(n, L, H) -> (n, G, L, H / G), a group's heads down the lanes of its
+    block; ``along_lanes``: (n, G, H / G, L), the steps along the lanes."""
+    n, length, heads = a.shape
+    a = a.reshape(n, length, groups, heads // groups)
+    return a.transpose(0, 2, 3, 1) if along_lanes else a.transpose(0, 2, 1, 3)
+
+
+def _window_operands(x, dt, cum, B, C, z, skip, scale, state, groups: int):
+    """The kernels' common operands, in their order: a head's dt and
+    cumulative sum are read down the rows (against a step's x) and along the
+    lanes (against a column of the decays), so both lie both ways; the
+    skip is said once a channel."""
+    n, heads, channels = x.shape[0], dt.shape[2], x.shape[2]
+    return (x, z, B, C, _by_group(dt, groups), _by_group(cum, groups),
+            _by_group(dt, groups, True), _by_group(cum, groups, True),
+            jnp.repeat(skip, channels // heads)[None], scale[None],
+            state.reshape(n, groups, channels // groups, state.shape[-1]))
+
+
+def _window_forward(x, dt, cum, B, C, z, skip, scale, state, groups, eps, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    length, channels = x.shape[1:]
+    head_dim = channels // dt.shape[2]
+    operands = _window_operands(x, dt, cum, B, C, z, skip, scale, state, groups)
+    (out,) = _window_call(
+        functools.partial(_window_forward_kernel, head_dim=head_dim, eps=eps),
+        operands, [jax.ShapeDtypeStruct(x.shape, x.dtype)],
+        [pltpu.VMEM((length, channels // groups), jnp.float32)], groups, interpret)
+    # the state handed on, by a program a caller who drops it never runs
+    (new,) = _window_call(
+        functools.partial(_window_state_kernel, head_dim=head_dim),
+        (x, B, operands[4], operands[5], operands[-1]),
+        [jax.ShapeDtypeStruct(operands[-1].shape, jnp.float32)], [], groups, interpret)
+    return out, new.reshape(state.shape)
+
+
+def _window_backward(x, dt, cum, B, C, z, skip, scale, state, d_out, d_new, groups, eps,
+                     interpret):
+    """``d_new`` None: the caller dropped the new state."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, length, channels = x.shape
+    heads, f32 = dt.shape[2], jnp.float32
+    operands = _window_operands(x, dt, cum, B, C, z, skip, scale, state, groups)
+    like = lambda a, dtype=None: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype)  # noqa: E731
+    by_rows, by_lanes = operands[4], operands[6]
+    block = (length, channels // groups)
+    handed_on = () if d_new is None else (d_new.reshape(operands[-1].shape),)
+    dx, dz, dB, dC, d_state, ddt_i, dcum_i, ddt_j, dcum_j, d_skip, d_scale = _window_call(
+        functools.partial(_window_backward_kernel, head_dim=channels // heads, eps=eps),
+        operands + (d_out,) + handed_on,
+        [like(x), like(z), like(B), like(C), like(operands[-1]), like(by_rows), like(by_rows),
+         like(by_lanes), like(by_lanes), jax.ShapeDtypeStruct((n, 1, channels), f32),
+         jax.ShapeDtypeStruct((n, 1, channels), f32)],
+        [pltpu.VMEM(block, f32), pltpu.VMEM(block, x.dtype), pltpu.VMEM(block, x.dtype)],
+        groups, interpret)
+    # what a head's rows and its lanes each summed, added as (n, L, H)
+    whole = lambda i, j: (i.transpose(0, 2, 1, 3) + j.transpose(0, 3, 1, 2)).reshape(dt.shape)  # noqa: E731
+    return (dx, whole(ddt_i, ddt_j), whole(dcum_i, dcum_j), dB, dC, dz,
+            d_skip.reshape(n, heads, -1).sum(axis=(0, 2)), d_scale.sum(axis=(0, 1)),
+            d_state.reshape(state.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _window(x, dt, cum, B, C, z, skip, scale, state, groups, eps, interpret):
+    return _window_forward(x, dt, cum, B, C, z, skip, scale, state, groups, eps, interpret)
+
+
+def _window_fwd(x, dt, cum, B, C, z, skip, scale, state, groups, eps, interpret):
+    kept = tuple(a.value for a in (x, dt, cum, B, C, z, skip, scale, state))
+    return _window_forward(*kept, groups, eps, interpret), kept
+
+
+def _window_bwd(groups, eps, interpret, kept, cotangents):
+    zero = jax.custom_derivatives.SymbolicZero
+    d_out, d_new = cotangents
+    if isinstance(d_out, zero):
+        d_out = jnp.zeros(d_out.shape, d_out.dtype)
+    return _window_backward(*kept, d_out, None if isinstance(d_new, zero) else d_new, groups, eps,
+                            interpret)
+
+
+_window.defvjp(_window_fwd, _window_bwd, symbolic_zeros=True)
+
+
+def _running_sum(a):
+    """(N, L, H) float32 summed along its steps up to each, as a product
+    with a triangle of ones at full precision: XLA's ``cumsum`` along a
+    middle axis took a millisecond a call at the cell's (64, 96, 64), a
+    quarter of the kernels' own time."""
+    lower = jnp.tril(jnp.ones((a.shape[1],) * 2, a.dtype))
+    return jnp.einsum("ij,njh->nih", lower, a, precision=jax.lax.Precision.HIGHEST)
+
+
+def ssd_window(x, dt, A, B, C, z, skip, scale, state, eps: float,
+               interpret: Optional[bool] = None):
+    """A bfloat16 window part's core where ``window_fits``: x (N, L, H, P),
+    dt (N, L, H) float32, A (H,) float32, B and C (N, L, G, S), the gate z
+    (N, L, H x P), the mixer's ``D`` (H,) and ``norm_scale`` (H x P,), state
+    (N, H, P, S) float32 -> (the mixer's normed output (N, L, H x P) in x's
+    dtype, the state after step L - 1): ``ssd_chunked`` on a part that is
+    one chunk, ``+ D x``, ``x silu(z)`` and the RMS norm over a group's
+    channels, in one Pallas program a (row, group) (the interpreter off the
+    TPU) that keeps the (L, L) scores and decays and the float32 epilogue in
+    VMEM.  Products accumulate in float32 and round their operands where the
+    lines do; the backward pass is a second program that computes the decays
+    and the pre-norm output again from the same operands."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n, length, heads, head_dim = x.shape
+    flat = lambda a: a.reshape(n, length, -1)  # noqa: E731
+    f32 = jnp.float32
+    out, new = _window(
+        flat(x), dt, _running_sum(dt * A), flat(B), flat(C), z, skip.astype(f32),
+        scale.astype(f32), state.reshape(n, heads * head_dim, -1), B.shape[2], float(eps),
+        bool(interpret))
+    return out, new.reshape(state.shape)

@@ -44,7 +44,7 @@ import optax
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..ops import attention_core, compute_loss_from_outputs
+from ..ops import attention_core, compute_loss_from_outputs, ssd
 from ..utils import tree_map
 from ..utils.compile_cache import scoped_program_options
 from ..utils.trace import (
@@ -553,7 +553,7 @@ class TrainContext:
         # program and does not flip between two
         self._packs = takes_packed_order(module, self.args)
         self._packed_bounds: Dict[str, int] = {}
-        # the attention paths (attention_core.PATHS) already written out
+        # the kernel choices (attention_core.PATHS, ssd.WINDOW_PATHS) already written out
         self._attention_paths: set = set()
         # scopes a net brings that older programs of its class lack (a
         # ``HybridNet`` with ``C`` layers: ``cca_mix``): part of the step's cache key
@@ -873,13 +873,19 @@ class TrainContext:
         """One ``model.attention_path`` event for each static choice between
         the whole-row attention kernel and the einsum lines that a trace of
         the step has made (``ops/attention_core.py`` ``fits``: per window
-        part's operands) and no event of this context has said yet, once a
+        part's operands), and one ``model.ssd_window_path`` for each between
+        the Mamba-2 window kernel and the scan's lines (``ops/ssd.py``
+        ``window_fits``), that no event of this context has said yet, once a
         tracer is on to take it."""
-        if len(self._attention_paths) == len(attention_core.PATHS) or not trace_enabled():
+        made = len(attention_core.PATHS) + len(ssd.WINDOW_PATHS)
+        if len(self._attention_paths) == made or not trace_enabled():
             return
-        for key in set(attention_core.PATHS) - self._attention_paths:
-            trace_event("model.attention_path", 0.0, plane="learner", **attention_core.PATHS[key])
-            self._attention_paths.add(key)
+        chosen = {("model.attention_path", key): made for key, made in attention_core.PATHS.items()}
+        chosen.update((("model.ssd_window_path", key), made)
+                      for key, made in ssd.WINDOW_PATHS.items())
+        for said in set(chosen) - self._attention_paths:
+            trace_event(said[0], 0.0, plane="learner", **chosen[said])
+            self._attention_paths.add(said)
 
     def put_batches(self, host_batches):
         """Stack k host batches -> one (k, B, ...) device tree, B sharded

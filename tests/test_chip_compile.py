@@ -458,3 +458,55 @@ def test_ssd_step_rows_compiles_for_v5e_and_writes_the_buffer_it_read(v5e, playe
     assert "tpu_custom_call" in compiled.as_text()
     memory, state = compiled.memory_analysis(), 4 * n * players * (h * p * s + 3 * width)
     assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < state // 8
+
+
+# -- a bfloat16 window part's Mamba-2 core (ops/ssd.py ssd_window) -------------
+
+@pytest.mark.parametrize("part,length,handed_on", [
+    ("burn_in", 8, True), ("packed", 96, False), ("unpacked", 184, True), ("longest", 312, True)])
+def test_the_mamba_window_core_compiles_for_v5e_under_the_scope_that_times_it(v5e, monkeypatch,
+                                                                              part, length,
+                                                                              handed_on):
+    """``Mamba2Mixer``'s window mode and its gradient at
+    ``nemotron_twotower_30b_a3b``'s widths (64 heads of 64 in 8 groups, state
+    128, on a 2,688-wide stream) and the cell's parts: 64 rows of 96 packed
+    steps and the 184 of the unpacked window ``judge_forward`` runs go
+    through ``ops/ssd.py``'s kernels, each a Mosaic call under the ``ssd``
+    scope the benchmark times, and no (rows, heads, steps, steps) decay or
+    score tile is left in the program.  A caller that drops the part's last
+    state, as the train step does, runs the forward and the backward kernel
+    and no other; one that uses it (``handed_on``) also the state's own.
+    The 8 burn-in steps keep the lines and hold no kernel; 312 steps are
+    the longest part ``VMEM_WINDOW`` lets through, and Mosaic takes them."""
+    from benchmark import trace_reduce
+    from handyrl_tpu.models.hybrid import Mamba2Mixer
+    from handyrl_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    module = Mamba2Mixer(2688, 64, 64, 8, 128, 4, 128, 1e-5, 1e-3, 0.1, 1e-4)
+    aval = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)  # noqa: E731
+    h, valid = aval((64, length, 2688)), aval((64, length), jnp.bool_)
+    state = {"ssm": aval((64, 64, 64, 128), jnp.float32), "conv": aval((64, 3, 6144), jnp.float32)}
+    zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+    params = jax.tree.map(lambda x: aval(x.shape), jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros(h.shape, h.dtype), zeros, jnp.ones(valid.shape, bool))))
+
+    def loss(params, h, state, valid):
+        out, new = module.apply(params, h, state, valid)
+        return (out.astype(jnp.float32) ** 2).sum() + (
+            (new["ssm"] ** 2).sum() if handed_on else 0.0)
+
+    ssd.WINDOW_PATHS.clear()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, h, state, valid).compile().as_text()
+    (chosen,) = [made for key, made in ssd.WINDOW_PATHS.items() if key[0] == "bfloat16"]
+    ssd.WINDOW_PATHS.clear()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    if part == "burn_in":
+        assert chosen["path"] == "lines" and not calls
+        return
+    assert chosen["path"] == "kernel" and len(calls) == 2 + handed_on, (chosen, len(calls))
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert all(trace_reduce.scopes_of(n, ["ssd"]) == ["ssd"] for n in names), names
+    assert not re.search(r"f32\[\d+,\d+,\d+,\d+,%d,%d\]" % (length, length), text)

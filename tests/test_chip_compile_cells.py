@@ -11,6 +11,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from described_v5e import _lowered, _no_compile_cache, v5e, v5e_2x2  # noqa: F401  (fixtures)
 
@@ -62,6 +63,51 @@ def test_the_local_and_global_attention_cells_step_compiles_for_a_v5e_and_fits(v
         r"= (bf16|f32)\[(4,)?%d,(2048,2048|1024,2048)\]\S* (copy|copy-start)\(" % held)
     found = [line.strip()[:160] for line in text.splitlines() if copies.search(line)]
     assert not found, found[:3]
+
+
+@pytest.mark.parametrize("compiled", [False, pytest.param(True, marks=pytest.mark.slow)],
+                         ids=["lowered", "compiled"])
+def test_the_mamba_cells_step_for_a_v5e_holds_the_window_kernel(v5e_2x2, monkeypatch, compiled):
+    """``nemotron_twotower_train_t192``'s train step as its files give it
+    (pattern ``MEMEM*EME`` at the published widths, B32 x 2p x T192 packed to
+    8 + 96 slots, ``remat: block``, bfloat16), lowered for a described v5e:
+    each of the four Mamba layers' 96-step part runs ``ops/ssd.py``'s window
+    kernel forward, replayed under its checkpoint and backward, twelve Mosaic
+    calls under the ``ssd`` scope the benchmark times (the step drops the
+    part's last state, so the state's own kernel is in no program), and the 8 burn-in
+    steps keep the lines (what the recorder will say as
+    ``model.ssd_window_path``).  ``compiled`` (``slow``: 75 s of compile on
+    eight cores, PR 69) also holds the program to the chip: 10.47 GB at its
+    peak (the parent's 10.30), 7.08 of it the arguments."""
+    import json
+
+    from handyrl_tpu.ops import ssd
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "workloads", "nemotron_twotower_train_t192.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(bench, "configs", cell["config"] + ".json")) as f:
+        config = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # not the interpreter
+    ssd.WINDOW_PATHS.clear()
+    _, lowered = _lowered(v5e_2x2, 1, dict(config["env_args"]),
+                          dict(config["train_args"], **cell["train_args"]), packed=(8, 96))
+    chosen = {made["length"]: made["path"] for made in ssd.WINDOW_PATHS.values()}
+    ssd.WINDOW_PATHS.clear()
+    assert chosen == {8: "lines", 96: "kernel"}, chosen
+    if not compiled:
+        names = re.findall(r'stablehlo\.custom_call @tpu_custom_call.*?kernel_name = "(\w+)"',
+                           lowered.as_text())
+        assert names.count("_window_forward_kernel") == 8, names
+        assert names.count("_window_backward_kernel") == 4, names
+        assert "_window_state_kernel" not in names      # the step drops a part's last state
+        return
+    program = lowered.compile()
+    calls = [line for line in program.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line and "/ssd/" in line]
+    assert len(calls) == 12, len(calls)
+    memory = program.memory_analysis()
+    assert 7.0e9 < memory.argument_size_in_bytes < memory.peak_memory_in_bytes < 11.5e9
 
 
 def test_actor_cell_rollout_compiles_for_a_v5e_and_fits_with_its_state_donated(v5e, monkeypatch):
